@@ -31,7 +31,7 @@ from . import retrace  # noqa: F401
 from . import selfcheck  # noqa: F401
 from . import concurrency  # noqa: F401  (CC lint: threads & locks)
 from . import lockdep  # noqa: F401     (runtime lock-order witness)
-from .memory import (HBM_BYTES, PeakEstimate, estimate_peak,  # noqa: F401
+from .memory import (device_hbm_bytes, PeakEstimate, estimate_peak,  # noqa: F401
                      estimate_offload_stream_hbm, estimate_train_step_hbm,
                      offload_stream_plan, stream_plan_check)
 from .resilience_lint import checkpoint_story_check  # noqa: F401
@@ -40,7 +40,7 @@ __all__ = [
     "Diagnostic", "max_severity", "render", "to_json",
     "OpNode", "Program", "capture", "run_passes", "PASSES",
     "memory", "spmd", "retrace", "selfcheck", "concurrency", "lockdep",
-    "HBM_BYTES", "PeakEstimate", "estimate_peak", "estimate_train_step_hbm",
+    "device_hbm_bytes", "PeakEstimate", "estimate_peak", "estimate_train_step_hbm",
     "estimate_offload_stream_hbm", "offload_stream_plan",
     "stream_plan_check", "checkpoint_story_check",
 ]

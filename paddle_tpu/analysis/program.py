@@ -135,6 +135,7 @@ class OpNode:
 
 # params that hold sub-jaxprs but re-execute them (trip-count semantics)
 _CALL_LABELS = {
+    "jit": lambda e: f"pjit:{e.params.get('name', '')}",  # jax >= 0.7 name
     "pjit": lambda e: f"pjit:{e.params.get('name', '')}",
     "closed_call": lambda e: "closed_call",
     "core_call": lambda e: "call",

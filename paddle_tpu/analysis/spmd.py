@@ -26,7 +26,7 @@ from __future__ import annotations
 from typing import Any, Dict, List, Optional, Tuple
 
 from .diagnostics import Diagnostic
-from .memory import HBM_BYTES
+from .memory import device_hbm_bytes
 from .program import (Program, register_pass, _aval_bytes, _aval_str,
                       _sub_jaxprs, _as_open, _user_location)
 
@@ -116,7 +116,7 @@ class _Walker:
             if name in COLLECTIVES:
                 self._check_collective(eqn, manual, sizes, in_manual_region)
             elif not in_manual_region and name not in (
-                    "pjit", "closed_call", "remat2", "checkpoint"):
+                    "jit", "pjit", "closed_call", "remat2", "checkpoint"):
                 self._check_fat(eqn)
             for _, sub in _sub_jaxprs(eqn):
                 self.walk(_as_open(sub), manual, sizes, in_manual_region)
@@ -210,8 +210,10 @@ class _Walker:
 
 
 @register_pass("spmd")
-def spmd_pass(program: Program, hbm_bytes: int = HBM_BYTES,
+def spmd_pass(program: Program, hbm_bytes: Optional[int] = None,
               hbm_frac: float = 0.5, **_cfg) -> List[Diagnostic]:
+    if hbm_bytes is None:
+        hbm_bytes = device_hbm_bytes()
     w = _Walker(hbm_bytes, hbm_frac)
     w.walk(program.jaxpr, manual=(), sizes={}, in_manual_region=False)
     return w.finish()

@@ -580,9 +580,9 @@ def score_config(profile: ModelProfile, config: Dict[str, Any], *,
         if "mesh" not in config else config
     link = link or link_model_for()
     if hbm_bytes is None:
-        from .engine import usable_hbm_bytes
+        from ...analysis.memory import device_hbm_bytes
 
-        hbm_bytes = usable_hbm_bytes()
+        hbm_bytes = device_hbm_bytes()
     ratio = _drift_ratio() if drift_ratio is None else drift_ratio
     peak, mem_break = _predict_peak_bytes(profile, cfg, _opt_words(optimizer),
                                           ratio)
@@ -629,9 +629,9 @@ def plan(model, n_devices: Optional[int] = None,
     if n_devices is None:
         n_devices = len(jax.devices())
     if hbm_bytes is None:
-        from .engine import usable_hbm_bytes
+        from ...analysis.memory import device_hbm_bytes
 
-        hbm_bytes = usable_hbm_bytes()
+        hbm_bytes = device_hbm_bytes()
     profile = profile_model(model, batch=batch, seq=seq,
                             sample_batch=sample_batch, loss_fn=loss_fn)
     link = link or link_model_for(topology)

@@ -23,10 +23,7 @@ from typing import List, Optional
 import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
-try:  # jax >= 0.8 moved shard_map out of experimental
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from ..core.tensor import Tensor
 from .mesh import MeshEnv, get_mesh_env, require_mesh_env
@@ -201,15 +198,6 @@ def _out_spec(kind, ax, **kw):
     if kind == "alltoall":
         return P(ax)
     raise ValueError(kind)
-
-
-def _in_axis_context() -> Optional[str]:
-    """True when called under shard_map/pjit trace with our axes bound."""
-    try:
-        frame = jax.core.get_axis_env() if hasattr(jax.core, "get_axis_env") else None
-    except Exception:
-        frame = None
-    return None
 
 
 def _prep(tensor, group):

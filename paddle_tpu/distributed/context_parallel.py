@@ -23,8 +23,7 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .mesh import (MeshEnv, get_mesh_env, shard_map_compat,
-                   shard_map_requires_native)
+from .mesh import MeshEnv, get_mesh_env
 
 
 def _merge(o1, lse1, o2, lse2):
@@ -97,8 +96,7 @@ def ring_attention_bhsd(q, k, v, causal=True, scale=None,
     def local(ql, kl, vl):
         return _ring_local(ql, kl, vl, cp, causal, float(scale), axis)
 
-    shard_map_requires_native({axis}, env)  # pallas inside the manual region
-    return shard_map_compat(
+    return jax.shard_map(
         local, mesh=env.mesh,
         in_specs=(P(None, axis), P(None, axis), P(None, axis)),
         out_specs=P(None, axis), axis_names={axis}, check_vma=False,
@@ -168,8 +166,7 @@ def ulysses_attention_bshd(q, k, v, causal=True, scale=None,
         # [b, s, h/cp, d] -> [b, s/cp, h, d]: scatter sequence, gather heads
         return lax.all_to_all(oh, axis, split_axis=1, concat_axis=2, tiled=True)
 
-    shard_map_requires_native({axis}, env)  # pallas inside the manual region
-    return shard_map_compat(
+    return jax.shard_map(
         local, mesh=env.mesh,
         in_specs=(P(None, axis), P(None, axis), P(None, axis)),
         out_specs=P(None, axis), axis_names={axis}, check_vma=False,

@@ -1312,8 +1312,10 @@ def elastic_fit(build: Callable[[FleetWorkerContext], Dict[str, Any]], *,
     checkpoint, and run ``Model.fit`` under the fleet protocol.
 
     ``build(ctx)`` returns ``{"network", "optimizer", "loss", "dataset"}``
-    (plus optional ``"callbacks"``/``"loss_fn"``/``"sample_batch"`` for
-    the planner). Returns ``{"losses", "plan", "resumed_from", ...}`` on
+    (plus optional ``"callbacks"``/``"loss_fn"``/``"sample_batch"``/
+    ``"hbm_bytes"`` for the planner — the per-device memory budget defaults
+    to the device's own ``bytes_limit``; a CPU fleet, whose backend reports
+    none, must state one). Returns ``{"losses", "plan", "resumed_from", ...}`` on
     completion; a fenced worker exits the process with ``EXIT_FENCED``
     and a coordinator-lost worker with ``EXIT_COORD_LOST`` (see
     ``FleetWorkerContext.exit`` for why the exit is ``os._exit``-fast).
@@ -1332,7 +1334,8 @@ def elastic_fit(build: Callable[[FleetWorkerContext], Dict[str, Any]], *,
     if replan:
         plan_desc = ctx.replan(network, batch=global_batch,
                                sample_batch=parts.get("sample_batch"),
-                               loss_fn=parts.get("loss_fn"))
+                               loss_fn=parts.get("loss_fn"),
+                               hbm_bytes=parts.get("hbm_bytes"))
         if plan_desc:
             dp = int(plan_desc.get("config", {}).get("mesh", {})
                      .get("dp", ctx.world)) or ctx.world

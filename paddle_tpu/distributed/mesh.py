@@ -29,52 +29,70 @@ AXES = ("dp", "pp", "sdp", "mp", "cp", "ep")
 _GLOBAL: Dict[str, Optional[object]] = {"env": None}
 
 
-def _auto_axes(mesh, axis_names) -> frozenset:
-    """Mesh axes that must stay AUTO (GSPMD) for a shard_map manual over
-    `axis_names`. Size-1 axes are harmless to treat as manual, so they are
-    excluded — which routes pure-manual meshes down the (much better
-    supported) full-manual path of the older shard_map."""
-    sizes = dict(mesh.shape)
-    return frozenset(ax for ax in mesh.axis_names
-                     if ax not in axis_names and sizes.get(ax, 1) > 1)
+def kernel_mesh():
+    """The live mesh a Pallas kernel call must be wrapped for, or None.
+
+    GSPMD cannot partition a Mosaic custom call ("Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map"), so
+    under a live multi-device mesh every kernel call goes through
+    ``run_kernel_on_mesh``; with no mesh, or one device, it is called
+    directly."""
+    env = _GLOBAL["env"]
+    return env if env is not None and env.nranks > 1 else None
 
 
-def shard_map_compat(f, mesh, in_specs, out_specs, axis_names=None,
-                     check_vma=False):
-    """`jax.shard_map` (the jax>=0.8 surface: axis_names = the manual set,
-    check_vma) over whatever this jax provides. Older jax spells the same
-    thing `jax.experimental.shard_map.shard_map(check_rep=..., auto=...)`
-    with auto = the complement of the manual set."""
-    if hasattr(jax, "shard_map"):
-        kw = {"check_vma": check_vma}
-        if axis_names:
-            kw["axis_names"] = set(axis_names)
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kw)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    kw = {"check_rep": bool(check_vma)}
-    if axis_names:
-        auto = _auto_axes(mesh, axis_names)
-        if auto:
-            kw["auto"] = auto
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kw)
+def kernel_mesh_ok(seq_local: bool = True) -> bool:
+    """Can a Pallas kernel run under the live mesh? Not inside the
+    pipeline's own manual region (pp > 1: a nested full-manual shard_map
+    cannot be entered from there), and a kernel that needs GLOBAL sequence
+    positions (``seq_local=False``: RoPE) not on a sequence-split (cp > 1)
+    mesh. Call sites fall back to the composed-XLA form, which GSPMD
+    partitions itself."""
+    env = kernel_mesh()
+    if env is None:
+        return True
+    if env.get_dim("pp") > 1:
+        return False
+    return seq_local or env.get_dim("cp") == 1
 
 
-def shard_map_requires_native(axis_names, env) -> None:
-    """Raise a clear error when a partial-auto shard_map over THIS mesh
-    cannot work on an older jax (no jax.shard_map): kernels inside the
-    manual region crash the 0.4-era partial-auto lowering outright."""
-    if hasattr(jax, "shard_map"):
-        return
-    auto = _auto_axes(env.mesh, axis_names)
-    if auto:
-        raise NotImplementedError(
-            f"this operation needs a partial-auto shard_map (manual over "
-            f"{sorted(axis_names)}, auto over {sorted(auto)}) which this "
-            f"jax ({jax.__version__}) cannot lower reliably; upgrade jax "
-            f"or collapse the auto axes to size 1")
+def _axes_if_divisible(env, names, n):
+    axes = tuple(ax for ax in names if env.get_dim(ax) > 1)
+    deg = math.prod(env.get_dim(ax) for ax in axes)
+    if not axes or n % deg:
+        return None  # stays replicated over those axes: right, only slower
+    return axes if len(axes) > 1 else axes[0]
+
+
+def activation_spec(shape, layout: str) -> PartitionSpec:
+    """How an activation a kernel consumes is laid out on the live mesh:
+    ``"bshd"`` = [batch, seq, heads, head_dim] (batch over dp/sdp, heads
+    over mp); ``"rows"`` = [batch, (seq,) ..., hidden] (batch over dp/sdp,
+    seq over cp). A dim the degree does not divide is left unsplit. None
+    when there is no multi-device mesh (``run_kernel_on_mesh`` then calls
+    the kernel directly)."""
+    env = kernel_mesh()
+    if env is None:
+        return None
+    data = _axes_if_divisible(env, ("dp", "sdp"), shape[0])
+    if layout == "bshd":
+        return PartitionSpec(data, None,
+                             _axes_if_divisible(env, ("mp",), shape[2]), None)
+    rest = [None] * (len(shape) - 1)
+    if len(shape) >= 3:
+        rest[0] = _axes_if_divisible(env, ("cp",), shape[1])
+    return PartitionSpec(data, *rest)
+
+
+def run_kernel_on_mesh(fn, args, in_specs, out_specs):
+    """``fn(*args)`` as one full-manual ``shard_map`` over the live mesh
+    (each operand split by its spec, ``PartitionSpec()`` = replicated), or
+    a plain call when there is no multi-device mesh."""
+    env = kernel_mesh()
+    if env is None:
+        return fn(*args)
+    return jax.shard_map(fn, mesh=env.mesh, in_specs=tuple(in_specs),
+                         out_specs=out_specs, check_vma=False)(*args)
 
 
 class MeshEnv:

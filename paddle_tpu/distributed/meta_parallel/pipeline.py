@@ -185,9 +185,7 @@ def pipeline_shard_map(stage_fn: Callable, env: MeshEnv, n_stage_args: int,
                 with_aux=with_aux)
 
         out_specs = (P(), P()) if with_aux else P()
-        from ..mesh import shard_map_compat
-
-        return shard_map_compat(
+        return jax.shard_map(
             local, mesh=env.mesh, in_specs=(P(),) + (P("pp"),) * n_stage_args,
             out_specs=out_specs, axis_names={"pp"}, check_vma=False,
         )(x_mb, *stage_params)

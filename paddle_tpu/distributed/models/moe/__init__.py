@@ -85,14 +85,6 @@ def _global_a2a_p(x, local_count, global_count, *, _env_id):
     if ep <= 1:
         return x
 
-    if not hasattr(jax, "shard_map"):
-        # 0.4-era jax: the manual all_to_all lowering SIGABRTs the CPU
-        # backend outright (not a catchable error) — refuse cleanly instead
-        raise NotImplementedError(
-            f"global_scatter/global_gather need jax.shard_map (jax >= 0.7); "
-            f"this jax ({jax.__version__}) cannot lower the manual "
-            f"all_to_all — use the index/einsum dispatch modes instead")
-
     def local(xl, lcl, gcl):
         # xl: [1, n_expert, capacity, d] — this rank's buckets for everyone
         y = jax.lax.all_to_all(xl[0], "ep", split_axis=0, concat_axis=0,

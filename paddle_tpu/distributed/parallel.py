@@ -858,11 +858,34 @@ class ShardedTrainStep:
             return None
         return list(self._stream[3].events)
 
+    def _ensure_built(self, arrays):
+        if self._jitted is None:
+            from ..jit import _audit_instance_label, _maybe_audit, _obs
+
+            _obs()[1].inc(("sharded_train_step", "build"))
+            self._jitted = _maybe_audit(
+                _audit_instance_label("ShardedTrainStep"),
+                self._build(arrays))
+
+    def lower(self, *batch):
+        """AOT-lower the plain sharded step for this batch's shapes without
+        running it (see ``jit.TrainStep.lower``): the compiled text shows
+        which collectives GSPMD put in."""
+        if self.offload or self.scaler is not None or self.accum_steps > 1:
+            raise NotImplementedError(
+                "lower() covers the plain ShardedTrainStep executable")
+        from ..jit import _batch_arrays, lowerable, step_args
+
+        arrays = _batch_arrays(batch)
+        self._ensure_built(arrays)
+        return lowerable(self._jitted).lower(
+            *step_args(self, arrays, jax.random.key(0)))
+
     def __call__(self, *batch):
-        from ..jit import _obs
+        from ..jit import _batch_arrays, _obs, step_args
 
         opt = self.optimizer
-        arrays = [b.data if isinstance(b, Tensor) else jnp.asarray(b) for b in batch]
+        arrays = _batch_arrays(batch)
         tl, tc = _obs()
         if self.offload:
             with tl.step():
@@ -872,19 +895,9 @@ class ShardedTrainStep:
                 return self._call_amp(arrays)
         with tl.step():
             cold = self._jitted is None
-            if cold:
-                from ..jit import _audit_instance_label, _maybe_audit
-
-                tc.inc(("sharded_train_step", "build"))
-                self._jitted = _maybe_audit(
-                    _audit_instance_label("ShardedTrainStep"),
-                    self._build(arrays))
-            params = [p.data for p in self.train_params]
-            states = [opt._accumulators[id(p)] for p in self.train_params]
-            frozen_arrays = [t.data for t in self.frozen]
-            lr = jnp.asarray(opt.get_lr(), jnp.float32)
-            step_no = jnp.asarray(opt._global_step + 1, jnp.int32)
-            key = random_mod.next_key()
+            self._ensure_built(arrays)
+            (params, states, frozen_arrays, lr, step_no,
+             key) = step_args(self, (), random_mod.next_key())
             from ..jit import _memobs
 
             mo = _memobs()

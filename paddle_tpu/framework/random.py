@@ -23,7 +23,10 @@ class Generator:
 
     def manual_seed(self, seed: int):
         self._seed = int(seed)
-        self._key = jax.random.key(self._seed)
+        # the root key is made on first use: building it here would run a
+        # computation (and so create a JAX backend) at `import paddle_tpu`,
+        # and a supervisor that imports the package must not hold the chip
+        self._root = None
         self._counter = 0
         self._trace_keys = []
         self._trace_counter = 0
@@ -41,6 +44,16 @@ class Generator:
         if self._trace_keys:
             self._trace_keys.pop()
 
+    @property
+    def _key(self):
+        if self._root is None:
+            self._root = jax.random.key(self._seed)
+        return self._root
+
+    @_key.setter
+    def _key(self, key):
+        self._root = key
+
     def initial_seed(self) -> int:
         return self._seed
 
@@ -57,7 +70,7 @@ class Generator:
 
     def set_state(self, state):
         self._seed, self._counter = state
-        self._key = jax.random.key(self._seed)
+        self._root = None
 
 
 _GLOBAL_GENERATOR = Generator(0)

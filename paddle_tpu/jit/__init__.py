@@ -286,6 +286,32 @@ def to_static(function=None, input_spec=None, build_strategy=None, backend=None,
     return StaticLayer(function, input_spec)
 
 
+def _batch_arrays(batch):
+    return [b.data if isinstance(b, Tensor) else jnp.asarray(b)
+            for b in batch]
+
+
+def step_args(step, arrays, key):
+    """The compiled step's argument tuple — one signature for TrainStep
+    and ShardedTrainStep: (params, states, frozen, lr, step_no, key,
+    *batch)."""
+    opt = step.optimizer
+    return ([p.data for p in step.train_params],
+            [opt._accumulators[id(p)] for p in step.train_params],
+            [t.data for t in step.frozen],
+            jnp.asarray(opt.get_lr(), jnp.float32),
+            jnp.asarray(opt._global_step + 1, jnp.int32),
+            key, *arrays)
+
+
+def lowerable(jitted):
+    """Peel call-recording wrappers (retrace audit) down to the object
+    that can ``.lower()``."""
+    while not hasattr(jitted, "lower"):
+        jitted = jitted.__wrapped__
+    return jitted
+
+
 class TrainStep:
     """Whole-step compiler: the hybrid of InterpreterCore + generated grad ops.
 
@@ -361,22 +387,31 @@ class TrainStep:
         ``steps``."""
         return AccumulateStep(self, steps, remat=remat, average=average)
 
+    def lower(self, *batch):
+        """AOT-lower the whole step for this batch's shapes WITHOUT running
+        it (``jax.stages.Lowered``): ``.compile()`` then answers what only
+        the compiled program can — ``memory_analysis()`` (does this batch
+        fit?) and ``as_text()`` (are the kernels really in it?). The step's
+        state and the RNG stream are untouched."""
+        self._ensure_built()
+        return lowerable(self._jitted).lower(*step_args(
+            self, _batch_arrays(batch), jax.random.key(0)))
+
+    def _ensure_built(self):
+        if self._jitted is None:
+            _obs()[1].inc(("train_step", "build"))
+            self._jitted = _maybe_audit(
+                _audit_instance_label("TrainStep"), self._build())
+
     def __call__(self, *batch):
-        tl, tc = _obs()
+        tl, _tc = _obs()
         with tl.step():
             cold = self._jitted is None
-            if cold:
-                tc.inc(("train_step", "build"))
-                self._jitted = _maybe_audit(
-                    _audit_instance_label("TrainStep"), self._build())
+            self._ensure_built()
             opt = self.optimizer
-            params = [p.data for p in self.train_params]
-            states = [opt._accumulators[id(p)] for p in self.train_params]
-            frozen_arrays = [t.data for t in self.frozen]
-            lr = jnp.asarray(opt.get_lr(), jnp.float32)
-            step_no = jnp.asarray(opt._global_step + 1, jnp.int32)
-            arrays = [b.data if isinstance(b, Tensor) else jnp.asarray(b) for b in batch]
-            key = random_mod.next_key()
+            arrays = _batch_arrays(batch)
+            (params, states, frozen_arrays, lr, step_no,
+             key) = step_args(self, (), random_mod.next_key())
             mo = _memobs()
             drift_args = mo.struct_args(
                 (params, states, frozen_arrays, lr, step_no, key)

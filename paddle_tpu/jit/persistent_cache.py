@@ -26,6 +26,13 @@ with ``enable(dir)`` or the env vars ``PT_PERSISTENT_CACHE_DIR=<dir>`` /
 ``PT_PERSISTENT_CACHE=1`` (read once at import). Corrupt or stale entries
 are ignored gracefully (treated as a miss and overwritten).
 
+ONE directory rule (``default_dir``): when ``JAX_COMPILATION_CACHE_DIR``
+is set, every cache of this process — these entries and JAX's own
+compilation cache — lives there and no code sets another. Unset, the
+default is the fixed path ``<checkout>/.cache/jax`` (git-ignored): the
+path is part of a cache key, so a directory that moves (tempdir, pid,
+time) never hits.
+
 Counters: ``stats()`` reports hits / misses / backend compiles / load
 errors, per label — surfaced through ``analysis.retrace`` summaries and
 ``serving`` ``engine.stats()``.
@@ -40,7 +47,12 @@ import threading
 from typing import Any, Callable, Dict, Optional, Tuple
 
 __all__ = ["enable", "disable", "is_enabled", "cache_dir", "stats",
-           "reset_stats", "cached_jit", "CachedJit", "clear"]
+           "reset_stats", "cached_jit", "CachedJit", "clear", "default_dir",
+           "enable_jax_compilation_cache"]
+
+_JAX_ENV = "JAX_COMPILATION_CACHE_DIR"
+_CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
 
 _MAGIC = b"PTXC1\n"  # format tag; bump on layout change
 
@@ -68,17 +80,42 @@ def _env_meta() -> Tuple[str, ...]:
             str(len(jax.devices())))
 
 
+def default_dir() -> str:
+    """The compile-cache directory when the caller names none:
+    ``JAX_COMPILATION_CACHE_DIR`` if set, else ``PT_PERSISTENT_CACHE_DIR``,
+    else the fixed ``<checkout>/.cache/jax``."""
+    return os.environ.get(_JAX_ENV) or \
+        os.environ.get("PT_PERSISTENT_CACHE_DIR") or \
+        os.path.join(_CHECKOUT, ".cache", "jax")
+
+
+def enable_jax_compilation_cache() -> str:
+    """Turn on JAX's OWN persistent compilation cache (every ``jax.jit``
+    compile, not only ``cached_jit`` programs) and return its directory.
+    With ``JAX_COMPILATION_CACHE_DIR`` set JAX already points there and
+    nothing here sets another; unset, it is pointed at ``default_dir()``."""
+    import jax
+
+    path = os.environ.get(_JAX_ENV)
+    if not path:
+        path = default_dir()
+        os.makedirs(path, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return path
+
+
 def enable(path: Optional[str] = None) -> str:
-    """Turn the cache on (idempotent). Returns the active directory.
+    """Turn the cache on (idempotent). Returns the active directory:
+    ``JAX_COMPILATION_CACHE_DIR`` when that is set (it overrides ``path``
+    — one process, one cache directory), else ``path``, else the
+    directory already active, else ``default_dir()``.
 
     Entries are unpickled at load, so the directory must not be writable
-    by other users: the fallback default is per-uid under the tempdir,
-    created 0700, and a directory owned by someone else is refused."""
-    if path is None:
-        uid = os.getuid() if hasattr(os, "getuid") else "u"
-        path = _STATE.dir or os.environ.get("PT_PERSISTENT_CACHE_DIR") or \
-            os.path.join(tempfile.gettempdir(),
-                         f"paddle_tpu_exec_cache-{uid}")
+    by other users: it is created 0700, and a directory owned by someone
+    else is refused."""
+    path = os.environ.get(_JAX_ENV) or path or _STATE.dir or default_dir()
     os.makedirs(path, mode=0o700, exist_ok=True)
     if hasattr(os, "getuid"):
         st = os.stat(path)
@@ -211,12 +248,10 @@ def _fallback_jax_cache() -> None:
     the XLA backend work (coarser: caches at the XLA client layer)."""
     import jax
 
-    try:
+    if not os.environ.get(_JAX_ENV):  # set: JAX is there already
         jax.config.update("jax_compilation_cache_dir", _STATE.dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        pass  # the cache is an optimization; never sink the caller
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
 
 
 _SHARDING_REPRS: "weakref.WeakKeyDictionary" = None  # type: ignore[assignment]

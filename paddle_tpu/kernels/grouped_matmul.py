@@ -32,6 +32,10 @@ import jax.numpy as jnp
 DEFAULT_TILING = (512, 512, 1024)
 
 
+def _on_tpu() -> bool:
+    return jax.default_backend() == "tpu"
+
+
 def grouped_matmul(lhs, rhs, group_sizes, *, tiling=None):
     """lhs[m, k] @ rhs[g, k, n] per contiguous row group -> [m, n].
 
@@ -47,7 +51,7 @@ def grouped_matmul(lhs, rhs, group_sizes, *, tiling=None):
     # be tileable (fwd AND the bwd tgmm, which transposes the roles of
     # m/k/n) — small/odd layers take the XLA ragged_dot expansion instead
     aligned = m % 8 == 0 and k % 128 == 0 and n % 128 == 0
-    if jax.default_backend() == "tpu" and aligned:
+    if _on_tpu() and aligned:
         from jax.experimental.pallas.ops.tpu import megablox as mb
 
         tm, tk, tn = tiling or DEFAULT_TILING

@@ -1,18 +1,7 @@
 """Shared helpers for the fused-op kernel modules."""
 from __future__ import annotations
 
-import jax
-
-__all__ = ["interpret_default", "pick_rows"]
-
-
-def interpret_default() -> bool:
-    """Run the Pallas kernel through the interpreter? (CPU backend —
-    tests and virtual meshes; real TPUs compile.)"""
-    try:
-        return jax.default_backend() == "cpu"
-    except Exception:  # pragma: no cover
-        return True
+__all__ = ["pick_rows"]
 
 
 def pick_rows(n: int, pref: int = 256) -> int:

@@ -44,7 +44,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..registry import register_kernel, resolve
-from ._common import interpret_default as _interpret
 from ._common import pick_rows as _pick_rows
 
 __all__ = ["fused_moe_mlp", "fused_route", "MAX_EXPERTS"]
@@ -75,32 +74,50 @@ def _routing_kernel(x_ref, wg_ref, gv_ref, gi_ref, pos_ref, cnt_ref,
     p = p / jnp.sum(p, axis=-1, keepdims=True)             # [bn, e]
     bn = p.shape[0]
     lane = jax.lax.broadcasted_iota(jnp.int32, (bn, e), 1)
+    klane = jax.lax.broadcasted_iota(jnp.int32, (bn, top_k), 1)
+    # everything stays 2-D ([bn, 1] columns, [bn, e] rows): the TPU
+    # lowering has no argmax/cumsum/stack on 1-D vectors, so the top-k
+    # pick is max + first-lane-at-max, and the outputs are assembled by
+    # lane-select instead of stack
     masked = p
-    gvs, gis = [], []
-    for _c in range(top_k):                                # iterative top-k
-        idx = jnp.argmax(masked, axis=-1).astype(jnp.int32)
-        gvs.append(jnp.max(masked, axis=-1))
-        gis.append(idx)
-        masked = jnp.where(lane == idx[:, None], -1.0, masked)
-    gv = jnp.stack(gvs, axis=1)                            # [bn, k]
-    gi = jnp.stack(gis, axis=1)
+    gv = jnp.zeros((bn, top_k), jnp.float32)
+    gi = jnp.zeros((bn, top_k), jnp.int32)
+    ohs = []
+    for c in range(top_k):                                 # iterative top-k
+        vmax = jnp.max(masked, axis=-1, keepdims=True)     # [bn, 1]
+        idx = jnp.min(jnp.where(masked == vmax, lane, e), axis=-1,
+                      keepdims=True)                       # first max lane
+        oh = lane == idx                                   # [bn, e]
+        ohs.append(oh)
+        gv = jnp.where(klane == c, vmax, gv)
+        gi = jnp.where(klane == c, idx, gi)
+        masked = jnp.where(oh, -1.0, masked)
     gv = gv / jnp.maximum(jnp.sum(gv, axis=-1, keepdims=True), 1e-9)
 
-    # position-in-expert, token-major (row r = t*k + c): running per-expert
-    # counters persist in scratch across the sequential grid — this IS the
-    # stable sort-by-expert, without executing a sort
-    flat_e = gi.reshape(bn * top_k)
-    lane_f = jax.lax.broadcasted_iota(jnp.int32, (bn * top_k, e), 1)
-    oh = lane_f == flat_e[:, None]
-    ohi = oh.astype(jnp.int32)
-    pos_local = jnp.cumsum(ohi, axis=0) - 1                # [bn*k, e]
-    base = carry[...]                                      # [1, e]
-    pos_flat = jnp.sum(jnp.where(oh, pos_local + base, 0), axis=-1)
-    pos_ref[...] = pos_flat.reshape(bn, top_k).astype(jnp.int32)
-    carry[...] = base + jnp.sum(ohi, axis=0, keepdims=True)
+    # position-in-expert, token-major (row r = t*k + c): the rows of
+    # expert x ahead of (t, c) are every pick of x by an earlier token
+    # plus this token's earlier choices. The exclusive prefix over tokens
+    # is a strictly-lower-triangular matmul on the MXU (the TPU lowering
+    # has no cumsum; counts < 2^24 are exact in f32), and the running
+    # per-expert counters persist in scratch across the sequential grid —
+    # this IS the stable sort-by-expert, without executing a sort
+    tot = sum(oh.astype(jnp.float32) for oh in ohs)        # [bn, e]
+    row = jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (bn, bn), 1)
+    tril = (col < row).astype(jnp.float32)
+    excl = jax.lax.dot_general(tril, tot, (((1,), (0,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+    base = carry[...]                                      # [1, e] int32
+    ahead = excl.astype(jnp.int32) + base                  # [bn, e]
+    pos = jnp.zeros((bn, top_k), jnp.int32)
+    for c, oh in enumerate(ohs):
+        pos_c = jnp.sum(jnp.where(oh, ahead, 0), axis=-1, keepdims=True)
+        pos = jnp.where(klane == c, pos_c, pos)
+        ahead = ahead + oh.astype(jnp.int32)
+    pos_ref[...] = pos
+    carry[...] = base + jnp.sum(tot, axis=0, keepdims=True).astype(jnp.int32)
     me_acc[...] += jnp.sum(p, axis=0, keepdims=True)
-    top1 = (lane == gi[:, 0][:, None]).astype(jnp.float32)
-    ce_acc[...] += jnp.sum(top1, axis=0, keepdims=True)
+    ce_acc[...] += jnp.sum(ohs[0].astype(jnp.float32), axis=0, keepdims=True)
     gv_ref[...] = gv
     gi_ref[...] = gi
 
@@ -186,7 +203,7 @@ def _route_diff(xt, wg, gate_i, top_k, e):
 def _route_impl(xt, wg, top_k, impl):
     gv, gi, pos, cnt, me, ce = (
         _routing_pallas(xt, wg, top_k,
-                        interpret=(impl == "interpret") or _interpret())
+                        interpret=(impl == "interpret"))
         if impl in ("pallas", "interpret")
         else _routing_composed(xt, wg, top_k))
     n = xt.shape[0]
@@ -245,27 +262,32 @@ def _gather_rows(src, idx, impl):
         return jnp.take(src, idx, axis=0)
     n = idx.shape[0]
     h = src.shape[1]
+    # rows ride as [rows, 1, h]: a (1, h) block of a 2-D array is not a
+    # tile the TPU lowering accepts; (1, h) equal to the array's last two
+    # dims is
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
-        in_specs=[pl.BlockSpec((1, h), lambda i, idx_ref: (idx_ref[i], 0))],
-        out_specs=pl.BlockSpec((1, h), lambda i, idx_ref: (i, 0)),
+        in_specs=[pl.BlockSpec((1, 1, h),
+                               lambda i, idx_ref: (idx_ref[i], 0, 0))],
+        out_specs=pl.BlockSpec((1, 1, h), lambda i, idx_ref: (i, 0, 0)),
     )
     return pl.pallas_call(
         _gather_kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, h), src.dtype),
-        interpret=(impl == "interpret") or _interpret(),
-    )(idx, src)
+        out_shape=jax.ShapeDtypeStruct((n, 1, h), src.dtype),
+        interpret=(impl == "interpret"),
+    )(idx, src[:, None, :]).reshape(n, h)
 
 
 def _make_combine_kernel(top_k):
     def kernel(dest_ref, g_ref, *refs):
         del dest_ref
         y_refs, out_ref = refs[:top_k], refs[top_k]
-        acc = jnp.zeros(out_ref.shape, jnp.float32)
+        g = g_ref[0].astype(jnp.float32)                   # [1, k]
+        acc = jnp.zeros(out_ref.shape[1:], jnp.float32)    # [1, h]
         for c in range(top_k):
-            acc += g_ref[...][0, c] * y_refs[c][...].astype(jnp.float32)
-        out_ref[...] = acc.astype(out_ref.dtype)
+            acc += g[:, c:c + 1] * y_refs[c][0].astype(jnp.float32)
+        out_ref[0] = acc.astype(out_ref.dtype)
     return kernel
 
 
@@ -280,22 +302,26 @@ def _combine_rows(y, gates, dest2, impl, out_dtype=None):
                        gates[..., None].astype(jnp.float32),
                        axis=1).astype(out_dtype)
     h = y.shape[1]
-    in_specs = [pl.BlockSpec((1, k), lambda i, d: (i, 0))]
+    in_specs = [pl.BlockSpec((1, 1, k), lambda i, d: (i, 0, 0))]
     for c in range(k):
         in_specs.append(pl.BlockSpec(
-            (1, h), functools.partial(
-                lambda i, d, _c: (d[i, _c], 0), _c=c)))
+            (1, 1, h), functools.partial(
+                lambda i, d, _c: (d[i * k + _c], 0, 0), _c=c)))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(n,),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, h), lambda i, d: (i, 0)),
+        out_specs=pl.BlockSpec((1, 1, h), lambda i, d: (i, 0, 0)),
     )
+    y3 = y[:, None, :]                 # row blocks: see _gather_rows
+    # the destination map is prefetched FLAT: a 2-D [n, k] SMEM operand
+    # pads its last dim to 128 lanes (2 MiB at n=4096 — over the chip's
+    # 1 MiB of SMEM); 1-D costs 4 bytes per entry
     return pl.pallas_call(
         _make_combine_kernel(k), grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n, h), out_dtype),
-        interpret=(impl == "interpret") or _interpret(),
-    )(dest2, gates, *([y] * k))
+        out_shape=jax.ShapeDtypeStruct((n, 1, h), out_dtype),
+        interpret=(impl == "interpret"),
+    )(dest2.reshape(n * k), gates[:, None, :], *([y3] * k)).reshape(n, h)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))

@@ -36,7 +36,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..registry import register_kernel, resolve
-from ._common import interpret_default as _interpret
 
 __all__ = ["paged_attention"]
 
@@ -44,7 +43,14 @@ _NEG = -1e30
 
 
 def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, W, nh, kvh, hd, PL, scale):
+                  acc_ref, m_ref, l_ref, *, nh, kvh, PL, scale):
+    """One (slot, page) grid step. Blocks: ``pos`` [1, W, 1] (a column,
+    so the visibility mask is a plain broadcast against the key iota),
+    ``q``/``o`` [1, nh, W, hd] (head-major: each head's rows are one
+    aligned [W, hd] slab), ``k``/``v`` [1, PL, kvh, hd] (the arena's own
+    layout — one page DMA'd per step, head ``g`` read as a strided
+    slab)."""
+    del tbl_ref  # consumed by the index maps
     b = pl.program_id(1)
     rep = nh // kvh
 
@@ -54,38 +60,34 @@ def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    qpos = pos_ref[...][0]                                   # [W] int32
-    kpos = b * PL + jax.lax.broadcasted_iota(jnp.int32, (1, PL), 1)[0]
-    # rows are (w, r) pairs flattened per kv-head group
-    qpos_r = jnp.broadcast_to(qpos[:, None], (W, rep)).reshape(W * rep)
-    visible = kpos[None, :] <= qpos_r[:, None]               # [W*rep, PL]
+    qpos = pos_ref[0]                                        # [W, 1] int32
+    kpos = b * PL + jax.lax.broadcasted_iota(jnp.int32, (1, PL), 1)
+    visible = kpos <= qpos                                   # [W, PL]
 
-    for g in range(kvh):
-        lo, hi = g * W * rep, (g + 1) * W * rep
-        q = q_ref[0][:, g * rep:(g + 1) * rep, :].reshape(W * rep, hd)
-        k = k_ref[0][:, g, :]                                # [PL, hd]
-        v = v_ref[0][:, g, :]
+    for h in range(nh):
+        g = h // rep
+        q = q_ref[0, h]                                      # [W, hd]
+        k = k_ref[0, :, g, :]                                # [PL, hd]
+        v = v_ref[0, :, g, :]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         s = jnp.where(visible, s, _NEG)
-        m_prev = m_ref[lo:hi, :1]
+        m_prev = m_ref[h, :, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.where(s > _NEG * 0.5, jnp.exp(s - m_new), 0.0)
-        l_new = alpha * l_ref[lo:hi, :1] + jnp.sum(p, axis=-1, keepdims=True)
-        acc_ref[lo:hi, :] = acc_ref[lo:hi, :] * alpha + jax.lax.dot_general(
+        l_new = alpha * l_ref[h, :, :1] + jnp.sum(p, axis=-1, keepdims=True)
+        acc_ref[h] = acc_ref[h] * alpha + jax.lax.dot_general(
             p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-        m_ref[lo:hi, :] = jnp.broadcast_to(m_new, (hi - lo, m_ref.shape[1]))
-        l_ref[lo:hi, :] = jnp.broadcast_to(l_new, (hi - lo, l_ref.shape[1]))
+        m_ref[h] = jnp.broadcast_to(m_new, m_ref.shape[1:])
+        l_ref[h] = jnp.broadcast_to(l_new, l_ref.shape[1:])
 
     @pl.when(b == pl.num_programs(1) - 1)
     def _():
-        for g in range(kvh):
-            lo, hi = g * W * rep, (g + 1) * W * rep
-            l = jnp.maximum(l_ref[lo:hi, :1], 1e-30)
-            ctx = (acc_ref[lo:hi, :] / l).reshape(W, rep, hd)
-            o_ref[0, :, g * rep:(g + 1) * rep, :] = ctx.astype(o_ref.dtype)
+        for h in range(nh):
+            l = jnp.maximum(l_ref[h, :, :1], 1e-30)
+            o_ref[0, h] = (acc_ref[h] / l).astype(o_ref.dtype)
 
 
 def _paged_pallas(q, k_arena, v_arena, tables, pos, scale, interpret):
@@ -93,31 +95,34 @@ def _paged_pallas(q, k_arena, v_arena, tables, pos, scale, interpret):
     P, PL, kvh, _ = k_arena.shape
     B = tables.shape[1]
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, W=W, nh=nh, kvh=kvh, hd=hd, PL=PL,
+        functools.partial(_paged_kernel, nh=nh, kvh=kvh, PL=PL,
                           scale=scale),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(S, B),
             in_specs=[
-                pl.BlockSpec((1, W), lambda s, b, t: (s, 0)),
-                pl.BlockSpec((1, W, nh, hd), lambda s, b, t: (s, 0, 0, 0)),
+                # pos rides as [S, W, 1]: a (1, W) block of [S, W] is not
+                # a tile the TPU lowering accepts; (W, 1) equals the
+                # array's last two dims and lands as a column vector
+                pl.BlockSpec((1, W, 1), lambda s, b, t: (s, 0, 0)),
+                pl.BlockSpec((1, nh, W, hd), lambda s, b, t: (s, 0, 0, 0)),
                 pl.BlockSpec((1, PL, kvh, hd),
                              lambda s, b, t: (t[s, b], 0, 0, 0)),
                 pl.BlockSpec((1, PL, kvh, hd),
                              lambda s, b, t: (t[s, b], 0, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, W, nh, hd),
+            out_specs=pl.BlockSpec((1, nh, W, hd),
                                    lambda s, b, t: (s, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((W * nh, hd), jnp.float32),
-                pltpu.VMEM((W * nh, 128), jnp.float32),
-                pltpu.VMEM((W * nh, 128), jnp.float32),
+                pltpu.VMEM((nh, W, hd), jnp.float32),
+                pltpu.VMEM((nh, W, 128), jnp.float32),
+                pltpu.VMEM((nh, W, 128), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((S, W, nh, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, nh, W, hd), q.dtype),
         interpret=interpret,
-    )(tables, pos, q, k_arena, v_arena)
-    return out
+    )(tables, pos[:, :, None], jnp.swapaxes(q, 1, 2), k_arena, v_arena)
+    return jnp.swapaxes(out, 1, 2)
 
 
 def _paged_composed(q, k_arena, v_arena, tables, pos, scale):
@@ -145,7 +150,7 @@ def _paged_composed(q, k_arena, v_arena, tables, pos, scale):
 def _run(q, k_arena, v_arena, tables, pos, scale, impl):
     if impl in ("pallas", "interpret"):
         return _paged_pallas(q, k_arena, v_arena, tables, pos, scale,
-                             interpret=(impl == "interpret") or _interpret())
+                             interpret=(impl == "interpret"))
     return _paged_composed(q, k_arena, v_arena, tables, pos, scale)
 
 

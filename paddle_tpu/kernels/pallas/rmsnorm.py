@@ -31,7 +31,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..registry import register_kernel, resolve
-from ._common import interpret_default as _interpret
 from ._common import pick_rows as _pick_rows
 
 __all__ = ["rms_norm", "rms_norm_residual"]
@@ -205,14 +204,14 @@ def _bwd_composed(s, w, rstd, dy, dr, residual):
 def _run_fwd(x2, r2, w, eps, impl, residual):
     if impl in ("pallas", "interpret"):
         return _fwd_pallas(x2, r2, w, eps, residual,
-                           interpret=(impl == "interpret") or _interpret())
+                           interpret=(impl == "interpret"))
     return _fwd_composed(x2, r2, w, eps, residual)
 
 
 def _run_bwd(s, w, rstd, dy, dr, impl, residual):
     if impl in ("pallas", "interpret"):
         return _bwd_pallas(s, w, rstd, dy, dr, residual,
-                           interpret=(impl == "interpret") or _interpret())
+                           interpret=(impl == "interpret"))
     return _bwd_composed(s, w, rstd, dy, dr, residual)
 
 

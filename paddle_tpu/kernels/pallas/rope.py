@@ -23,46 +23,61 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from ..registry import register_kernel, resolve
-from ._common import interpret_default as _interpret
 from ._common import pick_rows
 
 __all__ = ["rope_apply"]
 
 
-def _pick_seq_block(s: int, pref: int = 512) -> int:
-    return pick_rows(s, pref)
+# VMEM the kernel may plan for per grid step (v5e scopes 16 MiB): the
+# in/out blocks are double-buffered in x.dtype and the body holds ~4 f32
+# temporaries of the block, so the sequence block is sized from
+# heads x head_dim, not from the sequence alone (a 512-row block at
+# 16 x 128 asked for 24.4 MiB and was refused by the chip's compiler)
+_VMEM_BUDGET = 6 << 20
+
+
+def _pick_seq_block(s: int, h: int, d: int, itemsize: int) -> int:
+    per_row = h * d * (4 * itemsize + 4 * 4)
+    return pick_rows(s, max(8, min(512, _VMEM_BUDGET // per_row)))
 
 
 def _angles(bs: int, d: int, theta: float, base_pos):
-    """cos/sin [bs, 1, d//2] for positions base_pos + [0..bs) — computed
-    in-register (f32) from iotas; no table input."""
+    """cos and sign-folded sin, [bs, 1, d], for positions base_pos +
+    [0..bs) — computed in-register from INTEGER iotas cast to f32 (the
+    TPU iota op yields integers only); no table input. Both halves of the
+    lane axis carry the same angle; sin is negated on the first half so
+    ``x*cos + swap_halves(x)*sin`` is the rotate-half rotation."""
     half = d // 2
-    pos = base_pos + jax.lax.broadcasted_iota(jnp.float32, (bs, 1, half), 0)
+    pos = (base_pos + jax.lax.broadcasted_iota(jnp.int32, (bs, 1, d), 0)
+           ).astype(jnp.float32)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (bs, 1, d), 2)
+    upper = lane >= half
+    idx = jnp.where(upper, lane - half, lane).astype(jnp.float32)
     # inv_freq_i = theta^(-2i/d) == exp(-(2i/d) * ln(theta))
-    idx = jax.lax.broadcasted_iota(jnp.float32, (bs, 1, half), 2)
-    inv = jnp.exp(idx * (-2.0 / d) * math.log(theta))
-    freqs = pos * inv
-    return jnp.cos(freqs), jnp.sin(freqs)
+    freqs = pos * jnp.exp(idx * (-2.0 / d) * math.log(theta))
+    return jnp.cos(freqs), jnp.where(upper, jnp.sin(freqs), -jnp.sin(freqs))
 
 
 def _rope_kernel(x_ref, o_ref, *, theta, pos_offset, block_s, d, inverse):
     s_start = pl.program_id(1) * block_s
-    cos, sin = _angles(block_s, d, theta, jnp.float32(pos_offset) + s_start)
+    cos, sin = _angles(block_s, d, theta, pos_offset + s_start)
     if inverse:
         sin = -sin
     xf = x_ref[0].astype(jnp.float32)          # [block_s, h, d]
     half = d // 2
-    x1, x2 = xf[..., :half], xf[..., half:]
-    o_ref[0] = jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-        axis=-1).astype(o_ref.dtype)
+    if d % 128 == 0:
+        swapped = pltpu.roll(xf, half, 2)      # lane rotate: one XLU pass
+    else:
+        swapped = jnp.concatenate([xf[..., half:], xf[..., :half]], axis=-1)
+    o_ref[0] = (xf * cos + swapped * sin).astype(o_ref.dtype)
 
 
 def _rope_pallas(x, theta, pos_offset, inverse, interpret):
     b, s, h, d = x.shape
-    bs = _pick_seq_block(s)
+    bs = _pick_seq_block(s, h, d, x.dtype.itemsize)
     return pl.pallas_call(
         functools.partial(_rope_kernel, theta=theta, pos_offset=pos_offset,
                           block_s=bs, d=d, inverse=inverse),
@@ -92,7 +107,7 @@ def _rope_composed(x, theta, pos_offset, inverse):
 def _run(x, theta, pos_offset, impl, inverse):
     if impl in ("pallas", "interpret"):
         return _rope_pallas(x, theta, pos_offset, inverse,
-                            interpret=(impl == "interpret") or _interpret())
+                            interpret=(impl == "interpret"))
     return _rope_composed(x, theta, pos_offset, inverse)
 
 

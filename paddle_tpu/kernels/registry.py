@@ -26,6 +26,7 @@ PR-9 planner prices the same entries via ``cost_model.fused``.
 """
 from __future__ import annotations
 
+import functools
 import os
 from typing import Any, Callable, Dict, Optional, Tuple
 
@@ -74,22 +75,18 @@ def _ensure_provider():
 
 
 def _backend() -> str:
-    try:
-        import jax
+    # no fallback: a backend that cannot be asked is an error the caller
+    # must see, not a silent "cpu" (which would hide the device)
+    import jax
 
-        return jax.default_backend()
-    except Exception:  # pragma: no cover
-        return "cpu"
+    return jax.default_backend()
 
 
 def _flag() -> str:
-    try:
-        from ..framework import flags as flags_mod
+    from ..framework import flags as flags_mod
 
-        return str(flags_mod.get_flags("FLAGS_fused_kernels")
-                   ["FLAGS_fused_kernels"]).strip()
-    except Exception:  # mid-build partial package
-        return "auto"
+    return str(flags_mod.get_flags("FLAGS_fused_kernels")
+               ["FLAGS_fused_kernels"]).strip()
 
 
 def fused_enabled(name: str) -> bool:
@@ -102,10 +99,7 @@ def fused_enabled(name: str) -> bool:
     any backend (e.g. ``rms_norm,rope``).
     """
     if name not in _KERNELS:
-        try:
-            _register_builtin()  # first touch in this process
-        except Exception:  # pragma: no cover - mid-build partial package
-            return False
+        _register_builtin()  # first touch in this process
     if name not in _KERNELS:
         return False
     mode = _flag()
@@ -119,10 +113,7 @@ def fused_enabled(name: str) -> bool:
 
 
 def enabled_ops() -> Tuple[str, ...]:
-    try:
-        _register_builtin()  # a fresh process has an empty table
-    except Exception:  # pragma: no cover - mid-build partial package
-        pass
+    _register_builtin()  # a fresh process has an empty table
     return tuple(sorted(n for n in _KERNELS if fused_enabled(n)))
 
 
@@ -142,7 +133,7 @@ def resolve(name: str) -> Tuple[str, Callable]:
     entry = _KERNELS[name]
     if _interpret_forced():
         entry.calls["interpret"] += 1
-        return "interpret", entry.pallas
+        return "interpret", functools.partial(entry.pallas, impl="interpret")
     if _backend() == "tpu":
         entry.calls["pallas"] += 1
         return "pallas", entry.pallas
@@ -154,10 +145,7 @@ def kernel_table() -> Dict[str, Any]:
     """Per-op dispatch truth: which implementation each registered fused
     op resolves to right now, whether its call-site gate is open, and the
     trace-time call counts (the ``fused_kernels`` hub provider)."""
-    try:
-        _register_builtin()
-    except Exception:  # pragma: no cover - mid-build partial package
-        pass
+    _register_builtin()
     backend = _backend()
     mode = _flag()
     impl = "interpret" if _interpret_forced() else (

@@ -94,9 +94,14 @@ class LlamaMoEConfig(LlamaConfig):
 def _rope(x, *, theta, pos_offset, fused=False):
     # x: [b, s, h, d]; rotate-half RoPE in fp32
     if fused:
+        from ..distributed.mesh import activation_spec, run_kernel_on_mesh
         from ..kernels.pallas.rope import rope_apply as _fused_rope
 
-        return _fused_rope(x, theta, pos_offset)
+        # seq stays unsplit, so every shard sees global positions
+        spec = activation_spec(x.shape, "bshd")
+        return run_kernel_on_mesh(
+            lambda xl: _fused_rope(xl, theta, pos_offset), (x,), (spec,),
+            spec)
     b, s, h, d = x.shape
     pos = jnp.arange(pos_offset, pos_offset + s, dtype=jnp.float32)
     inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
@@ -112,10 +117,12 @@ def _rope(x, *, theta, pos_offset, fused=False):
 def apply_rotary_pos_emb(x: Tensor, theta: float = 10000.0, pos_offset: int = 0) -> Tensor:
     # the fused-kernel gate is a primitive ATTR (cache-key participant):
     # an FLAGS_fused_kernels flip retraces and the retrace auditor names it
+    from ..distributed.mesh import kernel_mesh_ok
     from ..kernels.registry import fused_enabled
 
     return _rope(x, theta=float(theta), pos_offset=int(pos_offset),
-                 fused=fused_enabled("rope"))
+                 fused=fused_enabled("rope")
+                 and kernel_mesh_ok(seq_local=False))
 
 
 def _cp_axes():
@@ -222,10 +229,10 @@ class LlamaDecoderLayer(nn.Layer):
         self.post_attention_layernorm = nn.RMSNorm(config.hidden_size, config.rms_norm_eps)
 
     def forward(self, hidden):
-        from ..kernels.registry import fused_enabled
+        from ..nn.functional.common import _rms_fused_gate
 
         hidden = _mark_seq(hidden)
-        if fused_enabled("rms_norm"):
+        if _rms_fused_gate():
             # fused residual-add + norm: the attn output, the residual
             # stream and the post-norm read/write collapse into one HBM
             # pass (kernels/pallas/rmsnorm.py); the first norm of the

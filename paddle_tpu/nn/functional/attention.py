@@ -41,12 +41,18 @@ def _sdpa_xla(q, k, v, mask, *, causal, scale, dropout_p, key=None):
 @primitive("sdpa")
 def _sdpa(q, k, v, *, causal, scale, impl="xla"):
     if impl == "flash":
-        try:
-            from ...kernels.flash_attention import flash_attention
+        # no fallback: a kernel the chip's compiler refuses must surface
+        # (a silent XLA softmax here once hid every such refusal)
+        from ...distributed.mesh import activation_spec, run_kernel_on_mesh
+        from ...kernels.flash_attention import flash_attention
 
-            return flash_attention(q, k, v, causal=causal, scale=scale)
-        except Exception:  # pragma: no cover - kernel unavailable
-            pass
+        # under a live mesh: batch over dp/sdp, heads over mp, one
+        # full-manual shard_map (GSPMD cannot partition a Mosaic kernel)
+        spec = activation_spec(q.shape, "bshd")
+        return run_kernel_on_mesh(
+            lambda ql, kl, vl: flash_attention(ql, kl, vl, causal=causal,
+                                               scale=scale),
+            (q, k, v), (spec, spec, spec), spec)
     return _sdpa_xla(q, k, v, None, causal=causal, scale=scale,
                      dropout_p=0.0)
 
@@ -85,13 +91,14 @@ def attention_backend(sq: int, sk: int, head_dim: int,
     if os.environ.get("PADDLE_TPU_DISABLE_FLASH", "0") == "1":
         return "xla"
     if platform is None:
-        try:
-            platform = jax.devices()[0].platform
-        except Exception:
-            return "xla"
+        platform = jax.devices()[0].platform
     if platform == "cpu":
         return "xla"
+    from ...distributed.mesh import kernel_mesh_ok
     from ...framework import flags as flags_mod
+
+    if not kernel_mesh_ok():  # inside the pipeline's manual region
+        return "xla"
 
     if not flags_mod.flag("use_pallas_flash_attention"):
         return "xla"

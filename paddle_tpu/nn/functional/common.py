@@ -86,19 +86,19 @@ def embedding(x, weight, padding_idx=None, sparse=False, name=None,
             if isinstance(ids, np.ndarray):
                 # host ids validate host-side (no H2D round-trip)
                 lo, hi = int(ids.min()), int(ids.max())
-            elif not jax.core.trace_state_clean():
-                # CONCRETE device ids under an AMBIENT trace: possible
-                # when an upstream op ran through an AOT-compiled
-                # executable (persistent-cache per-op jits) — the
-                # min/max readback below would be STAGED by the ambient
-                # trace and np.asarray would crash on the new tracer.
-                # Same contract as tracer ids: traced programs are
-                # documented unchecked.
-                lo, hi = 0, -1
             else:
-                # ONE blocking readback for both bounds, not two
-                lo, hi = (int(v) for v in np.asarray(
-                    jnp.stack([jnp.min(ids), jnp.max(ids)])))
+                bounds = jnp.stack([jnp.min(ids), jnp.max(ids)])
+                if isinstance(bounds, jax.core.Tracer):
+                    # CONCRETE device ids under an AMBIENT trace (an
+                    # upstream op ran through an AOT-compiled executable
+                    # — persistent-cache per-op jits): the reduction was
+                    # STAGED, so there is nothing to read back. Same
+                    # contract as tracer ids: traced programs are
+                    # documented unchecked.
+                    lo, hi = 0, -1
+                else:
+                    # ONE blocking readback for both bounds, not two
+                    lo, hi = (int(v) for v in np.asarray(bounds))
             if lo < 0 or hi >= n:
                 raise ValueError(
                     f"embedding: id out of range [0, {n}) "
@@ -171,9 +171,14 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5, name=N
 @primitive("rms_norm_op")
 def _rms_norm(x, w, *, eps, fused=False):
     if fused:
+        from jax.sharding import PartitionSpec as P
+
+        from ...distributed.mesh import activation_spec, run_kernel_on_mesh
         from ...kernels.pallas.rmsnorm import rms_norm as _fused
 
-        return _fused(x, w, eps)
+        spec = activation_spec(x.shape, "rows")
+        return run_kernel_on_mesh(lambda xl, wl: _fused(xl, wl, eps),
+                                  (x, w), (spec, P()), spec)
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
     xn = x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
     return (xn * w.astype(jnp.float32)).astype(x.dtype)
@@ -185,9 +190,15 @@ def _rms_norm_residual(x, res, w, *, eps, fused=False):
     (y, s): fused through kernels/pallas when the registry gate is open,
     else the composed two-op form (identical math)."""
     if fused:
+        from jax.sharding import PartitionSpec as P
+
+        from ...distributed.mesh import activation_spec, run_kernel_on_mesh
         from ...kernels.pallas.rmsnorm import rms_norm_residual as _fused
 
-        return _fused(x, res, w, eps)
+        spec = activation_spec(x.shape, "rows")
+        return run_kernel_on_mesh(
+            lambda xl, rl, wl: _fused(xl, rl, wl, eps), (x, res, w),
+            (spec, spec, P()), (spec, spec))
     s = x + res
     var = jnp.mean(jnp.square(s.astype(jnp.float32)), axis=-1, keepdims=True)
     sn = s.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
@@ -195,9 +206,10 @@ def _rms_norm_residual(x, res, w, *, eps, fused=False):
 
 
 def _rms_fused_gate() -> bool:
+    from ...distributed.mesh import kernel_mesh_ok
     from ...kernels.registry import fused_enabled
 
-    return fused_enabled("rms_norm")
+    return fused_enabled("rms_norm") and kernel_mesh_ok()
 
 
 def rms_norm(x, weight, epsilon=1e-6, name=None):

@@ -52,7 +52,7 @@ def test_capture_train_step_walks_whole_step():
     assert len(prog.nodes) > 100          # fwd + bwd + update
     assert any(prog.donated_invars)       # donation mask captured
     # the pass runner executes every registered pass without error
-    diags = A.run_passes(prog)
+    diags = A.run_passes(prog, hbm_bytes=int(16e9))  # CPU reports none
     assert all(d.severity in ("info", "warning", "error") for d in diags)
 
 
@@ -169,7 +169,7 @@ def test_spmd_flags_broken_ppermute_pair():
     sm = shard_map(f, mesh=mesh, in_specs=P("pp"), out_specs=P("pp"),
                    check_rep=False)
     prog = A.capture(sm, jnp.ones((8, 4)))
-    diags = A.run_passes(prog, passes=["spmd"])
+    diags = A.run_passes(prog, passes=["spmd"], hbm_bytes=int(16e9))
     codes = {d.code for d in diags}
     assert "SP002" in codes   # malformed perm (duplicate destination)
     assert "SP003" in codes   # mismatched stage handoff
@@ -190,7 +190,7 @@ def test_spmd_clean_pipeline_has_no_findings():
     sm = shard_map(f, mesh=mesh, in_specs=P("pp"), out_specs=P("pp"),
                    check_rep=False)
     prog = A.capture(sm, jnp.ones((8, 4)))
-    diags = A.run_passes(prog, passes=["spmd"])
+    diags = A.run_passes(prog, passes=["spmd"], hbm_bytes=int(16e9))
     assert not [d for d in diags if d.severity == "error"]
 
 
@@ -201,7 +201,7 @@ def test_spmd_flags_fat_unsharded_intermediate():
 
     prog = A.capture(f, jnp.ones((64,), jnp.float32))
     diags = A.run_passes(prog, passes=["spmd"],
-                         hbm_bytes=int(9.5e9), hbm_frac=0.25)
+                         hbm_bytes=int(16e9), hbm_frac=0.25)
     assert any(d.code == "SP004" for d in diags)
 
 
@@ -267,7 +267,7 @@ def test_memory_pass_flags_static_oom():
         return (big * 2.0).sum()
 
     prog = A.capture(f, jnp.ones((256,), jnp.float32))
-    diags = A.run_passes(prog, passes=["memory"], hbm_bytes=int(9.5e9))
+    diags = A.run_passes(prog, passes=["memory"], hbm_bytes=int(16e9))
     assert any(d.code == "MM003" and d.severity == "error" for d in diags)
 
 

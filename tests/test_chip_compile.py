@@ -1,0 +1,151 @@
+"""AOT compiles for the described chip (on-chip-measurement §2 step 3).
+
+Every Pallas kernel the two main paths reach (``chip_smoke.py``: Llama
+train at hidden 2048 / seq 2048 / 16 heads x 128, GPT-2-small serve) is
+lowered and compiled here for a ``v5e:2x2`` topology that is described,
+not attached: what the chip's compiler refuses (tiling, VMEM, missing
+lowerings) fails a tier-1 test instead of a chip call. Interpret-mode
+parity lives in ``test_pallas_kernels.py``; nothing here runs a kernel.
+
+The topology is described inside a module-scoped fixture only (never at
+import / collection: one process at a time may load the TPU library, and
+xdist workers import every test file). All compiles happen in this
+process; JAX's persistent cache is off around them (a described-device
+compile is written to the cache but can never be read back).
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+BF16 = jnp.bfloat16
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(autouse=True)
+def _no_persistent_cache():
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prior = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prior)
+    cc.reset_cache()
+
+
+def _compile(fn, one_chip, *shapes):
+    """Lower + compile ``fn`` for the described chip; returns the
+    compiled program's text (must contain the Mosaic custom call)."""
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    compiled = jax.jit(fn).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text, "kernel is not in the program"
+    return text
+
+
+@pytest.mark.parametrize("b,s,h,d", [(4, 2048, 16, 128), (8, 1024, 12, 64)])
+def test_flash_fwd_bwd(one_chip, monkeypatch, b, s, h, d):
+    from paddle_tpu.kernels import flash_attention as fa
+
+    # this process's backend is the CPU; the kernel must compile, not
+    # interpret — steered here, in the test (the program has no option)
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+
+    def loss(q, k, v):
+        return jnp.sum(fa.flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32))
+
+    qkv = ((b, s, h, d), BF16)
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, qkv, qkv, qkv)
+
+
+@pytest.mark.parametrize("residual", [False, True])
+def test_rmsnorm_fwd_bwd(one_chip, residual):
+    from paddle_tpu.kernels.pallas import rmsnorm as krms
+
+    n, hdim = 8192, 2048
+
+    if residual:
+        def loss(x, r, w):
+            y, s = krms.rms_norm_residual(x, r, w, 1e-6, impl="pallas")
+            return jnp.sum(y.astype(jnp.float32)) + \
+                jnp.sum(s.astype(jnp.float32))
+        shapes = [((n, hdim), BF16), ((n, hdim), BF16), ((hdim,), BF16)]
+        argnums = (0, 1, 2)
+    else:
+        def loss(x, w):
+            return jnp.sum(krms.rms_norm(x, w, 1e-6, impl="pallas")
+                           .astype(jnp.float32))
+        shapes = [((n, hdim), BF16), ((hdim,), BF16)]
+        argnums = (0, 1)
+    _compile(jax.grad(loss, argnums=argnums), one_chip, *shapes)
+
+
+@pytest.mark.parametrize("b", [4, 16])
+def test_rope_fwd_inverse(one_chip, b):
+    from paddle_tpu.kernels.pallas import rope as krope
+
+    def loss(x):  # grad = the inverse rotation through the same kernel
+        return jnp.sum(krope.rope_apply(x, 1e4, 0, impl="pallas")
+                       .astype(jnp.float32))
+
+    _compile(jax.value_and_grad(loss), one_chip, ((b, 2048, 16, 128), BF16))
+
+
+@pytest.mark.parametrize("W", [1, 5, 64])
+@pytest.mark.parametrize("dtype", [jnp.float32, BF16])
+def test_paged_attention(one_chip, W, dtype):
+    """GPT-2-small heads (12 x 64), page_len 16: decode (W=1), the
+    speculative verify window (W=spec_tokens+1) and a prefill bucket."""
+    from paddle_tpu.kernels.pallas import paged_attention as kpaged
+
+    S, nh, hd, PL, P, B = 8, 12, 64, 16, 257, 32
+
+    def run(q, k, v, tables, pos):
+        return kpaged.paged_attention(q, k, v, tables, pos, impl="pallas")
+
+    _compile(run, one_chip,
+             ((S, W, nh, hd), dtype), ((P, PL, nh, hd), dtype),
+             ((P, PL, nh, hd), dtype), ((S, B), jnp.int32),
+             ((S, W), jnp.int32))
+
+
+def test_moe_routing_dispatch(one_chip, monkeypatch):
+    """The ``moe`` recipe's layer (hidden 1536, 8 experts, top-2, expert
+    MLP 2048) through the fused routing/dispatch kernels, fwd + bwd.
+    The expert FFN rides megablox ``gmm``, which gates on the backend —
+    steered to its TPU branch here, in the test."""
+    from paddle_tpu.kernels import grouped_matmul as gm
+    from paddle_tpu.kernels.pallas import moe_dispatch as kmoe
+
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    b, s, h, e, inter = 2, 2048, 1536, 8, 2048
+
+    def loss(x, wg, w_gate, w_up, w_down):
+        out, aux = kmoe.fused_moe_mlp(x, wg, w_gate, w_up, w_down,
+                                      top_k=2, impl="pallas")
+        return jnp.sum(out.astype(jnp.float32)) + aux
+
+    _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), one_chip,
+             ((b, s, h), BF16), ((h, e), jnp.float32),
+             ((e, h, inter), BF16), ((e, h, inter), BF16),
+             ((e, inter, h), BF16))

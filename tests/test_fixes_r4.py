@@ -62,7 +62,9 @@ class TestProposeMesh:
     def test_large_model_8dev(self):
         from paddle_tpu.distributed.auto_parallel.engine import propose_mesh
 
-        axes = propose_mesh(8, param_bytes=int(14e9), num_heads=32)
+        # the CPU backend reports no bytes_limit: a stated test budget
+        axes = propose_mesh(8, param_bytes=int(14e9), num_heads=32,
+                            hbm_bytes=10e9)
         total = 1
         for d in axes.values():
             total *= d

@@ -228,7 +228,7 @@ def test_analysis_models_two_group_working_set():
     est = analysis.estimate_offload_stream_hbm(step, x, y)
     assert est["peak_bytes"] == (est["device_program_peak_bytes"]
                                  + est["stream_working_set_bytes"])
-    diags = analysis.stream_plan_check(step, x, y)
+    diags = analysis.stream_plan_check(step, x, y, hbm_bytes=int(16e9))
     assert [d.code for d in diags] == ["MM012"]  # tiny net fits
     dist.reset_mesh()
 

@@ -527,9 +527,9 @@ def test_planner_fused_entries_reprice_and_rerank():
 
     paddle.seed(0)
     m = LlamaForCausalLM(LlamaConfig.tiny())
-    off = dist.plan(m, n_devices=8, hbm_bytes=9.5e9, batch=16, seq=64,
+    off = dist.plan(m, n_devices=8, hbm_bytes=16e9, batch=16, seq=64,
                     fused_kernels=False)
-    on = dist.plan(m, n_devices=8, hbm_bytes=9.5e9, batch=16, seq=64,
+    on = dist.plan(m, n_devices=8, hbm_bytes=16e9, batch=16, seq=64,
                    fused_kernels=True)
     by_key = {str(c.config): c.predicted_step_s for c in off}
     deltas = [by_key[str(c.config)] - c.predicted_step_s
@@ -540,12 +540,12 @@ def test_planner_fused_entries_reprice_and_rerank():
 
     paddle.seed(0)
     moe = dist.plan(LlamaForCausalLM(LlamaMoEConfig.tiny()), n_devices=8,
-                    hbm_bytes=9.5e9, batch=16, seq=64, fused_kernels=True)
+                    hbm_bytes=16e9, batch=16, seq=64, fused_kernels=True)
     assert "moe_dispatch" in moe[0].breakdown["fused_ops"]
     # fused_kernels=None follows the live registry (CPU auto -> none)
     flags_mod.set_flags({"FLAGS_fused_kernels": "auto"})
     paddle.seed(0)
-    auto = dist.plan(m, n_devices=8, hbm_bytes=9.5e9, batch=16, seq=64)
+    auto = dist.plan(m, n_devices=8, hbm_bytes=16e9, batch=16, seq=64)
     if jax.default_backend() == "cpu":
         assert "fused_gain_s" not in auto[0].breakdown
 

@@ -127,7 +127,7 @@ class TestScoringAndRanking:
         assert cands and all(not c.feasible for c in cands)
         # default return prunes them: a realistic budget returns ONLY
         # feasible candidates unless include_infeasible is passed
-        ok = dist.plan(model, n_devices=8, hbm_bytes=9.5e9,
+        ok = dist.plan(model, n_devices=8, hbm_bytes=16e9,
                        batch=16, seq=64)
         assert ok and all(c.feasible for c in ok)
         both = dist.plan(model, n_devices=8, hbm_bytes=2e6, batch=16,
@@ -140,15 +140,15 @@ class TestScoringAndRanking:
     def test_ranking_deterministic(self):
         paddle.seed(0)
         model = LlamaForCausalLM(LlamaConfig.tiny())
-        a = dist.plan(model, n_devices=8, hbm_bytes=9.5e9, batch=16, seq=64)
-        b = dist.plan(model, n_devices=8, hbm_bytes=9.5e9, batch=16, seq=64)
+        a = dist.plan(model, n_devices=8, hbm_bytes=16e9, batch=16, seq=64)
+        b = dist.plan(model, n_devices=8, hbm_bytes=16e9, batch=16, seq=64)
         assert [c.describe() for c in a] == [c.describe() for c in b]
         assert [c.predicted_step_s for c in a] == \
             [c.predicted_step_s for c in b]
 
     def test_bigger_model_needs_more_memory(self):
         prof, _ = _tiny_profile()
-        cand = planner.score_config(prof, {"dp": 8}, hbm_bytes=9.5e9,
+        cand = planner.score_config(prof, {"dp": 8}, hbm_bytes=16e9,
                                     drift_ratio=1.0)
         # same config, 100x the params: peak must scale up
         import dataclasses
@@ -156,7 +156,7 @@ class TestScoringAndRanking:
         prof_big = dataclasses.replace(
             prof, param_bytes=prof.param_bytes * 100,
             param_elems=prof.param_elems * 100)
-        big = planner.score_config(prof_big, {"dp": 8}, hbm_bytes=9.5e9,
+        big = planner.score_config(prof_big, {"dp": 8}, hbm_bytes=16e9,
                                    drift_ratio=1.0)
         assert big.predicted_peak_bytes > 10 * cand.predicted_peak_bytes
 
@@ -171,11 +171,11 @@ class TestScoringAndRanking:
                                    param_bytes=prof.param_bytes * 200,
                                    param_elems=prof.param_elems * 200)
         base = planner.score_config(
-            prof, {"sharding": 8, "level": "os_g"}, hbm_bytes=9.5e9,
+            prof, {"sharding": 8, "level": "os_g"}, hbm_bytes=16e9,
             drift_ratio=1.0)
         off = planner.score_config(
             prof, {"sharding": 8, "level": "os_g", "offload": True},
-            hbm_bytes=9.5e9, drift_ratio=1.0)
+            hbm_bytes=16e9, drift_ratio=1.0)
         assert off.predicted_peak_bytes < base.predicted_peak_bytes
         assert off.predicted_step_s > base.predicted_step_s
 
@@ -186,7 +186,7 @@ class TestScoringAndRanking:
         prof_moe, _ = _tiny_profile(moe=True)
         for raw in MULTICHIP_R05:
             prof = prof_moe if raw.get("ep", 1) > 1 else prof_dense
-            cand = planner.score_config(prof, dict(raw), hbm_bytes=9.5e9)
+            cand = planner.score_config(prof, dict(raw), hbm_bytes=16e9)
             assert np.isfinite(cand.predicted_step_s) and \
                 cand.predicted_step_s > 0, raw
             assert cand.predicted_peak_bytes > 0, raw
@@ -199,7 +199,7 @@ class TestScoringAndRanking:
     def test_plan_candidate_config_surfaces(self):
         paddle.seed(0)
         model = LlamaForCausalLM(LlamaConfig.tiny())
-        cands = dist.plan(model, n_devices=8, hbm_bytes=9.5e9,
+        cands = dist.plan(model, n_devices=8, hbm_bytes=16e9,
                           batch=16, seq=64)
         top = cands[0]
         mesh = top.mesh
@@ -218,9 +218,9 @@ class TestScoringAndRanking:
 
     def test_drift_ratio_scales_the_gate(self):
         prof, _ = _tiny_profile()
-        under = planner.score_config(prof, {"dp": 8}, hbm_bytes=9.5e9,
+        under = planner.score_config(prof, {"dp": 8}, hbm_bytes=16e9,
                                      drift_ratio=0.5)
-        over = planner.score_config(prof, {"dp": 8}, hbm_bytes=9.5e9,
+        over = planner.score_config(prof, {"dp": 8}, hbm_bytes=16e9,
                                     drift_ratio=2.0)
         # a ratio < 1 means the estimator under-predicts XLA: the
         # calibrated peak must be LARGER
@@ -237,7 +237,8 @@ class TestEngineAutoPlan:
                           optimizer=o)
         x = paddle.randn([8, 16])
         y = paddle.randn([8, 16])
-        eng.prepare(sample_batch=(x, y), auto_plan=True)
+        # the CPU backend reports no bytes_limit: the caller states one
+        eng.prepare(sample_batch=(x, y), auto_plan=True, hbm_bytes=16e9)
         assert eng.applied_plan is not None
         assert eng.plan_candidates and eng.plan_candidates[0].feasible
         assert eng.applied_plan is eng.plan_candidates[0]
@@ -280,5 +281,5 @@ class TestEngineAutoPlan:
         paddle.seed(0)
         model = LlamaForCausalLM(LlamaConfig.tiny())
         cands = CostModel().plan_parallel(model, n_devices=8,
-                                          hbm_bytes=9.5e9, batch=16, seq=64)
+                                          hbm_bytes=16e9, batch=16, seq=64)
         assert cands and cands[0].feasible

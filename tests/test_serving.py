@@ -47,6 +47,17 @@ def _mk_engine(pred, **cfg):
 
 # -- batching correctness -----------------------------------------------------
 
+
+def _assert_rows_match(got, ref):
+    """A row served from a padded bucket-N batch vs the same row run alone
+    (batch 1): the two are DIFFERENT XLA programs, and jax 0.9's CPU
+    backend tiles their matmul reductions differently, so they agree to a
+    few float32 ulps (5.96e-08 observed), not bit for bit. What the test
+    pins is that no other row (or padding) leaks into a result — a leak is
+    an O(1) difference, far outside this tolerance."""
+    np.testing.assert_allclose(got, ref, rtol=1e-6, atol=1e-6)
+
+
 def test_concurrent_clients_match_unbatched_predictor(mlp_predictor):
     """8 concurrent client threads; every batched result must be
     bit-identical to an unbatched Predictor.run of the same sample."""
@@ -73,7 +84,7 @@ def test_concurrent_clients_match_unbatched_predictor(mlp_predictor):
     for c in range(n_clients):
         for j in range(per_client):
             ref = pred.run([samples[c, j][None]])[0][0]
-            np.testing.assert_array_equal(results[c][j][0], ref)
+            _assert_rows_match(results[c][j][0], ref)
     # the stats snapshot carries the acceptance metrics
     assert stats["counters"]["responses_total"] == n_clients * per_client
     assert stats["qps"] > 0
@@ -95,7 +106,7 @@ def test_batch_padding_roundtrip_rows(mlp_predictor):
         outs = [f.result(timeout=60) for f in futs]
         stats = eng.stats()
     for x, o in zip(xs, outs):
-        np.testing.assert_array_equal(o[0], pred.run([x[None]])[0][0])
+        _assert_rows_match(o[0], pred.run([x[None]])[0][0])
     # all three coalesced into ONE bucket-4 batch: occupancy 3/4
     assert stats["counters"]["batches_total"] == 1
     assert abs(stats["batch_occupancy"] - 0.75) < 1e-6
@@ -207,8 +218,8 @@ def test_bad_payload_fails_own_future_only(mlp_predictor):
                 bad.result(timeout=30)
         ref1 = pred.run([np.zeros((1, 8), np.float32)])[0][0]
         ref2 = pred.run([np.ones((1, 8), np.float32)])[0][0]
-        np.testing.assert_array_equal(good1.result(timeout=60)[0], ref1)
-        np.testing.assert_array_equal(good2.result(timeout=60)[0], ref2)
+        _assert_rows_match(good1.result(timeout=60)[0], ref1)
+        _assert_rows_match(good2.result(timeout=60)[0], ref2)
         assert eng.metrics.counter("bad_requests") == 3
 
 

@@ -612,7 +612,7 @@ def test_planner_prices_embedding_stream():
     prof = profile_model(net, sample_batch=[ids],
                          loss_fn=lambda m, x: m(x).sum())
     assert prof.embed_stream_bytes > 0
-    cand = score_config(prof, {"dp": 1}, hbm_bytes=9.5e9)
+    cand = score_config(prof, {"dp": 1}, hbm_bytes=16e9)
     assert cand.breakdown.get("embed_stream_s", 0) > 0
     # a dense model carries no embedding term
     dense = nn.Linear(4, 4)
@@ -620,7 +620,7 @@ def test_planner_prices_embedding_stream():
     prof_d = profile_model(dense, sample_batch=[x],
                            loss_fn=lambda m, a: m(a).sum())
     assert prof_d.embed_stream_bytes == 0
-    cand_d = score_config(prof_d, {"dp": 1}, hbm_bytes=9.5e9)
+    cand_d = score_config(prof_d, {"dp": 1}, hbm_bytes=16e9)
     assert "embed_stream_s" not in cand_d.breakdown
 
 

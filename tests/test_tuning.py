@@ -760,5 +760,5 @@ class TestReplanOverride:
         paddle.seed(0)
         model = LlamaForCausalLM(LlamaConfig.tiny())
         ctx = FleetWorkerContext(rank=0, world=1, gen=0, store=store)
-        got = ctx.replan(model, batch=16)
+        got = ctx.replan(model, batch=16, hbm_bytes=16e9)
         assert got["config"]["mesh"]["dp"] == 1  # freshly planned

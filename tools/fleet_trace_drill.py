@@ -35,9 +35,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))  # run from anywhere
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-_CACHE_DIR = os.environ.setdefault(
-    "PT_PERSISTENT_CACHE_DIR",
-    tempfile.mkdtemp(prefix="pt_fleettrace_cache_"))
+# compile cache ON at its one fixed place (<checkout>/.cache/jax, or
+# JAX_COMPILATION_CACHE_DIR): children and restarts warm from it
+os.environ.setdefault("PT_PERSISTENT_CACHE", "1")
 
 import numpy as np  # noqa: E402
 

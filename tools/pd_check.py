@@ -85,12 +85,12 @@ def _check_pipeline(A, cfg_kwargs):
     """ppermute-pipeline program over a pp=2 host mesh (the
     examples/distributed_data_parallel.py-family program shape): the spmd
     pass walks the real stage-handoff collectives."""
+    import jax
     import jax.numpy as jnp
 
     import paddle_tpu.distributed as dist
     from paddle_tpu.distributed.meta_parallel.pipeline import (
         ppermute_pipeline)
-    from paddle_tpu.distributed.mesh import shard_map_compat
     from jax.sharding import PartitionSpec as P
 
     dist.reset_mesh()
@@ -105,9 +105,9 @@ def _check_pipeline(A, cfg_kwargs):
         def local(x_local):
             return ppermute_pipeline(stage, x_local, 2, remat=False)
 
-        return shard_map_compat(local, mesh=env.mesh, in_specs=P(),
-                                out_specs=P(), axis_names={"pp"},
-                                check_vma=False)(x_mb)
+        return jax.shard_map(local, mesh=env.mesh, in_specs=P(),
+                             out_specs=P(), axis_names={"pp"},
+                             check_vma=False)(x_mb)
 
     x = jnp.ones((4, 2, 8), jnp.float32)  # [M, mb, d]
     prog = A.capture(piped, x, label="pipeline.ppermute")
@@ -159,8 +159,10 @@ def main(argv=None):
                     help=f"comma list from {sorted(MODEL_CHECKS)}")
     ap.add_argument("--passes", default=None,
                     help="comma list of jaxpr passes (default: all)")
-    ap.add_argument("--hbm-gb", type=float, default=9.5,
-                    help="HBM envelope for the memory/spmd passes")
+    ap.add_argument("--hbm-gb", type=float, default=16.0,
+                    help="the TARGET chip's HBM for the memory/spmd passes "
+                         "(default: a v5e's published 16 GB; a live chip "
+                         "reports its own as memory_stats()['bytes_limit'])")
     ap.add_argument("--frac", type=float, default=0.5,
                     help="fat-intermediate threshold as a fraction of HBM")
     ap.add_argument("--no-retrace-demo", action="store_true")

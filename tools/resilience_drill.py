@@ -287,6 +287,7 @@ def _run_fleet_child(out_dir: str) -> None:
         return {"network": net, "optimizer": opt, "loss": ce,
                 "dataset": ds, "sample_batch": (xb, yb),
                 "loss_fn": lambda m, x, y: ce(m(x), y),
+                "hbm_bytes": 16e9,  # CPU fleet: the backend reports none
                 "on_exit": _write}
 
     res = elastic_fit(build, global_batch=FLEET_GLOBAL_BATCH, epochs=1,

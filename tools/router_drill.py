@@ -20,14 +20,17 @@ import json
 import os
 import shutil
 import sys
-import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))  # run from anywhere
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 os.environ["PT_RETRACE_AUDIT"] = "1"
-_CACHE_DIR = tempfile.mkdtemp(prefix="pt_routerdrill_cache_")
+# a fixed path (the path is part of a cache key), emptied at start so that
+# replica A really compiles: the drill measures cold-then-warm
+_CACHE_DIR = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), ".cache", "router_drill")
+shutil.rmtree(_CACHE_DIR, ignore_errors=True)
 os.environ["PT_PERSISTENT_CACHE_DIR"] = _CACHE_DIR  # read at import
 
 import numpy as np  # noqa: E402

@@ -55,9 +55,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))  # run from anywhere
 
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
-_CACHE_DIR = os.environ.setdefault(
-    "PT_PERSISTENT_CACHE_DIR",
-    tempfile.mkdtemp(prefix="pt_tuning_cache_"))
+# compile cache ON at its one fixed place (<checkout>/.cache/jax, or
+# JAX_COMPILATION_CACHE_DIR): children and restarts warm from it
+os.environ.setdefault("PT_PERSISTENT_CACHE", "1")
 
 # -- serving leg constants ----------------------------------------------------
 DECLARED_PREFILL = (32, 40)      # sized for long prompts; traffic is short
@@ -387,7 +387,7 @@ def _run_elastic_child(out_dir: str) -> None:
             base = replan_for_world(net, ctx.world,
                                     batch=ELASTIC_GLOBAL_BATCH,
                                     sample_batch=(xb, yb),
-                                    loss_fn=loss_fn)
+                                    loss_fn=loss_fn, hbm_bytes=64e9)
             initial = planner.plan_digest(base.config)
             tuner = ElasticPlanTuner(
                 ctx, prof, pure, margin=0.2, measure_steps=5,
@@ -399,7 +399,8 @@ def _run_elastic_child(out_dir: str) -> None:
             cbs.append(TunerStepCallback(tuner, initial, ctx.gen))
         return {"network": net, "optimizer": opt, "loss": ce,
                 "dataset": ds, "sample_batch": (xb, yb),
-                "loss_fn": loss_fn, "callbacks": cbs, "on_exit": _write}
+                "loss_fn": loss_fn, "hbm_bytes": 64e9,
+                "callbacks": cbs, "on_exit": _write}
 
     res = elastic_fit(build, global_batch=ELASTIC_GLOBAL_BATCH, epochs=1,
                       checkpoint_every=ELASTIC_CKPT_EVERY)
