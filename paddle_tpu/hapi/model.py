@@ -45,6 +45,12 @@ def _timeline():
     return timeline()
 
 
+def _span(name):
+    from ..observability.trace import span
+
+    return span(name)
+
+
 def _resilience():
     from ..distributed import resilience
 
@@ -534,7 +540,9 @@ class Model:
             # when the DevicePrefetcher keeps the queue fed)
             with (tl.step() if tl is not None else nullcontext()) as st:
                 t_wait = _time.perf_counter()
-                batch = next(it, _END)
+                with (_span("pt.train.data_wait") if tl is not None
+                      else nullcontext()):
+                    batch = next(it, _END)
                 t_got = _time.perf_counter()
                 if batch is _END:
                     if st is not None:

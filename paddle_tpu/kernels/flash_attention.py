@@ -123,6 +123,7 @@ def _flash_fwd(q, k, v, offset, causal, scale, block_q, block_k):
     out, lse3 = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
+        name="pt_flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -267,6 +268,7 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, offset, causal, scale,
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
+        name="pt_flash_bwd_dkv",
         grid=(bh, sk // block_k, sq // block_q),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),
@@ -295,6 +297,7 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, offset, causal, scale,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, scale=scale, causal=causal,
                           block_q=block_q, block_k=block_k),
+        name="pt_flash_bwd_dq",
         grid=(bh, sq // block_q, sk // block_k),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -135,6 +135,7 @@ def _routing_pallas(xt, wg, top_k, interpret):
     grid = (n // bn,)
     gv, gi, pos, cnt, me, ce = pl.pallas_call(
         functools.partial(_routing_kernel, top_k=top_k, e=e),
+        name="pt_moe_route",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bn, h), lambda i: (i, 0)),
@@ -273,7 +274,7 @@ def _gather_rows(src, idx, impl):
         out_specs=pl.BlockSpec((1, 1, h), lambda i, idx_ref: (i, 0, 0)),
     )
     return pl.pallas_call(
-        _gather_kernel, grid_spec=grid_spec,
+        _gather_kernel, name="pt_moe_dispatch", grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, 1, h), src.dtype),
         interpret=(impl == "interpret"),
     )(idx, src[:, None, :]).reshape(n, h)
@@ -318,7 +319,7 @@ def _combine_rows(y, gates, dest2, impl, out_dtype=None):
     # pads its last dim to 128 lanes (2 MiB at n=4096 — over the chip's
     # 1 MiB of SMEM); 1-D costs 4 bytes per entry
     return pl.pallas_call(
-        _make_combine_kernel(k), grid_spec=grid_spec,
+        _make_combine_kernel(k), name="pt_moe_combine", grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, 1, h), out_dtype),
         interpret=(impl == "interpret"),
     )(dest2.reshape(n * k), gates[:, None, :], *([y3] * k)).reshape(n, h)

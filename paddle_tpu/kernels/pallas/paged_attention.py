@@ -97,6 +97,7 @@ def _paged_pallas(q, k_arena, v_arena, tables, pos, scale, interpret):
     out = pl.pallas_call(
         functools.partial(_paged_kernel, nh=nh, kvh=kvh, PL=PL,
                           scale=scale),
+        name="pt_paged_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(S, B),

@@ -73,6 +73,7 @@ def _fwd_pallas(x2, r2, w, eps, residual, interpret):
     if residual:
         y, s, rstd = pl.pallas_call(
             functools.partial(_fwd_kernel, eps=eps),
+            name="pt_rmsnorm_fwd_residual",
             grid=grid,
             in_specs=[row, row, wspec],
             out_specs=[row, row, rstd_spec],
@@ -86,6 +87,7 @@ def _fwd_pallas(x2, r2, w, eps, residual, interpret):
         return y, s, rstd
     y, rstd = pl.pallas_call(
         functools.partial(_fwd_kernel_plain, eps=eps),
+        name="pt_rmsnorm_fwd",
         grid=grid,
         in_specs=[row, wspec],
         out_specs=[row, rstd_spec],
@@ -176,14 +178,14 @@ def _bwd_pallas(s, w, rstd, dy, dr, residual, interpret):
     scratch = [pltpu.VMEM((1, h), jnp.float32)]
     if residual:
         dx, dw = pl.pallas_call(
-            _bwd_kernel, grid=grid,
+            _bwd_kernel, name="pt_rmsnorm_bwd_residual", grid=grid,
             in_specs=[row, wspec, rstd_spec, row, row],
             out_specs=out_specs, out_shape=out_shape,
             scratch_shapes=scratch, interpret=interpret,
         )(s, w2, rstd, dy, dr)
     else:
         dx, dw = pl.pallas_call(
-            _bwd_kernel_plain, grid=grid,
+            _bwd_kernel_plain, name="pt_rmsnorm_bwd", grid=grid,
             in_specs=[row, wspec, rstd_spec, row],
             out_specs=out_specs, out_shape=out_shape,
             scratch_shapes=scratch, interpret=interpret,

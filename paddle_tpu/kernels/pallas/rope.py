@@ -81,6 +81,7 @@ def _rope_pallas(x, theta, pos_offset, inverse, interpret):
     return pl.pallas_call(
         functools.partial(_rope_kernel, theta=theta, pos_offset=pos_offset,
                           block_s=bs, d=d, inverse=inverse),
+        name="pt_rope",
         grid=(b, s // bs),
         in_specs=[pl.BlockSpec((1, bs, h, d), lambda i, j: (i, j, 0, 0))],
         out_specs=pl.BlockSpec((1, bs, h, d), lambda i, j: (i, j, 0, 0)),

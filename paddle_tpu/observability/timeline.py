@@ -19,21 +19,22 @@ per-step phase split:
 - ``stream_wait``    offload-path steps only: blocked on the streaming
                      lane (a group transfer not yet hidden behind compute)
 
-Device truth: while an ``observability.trace.capture_steps()`` window is
-open, every step/phase bracket also emits a ``jax.profiler``
-TraceAnnotation (``pt_step#<n>`` / ``pt_phase#<name>``) into the XPlane
-capture; the post-capture correlation ingests per-step *device* time back
-here (``ingest_device_steps``), so ``summary()`` reports
-``device_compute_us`` measured by XLA's own tracer — in every mode, not
-just detailed — with ``device_source`` naming where the number came from
-(``"xplane"`` vs the ``device_block`` host proxy).
+Device truth: every step and phase bracket is an
+``observability.trace.span`` — ``pt.train.step`` (``step_num`` as its
+argument) and ``pt.train.<phase>`` — so it is in whatever profiler trace is
+running (``capture_steps()``, TensorBoard, xprof, the benchmark's own) on
+the device's clock, and in the tracer's worker ring. ``capture_steps()``'s
+correlation ingests per-step *device* time back here
+(``ingest_device_steps``), so ``summary()`` reports ``device_compute_us``
+measured by XLA's own tracer — in every mode, not just detailed — with
+``device_source`` naming where the number came from (``"xplane"`` vs the
+``device_block`` host proxy).
 
 Producers: ``jit.TrainStep`` / ``AccumulateStep`` / ``ShardedTrainStep`` /
 ``ShardedAccumulateStep`` wrap their calls, ``hapi.Model.fit`` wraps its
 epoch loop. Each phase is aggregated (count/total/max/last — a few adds
-per step) and, while a ``profiler.Profiler`` is recording, emitted as a
-``RecordEvent`` span named ``step:<phase>`` so the chrome-trace export
-shows the warm path next to user/op spans. Completed steps additionally
+per step); while a ``profiler.Profiler`` is recording the spans are in its
+chrome-trace export too, next to user/op spans. Completed steps additionally
 feed any registered observers (the flight recorder's ring) and the
 ``step_time_ms`` histogram.
 """
@@ -42,6 +43,8 @@ from __future__ import annotations
 import threading
 import time
 from typing import Callable, Dict, List, Optional, Tuple
+
+from .trace.request_trace import span
 
 __all__ = ["StepTimeline", "timeline"]
 
@@ -73,13 +76,8 @@ class _PhaseCtx:
         self._span = None
 
     def __enter__(self):
-        annot = self._tl._annot
-        if annot is not None:
-            try:
-                self._span = annot(f"pt_phase#{self._name}")
-                self._span.__enter__()
-            except Exception:
-                self._span = None
+        self._span = span("pt.train." + self._name)
+        self._span.__enter__()
         self._t0 = time.perf_counter()
         return self
 
@@ -87,12 +85,7 @@ class _PhaseCtx:
         self._tl.record(self._name,
                         (time.perf_counter() - self._t0) * 1e3,
                         t0=self._t0)
-        if self._span is not None:
-            try:
-                self._span.__exit__(None, None, None)
-            except Exception:
-                pass
-            self._span = None
+        self._span.__exit__(None, None, None)
         return False
 
 
@@ -128,7 +121,7 @@ class StepTimeline:
         self._lock = threading.Lock()
         self._phases: Dict[str, _PhaseAgg] = {}
         self._steps = 0
-        self._begun = 0  # step brackets opened (capture-annotation index)
+        self._begun = 0  # step brackets opened (pt.train.step's step_num)
         self._step_total = _PhaseAgg()
         self._detail = False
         # XPlane-correlated device time per step (ingest_device_steps);
@@ -138,10 +131,6 @@ class StepTimeline:
         # last completed step's phase spans, (name, rel_ms, dur_ms) in
         # record order — the "ordered" assertion surface for tests/pd_top
         self._last_step: List[Tuple[str, float, float]] = []
-        # while an observability.trace capture window is open, step/phase
-        # brackets also emit jax.profiler TraceAnnotations; one attribute
-        # read per bracket when disarmed
-        self._annot: Optional[Callable] = None
         # completed-step observers (the flight recorder): fn(ms, phases)
         self._observers: List[Callable] = []
         # step_time_ms histogram, resolved lazily once (not per step —
@@ -169,15 +158,6 @@ class StepTimeline:
             return profiler.is_recording()
         except Exception:
             return False
-
-    def _arm_annotations(self, factory: Callable) -> None:
-        """Capture window open: ``factory(name)`` returns a context manager
-        (``jax.profiler.TraceAnnotation``) emitted around every step and
-        phase bracket so the XPlane artifact carries correlation anchors."""
-        self._annot = factory
-
-    def _disarm_annotations(self) -> None:
-        self._annot = None
 
     def add_observer(self, fn: Callable) -> None:
         """``fn(wall_ms, phases)`` after every completed (non-cancelled)
@@ -210,7 +190,6 @@ class StepTimeline:
             agg.add(ms)
             if cur is not None and t0 is not None:
                 cur.append((name, (t0 - self._tls.t0) * 1e3, ms))
-        self._maybe_span(name, ms, t0)
 
     def ingest_device_steps(self, per_step_us, source: str = "xplane") -> None:
         """Land XPlane-correlated per-step device-compute times (us). The
@@ -223,18 +202,6 @@ class StepTimeline:
             if per_step_us:
                 self._device_source = source
 
-    def _maybe_span(self, name: str, ms: float, t0: Optional[float]) -> None:
-        """Emit a host-tracer span while a Profiler is recording, so the
-        chrome trace shows step phases next to op and user spans."""
-        try:
-            from .. import profiler
-
-            if t0 is not None and profiler.is_recording():
-                profiler._RECORDER.record(f"step:{name}", t0 * 1e6,
-                                          ms * 1e3, "StepTimeline")
-        except Exception:
-            pass
-
     def _begin_step(self) -> float:
         t0 = time.perf_counter()
         ts = self._tls
@@ -243,21 +210,11 @@ class StepTimeline:
         if depth == 0:  # the outermost bracket owns the step
             ts.cur = []
             ts.t0 = t0
-            annot = self._annot
-            if annot is not None:
-                with self._lock:
-                    n = self._begun
-                    self._begun += 1
-                try:
-                    span = annot(f"pt_step#{n}")
-                    span.__enter__()
-                    ts.span = span
-                except Exception:
-                    ts.span = None
-            else:
-                with self._lock:
-                    self._begun += 1
-                ts.span = None
+            with self._lock:
+                n = self._begun
+                self._begun += 1
+            ts.span = span("pt.train.step", step_num=n)
+            ts.span.__enter__()
         return t0
 
     def _end_step(self, t0: float, cancelled: bool = False) -> None:
@@ -267,12 +224,9 @@ class StepTimeline:
         if ts.depth > 0:
             return
         cur, ts.cur = getattr(ts, "cur", None), None
-        span, ts.span = getattr(ts, "span", None), None
-        if span is not None:
-            try:
-                span.__exit__(None, None, None)
-            except Exception:
-                pass
+        step_span, ts.span = getattr(ts, "span", None), None
+        if step_span is not None:
+            step_span.__exit__(None, None, None)
         if cancelled:
             return
         with self._lock:
@@ -281,7 +235,6 @@ class StepTimeline:
             if cur is not None:
                 self._last_step = cur
             observers = list(self._observers)
-        self._maybe_span("total", ms, t0)
         try:
             h = self._step_hist
             if h is None:

@@ -4,10 +4,13 @@ Three layers on top of the PR-4 telemetry hub (see docs/observability.md,
 "Device-truth tracing"):
 
 - **XPlane ingestion** (``capture_steps`` / ``xplane``): capture a
-  ``jax.profiler`` trace around a step window, parse the artifact,
+  ``jax.profiler`` trace around a step window, read its ``.xplane.pb``,
   correlate device events back to StepTimeline steps/phases — real
   ``device_compute_us`` (every mode), a top-k device op table, and
   host/device overlap efficiency;
+- **spans** (``span``): the program's one span primitive — a
+  ``jax.profiler.TraceAnnotation`` in whatever profiler trace is running,
+  and a row in the tracer's worker ring (``pt.serve.*``, ``pt.train.*``);
 - **request-scoped tracing** (``tracer()``): a propagated trace ID per
   serving request (admission -> queue -> coalesce -> execute / prefill ->
   decode -> completion) plus the GenerationEngine slot-occupancy track,
@@ -23,16 +26,15 @@ from .capture import (  # noqa: F401
     StepTraceCapture, capture_steps, device_trace_provider, last_correlation,
 )
 from .flight import FlightRecorder, dump_bundle, flight_recorder  # noqa: F401
-from .request_trace import RequestTracer, tracer  # noqa: F401
+from .request_trace import RequestTracer, span, tracer  # noqa: F401
 from .xplane import (  # noqa: F401
-    CorrelatedTrace, correlate, correlate_logdir, find_trace_artifacts,
-    load_trace_file,
+    CorrelatedTrace, correlate, correlate_logdir, find_xplane, read_xplane,
 )
 
 __all__ = [
     "StepTraceCapture", "capture_steps", "last_correlation",
     "device_trace_provider", "CorrelatedTrace", "correlate",
-    "correlate_logdir", "find_trace_artifacts", "load_trace_file",
+    "correlate_logdir", "find_xplane", "read_xplane", "span",
     "RequestTracer", "tracer", "FlightRecorder", "flight_recorder",
     "dump_bundle",
 ]

@@ -11,9 +11,9 @@ steps and correlate the XPlane artifact back into the StepTimeline.
     cor = cap.result              # CorrelatedTrace
     cor.summary()["op_table"]     # top-k device-attributed ops
 
-While the window is open, ``StepTimeline`` brackets emit
-``pt_step#<n>``/``pt_phase#<name>`` TraceAnnotations into the capture; on
-exit the artifact is parsed (``xplane.correlate_logdir``), per-step device
+``StepTimeline``'s brackets are always ``trace.span``s (``pt.train.step``,
+``pt.train.<phase>``), so they are in the capture; on exit the
+``.xplane.pb`` is read (``xplane.correlate_logdir``), per-step device
 time is ingested into ``timeline()`` (``device_compute_us`` with
 ``device_source="xplane"`` — every mode, not just detailed), and the
 correlation digest is published to the hub's ``device_trace`` provider
@@ -31,7 +31,6 @@ import tempfile
 import threading
 from typing import Any, Dict, Optional
 
-from ..timeline import timeline
 from . import xplane
 
 __all__ = ["StepTraceCapture", "capture_steps", "last_correlation",
@@ -84,16 +83,10 @@ class StepTraceCapture:
             self._tracing = True
         except Exception as e:  # an already-running trace (PR-4 Profiler)
             self.error = f"start_trace failed: {e}"
-            return self
-        timeline()._arm_annotations(jax.profiler.TraceAnnotation)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         if self._tracing:
-            # only the capture that ARMED the annotations disarms them: a
-            # failed-to-start window (trace already running) must not strip
-            # the anchors out from under the active one
-            timeline()._disarm_annotations()
             import jax
 
             try:
@@ -117,6 +110,8 @@ class StepTraceCapture:
         self.result = cor
         dev = [us for us in cor.device_us_per_step() if us > 0]
         if dev:
+            from ..timeline import timeline  # it imports this package
+
             timeline().ingest_device_steps(dev, source="xplane")
         with _LOCK:
             _LAST = cor

@@ -60,7 +60,6 @@ from collections import deque
 from typing import Any, Dict, List, Optional, Tuple
 
 from ..registry import family
-from ..timeline import timeline
 
 __all__ = ["FlightRecorder", "flight_recorder", "dump_bundle"]
 
@@ -202,7 +201,11 @@ class FlightRecorder:
         self.auto_dump = bool(auto_dump)
         self.min_dump_interval_s = float(min_dump_interval_s)
         self.max_dumps = int(max_dumps)
-        self._tl = timeline_obj if timeline_obj is not None else timeline()
+        if timeline_obj is None:
+            from ..timeline import timeline  # it imports this package
+
+            timeline_obj = timeline()
+        self._tl = timeline_obj
         self._lock = threading.Lock()
         self._ring: deque = deque(maxlen=self.capacity)
         self._total_steps = 0  # monotone; never wraps with the ring

@@ -14,9 +14,20 @@ Perfetto JSON next to the profiler's host spans:
   GenerationEngine occupancy timeline (each residency span carries the
   owning trace ID and token count).
 
+- one ``worker:<engine>`` row per thread that ran ``span()``s: the serving
+  worker's rounds, waits and page work, the train loop's steps and phases.
+
 Cost per request: a few dict appends under one lock. The ring bounds
 memory (finished traces beyond ``capacity`` drop oldest-first and are
 counted), so the tracer is always-on — no sampling knob to forget.
+
+``span(name, **args)`` is the program's one span primitive: it enters a
+``jax.profiler.TraceAnnotation`` unconditionally — so the span lands in
+whatever profiler trace is running (TensorBoard, xprof, the benchmark's
+own), on the device's clock, and costs a few microseconds when none is —
+and on exit appends itself, with the span that enclosed it on its thread,
+to the tracer's bounded worker ring. Names are stable and dotted
+(``pt.<tier>.<what>``); what varies is an argument, never part of the name.
 """
 from __future__ import annotations
 
@@ -28,7 +39,9 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
-__all__ = ["RequestTracer", "tracer"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["RequestTracer", "tracer", "span"]
 
 
 def _us(t_monotonic: float) -> float:
@@ -54,14 +67,17 @@ class _Trace:
 class RequestTracer:
     """Process-wide request-span collector (one instance via ``tracer()``)."""
 
-    def __init__(self, capacity: int = 2048, slot_capacity: int = 1024):
+    def __init__(self, capacity: int = 2048, slot_capacity: int = 1024,
+                 worker_capacity: int = 16384):
         self._lock = threading.Lock()
         self._seq = itertools.count(1)
         self._live: Dict[str, _Trace] = {}
         self._done: deque = deque(maxlen=capacity)
         self._slots: deque = deque(maxlen=slot_capacity)
+        self._worker: deque = deque(maxlen=worker_capacity)
         self._counts = {"started": 0, "finished": 0, "failed": 0,
-                        "spans": 0, "slot_spans": 0}
+                        "spans": 0, "slot_spans": 0, "worker_spans": 0,
+                        "worker_dropped": 0}
 
     # -- recording ------------------------------------------------------------
     def start(self, engine: str, kind: str = "request",
@@ -127,11 +143,41 @@ class RequestTracer:
                                 "trace_id": trace_id, "args": args})
             self._counts["slot_spans"] += 1
 
+    def worker_span(self, span_id: int, name: str, t0: float, t1: float,
+                    thread: str, parent: Optional[int], args: Dict) -> None:
+        """One closed ``span()`` of a worker or stepping thread. ``parent``
+        is the id of the span that enclosed it on that thread, so a span's
+        self time is its duration less its children's."""
+        row = {"id": span_id, "name": name, "t0": t0,
+               "dur_us": max(_us(t1 - t0), 0.0), "thread": thread,
+               "parent": parent, "args": args}
+        with self._lock:
+            if len(self._worker) == self._worker.maxlen:
+                self._counts["worker_dropped"] += 1
+            self._worker.append(row)
+            self._counts["worker_spans"] += 1
+
     # -- reads ----------------------------------------------------------------
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:
             return {**self._counts, "live": len(self._live),
-                    "ring": len(self._done), "slot_ring": len(self._slots)}
+                    "ring": len(self._done), "slot_ring": len(self._slots),
+                    "worker_ring": len(self._worker)}
+
+    def worker_spans(self, thread: Optional[str] = None) -> List[Dict]:
+        """The worker ring (oldest first, children before their parents:
+        a span lands when it closes), each row with its ``self_us``."""
+        with self._lock:
+            rows = [dict(r) for r in self._worker
+                    if thread is None or r["thread"] == thread]
+        children: Dict[int, float] = {}
+        for r in rows:
+            if r["parent"] is not None:
+                children[r["parent"]] = children.get(r["parent"], 0.0) \
+                    + r["dur_us"]
+        for r in rows:
+            r["self_us"] = max(r["dur_us"] - children.get(r["id"], 0.0), 0.0)
+        return rows
 
     @staticmethod
     def _export(tr: "_Trace", slots: Optional[List[Dict]] = None) -> Dict:
@@ -191,6 +237,7 @@ class RequestTracer:
         with self._lock:
             done = list(self._done)
             slots = list(self._slots)
+            worker = list(self._worker)
         events: List[Dict] = []
         pids: Dict[str, int] = {}
 
@@ -225,6 +272,13 @@ class RequestTracer:
                 "ts": _us(s["t0"]), "dur": s["dur_us"], "cat": "slot",
                 "args": {"trace_id": s["trace_id"], **s["args"]},
             })
+        for s in worker:  # nested spans of one thread stack in one row
+            pid = pid_of("worker:" + s["thread"].removeprefix("pt-serving-"))
+            events.append({
+                "ph": "X", "pid": pid, "tid": 1, "name": s["name"],
+                "ts": _us(s["t0"]), "dur": s["dur_us"], "cat": "worker",
+                "args": s["args"],
+            })
         return events
 
     def export_chrome(self, path: str) -> str:
@@ -243,6 +297,7 @@ class RequestTracer:
             self._live.clear()
             self._done.clear()
             self._slots.clear()
+            self._worker.clear()
             for k in self._counts:
                 self._counts[k] = 0
 
@@ -253,3 +308,50 @@ _TRACER = RequestTracer()
 def tracer() -> RequestTracer:
     """The process-wide request tracer every serving engine feeds."""
     return _TRACER
+
+
+_SPAN_SEQ = itertools.count(1)
+_TLS = threading.local()
+_PROFILER = None  # paddle_tpu.profiler, resolved at the first span
+
+
+class span:
+    """``with span("pt.serve.decode_round", n_active=3): ...`` — see the
+    module docstring. While a ``paddle_tpu.profiler.Profiler`` records,
+    the span is also in its host-event buffer (the chrome export)."""
+
+    __slots__ = ("name", "args", "_annot", "_id", "_t0")
+
+    def __init__(self, name: str, **args):
+        self.name = name
+        self.args = args
+        self._annot = TraceAnnotation(name, **args)
+
+    def __enter__(self) -> "span":
+        try:
+            stack = _TLS.stack
+        except AttributeError:
+            stack = _TLS.stack = []
+            _TLS.thread = threading.current_thread().name
+        self._id = next(_SPAN_SEQ)
+        stack.append(self._id)
+        self._annot.__enter__()
+        self._t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        global _PROFILER
+        t1 = time.monotonic()
+        self._annot.__exit__(None, None, None)
+        stack = _TLS.stack
+        stack.pop()
+        _TRACER.worker_span(self._id, self.name, self._t0, t1, _TLS.thread,
+                            stack[-1] if stack else None, self.args)
+        if _PROFILER is None:
+            from ... import profiler as _PROFILER
+        if _PROFILER._RECORDER.active:  # its clock is perf_counter
+            dur_us = (t1 - self._t0) * 1e6
+            _PROFILER._RECORDER.record(
+                self.name, time.perf_counter() * 1e6 - dur_us, dur_us,
+                "Span")
+        return False

@@ -1,65 +1,88 @@
-"""XPlane/trace-artifact ingestion: device truth for the step timeline.
+"""XPlane ingestion: device truth for the step timeline.
 
-``jax.profiler.start_trace`` writes an XPlane protobuf AND a pre-rendered
-chrome-trace next to it (``plugins/profile/<ts>/*.trace.json.gz``) — the
-same merged host+device view the reference's chrometracing_logger.cc
-produces. The protobuf needs the tensorflow profiler proto stack (not a
-dependency here); the chrome JSON carries everything this layer needs:
+``jax.profiler.start_trace`` writes ``plugins/profile/<ts>/*.xplane.pb``
+under its log directory; ``jax.profiler.ProfileData`` reads it with nothing
+but JAX: planes, their lines, and events with a start, a duration and their
+stats. What this layer needs from it:
 
-- host threads with our ``pt_step#<n>`` / ``pt_phase#<name>``
-  TraceAnnotation spans (emitted by ``StepTimeline`` while a capture
-  window is armed — the correlation anchors);
-- device execution events: XLA op spans carrying ``args.hlo_op`` /
-  ``args.hlo_module`` (CPU backend: on the ``tf_XLAEigen`` executor
-  threads; TPU backend: on ``/device:TPU:*`` process lines).
+- the program's own spans on the host plane: ``StepTimeline`` brackets every
+  step and phase with ``trace.span`` — ``pt.train.step`` (its ``step_num``
+  stat is the step's index) and ``pt.train.<phase>`` — the correlation
+  anchors, in every trace, whoever started the profiler;
+- device execution events. TPU: the ``XLA Ops`` line of each
+  ``/device:TPU:<n>`` plane, one event per executed HLO instruction, NESTED
+  where an instruction contains others (a ``while`` spans its body), named
+  by the instruction's whole HLO text (``%fusion.3 = bf16[...] fusion(...)``;
+  the op's name is what stands before `` = ``). CPU backend: events that
+  carry an ``hlo_op`` stat, on the ``tf_XLA*`` executor threads of the host
+  plane.
 
-``correlate`` assigns device events to step windows by time containment
-(host and device share the trace clock), unions overlapping intervals per
-thread so nested/fused spans never double-count, and splits each step's
-device time into *exposed* (overlapping a ``device_block``/``stream_wait``
-host span — the host was waiting for it) vs *hidden* (overlapped by
-useful host work) — the device-truth ``overlap_efficiency``.
+``correlate`` assigns device events to step windows by time (host and device
+share the trace clock), unions overlapping intervals per line so nested
+spans never double-count, gives the op table each op's SELF time (its
+interval less what its children cover), and splits each step's device time
+into *exposed* (overlapping a ``device_block``/``stream_wait``/``data_wait``
+host span — the host was waiting for it) vs *hidden* (overlapped by useful
+host work) — the device-truth ``overlap_efficiency``.
 """
 from __future__ import annotations
 
 import bisect
 import glob
-import gzip
-import json
 import os
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+import re
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
-__all__ = ["find_trace_artifacts", "load_trace_file", "correlate",
-           "correlate_logdir", "CorrelatedTrace"]
+__all__ = ["find_xplane", "read_xplane", "correlate", "correlate_logdir",
+           "CorrelatedTrace", "TraceEvent"]
 
-STEP_PREFIX = "pt_step#"
-PHASE_PREFIX = "pt_phase#"
+STEP_SPAN = "pt.train.step"
+PHASE_PREFIX = "pt.train."
 # blocking host phases: device time under these was NOT hidden behind
 # useful host work (stall, not overlap)
 _BLOCKING_PHASES = ("device_block", "stream_wait", "data_wait")
-# whole-program group spans (bench heuristic): these CONTAIN the op spans
-# and must not be summed next to them
-_MODULE_MARKERS = ("jit_",)
+_DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):\d+")
+_OPS_LINE = "XLA Ops"
 
 
-def find_trace_artifacts(logdir: str) -> List[str]:
-    """The ``*.trace.json.gz`` files under a capture logdir, newest
-    first (one per host per capture)."""
-    pats = [os.path.join(logdir, "plugins", "profile", "*", "*.trace.json.gz"),
-            os.path.join(logdir, "*.trace.json.gz")]
-    files: List[str] = []
-    for p in pats:
-        files.extend(glob.glob(p))
-    return sorted(set(files), key=lambda f: os.path.getmtime(f), reverse=True)
+class TraceEvent(NamedTuple):
+    plane: str
+    line: str
+    name: str
+    ts: float            # microseconds on the trace clock
+    dur: float           # microseconds
+    stats: Dict[str, Any]
 
 
-def load_trace_file(path: str) -> Dict[str, Any]:
-    """Parse one chrome-trace artifact (.json or .json.gz)."""
-    if path.endswith(".gz"):
-        with gzip.open(path, "rt") as f:
-            return json.load(f)
-    with open(path) as f:
-        return json.load(f)
+def find_xplane(logdir: str) -> Optional[str]:
+    """The newest ``*.xplane.pb`` under a capture logdir."""
+    hits = glob.glob(os.path.join(logdir, "plugins", "profile", "*",
+                                  "*.xplane.pb"))
+    return max(hits, key=os.path.getmtime) if hits else None
+
+
+def read_xplane(path: str) -> List[TraceEvent]:
+    """The events this layer reads, out of one ``.xplane.pb``: the
+    ``pt.train.*`` spans, the ops of the device planes, and host-plane
+    events with an ``hlo_op`` stat (the CPU backend's device events)."""
+    from jax.profiler import ProfileData
+
+    out: List[TraceEvent] = []
+    for plane in ProfileData.from_file(path).planes:
+        device = bool(_DEVICE_PLANE.match(plane.name))
+        for line in plane.lines:
+            if device and line.name != _OPS_LINE:
+                continue
+            for e in line.events:
+                name = e.name
+                stats = {} if device else dict(e.stats)
+                if not (device or name.startswith(PHASE_PREFIX)
+                        or "hlo_op" in stats):
+                    continue
+                out.append(TraceEvent(plane.name, line.name, name,
+                                      e.start_ns / 1e3, e.duration_ns / 1e3,
+                                      stats))
+    return out
 
 
 def _overlap_us(intervals: List[Tuple[float, float]],
@@ -73,6 +96,23 @@ def _overlap_us(intervals: List[Tuple[float, float]],
             if hi > lo:
                 total += hi - lo
     return total
+
+
+def _self_us(events: Sequence[TraceEvent]) -> List[float]:
+    """Each event's duration less what its children cover (events of one
+    line nest properly or are disjoint); same order as ``events``."""
+    order = sorted(range(len(events)),
+                   key=lambda i: (events[i].ts, -events[i].dur))
+    own = [e.dur for e in events]
+    stack: List[int] = []
+    for i in order:
+        ev = events[i]
+        while stack and events[stack[-1]].ts + events[stack[-1]].dur <= ev.ts:
+            stack.pop()
+        if stack:
+            own[stack[-1]] -= ev.dur
+        stack.append(i)
+    return [max(v, 0.0) for v in own]
 
 
 class CorrelatedTrace:
@@ -127,67 +167,36 @@ class CorrelatedTrace:
         }
 
 
-def _is_device_event(ev: Dict, dev_pids: frozenset) -> bool:
-    args = ev.get("args")
-    if isinstance(args, dict) and "hlo_op" in args:
-        return True
-    if ev.get("pid") in dev_pids:
-        name = ev.get("name", "")
-        # skip whole-module group spans: they contain the op spans
-        if any(m in name for m in _MODULE_MARKERS) or name.isdigit():
-            return False
-        return True
-    return False
-
-
-def correlate(trace: Dict[str, Any],
+def correlate(events: Sequence[TraceEvent],
               source: Optional[str] = None) -> CorrelatedTrace:
-    """Correlate one chrome-trace dict: device events -> ``pt_step#`` /
-    ``pt_phase#`` windows by time containment."""
-    events = [e for e in trace.get("traceEvents", []) if e.get("ph") == "X"]
-    # process/thread name maps (metadata events)
-    pid_names: Dict[Any, str] = {}
-    tid_names: Dict[Tuple, str] = {}
-    for e in trace.get("traceEvents", []):
-        if e.get("ph") != "M":
-            continue
-        if e.get("name") == "process_name":
-            pid_names[e.get("pid")] = e.get("args", {}).get("name", "")
-        elif e.get("name") == "thread_name":
-            tid_names[(e.get("pid"), e.get("tid"))] = \
-                e.get("args", {}).get("name", "")
-    # device process lines (TPU/GPU captures put device timelines in their
-    # own pid; CPU captures only have hlo_op events on executor threads)
-    dev_pids = frozenset(p for p, n in pid_names.items()
-                         if "/device:" in n and "CPU" not in n)
-
+    """Correlate one trace's events (``read_xplane``): device events ->
+    ``pt.train.step`` / ``pt.train.<phase>`` windows by time."""
     steps: List[Dict] = []
     phase_spans: List[Tuple[str, float, float]] = []  # (name, t0, t1)
-    device_evs: List[Dict] = []
+    by_line: Dict[Tuple[str, str], List[TraceEvent]] = {}
     for e in events:
-        name = e.get("name", "")
-        ts, dur = float(e.get("ts", 0.0)), float(e.get("dur", 0.0))
-        if name.startswith(STEP_PREFIX):
-            try:
-                idx = int(name[len(STEP_PREFIX):])
-            except ValueError:
-                continue
-            steps.append({"step": idx, "window": (ts, ts + dur),
-                          "wall_us": dur})
-        elif name.startswith(PHASE_PREFIX):
-            phase_spans.append((name[len(PHASE_PREFIX):], ts, ts + dur))
-        elif dur > 0.01 and _is_device_event(e, dev_pids):
-            device_evs.append(e)
+        if e.name == STEP_SPAN:
+            steps.append({"step": e.stats.get("step_num"),
+                          "window": (e.ts, e.ts + e.dur), "wall_us": e.dur})
+        elif e.name.startswith(PHASE_PREFIX):
+            phase_spans.append((e.name[len(PHASE_PREFIX):], e.ts,
+                                e.ts + e.dur))
+        elif e.dur > 0.01:
+            by_line.setdefault((e.plane, e.line), []).append(e)
     steps.sort(key=lambda s: s["window"][0])
+    for i, s in enumerate(steps):  # a trace without the stat: by order
+        if s["step"] is None:
+            s["step"] = i
 
-    # op table: aggregate device events by op name (leaf hlo spans)
+    # op table: self time by op (a ``while`` owns only its own overhead)
     agg: Dict[Tuple[str, str], List[float]] = {}
-    for e in device_evs:
-        args = e.get("args") or {}
-        key = (e.get("name", "?"), str(args.get("hlo_module", "")))
-        row = agg.setdefault(key, [0, 0.0])
-        row[0] += 1
-        row[1] += float(e.get("dur", 0.0))
+    for evs in by_line.values():
+        for e, own in zip(evs, _self_us(evs)):
+            op = e.name.split(" = ", 1)[0].lstrip("%")
+            row = agg.setdefault((op, str(e.stats.get("hlo_module", ""))),
+                                 [0, 0.0])
+            row[0] += 1
+            row[1] += own
     op_table = [
         {"op": op, "module": mod, "calls": c,
          "total_us": round(us, 1), "avg_us": round(us / c, 1)}
@@ -199,30 +208,27 @@ def correlate(trace: Dict[str, Any],
     # event belongs to the LAST step whose window opened before it started
     # — this also catches the async spill (param/optimizer updates still
     # executing after the host unblocked on the loss and moved on). Only
-    # events before the first window stay unattributed. Per-tid interval
-    # unions prevent nested fused spans from double-counting.
-    per_step_tid: Dict[int, Dict[Any, List[Tuple[float, float]]]] = {}
+    # events before the first window stay unattributed. Per-line interval
+    # unions prevent nested spans from double-counting.
+    per_step_line: Dict[int, Dict[Any, List[Tuple[float, float]]]] = {}
     unattributed = 0.0
-    windows = [s["window"] for s in steps]
-    starts = [w0 for (w0, _w1) in windows]
-    for e in device_evs:
-        ts, dur = float(e.get("ts", 0.0)), float(e.get("dur", 0.0))
-        hit = bisect.bisect_right(starts, ts) - 1
-        if hit < 0:
-            unattributed += dur
-            continue
-        tid = (e.get("pid"), e.get("tid"))
-        per_step_tid.setdefault(hit, {}).setdefault(tid, []).append(
-            (ts, ts + dur))
+    starts = [s["window"][0] for s in steps]
+    for key, evs in by_line.items():
+        for e in evs:
+            hit = bisect.bisect_right(starts, e.ts) - 1
+            if hit < 0:
+                unattributed += e.dur
+                continue
+            per_step_line.setdefault(hit, {}).setdefault(key, []).append(
+                (e.ts, e.ts + e.dur))
 
     for i, s in enumerate(steps):
         w0, w1 = s["window"]
-        by_tid = per_step_tid.get(i, {})
-        # union per thread, then sum across threads (parallel device
-        # threads legitimately add)
+        # union per line, then sum across lines (parallel device threads
+        # and several chips legitimately add)
         merged: Dict[Any, List[Tuple[float, float]]] = {}
         dev_us = 0.0
-        for tid, ivs in by_tid.items():
+        for key, ivs in per_step_line.get(i, {}).items():
             ivs.sort()
             out: List[Tuple[float, float]] = []
             for t0, t1 in ivs:
@@ -230,24 +236,22 @@ def correlate(trace: Dict[str, Any],
                     out[-1] = (out[-1][0], max(out[-1][1], t1))
                 else:
                     out.append((t0, t1))
-            merged[tid] = out
+            merged[key] = out
             dev_us += sum(t1 - t0 for t0, t1 in out)
         # phase attribution + hidden/exposed split inside this window
-        my_phases = [(n, max(t0, w0), min(t1, w1))
-                     for (n, t0, t1) in phase_spans
-                     if t0 < w1 and t1 > w0]
         phases: Dict[str, Dict[str, float]] = {}
         blocking: List[Tuple[float, float]] = []
-        for name, t0, t1 in my_phases:
+        for name, p0, p1 in phase_spans:
+            if not (p0 < w1 and p1 > w0):
+                continue
+            t0, t1 = max(p0, w0), min(p1, w1)
             row = phases.setdefault(name, {"ms": 0.0, "device_us": 0.0})
             row["ms"] += (t1 - t0) / 1e3
             for ivs in merged.values():
                 row["device_us"] += _overlap_us(ivs, [(t0, t1)])
             if name in _BLOCKING_PHASES:
                 blocking.append((t0, t1))
-        exposed = 0.0
-        for ivs in merged.values():
-            exposed += _overlap_us(ivs, blocking)
+        exposed = sum(_overlap_us(ivs, blocking) for ivs in merged.values())
         s["device_us"] = dev_us
         s["exposed_us"] = exposed
         s["hidden_us"] = max(dev_us - exposed, 0.0)
@@ -255,19 +259,16 @@ def correlate(trace: Dict[str, Any],
                            "device_us": round(r["device_us"], 1)}
                        for n, r in phases.items()}
 
-    dev_threads = sorted({
-        tid_names.get((e.get("pid"), e.get("tid")),
-                      f"pid{e.get('pid')}/tid{e.get('tid')}")
-        for e in device_evs})
+    dev_threads = sorted({f"{plane}/{line}" for plane, line in by_line})
     return CorrelatedTrace(steps, op_table, unattributed, dev_threads,
                            source=source)
 
 
 def correlate_logdir(logdir: str) -> CorrelatedTrace:
-    """Parse + correlate the newest trace artifact under ``logdir``."""
-    files = find_trace_artifacts(logdir)
-    if not files:
+    """Read + correlate the newest ``.xplane.pb`` under ``logdir``."""
+    path = find_xplane(logdir)
+    if path is None:
         raise FileNotFoundError(
-            f"no *.trace.json.gz under {logdir!r} — did the capture run "
+            f"no *.xplane.pb under {logdir!r} — did the capture run "
             "(jax.profiler trace) and stop cleanly?")
-    return correlate(load_trace_file(files[0]), source=files[0])
+    return correlate(read_xplane(path), source=path)

@@ -38,6 +38,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..observability.trace.request_trace import span
 from .base import (BadRequest, DeadlineExceeded, EngineBase, EngineClosed,
                    _oom_guard, _tracer)
 from .paged_kv import (HostPagePool, PagedKVPool, PoolExhausted,
@@ -982,6 +983,7 @@ class GenerationEngine(EngineBase):
                     # cover it — requeue at the front, decode meanwhile
                     with self._cond:
                         self._queue.appendleft(req)
+                    self.metrics.inc("admits_requeued")
                     break
                 except Exception as e:  # isolate: fail this prompt only
                     if not req.future.done():
@@ -1008,7 +1010,9 @@ class GenerationEngine(EngineBase):
                         return
                     if not self._queue and not self._ops:
                         # untimed: submit/close/op notify — no idle polling
-                        self._cond.wait()
+                        self.metrics.inc("idle_waits")
+                        with span("pt.serve.idle_wait"):
+                            self._cond.wait()
                 continue
             if self._hist_slots is not None:
                 # concurrent-occupancy sample per decode window: the
@@ -1039,97 +1043,107 @@ class GenerationEngine(EngineBase):
         last real prompt position (matching ``generate``'s contract)."""
         import jax.numpy as jnp
 
-        p = len(req.prompt)
-        pl = self._pl
-        total_blocks = req.total_blocks
-        t0 = time.monotonic()
-        s = self._slots[slot_no]
-        s.table[:] = 0
-        # prefix reuse: longest cached chain of full prompt blocks, capped
-        # so at least one suffix token remains to produce the first logits
-        shared_pages: List[int] = []
-        trie = self._pool.trie
-        all_blocks = req.blocks
-        if trie is not None:
-            if self._pool.warm is not None:
-                # warm tier: restore spilled pages for this chain before
-                # matching, so a previously-evicted prefix costs a host
-                # dequantize instead of a re-prefill
-                self._pool.warm_restore(all_blocks[: (p - 1) // pl])
-            shared_pages = trie.match(all_blocks[: (p - 1) // pl], pl,
-                                      self._pool.allocator)
-        m = len(shared_pages)
-        try:
-            private = self._pool.allocate(total_blocks - m)
-        except PoolExhausted:
-            for pg in shared_pages:
-                self._pool.allocator.release(pg)
-            raise
-        # the queue span lands only once the join is certain — a
-        # PoolExhausted requeue above must not double-record queue time
-        _tracer().span(req.trace, "queue", req.t_submit, t0)
-        s.table[:m] = shared_pages
-        s.table[m:total_blocks] = private
-        s.blocks, s.shared = total_blocks, m
-        # COW hook: every block the decode path will write must be
-        # exclusively ours. By construction they already are (the trie
-        # shares FULL prompt blocks only), so this is a no-op guard — but
-        # a future partial-block sharing scheme lands here.
-        for bi in range(p // pl, total_blocks):
-            pg, copied = self._pool.ensure_writable(int(s.table[bi]))
-            if copied:
-                s.table[bi] = pg
-        # suffix prefill: one window-step call, this slot's pages only
-        start = m * pl
-        suffix = req.prompt[start:p]
-        W = self._prefill_bucket(len(suffix))
-        S, B = self.config.max_slots, self._n_blocks
-        tokens = np.zeros((S, W), dtype=np.int32)
-        tokens[slot_no, :len(suffix)] = suffix
-        lengths = np.zeros(S, dtype=np.int32)
-        lengths[slot_no] = start
-        tables = np.zeros((S, B), dtype=np.int32)
-        tables[slot_no] = s.table
-        with _oom_guard("generation", label=f"serving:{self.name}:prefill",
-                        engine=self.name, bucket=W):
-            nxt, lp, self._pool.k, self._pool.v = self._window(W)(
-                self._params, self._pool.k, self._pool.v,
-                jnp.asarray(tables), jnp.asarray(tokens),
-                jnp.asarray(lengths))
-        first = int(np.asarray(nxt)[slot_no, len(suffix) - 1])
-        first_lp = float(np.asarray(lp)[slot_no, len(suffix) - 1])
-        # draft model prefills the WHOLE prompt through its own forward
-        # (the draft is small; its dense slot arena has no prefix cache)
-        if self.spec_k:
-            self._draft_prefill(slot_no, req.prompt)
-        # adopt this prompt's full blocks into the prefix cache so the
-        # next same-prefix request skips their prefill
-        if trie is not None:
-            fp = p // pl
-            trie.insert(all_blocks[:fp], [int(x) for x in s.table[:fp]],
-                        self._pool.allocator)
-            self.metrics.inc("prefix_hit_tokens", m * pl)
-            if self._fam_prefix is not None:
-                self._fam_prefix.inc((self.name, "lookup_tokens"), p)
-                self._fam_prefix.inc((self.name, "hit_tokens"), m * pl)
-        self.metrics.inc("prompt_tokens_total", p)
-        self.metrics.inc("prefills_total")
-        if m:
-            self.metrics.inc("prefix_hits")
-        self.metrics.observe_queue_wait((t0 - req.t_submit) * 1e3)
-        t1 = time.monotonic()
-        _tracer().span(req.trace, "prefill", t0, t1, bucket=W,
-                       prompt_len=p, slot=slot_no, prefix_blocks=m)
-        if self._hist_ttft is not None:
-            self._hist_ttft.observe((t1 - req.t_submit) * 1e3)
-        req.t_decode0 = t1
+        with span("pt.serve.admit", trace_id=req.trace, slot=slot_no,
+                  prompt_len=len(req.prompt)) as sp:
+            p = len(req.prompt)
+            pl = self._pl
+            total_blocks = req.total_blocks
+            t0 = time.monotonic()
+            s = self._slots[slot_no]
+            trie = self._pool.trie
+            all_blocks = req.blocks
+            with span("pt.serve.page_table"):
+                s.table[:] = 0
+                # prefix reuse: longest cached chain of full prompt blocks,
+                # capped so at least one suffix token remains to produce the
+                # first logits
+                shared_pages: List[int] = []
+                if trie is not None:
+                    if self._pool.warm is not None:
+                        # warm tier: restore spilled pages for this chain
+                        # before matching, so a previously-evicted prefix costs
+                        # a host dequantize instead of a re-prefill
+                        self._pool.warm_restore(all_blocks[: (p - 1) // pl])
+                    shared_pages = trie.match(all_blocks[: (p - 1) // pl], pl,
+                                              self._pool.allocator)
+                m = len(shared_pages)
+                try:
+                    private = self._pool.allocate(total_blocks - m)
+                except PoolExhausted:
+                    for pg in shared_pages:
+                        self._pool.allocator.release(pg)
+                    raise
+                # the queue span lands only once the join is certain — a
+                # PoolExhausted requeue above must not double-record queue time
+                _tracer().span(req.trace, "queue", req.t_submit, t0)
+                s.table[:m] = shared_pages
+                s.table[m:total_blocks] = private
+                s.blocks, s.shared = total_blocks, m
+                # COW hook: every block the decode path will write must be
+                # exclusively ours. By construction they already are (the trie
+                # shares FULL prompt blocks only), so this is a no-op guard —
+                # but a future partial-block sharing scheme lands here.
+                for bi in range(p // pl, total_blocks):
+                    pg, copied = self._pool.ensure_writable(int(s.table[bi]))
+                    if copied:
+                        s.table[bi] = pg
+            # suffix prefill: one window-step call, this slot's pages only
+            start = m * pl
+            suffix = req.prompt[start:p]
+            W = self._prefill_bucket(len(suffix))
+            sp.args.update(bucket=W, prefix_blocks=m)
+            with span("pt.serve.prefill_dispatch", bucket=W, prefix_blocks=m):
+                S, B = self.config.max_slots, self._n_blocks
+                tokens = np.zeros((S, W), dtype=np.int32)
+                tokens[slot_no, :len(suffix)] = suffix
+                lengths = np.zeros(S, dtype=np.int32)
+                lengths[slot_no] = start
+                tables = np.zeros((S, B), dtype=np.int32)
+                tables[slot_no] = s.table
+                with _oom_guard("generation",
+                                label=f"serving:{self.name}:prefill",
+                                engine=self.name, bucket=W):
+                    nxt, lp, self._pool.k, self._pool.v = self._window(W)(
+                        self._params, self._pool.k, self._pool.v,
+                        jnp.asarray(tables), jnp.asarray(tokens),
+                        jnp.asarray(lengths))
+            with span("pt.serve.prefill_sync"):
+                first = int(np.asarray(nxt)[slot_no, len(suffix) - 1])
+                first_lp = float(np.asarray(lp)[slot_no, len(suffix) - 1])
+            # draft model prefills the WHOLE prompt through its own forward
+            # (the draft is small; its dense slot arena has no prefix cache)
+            if self.spec_k:
+                self._draft_prefill(slot_no, req.prompt)
+            # adopt this prompt's full blocks into the prefix cache so the
+            # next same-prefix request skips their prefill
+            if trie is not None:
+                fp = p // pl
+                with span("pt.serve.page_table"):
+                    trie.insert(all_blocks[:fp],
+                                [int(x) for x in s.table[:fp]],
+                                self._pool.allocator)
+                self.metrics.inc("prefix_hit_tokens", m * pl)
+                if self._fam_prefix is not None:
+                    self._fam_prefix.inc((self.name, "lookup_tokens"), p)
+                    self._fam_prefix.inc((self.name, "hit_tokens"), m * pl)
+            self.metrics.inc("prompt_tokens_total", p)
+            self.metrics.inc("prefills_total")
+            if m:
+                self.metrics.inc("prefix_hits")
+            self.metrics.observe_queue_wait((t0 - req.t_submit) * 1e3)
+            t1 = time.monotonic()
+            _tracer().span(req.trace, "prefill", t0, t1, bucket=W,
+                           prompt_len=p, slot=slot_no, prefix_blocks=m)
+            if self._hist_ttft is not None:
+                self._hist_ttft.observe((t1 - req.t_submit) * 1e3)
+            req.t_decode0 = t1
 
-        s.req = req
-        s.length = p
-        s.last_token = first
-        s.t0 = t1  # slot residency opens (occupancy track)
-        self._note_token(req, first, first_lp)
-        self._emit_finish_check(slot_no)
+            s.req = req
+            s.length = p
+            s.last_token = first
+            s.t0 = t1  # slot residency opens (occupancy track)
+            self._note_token(req, first, first_lp)
+            self._emit_finish_check(slot_no)
 
     def _note_token(self, req: _GenRequest, t: int, lp: float) -> None:
         """One emitted token: record it (token + behavior logprob) and
@@ -1174,57 +1188,71 @@ class GenerationEngine(EngineBase):
         advances by its accepted run plus the target's own next token —
         emitted tokens are target argmaxes, so greedy output is unchanged.
         """
-        from .. import profiler
+        import jax.numpy as jnp
 
         S, B = self.config.max_slots, self._n_blocks
         k = self.spec_k if self._spec_on else 0
         W = k + 1
-        tokens = np.zeros((S, W), dtype=np.int32)
-        lengths = np.zeros(S, dtype=np.int32)
-        tables = np.zeros((S, B), dtype=np.int32)
-        for i in active:
-            s = self._slots[i]
-            tokens[i, 0] = s.last_token
-            lengths[i] = min(s.length, self.max_len - 1)
-            tables[i] = s.table
-        # chaos site: scripted decode fault at an exact decode-step index
-        # (PT_FAULTS="decode_fault@step=2") — the in-flight requests fail,
-        # their slots release, queued prompts keep being admitted
-        self._decode_no = getattr(self, "_decode_no", -1) + 1
-        _injector().check("decode_fault", engine=self.name,
-                          step=self._decode_no)
-        t_dec = time.monotonic()
-        import jax.numpy as jnp
+        with span("pt.serve.decode_round", n_active=len(active), W=W):
+            with span("pt.serve.decode_build"):
+                tokens = np.zeros((S, W), dtype=np.int32)
+                lengths = np.zeros(S, dtype=np.int32)
+                tables = np.zeros((S, B), dtype=np.int32)
+                for i in active:
+                    s = self._slots[i]
+                    tokens[i, 0] = s.last_token
+                    lengths[i] = min(s.length, self.max_len - 1)
+                    tables[i] = s.table
+            # chaos site: scripted decode fault at an exact decode-step index
+            # (PT_FAULTS="decode_fault@step=2") — the in-flight requests fail,
+            # their slots release, queued prompts keep being admitted
+            self._decode_no = getattr(self, "_decode_no", -1) + 1
+            _injector().check("decode_fault", engine=self.name,
+                              step=self._decode_no)
+            t_dec = time.monotonic()
+            with span("pt.serve.decode_dispatch"):
+                if k:  # draft proposal: k dense decode steps, all slots
+                    cur = jnp.asarray(tokens[:, 0])
+                    for j in range(k):
+                        with _oom_guard("generation",
+                                        label=f"serving:{self.name}:draft",
+                                        engine=self.name,
+                                        step=self._decode_no):
+                            nd, self._dk, self._dv = self._draft_step(
+                                self._dparams, self._dk, self._dv, cur,
+                                jnp.asarray(lengths + j))
+                        tokens[:, j + 1] = np.asarray(nd)
+                        cur = nd
+                with _oom_guard("generation",
+                                label=f"serving:{self.name}:decode",
+                                engine=self.name, step=self._decode_no):
+                    nxt, lp, self._pool.k, self._pool.v = self._window(W)(
+                        self._params, self._pool.k, self._pool.v,
+                        jnp.asarray(tables), jnp.asarray(tokens),
+                        jnp.asarray(lengths))
+            with span("pt.serve.decode_sync"):
+                n = np.asarray(nxt)  # [S, W] target argmax at each position
+                lpn = np.asarray(lp)  # [S, W] its behavior logprob (f32)
+            fr = self._flight()
+            if fr is not None:  # decode steps land in the flight ring
+                fr.record_serving_step(self.name, "decode",
+                                       (time.monotonic() - t_dec) * 1e3,
+                                       len(active))
+            self.metrics.inc("decode_steps")
+            self.metrics.inc("slot_rounds", len(active))
+            self.metrics.observe_occupancy(len(active) / S)
+            with span("pt.serve.emit"):
+                emitted_total = self._emit_round(active, k, tokens, n, lpn)
+            self.metrics.inc("tokens_total", emitted_total)
+            if k:
+                self.metrics.inc("spec_rounds")
+                if self._fam_spec is not None:
+                    self._fam_spec.inc((self.name, "rounds"))
+                    self._fam_spec.inc((self.name, "emitted"), emitted_total)
 
-        with profiler.RecordEvent(
-                f"serving::decode[{self.name} n{len(active)}]", "Serving"):
-            if k:  # draft proposal: k dense decode steps, all slots batched
-                cur = jnp.asarray(tokens[:, 0])
-                for j in range(k):
-                    with _oom_guard("generation",
-                                    label=f"serving:{self.name}:draft",
-                                    engine=self.name, step=self._decode_no):
-                        nd, self._dk, self._dv = self._draft_step(
-                            self._dparams, self._dk, self._dv, cur,
-                            jnp.asarray(lengths + j))
-                    tokens[:, j + 1] = np.asarray(nd)
-                    cur = nd
-            with _oom_guard("generation", label=f"serving:{self.name}:decode",
-                            engine=self.name, step=self._decode_no):
-                nxt, lp, self._pool.k, self._pool.v = self._window(W)(
-                    self._params, self._pool.k, self._pool.v,
-                    jnp.asarray(tables), jnp.asarray(tokens),
-                    jnp.asarray(lengths))
-        n = np.asarray(nxt)  # [S, W] target argmax at each window position
-        lpn = np.asarray(lp)  # [S, W] its behavior logprob (f32)
-        fr = self._flight()
-        if fr is not None:  # decode steps land in the flight ring
-            fr.record_serving_step(self.name, "decode",
-                                   (time.monotonic() - t_dec) * 1e3,
-                                   len(active))
-        self.metrics.inc("decode_steps")
-        self.metrics.inc("slot_rounds", len(active))
-        self.metrics.observe_occupancy(len(active) / S)
+    def _emit_round(self, active: List[int], k: int, tokens, n, lpn) -> int:
+        """Accept, emit, finish and release, slot by slot; returns the
+        tokens emitted this round."""
         emitted_total = 0
         for i in active:
             s = self._slots[i]
@@ -1252,12 +1280,7 @@ class GenerationEngine(EngineBase):
                 emitted_total += 1
                 if self._emit_finish_check(i):
                     break
-        self.metrics.inc("tokens_total", emitted_total)
-        if k:
-            self.metrics.inc("spec_rounds")
-            if self._fam_spec is not None:
-                self._fam_spec.inc((self.name, "rounds"))
-                self._fam_spec.inc((self.name, "emitted"), emitted_total)
+        return emitted_total
 
     def _emit_finish_check(self, slot_no: int) -> bool:
         """Finish-and-release when the slot's request is done (budget

@@ -14,6 +14,7 @@ process; JAX's persistent cache is off around them (a described-device
 compile is written to the cache but can never be read back).
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -52,13 +53,28 @@ def _no_persistent_cache():
     cc.reset_cache()
 
 
-def _compile(fn, one_chip, *shapes):
+_CUSTOM_CALL = re.compile(
+    r"%([\w.\-]+) = [^\n]*custom_call_target=\"tpu_custom_call\"")
+
+
+def _compile(fn, one_chip, *shapes, names, foreign=None):
     """Lower + compile ``fn`` for the described chip; returns the
-    compiled program's text (must contain the Mosaic custom call)."""
+    compiled program's text. Every Mosaic custom call in it must carry
+    its kernel's name (``pt_<kernel>``: what the device trace shows on
+    the ``XLA Ops`` line) and ``names`` must all be there; ``foreign``
+    names a kernel that is JAX's own (megablox ``gmm``)."""
     args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
     compiled = jax.jit(fn).lower(*args).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" in text, "kernel is not in the program"
+    calls = _CUSTOM_CALL.findall(text)
+    assert calls, "kernel is not in the program"
+    assert "_unknown_" not in text
+    # jvp / transpose / checkpoint wrap the scope's name, never replace
+    # it: ``transpose(jvp(pt_flash_bwd_dq))`` -> transpose_jvp_pt_..._dq__
+    calls = [c for c in calls if not (foreign and foreign in c)]
+    kernels = [re.search(r"pt_[a-z0-9_]*[a-z0-9]", c) for c in calls]
+    assert all(kernels), calls
+    assert {m.group(0) for m in kernels} == set(names), calls
     return text
 
 
@@ -75,7 +91,8 @@ def test_flash_fwd_bwd(one_chip, monkeypatch, b, s, h, d):
                        .astype(jnp.float32))
 
     qkv = ((b, s, h, d), BF16)
-    _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, qkv, qkv, qkv)
+    _compile(jax.grad(loss, argnums=(0, 1, 2)), one_chip, qkv, qkv, qkv,
+             names=("pt_flash_fwd", "pt_flash_bwd_dkv", "pt_flash_bwd_dq"))
 
 
 @pytest.mark.parametrize("residual", [False, True])
@@ -91,13 +108,15 @@ def test_rmsnorm_fwd_bwd(one_chip, residual):
                 jnp.sum(s.astype(jnp.float32))
         shapes = [((n, hdim), BF16), ((n, hdim), BF16), ((hdim,), BF16)]
         argnums = (0, 1, 2)
+        names = ("pt_rmsnorm_fwd_residual", "pt_rmsnorm_bwd_residual")
     else:
         def loss(x, w):
             return jnp.sum(krms.rms_norm(x, w, 1e-6, impl="pallas")
                            .astype(jnp.float32))
         shapes = [((n, hdim), BF16), ((hdim,), BF16)]
         argnums = (0, 1)
-    _compile(jax.grad(loss, argnums=argnums), one_chip, *shapes)
+        names = ("pt_rmsnorm_fwd", "pt_rmsnorm_bwd")
+    _compile(jax.grad(loss, argnums=argnums), one_chip, *shapes, names=names)
 
 
 @pytest.mark.parametrize("b", [4, 16])
@@ -108,7 +127,8 @@ def test_rope_fwd_inverse(one_chip, b):
         return jnp.sum(krope.rope_apply(x, 1e4, 0, impl="pallas")
                        .astype(jnp.float32))
 
-    _compile(jax.value_and_grad(loss), one_chip, ((b, 2048, 16, 128), BF16))
+    _compile(jax.value_and_grad(loss), one_chip, ((b, 2048, 16, 128), BF16),
+             names=("pt_rope",))
 
 
 @pytest.mark.parametrize("W", [1, 5, 64])
@@ -126,7 +146,7 @@ def test_paged_attention(one_chip, W, dtype):
     _compile(run, one_chip,
              ((S, W, nh, hd), dtype), ((P, PL, nh, hd), dtype),
              ((P, PL, nh, hd), dtype), ((S, B), jnp.int32),
-             ((S, W), jnp.int32))
+             ((S, W), jnp.int32), names=("pt_paged_attention",))
 
 
 def test_moe_routing_dispatch(one_chip, monkeypatch):
@@ -148,4 +168,6 @@ def test_moe_routing_dispatch(one_chip, monkeypatch):
     _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)), one_chip,
              ((b, s, h), BF16), ((h, e), jnp.float32),
              ((e, h, inter), BF16), ((e, h, inter), BF16),
-             ((e, inter, h), BF16))
+             ((e, inter, h), BF16),
+             names=("pt_moe_route", "pt_moe_dispatch", "pt_moe_combine"),
+             foreign="gmm_")

@@ -165,8 +165,8 @@ def test_step_timeline_phases_ordered_for_jitted_fit(tmp_path):
     with open(out) as f:
         names = {ev["name"] for ev in json.load(f)["traceEvents"]}
     assert "user_span" in names
-    assert {"step:data_wait", "step:host_dispatch",
-            "step:device_block", "step:total"} <= names
+    assert {"pt.train.data_wait", "pt.train.host_dispatch",
+            "pt.train.device_block", "pt.train.step"} <= names
     assert tl.table()  # human summary renders
 
 

@@ -3,7 +3,6 @@ request-scoped serving traces, flight recorder + pd_dump bundles,
 histogram exposition. The heavy real-capture tests are slow-marked for
 tier-1 wall clock but run IN FULL by tools/ci.sh's tracing gate (which
 also runs tools/trace_drill.py — the three acceptance asserts)."""
-import gzip
 import json
 import os
 import time
@@ -19,74 +18,139 @@ from paddle_tpu.observability.timeline import StepTimeline
 
 # -- XPlane parse + correlation (synthetic artifact: exact math) ---------------
 
+def _pb(field, value):
+    """One protobuf field: an int as a varint, bytes/str length-delimited
+    (all the xplane schema needs here; the profiler's own file format)."""
+    def varint(n):
+        out = bytearray()
+        while True:
+            out.append((n & 0x7F) | (0x80 if n > 0x7F else 0))
+            n >>= 7
+            if not n:
+                return bytes(out)
+    if isinstance(value, int):
+        return varint(field << 3) + varint(value)
+    if isinstance(value, str):
+        value = value.encode()
+    return varint(field << 3 | 2) + varint(len(value)) + value
+
+
+def _xspace(planes):
+    """``{plane: {line: [(name, start_us, dur_us, {stat: value})]}}`` as the
+    bytes of an XSpace (XPlane: name=2 lines=3 event_metadata=4
+    stat_metadata=5; XLine: name=2 events=4; XEvent: metadata_id=1
+    offset_ps=2 duration_ps=3 stats=4; XStat: metadata_id=1 int64_value=4
+    str_value=5; the metadata maps: key=1 value=2, value: id=1 name=2)."""
+    space = b""
+    for pname, lines in planes.items():
+        names, stat_names, body = {}, {}, _pb(2, pname)
+        for lname, events in lines.items():
+            line = _pb(2, lname)
+            for name, ts, dur, stats in events:
+                ev = _pb(1, names.setdefault(name, len(names) + 1)) \
+                    + _pb(2, int(ts * 1e6)) + _pb(3, int(dur * 1e6))
+                for k, v in stats.items():
+                    sid = stat_names.setdefault(k, len(stat_names) + 1)
+                    ev += _pb(4, _pb(1, sid) + _pb(4 if isinstance(v, int)
+                                                   else 5, v))
+                line += _pb(4, ev)
+            body += _pb(3, line)
+        for field, table in ((4, names), (5, stat_names)):
+            for n, i in table.items():
+                body += _pb(field, _pb(1, i) + _pb(2, _pb(1, i) + _pb(2, n)))
+        space += _pb(1, body)
+    return space
+
+
 def _synthetic_trace():
     """Two steps; step 0: one 100us hlo op fully inside a device_block
     phase (exposed), step 1: one 80us op outside any blocking phase
     (hidden) + a 20us op spilling past the window (attributed to step 1),
-    plus one pre-window op (unattributed) and module-group noise."""
-    E = []
-    E.append({"ph": "M", "pid": 7, "name": "process_name",
-              "args": {"name": "/host:CPU"}})
-    E.append({"ph": "M", "pid": 7, "tid": 2, "name": "thread_name",
-              "args": {"name": "tf_XLAEigen/2"}})
-
-    def x(name, ts, dur, tid=1, args=None):
-        e = {"ph": "X", "pid": 7, "tid": tid, "name": name,
-             "ts": ts, "dur": dur}
-        if args:
-            e["args"] = args
-        E.append(e)
-
-    hlo = {"hlo_op": "fusion.1", "hlo_module": "jit_step"}
-    x("before", 500, 30, tid=2, args=hlo)              # pre-window: unattributed
-    x("pt_step#0", 1000, 1000)
-    x("pt_phase#host_dispatch", 1000, 300)
-    x("pt_phase#device_block", 1300, 600)
-    x("fusion.1", 1400, 100, tid=2, args=hlo)          # exposed (in block)
-    x("pt_step#1", 2500, 1000)
-    x("pt_phase#host_dispatch", 2500, 400)
-    x("fusion.2", 2600, 80, tid=2,
-      args={"hlo_op": "fusion.2", "hlo_module": "jit_step"})  # hidden
-    x("fusion.2", 3600, 20, tid=2,
-      args={"hlo_op": "fusion.2", "hlo_module": "jit_step"})  # spill -> step 1
-    x("jit_step", 2600, 900, tid=2)                    # module group: skipped
-    return {"displayTimeUnit": "ms", "traceEvents": E}
+    plus one pre-window op (unattributed), host noise, and on a TPU plane
+    a ``while`` that CONTAINS its body's op (self time, no double count)."""
+    hlo1 = {"hlo_op": "fusion.1", "hlo_module": "jit_step"}
+    hlo2 = {"hlo_op": "fusion.2", "hlo_module": "jit_step"}
+    T = "bf16[4,8]{1,0:T(8,128)(2,1)}"
+    return {
+        "/host:CPU": {
+            "python3": [
+                ("pt.train.step", 1000, 1000, {"step_num": 7}),
+                ("pt.train.host_dispatch", 1000, 300, {}),
+                ("pt.train.device_block", 1300, 600, {}),
+                ("pt.train.step", 2500, 1000, {"step_num": 8}),
+                ("pt.train.host_dispatch", 2500, 400, {}),
+                ("PjitFunction(step)", 2500, 400, {}),   # not ours: skipped
+            ],
+            "tf_XLAEigen/2": [
+                ("fusion.1", 500, 30, hlo1),             # pre-window
+                ("fusion.1", 1400, 100, hlo1),           # exposed (in block)
+                ("fusion.2", 2600, 80, hlo2),            # hidden
+                ("fusion.2", 3600, 20, hlo2),            # spill -> step 8
+                ("ThunkExecutor::Execute", 2600, 900, {}),  # no hlo_op
+            ],
+        },
+        "/device:TPU:0": {
+            "XLA Ops": [
+                (f"%while.1 = ({T}) while(({T}) %t.1), body=%b.1", 2700,
+                 100, {}),
+                (f"%pt_rope.3 = {T} custom-call({T} %p.1), "
+                 f"custom_call_target=\"tpu_custom_call\"", 2720, 60, {}),
+            ],
+            "XLA Modules": [("jit_step(1)", 2700, 100, {})],  # not an op
+        },
+    }
 
 
 def test_synthetic_trace_parse_and_correlate(tmp_path):
     d = tmp_path / "plugins" / "profile" / "2026_01_01"
     d.mkdir(parents=True)
-    with gzip.open(str(d / "host.trace.json.gz"), "wt") as f:
-        json.dump(_synthetic_trace(), f)
+    (d / "host.xplane.pb").write_bytes(_xspace(_synthetic_trace()))
     cor = otrace.correlate_logdir(str(tmp_path))
-    assert cor.source and cor.source.endswith(".trace.json.gz")
+    assert cor.source and cor.source.endswith(".xplane.pb")
     assert len(cor.steps) == 2 and cor.steps_correlated == 2
     s0, s1 = cor.steps
-    assert s0["step"] == 0 and s0["device_us"] == pytest.approx(100)
+    assert s0["step"] == 7 and s0["device_us"] == pytest.approx(100)
     assert s0["exposed_us"] == pytest.approx(100)   # inside device_block
     assert s0["hidden_us"] == pytest.approx(0)
     assert s0["phases"]["device_block"]["device_us"] == pytest.approx(100)
-    assert s1["device_us"] == pytest.approx(100)    # 80 in-window + 20 spill
-    assert s1["hidden_us"] == pytest.approx(100)    # no blocking phase
+    # 80 in-window + 20 spill on the CPU line, 100 (the while's union with
+    # its body, not 160) on the TPU's
+    assert s1["step"] == 8 and s1["device_us"] == pytest.approx(200)
+    assert s1["hidden_us"] == pytest.approx(200)    # no blocking phase
     assert cor.unattributed_device_us == pytest.approx(30)
-    assert cor.overlap_efficiency() == pytest.approx(0.5)
+    assert cor.overlap_efficiency() == pytest.approx(2 / 3, abs=1e-4)
     ops = {r["op"]: r for r in cor.op_table}
     assert ops["fusion.2"]["calls"] == 2
     assert ops["fusion.2"]["total_us"] == pytest.approx(100)
-    assert "jit_step" not in ops  # module-group span never double-counts
+    assert ops["fusion.2"]["module"] == "jit_step"
+    # the op table holds SELF time under the op's short name
+    assert ops["pt_rope.3"]["total_us"] == pytest.approx(60)
+    assert ops["while.1"]["total_us"] == pytest.approx(40)
+    assert not {"jit_step(1)", "PjitFunction(step)",
+                "ThunkExecutor::Execute"} & set(ops)
+    assert cor.device_threads == ["/device:TPU:0/XLA Ops",
+                                  "/host:CPU/tf_XLAEigen/2"]
     # summary is JSON-able and carries the op table + digest
     json.dumps(cor.summary())
 
 
-def test_find_trace_artifacts_empty(tmp_path):
-    assert otrace.find_trace_artifacts(str(tmp_path)) == []
+def test_find_xplane_empty_and_step_order_without_the_stat(tmp_path):
+    assert otrace.find_xplane(str(tmp_path)) is None
     with pytest.raises(FileNotFoundError):
         otrace.correlate_logdir(str(tmp_path))
+    # a trace whose step spans carry no ``step_num``: numbered by order
+    tr = _synthetic_trace()
+    tr["/host:CPU"]["python3"] = [
+        (n, ts, dur, {}) for n, ts, dur, _st in tr["/host:CPU"]["python3"]]
+    p = tmp_path / "plugins" / "profile" / "t" / "vm.xplane.pb"
+    p.parent.mkdir(parents=True)
+    p.write_bytes(_xspace(tr))
+    cor = otrace.correlate(otrace.read_xplane(str(p)))
+    assert [s["step"] for s in cor.steps] == [0, 1]
 
 
 # -- real CPU capture (heavy: runs jax.profiler) -------------------------------
 
-@pytest.mark.slow  # tier-1 wall clock; run in full by the ci.sh tracing gate
 def test_capture_real_cpu_trace_correlates():
     """The ISSUE-7 acceptance shape: a CPU-run traced window reports
     device_compute_us from XPlane correlation (not host-block), phases
@@ -201,6 +265,128 @@ def test_serving_trace_failures_finish():
     after = tr.snapshot()
     assert after["failed"] >= before["failed"] + 1
     assert after["live"] == before["live"]
+
+
+def test_span_ring_parents_drops_and_profiler_feed(tmp_path):
+    """The span primitive: the enclosing span of its thread is its parent,
+    other threads do not nest into it, arguments set before exit reach the
+    ring, a full ring counts what it drops, and a recording Profiler gets
+    the span under its own name (Paddle's chrome export)."""
+    import threading
+
+    from paddle_tpu import profiler
+    from paddle_tpu.observability.trace import span, tracer
+
+    prof = profiler.Profiler(targets=[profiler.ProfilerTarget.CPU])
+    prof.start()
+    with span("pt.test.outer", k=1) as outer:
+        with span("pt.test.inner"):
+            t = threading.Thread(target=lambda: span(
+                "pt.test.other").__enter__().__exit__(), name="other-thread")
+            t.start()
+            t.join()
+        outer.args["late"] = 2
+    prof.stop()
+    rows = {r["name"]: r for r in tracer().worker_spans()
+            if r["name"].startswith("pt.test.")}
+    assert rows["pt.test.inner"]["parent"] == rows["pt.test.outer"]["id"]
+    assert rows["pt.test.outer"]["parent"] is None
+    assert rows["pt.test.outer"]["args"] == {"k": 1, "late": 2}
+    assert rows["pt.test.other"]["parent"] is None
+    assert rows["pt.test.other"]["thread"] == "other-thread"
+    assert rows["pt.test.outer"]["self_us"] == pytest.approx(
+        rows["pt.test.outer"]["dur_us"] - rows["pt.test.inner"]["dur_us"])
+    out = str(tmp_path / "trace.json")
+    prof._export_chrome(out)
+    with open(out) as f:
+        names = {ev["name"] for ev in json.load(f)["traceEvents"]}
+    assert {"pt.test.outer", "pt.test.inner", "pt.test.other"} <= names
+    small = otrace.RequestTracer(worker_capacity=2)
+    for i in range(3):
+        small.worker_span(i, "pt.test.x", 0.0, 1.0, "t", None, {})
+    snap = small.snapshot()
+    assert snap["worker_spans"] == 3 and snap["worker_dropped"] == 1
+    assert [r["id"] for r in small.worker_spans()] == [1, 2]
+
+
+def test_generation_worker_spans_nest_and_account():
+    """The engine worker's own spans (``trace.span``): a decode round with
+    its build / dispatch / sync / emit, an admission with its page-table
+    work and prefill, one ``idle_wait`` per empty wait — each with the span
+    that enclosed it, so self times add up to the parents' durations — and
+    the request's trace id on its ``admit``."""
+    from paddle_tpu import serving
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+    from paddle_tpu.observability.trace import tracer
+
+    cfg = GPTConfig(vocab_size=32, hidden_size=32, num_hidden_layers=1,
+                    num_attention_heads=2, max_position_embeddings=64,
+                    dtype="float32")
+    paddle.seed(0)
+    eng = serving.GenerationEngine(
+        GPTForCausalLM(cfg), serving.GenerationConfig(
+            max_slots=2, max_seq_len=48, prefill_buckets=(16,)),
+        name="span_gen")
+    with eng:
+        prompt = np.arange(5).astype("int64")
+        for _ in range(2):  # the second submit finds the worker waiting
+            futs = [eng.submit(prompt, max_new_tokens=4) for _ in range(2)]
+            for f in futs:
+                assert len(f.result(timeout=300)) == 9
+            deadline = time.monotonic() + 30
+            while eng.stats()["active_slots"] and time.monotonic() < deadline:
+                time.sleep(0.01)
+            time.sleep(0.05)
+        counters = eng.stats()["counters"]
+    rows = tracer().worker_spans(thread="pt-serving-span_gen")
+    by_id = {r["id"]: r for r in rows}
+    kids = {}
+    for r in rows:
+        assert r["name"].startswith("pt.serve."), r["name"]
+        if r["parent"] is not None:
+            kids.setdefault(r["parent"], []).append(r)
+
+    def of(name):
+        return [r for r in rows if r["name"] == name]
+
+    rounds, admits = of("pt.serve.decode_round"), of("pt.serve.admit")
+    assert len(rounds) == counters["decode_steps"] >= 3
+    assert len(admits) == 4 == counters["prefills_total"]
+    for r in rounds:
+        assert [k["name"] for k in kids[r["id"]]] == [
+            "pt.serve.decode_build", "pt.serve.decode_dispatch",
+            "pt.serve.decode_sync", "pt.serve.emit"]
+        assert r["args"]["W"] == 1 and 1 <= r["args"]["n_active"] <= 2
+    for a in admits:
+        assert [k["name"] for k in kids[a["id"]]] == [
+            "pt.serve.page_table", "pt.serve.prefill_dispatch",
+            "pt.serve.prefill_sync", "pt.serve.page_table"]
+        assert a["args"]["prompt_len"] == 5 and a["args"]["bucket"] == 16
+        assert a["args"]["prefix_blocks"] == 0 and a["args"]["slot"] in (0, 1)
+    # the worker's row is tied to the requests' rows by the trace id
+    traces = {t["trace_id"] for t in tracer().traces(engine="span_gen")}
+    assert {a["args"]["trace_id"] for a in admits} == traces
+    # every wait with nothing to run is one span and one count
+    waits = of("pt.serve.idle_wait")
+    assert len(waits) == counters["idle_waits"] >= 2
+    assert all(w["parent"] is None and w["self_us"] == w["dur_us"]
+               for w in waits)
+    assert "admits_requeued" not in counters  # the pool never ran out
+    # self time = duration less the children's; children lie inside
+    for r in rows:
+        inner = kids.get(r["id"], [])
+        assert r["self_us"] + sum(k["dur_us"] for k in inner) == \
+            pytest.approx(r["dur_us"], abs=1e-3)
+        for k in inner:
+            assert k["t0"] >= r["t0"] and k["parent"] in by_id
+            assert k["t0"] + k["dur_us"] / 1e6 <= \
+                r["t0"] + r["dur_us"] / 1e6 + 1e-6
+    # the chrome export shows the ring as the worker's row
+    evs = tracer().chrome_events()
+    pid = next(e["pid"] for e in evs if e.get("ph") == "M"
+               and e["args"]["name"] == "worker:span_gen")
+    assert sum(1 for e in evs if e.get("cat") == "worker"
+               and e["pid"] == pid) == len(rows)
 
 
 @pytest.mark.slow  # GPT fixture is heavy; ci.sh tracing gate runs it

@@ -86,8 +86,10 @@ def _drill_serving() -> dict:
                         "requests.trace.json")
     tracer().export_chrome(path)
     with open(path) as f:
+        # request and slot rows; the worker:<thread> rows hold trace.span's
+        # ring, tied to a request only where a span serves one
         events = [e for e in json.load(f)["traceEvents"]
-                  if e.get("ph") == "X"]
+                  if e.get("ph") == "X" and e.get("cat") != "worker"]
     assert events, "empty request trace export"
     by_id: dict = {}
     for e in events:
