@@ -268,11 +268,14 @@ def _build_window_step(cfg, max_slots: int, n_blocks: int, page_len: int,
     tables into the pool arenas, attend each window token causally against
     the page pool, and return the greedy argmax at every window position.
 
-    One shape serves three roles — W=1 is the decode step, W=k+1 scores a
-    draft model's k proposals (speculative verify), W=bucket prefills a
-    prompt suffix (cold prefill is the zero-prefix special case). Rows
-    whose page table is all-zero write only the scratch page, so a prefill
-    call touches exactly one request's pages.
+    One body serves three roles, at two row counts. At ``max_slots`` rows,
+    W=1 is the decode step and W=k+1 scores a draft model's k proposals
+    (speculative verify); rows whose page table is all-zero write only the
+    scratch page. At ONE row, W=bucket prefills a prompt suffix (cold
+    prefill is the zero-prefix special case): an admission serves exactly
+    one request, so its program has that request's row and no other, and
+    touches that request's pages only. The arenas are the whole pool at
+    either row count.
 
     ``fused=True`` (registry-gated: ``FLAGS_fused_kernels``) attends
     straight against the page table through the Pallas paged-attention
@@ -438,7 +441,8 @@ class GenerationEngine(EngineBase):
         donate = self.config.donate_cache and jax.default_backend() != "cpu"
         self._donate = donate
         self._mcfg = mcfg
-        self._windows: Dict[int, Any] = {}  # W -> compiled window step
+        # (rows, W) -> compiled window step
+        self._windows: Dict[Tuple[int, int], Any] = {}
 
         # -- speculative decoding (draft model) --------------------------------
         self.spec_k = 0
@@ -539,11 +543,13 @@ class GenerationEngine(EngineBase):
         return st
 
     # -- executables ----------------------------------------------------------
-    def _window(self, W: int):
-        """The compiled window step for window size ``W`` (built once per
-        size; sizes come from the closed set {1, spec_k+1} ∪ buckets, so
-        steady state never retraces)."""
-        fn = self._windows.get(W)
+    def _window(self, rows: int, W: int):
+        """The compiled window step for ``rows`` rows of ``W`` tokens:
+        ``max_slots`` rows for a decode or verify round, ONE row for a
+        prefill (an admission serves one request). Built once per pair; the
+        pairs come from the closed set {(S, 1), (S, spec_k+1)} ∪
+        {(1, bucket)}, so steady state never retraces."""
+        fn = self._windows.get((rows, W))
         if fn is None:
             from .. import jit as jit_mod
             from ..kernels.registry import fused_enabled
@@ -552,37 +558,39 @@ class GenerationEngine(EngineBase):
             # the ":fused" label suffix keeps the retrace audit and the
             # persistent-cache keyspace honest about which path compiled
             fused = fused_enabled("paged_attention")
-            label = f"serving:{self.name}:window{W}" + \
+            role = "window" if rows == self.config.max_slots else "prefill"
+            label = f"serving:{self.name}:{role}{W}" + \
                 (":fused" if fused else "")
             fn = jit_mod._maybe_audit(
-                label, _build_window_step(self._mcfg, self.config.max_slots,
+                label, _build_window_step(self._mcfg, rows,
                                           self._n_blocks, self._pl, W,
                                           self._donate, label=label,
                                           fused=fused))
-            self._windows[W] = fn
+            self._windows[(rows, W)] = fn
         return fn
 
     def warmup(self):
         """Compile the whole steady-state executable set up front (decode,
-        speculative verify, every prefill bucket, draft steps) against the
-        scratch page — a warm replica restarting under the persistent
-        cache loads them all from disk with zero fresh XLA compiles."""
+        speculative verify, every one-row prefill bucket, draft steps)
+        against the scratch page — a warm replica restarting under the
+        persistent cache loads them all from disk with zero fresh XLA
+        compiles."""
         import jax.numpy as jnp
 
         S, B = self.config.max_slots, self._n_blocks
-        tables = jnp.zeros((S, B), jnp.int32)
-        lengths = jnp.zeros(S, jnp.int32)
-        sizes = [1] + ([self.spec_k + 1] if self.spec_k else []) + \
-            [b for b in self.config.prefill_buckets]
-        for W in sorted(set(sizes)):
-            tokens = jnp.zeros((S, W), jnp.int32)
-            _n, _lp, self._pool.k, self._pool.v = self._window(W)(
-                self._params, self._pool.k, self._pool.v, tables, tokens,
-                lengths)
+        programs = [(S, 1)] + \
+            ([(S, self.spec_k + 1)] if self.spec_k else []) + \
+            [(1, b) for b in self.config.prefill_buckets]
+        for rows, W in programs:
+            _n, _lp, self._pool.k, self._pool.v = self._window(rows, W)(
+                self._params, self._pool.k, self._pool.v,
+                jnp.zeros((rows, B), jnp.int32),
+                jnp.zeros((rows, W), jnp.int32),
+                jnp.zeros(rows, jnp.int32))
         if self.spec_k:
-            toks = jnp.zeros(S, jnp.int32)
+            zeros = jnp.zeros(S, jnp.int32)
             _n, self._dk, self._dv = self._draft_step(
-                self._dparams, self._dk, self._dv, toks, lengths)
+                self._dparams, self._dk, self._dv, zeros, zeros)
             # the draft PREFILL path too (its per-bucket insert
             # executables + the draft forward's op set) — slot 0's
             # garbage rows are overwritten at the first real admit
@@ -1087,29 +1095,26 @@ class GenerationEngine(EngineBase):
                     pg, copied = self._pool.ensure_writable(int(s.table[bi]))
                     if copied:
                         s.table[bi] = pg
-            # suffix prefill: one window-step call, this slot's pages only
+            # suffix prefill: one call of the ONE-ROW window step — this
+            # request's tokens, table and start, nobody else's
             start = m * pl
             suffix = req.prompt[start:p]
             W = self._prefill_bucket(len(suffix))
             sp.args.update(bucket=W, prefix_blocks=m)
-            with span("pt.serve.prefill_dispatch", bucket=W, prefix_blocks=m):
-                S, B = self.config.max_slots, self._n_blocks
-                tokens = np.zeros((S, W), dtype=np.int32)
-                tokens[slot_no, :len(suffix)] = suffix
-                lengths = np.zeros(S, dtype=np.int32)
-                lengths[slot_no] = start
-                tables = np.zeros((S, B), dtype=np.int32)
-                tables[slot_no] = s.table
+            with span("pt.serve.prefill_dispatch", bucket=W, prefix_blocks=m,
+                      rows=1):
+                tokens = np.zeros((1, W), dtype=np.int32)
+                tokens[0, :len(suffix)] = suffix
                 with _oom_guard("generation",
                                 label=f"serving:{self.name}:prefill",
                                 engine=self.name, bucket=W):
-                    nxt, lp, self._pool.k, self._pool.v = self._window(W)(
+                    nxt, lp, self._pool.k, self._pool.v = self._window(1, W)(
                         self._params, self._pool.k, self._pool.v,
-                        jnp.asarray(tables), jnp.asarray(tokens),
-                        jnp.asarray(lengths))
+                        jnp.asarray(s.table[None]), jnp.asarray(tokens),
+                        jnp.asarray(np.array([start], dtype=np.int32)))
             with span("pt.serve.prefill_sync"):
-                first = int(np.asarray(nxt)[slot_no, len(suffix) - 1])
-                first_lp = float(np.asarray(lp)[slot_no, len(suffix) - 1])
+                first = int(np.asarray(nxt)[0, len(suffix) - 1])
+                first_lp = float(np.asarray(lp)[0, len(suffix) - 1])
             # draft model prefills the WHOLE prompt through its own forward
             # (the draft is small; its dense slot arena has no prefix cache)
             if self.spec_k:
@@ -1128,6 +1133,9 @@ class GenerationEngine(EngineBase):
                     self._fam_prefix.inc((self.name, "hit_tokens"), m * pl)
             self.metrics.inc("prompt_tokens_total", p)
             self.metrics.inc("prefills_total")
+            # token-rows the prefill program ran (rows x W): what
+            # stats()["prefill_fill_rate"] divides the real tokens by
+            self.metrics.inc("prefill_window_tokens_total", W)
             if m:
                 self.metrics.inc("prefix_hits")
             self.metrics.observe_queue_wait((t0 - req.t_submit) * 1e3)
@@ -1226,7 +1234,7 @@ class GenerationEngine(EngineBase):
                 with _oom_guard("generation",
                                 label=f"serving:{self.name}:decode",
                                 engine=self.name, step=self._decode_no):
-                    nxt, lp, self._pool.k, self._pool.v = self._window(W)(
+                    nxt, lp, self._pool.k, self._pool.v = self._window(S, W)(
                         self._params, self._pool.k, self._pool.v,
                         jnp.asarray(tables), jnp.asarray(tokens),
                         jnp.asarray(lengths))
@@ -1375,6 +1383,10 @@ class GenerationEngine(EngineBase):
         pt = c.get("prompt_tokens_total", 0)
         snap["prefix_hit_rate"] = round(
             c.get("prefix_hit_tokens", 0) / pt, 4) if pt else 0.0
+        # share of the prefill programs' token-rows that held a real token
+        wt = c.get("prefill_window_tokens_total", 0)
+        snap["prefill_fill_rate"] = round(
+            (pt - c.get("prefix_hit_tokens", 0)) / wt, 4) if wt else 0.0
         rounds = c.get("slot_rounds", 0)  # per-SEQUENCE decode rounds
         snap["effective_tokens_per_step"] = round(
             c.get("tokens_total", 0) / rounds, 3) if rounds else 0.0

@@ -131,14 +131,8 @@ def test_rope_fwd_inverse(one_chip, b):
              names=("pt_rope",))
 
 
-@pytest.mark.parametrize("W", [1, 5, 64])
-@pytest.mark.parametrize("dtype", [jnp.float32, BF16])
-def test_paged_attention(one_chip, W, dtype):
-    """GPT-2-small heads (12 x 64), page_len 16: decode (W=1), the
-    speculative verify window (W=spec_tokens+1) and a prefill bucket."""
+def _compile_paged(one_chip, S, W, nh, hd, PL, P, B, dtype):
     from paddle_tpu.kernels.pallas import paged_attention as kpaged
-
-    S, nh, hd, PL, P, B = 8, 12, 64, 16, 257, 32
 
     def run(q, k, v, tables, pos):
         return kpaged.paged_attention(q, k, v, tables, pos, impl="pallas")
@@ -147,6 +141,24 @@ def test_paged_attention(one_chip, W, dtype):
              ((S, W, nh, hd), dtype), ((P, PL, nh, hd), dtype),
              ((P, PL, nh, hd), dtype), ((S, B), jnp.int32),
              ((S, W), jnp.int32), names=("pt_paged_attention",))
+
+
+@pytest.mark.parametrize("W", [1, 5, 64])
+@pytest.mark.parametrize("dtype", [jnp.float32, BF16])
+def test_paged_attention(one_chip, W, dtype):
+    """GPT-2-small heads (12 x 64), page_len 16: decode (W=1), the
+    speculative verify window (W=spec_tokens+1) and a prefill bucket."""
+    _compile_paged(one_chip, S=8, W=W, nh=12, hd=64, PL=16, P=257, B=32,
+                   dtype=dtype)
+
+
+@pytest.mark.parametrize("W", [64, 128, 256])
+def test_paged_attention_one_row_prefill(one_chip, W):
+    """The prefill's call: ONE row (an admission serves one request) at
+    GPT-2-large heads (20 x 64) against the serve cell's pool (1153 pages
+    of 16 tokens, 64 blocks a row), every prefill bucket."""
+    _compile_paged(one_chip, S=1, W=W, nh=20, hd=64, PL=16, P=1153, B=64,
+                   dtype=BF16)
 
 
 def test_moe_routing_dispatch(one_chip, monkeypatch):

@@ -337,6 +337,238 @@ def test_paged_admission_pool_capacity_bounds(tiny_lm):
         alloc.check()
 
 
+# -- the one-row prefill (ISSUE 26) ---------------------------------------------
+
+@pytest.mark.parametrize("attend", ["composed", "interpret"])
+@pytest.mark.parametrize("start", [0, 8], ids=["cold", "prefix_suffix"])
+def test_one_row_prefill_matches_all_slots_program(start, attend,
+                                                   monkeypatch):
+    """A prompt prefilled through the ONE-ROW window program gives the
+    same first token, the same logprob and the same page contents as the
+    all-slots program with the other rows zero — for a cold prompt and for
+    a prefix-cache suffix (``start > 0``: the first block is somebody's
+    cached page), through the composed attention and through the Pallas
+    paged kernel itself (interpreted) at S = 1. Pages that are not in the
+    request's table are bit-identical before and after."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+    from paddle_tpu.serving.generation import (_build_window_step,
+                                               _extract_gpt_params)
+
+    fused = attend == "interpret"
+    if fused:
+        monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
+    paddle.seed(0)
+    cfg = GPTConfig(vocab_size=64, hidden_size=32, num_hidden_layers=2,
+                    num_attention_heads=4, max_position_embeddings=64,
+                    dtype="float32")
+    params = _extract_gpt_params(GPTForCausalLM(cfg))
+    S, B, PL, W, slot = 3, 4, 8, 16, 1
+    P, nh, hd = S * B + 1, 4, cfg.hidden_size // 4
+    keys = jax.random.split(jax.random.key(26), 2 * cfg.num_hidden_layers)
+    arenas = [jax.random.normal(k, (P, PL, nh, hd), jnp.float32) * 0.1
+              for k in keys]
+    k0, v0 = arenas[:2], arenas[2:]       # other requests' live context
+    table = np.array([7, 3, 11, 0], np.int32)   # 3 blocks held, 1 not
+    n = 11                                       # real suffix tokens < W
+    suffix = np.arange(5, 5 + n, dtype=np.int32)
+    tokens = np.zeros((S, W), np.int32)
+    tokens[slot, :n] = suffix
+    lengths = np.zeros(S, np.int32)
+    lengths[slot] = start
+    tables = np.zeros((S, B), np.int32)
+    tables[slot] = table
+    build = lambda rows, tag: _build_window_step(  # noqa: E731
+        cfg, rows, B, PL, W, donate=False, label=f"t26:{tag}", fused=fused)
+    nxt_s, lp_s, k_s, v_s = build(S, "all")(
+        params, k0, v0, jnp.asarray(tables), jnp.asarray(tokens),
+        jnp.asarray(lengths))
+    nxt_1, lp_1, k_1, v_1 = build(1, "one")(
+        params, k0, v0, jnp.asarray(table[None]),
+        jnp.asarray(tokens[slot][None]), jnp.asarray([start], jnp.int32))
+    assert np.asarray(nxt_1).shape == (1, W)
+    # what _admit reads: the argmax and its logprob at the last real token
+    assert int(np.asarray(nxt_1)[0, n - 1]) == \
+        int(np.asarray(nxt_s)[slot, n - 1])
+    np.testing.assert_allclose(np.asarray(lp_1)[0, :n],
+                               np.asarray(lp_s)[slot, :n], atol=1e-5)
+    assert np.array_equal(np.asarray(nxt_1)[0, :n],
+                          np.asarray(nxt_s)[slot, :n])
+    mine = sorted(set(table.tolist()) - {0})
+    others = [pg for pg in range(1, P) if pg not in mine]
+    for new_1, new_s, old in zip(k_1 + v_1, k_s + v_s, k0 + v0):
+        new_1, new_s, old = map(np.asarray, (new_1, new_s, old))
+        np.testing.assert_allclose(new_1[mine], new_s[mine], atol=1e-6)
+        assert np.array_equal(new_1[others], old[others])
+        # the suffix really was written (not a vacuous comparison)
+        assert not np.array_equal(new_1[mine], old[mine])
+
+
+def test_admission_mid_decode_leaves_running_streams_unchanged(paged_engine):
+    """While other slots are mid-decode, an admission's one-row prefill
+    touches neither their pages nor their next tokens: every request's
+    greedy output is token-for-token what the same request gives alone."""
+    eng, _model, pattern = paged_engine
+    jobs = {"a": (pattern[:13], 14), "b": (pattern[3:12], 6),
+            "c": (pattern[1:20], 5)}
+    alone = {k: eng.submit(p.astype("int64"), max_new_tokens=m).result(
+        timeout=300).tolist() for k, (p, m) in jobs.items()}
+    futs, seen = {}, []
+
+    def admit_others(_tok):
+        # from the worker's own thread, between two of a's decode rounds
+        seen.append(1)
+        for k, at in (("b", 3), ("c", 6)):
+            if len(seen) == at:
+                p, m = jobs[k]
+                futs[k] = eng.submit(p.astype("int64"), max_new_tokens=m)
+
+    before = _counters(eng)
+    p, m = jobs["a"]
+    futs["a"] = eng.submit(p.astype("int64"), max_new_tokens=m,
+                           on_token=admit_others)
+    got_a = futs["a"].result(timeout=300).tolist()
+    assert set(futs) == {"a", "b", "c"}
+    assert got_a == alone["a"]
+    for k in ("b", "c"):
+        assert futs[k].result(timeout=300).tolist() == alone[k], k
+    after = _counters(eng)
+    assert after("prefills_total") - before("prefills_total") == 3
+    # b and c really joined while a was decoding: fewer rounds than the
+    # three would take one after the other
+    assert after("decode_steps") - before("decode_steps") < \
+        sum(m for _p, m in jobs.values()) - 3
+
+
+def _count_backend_compiles():
+    """(counter list, unregister): every XLA backend compile in this
+    process appends to the list — what the benchmark calls a compile
+    inside the window."""
+    import jax
+    from jax._src import monitoring
+
+    hits = []
+
+    def on(event, _secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            hits.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    return hits, lambda: monitoring.unregister_event_duration_listener(on)
+
+
+@pytest.mark.parametrize("draft", [False, True], ids=["plain", "draft"])
+def test_warmup_compiles_every_program_a_window_calls(tiny_lm, tmp_path,
+                                                      draft):
+    """After ``warmup()`` a mixed run over every bucket (cold prefills,
+    prefix-cache suffixes, decode, and verify with a draft model) records
+    zero retrace events, zero compile-cache misses and zero XLA compiles,
+    and the engine holds exactly {(S, 1)} ∪ {(1, bucket)} — plus
+    (S, k + 1) with a draft model: no all-slots prefill program exists."""
+    from paddle_tpu.jit import persistent_cache as pc
+    from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+    import paddle_tpu.analysis as A
+
+    model, pattern = tiny_lm
+    extra = {}
+    if draft:
+        paddle.seed(2)
+        extra = dict(spec_tokens=3, draft_model=GPTForCausalLM(GPTConfig(
+            vocab_size=32, hidden_size=16, num_hidden_layers=1,
+            num_attention_heads=2, max_position_embeddings=64,
+            dtype="float32")))
+    old_dir, old_enabled = pc.cache_dir(), pc.is_enabled()
+    pc.enable(str(tmp_path / "cache"))
+    pc.reset_stats()
+    os.environ["PT_RETRACE_AUDIT"] = "1"
+    A.retrace.enable()
+    compiles, unregister = _count_backend_compiles()
+    try:
+        name = "warmgen_draft" if draft else "warmgen"
+        eng = serving.GenerationEngine(
+            model, serving.GenerationConfig(max_slots=2, max_seq_len=32,
+                                            page_len=8,
+                                            prefill_buckets=(8, 16, 24),
+                                            **extra), name=name)
+        eng.warmup()
+        S = eng.config.max_slots
+        want = {(S, 1), (1, 8), (1, 16), (1, 24)} | \
+            ({(S, 4)} if draft else set())
+        assert set(eng._windows) == want
+        labels = {k for k in pc.stats()["by_label"]
+                  if k.startswith(f"serving:{name}:")}
+        assert {f"serving:{name}:window1", f"serving:{name}:prefill8",
+                f"serving:{name}:prefill16",
+                f"serving:{name}:prefill24"} <= labels
+        assert not any(":window8" in k or ":window16" in k
+                       or ":window24" in k for k in labels)
+        warm, n_compiles = pc.stats(), len(compiles)
+        assert n_compiles > 0           # the listener does hear compiles
+        # (the draft's one insert label sees its three bucket shapes IN
+        # warmup; the window and prefill labels see one shape each, ever)
+        retraced = eng.retrace_events()
+        assert retraced == (2 if draft else 0)
+        with eng:
+            # suffix lengths 5 / 11 / 19 -> buckets 8 / 16 / 24; the
+            # repeats hit the prefix cache and prefill a suffix at start>0
+            futs = [eng.submit(pattern[o:o + n].astype("int64"),
+                               max_new_tokens=3 + (i % 4))
+                    for i, (o, n) in enumerate(
+                        [(0, 5), (0, 11), (0, 19), (0, 19), (8, 11),
+                         (1, 19), (0, 11), (2, 5)])]
+            done, _ = fwait(futs, timeout=300)
+            assert len(done) == len(futs)
+            for f in futs:
+                f.result()
+            stats = eng.stats()
+        assert stats["counters"]["prefix_hits"] >= 1
+        assert set(eng._windows) == want
+        assert stats["retrace_events"] == retraced, stats
+        run = pc.stats()
+        assert run["misses"] == warm["misses"]
+        assert run["by_label"] == warm["by_label"]  # not even a lookup
+        assert len(compiles) == n_compiles, compiles[n_compiles:]
+    finally:
+        unregister()
+        A.retrace.disable()
+        A.retrace.reset()
+        os.environ.pop("PT_RETRACE_AUDIT", None)
+        pc.disable()
+        pc.reset_stats()
+        if old_enabled and old_dir:
+            pc.enable(old_dir)
+
+
+def test_prefill_window_tokens_counter_and_fill_rate(tiny_lm):
+    """``prefill_window_tokens_total`` grows by 1 x W per admission (the
+    prefill program has ONE row) and ``prefill_fill_rate`` is the share of
+    those token-rows that held a real, uncached prompt token."""
+    model, pattern = tiny_lm
+    eng = serving.GenerationEngine(
+        model, serving.GenerationConfig(max_slots=2, max_seq_len=32,
+                                        page_len=8,
+                                        prefill_buckets=(8, 16, 24)),
+        name="fillgen")
+    assert eng.stats()["prefill_fill_rate"] == 0.0    # nothing prefilled
+    with eng:
+        grew = []
+        for n in (5, 19, 19):     # cold W=8, cold W=24, 16 cached -> W=8
+            before = eng.metrics.counter("prefill_window_tokens_total")
+            eng.submit(pattern[:n].astype("int64"),
+                       max_new_tokens=2).result(timeout=300)
+            grew.append(
+                eng.metrics.counter("prefill_window_tokens_total") - before)
+        stats = eng.stats()
+    assert grew == [8, 24, 8]
+    c = stats["counters"]
+    assert c["prompt_tokens_total"] == 43 and c["prefix_hit_tokens"] == 16
+    assert stats["prefill_fill_rate"] == round((43 - 16) / 40, 4)
+    # the all-slots prefill would have run max_slots x W token-rows
+    assert c["prefill_window_tokens_total"] == 40
+
+
 # -- speculative decoding -----------------------------------------------------
 
 @pytest.fixture(scope="module")
