@@ -15,11 +15,15 @@ registers both through ``kernels.registry``:
   with gather-only VJPs, feeding ``kernels.grouped_matmul``;
 - ``paged_attention``: decode/window attention straight against the
   ``serving.paged_kv`` page table (per-page online softmax) instead of
-  gather-then-attend.
+  gather-then-attend;
+- ``ssm_step``: one step of the Mamba-2 state-space recurrence over a
+  slot-indexed state arena, the state read and written once, in place
+  (the decode half of a recurrent model behind ``GenerationEngine``).
 
 Import order matters only in that importing this package populates the
 registry; call sites go through ``kernels.registry.resolve``.
 """
-from . import moe_dispatch, paged_attention, rmsnorm, rope  # noqa: F401
+from . import (moe_dispatch, paged_attention, rmsnorm, rope,  # noqa: F401
+               ssm_step)
 
-__all__ = ["rmsnorm", "rope", "moe_dispatch", "paged_attention"]
+__all__ = ["rmsnorm", "rope", "moe_dispatch", "paged_attention", "ssm_step"]

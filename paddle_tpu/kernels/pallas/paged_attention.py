@@ -43,16 +43,17 @@ _NEG = -1e30
 
 
 def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
-                  acc_ref, m_ref, l_ref, *, nh, kvh, PL, scale):
-    """One (slot, page) grid step. Blocks: ``pos`` [1, W, 1] (a column,
+                  acc_ref, m_ref, l_ref, *, kvh, PL, scale):
+    """One (slot, page) grid step. Blocks: ``pos`` [1, R, 1] (a column,
     so the visibility mask is a plain broadcast against the key iota),
-    ``q``/``o`` [1, nh, W, hd] (head-major: each head's rows are one
-    aligned [W, hd] slab), ``k``/``v`` [1, PL, kvh, hd] (the arena's own
-    layout — one page DMA'd per step, head ``g`` read as a strided
-    slab)."""
+    ``q``/``o`` [1, kvh, R, hd] (K/V-head-major: the R = rep x W query
+    rows that share K/V head ``g`` — its ``rep`` query heads, W window
+    tokens each — are one aligned [R, hd] slab, so a grouped-query page
+    costs ``kvh`` matmuls, not ``nh``), ``k``/``v`` [1, PL, kvh, hd] (the
+    arena's own layout — one page DMA'd per step, head ``g`` read as a
+    strided slab)."""
     del tbl_ref  # consumed by the index maps
     b = pl.program_id(1)
-    rep = nh // kvh
 
     @pl.when(b == 0)
     def _():
@@ -60,15 +61,14 @@ def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = jnp.full_like(m_ref, _NEG)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    qpos = pos_ref[0]                                        # [W, 1] int32
+    qpos = pos_ref[0]                                        # [R, 1] int32
     kpos = b * PL + jax.lax.broadcasted_iota(jnp.int32, (1, PL), 1)
-    visible = kpos <= qpos                                   # [W, PL]
+    visible = kpos <= qpos                                   # [R, PL]
 
-    for h in range(nh):
-        g = h // rep
-        q = q_ref[0, h]                                      # [W, hd]
-        k = k_ref[0, :, g, :]                                # [PL, hd]
-        v = v_ref[0, :, g, :]
+    for h in range(kvh):
+        q = q_ref[0, h]                                      # [R, hd]
+        k = k_ref[0, :, h, :]                                # [PL, hd]
+        v = v_ref[0, :, h, :]
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         s = jnp.where(visible, s, _NEG)
@@ -85,7 +85,7 @@ def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(b == pl.num_programs(1) - 1)
     def _():
-        for h in range(nh):
+        for h in range(kvh):
             l = jnp.maximum(l_ref[h, :, :1], 1e-30)
             o_ref[0, h] = (acc_ref[h] / l).astype(o_ref.dtype)
 
@@ -94,9 +94,16 @@ def _paged_pallas(q, k_arena, v_arena, tables, pos, scale, interpret):
     S, W, nh, hd = q.shape
     P, PL, kvh, _ = k_arena.shape
     B = tables.shape[1]
+    rep = nh // kvh
+    R = rep * W  # query rows that share one K/V head
+    # the rep query heads of a K/V head ride together, [S, W, kvh, rep, hd]
+    # -> [S, kvh, rep * W, hd]; every one of the rep copies of a window
+    # token sees the same positions (rep = 1: a plain head-major swap)
+    qh = q.reshape(S, W, kvh, rep, hd).transpose(0, 2, 3, 1, 4) \
+        .reshape(S, kvh, R, hd)
+    qpos = jnp.tile(pos, (1, rep))[:, :, None]
     out = pl.pallas_call(
-        functools.partial(_paged_kernel, nh=nh, kvh=kvh, PL=PL,
-                          scale=scale),
+        functools.partial(_paged_kernel, kvh=kvh, PL=PL, scale=scale),
         name="pt_paged_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
@@ -105,25 +112,26 @@ def _paged_pallas(q, k_arena, v_arena, tables, pos, scale, interpret):
                 # pos rides as [S, W, 1]: a (1, W) block of [S, W] is not
                 # a tile the TPU lowering accepts; (W, 1) equals the
                 # array's last two dims and lands as a column vector
-                pl.BlockSpec((1, W, 1), lambda s, b, t: (s, 0, 0)),
-                pl.BlockSpec((1, nh, W, hd), lambda s, b, t: (s, 0, 0, 0)),
+                pl.BlockSpec((1, R, 1), lambda s, b, t: (s, 0, 0)),
+                pl.BlockSpec((1, kvh, R, hd), lambda s, b, t: (s, 0, 0, 0)),
                 pl.BlockSpec((1, PL, kvh, hd),
                              lambda s, b, t: (t[s, b], 0, 0, 0)),
                 pl.BlockSpec((1, PL, kvh, hd),
                              lambda s, b, t: (t[s, b], 0, 0, 0)),
             ],
-            out_specs=pl.BlockSpec((1, nh, W, hd),
+            out_specs=pl.BlockSpec((1, kvh, R, hd),
                                    lambda s, b, t: (s, 0, 0, 0)),
             scratch_shapes=[
-                pltpu.VMEM((nh, W, hd), jnp.float32),
-                pltpu.VMEM((nh, W, 128), jnp.float32),
-                pltpu.VMEM((nh, W, 128), jnp.float32),
+                pltpu.VMEM((kvh, R, hd), jnp.float32),
+                pltpu.VMEM((kvh, R, 128), jnp.float32),
+                pltpu.VMEM((kvh, R, 128), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((S, nh, W, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, kvh, R, hd), q.dtype),
         interpret=interpret,
-    )(tables, pos[:, :, None], jnp.swapaxes(q, 1, 2), k_arena, v_arena)
-    return jnp.swapaxes(out, 1, 2)
+    )(tables, qpos, qh, k_arena, v_arena)
+    return out.reshape(S, kvh, rep, W, hd).transpose(0, 3, 1, 2, 4) \
+        .reshape(S, W, nh, hd)
 
 
 def _paged_composed(q, k_arena, v_arena, tables, pos, scale):

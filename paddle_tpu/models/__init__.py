@@ -14,3 +14,6 @@ from .bert import (  # noqa: F401
 from .dit import (  # noqa: F401
     DiTConfig, DiT, DiTBlock, GaussianDiffusion,
 )
+from .falcon_h1 import (  # noqa: F401
+    FalconH1Config, FalconH1ForCausalLM, FalconH1Block,
+)

@@ -181,6 +181,12 @@ class GPTForCausalLM(nn.Layer):
                                     lab1, chunk=2048, ignore_index=-100)
         return hidden.matmul(manipulation.transpose(w, [1, 0]))
 
+    def served_model(self):
+        """This model on ``serving.GenerationEngine``'s seam."""
+        from ..serving.served_model import GPTServed
+
+        return GPTServed(self.config)
+
     def generate(self, input_ids, max_new_tokens=16, use_cache=True):
         """Greedy decode. With use_cache the prefill runs once and each new
         token reuses the per-layer KV cache (O(1) attention reads per step)."""

@@ -43,6 +43,8 @@ from .base import (BadRequest, DeadlineExceeded, EngineBase, EngineClosed,
                    _oom_guard, _tracer)
 from .paged_kv import (HostPagePool, PagedKVPool, PoolExhausted,
                        token_blocks)
+from .served_model import (GPTServed, ServedModel, flatten_params,
+                           nest_params)
 from .speculative import greedy_accept
 
 __all__ = ["GenerationConfig", "GenerationEngine", "flatten_gpt_params",
@@ -146,111 +148,64 @@ class _Slot:
         self.shared = 0   # leading entries borrowed from the prefix cache
 
 
+def _served(model_or_cfg) -> ServedModel:
+    """The protocol object of a model — or of a bare GPT config, which is
+    what the callers that predate the seam (tests, ``bench.py``, the AOT
+    rehearsal) hand the program builders."""
+    if isinstance(model_or_cfg, ServedModel):
+        return model_or_cfg
+    if hasattr(model_or_cfg, "served_model"):
+        return model_or_cfg.served_model()
+    return GPTServed(model_or_cfg)
+
+
 def _extract_gpt_params(model):
-    """Read the live weights of a ``GPTForCausalLM`` as a jax pytree (the
-    decode step closes over nothing — set_state_dict + a new engine picks
-    up new weights)."""
-    g = model.gpt
-
-    def a(t):
-        return t.data
-
-    return {
-        "embed": a(g.embed_tokens.weight),          # [vocab, h]
-        "pos": a(g.embed_positions.weight),         # [P, h]
-        "lnf_w": a(g.ln_f.weight), "lnf_b": a(g.ln_f.bias),
-        "layers": [
-            {"ln1_w": a(L.ln_1.weight), "ln1_b": a(L.ln_1.bias),
-             "qkv_w": a(L.attn.qkv_proj.weight),
-             "qkv_b": a(L.attn.qkv_proj.bias),
-             "out_w": a(L.attn.out_proj.weight),
-             "out_b": a(L.attn.out_proj.bias),
-             "ln2_w": a(L.ln_2.weight), "ln2_b": a(L.ln_2.bias),
-             "fc_in_w": a(L.fc_in.weight), "fc_in_b": a(L.fc_in.bias),
-             "fc_out_w": a(L.fc_out.weight), "fc_out_b": a(L.fc_out.bias)}
-            for L in g.layers],
-    }
+    """The live weights of a ``GPTForCausalLM`` as the engine's pytree
+    (``GPTServed.params``; kept under its old name for its callers)."""
+    return GPTServed(model.config).params(model)
 
 
-def flatten_gpt_params(tree) -> Dict[str, Any]:
-    """Flatten the engine param pytree to ``{dotted_name: array}`` — the
-    wire shape the post-training weight service streams (stable names,
-    no nesting to re-derive on the far side)."""
-    flat = {"embed": tree["embed"], "pos": tree["pos"],
-            "lnf_w": tree["lnf_w"], "lnf_b": tree["lnf_b"]}
-    for i, L in enumerate(tree["layers"]):
-        for k, v in L.items():
-            flat[f"layers.{i}.{k}"] = v
-    return flat
-
-
-def nest_gpt_params(flat) -> Dict[str, Any]:
-    """Inverse of :func:`flatten_gpt_params`."""
-    tree: Dict[str, Any] = {"layers": []}
-    layers: Dict[int, Dict[str, Any]] = {}
-    for name, v in flat.items():
-        if name.startswith("layers."):
-            _, idx, key = name.split(".", 2)
-            layers.setdefault(int(idx), {})[key] = v
-        else:
-            tree[name] = v
-    for i in sorted(layers):
-        if i != len(tree["layers"]):
-            raise ValueError(f"non-contiguous layer index {i}")
-        tree["layers"].append(layers[i])
-    return tree
+flatten_gpt_params = flatten_params
+nest_gpt_params = nest_params
 
 
 def _build_decode_step(cfg, max_slots: int, max_len: int, donate: bool,
                        label: str):
-    """One fixed-shape SLOT-ARENA executable: token+position embed,
-    per-layer pre-LN attention against ``[S, max_len, nh, hd]`` caches
-    (length-masked), MLP, tied head, greedy argmax. The draft model's
-    decode path — small enough that a dense per-slot arena beats paging
-    overhead. Cache buffers are donated so XLA updates in place."""
+    """One fixed-shape SLOT-ARENA executable: the served model's embed,
+    blocks and head at one token a slot, attending against dense
+    ``[S, max_len, nh, hd]`` caches (length-masked), greedy argmax. The
+    draft model's decode path — small enough that a dense per-slot arena
+    beats paging overhead. Cache buffers are donated so XLA updates in
+    place."""
     import jax
     import jax.numpy as jnp
 
-    nh = cfg.num_attention_heads
-    hd = cfg.hidden_size // nh
-    eps = cfg.layer_norm_epsilon
-    scale = 1.0 / math.sqrt(hd)
-
-    def ln(x, w, b):
-        mean = jnp.mean(x, axis=-1, keepdims=True)
-        var = jnp.var(x, axis=-1, keepdims=True)
-        return (x - mean) * jax.lax.rsqrt(var + eps) * w + b
+    sm = _served(cfg)
+    scale = sm.attn_scale
 
     def step(params, k_caches, v_caches, tokens, lengths):
         # tokens/lengths: [slots] int32; caches: per-layer [S, max_len, nh, hd]
         S = max_slots
-        pos_idx = jnp.minimum(lengths, params["pos"].shape[0] - 1)
-        x = params["embed"][tokens] + params["pos"][pos_idx]        # [S, h]
+        x = sm.embed(params, tokens[:, None], lengths[:, None])    # [S, 1, h]
         pos = jnp.arange(max_len)
         mask = pos[None, :] <= lengths[:, None]                    # [S, L]
         slot_idx = jnp.arange(S)
         wr = jnp.minimum(lengths, max_len - 1)
         new_k, new_v = [], []
         for p, kc, vc in zip(params["layers"], k_caches, v_caches):
-            h1 = ln(x, p["ln1_w"], p["ln1_b"])
-            qkv = (h1 @ p["qkv_w"] + p["qkv_b"]).reshape(S, 3, nh, hd)
-            q, k1, v1 = qkv[:, 0], qkv[:, 1], qkv[:, 2]
-            kc = kc.at[slot_idx, wr].set(k1)
-            vc = vc.at[slot_idx, wr].set(v1)
-            logits = jnp.einsum("shd,sLhd->shL", q, kc)
-            logits = logits.astype(jnp.float32) * scale
-            logits = jnp.where(mask[:, None, :], logits, -1e30)
-            probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-            ctx = jnp.einsum("shL,sLhd->shd", probs, vc).reshape(S, nh * hd)
-            x = x + (ctx @ p["out_w"] + p["out_b"])
-            h2 = ln(x, p["ln2_w"], p["ln2_b"])
-            m = jax.nn.gelu(h2 @ p["fc_in_w"] + p["fc_in_b"],
-                            approximate=True)
-            x = x + (m @ p["fc_out_w"] + p["fc_out_b"])
-            new_k.append(kc)
-            new_v.append(vc)
-        xf = ln(x, params["lnf_w"], params["lnf_b"])
-        logits = xf @ params["embed"].T                            # [S, vocab]
+            def attend(q, k1, v1):
+                kk = kc.at[slot_idx, wr].set(k1[:, 0])
+                vv = vc.at[slot_idx, wr].set(v1[:, 0])
+                new_k.append(kk)
+                new_v.append(vv)
+                logits = jnp.einsum("shd,sLhd->shL", q[:, 0], kk)
+                logits = logits.astype(jnp.float32) * scale
+                logits = jnp.where(mask[:, None, :], logits, -1e30)
+                probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+                return jnp.einsum("shL,sLhd->shd", probs, vv)[:, None]
+
+            x, _ = sm.block(p, x, lengths[:, None], attend, None, None)
+        logits = sm.head(params, x)[:, 0]                          # [S, vocab]
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return nxt, new_k, new_v
 
@@ -260,13 +215,14 @@ def _build_decode_step(cfg, max_slots: int, max_len: int, donate: bool,
         step, donate_argnums=(1, 2) if donate else (), label=label)
 
 
-def _build_window_step(cfg, max_slots: int, n_blocks: int, page_len: int,
+def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
                        window: int, donate: bool, label: str,
-                       fused: bool = False):
+                       fused: bool = False, prefill: bool = False):
     """The PAGED executable family: embed ``W = window`` tokens per slot
-    at positions ``lengths + [0..W)``, write their K/V through the page
-    tables into the pool arenas, attend each window token causally against
-    the page pool, and return the greedy argmax at every window position.
+    at positions ``lengths + [0..W)``, run the served model's blocks — each
+    block's ``attend(q, k, v)`` writes K/V through the page tables into the
+    pool arenas and attends each window token causally against the page
+    pool — and return the greedy argmax at every window position.
 
     One body serves three roles, at two row counts. At ``max_slots`` rows,
     W=1 is the decode step and W=k+1 scores a draft model's k proposals
@@ -277,6 +233,20 @@ def _build_window_step(cfg, max_slots: int, n_blocks: int, page_len: int,
     touches that request's pages only. The arenas are the whole pool at
     either row count.
 
+    ``step(params, k_arenas, v_arenas, tables, tokens, lengths,
+    n_valid=None, state=None)`` returns ``(next, logprob, k_arenas,
+    v_arenas, state)``. ``n_valid`` (``[rows]``: real tokens in each row's
+    window) gives the blocks ``valid = arange(W) < n_valid``. A ``prefill``
+    program (one fresh sequence a row, ``n_valid`` required) computes the
+    head at the last real position only (``[rows, 1]`` outputs): an
+    admission reads nothing else, and a 256 x 261120 float32 logits tensor
+    is 267 MB. A model that declares recurrent state
+    (``served.state_spec``) gets ``state``: ``None`` lets every block
+    start from zero (a prefill, which returns the rows' FINAL state for
+    the engine to install); the per-layer slot arenas (the decode program:
+    donated like the K/V arenas) are advanced one step and returned
+    updated, in place. A model without state gets and returns ``None``.
+
     ``fused=True`` (registry-gated: ``FLAGS_fused_kernels``) attends
     straight against the page table through the Pallas paged-attention
     kernel — the dense ``kc[tables]`` gathered context never
@@ -286,27 +256,34 @@ def _build_window_step(cfg, max_slots: int, n_blocks: int, page_len: int,
     import jax
     import jax.numpy as jnp
 
-    if fused:
-        from ..kernels.pallas.paged_attention import paged_attention
-
-    nh = cfg.num_attention_heads
-    hd = cfg.hidden_size // nh
-    eps = cfg.layer_norm_epsilon
-    scale = 1.0 / math.sqrt(hd)
+    sm = _served(served)
+    nh, kvh, hd = sm.num_heads, sm.num_kv_heads, sm.head_dim
+    scale = sm.attn_scale
+    stateful = sm.state_spec is not None
+    if stateful and not prefill and window != 1:
+        raise ValueError(
+            "a model with recurrent state decodes one token a round: "
+            f"no {window}-token window over live state")
     S, B, W, PL = max_slots, n_blocks, window, page_len
     L = B * PL  # gathered context length per slot
 
-    def ln(x, w, b):
-        mean = jnp.mean(x, axis=-1, keepdims=True)
-        var = jnp.var(x, axis=-1, keepdims=True)
-        return (x - mean) * jax.lax.rsqrt(var + eps) * w + b
+    if fused:
+        from ..kernels.pallas.paged_attention import paged_attention
 
-    def step(params, k_arenas, v_arenas, tables, tokens, lengths):
+        # ONE jitted callable for every layer: the kernel is traced and
+        # lowered once a program and called L times, not traced L times
+        # (the 36 kernel traces of a GPT-2-large program were most of
+        # warmup's time, PERF.md section 6, PR 28); XLA inlines the calls
+        @jax.jit
+        def paged_attend(q, kk, vv, tables, pos):
+            return paged_attention(q, kk, vv, tables, pos, scale=scale)
+
+    def step(params, k_arenas, v_arenas, tables, tokens, lengths,
+             n_valid=None, state=None):
         # tables: [S, B] page ids; tokens: [S, W]; lengths: [S] (int32)
         P = k_arenas[0].shape[0]
         pos = lengths[:, None] + jnp.arange(W)                     # [S, W]
-        pos_idx = jnp.minimum(pos, params["pos"].shape[0] - 1)
-        x = params["embed"][tokens] + params["pos"][pos_idx]       # [S, W, h]
+        x = sm.embed(params, tokens, pos)                          # [S, W, h]
         j = jnp.arange(L)
         mask = j[None, None, :] <= pos[:, :, None]                 # [S, W, L]
         # write positions: page-table lookup of each window token's block;
@@ -316,41 +293,45 @@ def _build_window_step(cfg, max_slots: int, n_blocks: int, page_len: int,
         pidx = jnp.take_along_axis(tables, jnp.minimum(blk, B - 1), axis=1)
         pidx = jnp.where(blk < B, pidx, 0)                         # [S, W]
         flat = (pidx * PL + pos % PL).reshape(-1)                  # [S*W]
-        new_k, new_v = [], []
-        for p, kc, vc in zip(params["layers"], k_arenas, v_arenas):
-            h1 = ln(x, p["ln1_w"], p["ln1_b"])
-            qkv = (h1 @ p["qkv_w"] + p["qkv_b"]).reshape(S, W, 3, nh, hd)
-            q, k1, v1 = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            kc = kc.reshape(P * PL, nh, hd).at[flat].set(
-                k1.reshape(S * W, nh, hd)).reshape(P, PL, nh, hd)
-            vc = vc.reshape(P * PL, nh, hd).at[flat].set(
-                v1.reshape(S * W, nh, hd)).reshape(P, PL, nh, hd)
-            if fused:
-                # attend against the page table directly (per-page online
-                # softmax); key j visible iff j <= pos[s, w] — the same
-                # containment the composed mask enforces
-                # impl resolves through the registry: Pallas on TPU, the
-                # composed twin on CPU, interpreter under
-                # PT_PALLAS_INTERPRET=1 (parity tests)
-                ctx = paged_attention(q, kc, vc, tables, pos, scale=scale)
-            else:
-                kk = kc[tables].reshape(S, L, nh, hd)
-                vv = vc[tables].reshape(S, L, nh, hd)
-                logits = jnp.einsum("swhd,sLhd->swhL", q, kk)
+        valid = None if n_valid is None else \
+            jnp.arange(W)[None, :] < n_valid[:, None]              # [S, W]
+        new_k, new_v, new_state = [], [], []
+        for li, (p, kc, vc) in enumerate(zip(params["layers"], k_arenas,
+                                             v_arenas)):
+            def attend(q, k1, v1):
+                kk = kc.reshape(P * PL, kvh, hd).at[flat].set(
+                    k1.reshape(S * W, kvh, hd)).reshape(P, PL, kvh, hd)
+                vv = vc.reshape(P * PL, kvh, hd).at[flat].set(
+                    v1.reshape(S * W, kvh, hd)).reshape(P, PL, kvh, hd)
+                new_k.append(kk)
+                new_v.append(vv)
+                if fused:
+                    # attend against the page table directly (per-page
+                    # online softmax); key j visible iff j <= pos[s, w] —
+                    # the same containment the composed mask enforces
+                    # impl resolves through the registry: Pallas on TPU,
+                    # the composed twin on CPU, interpreter under
+                    # PT_PALLAS_INTERPRET=1 (parity tests)
+                    return paged_attend(q, kk, vv, tables, pos)
+                gk = kk[tables].reshape(S, L, kvh, hd)
+                gv = vv[tables].reshape(S, L, kvh, hd)
+                if kvh != nh:
+                    gk = jnp.repeat(gk, nh // kvh, axis=2)
+                    gv = jnp.repeat(gv, nh // kvh, axis=2)
+                logits = jnp.einsum("swhd,sLhd->swhL", q, gk)
                 logits = logits.astype(jnp.float32) * scale
                 logits = jnp.where(mask[:, :, None, :], logits, -1e30)
-                probs = jax.nn.softmax(logits, axis=-1).astype(x.dtype)
-                ctx = jnp.einsum("swhL,sLhd->swhd", probs, vv)
-            ctx = ctx.reshape(S, W, nh * hd)
-            x = x + (ctx @ p["out_w"] + p["out_b"])
-            h2 = ln(x, p["ln2_w"], p["ln2_b"])
-            m = jax.nn.gelu(h2 @ p["fc_in_w"] + p["fc_in_b"],
-                            approximate=True)
-            x = x + (m @ p["fc_out_w"] + p["fc_out_b"])
-            new_k.append(kc)
-            new_v.append(vc)
-        xf = ln(x, params["lnf_w"], params["lnf_b"])
-        logits = xf @ params["embed"].T                        # [S, W, vocab]
+                probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
+                return jnp.einsum("swhL,sLhd->swhd", probs, gv)
+
+            x, st = sm.block(p, x, pos, attend,
+                             None if state is None else state[li], valid)
+            new_state.append(st)
+        if prefill:
+            # the head at the last real position only
+            last = jnp.maximum(n_valid - 1, 0)[:, None, None]
+            x = jnp.take_along_axis(x, last, axis=1)               # [S, 1, h]
+        logits = sm.head(params, x)                            # [S, W, vocab]
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         # behavior logprob of the greedy pick at every window position —
         # the post-training ledger rides it (f32: bf16 logits renormalize
@@ -358,16 +339,20 @@ def _build_window_step(cfg, max_slots: int, n_blocks: int, page_len: int,
         lf = logits.astype(jnp.float32)
         logp = (jnp.max(lf, axis=-1) -
                 jax.scipy.special.logsumexp(lf, axis=-1))      # [S, W] f32
-        return nxt, logp, new_k, new_v
+        return nxt, logp, new_k, new_v, new_state if stateful else None
+
+    donate_argnums = (1, 2, 7) if stateful else (1, 2)
 
     from ..jit import persistent_cache
 
     return persistent_cache.cached_jit(
-        step, donate_argnums=(1, 2) if donate else (), label=label)
+        step, donate_argnums=donate_argnums if donate else (), label=label)
 
 
 class GenerationEngine(EngineBase):
-    """Continuous-batching generation server over a ``GPTForCausalLM``.
+    """Continuous-batching generation server over any causal LM that
+    implements the served-model protocol (``serving.served_model``:
+    ``model.served_model()`` — ``GPTForCausalLM``, ``FalconH1ForCausalLM``).
 
     ::
 
@@ -399,21 +384,35 @@ class GenerationEngine(EngineBase):
 
         model.eval()  # serving semantics: dropout off
         self.model = model
-        mcfg = model.config
-        self.max_len = int(self.config.max_seq_len
-                           or mcfg.max_position_embeddings)
-        if self.max_len > mcfg.max_position_embeddings:
+        sm = self._sm = _served(model)
+        self.max_len = int(self.config.max_seq_len or sm.max_positions)
+        if self.max_len > sm.max_positions:
             raise ValueError(
                 f"max_seq_len {self.max_len} exceeds the model's position "
-                f"table ({mcfg.max_position_embeddings})")
+                f"table ({sm.max_positions})")
+        self._stateful = sm.state_spec is not None
+        if self._stateful:
+            # everything that assumes a cache is pages of K/V is wrong for
+            # a recurrent state: refused in words, never switched off
+            # silently (docs/serving.md, "Recurrent state")
+            if self.config.prefix_cache:
+                raise ValueError(
+                    f"{type(model).__name__} carries recurrent state per "
+                    "slot: a cached K/V prefix has no state to resume "
+                    "from, so the prefix cache cannot serve it — pass "
+                    "GenerationConfig(prefix_cache=False)")
+            if self.config.draft_model is not None:
+                raise ValueError(
+                    f"{type(model).__name__} carries recurrent state per "
+                    "slot: a rejected draft token would have advanced it "
+                    "and it cannot be rolled back, so speculative decoding "
+                    "is refused — pass draft_model=None")
         for b in self.config.prefill_buckets:
             if b > self.max_len:
                 raise ValueError(
                     f"prefill bucket {b} exceeds max_seq_len {self.max_len}")
-        self._params = _extract_gpt_params(model)
+        self._params = sm.params(model)
         dtype = self._params["embed"].dtype
-        nh = mcfg.num_attention_heads
-        hd = mcfg.hidden_size // nh
         S = self.config.max_slots
         pl = self.config.page_len
         self._pl = pl
@@ -427,10 +426,11 @@ class GenerationEngine(EngineBase):
             warm = HostPagePool(
                 capacity_bytes=self.config.warm_pool_bytes,
                 admit_threshold=self.config.warm_admit_threshold)
-        self._pool = PagedKVPool(mcfg.num_hidden_layers, num_pages, pl,
-                                 nh, hd, dtype,
+        self._pool = PagedKVPool(sm.num_layers, num_pages, pl,
+                                 sm.num_kv_heads, sm.head_dim, dtype,
                                  prefix_cache=self.config.prefix_cache,
-                                 warm_pool=warm)
+                                 warm_pool=warm, state_spec=sm.state_spec,
+                                 max_slots=S)
         # cross-thread ops the worker must execute (the allocator and
         # the arenas are worker-owned): (fn, Future) pairs — the KV
         # export/install seam the page shipper rides
@@ -440,9 +440,9 @@ class GenerationEngine(EngineBase):
 
         donate = self.config.donate_cache and jax.default_backend() != "cpu"
         self._donate = donate
-        self._mcfg = mcfg
-        # (rows, W) -> compiled window step
-        self._windows: Dict[Tuple[int, int], Any] = {}
+        self._state_install_fn = None
+        # (rows, W, prefill) -> compiled window step
+        self._windows: Dict[Tuple[int, int, bool], Any] = {}
 
         # -- speculative decoding (draft model) --------------------------------
         self.spec_k = 0
@@ -452,31 +452,34 @@ class GenerationEngine(EngineBase):
 
             dm = self.config.draft_model
             dm.eval()
-            dcfg = dm.config
-            if dcfg.vocab_size != mcfg.vocab_size:
+            dsm = _served(dm)
+            if dsm.state_spec is not None:
                 raise ValueError(
-                    f"draft vocab {dcfg.vocab_size} != target vocab "
-                    f"{mcfg.vocab_size}")
-            if dcfg.max_position_embeddings < self.max_len:
+                    "a draft model with recurrent state is refused: its "
+                    "slot arena is dense K/V only")
+            if dsm.vocab_size != sm.vocab_size:
                 raise ValueError(
-                    f"draft position table ({dcfg.max_position_embeddings}) "
+                    f"draft vocab {dsm.vocab_size} != target vocab "
+                    f"{sm.vocab_size}")
+            if dsm.max_positions < self.max_len:
+                raise ValueError(
+                    f"draft position table ({dsm.max_positions}) "
                     f"shorter than max_seq_len {self.max_len}")
             self.spec_k = max(1, self.config.spec_tokens)
             self._draft = dm
-            self._dparams = _extract_gpt_params(dm)
-            dnh = dcfg.num_attention_heads
-            dhd = dcfg.hidden_size // dnh
+            self._dparams = dsm.params(dm)
             ddtype = self._dparams["embed"].dtype
             dlen = B * pl
-            self._dk = [jnp.zeros((S, dlen, dnh, dhd), ddtype)
-                        for _ in range(dcfg.num_hidden_layers)]
-            self._dv = [jnp.zeros((S, dlen, dnh, dhd), ddtype)
-                        for _ in range(dcfg.num_hidden_layers)]
+            darena = (S, dlen, dsm.num_kv_heads, dsm.head_dim)
+            self._dk = [jnp.zeros(darena, ddtype)
+                        for _ in range(dsm.num_layers)]
+            self._dv = [jnp.zeros(darena, ddtype)
+                        for _ in range(dsm.num_layers)]
             from .. import jit as jit_mod
 
             dlabel = f"serving:{self.name}:draft_decode"
             self._draft_step = jit_mod._maybe_audit(
-                dlabel, _build_decode_step(dcfg, S, dlen, donate,
+                dlabel, _build_decode_step(dsm, S, dlen, donate,
                                            label=dlabel))
             ilabel = f"serving:{self.name}:draft_insert"
             self._dinsert = jit_mod._maybe_audit(
@@ -498,6 +501,9 @@ class GenerationEngine(EngineBase):
 
             register_component(f"serving:{self.name}:kv_pages",
                                type(self)._kv_pool_bytes, owner=self)
+            if self._stateful:
+                register_component(f"serving:{self.name}:state",
+                                   type(self)._state_pool_bytes, owner=self)
         except Exception:
             pass
         # hub families: prefix-cache and speculative-decode truth for the
@@ -528,6 +534,8 @@ class GenerationEngine(EngineBase):
         self._t_start = time.monotonic()
         self.metrics.gauge("slot_occupancy", self.slot_occupancy)
         self.metrics.gauge("kv_headroom", self.kv_headroom)
+        if self._stateful:
+            self.metrics.gauge("state_pool_bytes", self._state_pool_bytes)
         # prefix-cache truth (hits/misses/evictions) rides the snapshot
         # so pd_top / render_snapshot show the warm-tier tuning baseline
         self.metrics.gauge("prefix_cache", self._prefix_cache_stats)
@@ -543,13 +551,14 @@ class GenerationEngine(EngineBase):
         return st
 
     # -- executables ----------------------------------------------------------
-    def _window(self, rows: int, W: int):
+    def _window(self, rows: int, W: int, prefill: bool = False):
         """The compiled window step for ``rows`` rows of ``W`` tokens:
         ``max_slots`` rows for a decode or verify round, ONE row for a
         prefill (an admission serves one request). Built once per pair; the
         pairs come from the closed set {(S, 1), (S, spec_k+1)} ∪
         {(1, bucket)}, so steady state never retraces."""
-        fn = self._windows.get((rows, W))
+        key = (rows, W, prefill)
+        fn = self._windows.get(key)
         if fn is None:
             from .. import jit as jit_mod
             from ..kernels.registry import fused_enabled
@@ -558,15 +567,16 @@ class GenerationEngine(EngineBase):
             # the ":fused" label suffix keeps the retrace audit and the
             # persistent-cache keyspace honest about which path compiled
             fused = fused_enabled("paged_attention")
-            role = "window" if rows == self.config.max_slots else "prefill"
+            role = "prefill" if prefill else "window"
             label = f"serving:{self.name}:{role}{W}" + \
                 (":fused" if fused else "")
             fn = jit_mod._maybe_audit(
-                label, _build_window_step(self._mcfg, rows,
+                label, _build_window_step(self._sm, rows,
                                           self._n_blocks, self._pl, W,
                                           self._donate, label=label,
-                                          fused=fused))
-            self._windows[(rows, W)] = fn
+                                          fused=fused,
+                                          prefill=prefill))
+            self._windows[key] = fn
         return fn
 
     def warmup(self):
@@ -578,15 +588,19 @@ class GenerationEngine(EngineBase):
         import jax.numpy as jnp
 
         S, B = self.config.max_slots, self._n_blocks
-        programs = [(S, 1)] + \
-            ([(S, self.spec_k + 1)] if self.spec_k else []) + \
-            [(1, b) for b in self.config.prefill_buckets]
-        for rows, W in programs:
-            _n, _lp, self._pool.k, self._pool.v = self._window(rows, W)(
-                self._params, self._pool.k, self._pool.v,
-                jnp.zeros((rows, B), jnp.int32),
-                jnp.zeros((rows, W), jnp.int32),
-                jnp.zeros(rows, jnp.int32))
+        programs = [(S, 1, False)] + \
+            ([(S, self.spec_k + 1, False)] if self.spec_k else []) + \
+            [(1, b, True) for b in self.config.prefill_buckets]
+        for rows, W, prefill in programs:
+            # a decode round in which no row is valid; a prefill of one
+            # token, and the install of its row (slot 0 is free)
+            _n, _lp, row = self._run_window(
+                rows, W, jnp.zeros((rows, B), jnp.int32),
+                jnp.zeros((rows, W), jnp.int32), jnp.zeros(rows, jnp.int32),
+                n_valid=np.full(rows, int(prefill), np.int32),
+                prefill=prefill)
+            if row is not None:
+                self._install_state(0, row)
         if self.spec_k:
             zeros = jnp.zeros(S, jnp.int32)
             _n, self._dk, self._dv = self._draft_step(
@@ -598,6 +612,79 @@ class GenerationEngine(EngineBase):
                 self._draft_prefill(0, np.zeros(b, dtype=np.int64))
         self.metrics.inc("warmup_runs")
         return self
+
+    def _run_window(self, rows: int, W: int, tables, tokens, lengths,
+                    n_valid, prefill: bool = False):
+        """Call the ``(rows, W)`` window program on the pool's arenas and
+        rebind what it donates. ``n_valid`` is ``[rows]`` int32, host side.
+        A ``prefill`` returns its outputs at the last real position only
+        and, for a model with recurrent state, starts every layer from
+        zero, stops the recurrence at ``n_valid`` and hands back the row's
+        FINAL state; any other round of such a model advances the state
+        arenas in place. Returns ``(next, logprob, row)``; ``row`` is
+        ``None`` but for that prefill."""
+        import jax.numpy as jnp
+
+        pool, fn = self._pool, self._window(rows, W, prefill)
+        nxt, lp, pool.k, pool.v, state = fn(
+            self._params, pool.k, pool.v, tables, tokens, lengths,
+            jnp.asarray(n_valid), None if prefill else pool.state)
+        if prefill:
+            return nxt, lp, state
+        pool.state = state
+        return nxt, lp, None
+
+    def _state_pool_bytes(self) -> int:
+        """Bytes held by the slot-indexed recurrent-state arenas."""
+        return self._pool.state_bytes()
+
+    def _install_state(self, slot_no: int, row) -> None:
+        """Write one row's final prefill state (per layer, ``[1, ...]``
+        arrays) over slot ``slot_no``'s row of the state arenas, in place:
+        ONE donated program for all layers. The whole row is overwritten,
+        so nothing of the slot's previous tenant survives."""
+        import jax
+
+        fn = self._state_install_fn
+        if fn is None:
+            from .. import jit as jit_mod
+
+            def install(arenas, rows, slot):
+                return jax.tree_util.tree_map(
+                    lambda a, r: jax.lax.dynamic_update_slice(
+                        a, r.astype(a.dtype),
+                        (slot,) + (0,) * (a.ndim - 1)), arenas, rows)
+
+            label = f"serving:{self.name}:state_install"
+            fn = self._state_install_fn = jit_mod._maybe_audit(
+                label, jit_mod.persistent_cache.cached_jit(
+                    install, donate_argnums=(0,) if self._donate else (),
+                    label=label))
+        self._pool.state = fn(self._pool.state, row, np.int32(slot_no))
+
+    def slot_state(self, slot_no: int):
+        """The recurrent state at one slot's row: per layer, ``{name:
+        array}`` (copies on the device). A released slot's row stays as
+        its last tenant left it until the next admission overwrites it, so
+        on an idle or closed engine this is the FINAL state of the last
+        request the slot served — what a check compares with a reference
+        (the worker owns the arenas: do not call it under load)."""
+        if not self._stateful:
+            raise ValueError(f"{type(self.model).__name__} declares no "
+                             "recurrent state")
+        return [{name: arena[slot_no] for name, arena in layer.items()}
+                for layer in self._pool.state]
+
+    def release_caches(self) -> None:
+        """Give both caches' device buffers back (K/V arenas, state arenas,
+        the draft's slot arena). Only a closed engine may: nothing can be
+        served afterwards. For a caller that needs the memory while the
+        model's weights stay — the benchmark's float32 reference."""
+        if not self._closed or self._thread is not None:
+            raise RuntimeError("release_caches: close() the engine first")
+        self._pool.k, self._pool.v, self._pool.state = [], [], None
+        if self.spec_k:
+            self._dk, self._dv = [], []
 
     def _kv_pool_bytes(self) -> int:
         """Bytes held by the paged K/V pool (all layers), plus the draft
@@ -719,14 +806,14 @@ class GenerationEngine(EngineBase):
     # -- in-place weight push (post-training fast path) -----------------------
     def _coerce_swap_state(self, state) -> Dict[str, Any]:
         """Validate an incoming weight set against the live tree and land
-        it device-ready. Accepts a ``GPTForCausalLM``, the nested param
-        pytree, or the flat ``{dotted_name: array}`` wire shape."""
+        it device-ready. Accepts a model of the served class, the nested
+        param pytree, or the flat ``{dotted_name: array}`` wire shape."""
         import jax.numpy as jnp
 
-        if hasattr(state, "gpt"):
-            state = _extract_gpt_params(state)
+        if hasattr(state, "served_model"):
+            state = state.served_model().params(state)
         if "layers" not in state:
-            state = nest_gpt_params(dict(state))
+            state = nest_params(dict(state))
 
         def conv(old, new, path):
             if new is None:
@@ -855,6 +942,13 @@ class GenerationEngine(EngineBase):
                 if not fut.done():
                     fut.set_result(res)
 
+    def _refuse_kv_transfer(self, what: str) -> None:
+        if self._stateful:
+            raise RuntimeError(
+                f"{what}: {type(self.model).__name__} carries recurrent "
+                "state per slot — its K/V pages alone do not resume a "
+                "sequence, and no state snapshot is shipped with them")
+
     def export_kv_pages(self, prompt_ids):
         """Read the cached KV of ``prompt_ids``' full prompt blocks out of
         the page pool as host arrays — the page shipper's source side.
@@ -862,6 +956,7 @@ class GenerationEngine(EngineBase):
         ``[n, page_len, heads, dim]`` stacks. Raises ``KeyError`` when the
         prompt's blocks are not all cached (caller falls back to
         re-prefill)."""
+        self._refuse_kv_transfer("export_kv_pages")
         prompt = np.asarray(prompt_ids).reshape(-1)
         blocks = token_blocks(prompt, self._pl)
 
@@ -894,6 +989,7 @@ class GenerationEngine(EngineBase):
         next submit sharing this prompt prefix reuses the pages instead
         of prefilling. Returns pages newly adopted (blocks already
         cached keep their pages — first writer wins)."""
+        self._refuse_kv_transfer("install_kv_pages")
         prompt = np.asarray(prompt_ids).reshape(-1)
         blocks = token_blocks(prompt, self._pl)
         n = len(blocks)
@@ -1108,13 +1204,20 @@ class GenerationEngine(EngineBase):
                 with _oom_guard("generation",
                                 label=f"serving:{self.name}:prefill",
                                 engine=self.name, bucket=W):
-                    nxt, lp, self._pool.k, self._pool.v = self._window(1, W)(
-                        self._params, self._pool.k, self._pool.v,
-                        jnp.asarray(s.table[None]), jnp.asarray(tokens),
-                        jnp.asarray(np.array([start], dtype=np.int32)))
+                    nxt, lp, row = self._run_window(
+                        1, W, jnp.asarray(s.table[None]),
+                        jnp.asarray(tokens),
+                        jnp.asarray(np.array([start], dtype=np.int32)),
+                        n_valid=np.array([len(suffix)], dtype=np.int32),
+                        prefill=True)
+            if row is not None:
+                with span("pt.serve.state_install", slot=slot_no):
+                    self._install_state(slot_no, row)
+                self.metrics.inc("state_installs_total")
+            # a prefill returns its last real position only
             with span("pt.serve.prefill_sync"):
-                first = int(np.asarray(nxt)[0, len(suffix) - 1])
-                first_lp = float(np.asarray(lp)[0, len(suffix) - 1])
+                first = int(np.asarray(nxt)[0, 0])
+                first_lp = float(np.asarray(lp)[0, 0])
             # draft model prefills the WHOLE prompt through its own forward
             # (the draft is small; its dense slot arena has no prefix cache)
             if self.spec_k:
@@ -1231,13 +1334,16 @@ class GenerationEngine(EngineBase):
                                 jnp.asarray(lengths + j))
                         tokens[:, j + 1] = np.asarray(nd)
                         cur = nd
+                # every active row advances its state one step, in place;
+                # an idle row's n_valid is 0 and its state stays as it is
+                n_valid = np.zeros(S, dtype=np.int32)
+                n_valid[active] = 1
                 with _oom_guard("generation",
                                 label=f"serving:{self.name}:decode",
                                 engine=self.name, step=self._decode_no):
-                    nxt, lp, self._pool.k, self._pool.v = self._window(S, W)(
-                        self._params, self._pool.k, self._pool.v,
-                        jnp.asarray(tables), jnp.asarray(tokens),
-                        jnp.asarray(lengths))
+                    nxt, lp, _row = self._run_window(
+                        S, W, jnp.asarray(tables), jnp.asarray(tokens),
+                        jnp.asarray(lengths), n_valid=n_valid)
             with span("pt.serve.decode_sync"):
                 n = np.asarray(nxt)  # [S, W] target argmax at each position
                 lpn = np.asarray(lp)  # [S, W] its behavior logprob (f32)
@@ -1345,6 +1451,10 @@ class GenerationEngine(EngineBase):
             self._slot_hist.append((slot_no, t0, now, tokens))
             self._residencies += 1
         self._release_pages(s)
+        if self._stateful and req is not None:
+            # the row's state is dead from here: no decode round advances
+            # an idle row, and the next admission overwrites all of it
+            self.metrics.inc("state_resets_total")
         s.req = None
         s.length = 0
         s.last_token = 0

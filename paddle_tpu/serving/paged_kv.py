@@ -439,7 +439,8 @@ class PagedKVPool:
     def __init__(self, num_layers: int, num_pages: int, page_len: int,
                  num_heads: int, head_dim: int, dtype,
                  prefix_cache: bool = True,
-                 warm_pool: Optional[HostPagePool] = None):
+                 warm_pool: Optional[HostPagePool] = None,
+                 state_spec=None, max_slots: int = 0):
         import jax.numpy as jnp
 
         self.page_len = int(page_len)
@@ -452,6 +453,16 @@ class PagedKVPool:
                             dtype) for _ in range(num_layers)]
         self.v = [jnp.zeros((num_pages, page_len, num_heads, head_dim),
                             dtype) for _ in range(num_layers)]
+        # the second kind of cache: per layer, one slot-indexed arena per
+        # entry of a recurrent model's ``state_spec`` ({name: (per-slot
+        # shape, dtype)}) — e.g. the SSM state [slots, heads, P, N] and the
+        # conv tail [slots, d_conv - 1, channels]. Donated into every
+        # program like the K/V arenas and updated in place; a row is
+        # overwritten whole when its slot is admitted. None: K/V only.
+        self.state = None if state_spec is None else [
+            {name: jnp.zeros((int(max_slots),) + tuple(shape), dt)
+             for name, (shape, dt) in state_spec.items()}
+            for _ in range(num_layers)]
 
     # -- control plane --------------------------------------------------------
     def allocate(self, n: int) -> List[int]:
@@ -584,11 +595,16 @@ class PagedKVPool:
         return sum(int(a.nbytes) for a in self.k) + \
             sum(int(a.nbytes) for a in self.v)
 
+    def state_bytes(self) -> int:
+        return sum(int(a.nbytes) for layer in self.state or ()
+                   for a in layer.values())
+
     def stats(self) -> Dict[str, Any]:
         a = self.allocator
         out = {"pages_total": a.num_pages, "page_len": self.page_len,
                "pages_free": a.free_pages, "pages_live": a.live_pages,
                "pool_bytes": self.bytes(),
+               "state_bytes": self.state_bytes(),
                "alloc_total": a.alloc_total, "cow_total": a.cow_total,
                "headroom": round(a.free_pages / max(a.usable_pages, 1), 4)}
         if self.trie is not None:

@@ -1,0 +1,166 @@
+"""The served-model protocol: what a causal LM hands ``GenerationEngine``.
+
+The engine owns slots, pages, scheduling, spans and the two caches; it
+contains no model. A model that can be served implements
+``served_model()`` returning a :class:`ServedModel`:
+
+- ``params(model)``: the live weights as a jax pytree ``{..., "layers":
+  [{...}, ...]}`` — top-level arrays plus one dict per layer. The flat
+  ``{dotted_name: array}`` form of the same tree (:func:`flatten_params`)
+  is the wire shape ``swap_weights`` streams;
+- ``embed(params, tokens, pos) -> x``: ``[rows, W]`` ids at global
+  positions ``pos`` to the residual stream ``[rows, W, hidden]``;
+- ``block(p, x, pos, attend, state, valid) -> (x, state)``: ONE layer.
+  ``attend(q, k, v)`` is the engine's own attention: it writes ``k``/``v``
+  (``[rows, W, kv_heads, head_dim]``) through the page table into the K/V
+  arenas and returns the causal context of ``q`` (``[rows, W, heads,
+  head_dim]``) against the pages — fused Pallas kernel or composed gather,
+  the engine decides. ``state`` is the layer's recurrent state for the
+  program's rows, or ``None``: a stateless model (GPT-2) always gets and
+  returns ``None``; a model that declares ``state_spec`` gets ``None`` in a
+  prefill (a fresh sequence: start from zero and return the rows' FINAL
+  state) and its slot arenas in a decode round (advance one step, return
+  them updated). ``valid`` (``[rows, W]`` bool; a stateless model ignores
+  it) marks the window positions that hold a real token: the recurrence
+  must not advance on the others (a padded prefill bucket, an idle decode
+  row);
+- ``head(params, x) -> logits``: final norm and output head;
+- ``state_spec``: ``None``, or ``{name: (per-slot shape, dtype)}`` — the
+  slot-indexed arenas the engine keeps per layer beside the paged K/V.
+
+A model with recurrent state cannot use what assumes a cache is pages of
+K/V (the prefix trie, speculative verify, KV-page export/install): the
+engine refuses those in words (``docs/serving.md``).
+"""
+from __future__ import annotations
+
+import math
+from typing import Any, Dict, Optional, Tuple
+
+__all__ = ["ServedModel", "GPTServed", "flatten_params", "nest_params"]
+
+
+class ServedModel:
+    """Base of the protocol; see the module docstring."""
+
+    num_layers: int
+    num_heads: int
+    num_kv_heads: int
+    head_dim: int
+    vocab_size: int
+    max_positions: int
+    attn_scale: float
+    # None: the only cache is the paged K/V
+    state_spec: Optional[Dict[str, Tuple[Tuple[int, ...], Any]]] = None
+
+    def params(self, model) -> Dict[str, Any]:
+        raise NotImplementedError
+
+    def embed(self, params, tokens, pos):
+        raise NotImplementedError
+
+    def block(self, p, x, pos, attend, state, valid):
+        raise NotImplementedError
+
+    def head(self, params, x):
+        raise NotImplementedError
+
+
+def flatten_params(tree) -> Dict[str, Any]:
+    """Flatten an engine param pytree to ``{dotted_name: array}`` — the
+    wire shape the post-training weight service streams (stable names,
+    no nesting to re-derive on the far side)."""
+    flat = {k: v for k, v in tree.items() if k != "layers"}
+    for i, L in enumerate(tree["layers"]):
+        for k, v in L.items():
+            flat[f"layers.{i}.{k}"] = v
+    return flat
+
+
+def nest_params(flat) -> Dict[str, Any]:
+    """Inverse of :func:`flatten_params`."""
+    tree: Dict[str, Any] = {"layers": []}
+    layers: Dict[int, Dict[str, Any]] = {}
+    for name, v in flat.items():
+        if name.startswith("layers."):
+            _, idx, key = name.split(".", 2)
+            layers.setdefault(int(idx), {})[key] = v
+        else:
+            tree[name] = v
+    for i in sorted(layers):
+        if i != len(tree["layers"]):
+            raise ValueError(f"non-contiguous layer index {i}")
+        tree["layers"].append(layers[i])
+    return tree
+
+
+class GPTServed(ServedModel):
+    """GPT-2 on the seam: token + learned position embedding, pre-LN
+    blocks with biases (fused QKV, tanh-GELU MLP), tied head. Stateless."""
+
+    def __init__(self, cfg):
+        self.num_layers = cfg.num_hidden_layers
+        self.num_heads = self.num_kv_heads = cfg.num_attention_heads
+        self.head_dim = cfg.hidden_size // cfg.num_attention_heads
+        self.vocab_size = cfg.vocab_size
+        self.max_positions = cfg.max_position_embeddings
+        self.attn_scale = 1.0 / math.sqrt(self.head_dim)
+        self._eps = cfg.layer_norm_epsilon
+
+    def params(self, model):
+        """Read the live weights of a ``GPTForCausalLM`` (the programs
+        close over nothing — set_state_dict + a new engine picks up new
+        weights)."""
+        g = model.gpt
+
+        def a(t):
+            return t.data
+
+        return {
+            "embed": a(g.embed_tokens.weight),          # [vocab, h]
+            "pos": a(g.embed_positions.weight),         # [P, h]
+            "lnf_w": a(g.ln_f.weight), "lnf_b": a(g.ln_f.bias),
+            "layers": [
+                {"ln1_w": a(L.ln_1.weight), "ln1_b": a(L.ln_1.bias),
+                 "qkv_w": a(L.attn.qkv_proj.weight),
+                 "qkv_b": a(L.attn.qkv_proj.bias),
+                 "out_w": a(L.attn.out_proj.weight),
+                 "out_b": a(L.attn.out_proj.bias),
+                 "ln2_w": a(L.ln_2.weight), "ln2_b": a(L.ln_2.bias),
+                 "fc_in_w": a(L.fc_in.weight), "fc_in_b": a(L.fc_in.bias),
+                 "fc_out_w": a(L.fc_out.weight),
+                 "fc_out_b": a(L.fc_out.bias)}
+                for L in g.layers],
+        }
+
+    def _ln(self, x, w, b):
+        import jax
+        import jax.numpy as jnp
+
+        mean = jnp.mean(x, axis=-1, keepdims=True)
+        var = jnp.var(x, axis=-1, keepdims=True)
+        return (x - mean) * jax.lax.rsqrt(var + self._eps) * w + b
+
+    def embed(self, params, tokens, pos):
+        import jax.numpy as jnp
+
+        pos_idx = jnp.minimum(pos, params["pos"].shape[0] - 1)
+        return params["embed"][tokens] + params["pos"][pos_idx]
+
+    def block(self, p, x, pos, attend, state, valid):
+        import jax
+
+        S, W = x.shape[0], x.shape[1]
+        nh, hd = self.num_heads, self.head_dim
+        h1 = self._ln(x, p["ln1_w"], p["ln1_b"])
+        qkv = (h1 @ p["qkv_w"] + p["qkv_b"]).reshape(S, W, 3, nh, hd)
+        ctx = attend(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2])
+        ctx = ctx.reshape(S, W, nh * hd)
+        x = x + (ctx @ p["out_w"] + p["out_b"])
+        h2 = self._ln(x, p["ln2_w"], p["ln2_b"])
+        m = jax.nn.gelu(h2 @ p["fc_in_w"] + p["fc_in_b"], approximate=True)
+        return x + (m @ p["fc_out_w"] + p["fc_out_b"]), None
+
+    def head(self, params, x):
+        xf = self._ln(x, params["lnf_w"], params["lnf_b"])
+        return xf @ params["embed"].T

@@ -161,6 +161,39 @@ def test_paged_attention_one_row_prefill(one_chip, W):
                    dtype=BF16)
 
 
+@pytest.mark.parametrize("S,W", [(64, 1), (1, 128), (1, 256)])
+def test_paged_attention_grouped_query_128(one_chip, S, W):
+    """Falcon-H1-34B's attention: 20 query heads over 4 K/V heads of 128
+    against the cell's pool (2113 pages of 16 tokens, 32 blocks a row) —
+    the 64-slot decode round and both one-row prefill buckets."""
+    from paddle_tpu.kernels.pallas import paged_attention as kpaged
+
+    def run(q, k, v, tables, pos):
+        return kpaged.paged_attention(q, k, v, tables, pos, impl="pallas")
+
+    _compile(run, one_chip,
+             ((S, W, 20, 128), BF16), ((2113, 16, 4, 128), BF16),
+             ((2113, 16, 4, 128), BF16), ((S, 32), jnp.int32),
+             ((S, W), jnp.int32), names=("pt_paged_attention",))
+
+
+def test_ssm_step_published_widths(one_chip):
+    """One step of the Mamba-2 recurrence at Falcon-H1-34B's widths (32
+    heads x 128, state 256, 2 groups) over a 64-slot arena: the state goes
+    in and comes out of the SAME buffer."""
+    from paddle_tpu.kernels.pallas import ssm_step as kssm
+
+    f32 = jnp.float32
+    R, H, P, N, G = 64, 32, 128, 256, 2
+    text = _compile(
+        lambda s, x, dt, a, b, c, d: kssm.ssm_step(s, x, dt, a, b, c, d,
+                                                   impl="pallas"),
+        one_chip, ((R, H, P, N), f32), ((R, H, P), f32), ((R, H), f32),
+        ((H,), f32), ((R, G, N), f32), ((R, G, N), f32), ((H,), f32),
+        names=("pt_ssm_step",))
+    assert "output_to_operand_aliasing" in text
+
+
 def test_moe_routing_dispatch(one_chip, monkeypatch):
     """The ``moe`` recipe's layer (hidden 1536, 8 experts, top-2, expert
     MLP 2048) through the fused routing/dispatch kernels, fwd + bwd.

@@ -382,10 +382,10 @@ def test_one_row_prefill_matches_all_slots_program(start, attend,
     tables[slot] = table
     build = lambda rows, tag: _build_window_step(  # noqa: E731
         cfg, rows, B, PL, W, donate=False, label=f"t26:{tag}", fused=fused)
-    nxt_s, lp_s, k_s, v_s = build(S, "all")(
+    nxt_s, lp_s, k_s, v_s, _ = build(S, "all")(
         params, k0, v0, jnp.asarray(tables), jnp.asarray(tokens),
         jnp.asarray(lengths))
-    nxt_1, lp_1, k_1, v_1 = build(1, "one")(
+    nxt_1, lp_1, k_1, v_1, _ = build(1, "one")(
         params, k0, v0, jnp.asarray(table[None]),
         jnp.asarray(tokens[slot][None]), jnp.asarray([start], jnp.int32))
     assert np.asarray(nxt_1).shape == (1, W)
@@ -494,8 +494,8 @@ def test_warmup_compiles_every_program_a_window_calls(tiny_lm, tmp_path,
                                             **extra), name=name)
         eng.warmup()
         S = eng.config.max_slots
-        want = {(S, 1), (1, 8), (1, 16), (1, 24)} | \
-            ({(S, 4)} if draft else set())
+        want = {(S, 1, False), (1, 8, True), (1, 16, True),
+                (1, 24, True)} | ({(S, 4, False)} if draft else set())
         assert set(eng._windows) == want
         labels = {k for k in pc.stats()["by_label"]
                   if k.startswith(f"serving:{name}:")}
