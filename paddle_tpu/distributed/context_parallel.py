@@ -23,7 +23,20 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
-from .mesh import MeshEnv, get_mesh_env
+from .mesh import MeshEnv, _axes_if_divisible, get_mesh_env
+
+
+def _cp_shard_map(local, env, axis, batch):
+    """``local`` over [batch-major rows, seq, ...] operands, manual over the
+    sequence axis AND the data axes the batch splits over: each data
+    replica rings its own rows (left to GSPMD, the rows inside the manual
+    region are the partitioner's guess — the global batch, at worst)."""
+    data = _axes_if_divisible(env, ("dp", "sdp"), batch) or ()
+    data = (data,) if isinstance(data, str) else data
+    spec = P(data or None, axis)
+    return jax.shard_map(local, mesh=env.mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, axis_names={axis, *data},
+                         check_vma=False)
 
 
 def _merge(o1, lse1, o2, lse2):
@@ -80,8 +93,9 @@ def _ring_local(q, k, v, cp, causal, scale, axis):
 
 
 def ring_attention_bhsd(q, k, v, causal=True, scale=None,
-                        env: MeshEnv = None, axis: str = "cp"):
-    """q/k/v: [bh, s, d] with s sharded over `axis`. Returns [bh, s, d]."""
+                        env: MeshEnv = None, axis: str = "cp", batch: int = 1):
+    """q/k/v: [bh, s, d] with s sharded over `axis`. Returns [bh, s, d].
+    ``batch`` is b of the batch-major bh rows (1: the rows are not split)."""
     env = env or get_mesh_env()
     cp = env.get_dim(axis) if env is not None else 1
     if scale is None:
@@ -96,11 +110,7 @@ def ring_attention_bhsd(q, k, v, causal=True, scale=None,
     def local(ql, kl, vl):
         return _ring_local(ql, kl, vl, cp, causal, float(scale), axis)
 
-    return jax.shard_map(
-        local, mesh=env.mesh,
-        in_specs=(P(None, axis), P(None, axis), P(None, axis)),
-        out_specs=P(None, axis), axis_names={axis}, check_vma=False,
-    )(q, k, v)
+    return _cp_shard_map(local, env, axis, batch)(q, k, v)
 
 
 def ring_attention(q, k, v, causal=True, scale=None, env: MeshEnv = None):
@@ -118,7 +128,8 @@ def _ring_bshd(q, k, v, causal, scale, env=None):
     qm = jnp.moveaxis(q, 2, 1).reshape(b * h, s, d)
     km = jnp.moveaxis(k, 2, 1).reshape(b * h, s, d)
     vm = jnp.moveaxis(v, 2, 1).reshape(b * h, s, d)
-    om = ring_attention_bhsd(qm, km, vm, causal=causal, scale=scale, env=env)
+    om = ring_attention_bhsd(qm, km, vm, causal=causal, scale=scale, env=env,
+                             batch=b)
     return jnp.moveaxis(om.reshape(b, h, s, d), 1, 2)
 
 
@@ -166,11 +177,7 @@ def ulysses_attention_bshd(q, k, v, causal=True, scale=None,
         # [b, s, h/cp, d] -> [b, s/cp, h, d]: scatter sequence, gather heads
         return lax.all_to_all(oh, axis, split_axis=1, concat_axis=2, tiled=True)
 
-    return jax.shard_map(
-        local, mesh=env.mesh,
-        in_specs=(P(None, axis), P(None, axis), P(None, axis)),
-        out_specs=P(None, axis), axis_names={axis}, check_vma=False,
-    )(q, k, v)
+    return _cp_shard_map(local, env, axis, q.shape[0])(q, k, v)
 
 
 @primitive("ulysses_attention")

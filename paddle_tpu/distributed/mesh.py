@@ -17,7 +17,9 @@ the context/expert axes the reference lacked, SURVEY §5 long-context note):
 """
 from __future__ import annotations
 
+import collections
 import math
+import re
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -137,6 +139,58 @@ def init_mesh(dp=1, mp=1, pp=1, sharding=1, cp=1, ep=1, devices=None) -> MeshEnv
                   devices)
     _GLOBAL["env"] = env
     return env
+
+
+_COLLECTIVE = re.compile(
+    r"= (\(.*?\)|\S+) (all-reduce|all-gather|reduce-scatter|all-to-all|"
+    r"collective-permute)(-start)?\(")
+_SHAPE = re.compile(r"\b([a-z]+\d*\[[\d,]*\])")
+_GROUPS = re.compile(r"(?:replica_groups|source_target_pairs)="
+                     r"(\{\{.*?\}\}|\{\}|\[[\d,]+\]<=\[[\d,]+\](?:T\([\d,]+\))?)")
+
+
+def _device_groups(written: str, n: int) -> np.ndarray:
+    """An HLO ``replica_groups`` / ``source_target_pairs`` attribute as rows
+    of partition ids: ``{{0,2},{1,3}}``, ``{}`` (everyone) or the iota form
+    ``[groups,size]<=[dims]T(perm)``."""
+    if written == "{}":
+        return np.arange(n).reshape(1, n)
+    if written.startswith("{"):
+        return np.asarray([[int(i) for i in g.split(",")]
+                           for g in re.findall(r"\{([\d,]+)\}", written)])
+    shape, dims, perm = re.fullmatch(
+        r"\[([\d,]+)\]<=\[([\d,]+)\](?:T\(([\d,]+)\))?", written).groups()
+    ints = lambda t: [int(i) for i in t.split(",")]  # noqa: E731
+    ids = np.arange(math.prod(ints(dims))).reshape(ints(dims))
+    if perm:
+        ids = ids.transpose(ints(perm))
+    return ids.reshape(ints(shape))
+
+
+def compiled_collectives(text: str, mesh: Mesh) -> List[dict]:
+    """The collectives of one compiled SPMD program, read from its text
+    (``lowered.compile().as_text()``): rows ``{"axes", "op", "shapes",
+    "count"}`` — the mesh axes a group of the instruction spans, its opcode,
+    its result shapes as written (``"f32[4,32,64]"``; several where XLA
+    combined operands) and how many such instructions the program holds. An
+    instruction inside a loop body counts once, whatever the trip count.
+    Partition ``i`` is ``mesh.devices.flat[i]``, jit's device assignment."""
+    counts = collections.Counter()
+    for line in text.splitlines():
+        m = _COLLECTIVE.search(line)
+        if m is None:
+            continue
+        op, shapes = m.group(2), tuple(_SHAPE.findall(m.group(1)))
+        if m.group(3) and op in ("all-gather", "collective-permute"):
+            shapes = shapes[len(shapes) // 2:]  # a start's type: (operands, results)
+        g = _GROUPS.search(line)
+        groups = _device_groups(g.group(1) if g else "{}", mesh.devices.size)
+        coords = np.stack(np.unravel_index(groups, mesh.devices.shape), -1)
+        spans = (coords != coords[:, :1]).any(axis=(0, 1))
+        axes = tuple(ax for ax, on in zip(mesh.axis_names, spans) if on)
+        counts[axes, op, shapes] += 1
+    return [{"axes": a, "op": o, "shapes": s, "count": c}
+            for (a, o, s), c in sorted(counts.items())]
 
 
 def auto_mesh(devices=None) -> MeshEnv:

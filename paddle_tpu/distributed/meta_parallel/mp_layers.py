@@ -39,6 +39,17 @@ def mark_sharding(x: Tensor, *spec) -> Tensor:
     return _shard_constraint(x, spec=tuple(spec), _env_id=id(env))
 
 
+def _mark_feature(x: Tensor, feature) -> Tensor:
+    """Constrain only the dim a tensor-parallel layer owns: the last
+    (feature) dim is on ``"mp"`` or replicated (``None``). The leading dims
+    are left ``UNCONSTRAINED`` — the layer cannot know which of them is the
+    batch (dp/sdp) and which the sequence (cp); the model anchors those
+    (``models/llama.py:_mark_seq``) and GSPMD propagates them. A ``None``
+    there would say "whole on every device" and make every data replica
+    gather the global batch and repeat the others' work."""
+    return mark_sharding(x, *([P.UNCONSTRAINED] * (x.ndim - 1) + [feature]))
+
+
 def constrain_spec(arr, spec):
     """with_sharding_constraint on a raw array, robust to being inside a
     partial-manual shard_map (the pp pipeline): constraints there must be
@@ -70,8 +81,8 @@ def constrain_spec(arr, spec):
 
     if manual:
         def strip(entry):
-            if entry is None:
-                return None
+            if entry is None or entry is P.UNCONSTRAINED:
+                return entry
             if isinstance(entry, (tuple, list)):
                 kept = tuple(e for e in entry if e not in manual)
                 return kept or None
@@ -104,7 +115,7 @@ class VocabParallelEmbedding(nn.Layer):
 
     def forward(self, x):
         out = F.embedding(x, self.weight)
-        return mark_sharding(out, None, None, None) if out.ndim == 3 else out
+        return _mark_feature(out, None) if out.ndim == 3 else out
 
 
 class ColumnParallelLinear(nn.Layer):
@@ -131,11 +142,9 @@ class ColumnParallelLinear(nn.Layer):
 
     def forward(self, x):
         out = F.linear(x, self.weight, self.bias)
-        if self.gather_output:
-            # replicate (XLA inserts the all-gather)
-            return mark_sharding(out, *([None] * out.ndim))
-        # keep sharded on the feature dim
-        return mark_sharding(out, *([None] * (out.ndim - 1) + ["mp"]))
+        # gather_output: the feature dim whole on every mp rank (XLA inserts
+        # the all-gather); else it stays sharded over mp
+        return _mark_feature(out, None if self.gather_output else "mp")
 
 
 class RowParallelLinear(nn.Layer):
@@ -160,10 +169,10 @@ class RowParallelLinear(nn.Layer):
 
     def forward(self, x):
         if self.input_is_parallel:
-            x = mark_sharding(x, *([None] * (x.ndim - 1) + ["mp"]))
+            x = _mark_feature(x, "mp")
         out = F.linear(x, self.weight, None)
         # partial sums reduce here (XLA inserts the all-reduce / reduce-scatter)
-        out = mark_sharding(out, *([None] * out.ndim))
+        out = _mark_feature(out, None)
         if self.bias is not None:
             out = out + self.bias
         return out
@@ -179,6 +188,6 @@ class ParallelCrossEntropy(nn.Layer):
         self.ignore_index = ignore_index
 
     def forward(self, input, label):
-        logits = mark_sharding(input, *([None] * (input.ndim - 1) + ["mp"]))
+        logits = _mark_feature(input, "mp")
         return F.cross_entropy(logits, label, reduction="none",
                                ignore_index=self.ignore_index)

@@ -881,6 +881,16 @@ class ShardedTrainStep:
         return lowerable(self._jitted).lower(
             *step_args(self, arrays, jax.random.key(0)))
 
+    def collectives(self, *batch):
+        """Does ``dp`` divide my step? The compiled step's collectives for
+        this batch's shapes, by mesh axis, opcode and shape with their
+        count (``mesh.compiled_collectives``). It compiles; nothing runs,
+        and the step's own path never calls it."""
+        from .mesh import compiled_collectives
+
+        return compiled_collectives(
+            self.lower(*batch).compile().as_text(), self.env.mesh)
+
     def __call__(self, *batch):
         from ..jit import _batch_arrays, _obs, step_args
 

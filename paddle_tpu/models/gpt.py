@@ -17,7 +17,7 @@ from ..ops import creation, manipulation
 from ..distributed.meta_parallel.mp_layers import (
     ColumnParallelLinear, RowParallelLinear, VocabParallelEmbedding,
 )
-from .llama import _fused_linear_ce, _mark_seq
+from .llama import _data_degree, _fused_linear_ce, _mark_seq
 
 
 @dataclass
@@ -178,7 +178,8 @@ class GPTForCausalLM(nn.Layer):
                                       [-1, self.config.hidden_size])
             lab1 = manipulation.reshape(labels[:, 1:], [-1])
             return _fused_linear_ce(h2, manipulation.transpose(w, [1, 0]),
-                                    lab1, chunk=2048, ignore_index=-100)
+                                    lab1, chunk=2048, ignore_index=-100,
+                                    groups=_data_degree())
         return hidden.matmul(manipulation.transpose(w, [1, 0]))
 
     def served_model(self):

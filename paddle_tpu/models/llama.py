@@ -299,23 +299,44 @@ class LlamaModel(nn.Layer):
         return self.norm(hidden)
 
 
+def _data_degree() -> int:
+    """How many data replicas (dp x sdp) the live mesh has; 1 with no mesh."""
+    env = get_mesh_env()
+    return env.get_dim("dp") * env.get_dim("sdp") if env is not None else 1
+
+
 @primitive("fused_linear_ce")
-def _fused_linear_ce(hidden2d, w, labels1d, *, chunk, ignore_index):
+def _fused_linear_ce(hidden2d, w, labels1d, *, chunk, ignore_index, groups=1):
     """lm_head matmul + softmax CE scanned over token chunks: the [N, vocab]
     logits tensor never materializes (compile-size + HBM win for 32k+ vocabs;
-    plays the c_softmax_with_cross_entropy fused-kernel role)."""
+    plays the c_softmax_with_cross_entropy fused-kernel role).
+
+    ``groups`` is the mesh's data degree (``_data_degree()``): the rows are
+    batch-major, so they fall into ``groups`` contiguous runs, one a data
+    replica. Each run is chunked on its own and the scan walks the chunks
+    with the runs riding along as a leading dim of the body — a scanned dim
+    cannot be sharded, so chunking across the runs would make every replica
+    compute the head for the whole global batch."""
     import jax
 
-    n = hidden2d.shape[0]
-    n_chunks = max(n // chunk, 1)
-    c = -(-n // n_chunks)  # ceil: every token contributes
-    pad = n_chunks * c - n
+    n, width = hidden2d.shape
+    g = groups if n % groups == 0 else 1
+    lead = (g,) if g > 1 else ()
+    m = n // g  # rows a data replica holds
+    n_chunks = max(m // chunk, 1)
+    c = -(-m // n_chunks)  # ceil: every token contributes
+    pad = n_chunks * c - m
+    h3 = hidden2d.reshape(*lead, m, width)
+    l2 = labels1d.reshape(*lead, m)
     if pad:
-        hidden2d = jnp.pad(hidden2d, ((0, pad), (0, 0)))
-        labels1d = jnp.pad(labels1d, (0, pad),
-                           constant_values=ignore_index)  # padded rows masked
-    h3 = hidden2d.reshape(n_chunks, c, hidden2d.shape[1])
-    l2 = labels1d.reshape(n_chunks, c)
+        none = ((0, 0),) * len(lead)
+        h3 = jnp.pad(h3, (*none, (0, pad), (0, 0)))
+        l2 = jnp.pad(l2, (*none, (0, pad)),
+                     constant_values=ignore_index)  # padded rows masked
+    h3 = h3.reshape(*lead, n_chunks, c, width)
+    l2 = l2.reshape(*lead, n_chunks, c)
+    if lead:  # scan over the chunks, never over the runs
+        h3, l2 = jnp.moveaxis(h3, 1, 0), jnp.moveaxis(l2, 1, 0)
 
     def body(acc, xs):
         h, lab = xs
@@ -323,7 +344,7 @@ def _fused_linear_ce(hidden2d, w, labels1d, *, chunk, ignore_index):
         logp = jax.nn.log_softmax(logits, axis=-1)
         mask = lab != ignore_index
         safe = jnp.where(mask, lab, 0).astype(jnp.int32)
-        picked = jnp.take_along_axis(logp, safe[:, None], axis=1)[:, 0]
+        picked = jnp.take_along_axis(logp, safe[..., None], axis=-1)[..., 0]
         loss_sum = -jnp.sum(jnp.where(mask, picked, 0.0))
         cnt = jnp.sum(mask)
         return (acc[0] + loss_sum, acc[1] + cnt), None
@@ -360,7 +381,7 @@ class LlamaForCausalLM(nn.Layer):
             loss = _fused_linear_ce(h2, self.lm_head.weight, lab1,
                                     chunk=getattr(self.config, "ce_chunk",
                                                   2048),
-                                    ignore_index=-100)
+                                    ignore_index=-100, groups=_data_degree())
             if aux is not None:
                 loss = loss + getattr(self.config, "aux_loss_weight", 0.0) * aux
             return loss
