@@ -4,8 +4,8 @@ One process, one TPU chip, in sequence (``python chip_smoke.py``):
 
 - *device*   refuse to run unless JAX's first device is a TPU;
 - *kernels*  every registered Pallas kernel at the smoke's real widths
-             against its composed-XLA twin, ON the chip;
-- *train*    ``LlamaForCausalLM`` at the ``big`` recipe's full widths
+             against its jnp reference, ON the chip;
+- *train*    a 1.16B Llama-shaped model at full widths
              (hidden 2048, MLP 5632, 16 heads x 128, vocab 32000, bf16,
              remat, seq 2048, 20 layers) through ``jit.TrainStep`` + AdamW:
              the largest of batch 16/8/4 that fits, finite decreasing
@@ -46,8 +46,6 @@ LOGIT_TOL = 0.1
 # relative band for losses of the SAME seed under another layout (bf16
 # partial sums land in another order on a mesh)
 LOSS_RTOL = 1e-2
-
-REGISTRY_IMPLS = ("pallas", "interpret", "composed")
 
 
 def say(phase: str, **fields) -> None:
@@ -118,28 +116,27 @@ def kernel_calls() -> dict:
 
 def check_kernel_table(phase: str, ops, expect_impl: str, before: dict):
     """Every op of ``ops`` was resolved to ``expect_impl`` at least once
-    since ``before`` and to nothing else: no interpreter and no composed
-    twin on a path that claims the Pallas kernel."""
+    since ``before`` and to nothing else: no interpreter and no jnp
+    reference on a path that claims the Pallas kernel."""
     from paddle_tpu.kernels import registry
 
     table = registry.kernel_table()
     for op in ops:
         row = table["ops"][op]
-        delta = {k: row["calls"][k] - before[op][k] for k in REGISTRY_IMPLS}
-        say(f"{phase}.kernel", op=op, enabled=row["enabled"],
-            impl=row["impl"], calls=json.dumps(delta))
-        assert row["enabled"], f"{op}: gate closed on the {phase} path"
+        delta = {k: row["calls"][k] - before[op][k] for k in registry.IMPLS}
+        say(f"{phase}.kernel", op=op, impl=row["impl"],
+            calls=json.dumps(delta))
         assert row["impl"] == expect_impl, (op, row["impl"], expect_impl)
         assert delta[expect_impl] > 0, f"{op}: never taken on {phase} path"
         others = {k: v for k, v in delta.items() if k != expect_impl and v}
         assert not others, f"{op}: also resolved to {others}"
 
 
-# -- kernels: Pallas vs composed twin, on the device ---------------------------
+# -- kernels: Pallas vs jnp reference, on the device --------------------------
 
 def kernels_phase(impl: str, *, rope_shape, norm_shape, paged, moe) -> None:
-    """Run every registered kernel through ``impl`` and through its
-    composed-XLA twin on the same inputs; the results must agree to bf16
+    """Run every registered kernel through ``impl`` and through its jnp
+    reference on the same inputs; the results must agree to bf16
     rounding. A kernel the compiler accepts is not yet a kernel that is
     right — this is where the repaired ones are shown to be."""
     import jax
@@ -162,19 +159,19 @@ def kernels_phase(impl: str, *, rope_shape, norm_shape, paged, moe) -> None:
     x = jax.random.normal(next(keys), rope_shape, bf16)
     for off in (0, 7):
         close(f"rope@{off}", rope.rope_apply(x, 1e4, off, impl=impl),
-              rope.rope_apply(x, 1e4, off, impl="composed"), 0.05)
+              rope.rope_apply(x, 1e4, off, impl="reference"), 0.05)
     g = jax.grad(lambda z, i: jnp.sum(jnp.sin(
         rope.rope_apply(z, 1e4, 0, impl=i).astype(jnp.float32))),
         argnums=0)
-    close("rope.vjp", g(x, impl), g(x, "composed"), 0.05)
+    close("rope.vjp", g(x, impl), g(x, "reference"), 0.05)
 
     x = jax.random.normal(next(keys), norm_shape, bf16)
     r = jax.random.normal(next(keys), norm_shape, bf16)
     w = 1.0 + 0.1 * jax.random.normal(next(keys), norm_shape[-1:], bf16)
     close("rms_norm", rmsnorm.rms_norm(x, w, 1e-6, impl=impl),
-          rmsnorm.rms_norm(x, w, 1e-6, impl="composed"), 0.05)
+          rmsnorm.rms_norm(x, w, 1e-6, impl="reference"), 0.05)
     yi, si = rmsnorm.rms_norm_residual(x, r, w, 1e-6, impl=impl)
-    yc, sc = rmsnorm.rms_norm_residual(x, r, w, 1e-6, impl="composed")
+    yc, sc = rmsnorm.rms_norm_residual(x, r, w, 1e-6, impl="reference")
     close("rms_norm_residual.y", yi, yc, 0.05)
     close("rms_norm_residual.s", si, sc, 0.05)
 
@@ -193,7 +190,7 @@ def kernels_phase(impl: str, *, rope_shape, norm_shape, paged, moe) -> None:
               paged_attention.paged_attention(q, ka, va, tables, pos,
                                               impl=impl),
               paged_attention.paged_attention(q, ka, va, tables, pos,
-                                              impl="composed"), 0.05)
+                                              impl="reference"), 0.05)
 
     b, s, h, e, inter, k = moe["batch"], moe["seq"], moe["hidden"], \
         moe["experts"], moe["inter"], moe["top_k"]
@@ -205,7 +202,7 @@ def kernels_phase(impl: str, *, rope_shape, norm_shape, paged, moe) -> None:
     oi, auxi = moe_dispatch.fused_moe_mlp(x, wg, w_gate, w_up, w_down,
                                           top_k=k, impl=impl)
     oc, auxc = moe_dispatch.fused_moe_mlp(x, wg, w_gate, w_up, w_down,
-                                          top_k=k, impl="composed")
+                                          top_k=k, impl="reference")
     close("moe_dispatch.out", oi, oc, 0.05)
     close("moe_dispatch.aux", auxi, auxc, 1e-3)
 
@@ -540,7 +537,7 @@ def sharded_phase(cfg, *, seq: int, batch: int, steps: int, meshes,
 # -- the real sizes ------------------------------------------------------------
 
 def big_llama(layers: int):
-    """``bench.py``'s ``big`` recipe: the 1.16B Llama-shaped flagship."""
+    """The 1.16B Llama-shaped model of the bring-up (PR 21)."""
     from paddle_tpu.models import LlamaConfig
 
     return LlamaConfig(

@@ -14,7 +14,7 @@ through. The seeds below come from this repo's own measurements:
   from the PR-5 ``stream_capacity`` legs (an old set-up; not re-measured
   on the current chip);
 - ``cpu-host``: the 8-device ``--xla_force_host_platform_device_count``
-  dryrun mesh (MULTICHIP_r05) — "links" are memcpys between thread
+  dryrun mesh (``__graft_entry__``) — "links" are memcpys between thread
   shards, cheap on bytes but expensive per collective (every extra
   partitioned op pays SPMD overhead on an oversubscribed host).
 

@@ -121,15 +121,11 @@ def fused_entries(topology: Optional[str] = None) -> Dict[str, FusedOpEntry]:
 
 
 def enabled_fused_ops() -> Tuple[str, ...]:
-    """The ops the live kernel registry would actually engage (the
-    planner's default when the caller does not pin a set)."""
-    try:
-        from ..kernels.registry import enabled_ops, registry
+    """The ops whose Pallas kernel this platform runs (the planner's
+    default when the caller does not pin a set)."""
+    from ..kernels.registry import enabled_ops
 
-        registry()  # make sure the builtin library is registered
-        return enabled_ops()
-    except Exception:
-        return ()
+    return enabled_ops()
 
 
 def fused_gain_s(profile, cfg: Dict[str, Any], link,

@@ -18,7 +18,8 @@ PRs validated:
   scaled by the measured drift ratio, so infeasible plans are pruned
   before ranking, not discovered by an OOM.
 
-The search space is exactly what this repo executes (MULTICHIP_r05):
+The search space is exactly what this repo executes (the dryrun matrix,
+``__graft_entry__._mesh_configs``):
 mesh shapes over dp/mp/pp/cp/ep/sharding (divisor-constrained by
 heads/layers/experts) x ``accumulate(k)`` x remat on/off x
 offload/``os_g``. ``plan()`` returns ranked ``PlanCandidate``s whose
@@ -210,7 +211,7 @@ def profile_model(model, batch: int = 8, seq: int = 128,
 
 def normalize_config(raw: Dict[str, Any], batch: Optional[int] = None
                      ) -> Dict[str, Any]:
-    """Canonical config dict from a loose one (e.g. a MULTICHIP_r05 matrix
+    """Canonical config dict from a loose one (e.g. a dryrun-matrix
     entry ``{"dp": 2, "mp": 2, "cp": 2}`` or ``{"sharding": 4, "dp": 2,
     "level": "os_g"}``). Keys outside the mesh axes pass through."""
     mesh = {ax: int(raw.get(ax, 1) or 1) for ax in AXES}
@@ -548,10 +549,9 @@ def _opt_words(optimizer) -> float:
 
 
 def _resolve_fused_ops(fused_kernels) -> Tuple[str, ...]:
-    """Normalize the ``fused_kernels`` knob: None = whatever the live
-    kernel registry would engage (``FLAGS_fused_kernels`` + backend),
-    True = every registered op, False/() = none, or an explicit op
-    iterable."""
+    """Normalize the ``fused_kernels`` knob: None = the ops whose Pallas
+    kernel this platform runs (``kernels.registry.enabled_ops``), True =
+    every registered op, False/() = none, or an explicit op iterable."""
     from ...cost_model.fused import FUSED_OP_ENTRIES, enabled_fused_ops
 
     if fused_kernels is None:
@@ -570,12 +570,13 @@ def score_config(profile: ModelProfile, config: Dict[str, Any], *,
                  drift_ratio: Optional[float] = None,
                  headroom: float = 0.9,
                  fused_kernels=None) -> PlanCandidate:
-    """Score ONE config (loose dicts accepted — every MULTICHIP_r05
-    matrix entry round-trips through here). ``fused_kernels`` prices the
-    kernels/pallas layer into the step-time model: None follows the live
-    registry gate, True/False force it, an iterable names the op set —
-    the per-op deltas land in the breakdown (``fused_gain_s`` /
-    ``fused_ops``) so a fusion that changes a ranking is visible."""
+    """Score ONE config (loose dicts accepted — every dryrun-matrix
+    entry round-trips through here). ``fused_kernels`` prices the
+    kernels/pallas layer into the step-time model: None follows the
+    platform (``kernels.registry.enabled_ops``), True/False force it, an
+    iterable names the op set — the per-op deltas land in the breakdown
+    (``fused_gain_s`` / ``fused_ops``) so a fusion that changes a ranking
+    is visible."""
     cfg = normalize_config(dict(config), batch=profile.batch) \
         if "mesh" not in config else config
     link = link or link_model_for()

@@ -48,8 +48,8 @@ def kernel_mesh_ok(seq_local: bool = True) -> bool:
     pipeline's own manual region (pp > 1: a nested full-manual shard_map
     cannot be entered from there), and a kernel that needs GLOBAL sequence
     positions (``seq_local=False``: RoPE) not on a sequence-split (cp > 1)
-    mesh. Call sites fall back to the composed-XLA form, which GSPMD
-    partitions itself."""
+    mesh. ``kernels.registry.resolve`` then answers with the op's jnp
+    reference, which GSPMD partitions itself."""
     env = kernel_mesh()
     if env is None:
         return True

@@ -134,13 +134,6 @@ define_flag("moe_dispatch", "index",
             "routing/dispatch kernel feeding the grouped matmul, "
             "single-device experts — kernels/pallas/moe_dispatch.py) or "
             "'einsum' (GShard one-hot dispatch einsums, oracle)")
-define_flag("fused_kernels", "auto",
-            "Fused-kernel (kernels/pallas/) call-site gate: 'auto' engages "
-            "the fused ops on TPU and keeps the legacy composed-XLA path "
-            "on CPU; 'on'/'off' force it everywhere; a comma list (e.g. "
-            "'rms_norm,rope') enables exactly those ops on any backend. "
-            "Live-read per call; the decision rides the op jit cache key "
-            "so a flip retraces (auditable via analysis.retrace).")
 define_flag("flash_min_seq", 128,
             "Minimum q AND kv sequence length before nn.functional "
             "attention routes to the Pallas flash kernel on TPU (shorter "

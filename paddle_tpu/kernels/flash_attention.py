@@ -30,10 +30,10 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# Tuned on v5e (round-3 sweep, 1.16B Llama @ seq 2048, bench.py config):
-# (q,k)=(256,512) 49.5% MFU, (512,512) 52.4%, (512,1024) 54.8%,
-# (1024,1024) 55.6% <- best; (1024,2048) exceeds VMEM. Override per-call or
-# via FLAGS_flash_block_q/k.
+# From a sweep on an earlier set-up of the v5e (1.16B Llama @ seq 2048;
+# (1024,2048) exceeded VMEM), not re-measured by the benchmark: block sizes
+# as a function of shape are ROADMAP D4b. Override per-call or via
+# FLAGS_flash_block_q/k.
 DEFAULT_BLOCK_Q = 1024
 DEFAULT_BLOCK_K = 1024
 _NEG = -1e30

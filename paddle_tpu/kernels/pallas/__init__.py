@@ -1,8 +1,8 @@
 """Pallas fused-op library (the operators/fused/ role, TPU-native).
 
-Each module ships one fused op as a matched pair — the Pallas TPU kernel
-and its composed-XLA twin (identical math + custom-VJP structure) — and
-registers both through ``kernels.registry``:
+Each module ships one fused op behind one public function holding the
+Pallas TPU kernel and a plain ``jnp`` reference (``impl=None`` asks
+``kernels.registry.resolve`` which runs), and registers its name there:
 
 - ``rmsnorm``: RMSNorm and RMSNorm+residual, fwd + VJP in single kernels
   (the FlashAttention lesson applied to norms: the f32 normalize never
@@ -21,7 +21,7 @@ registers both through ``kernels.registry``:
   (the decode half of a recurrent model behind ``GenerationEngine``).
 
 Import order matters only in that importing this package populates the
-registry; call sites go through ``kernels.registry.resolve``.
+registry.
 """
 from . import (moe_dispatch, paged_attention, rmsnorm, rope,  # noqa: F401
                ssm_step)

@@ -167,8 +167,8 @@ def _routing_pallas(xt, wg, top_k, interpret):
     return gv, gi, pos, cnt.reshape(e), me.reshape(e), ce.reshape(e)
 
 
-def _routing_composed(xt, wg, top_k):
-    """The jnp twin: identical math, token-major cumsum positions."""
+def _routing_reference(xt, wg, top_k):
+    """The jnp reference: identical math, token-major cumsum positions."""
     n, _ = xt.shape
     e = wg.shape[1]
     logits = jnp.matmul(xt.astype(jnp.float32), wg.astype(jnp.float32))
@@ -206,7 +206,7 @@ def _route_impl(xt, wg, top_k, impl):
         _routing_pallas(xt, wg, top_k,
                         interpret=(impl == "interpret"))
         if impl in ("pallas", "interpret")
-        else _routing_composed(xt, wg, top_k))
+        else _routing_reference(xt, wg, top_k))
     n = xt.shape[0]
     e = wg.shape[1]
     aux = e * jnp.sum((me / n) * (ce / n))
@@ -259,7 +259,7 @@ def _gather_rows(src, idx, impl):
     """out[i] = src[idx[i]] — the grouped-layout gather. One row block
     per grid step, destination-ordered; the index vector rides SMEM via
     scalar prefetch so the DMA engine walks it ahead of compute."""
-    if impl == "composed":
+    if impl == "reference":
         return jnp.take(src, idx, axis=0)
     n = idx.shape[0]
     h = src.shape[1]
@@ -297,7 +297,7 @@ def _combine_rows(y, gates, dest2, impl, out_dtype=None):
     expressed as k gathers + an f32 weighted add per token row."""
     n, k = dest2.shape
     out_dtype = out_dtype or y.dtype
-    if impl == "composed":
+    if impl == "reference":
         rows = jnp.take(y, dest2.reshape(n * k), axis=0).reshape(n, k, -1)
         return jnp.sum(rows.astype(jnp.float32) *
                        gates[..., None].astype(jnp.float32),
@@ -390,7 +390,7 @@ def fused_moe_mlp(x, wg, w_gate, w_up, w_down, *, top_k, impl=None):
     from ..grouped_matmul import grouped_matmul
 
     if impl is None:
-        impl = resolve("moe_dispatch")[0]
+        impl = resolve("moe_dispatch")
     b, s, h = x.shape
     n = b * s
     e = wg.shape[1]
@@ -431,8 +431,6 @@ def fused_moe_mlp(x, wg, w_gate, w_up, w_down, *, top_k, impl=None):
 
 register_kernel(
     "moe_dispatch",
-    pallas=functools.partial(fused_moe_mlp, impl="pallas"),
-    composed=functools.partial(fused_moe_mlp, impl="composed"),
     doc="dropless MoE routing+dispatch: one routing kernel (top-k + "
         "sort-by-expert counters), scalar-prefetch gathers, gather-only "
         "VJPs, grouped_matmul FFN")

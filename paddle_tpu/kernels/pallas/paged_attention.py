@@ -17,13 +17,12 @@ Layouts (matching ``serving.paged_kv`` + ``_build_window_step``):
 - ``pos``:      [S, W] int32 global positions; key position j is visible
                 to window token (s, w) iff j <= pos[s, w]
 
-Serving never differentiates through the decode step, but the op still
-carries a VJP (backward = ``jax.vjp`` of the composed twin) so the
+Serving never differentiates through the decode step, but the kernel
+still carries a VJP (backward = ``jax.vjp`` of the reference) so the
 parity suite can pin gradients and nothing breaks if a scoring path
-ever backprops through it. The composed twin IS the PR-11 gather-then-
-attend math — on CPU the registry resolves to it, so the paged-decode
-step is by construction no slower than the gather path there; the TPU
-A/B rides the bench ``fused_kernels`` recipe.
+ever backprops through it. The reference IS the PR-11 gather-then-attend
+math, and what the window step computes on any backend but the TPU; the
+two have not been timed against each other on the chip.
 """
 from __future__ import annotations
 
@@ -134,9 +133,8 @@ def _paged_pallas(q, k_arena, v_arena, tables, pos, scale, interpret):
         .reshape(S, W, nh, hd)
 
 
-def _paged_composed(q, k_arena, v_arena, tables, pos, scale):
-    """The PR-11 gather-then-attend math, verbatim (the CPU production
-    path and the TPU A/B reference)."""
+def _reference(q, k_arena, v_arena, tables, pos, scale):
+    """The PR-11 gather-then-attend math, verbatim."""
     S, W, nh, hd = q.shape
     _P, PL, kvh, _ = k_arena.shape
     B = tables.shape[1]
@@ -156,29 +154,22 @@ def _paged_composed(q, k_arena, v_arena, tables, pos, scale):
     return jnp.einsum("swhL,sLhd->swhd", probs, vv)
 
 
-def _run(q, k_arena, v_arena, tables, pos, scale, impl):
-    if impl in ("pallas", "interpret"):
-        return _paged_pallas(q, k_arena, v_arena, tables, pos, scale,
-                             interpret=(impl == "interpret"))
-    return _paged_composed(q, k_arena, v_arena, tables, pos, scale)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _paged(q, k_arena, v_arena, tables, pos, scale, impl):
-    return _run(q, k_arena, v_arena, tables, pos, scale, impl)
+def _paged(q, k_arena, v_arena, tables, pos, scale, interpret):
+    return _paged_pallas(q, k_arena, v_arena, tables, pos, scale, interpret)
 
 
-def _paged_fwd(q, k_arena, v_arena, tables, pos, scale, impl):
-    out = _run(q, k_arena, v_arena, tables, pos, scale, impl)
+def _paged_fwd(q, k_arena, v_arena, tables, pos, scale, interpret):
+    out = _paged_pallas(q, k_arena, v_arena, tables, pos, scale, interpret)
     return out, (q, k_arena, v_arena, tables, pos)
 
 
-def _paged_bwd(scale, impl, res, do):
+def _paged_bwd(scale, interpret, res, do):
     # serving never backprops through decode; the VJP exists for the
-    # parity suite and recomputes through the composed twin
+    # parity suite and recomputes through the reference
     q, k_arena, v_arena, tables, pos = res
     _, vjp = jax.vjp(
-        lambda qq, kk, vv: _paged_composed(qq, kk, vv, tables, pos, scale),
+        lambda qq, kk, vv: _reference(qq, kk, vv, tables, pos, scale),
         q, k_arena, v_arena)
     dq, dk, dv = vjp(do)
     return dq, dk, dv, None, None
@@ -191,21 +182,24 @@ def paged_attention(q, k_arena, v_arena, tables, pos, scale=None,
                     impl: str = None):
     """Window attention straight against the page table. ``q`` [S, W,
     nh, hd]; arenas [P, PL, kvh, hd]; ``tables`` [S, B]; ``pos`` [S, W]
-    (key j visible iff j <= pos). Returns [S, W, nh, hd] in q.dtype."""
+    (key j visible iff j <= pos). Returns [S, W, nh, hd] in q.dtype.
+    ``impl``: None (``registry.resolve``), 'pallas', 'interpret' or
+    'reference'."""
     nh, kvh = q.shape[2], k_arena.shape[2]
     if nh % kvh:
         raise ValueError(f"num_heads {nh} not a multiple of kv heads {kvh}")
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     if impl is None:
-        impl = resolve("paged_attention")[0]
-    return _paged(q, k_arena, v_arena, tables.astype(jnp.int32),
-                  pos.astype(jnp.int32), float(scale), impl)
+        impl = resolve("paged_attention")
+    tables, pos = tables.astype(jnp.int32), pos.astype(jnp.int32)
+    if impl == "reference":
+        return _reference(q, k_arena, v_arena, tables, pos, scale)
+    return _paged(q, k_arena, v_arena, tables, pos, float(scale),
+                  impl == "interpret")
 
 
 register_kernel(
     "paged_attention",
-    pallas=functools.partial(paged_attention, impl="pallas"),
-    composed=functools.partial(paged_attention, impl="composed"),
     doc="decode window attention against the PagedKVPool page table: "
         "per-page online softmax, no dense gathered context")

@@ -15,11 +15,11 @@ Two entry points:
   pattern ``s = x + res; y = norm(s)`` fused; ``s`` is returned as the
   new residual stream (both outputs carry cotangents in the VJP).
 
-Both carry custom VJPs whose backward is also one kernel (dx [+dres] and
-a cross-row dw accumulated in VMEM scratch over the sequential grid).
-The composed-XLA twin implements the identical math + VJP structure in
-jnp — the CPU production path and the TPU A/B reference. Parity is
-pinned by tests/test_pallas_kernels.py (fwd and grads, odd widths).
+The kernels carry custom VJPs whose backward is also one kernel (dx
+[+dres] and a cross-row dw accumulated in VMEM scratch over the
+sequential grid). The reference is the plain forward in jnp,
+differentiated by JAX — what every non-TPU backend runs. Parity is pinned
+by tests/test_pallas_kernels.py (fwd and grads, odd widths).
 """
 from __future__ import annotations
 
@@ -98,16 +98,6 @@ def _fwd_pallas(x2, r2, w, eps, residual, interpret):
         interpret=interpret,
     )(x2, w2)
     return y, x2, rstd
-
-
-def _fwd_composed(x2, r2, w, eps, residual):
-    if residual:
-        s = x2.astype(jnp.float32) + r2.astype(jnp.float32)
-    else:
-        s = x2.astype(jnp.float32)
-    rstd = jax.lax.rsqrt(jnp.mean(s * s, axis=-1, keepdims=True) + eps)
-    y = (s * rstd * w.astype(jnp.float32)).astype(x2.dtype)
-    return y, (s.astype(x2.dtype) if residual else x2), rstd
 
 
 # -- backward -----------------------------------------------------------------
@@ -193,43 +183,21 @@ def _bwd_pallas(s, w, rstd, dy, dr, residual, interpret):
     return dx, dw.reshape(h)
 
 
-def _bwd_composed(s, w, rstd, dy, dr, residual):
-    ds, dw = _bwd_body(s.astype(jnp.float32),
-                       w.astype(jnp.float32), rstd,
-                       dy.astype(jnp.float32),
-                       dr.astype(jnp.float32) if residual else None)
-    return ds.astype(s.dtype), dw.reshape(-1)
-
-
-# -- differentiable wrappers ([n, h] layout) ----------------------------------
-
-def _run_fwd(x2, r2, w, eps, impl, residual):
-    if impl in ("pallas", "interpret"):
-        return _fwd_pallas(x2, r2, w, eps, residual,
-                           interpret=(impl == "interpret"))
-    return _fwd_composed(x2, r2, w, eps, residual)
-
-
-def _run_bwd(s, w, rstd, dy, dr, impl, residual):
-    if impl in ("pallas", "interpret"):
-        return _bwd_pallas(s, w, rstd, dy, dr, residual,
-                           interpret=(impl == "interpret"))
-    return _bwd_composed(s, w, rstd, dy, dr, residual)
-
+# -- differentiable wrappers around the kernels ([n, h] layout) ---------------
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _rms2(x2, w, eps, impl):
-    return _run_fwd(x2, x2, w, eps, impl, residual=False)[0]
+def _rms2(x2, w, eps, interpret):
+    return _fwd_pallas(x2, x2, w, eps, False, interpret)[0]
 
 
-def _rms2_fwd(x2, w, eps, impl):
-    y, s, rstd = _run_fwd(x2, x2, w, eps, impl, residual=False)
+def _rms2_fwd(x2, w, eps, interpret):
+    y, s, rstd = _fwd_pallas(x2, x2, w, eps, False, interpret)
     return y, (s, w, rstd)
 
 
-def _rms2_bwd(eps, impl, res, dy):
+def _rms2_bwd(eps, interpret, res, dy):
     s, w, rstd = res
-    dx, dw = _run_bwd(s, w, rstd, dy, dy, impl, residual=False)
+    dx, dw = _bwd_pallas(s, w, rstd, dy, dy, False, interpret)
     return dx, dw.astype(w.dtype)
 
 
@@ -237,53 +205,65 @@ _rms2.defvjp(_rms2_fwd, _rms2_bwd)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _rms2_res(x2, r2, w, eps, impl):
-    y, s, _ = _run_fwd(x2, r2, w, eps, impl, residual=True)
+def _rms2_res(x2, r2, w, eps, interpret):
+    y, s, _ = _fwd_pallas(x2, r2, w, eps, True, interpret)
     return y, s
 
 
-def _rms2_res_fwd(x2, r2, w, eps, impl):
-    y, s, rstd = _run_fwd(x2, r2, w, eps, impl, residual=True)
+def _rms2_res_fwd(x2, r2, w, eps, interpret):
+    y, s, rstd = _fwd_pallas(x2, r2, w, eps, True, interpret)
     return (y, s), (s, w, rstd)
 
 
-def _rms2_res_bwd(eps, impl, res, cts):
+def _rms2_res_bwd(eps, interpret, res, cts):
     s, w, rstd = res
     dy, dr = cts
-    ds, dw = _run_bwd(s, w, rstd, dy, dr, impl, residual=True)
+    ds, dw = _bwd_pallas(s, w, rstd, dy, dr, True, interpret)
     return ds, ds, dw.astype(w.dtype)
 
 
 _rms2_res.defvjp(_rms2_res_fwd, _rms2_res_bwd)
 
 
+# -- the jnp reference ---------------------------------------------------------
+
+def _reference(x, w, eps):
+    xf = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
+    return (xf * jax.lax.rsqrt(var + eps)
+            * w.astype(jnp.float32)).astype(x.dtype)
+
+
 # -- public API ([..., h] layout) ---------------------------------------------
 
 def rms_norm(x, w, eps: float = 1e-6, impl: str = None):
-    """Fused RMSNorm over the last axis. ``impl``: None (registry pick),
-    'pallas', 'interpret' (Pallas through the interpreter — parity
-    tests), or 'composed' (the jnp twin)."""
+    """RMSNorm over the last axis. ``impl``: None (``registry.resolve``),
+    'pallas', 'interpret' (the kernel through the Pallas interpreter —
+    parity tests) or 'reference' (plain jnp)."""
     if impl is None:
-        impl = resolve("rms_norm")[0]
+        impl = resolve("rms_norm")
+    if impl == "reference":
+        return _reference(x, w, eps)
     h = x.shape[-1]
-    y = _rms2(x.reshape(-1, h), w, float(eps), impl)
+    y = _rms2(x.reshape(-1, h), w, float(eps), impl == "interpret")
     return y.reshape(x.shape)
 
 
 def rms_norm_residual(x, res, w, eps: float = 1e-6, impl: str = None):
-    """Fused ``s = x + res; y = rmsnorm(s) * w`` -> ``(y, s)`` — the
-    pre-norm decoder pattern with the residual add folded into the same
-    HBM pass. Returns the normed branch input and the new residual."""
+    """``s = x + res; y = rmsnorm(s) * w`` -> ``(y, s)`` — the pre-norm
+    decoder pattern with the residual add folded into the same HBM pass.
+    Returns the normed branch input and the new residual."""
     if impl is None:
-        impl = resolve("rms_norm")[0]
+        impl = resolve("rms_norm")
+    if impl == "reference":
+        s = x + res
+        return _reference(s, w, eps).astype(x.dtype), s
     h = x.shape[-1]
     y, s = _rms2_res(x.reshape(-1, h), res.reshape(-1, h), w, float(eps),
-                     impl)
+                     impl == "interpret")
     return y.reshape(x.shape), s.reshape(x.shape)
 
 
 register_kernel(
     "rms_norm",
-    pallas=functools.partial(rms_norm, impl="pallas"),
-    composed=functools.partial(rms_norm, impl="composed"),
     doc="RMSNorm (+residual) fused: one HBM pass fwd, one-kernel VJP")

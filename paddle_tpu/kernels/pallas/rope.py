@@ -1,6 +1,6 @@
 """Fused rotate-half RoPE — Pallas kernel (fwd + VJP).
 
-The composed form materializes cos/sin tables, splits the activation,
+The reference form materializes cos/sin tables, splits the activation,
 and concatenates — several elementwise HLOs over the full [b, s, h, d]
 q/k tensors. The fused kernel streams each sequence block once and
 computes the angles in-register from the block's global positions (no
@@ -12,8 +12,8 @@ cotangent — RoPE becomes memory-traffic-free to differentiate.
 
 ``pos_offset`` shifts the global positions (decode-cache append and the
 context-parallel rank offset ride this, matching ``models/llama.py``'s
-``rope_apply`` contract). Parity vs the composed twin (and the legacy
-``_rope`` primitive) is pinned by tests/test_pallas_kernels.py.
+``rope_apply`` contract). The reference is the plain forward in jnp,
+differentiated by JAX. Parity is pinned by tests/test_pallas_kernels.py.
 """
 from __future__ import annotations
 
@@ -90,39 +90,30 @@ def _rope_pallas(x, theta, pos_offset, inverse, interpret):
     )(x)
 
 
-def _rope_composed(x, theta, pos_offset, inverse):
+def _reference(x, theta, pos_offset):
     b, s, h, d = x.shape
     pos = jnp.arange(pos_offset, pos_offset + s, dtype=jnp.float32)
     inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    freqs = jnp.outer(pos, inv)
+    freqs = jnp.outer(pos, inv)  # [s, d/2]
     cos = jnp.cos(freqs)[None, :, None, :]
     sin = jnp.sin(freqs)[None, :, None, :]
-    if inverse:
-        sin = -sin
     xf = x.astype(jnp.float32)
     x1, x2 = xf[..., : d // 2], xf[..., d // 2:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
-def _run(x, theta, pos_offset, impl, inverse):
-    if impl in ("pallas", "interpret"):
-        return _rope_pallas(x, theta, pos_offset, inverse,
-                            interpret=(impl == "interpret"))
-    return _rope_composed(x, theta, pos_offset, inverse)
-
-
 @functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
-def _rope4(x, theta, pos_offset, impl):
-    return _run(x, theta, pos_offset, impl, inverse=False)
+def _rope4(x, theta, pos_offset, interpret):
+    return _rope_pallas(x, theta, pos_offset, False, interpret)
 
 
-def _rope4_fwd(x, theta, pos_offset, impl):
-    return _run(x, theta, pos_offset, impl, inverse=False), None
+def _rope4_fwd(x, theta, pos_offset, interpret):
+    return _rope_pallas(x, theta, pos_offset, False, interpret), None
 
 
-def _rope4_bwd(theta, pos_offset, impl, _res, dy):
-    return (_run(dy, theta, pos_offset, impl, inverse=True),)
+def _rope4_bwd(theta, pos_offset, interpret, _res, dy):
+    return (_rope_pallas(dy, theta, pos_offset, True, interpret),)
 
 
 _rope4.defvjp(_rope4_fwd, _rope4_bwd)
@@ -130,17 +121,17 @@ _rope4.defvjp(_rope4_fwd, _rope4_bwd)
 
 def rope_apply(x, theta: float = 10000.0, pos_offset: int = 0,
                impl: str = None):
-    """Fused rotate-half RoPE on [b, s, h, d]; d must be even. ``impl``:
-    None (registry pick), 'pallas', 'interpret', or 'composed'."""
+    """Rotate-half RoPE on [b, s, h, d]; d must be even. ``impl``: None
+    (``registry.resolve``), 'pallas', 'interpret' or 'reference'."""
     if x.shape[-1] % 2:
         raise ValueError(f"RoPE head_dim must be even, got {x.shape[-1]}")
     if impl is None:
-        impl = resolve("rope")[0]
-    return _rope4(x, float(theta), int(pos_offset), impl)
+        impl = resolve("rope")
+    if impl == "reference":
+        return _reference(x, theta, pos_offset)
+    return _rope4(x, float(theta), int(pos_offset), impl == "interpret")
 
 
 register_kernel(
-    "rope",
-    pallas=functools.partial(rope_apply, impl="pallas"),
-    composed=functools.partial(rope_apply, impl="composed"),
+    "rope", seq_local=False,
     doc="rotate-half RoPE: in-register angles, residual-free inverse VJP")

@@ -90,9 +90,8 @@ def _ssm_pallas(state, x, dt, a, b, c, d, interpret):
     return new_state, jnp.swapaxes(yt, 2, 3).reshape(R, H, P)
 
 
-def _ssm_composed(state, x, dt, a, b, c, d):
-    """The same step in plain ``jnp`` (the CPU production path and the TPU
-    A/B reference)."""
+def _reference(state, x, dt, a, b, c, d):
+    """The same step in plain ``jnp``."""
     H, G = state.shape[1], b.shape[1]
     bh, ch = (jnp.repeat(m, H // G, axis=1) for m in (b, c))  # [R, H, N]
     new = jnp.exp(dt * a)[:, :, None, None] * state + \
@@ -112,18 +111,16 @@ def ssm_step(state, x, dt, a, b, c, d, impl: str = None):
     if state.dtype != F32:
         raise ValueError(f"state must be float32, got {state.dtype}")
     if impl is None:
-        impl = resolve("ssm_step")[0]
+        impl = resolve("ssm_step")
     args = [t.astype(F32) for t in (x, dt, a, b, c, d)]
     x, dt, a, b, c, d = args
-    if impl in ("pallas", "interpret"):
-        return _ssm_pallas(state, x, dt, a, b, c, d,
-                           interpret=(impl == "interpret"))
-    return _ssm_composed(state, x, dt, a, b, c, d)
+    if impl == "reference":
+        return _reference(state, x, dt, a, b, c, d)
+    return _ssm_pallas(state, x, dt, a, b, c, d,
+                       interpret=(impl == "interpret"))
 
 
 register_kernel(
     "ssm_step",
-    pallas=functools.partial(ssm_step, impl="pallas"),
-    composed=functools.partial(ssm_step, impl="composed"),
     doc="one step of the Mamba-2 recurrence over the slot-indexed state "
         "arena: state read and written once, in place")

@@ -1,61 +1,64 @@
-"""Fused-kernel registry: ONE dispatch seam for the Pallas op library.
+"""Kernel registry: the ONE seam between a fused op's call sites and its
+implementations.
 
 Reference role: paddle/fluid/operators/fused/ — the reference ships its
 hot-path fusions (fused_attention, fused_ffn, fused_rms_norm) as separate
-CUDA kernels picked by a pass. TPU-native mapping: each fused op registers
-here with TWO implementations of the SAME fused algorithm:
+CUDA kernels picked by a pass. TPU-native mapping: each op of
+``kernels/pallas/`` is one public function holding exactly two
+implementations — the Pallas TPU kernel and a plain ``jnp`` reference —
+and ``resolve(name)`` is the one function that says which runs, from what
+the process can observe:
 
-- ``pallas``: the Pallas TPU kernel (``kernels/pallas/``). On CPU the same
-  kernel runs in interpret mode when ``PT_PALLAS_INTERPRET=1`` — that is
-  the parity-test surface, not a production path (the interpreter is slow).
-- ``composed``: the composed-XLA twin — identical math and custom-VJP
-  structure, expressed in jnp. Fast on CPU (tier-1, virtual meshes) and
-  the A/B reference on TPU.
+- ``"reference"`` where a Mosaic call cannot run under the live mesh
+  (``distributed.mesh.kernel_mesh_ok``: ``pp > 1``; ``cp > 1`` for a
+  kernel that needs global sequence positions), whatever the platform;
+- ``"interpret"`` (the Pallas kernel through the Pallas interpreter) when
+  ``PT_PALLAS_INTERPRET=1`` — the parity tests' hook, not a production
+  path (the interpreter is slow);
+- ``"pallas"`` on the TPU, ``"reference"`` on any other backend.
 
-Call sites gate on ``fused_enabled(name)`` (live ``FLAGS_fused_kernels``:
-``auto`` = fused on TPU, legacy composed-XLA path on CPU; ``on``/``off``
-force it; a comma list enables exactly the named ops on any backend) and
-then call ``resolve(name)`` for the implementation. The gate decision must
-reach the jit cache key — layer code passes it as a primitive ATTR (see
-``nn/functional/common.py``, ``models/llama.py``) so a flag flip retraces
-and the ``analysis.retrace`` auditor names the flip.
+No flag selects an implementation. Layer code passes the answer down as a
+primitive ATTR (``nn/functional/common.py``, ``models/llama.py``), as
+``sdpa`` does with its ``impl``: the live mesh can change inside a process,
+and the op cache and the ``analysis.retrace`` auditor must see it.
 
-``kernel_table()`` is the introspection surface (per-op choice + trace
+``kernel_table()`` is the introspection surface (per-op answer + decision
 counts), registered as the ``fused_kernels`` observability provider; the
 PR-9 planner prices the same entries via ``cost_model.fused``.
 """
 from __future__ import annotations
 
-import functools
 import os
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Dict, Tuple
 
-__all__ = ["register_kernel", "fused_enabled", "resolve", "kernel_table",
-           "enabled_ops", "KernelEntry"]
+__all__ = ["register_kernel", "resolve", "kernel_table", "enabled_ops",
+           "KernelEntry", "IMPLS"]
+
+IMPLS = ("pallas", "interpret", "reference")
 
 
 class KernelEntry:
-    __slots__ = ("name", "pallas", "composed", "doc", "calls")
+    __slots__ = ("name", "seq_local", "doc", "calls")
 
-    def __init__(self, name: str, pallas: Callable, composed: Callable,
-                 doc: str = ""):
+    def __init__(self, name: str, seq_local: bool = True, doc: str = ""):
         self.name = name
-        self.pallas = pallas
-        self.composed = composed
+        # False: the kernel computes from GLOBAL sequence positions (RoPE)
+        # and cannot run on a sequence-split mesh
+        self.seq_local = seq_local
         self.doc = doc
-        # trace-time counters per implementation (a count here is a
-        # compile-side event, not a per-step cost — the audit semantics)
-        self.calls: Dict[str, int] = {"pallas": 0, "interpret": 0,
-                                      "composed": 0}
+        # decisions taken, by answer: one per call that reaches the seam —
+        # every eager op call, and once per trace of a compiled program
+        # (a cached program does not decide again)
+        self.calls: Dict[str, int] = dict.fromkeys(IMPLS, 0)
 
 
 _KERNELS: Dict[str, KernelEntry] = {}
 _PROVIDER_REGISTERED = False
 
 
-def register_kernel(name: str, *, pallas: Callable, composed: Callable,
+def register_kernel(name: str, *, seq_local: bool = True,
                     doc: str = "") -> KernelEntry:
-    entry = KernelEntry(name, pallas, composed, doc)
+    entry = KernelEntry(name, seq_local, doc)
     _KERNELS[name] = entry
     _ensure_provider()
     return entry
@@ -75,102 +78,62 @@ def _ensure_provider():
 
 
 def _backend() -> str:
-    # no fallback: a backend that cannot be asked is an error the caller
+    # the one platform probe every decision reads, at call time. No
+    # fallback: a backend that cannot be asked is an error the caller
     # must see, not a silent "cpu" (which would hide the device)
     import jax
 
     return jax.default_backend()
 
 
-def _flag() -> str:
-    from ..framework import flags as flags_mod
+def _decide(entry: KernelEntry) -> str:
+    from ..distributed.mesh import kernel_mesh_ok
 
-    return str(flags_mod.get_flags("FLAGS_fused_kernels")
-               ["FLAGS_fused_kernels"]).strip()
+    if not kernel_mesh_ok(seq_local=entry.seq_local):
+        return "reference"  # GSPMD partitions the jnp form itself
+    if os.environ.get("PT_PALLAS_INTERPRET", "0") == "1":
+        return "interpret"
+    return "pallas" if _backend() == "tpu" else "reference"
 
 
-def fused_enabled(name: str) -> bool:
-    """Live per-op gate: should this call site take the fused path?
-
-    ``auto`` (default): fused on TPU, legacy composed-XLA on CPU — tier-1
-    keeps running the code it always ran. ``on``: fused everywhere (CPU
-    executes the composed twin unless ``PT_PALLAS_INTERPRET=1``).
-    ``off``: never. A comma-separated op list enables exactly those ops on
-    any backend (e.g. ``rms_norm,rope``).
-    """
-    if name not in _KERNELS:
-        _register_builtin()  # first touch in this process
-    if name not in _KERNELS:
-        return False
-    mode = _flag()
-    if mode == "off":
-        return False
-    if mode == "on":
-        return True
-    if mode == "auto" or not mode:
-        return _backend() == "tpu"
-    return name in {m.strip() for m in mode.split(",") if m.strip()}
+def resolve(name: str) -> str:
+    """Which implementation of op ``name`` runs here and now: one of
+    ``IMPLS`` (see the module docstring for the rule). Counted in the
+    entry's ``calls``."""
+    entry = registry()[name]
+    impl = _decide(entry)
+    entry.calls[impl] += 1
+    return impl
 
 
 def enabled_ops() -> Tuple[str, ...]:
-    _register_builtin()  # a fresh process has an empty table
-    return tuple(sorted(n for n in _KERNELS if fused_enabled(n)))
-
-
-def _interpret_forced() -> bool:
-    return os.environ.get("PT_PALLAS_INTERPRET", "0") == "1"
-
-
-def resolve(name: str) -> Tuple[str, Callable]:
-    """(impl, fn) for one fused op: ``pallas`` on TPU, ``composed`` on CPU,
-    ``interpret`` (the Pallas kernel through the interpreter) when
-    ``PT_PALLAS_INTERPRET=1`` — the parity-test hook. The choice is
-    per-process (backend cannot change mid-process); the live gate is
-    ``fused_enabled``, which call sites thread into their jit cache keys.
-    """
-    if name not in _KERNELS:
-        _register_builtin()
-    entry = _KERNELS[name]
-    if _interpret_forced():
-        entry.calls["interpret"] += 1
-        return "interpret", functools.partial(entry.pallas, impl="interpret")
-    if _backend() == "tpu":
-        entry.calls["pallas"] += 1
-        return "pallas", entry.pallas
-    entry.calls["composed"] += 1
-    return "composed", entry.composed
+    """The ops whose Pallas kernel this platform would run (the planner's
+    default ``fused_kernels`` set). Of the platform alone: the planner
+    prices candidate meshes, not the live one."""
+    return tuple(sorted(registry())) if _backend() == "tpu" else ()
 
 
 def kernel_table() -> Dict[str, Any]:
-    """Per-op dispatch truth: which implementation each registered fused
-    op resolves to right now, whether its call-site gate is open, and the
-    trace-time call counts (the ``fused_kernels`` hub provider)."""
-    _register_builtin()
-    backend = _backend()
-    mode = _flag()
-    impl = "interpret" if _interpret_forced() else (
-        "pallas" if backend == "tpu" else "composed")
+    """Per-op dispatch truth: the implementation each registered op
+    resolves to right now (backend, live mesh, interpreter hook) and how
+    many decisions went to each so far (``KernelEntry.calls``) — the
+    ``fused_kernels`` hub provider. Reading the table counts nothing."""
     return {
-        "flag": mode,
-        "backend": backend,
+        "backend": _backend(),
         "ops": {
             name: {
-                "enabled": fused_enabled(name),
-                "impl": impl,
+                "impl": _decide(e),
                 "calls": dict(e.calls),
                 "doc": e.doc,
             }
-            for name, e in sorted(_KERNELS.items())
+            for name, e in sorted(registry().items())
         },
     }
 
 
-def _register_builtin():
-    """Import the Pallas library so its ops land in the registry (safe to
-    call repeatedly; imports are idempotent)."""
+def registry() -> Dict[str, KernelEntry]:
+    """The table, with the Pallas library's ops in it (importing the
+    package registers them; a fresh process has an empty table)."""
     from . import pallas as _  # noqa: F401
 
-
-def registry() -> Dict[str, KernelEntry]:
-    _register_builtin()
     return _KERNELS

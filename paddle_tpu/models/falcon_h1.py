@@ -248,11 +248,9 @@ def _ssm_branch(cfg: FalconH1Config, p, u, state, valid):
         if W != 1:
             raise ValueError("one token a step over a live state")
         from ..kernels.pallas.ssm_step import ssm_step
-        from ..kernels.registry import fused_enabled
 
-        impl = None if fused_enabled("ssm_step") else "composed"
         ssm, y = ssm_step(state["ssm"], xh[:, 0], dt[:, 0], a, bg[:, 0],
-                          cg[:, 0], d, impl=impl)
+                          cg[:, 0], d)
         y = y[:, None]
     y = y.reshape(R, W, d_ssm) * jax.nn.silu(z)
     yg = y.reshape(R, W, G, d_ssm // G)

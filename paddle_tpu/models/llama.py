@@ -91,38 +91,27 @@ class LlamaMoEConfig(LlamaConfig):
 
 
 @primitive("rope_apply")
-def _rope(x, *, theta, pos_offset, fused=False):
+def _rope(x, *, theta, pos_offset, impl):
     # x: [b, s, h, d]; rotate-half RoPE in fp32
-    if fused:
-        from ..distributed.mesh import activation_spec, run_kernel_on_mesh
-        from ..kernels.pallas.rope import rope_apply as _fused_rope
+    from ..kernels.pallas.rope import rope_apply as kernel
 
-        # seq stays unsplit, so every shard sees global positions
-        spec = activation_spec(x.shape, "bshd")
-        return run_kernel_on_mesh(
-            lambda xl: _fused_rope(xl, theta, pos_offset), (x,), (spec,),
-            spec)
-    b, s, h, d = x.shape
-    pos = jnp.arange(pos_offset, pos_offset + s, dtype=jnp.float32)
-    inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
-    freqs = jnp.outer(pos, inv)  # [s, d/2]
-    cos = jnp.cos(freqs)[None, :, None, :]
-    sin = jnp.sin(freqs)[None, :, None, :]
-    xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., : d // 2], xf[..., d // 2 :]
-    out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
-    return out.astype(x.dtype)
+    if impl == "reference":  # plain jnp: GSPMD partitions it itself
+        return kernel(x, theta, pos_offset, impl)
+    from ..distributed.mesh import activation_spec, run_kernel_on_mesh
+
+    # seq stays unsplit, so every shard sees global positions
+    spec = activation_spec(x.shape, "bshd")
+    return run_kernel_on_mesh(
+        lambda xl: kernel(xl, theta, pos_offset, impl), (x,), (spec,), spec)
 
 
 def apply_rotary_pos_emb(x: Tensor, theta: float = 10000.0, pos_offset: int = 0) -> Tensor:
-    # the fused-kernel gate is a primitive ATTR (cache-key participant):
-    # an FLAGS_fused_kernels flip retraces and the retrace auditor names it
-    from ..distributed.mesh import kernel_mesh_ok
-    from ..kernels.registry import fused_enabled
+    # the implementation is a primitive ATTR (cache-key participant): a
+    # change of it retraces and the retrace auditor names it
+    from ..kernels.registry import resolve
 
     return _rope(x, theta=float(theta), pos_offset=int(pos_offset),
-                 fused=fused_enabled("rope")
-                 and kernel_mesh_ok(seq_local=False))
+                 impl=resolve("rope"))
 
 
 def _cp_axes():
@@ -229,25 +218,16 @@ class LlamaDecoderLayer(nn.Layer):
         self.post_attention_layernorm = nn.RMSNorm(config.hidden_size, config.rms_norm_eps)
 
     def forward(self, hidden):
-        from ..nn.functional.common import _rms_fused_gate
-
         hidden = _mark_seq(hidden)
-        if _rms_fused_gate():
-            # fused residual-add + norm: the attn output, the residual
-            # stream and the post-norm read/write collapse into one HBM
-            # pass (kernels/pallas/rmsnorm.py); the first norm of the
-            # layer has no preceding add, so it fuses as the plain kernel
-            attn_out = self.self_attn(self.input_layernorm(hidden))
-            mlp_in, hidden = F.rms_norm_residual(
-                attn_out, hidden, self.post_attention_layernorm.weight,
-                self.post_attention_layernorm._epsilon)
-            hidden = hidden + self.mlp(mlp_in)
-        else:
-            residual = hidden
-            hidden = residual + self.self_attn(self.input_layernorm(hidden))
-            residual = hidden
-            hidden = residual + self.mlp(
-                self.post_attention_layernorm(hidden))
+        # residual add + post-attention norm as ONE op: where the Pallas
+        # kernel runs, the attn output, the residual stream and the norm's
+        # read/write collapse into one HBM pass (kernels/pallas/rmsnorm.py);
+        # the first norm of the layer has no preceding add
+        attn_out = self.self_attn(self.input_layernorm(hidden))
+        mlp_in, hidden = F.rms_norm_residual(
+            attn_out, hidden, self.post_attention_layernorm.weight,
+            self.post_attention_layernorm._epsilon)
+        hidden = hidden + self.mlp(mlp_in)
         return _mark_seq(hidden)
 
 

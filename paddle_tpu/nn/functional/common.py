@@ -169,61 +169,55 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5, name=N
 
 
 @primitive("rms_norm_op")
-def _rms_norm(x, w, *, eps, fused=False):
-    if fused:
-        from jax.sharding import PartitionSpec as P
+def _rms_norm(x, w, *, eps, impl):
+    from ...kernels.pallas.rmsnorm import rms_norm as kernel
 
-        from ...distributed.mesh import activation_spec, run_kernel_on_mesh
-        from ...kernels.pallas.rmsnorm import rms_norm as _fused
+    if impl == "reference":  # plain jnp: GSPMD partitions it itself
+        return kernel(x, w, eps, impl)
+    from jax.sharding import PartitionSpec as P
 
-        spec = activation_spec(x.shape, "rows")
-        return run_kernel_on_mesh(lambda xl, wl: _fused(xl, wl, eps),
-                                  (x, w), (spec, P()), spec)
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    xn = x.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
-    return (xn * w.astype(jnp.float32)).astype(x.dtype)
+    from ...distributed.mesh import activation_spec, run_kernel_on_mesh
+
+    spec = activation_spec(x.shape, "rows")
+    return run_kernel_on_mesh(lambda xl, wl: kernel(xl, wl, eps, impl),
+                              (x, w), (spec, P()), spec)
 
 
 @primitive("rms_norm_residual_op")
-def _rms_norm_residual(x, res, w, *, eps, fused=False):
+def _rms_norm_residual(x, res, w, *, eps, impl):
     """Pre-norm decoder pattern ``s = x + res; y = rmsnorm(s)`` ->
-    (y, s): fused through kernels/pallas when the registry gate is open,
-    else the composed two-op form (identical math)."""
-    if fused:
-        from jax.sharding import PartitionSpec as P
+    (y, s), one HBM pass where the Pallas kernel runs."""
+    from ...kernels.pallas.rmsnorm import rms_norm_residual as kernel
 
-        from ...distributed.mesh import activation_spec, run_kernel_on_mesh
-        from ...kernels.pallas.rmsnorm import rms_norm_residual as _fused
+    if impl == "reference":
+        return kernel(x, res, w, eps, impl)
+    from jax.sharding import PartitionSpec as P
 
-        spec = activation_spec(x.shape, "rows")
-        return run_kernel_on_mesh(
-            lambda xl, rl, wl: _fused(xl, rl, wl, eps), (x, res, w),
-            (spec, spec, P()), (spec, spec))
-    s = x + res
-    var = jnp.mean(jnp.square(s.astype(jnp.float32)), axis=-1, keepdims=True)
-    sn = s.astype(jnp.float32) * jax.lax.rsqrt(var + eps)
-    return (sn * w.astype(jnp.float32)).astype(x.dtype), s
+    from ...distributed.mesh import activation_spec, run_kernel_on_mesh
 
-
-def _rms_fused_gate() -> bool:
-    from ...distributed.mesh import kernel_mesh_ok
-    from ...kernels.registry import fused_enabled
-
-    return fused_enabled("rms_norm") and kernel_mesh_ok()
+    spec = activation_spec(x.shape, "rows")
+    return run_kernel_on_mesh(
+        lambda xl, rl, wl: kernel(xl, rl, wl, eps, impl), (x, res, w),
+        (spec, spec, P()), (spec, spec))
 
 
 def rms_norm(x, weight, epsilon=1e-6, name=None):
     """RMSNorm (not in the reference snapshot; required by the Llama
-    family). The fused-kernel gate rides the jit cache key as an attr,
-    so an ``FLAGS_fused_kernels`` flip retraces (retrace-auditable)."""
-    return _rms_norm(x, weight, eps=float(epsilon), fused=_rms_fused_gate())
+    family). The implementation ``kernels.registry.resolve`` picks rides
+    the jit cache key as an attr, so a change of it (another live mesh)
+    retraces (retrace-auditable)."""
+    from ...kernels.registry import resolve
+
+    return _rms_norm(x, weight, eps=float(epsilon), impl=resolve("rms_norm"))
 
 
 def rms_norm_residual(x, residual, weight, epsilon=1e-6, name=None):
     """Fused residual-add + RMSNorm -> ``(normed, new_residual)`` — the
     decoder-layer hot pattern (see docs/performance.md "Fused kernels")."""
+    from ...kernels.registry import resolve
+
     return _rms_norm_residual(x, residual, weight, eps=float(epsilon),
-                              fused=_rms_fused_gate())
+                              impl=resolve("rms_norm"))
 
 
 @primitive("batch_norm_infer_op")

@@ -150,8 +150,8 @@ class _Slot:
 
 def _served(model_or_cfg) -> ServedModel:
     """The protocol object of a model — or of a bare GPT config, which is
-    what the callers that predate the seam (tests, ``bench.py``, the AOT
-    rehearsal) hand the program builders."""
+    what the callers that predate the seam (tests, the AOT rehearsal) hand
+    the program builders."""
     if isinstance(model_or_cfg, ServedModel):
         return model_or_cfg
     if hasattr(model_or_cfg, "served_model"):
@@ -161,7 +161,8 @@ def _served(model_or_cfg) -> ServedModel:
 
 def _extract_gpt_params(model):
     """The live weights of a ``GPTForCausalLM`` as the engine's pytree
-    (``GPTServed.params``; kept under its old name for its callers)."""
+    (``GPTServed.params``; kept under its old name for
+    ``benchmark/rehearse_aot.py``: ROADMAP D14)."""
     return GPTServed(model.config).params(model)
 
 
@@ -217,7 +218,7 @@ def _build_decode_step(cfg, max_slots: int, max_len: int, donate: bool,
 
 def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
                        window: int, donate: bool, label: str,
-                       fused: bool = False, prefill: bool = False):
+                       fused: bool = True, prefill: bool = False):
     """The PAGED executable family: embed ``W = window`` tokens per slot
     at positions ``lengths + [0..W)``, run the served model's blocks — each
     block's ``attend(q, k, v)`` writes K/V through the page tables into the
@@ -247,17 +248,18 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     donated like the K/V arenas) are advanced one step and returned
     updated, in place. A model without state gets and returns ``None``.
 
-    ``fused=True`` (registry-gated: ``FLAGS_fused_kernels``) attends
-    straight against the page table through the Pallas paged-attention
-    kernel — the dense ``kc[tables]`` gathered context never
-    materializes; ``fused=False`` keeps the composed gather-then-attend
-    path (the CPU production path and the TPU A/B reference).
+    Attention is ``kernels.pallas.paged_attention``: on the TPU the Pallas
+    kernel attends straight against the page table (the dense
+    ``kc[tables]`` gathered context never materializes), elsewhere its jnp
+    reference gathers and attends — ``kernels.registry.resolve`` decides
+    as the program is traced. ``fused`` selects nothing: it is accepted,
+    as ``True`` only, for ``benchmark/rehearse_aot*.py`` (ROADMAP D14).
     """
     import jax
     import jax.numpy as jnp
 
     sm = _served(served)
-    nh, kvh, hd = sm.num_heads, sm.num_kv_heads, sm.head_dim
+    kvh, hd = sm.num_kv_heads, sm.head_dim
     scale = sm.attn_scale
     stateful = sm.state_spec is not None
     if stateful and not prefill and window != 1:
@@ -265,18 +267,21 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
             "a model with recurrent state decodes one token a round: "
             f"no {window}-token window over live state")
     S, B, W, PL = max_slots, n_blocks, window, page_len
-    L = B * PL  # gathered context length per slot
+    if fused is not True:
+        raise ValueError(
+            "fused= no longer selects a path: kernels.registry.resolve "
+            "decides; for the jnp reference call kernels.pallas."
+            "paged_attention.paged_attention(..., impl='reference')")
 
-    if fused:
-        from ..kernels.pallas.paged_attention import paged_attention
+    from ..kernels.pallas.paged_attention import paged_attention
 
-        # ONE jitted callable for every layer: the kernel is traced and
-        # lowered once a program and called L times, not traced L times
-        # (the 36 kernel traces of a GPT-2-large program were most of
-        # warmup's time, PERF.md section 6, PR 28); XLA inlines the calls
-        @jax.jit
-        def paged_attend(q, kk, vv, tables, pos):
-            return paged_attention(q, kk, vv, tables, pos, scale=scale)
+    # ONE jitted callable for every layer: the kernel is traced and
+    # lowered once a program and called L times, not traced L times
+    # (the 36 kernel traces of a GPT-2-large program were most of
+    # warmup's time, PERF.md section 6, PR 28); XLA inlines the calls
+    @jax.jit
+    def paged_attend(q, kk, vv, tables, pos):
+        return paged_attention(q, kk, vv, tables, pos, scale=scale)
 
     def step(params, k_arenas, v_arenas, tables, tokens, lengths,
              n_valid=None, state=None):
@@ -284,8 +289,6 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
         P = k_arenas[0].shape[0]
         pos = lengths[:, None] + jnp.arange(W)                     # [S, W]
         x = sm.embed(params, tokens, pos)                          # [S, W, h]
-        j = jnp.arange(L)
-        mask = j[None, None, :] <= pos[:, :, None]                 # [S, W, L]
         # write positions: page-table lookup of each window token's block;
         # blocks past the table (or past a request's allocation: table
         # entry 0) land in the scratch page — never another slot's pages
@@ -305,24 +308,8 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
                     v1.reshape(S * W, kvh, hd)).reshape(P, PL, kvh, hd)
                 new_k.append(kk)
                 new_v.append(vv)
-                if fused:
-                    # attend against the page table directly (per-page
-                    # online softmax); key j visible iff j <= pos[s, w] —
-                    # the same containment the composed mask enforces
-                    # impl resolves through the registry: Pallas on TPU,
-                    # the composed twin on CPU, interpreter under
-                    # PT_PALLAS_INTERPRET=1 (parity tests)
-                    return paged_attend(q, kk, vv, tables, pos)
-                gk = kk[tables].reshape(S, L, kvh, hd)
-                gv = vv[tables].reshape(S, L, kvh, hd)
-                if kvh != nh:
-                    gk = jnp.repeat(gk, nh // kvh, axis=2)
-                    gv = jnp.repeat(gv, nh // kvh, axis=2)
-                logits = jnp.einsum("swhd,sLhd->swhL", q, gk)
-                logits = logits.astype(jnp.float32) * scale
-                logits = jnp.where(mask[:, :, None, :], logits, -1e30)
-                probs = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
-                return jnp.einsum("swhL,sLhd->swhd", probs, gv)
+                # key j of the slot's pages is visible iff j <= pos[s, w]
+                return paged_attend(q, kk, vv, tables, pos)
 
             x, st = sm.block(p, x, pos, attend,
                              None if state is None else state[li], valid)
@@ -561,20 +548,13 @@ class GenerationEngine(EngineBase):
         fn = self._windows.get(key)
         if fn is None:
             from .. import jit as jit_mod
-            from ..kernels.registry import fused_enabled
 
-            # build-time decision (executables are cached per engine);
-            # the ":fused" label suffix keeps the retrace audit and the
-            # persistent-cache keyspace honest about which path compiled
-            fused = fused_enabled("paged_attention")
             role = "prefill" if prefill else "window"
-            label = f"serving:{self.name}:{role}{W}" + \
-                (":fused" if fused else "")
+            label = f"serving:{self.name}:{role}{W}"
             fn = jit_mod._maybe_audit(
                 label, _build_window_step(self._sm, rows,
                                           self._n_blocks, self._pl, W,
                                           self._donate, label=label,
-                                          fused=fused,
                                           prefill=prefill))
             self._windows[key] = fn
         return fn

@@ -2,8 +2,8 @@
 
 The phases of ``chip_smoke.py`` are functions of a model config: here the
 train, serve and kernel phases run end to end at tiny sizes with the REAL
-Pallas kernels through the interpreter (``FLAGS_fused_kernels=on`` +
-``PT_PALLAS_INTERPRET=1``), and the sharded phase on the 8-device virtual
+Pallas kernels through the interpreter (``PT_PALLAS_INTERPRET=1``), and
+the sharded phase on the 8-device virtual
 mesh. ``main()`` itself has no size option and refuses to run without a
 chip — pinned here too.
 """
@@ -15,7 +15,6 @@ import jax
 import pytest
 
 import chip_smoke
-from paddle_tpu.framework import flags as flags_mod
 from paddle_tpu.models import GPTConfig, LlamaConfig
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -23,13 +22,10 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 @pytest.fixture
 def interpreted_kernels(monkeypatch):
-    """The chip's path at tiny size: every fused-op gate open, the Pallas
-    kernels themselves executed by the interpreter."""
+    """The chip's path at tiny size: the Pallas kernels themselves,
+    executed by the interpreter."""
     monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
-    prior = flags_mod.get_flags("FLAGS_fused_kernels")
-    flags_mod.set_flags({"FLAGS_fused_kernels": "on"})
-    yield "interpret"
-    flags_mod.set_flags(prior)
+    return "interpret"
 
 
 def test_main_refuses_without_a_chip():
@@ -73,7 +69,7 @@ def test_serve_phase_tiny(interpreted_kernels):
 def test_sharded_phase_tiny_on_virtual_mesh(interpreted_kernels):
     """§2 step 2: the multi-chip phase's meshes and sharding rules on the
     virtual devices (8 here; the chip run uses the host's four) — with the
-    fused-kernel gates open, so the kernels run where the chip runs them:
+    kernels interpreted, so they run where the chip runs them:
     inside ``run_kernel_on_mesh``'s shard_map (GSPMD cannot partition a
     Mosaic call)."""
     if len(jax.devices()) != 8:

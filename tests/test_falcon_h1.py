@@ -192,7 +192,7 @@ def _recurrence_inputs(key, T, R, H, P, N, G):
 
 
 def test_ssm_step_kernel_twin_and_reference_recurrence():
-    """``pt_ssm_step`` through the Pallas interpreter, its composed twin,
+    """``pt_ssm_step`` through the Pallas interpreter, its jnp reference,
     and T steps of the reference's plain recurrence; a row whose ``dt`` is
     0 keeps its state bit for bit."""
     from paddle_tpu.kernels.pallas.ssm_step import ssm_step
@@ -207,7 +207,7 @@ def test_ssm_step_kernel_twin_and_reference_recurrence():
         s_i, y_i = ssm_step(s_i, x[t], dt[t], a, b[t], c[t], d,
                             impl="interpret")
         s_c, y_c = ssm_step(s_c, x[t], dt[t], a, b[t], c[t], d,
-                            impl="composed")
+                            impl="reference")
         np.testing.assert_allclose(y_i, y_c, atol=2e-5)
     np.testing.assert_allclose(s_i, s_c, atol=2e-5)
     np.testing.assert_array_equal(s_i[1], s0[1])
@@ -316,7 +316,7 @@ def test_the_benchmarks_reference_is_the_repos():
 
 # -- GPT-2 through the seam against the parent's hand-written step -------------
 
-def _parent_window_step(cfg, S, B, W, PL, fused):
+def _parent_window_step(cfg, S, B, W, PL, gather):
     """``_build_window_step`` as it stood before the seam (PR 26), kept here
     as the oracle: GPT-2's block written out inside the engine."""
     from paddle_tpu.kernels.pallas.paged_attention import paged_attention
@@ -349,7 +349,7 @@ def _parent_window_step(cfg, S, B, W, PL, fused):
                 k1.reshape(S * W, nh, hd)).reshape(P, PL, nh, hd)
             vc = vc.reshape(P * PL, nh, hd).at[flat].set(
                 v1.reshape(S * W, nh, hd)).reshape(P, PL, nh, hd)
-            if fused:
+            if not gather:
                 ctx = paged_attention(q, kc, vc, tables, pos, scale=scale)
             else:
                 kk = kc[tables].reshape(S, L, nh, hd)
@@ -376,13 +376,14 @@ def _parent_window_step(cfg, S, B, W, PL, fused):
     return step
 
 
-@pytest.mark.parametrize("fused", [False, True])
+@pytest.mark.parametrize("oracle", ["gather", "paged_attention"])
 @pytest.mark.parametrize("rows,W", [(3, 1), (1, 16), (3, 4)])
-def test_gpt2_through_the_seam_is_the_parents_program(rows, W, fused):
+def test_gpt2_through_the_seam_is_the_parents_program(rows, W, oracle):
     """Decode, one-row prefill and verify: bit-equal outputs to the parent's
-    hand-written step, and the SAME lowered program text on the composed
-    path; on the fused path the attention kernel is one called function
-    (XLA inlines it: the compiled program has the parent's instructions)."""
+    hand-written step, with its attention written out as gather-then-attend
+    and through ``paged_attention``. The one difference in the program: the
+    attention is one called function (XLA inlines it: the compiled program
+    has the parent's instructions)."""
     from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 
     paddle.seed(0)
@@ -402,21 +403,17 @@ def test_gpt2_through_the_seam_is_the_parents_program(rows, W, fused):
             jnp.asarray(rng.integers(0, 64, (rows, W)), jnp.int32),
             jnp.asarray(rng.integers(0, 12, rows), jnp.int32))
     ours = gen._build_window_step(model.served_model(), rows, B, PL, W,
-                                  donate=False, label="t28:seam",
-                                  fused=fused)
-    theirs = jax.jit(_parent_window_step(cfg, rows, B, W, PL, fused))
+                                  donate=False, label=f"t28:seam:{oracle}")
+    theirs = jax.jit(_parent_window_step(cfg, rows, B, W, PL,
+                                         gather=oracle == "gather"))
     from paddle_tpu.jit import lowerable
 
+    # ONE function the program calls once a layer (traced and lowered
+    # once), not a copy a layer
     ours_text = lowerable(ours).lower(*args).as_text()
-    if fused:
-        # the one difference: the attention is ONE function the program
-        # calls once a layer (traced and lowered once), not a copy a layer
-        assert ours_text.count("call @paged_attend") == \
-            cfg.num_hidden_layers
-        assert len(re.findall(r"func\.func private @paged_attend\w*\(",
-                              ours_text)) == 1
-    else:
-        assert ours_text == theirs.lower(*args).as_text()
+    assert ours_text.count("call @paged_attend") == cfg.num_hidden_layers
+    assert len(re.findall(r"func\.func private @paged_attend\w*\(",
+                          ours_text)) == 1
     for a, b in zip(jax.tree_util.tree_leaves(ours(*args)),
                     jax.tree_util.tree_leaves(theirs(*args))):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

@@ -1,10 +1,8 @@
-"""Round-6 satellite fixes: bench headline contract, master rendezvous
-diagnostics, port reservations, checkpoint accumulator resharding."""
+"""Round-6 satellite fixes: master rendezvous diagnostics, port
+reservations, checkpoint accumulator resharding."""
 import json
 import os
 import socket
-import subprocess
-import sys
 import threading
 
 import numpy as np
@@ -15,136 +13,6 @@ import paddle_tpu.optimizer as opt
 from paddle_tpu import jit
 from paddle_tpu.distributed.run.master import (
     Master, free_port, release_reserved_ports, reserve_port)
-
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-# -- bench.py headline contract ----------------------------------------------
-
-@pytest.mark.slow  # tier-1 wall-clock relief (ISSUE-5): the full CPU bench
-# smoke runs minutes; tools/ci.sh's perf gate runs it and asserts MORE
-# (first+last line parse, size cap, stream_capacity/persistent_cache rows)
-def test_bench_prints_compact_parseable_headline():
-    """The driver contract: bench.py emits a compact parseable headline
-    JSON line on stdout (CPU smoke path) well within budget."""
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        capture_output=True, text=True, timeout=600,
-        env={**os.environ, "JAX_PLATFORMS": "cpu"})
-    assert r.returncode == 0, r.stderr[-2000:]
-    lines = [ln for ln in r.stdout.splitlines() if ln.startswith("{")]
-    assert lines, f"no JSON line on stdout: {r.stdout[-500:]}"
-    parsed = json.loads(lines[-1])
-    assert parsed["metric"] == "llama_pretrain_mfu"
-    assert "value" in parsed and "vs_baseline" in parsed
-    # r4's failure mode was an oversized line; keep every printed line small
-    assert all(len(ln) < 8192 for ln in lines)
-
-
-def test_bench_compact_strips_heavy_keys():
-    import bench
-
-    detail = {"mfu": 50.0,
-              "device_op_table": {"rows": list(range(1000))},
-              "losses_tpu": list(range(500)),
-              "nested": {"op_table": [1] * 500, "keep": 1}}
-    out = bench._compact(detail)
-    assert "device_op_table" not in out
-    assert "losses_tpu" not in out
-    assert "op_table" not in out["nested"]
-    assert out["nested"]["keep"] == 1
-    line = bench._headline({"mfu": 50.0}, detail)
-    assert len(line) < 8000 and json.loads(line)["value"] == 50.0
-
-
-@pytest.mark.slow  # spawns a real bench smoke and kills it mid-run; the
-# ci.sh planner gate runs it (tier-1 wall-clock relief)
-def test_bench_sigterm_leaves_parseable_last_line():
-    """Blackout round-3 regression (ISSUE-10 satellite): a bench process
-    SIGTERM'd mid-run — with the `timeout -k 10`-style SIGKILL follow-up —
-    must still leave a parseable headline as its LAST stdout line. The
-    watchdog/handler pair guarantees it even when the main thread is
-    pinned inside a native XLA call where a Python signal handler cannot
-    run."""
-    import signal
-    import tempfile
-    import time as _time
-
-    with tempfile.TemporaryFile("w+") as out:
-        p = subprocess.Popen(
-            [sys.executable, os.path.join(REPO, "bench.py")],
-            stdout=out, stderr=subprocess.DEVNULL, cwd=REPO,
-            env={**os.environ, "JAX_PLATFORMS": "cpu",
-                 "BENCH_BUDGET_S": "600"})
-        killed = False
-        try:
-            _time.sleep(8)  # past the first stub emit, mid-measure
-            p.send_signal(signal.SIGTERM)
-            try:
-                # generous window: the Python handler needs the main
-                # thread to surface from native code (an XLA compile on a
-                # loaded host can exceed the driver's literal 10s — THAT
-                # path is the budget watchdog's job, tested separately);
-                # what this test pins is the stdout contract either way
-                p.wait(timeout=30)
-            except subprocess.TimeoutExpired:
-                killed = True
-                p.kill()
-                p.wait(timeout=30)
-        finally:
-            if p.poll() is None:
-                p.kill()
-                p.wait(timeout=30)
-        out.seek(0)
-        lines = [ln for ln in out.read().splitlines() if ln.strip()]
-    assert lines, "SIGTERM'd bench left nothing on stdout"
-    parsed = json.loads(lines[-1])  # the driver's contract — ALWAYS holds
-    assert parsed["metric"] == "llama_pretrain_mfu"
-    assert len(lines[-1]) < 2000
-    assert not killed, "SIGTERM handler never ran within 30s"
-
-
-@pytest.mark.slow  # ~25s of wall clock by design; the ci.sh planner gate
-# runs it
-def test_bench_watchdog_emits_before_tiny_budget_expires():
-    """The budget watchdog is the SIGKILL-proof half: with a budget far
-    smaller than the smoke, the process must exit 0 BY ITSELF with the
-    headline re-printed last — no external signal needed."""
-    r = subprocess.run(
-        [sys.executable, os.path.join(REPO, "bench.py")],
-        capture_output=True, text=True, timeout=120, cwd=REPO,
-        env={**os.environ, "JAX_PLATFORMS": "cpu",
-             "BENCH_BUDGET_S": "25"})
-    lines = [ln for ln in r.stdout.splitlines() if ln.strip()]
-    assert lines, r.stderr[-1000:]
-    parsed = json.loads(lines[-1])  # ALWAYS parseable — the contract
-    assert parsed["metric"] == "llama_pretrain_mfu"
-    # rc mirrors whether the flagship value landed before truncation
-    assert r.returncode == (0 if parsed["value"] is not None else 1), \
-        (r.returncode, parsed["value"])
-
-
-def test_bench_reads_back_prior_headline(tmp_path, monkeypatch):
-    """Startup read-back: an interrupted prior round's on-disk headline
-    surfaces in the next round's starting stub."""
-    import bench
-
-    monkeypatch.chdir(tmp_path)
-    os.makedirs("bench_artifacts", exist_ok=True)
-    row = {"metric": "llama_pretrain_mfu", "value": 55.9,
-           "vs_baseline": 1.471, "detail": {"status": "interrupted"}}
-    with open(os.path.join("bench_artifacts", "headline.json"), "w") as f:
-        f.write(json.dumps(row))
-    prior = bench._prior_headline()
-    assert prior == {"value": 55.9, "vs_baseline": 1.471}
-    # a stub/None-valued prior (this round's own startup write) is ignored
-    with open(os.path.join("bench_artifacts", "headline.json"), "w") as f:
-        f.write(json.dumps(dict(row, value=None)))
-    assert bench._prior_headline() is None
-    # and a missing/corrupt artifact never raises
-    with open(os.path.join("bench_artifacts", "headline.json"), "w") as f:
-        f.write("{not json")
-    assert bench._prior_headline() is None
 
 
 # -- master.py: mixed-rank gang diagnostics ----------------------------------

@@ -1,5 +1,6 @@
 """Round-3 nn/nn.functional surface completion: 1D/3D families, unpool,
 losses, beam search — numpy-oracle checks."""
+import os
 import re
 
 import numpy as np
@@ -14,11 +15,16 @@ def _t(a, dt="float32"):
     return paddle.to_tensor(np.asarray(a, dt))
 
 
+REFERENCE = "/root/reference/python/paddle"
+
+
 def test_nn_and_functional_export_parity():
+    if not os.path.isdir(REFERENCE):
+        pytest.skip(f"the reference snapshot is not mounted: {REFERENCE} "
+                    "is absent, and its export lists come from nowhere else")
     for sub, refpath in [
-            ("nn", "/root/reference/python/paddle/nn/__init__.py"),
-            ("nn.functional",
-             "/root/reference/python/paddle/nn/functional/__init__.py")]:
+            ("nn", f"{REFERENCE}/nn/__init__.py"),
+            ("nn.functional", f"{REFERENCE}/nn/functional/__init__.py")]:
         ref = open(refpath).read()
         ref_names = set(re.findall(r"'(\w+)',?\s*(?:#.*)?$", ref, re.M))
         mod = paddle

@@ -350,8 +350,7 @@ def test_fit_auto_prefetch_decision_and_mesh_run():
 
 def test_timeline_hot_path_overhead_bounded():
     """The off-path contract: an empty step bracket (no Profiler, no
-    exposition) costs a few dict adds — generously bounded here; the
-    bench `warm_path` recipe carries the precise number."""
+    exposition) costs a few dict adds — generously bounded here."""
     tl = obs.StepTimeline()  # fresh: no global skew
     n = 2000
     import time as _time

@@ -5,7 +5,7 @@ On the CPU test backend both "host" and "device" are the same chip, so
 overlap buys no wall clock here — these tests pin NUMERICS (overlapped
 bit-equal to serialized), the group SCHEDULE (pipelined submission
 order, also under accumulate(k)), and the telemetry/analysis surfaces;
-the latency story is bench.py's stream_capacity A/B."""
+the latency story is not measured on the chip (ROADMAP D3)."""
 import numpy as np
 import pytest
 
@@ -236,8 +236,8 @@ def test_analysis_models_two_group_working_set():
 @pytest.mark.dist
 @pytest.mark.slow
 def test_llama_stream_ab_parity():
-    """The bench recipe's exact A/B at test scale (run by tools/ci.sh;
-    slow-marked for tier-1 wall clock): a tiny Llama through
+    """The overlap A/B at test scale (run by tools/ci.sh; slow-marked for
+    tier-1 wall clock): a tiny Llama through
     group_sharded_parallel(offload=True), overlapped vs serialized lane,
     losses bit-equal and transfer time measurably hidden."""
     from paddle_tpu.models import LlamaConfig, LlamaForCausalLM

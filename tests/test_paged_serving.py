@@ -339,7 +339,7 @@ def test_paged_admission_pool_capacity_bounds(tiny_lm):
 
 # -- the one-row prefill (ISSUE 26) ---------------------------------------------
 
-@pytest.mark.parametrize("attend", ["composed", "interpret"])
+@pytest.mark.parametrize("attend", ["reference", "interpret"])
 @pytest.mark.parametrize("start", [0, 8], ids=["cold", "prefix_suffix"])
 def test_one_row_prefill_matches_all_slots_program(start, attend,
                                                    monkeypatch):
@@ -347,8 +347,8 @@ def test_one_row_prefill_matches_all_slots_program(start, attend,
     same first token, the same logprob and the same page contents as the
     all-slots program with the other rows zero — for a cold prompt and for
     a prefix-cache suffix (``start > 0``: the first block is somebody's
-    cached page), through the composed attention and through the Pallas
-    paged kernel itself (interpreted) at S = 1. Pages that are not in the
+    cached page), through the jnp reference attention and through the
+    Pallas paged kernel itself (interpreted) at S = 1. Pages that are not in the
     request's table are bit-identical before and after."""
     import jax
     import jax.numpy as jnp
@@ -357,8 +357,7 @@ def test_one_row_prefill_matches_all_slots_program(start, attend,
     from paddle_tpu.serving.generation import (_build_window_step,
                                                _extract_gpt_params)
 
-    fused = attend == "interpret"
-    if fused:
+    if attend == "interpret":
         monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
     paddle.seed(0)
     cfg = GPTConfig(vocab_size=64, hidden_size=32, num_hidden_layers=2,
@@ -381,7 +380,7 @@ def test_one_row_prefill_matches_all_slots_program(start, attend,
     tables = np.zeros((S, B), np.int32)
     tables[slot] = table
     build = lambda rows, tag: _build_window_step(  # noqa: E731
-        cfg, rows, B, PL, W, donate=False, label=f"t26:{tag}", fused=fused)
+        cfg, rows, B, PL, W, donate=False, label=f"t26:{attend}:{tag}")
     nxt_s, lp_s, k_s, v_s, _ = build(S, "all")(
         params, k0, v0, jnp.asarray(tables), jnp.asarray(tokens),
         jnp.asarray(lengths))

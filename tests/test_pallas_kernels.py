@@ -1,12 +1,13 @@
 """ISSUE-13: the kernels/pallas fused-op layer.
 
 Interpret-mode (the Pallas kernels through the Pallas interpreter) vs
-composed-XLA parity — forward AND gradients — for fused MoE routing/
+the jnp references — forward AND gradients — for fused MoE routing/
 dispatch, RMSNorm(+residual), RoPE and paged attention, including odd /
 non-divisible shapes, GQA head ratios and the flash ``q_offset``
-context-parallel path; the registry/flag seam; the retrace-auditable
-attention-path threshold (``FLAGS_flash_min_seq``); zero-retrace on the
-warm fused path; and the planner's fused-kernel cost entries.
+context-parallel path; the registry's table (its decision is
+tests/test_kernel_seam.py); the retrace-auditable attention-path
+threshold (``FLAGS_flash_min_seq``); zero-retrace on the warm kernel
+path; and the planner's fused-kernel cost entries.
 """
 import os
 
@@ -27,6 +28,11 @@ from paddle_tpu.kernels.pallas import rope as krope
 TOL = dict(rtol=2e-5, atol=2e-5)
 
 
+def _calls(op):
+    """Decisions the seam took for ``op`` so far, by answer."""
+    return dict(kreg.kernel_table()["ops"][op]["calls"])
+
+
 def _close(a, b, **kw):
     np.testing.assert_allclose(np.asarray(a, np.float32),
                                np.asarray(b, np.float32),
@@ -35,8 +41,7 @@ def _close(a, b, **kw):
 
 @pytest.fixture(autouse=True)
 def _restore_flags():
-    prior = flags_mod.get_flags(["FLAGS_fused_kernels",
-                                 "FLAGS_moe_dispatch",
+    prior = flags_mod.get_flags(["FLAGS_moe_dispatch",
                                  "FLAGS_flash_min_seq"])
     yield
     flags_mod.set_flags(prior)
@@ -44,36 +49,20 @@ def _restore_flags():
 
 # -- registry seam ------------------------------------------------------------
 
-def test_registry_gate_modes():
-    kreg.registry()  # ensure builtin ops registered
-    flags_mod.set_flags({"FLAGS_fused_kernels": "off"})
-    assert not kreg.fused_enabled("rms_norm")
-    flags_mod.set_flags({"FLAGS_fused_kernels": "on"})
-    assert kreg.fused_enabled("rms_norm")
-    assert kreg.fused_enabled("paged_attention")
-    flags_mod.set_flags({"FLAGS_fused_kernels": "rms_norm,rope"})
-    assert kreg.fused_enabled("rms_norm") and kreg.fused_enabled("rope")
-    assert not kreg.fused_enabled("moe_dispatch")
-    flags_mod.set_flags({"FLAGS_fused_kernels": "auto"})
-    # auto on the CPU test backend = legacy composed path (tier-1 runs
-    # the code it always ran)
-    assert kreg.fused_enabled("rms_norm") == (
-        jax.default_backend() == "tpu")
-    # unknown ops never gate on
-    assert not kreg.fused_enabled("nope")
-
-
 def test_registry_resolve_and_table():
-    impl, fn = kreg.resolve("rms_norm")
+    before = _calls("rms_norm")
+    impl = kreg.resolve("rms_norm")
     assert impl == ("pallas" if jax.default_backend() == "tpu"
-                    else "composed")
-    assert callable(fn)
+                    else "reference")
     table = kreg.kernel_table()
+    assert set(table) == {"backend", "ops"}
     assert set(table["ops"]) >= {"rms_norm", "rope", "moe_dispatch",
-                                 "paged_attention"}
+                                 "paged_attention", "ssm_step"}
     row = table["ops"]["rms_norm"]
-    assert row["impl"] in ("pallas", "composed", "interpret")
-    assert row["calls"]["composed"] >= 1
+    assert set(row) == {"impl", "calls", "doc"} and row["impl"] == impl
+    # one decision, counted once; reading the table counts nothing
+    assert row["calls"][impl] == before[impl] + 1
+    assert _calls("rms_norm") == row["calls"]
     # the table is a hub provider
     from paddle_tpu import observability as obs
 
@@ -84,17 +73,17 @@ def test_registry_resolve_and_table():
 
 @pytest.mark.parametrize("shape", [(4, 96), (2, 7, 96), (3, 5, 130)])
 def test_rms_norm_parity_fwd(shape):
-    """Interpret vs composed vs the legacy primitive, odd widths."""
+    """Interpret vs reference, directly and through the primitive, odd
+    widths."""
     ks = jax.random.split(jax.random.key(0), 2)
     x = jax.random.normal(ks[0], shape, jnp.float32)
     w = jax.random.normal(ks[1], shape[-1:], jnp.float32)
     yi = krms.rms_norm(x, w, 1e-6, impl="interpret")
-    yc = krms.rms_norm(x, w, 1e-6, impl="composed")
+    yc = krms.rms_norm(x, w, 1e-6, impl="reference")
     from paddle_tpu.nn.functional.common import _rms_norm
 
-    yl = _rms_norm.fn(x, w, eps=1e-6, fused=False)
     _close(yi, yc)
-    _close(yi, yl)
+    _close(_rms_norm.fn(x, w, eps=1e-6, impl="interpret"), yc)
 
 
 def test_rms_norm_residual_parity_fwd_and_grad():
@@ -110,37 +99,33 @@ def test_rms_norm_residual_parity_fwd_and_grad():
         return f
 
     yi, si = krms.rms_norm_residual(x, r, w, 1e-6, impl="interpret")
-    yc, sc = krms.rms_norm_residual(x, r, w, 1e-6, impl="composed")
+    yc, sc = krms.rms_norm_residual(x, r, w, 1e-6, impl="reference")
     _close(yi, yc)
     _close(si, sc)
     _close(si, x + r)  # the new residual IS the sum
+    # the kernel's hand-written VJP against JAX's autodiff of the reference
     gi = jax.grad(loss("interpret"), argnums=(0, 1, 2))(x, r, w)
-    gc = jax.grad(loss("composed"), argnums=(0, 1, 2))(x, r, w)
+    gc = jax.grad(loss("reference"), argnums=(0, 1, 2))(x, r, w)
     for a, b in zip(gi, gc):
         _close(a, b)
-    # composed twin's grads vs pure-jnp autodiff of the same math
-    def ref(x, r, w):
-        s = (x + r).astype(jnp.float32)
-        y = s * jax.lax.rsqrt(jnp.mean(s * s, -1, keepdims=True) + 1e-6) * w
-        return jnp.sum(y * 1.3) + jnp.sum(jnp.sin(s))
-    gr = jax.grad(ref, argnums=(0, 1, 2))(x, r, w)
-    for a, b in zip(gc, gr):
-        _close(a, b)
 
 
-def test_rms_norm_functional_gate_routes_fused():
-    """The functional passes the live gate as a primitive attr; 'on' on
-    CPU runs the composed twin — same numbers as legacy."""
+def test_rms_norm_functional_routes_through_the_seam(monkeypatch):
+    """The functional passes the seam's answer as a primitive attr: the
+    interpreted kernel under PT_PALLAS_INTERPRET=1, the reference without —
+    same numbers."""
     import paddle_tpu.nn.functional as F
 
     x = paddle.randn([2, 5, 64])
     w = paddle.ones([64])
-    flags_mod.set_flags({"FLAGS_fused_kernels": "off"})
-    y_off = np.asarray(F.rms_norm(x, w).numpy())
-    flags_mod.set_flags({"FLAGS_fused_kernels": "on"})
-    y_on = np.asarray(F.rms_norm(x, w).numpy())
-    _close(y_off, y_on)
+    y_ref = np.asarray(F.rms_norm(x, w).numpy())
+    before = _calls("rms_norm")
+    monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
+    y_int = np.asarray(F.rms_norm(x, w).numpy())
     y2, s2 = F.rms_norm_residual(x, x, w)
+    assert _calls("rms_norm") == {**before,
+                                  "interpret": before["interpret"] + 2}
+    _close(y_ref, y_int)
     _close(np.asarray(s2.numpy()), 2 * np.asarray(x.numpy()))
 
 
@@ -152,29 +137,24 @@ def test_rms_norm_functional_gate_routes_fused():
 def test_rope_parity_fwd_and_grad(shape, offset):
     x = jax.random.normal(jax.random.key(2), shape, jnp.float32)
     oi = krope.rope_apply(x, 1e4, offset, impl="interpret")
-    oc = krope.rope_apply(x, 1e4, offset, impl="composed")
+    oc = krope.rope_apply(x, 1e4, offset, impl="reference")
     from paddle_tpu.models.llama import _rope
 
-    ol = _rope.fn(x, theta=1e4, pos_offset=offset, fused=False)
     _close(oi, oc)
-    _close(oi, ol)
+    _close(_rope.fn(x, theta=1e4, pos_offset=offset, impl="interpret"), oc)
 
     def loss(impl):
         return lambda z: jnp.sum(
             jnp.sin(krope.rope_apply(z, 1e4, offset, impl=impl)))
 
-    gi = jax.grad(loss("interpret"))(x)
-    gc = jax.grad(loss("composed"))(x)
-    gl = jax.grad(lambda z: jnp.sum(jnp.sin(
-        _rope.fn(z, theta=1e4, pos_offset=offset, fused=False))))(x)
-    _close(gi, gc)
-    _close(gi, gl)
+    # the kernel's inverse-rotation VJP against autodiff of the reference
+    _close(jax.grad(loss("interpret"))(x), jax.grad(loss("reference"))(x))
 
 
 def test_rope_rejects_odd_head_dim():
     x = jnp.zeros((1, 4, 2, 7))
     with pytest.raises(ValueError):
-        krope.rope_apply(x, 1e4, 0, impl="composed")
+        krope.rope_apply(x, 1e4, 0, impl="reference")
 
 
 # -- fused MoE routing/dispatch ----------------------------------------------
@@ -189,12 +169,12 @@ def _moe_weights(h=32, e=4, i=48, key=7):
 
 def test_fused_route_parity_and_order():
     """The routing kernel's gates/positions/counts/aux match the jnp
-    twin, and positions reproduce the gmm path's stable-argsort order."""
+    reference, and positions reproduce the gmm path's stable-argsort order."""
     h, e, k = 24, 4, 2
     wg, *_ = _moe_weights(h=h, e=e)
     xt = jax.random.normal(jax.random.key(3), (30, h), jnp.float32)
     gi_out = kmoe.fused_route(xt, wg, k, "interpret")
-    gc_out = kmoe.fused_route(xt, wg, k, "composed")
+    gc_out = kmoe.fused_route(xt, wg, k, "reference")
     for a, b in zip(gi_out, gc_out):
         _close(a, b)
     gv, gi, pos, cnt, aux = gc_out
@@ -242,13 +222,13 @@ def test_fused_moe_parity_vs_gmm_and_index():
 
     args = (x, wg, w_gate, w_up, w_down)
     of, auxf = kmoe.fused_moe_mlp(*args, top_k=2, impl="interpret")
-    oc, auxc = kmoe.fused_moe_mlp(*args, top_k=2, impl="composed")
+    oc, auxc = kmoe.fused_moe_mlp(*args, top_k=2, impl="reference")
     og, auxg = moe_mod._moe_mlp_gmm(*args, top_k=2)
     _close(of, oc)
     _close(of, og)
     _close(auxf, auxg)
     gi = jax.grad(floss("interpret"), argnums=tuple(range(5)))(*args)
-    gc = jax.grad(floss("composed"), argnums=tuple(range(5)))(*args)
+    gc = jax.grad(floss("reference"), argnums=tuple(range(5)))(*args)
     gg = jax.grad(gmm_loss, argnums=tuple(range(5)))(*args)
     gx = jax.grad(idx_loss, argnums=tuple(range(5)))(*args)
     for a, b in zip(gi, gc):
@@ -291,7 +271,7 @@ def test_fused_moe_grad_under_scan():
     def loss(x, wg):
         def body(c, _):
             o, aux = kmoe.fused_moe_mlp(c, wg, w_gate, w_up, w_down,
-                                        top_k=2, impl="composed")
+                                        top_k=2, impl="reference")
             return o, aux
         out, auxes = jax.lax.scan(body, x, None, length=2)
         return jnp.sum(out * out) + 0.1 * jnp.sum(auxes)
@@ -307,7 +287,7 @@ def test_fused_moe_rejects_too_many_experts():
     with pytest.raises(ValueError):
         kmoe.fused_moe_mlp(jnp.zeros((1, 4, h)), wg,
                            jnp.zeros((e, h, 8)), jnp.zeros((e, h, 8)),
-                           jnp.zeros((e, 8, h)), top_k=2, impl="composed")
+                           jnp.zeros((e, 8, h)), top_k=2, impl="reference")
 
 
 # -- paged attention ----------------------------------------------------------
@@ -315,7 +295,7 @@ def test_fused_moe_rejects_too_many_experts():
 @pytest.mark.parametrize("nh,kvh,hd,PL", [(4, 4, 16, 4), (4, 2, 16, 4),
                                           (6, 2, 12, 5)])
 def test_paged_attention_parity(nh, kvh, hd, PL):
-    """Interpret vs composed (the PR-11 gather math), GQA ratios and
+    """Interpret vs reference (the PR-11 gather math), GQA ratios and
     non-divisible page/head shapes; grads through the VJP."""
     S, W, P, B = 3, 2, 11, 3
     ks = jax.random.split(jax.random.key(6), 5)
@@ -325,13 +305,13 @@ def test_paged_attention_parity(nh, kvh, hd, PL):
     tables = jax.random.randint(ks[3], (S, B), 0, P).astype(jnp.int32)
     pos = jnp.array([[3, 4], [0, 1], [2 * PL, 2 * PL + 1]], jnp.int32)
     pi = kpaged.paged_attention(q, ka, va, tables, pos, impl="interpret")
-    pc = kpaged.paged_attention(q, ka, va, tables, pos, impl="composed")
+    pc = kpaged.paged_attention(q, ka, va, tables, pos, impl="reference")
     _close(pi, pc)
     gi = jax.grad(lambda a, b_, c: jnp.sum(kpaged.paged_attention(
         a, b_, c, tables, pos, impl="interpret") ** 2),
         argnums=(0, 1, 2))(q, ka, va)
     gc = jax.grad(lambda a, b_, c: jnp.sum(kpaged.paged_attention(
-        a, b_, c, tables, pos, impl="composed") ** 2),
+        a, b_, c, tables, pos, impl="reference") ** 2),
         argnums=(0, 1, 2))(q, ka, va)
     for a, b in zip(gi, gc):
         _close(a, b)
@@ -364,19 +344,21 @@ def test_paged_attention_masks_by_position():
     _close(o3[0], ref, rtol=1e-4, atol=1e-4)
 
 
-def test_window_step_fused_seam_token_parity():
-    """The serving window step builds fused vs composed to identical
-    argmaxes and K/V writes (the CPU 'no worse than gather' contract is
-    ratio-checked by the bench fused_kernels recipe)."""
+def test_window_step_seam_token_parity(monkeypatch):
+    """The serving window step traced to the jnp reference and to the
+    interpreted Pallas kernel: identical argmaxes, logprobs and K/V
+    writes. Each program asks the seam as it is traced, and the two get
+    different answers — not one program compared with itself."""
     from paddle_tpu.models import GPTForCausalLM
     from paddle_tpu.models.gpt import GPTConfig
-    from paddle_tpu.serving.generation import (_build_window_step,
-                                               _extract_gpt_params)
+    from paddle_tpu.serving.generation import _build_window_step
 
     paddle.seed(0)
     cfg = GPTConfig(vocab_size=64, hidden_size=32, num_hidden_layers=2,
-                    num_attention_heads=4, max_position_embeddings=64)
-    params = _extract_gpt_params(GPTForCausalLM(cfg))
+                    num_attention_heads=4, max_position_embeddings=64,
+                    dtype="float32")
+    model = GPTForCausalLM(cfg)
+    params = model.served_model().params(model)
     S, B, PL, W = 2, 4, 8, 2
     P = S * B + 1
     hd = cfg.hidden_size // cfg.num_attention_heads
@@ -387,15 +369,25 @@ def test_window_step_fused_seam_token_parity():
     tokens = jnp.array([[1, 2], [3, 4]], jnp.int32)
     lengths = jnp.array([5, 11], jnp.int32)
     outs = {}
-    for fused in (False, True):
+    for impl in ("reference", "interpret"):
+        if impl == "interpret":
+            monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
+        before = _calls("paged_attention")
         stp = _build_window_step(cfg, S, B, PL, W, donate=False,
-                                 label=f"t:{fused}", fused=fused)
-        outs[fused] = stp(params, mk(ks[0]), mk(ks[1]), tables, tokens,
-                          lengths)
-    assert np.array_equal(np.asarray(outs[False][0]),
-                          np.asarray(outs[True][0]))
-    for a, b in zip(outs[False][1], outs[True][1]):
-        _close(a, b, rtol=0, atol=0)
+                                 label=f"t:{impl}")
+        outs[impl] = stp(params, mk(ks[0]), mk(ks[1]), tables, tokens,
+                         lengths)
+        took = [k for k, v in _calls("paged_attention").items()
+                if v > before[k]]
+        assert took == [impl], took
+    assert np.array_equal(np.asarray(outs["reference"][0]),
+                          np.asarray(outs["interpret"][0]))
+    for a, b in zip(jax.tree_util.tree_leaves(outs["reference"][1:4]),
+                    jax.tree_util.tree_leaves(outs["interpret"][1:4])):
+        _close(a, b)
+    with pytest.raises(ValueError, match="no longer selects"):
+        _build_window_step(cfg, S, B, PL, W, donate=False, label="t:off",
+                           fused=False)
 
 
 # -- flash q_offset (context-parallel path) -----------------------------------
@@ -459,60 +451,64 @@ def test_attention_impl_attr_is_cache_key_participant():
                      ("scale", 0.1))) in _FWD_CACHE
 
 
-# -- zero-retrace on the warm fused path --------------------------------------
+# -- zero-retrace on the warm kernel path --------------------------------------
 
-def test_warm_fused_path_zero_retrace():
-    """With the audit armed, repeated fused calls at fixed shapes add
-    ZERO retrace events; flipping the gate is a NEW key, not a silent
-    recompile of the old one."""
+def test_warm_kernel_path_zero_retrace(monkeypatch):
+    """With the audit armed, repeated kernel-path calls at fixed shapes
+    add ZERO retrace events; another answer from the seam is a NEW key,
+    not a silent recompile of the old one."""
     import paddle_tpu.analysis as A
     import paddle_tpu.nn.functional as F
 
-    os.environ["PT_RETRACE_AUDIT"] = "1"
+    monkeypatch.setenv("PT_RETRACE_AUDIT", "1")
     A.retrace.enable()
     try:
-        flags_mod.set_flags({"FLAGS_fused_kernels": "on"})
+        monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
         x = paddle.randn([2, 6, 64])
         w = paddle.ones([64])
         F.rms_norm(x, w)
         F.rms_norm_residual(x, x, w)
         base = A.retrace.get_auditor().summary()["retrace_events"]
-        for _ in range(3):  # warm path: same shapes, same flags
+        for _ in range(3):  # warm path: same shapes, same answer
             F.rms_norm(x, w)
             F.rms_norm_residual(x, x, w)
         assert A.retrace.get_auditor().summary()["retrace_events"] == base
-        flags_mod.set_flags({"FLAGS_fused_kernels": "off"})
-        F.rms_norm(x, w)  # the flip is an AUDITED new key: one event
+        monkeypatch.delenv("PT_PALLAS_INTERPRET")
+        F.rms_norm(x, w)  # the change is an AUDITED new key: one event
         aud = A.retrace.get_auditor()
         assert aud.summary()["retrace_events"] == base + 1
         ev = aud.events[-1]
-        assert "fused" in str(ev.deltas), ev.deltas  # names the attr flip
+        assert "impl" in str(ev.deltas), ev.deltas  # names the attr
     finally:
         A.retrace.disable()
         A.retrace.reset()
-        os.environ.pop("PT_RETRACE_AUDIT", None)
 
 
-# -- llama end-to-end gate parity ---------------------------------------------
+# -- llama end-to-end seam parity ----------------------------------------------
 
-def test_llama_fused_gate_loss_parity():
-    """tiny-Llama fwd+bwd: gate on (CPU -> composed twins) equals gate
-    off to float tolerance — the tier-1 'runs both, pins parity' seam."""
+def test_llama_seam_loss_parity(monkeypatch):
+    """tiny-Llama fwd+bwd through the interpreted Pallas kernels (RMSNorm,
+    RMSNorm+residual and RoPE with their hand-written VJPs) equals the jnp
+    references under JAX's autodiff to float tolerance."""
     import paddle_tpu.optimizer as opt
     from paddle_tpu import jit
     from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 
     cfg = LlamaConfig.tiny()
     losses = {}
-    for mode in ("off", "on"):
-        flags_mod.set_flags({"FLAGS_fused_kernels": mode})
+    for impl in ("reference", "interpret"):
+        if impl == "interpret":
+            monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
+        before = {op: _calls(op) for op in ("rms_norm", "rope")}
         paddle.seed(0)
         m = LlamaForCausalLM(cfg)
         o = opt.AdamW(learning_rate=1e-3, parameters=m.parameters())
         step = jit.TrainStep(m, lambda mm, x, y: mm(x, labels=y), o)
         ids = paddle.randint(0, cfg.vocab_size, [2, 32])
-        losses[mode] = [float(step(ids, ids)) for _ in range(2)]
-    np.testing.assert_allclose(losses["off"], losses["on"],
+        losses[impl] = [float(step(ids, ids)) for _ in range(2)]
+        for op, was in before.items():  # the step really took this path
+            assert _calls(op)[impl] > was[impl], (op, impl)
+    np.testing.assert_allclose(losses["reference"], losses["interpret"],
                                rtol=1e-4, atol=1e-5)
 
 
@@ -542,8 +538,7 @@ def test_planner_fused_entries_reprice_and_rerank():
     moe = dist.plan(LlamaForCausalLM(LlamaMoEConfig.tiny()), n_devices=8,
                     hbm_bytes=16e9, batch=16, seq=64, fused_kernels=True)
     assert "moe_dispatch" in moe[0].breakdown["fused_ops"]
-    # fused_kernels=None follows the live registry (CPU auto -> none)
-    flags_mod.set_flags({"FLAGS_fused_kernels": "auto"})
+    # fused_kernels=None follows the platform (CPU -> none)
     paddle.seed(0)
     auto = dist.plan(m, n_devices=8, hbm_bytes=16e9, batch=16, seq=64)
     if jax.default_backend() == "cpu":

@@ -9,7 +9,7 @@ abstract capture; nothing needs 8 real devices):
   divisibility over the data axes;
 - HBM-infeasible configs are pruned (deliberately tiny hbm_bytes);
 - ranking is deterministic call-to-call;
-- every MULTICHIP_r05 matrix config round-trips through plan() scoring;
+- every dryrun-matrix config round-trips through plan() scoring;
 - Engine.prepare(auto_plan=True) applies the top pick end to end.
 """
 import numpy as np
@@ -24,8 +24,8 @@ from paddle_tpu.distributed.auto_parallel import planner
 from paddle_tpu.models import LlamaConfig, LlamaForCausalLM, LlamaMoEConfig
 
 # the exact mesh configs the 8-device dryrun matrix executes
-# (__graft_entry__._mesh_configs(8), MULTICHIP_r05 all green)
-MULTICHIP_R05 = (
+# (__graft_entry__._mesh_configs(8))
+DRYRUN_MATRIX = (
     {"dp": 2, "mp": 2, "cp": 2},
     {"sharding": 4, "dp": 2, "level": "os_g"},
     {"sharding": 2, "mp": 2, "dp": 2, "level": "p_g_os"},
@@ -184,7 +184,7 @@ class TestScoringAndRanking:
         without error and produce finite time + memory predictions."""
         prof_dense, _ = _tiny_profile()
         prof_moe, _ = _tiny_profile(moe=True)
-        for raw in MULTICHIP_R05:
+        for raw in DRYRUN_MATRIX:
             prof = prof_moe if raw.get("ep", 1) > 1 else prof_dense
             cand = planner.score_config(prof, dict(raw), hbm_bytes=16e9)
             assert np.isfinite(cand.predicted_step_s) and \

@@ -2,7 +2,7 @@
 weights live in (pinned) host memory and stream through HBM layer by layer.
 On the CPU test backend memory kinds are inert, so these tests check the
 NUMERICS of the unrolled streaming path against the scan path; the capacity
-lift is proven on hardware by bench.py's hbm_envelope row."""
+lift is not measured on the chip (ROADMAP D3)."""
 import numpy as np
 import pytest
 

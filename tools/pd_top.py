@@ -3,7 +3,7 @@
 
 The `top(1)` of the telemetry hub (docs/observability.md):
 
-    python tools/pd_top.py bench_artifacts/telemetry_warm_path.json
+    python tools/pd_top.py snapshot.json   # a saved observability.snapshot()
     python tools/pd_top.py --port 9100                # live /snapshot
     python tools/pd_top.py --port 9100 --watch 2      # refresh every 2s
     python tools/pd_top.py --port 9100 --json         # raw JSON passthrough
