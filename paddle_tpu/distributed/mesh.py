@@ -37,8 +37,9 @@ def kernel_mesh():
     GSPMD cannot partition a Mosaic custom call ("Mosaic kernels cannot be
     automatically partitioned. Please wrap the call in a shard_map"), so
     under a live multi-device mesh every kernel call goes through
-    ``run_kernel_on_mesh``; with no mesh, or one device, it is called
-    directly."""
+    ``run_kernel_on_mesh`` (the op brings its backward) or, forward-only,
+    ``run_forward_kernel_on_mesh``; with no mesh, or one device, it is
+    called directly."""
     env = _GLOBAL["env"]
     return env if env is not None and env.nranks > 1 else None
 
@@ -71,8 +72,8 @@ def activation_spec(shape, layout: str) -> PartitionSpec:
     ``"bshd"`` = [batch, seq, heads, head_dim] (batch over dp/sdp, heads
     over mp); ``"rows"`` = [batch, (seq,) ..., hidden] (batch over dp/sdp,
     seq over cp). A dim the degree does not divide is left unsplit. None
-    when there is no multi-device mesh (``run_kernel_on_mesh`` then calls
-    the kernel directly)."""
+    when there is no multi-device mesh (``run_kernel_on_mesh`` then needs
+    no spec)."""
     env = kernel_mesh()
     if env is None:
         return None
@@ -86,15 +87,72 @@ def activation_spec(shape, layout: str) -> PartitionSpec:
     return PartitionSpec(data, *rest)
 
 
-def run_kernel_on_mesh(fn, args, in_specs, out_specs):
-    """``fn(*args)`` as one full-manual ``shard_map`` over the live mesh
-    (each operand split by its spec, ``PartitionSpec()`` = replicated), or
-    a plain call when there is no multi-device mesh."""
+def _spec_axes(spec) -> set:
+    return {ax for part in spec if part is not None
+            for ax in ((part,) if isinstance(part, str) else part)}
+
+
+def _manual_region(env, fn, in_specs, out_specs):
+    return jax.shard_map(fn, mesh=env.mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
+
+
+def run_kernel_on_mesh(fwd, bwd, args, in_specs, out_specs, res_specs):
+    """A differentiable kernel op on the live mesh, from the two halves the
+    op brings: ``fwd(*args) -> (out, residuals)`` and ``bwd(residuals,
+    cotangent) -> one gradient an argument``, each written for ONE shard.
+    With no multi-device mesh it is the plain ``custom_vjp`` over them.
+
+    Under a mesh the forward runs as one full-manual ``shard_map`` (operands
+    split by ``in_specs``, ``PartitionSpec()`` = replicated; the residuals
+    leave under ``res_specs``, which the op states) and the backward as
+    another, under the same specs. The differentiation rule sits OUTSIDE
+    both, so JAX transposes neither region. It must not: with
+    ``check_vma=False`` the transpose of a ``shard_map`` cannot know that an
+    operand was replicated over a mesh axis its spec does not name, so it
+    divides the cotangent by that axis's size and ``psum``s the operand's
+    gradient over it — one all-reduce of a whole activation for every
+    operand replicated over an axis, to add equal parts back together
+    (PR 31: two a decoder layer over ``mp``). ``check_vma=True`` is not the
+    way out on jax 0.9: the Pallas interpreter fails under it.
+
+    What the math does sum is summed here, for every op: a gradient is
+    ``psum``med over the axes that split another operand or an output but
+    not its own operand — there each shard saw its share of the rows and
+    holds a partial sum (a norm's ``dw`` over ``dp``/``sdp``/``cp``). Over
+    an axis no spec names every shard computed the same value, and nothing
+    is reduced. A half must therefore hold no collective of its own."""
+    from ..kernels.pallas._common import differentiable
+
+    env = kernel_mesh()
+    if env is None:
+        return differentiable(fwd, bwd)(*args)
+    in_specs = tuple(in_specs)
+    split = set().union(*map(_spec_axes, in_specs + tuple(jax.tree.leaves(
+        out_specs, is_leaf=lambda s: isinstance(s, PartitionSpec)))))
+    partial_over = [tuple(ax for ax in env.axis_names
+                          if ax in split - _spec_axes(spec))
+                    for spec in in_specs]
+
+    def bwd_summed(res, ct):
+        return tuple(jax.lax.psum(g, axes) if axes else g
+                     for g, axes in zip(bwd(res, ct), partial_over))
+
+    return differentiable(
+        _manual_region(env, fwd, in_specs, (out_specs, res_specs)),
+        _manual_region(env, bwd_summed, (res_specs, out_specs), in_specs),
+    )(*args)
+
+
+def run_forward_kernel_on_mesh(fn, args, in_specs, out_specs):
+    """``fn(*args)`` as one full-manual ``shard_map`` over the live mesh, or
+    a plain call when there is no multi-device mesh. If ``fn`` is
+    differentiated, JAX transposes the region (see ``run_kernel_on_mesh``
+    for what that costs wherever a spec leaves a mesh axis out)."""
     env = kernel_mesh()
     if env is None:
         return fn(*args)
-    return jax.shard_map(fn, mesh=env.mesh, in_specs=tuple(in_specs),
-                         out_specs=out_specs, check_vma=False)(*args)
+    return _manual_region(env, fn, tuple(in_specs), out_specs)(*args)
 
 
 class MeshEnv:

@@ -15,11 +15,12 @@ Two entry points:
   pattern ``s = x + res; y = norm(s)`` fused; ``s`` is returned as the
   new residual stream (both outputs carry cotangents in the VJP).
 
-The kernels carry custom VJPs whose backward is also one kernel (dx
-[+dres] and a cross-row dw accumulated in VMEM scratch over the
-sequential grid). The reference is the plain forward in jnp,
-differentiated by JAX — what every non-TPU backend runs. Parity is pinned
-by tests/test_pallas_kernels.py (fwd and grads, odd widths).
+Each op is a forward half and a backward half (``*_halves``); the
+backward is also one kernel (dx [+dres] and a cross-row dw accumulated in
+VMEM scratch over the sequential grid). The reference is the plain
+forward in jnp, differentiated by JAX — what every non-TPU backend runs.
+Parity is pinned by tests/test_pallas_kernels.py (fwd and grads, odd
+widths), the mesh's gradients by tests/test_kernel_mesh_grads.py.
 """
 from __future__ import annotations
 
@@ -31,9 +32,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..registry import register_kernel, resolve
+from ._common import differentiable
 from ._common import pick_rows as _pick_rows
 
-__all__ = ["rms_norm", "rms_norm_residual"]
+__all__ = ["rms_norm", "rms_norm_residual", "rms_norm_halves",
+           "rms_norm_residual_halves"]
 
 
 # -- forward ------------------------------------------------------------------
@@ -183,46 +186,58 @@ def _bwd_pallas(s, w, rstd, dy, dr, residual, interpret):
     return dx, dw.reshape(h)
 
 
-# -- differentiable wrappers around the kernels ([n, h] layout) ---------------
+# -- the two halves of each op ([..., h] layout) ------------------------------
+# ``fwd(*operands) -> (out, residuals)`` and ``bwd(residuals, cotangent) ->
+# one gradient an operand``, for ``_common.differentiable`` (one device) and
+# ``distributed.mesh.run_kernel_on_mesh`` (a live mesh: each half a manual
+# region of its own, a half seeing ONE shard's rows). The residuals keep the
+# operand's rank (``rstd`` is [..., 1]) so that they cross from one region to
+# the other under the operand's own spec; ``dw`` is the sum over the rows the
+# half saw.
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3))
-def _rms2(x2, w, eps, interpret):
-    return _fwd_pallas(x2, x2, w, eps, False, interpret)[0]
+def rms_norm_halves(eps, impl):
+    interpret = impl == "interpret"
 
+    def fwd(x, w):
+        h = x.shape[-1]
+        x2 = x.reshape(-1, h)
+        y, _, rstd = _fwd_pallas(x2, x2, w, eps, False, interpret)
+        # the saved "s" of the plain backward IS the primal input
+        return y.reshape(x.shape), (x, w, rstd.reshape(x.shape[:-1] + (1,)))
 
-def _rms2_fwd(x2, w, eps, interpret):
-    y, s, rstd = _fwd_pallas(x2, x2, w, eps, False, interpret)
-    return y, (s, w, rstd)
+    def bwd(res, dy):
+        s, w, rstd = res
+        h = s.shape[-1]
+        dy2 = dy.reshape(-1, h)
+        dx, dw = _bwd_pallas(s.reshape(-1, h), w, rstd.reshape(-1, 1), dy2,
+                             dy2, False, interpret)
+        return dx.reshape(s.shape), dw.astype(w.dtype)
 
-
-def _rms2_bwd(eps, interpret, res, dy):
-    s, w, rstd = res
-    dx, dw = _bwd_pallas(s, w, rstd, dy, dy, False, interpret)
-    return dx, dw.astype(w.dtype)
-
-
-_rms2.defvjp(_rms2_fwd, _rms2_bwd)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _rms2_res(x2, r2, w, eps, interpret):
-    y, s, _ = _fwd_pallas(x2, r2, w, eps, True, interpret)
-    return y, s
-
-
-def _rms2_res_fwd(x2, r2, w, eps, interpret):
-    y, s, rstd = _fwd_pallas(x2, r2, w, eps, True, interpret)
-    return (y, s), (s, w, rstd)
+    return fwd, bwd
 
 
-def _rms2_res_bwd(eps, interpret, res, cts):
-    s, w, rstd = res
-    dy, dr = cts
-    ds, dw = _bwd_pallas(s, w, rstd, dy, dr, True, interpret)
-    return ds, ds, dw.astype(w.dtype)
+def rms_norm_residual_halves(eps, impl):
+    interpret = impl == "interpret"
 
+    def fwd(x, r, w):
+        h = x.shape[-1]
+        y, s, rstd = _fwd_pallas(x.reshape(-1, h), r.reshape(-1, h), w, eps,
+                                 True, interpret)
+        s = s.reshape(x.shape)
+        return (y.reshape(x.shape), s), \
+            (s, w, rstd.reshape(x.shape[:-1] + (1,)))
 
-_rms2_res.defvjp(_rms2_res_fwd, _rms2_res_bwd)
+    def bwd(res, cts):
+        s, w, rstd = res
+        dy, dr = cts
+        h = s.shape[-1]
+        ds, dw = _bwd_pallas(s.reshape(-1, h), w, rstd.reshape(-1, 1),
+                             dy.reshape(-1, h), dr.reshape(-1, h), True,
+                             interpret)
+        ds = ds.reshape(s.shape)
+        return ds, ds, dw.astype(w.dtype)
+
+    return fwd, bwd
 
 
 # -- the jnp reference ---------------------------------------------------------
@@ -244,9 +259,7 @@ def rms_norm(x, w, eps: float = 1e-6, impl: str = None):
         impl = resolve("rms_norm")
     if impl == "reference":
         return _reference(x, w, eps)
-    h = x.shape[-1]
-    y = _rms2(x.reshape(-1, h), w, float(eps), impl == "interpret")
-    return y.reshape(x.shape)
+    return differentiable(*rms_norm_halves(float(eps), impl))(x, w)
 
 
 def rms_norm_residual(x, res, w, eps: float = 1e-6, impl: str = None):
@@ -258,10 +271,8 @@ def rms_norm_residual(x, res, w, eps: float = 1e-6, impl: str = None):
     if impl == "reference":
         s = x + res
         return _reference(s, w, eps).astype(x.dtype), s
-    h = x.shape[-1]
-    y, s = _rms2_res(x.reshape(-1, h), res.reshape(-1, h), w, float(eps),
-                     impl == "interpret")
-    return y.reshape(x.shape), s.reshape(x.shape)
+    return differentiable(*rms_norm_residual_halves(float(eps), impl))(
+        x, res, w)
 
 
 register_kernel(

@@ -26,9 +26,9 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ..registry import register_kernel, resolve
-from ._common import pick_rows
+from ._common import differentiable, pick_rows
 
-__all__ = ["rope_apply"]
+__all__ = ["rope_apply", "rope_halves"]
 
 
 # VMEM the kernel may plan for per grid step (v5e scopes 16 MiB): the
@@ -103,20 +103,18 @@ def _reference(x, theta, pos_offset):
         [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2, 3))
-def _rope4(x, theta, pos_offset, interpret):
-    return _rope_pallas(x, theta, pos_offset, False, interpret)
+def rope_halves(theta, pos_offset, impl):
+    """``(fwd, bwd)`` of the op (see ``rmsnorm.rms_norm_halves``): no
+    residuals, the backward is the inverse rotation of the cotangent."""
+    interpret = impl == "interpret"
 
+    def fwd(x):
+        return _rope_pallas(x, theta, pos_offset, False, interpret), ()
 
-def _rope4_fwd(x, theta, pos_offset, interpret):
-    return _rope_pallas(x, theta, pos_offset, False, interpret), None
+    def bwd(_res, dy):
+        return (_rope_pallas(dy, theta, pos_offset, True, interpret),)
 
-
-def _rope4_bwd(theta, pos_offset, interpret, _res, dy):
-    return (_rope_pallas(dy, theta, pos_offset, True, interpret),)
-
-
-_rope4.defvjp(_rope4_fwd, _rope4_bwd)
+    return fwd, bwd
 
 
 def rope_apply(x, theta: float = 10000.0, pos_offset: int = 0,
@@ -129,7 +127,8 @@ def rope_apply(x, theta: float = 10000.0, pos_offset: int = 0,
         impl = resolve("rope")
     if impl == "reference":
         return _reference(x, theta, pos_offset)
-    return _rope4(x, float(theta), int(pos_offset), impl == "interpret")
+    return differentiable(
+        *rope_halves(float(theta), int(pos_offset), impl))(x)
 
 
 register_kernel(

@@ -93,16 +93,17 @@ class LlamaMoEConfig(LlamaConfig):
 @primitive("rope_apply")
 def _rope(x, *, theta, pos_offset, impl):
     # x: [b, s, h, d]; rotate-half RoPE in fp32
-    from ..kernels.pallas.rope import rope_apply as kernel
+    from ..kernels.pallas import rope
 
     if impl == "reference":  # plain jnp: GSPMD partitions it itself
-        return kernel(x, theta, pos_offset, impl)
+        return rope.rope_apply(x, theta, pos_offset, impl)
     from ..distributed.mesh import activation_spec, run_kernel_on_mesh
 
-    # seq stays unsplit, so every shard sees global positions
+    # seq stays unsplit, so every shard sees global positions; no residuals
     spec = activation_spec(x.shape, "bshd")
     return run_kernel_on_mesh(
-        lambda xl: kernel(xl, theta, pos_offset, impl), (x,), (spec,), spec)
+        *rope.rope_halves(theta, pos_offset, impl), (x,), in_specs=(spec,),
+        out_specs=spec, res_specs=())
 
 
 def apply_rotary_pos_emb(x: Tensor, theta: float = 10000.0, pos_offset: int = 0) -> Tensor:

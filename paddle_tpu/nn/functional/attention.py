@@ -43,13 +43,14 @@ def _sdpa(q, k, v, *, causal, scale, impl="xla"):
     if impl == "flash":
         # no fallback: a kernel the chip's compiler refuses must surface
         # (a silent XLA softmax here once hid every such refusal)
-        from ...distributed.mesh import activation_spec, run_kernel_on_mesh
+        from ...distributed.mesh import (activation_spec,
+                                         run_forward_kernel_on_mesh)
         from ...kernels.flash_attention import flash_attention
 
         # under a live mesh: batch over dp/sdp, heads over mp, one
         # full-manual shard_map (GSPMD cannot partition a Mosaic kernel)
         spec = activation_spec(q.shape, "bshd")
-        return run_kernel_on_mesh(
+        return run_forward_kernel_on_mesh(
             lambda ql, kl, vl: flash_attention(ql, kl, vl, causal=causal,
                                                scale=scale),
             (q, k, v), (spec, spec, spec), spec)

@@ -170,35 +170,39 @@ def layer_norm(x, normalized_shape, weight=None, bias=None, epsilon=1e-5, name=N
 
 @primitive("rms_norm_op")
 def _rms_norm(x, w, *, eps, impl):
-    from ...kernels.pallas.rmsnorm import rms_norm as kernel
+    from ...kernels.pallas import rmsnorm
 
     if impl == "reference":  # plain jnp: GSPMD partitions it itself
-        return kernel(x, w, eps, impl)
+        return rmsnorm.rms_norm(x, w, eps, impl)
     from jax.sharding import PartitionSpec as P
 
     from ...distributed.mesh import activation_spec, run_kernel_on_mesh
 
+    # the rows split over the data axes (and cp), w whole on every device;
+    # residuals (x, w, rstd): rstd [..., 1] has the rows' layout
     spec = activation_spec(x.shape, "rows")
-    return run_kernel_on_mesh(lambda xl, wl: kernel(xl, wl, eps, impl),
-                              (x, w), (spec, P()), spec)
+    return run_kernel_on_mesh(
+        *rmsnorm.rms_norm_halves(eps, impl), (x, w), in_specs=(spec, P()),
+        out_specs=spec, res_specs=(spec, P(), spec))
 
 
 @primitive("rms_norm_residual_op")
 def _rms_norm_residual(x, res, w, *, eps, impl):
     """Pre-norm decoder pattern ``s = x + res; y = rmsnorm(s)`` ->
     (y, s), one HBM pass where the Pallas kernel runs."""
-    from ...kernels.pallas.rmsnorm import rms_norm_residual as kernel
+    from ...kernels.pallas import rmsnorm
 
     if impl == "reference":
-        return kernel(x, res, w, eps, impl)
+        return rmsnorm.rms_norm_residual(x, res, w, eps, impl)
     from jax.sharding import PartitionSpec as P
 
     from ...distributed.mesh import activation_spec, run_kernel_on_mesh
 
-    spec = activation_spec(x.shape, "rows")
+    spec = activation_spec(x.shape, "rows")  # residuals (s, w, rstd)
     return run_kernel_on_mesh(
-        lambda xl, rl, wl: kernel(xl, rl, wl, eps, impl), (x, res, w),
-        (spec, spec, P()), (spec, spec))
+        *rmsnorm.rms_norm_residual_halves(eps, impl), (x, res, w),
+        in_specs=(spec, spec, P()), out_specs=(spec, spec),
+        res_specs=(spec, P(), spec))
 
 
 def rms_norm(x, weight, epsilon=1e-6, name=None):

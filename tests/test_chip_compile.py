@@ -131,6 +131,46 @@ def test_rope_fwd_inverse(one_chip, b):
              names=("pt_rope",))
 
 
+def test_norms_and_rope_on_the_mesh(topo):
+    """Cell 3's kernels as the mesh runs them (``run_kernel_on_mesh``: the
+    forward one manual region, the op's own backward another) compile for
+    the four described chips on ``dp=2 x mp=2``, and no gradient of an
+    activation is all-reduced: what a shard computed for its rows is the
+    gradient of its rows (ISSUE 31)."""
+    import paddle_tpu.distributed as dist
+    from paddle_tpu.distributed.mesh import (activation_spec,
+                                             compiled_collectives)
+    from paddle_tpu.models.llama import _rope
+    from paddle_tpu.nn.functional.common import _rms_norm_residual
+
+    def loss(x, r, w, q):
+        y, s = _rms_norm_residual.fn(x, r, w, eps=1e-5, impl="pallas")
+        o = _rope.fn(q, theta=1e6, pos_offset=0, impl="pallas")
+        return sum(jnp.sum(a.astype(jnp.float32)) for a in (y, s, o))
+
+    dist.reset_mesh()
+    env = dist.init_mesh(dp=2, mp=2, devices=list(topo.devices))
+    try:
+        shapes = [(8, 2048, 2048)] * 2 + [(2048,), (8, 2048, 16, 128)]
+        layouts = ["rows", "rows", None, "bshd"]
+        held = [env.sharding_for(activation_spec(s, lay)) if lay
+                else env.replicated() for s, lay in zip(shapes, layouts)]
+        args = [jax.ShapeDtypeStruct(s, BF16, sharding=sh)
+                for s, sh in zip(shapes, held)]
+        text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3)),
+                       out_shardings=tuple(held)).lower(*args) \
+            .compile().as_text()
+    finally:
+        dist.reset_mesh()
+    kernels = {re.search(r"pt_[a-z0-9_]*[a-z0-9]", c).group(0)
+               for c in _CUSTOM_CALL.findall(text)}
+    assert kernels == {"pt_rmsnorm_fwd_residual", "pt_rmsnorm_bwd_residual",
+                       "pt_rope"}
+    reduced = [(r["axes"], r["shapes"])
+               for r in compiled_collectives(text, env.mesh)]
+    assert reduced == [(("dp",), ("bf16[2048]",))], reduced   # dw, over dp
+
+
 def _compile_paged(one_chip, S, W, nh, hd, PL, P, B, dtype):
     from paddle_tpu.kernels.pallas import paged_attention as kpaged
 

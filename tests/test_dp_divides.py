@@ -75,12 +75,22 @@ def one_device_losses():
     return [float(step(x, x)) for _ in range(2)]
 
 
+MESHES = {"dp2-mp2": dict(dp=2, mp=2), "sdp2-mp2": dict(sharding=2, mp=2),
+          "dp2-cp2": dict(dp=2, cp=2)}
+
+
 @pytest.mark.dist
-@pytest.mark.parametrize("mesh", [dict(dp=2, mp=2), dict(sharding=2, mp=2),
-                                  dict(dp=2, cp=2)],
-                         ids=["dp2-mp2", "sdp2-mp2", "dp2-cp2"])
-def test_data_axes_divide_the_compiled_step(mesh, one_device_losses):
-    env = dist.init_mesh(**mesh, devices=jax.devices()[:4])
+@pytest.mark.parametrize("mesh,norms", [
+    ("dp2-mp2", "reference"), ("sdp2-mp2", "reference"),
+    ("dp2-cp2", "reference"),
+    # the chip's path: the Pallas norms and RoPE (interpreted here), each a
+    # manual region forward and another backward (ISSUE 31)
+    ("dp2-mp2", "kernels"), ("sdp2-mp2", "kernels")])
+def test_data_axes_divide_the_compiled_step(mesh, norms, one_device_losses,
+                                            monkeypatch):
+    if norms == "kernels":
+        monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
+    env = dist.init_mesh(**MESHES[mesh], devices=jax.devices()[:4])
     step, x = _step_and_batch(dist.ShardedTrainStep)
     text = step.lower(x, x).compile().as_text()
     rows = compiled_collectives(text, env.mesh)   # what collectives() reads
@@ -97,7 +107,19 @@ def test_data_axes_divide_the_compiled_step(mesh, one_device_losses):
                 and math.prod(d[:-1]) > replica_rows]
     assert not too_many, f"mp all-reduces on more than a replica's rows: {too_many}"
     if env.get_dim("mp") > 1:
-        assert [B // 2, S, HIDDEN] in mp_reduced, mp_reduced
+        # a replica's activation crosses mp where the math sums it (the
+        # embedding, o_proj and down_proj, o_proj again in the recompute,
+        # the column-parallel layers' input gradients) and nowhere else,
+        # whichever implementation the norms take: four lone instructions
+        # and two XLA combined, nine operands. A custom_vjp INSIDE a
+        # check_vma=False shard_map cost three more, named psum: JAX's
+        # transpose adding equal copies of dx together
+        activation = f"f32[{B // 2},{S},{HIDDEN}]"
+        assert {(len(r["shapes"]), r["count"]) for r in rows
+                if r["op"] == "all-reduce" and r["axes"] == ("mp",)
+                and activation in r["shapes"]} == {(1, 4), (2, 1), (3, 1)}, rows
+        assert not re.findall(rf"%psum[\w.\-]* = {re.escape(activation)}\S* "
+                              r"all-reduce", text)
     # both replicas computing identical gradients need no reduction over the
     # data axes; dividing the batch does
     assert any(r["op"] in ("all-reduce", "reduce-scatter")
