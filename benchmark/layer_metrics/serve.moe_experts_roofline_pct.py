@@ -1,0 +1,25 @@
+"""Kernels: the held experts' grouped matmuls' share of their roofline over
+the traced window — the least time the chip could take
+(``benchmark/lib/moe_cost.py``: the weights of the experts that got a row,
+their rows and 2 x rows x 3 x hidden x width operations, from the counts the
+window programs handed back) over the ``gmm`` calls' measured time."""
+from benchmark.lib import kernel_time, moe_cost, peaks, program_trace
+
+UNIT = "%"
+
+
+def reduce(trace, counters, spans, shapes):
+    shape = shapes.get("moe")
+    if not shape or not shape.get("traced"):
+        return None
+    took = kernel_time.seconds_in_window(
+        program_trace.current(shapes, "serve"), "gmm")
+    if not took:
+        return None
+    import jax
+
+    t = shape["traced"]
+    floor = moe_cost.floor_seconds(
+        moe_cost.gmm_cost(t["rows"], t["experts_hit"], shape),
+        peaks.peaks_for(jax.devices()[0].device_kind))
+    return 100.0 * floor["seconds"] / took

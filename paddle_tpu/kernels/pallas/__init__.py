@@ -18,12 +18,17 @@ Pallas TPU kernel and a plain ``jnp`` reference (``impl=None`` asks
   gather-then-attend;
 - ``ssm_step``: one step of the Mamba-2 state-space recurrence over a
   slot-indexed state arena, the state read and written once, in place
-  (the decode half of a recurrent model behind ``GenerationEngine``).
+  (the decode half of a recurrent model behind ``GenerationEngine``);
+- ``mla_paged_attention``: absorbed latent attention (MLA) against a paged
+  cache of ``[c_kv | k_r]`` rows — all heads of a token one slab against a
+  page, walking only the pages a row's length covers (decode round and
+  prefill chunk of a latent-attention model).
 
 Import order matters only in that importing this package populates the
 registry.
 """
-from . import (moe_dispatch, paged_attention, rmsnorm, rope,  # noqa: F401
-               ssm_step)
+from . import (mla_paged_attention, moe_dispatch,  # noqa: F401
+               paged_attention, rmsnorm, rope, ssm_step)
 
-__all__ = ["rmsnorm", "rope", "moe_dispatch", "paged_attention", "ssm_step"]
+__all__ = ["rmsnorm", "rope", "moe_dispatch", "paged_attention", "ssm_step",
+           "mla_paged_attention"]

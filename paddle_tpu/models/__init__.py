@@ -17,3 +17,6 @@ from .dit import (  # noqa: F401
 from .falcon_h1 import (  # noqa: F401
     FalconH1Config, FalconH1ForCausalLM, FalconH1Block,
 )
+from .openpangu_moe import (  # noqa: F401
+    OpenPanguMoEConfig, OpenPanguMoEForCausalLM, OpenPanguMoEBlock,
+)

@@ -61,15 +61,31 @@ def drain_aux(bucket):
     return total
 
 
-def _route(xt, wg, top_k):
-    """Router: fp32 softmax + renormalized top-k, and the Switch/GShard
+def _route(xt, wg, top_k, score="softmax", norm_topk=True, scale=1.0,
+           precision=None):
+    """Router: fp32 scores over ALL ``e`` router outputs (``score``:
+    'softmax', or 'sigmoid' as the DeepSeek-V3 family scores), top-k,
+    renormalized over the chosen k when ``norm_topk``, times ``scale`` (the
+    family's ``routed_scaling_factor``) — and the Switch/GShard
     load-balancing aux (e * sum(frac_tokens * frac_probs))."""
     n, _ = xt.shape
     e = wg.shape[1]
-    logits = jnp.matmul(xt.astype(jnp.float32), wg.astype(jnp.float32))
-    probs = jax.nn.softmax(logits, axis=-1)  # [n, e]
+    logits = jnp.matmul(xt.astype(jnp.float32), wg.astype(jnp.float32),
+                        precision=precision)
+    if score == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)  # [n, e]
+    elif score == "sigmoid":
+        probs = jax.nn.sigmoid(logits)
+    else:
+        raise ValueError(f"router score {score!r}: 'softmax' or 'sigmoid'")
     gate_v, gate_i = jax.lax.top_k(probs, top_k)  # [n, k]
-    gate_v = gate_v / jnp.maximum(jnp.sum(gate_v, -1, keepdims=True), 1e-9)
+    if norm_topk:
+        total = jnp.sum(gate_v, -1, keepdims=True)
+        # the softmax form as it always was; the sigmoid family's own epsilon
+        gate_v = gate_v / (jnp.maximum(total, 1e-9) if score == "softmax"
+                           else total + 1e-20)
+    if scale != 1.0:
+        gate_v = gate_v * scale
     me = jnp.mean(probs, axis=0)
     ce = jnp.mean(jax.nn.one_hot(gate_i[:, 0], e, dtype=jnp.float32), axis=0)
     aux = e * jnp.sum(me * ce)
@@ -341,6 +357,70 @@ def _moe_mlp_gmm(x, wg, w_gate, w_up, w_down, *, top_k):
     y_tok = _perm_rows(ys, inv, order).reshape(n, top_k, h)
     out = jnp.sum(y_tok * gate_v[:, :, None].astype(x.dtype), axis=1)
     return out.reshape(b, s, h), aux
+
+
+# grouped-matmul tiles of the held-experts path: a round's rows are few (a
+# decode round of 128 slots gives a held expert ~4), so the row tile is the
+# MXU's own 128, not DEFAULT_TILING's 512 (every (expert, row tile) visit
+# multiplies the whole tile); k and n tiles divide 7680 and 2048
+_SHARE_TILING = (128, 512, 1024)
+
+
+def moe_held_experts_mlp(x, wr, w_gate, w_up, w_down, *, top_k, first,
+                         score="sigmoid", norm_topk=True, scale=1.0,
+                         valid=None):
+    """One chip's SHARE of a routed expert layer under expert parallelism:
+    route ``x`` [n, h] over all ``E`` router outputs (``wr`` [h, E]), keep
+    the (token, choice) pairs whose expert lies in ``[first, first +
+    count)`` — the experts this chip holds, ``count = w_gate.shape[0]``
+    stacked weights — sort them by expert, run them through three grouped
+    matmuls (``kernels/grouped_matmul.py``: megablox on the TPU, whose grid
+    covers only the rows that met a held expert) and sum each token's
+    weighted results. What the other experts would have added is NOT here:
+    under ``ep`` it arrives by the exchange; a chip alone returns its part.
+
+    ``valid`` [n] bool marks the rows that hold a real token (a padded
+    prefill window, an idle decode row): the others route nowhere. Returns
+    ``(y [n, h] float32, stats)``, ``stats`` int32 scalars: ``pairs`` (routed
+    pairs of real tokens), ``held`` (those that met a held expert),
+    ``experts_hit`` (held experts that got a row: those whose weights the
+    call streams)."""
+    from ...kernels.grouped_matmul import grouped_matmul
+
+    n, h = x.shape
+    count = w_gate.shape[0]
+    kn = top_k * n
+    gate_v, gate_i, _aux = _route(x, wr, top_k, score=score,
+                                  norm_topk=norm_topk, scale=scale,
+                                  precision=jax.lax.Precision.HIGHEST)
+    local = gate_i - first                                    # [n, k]
+    held = (local >= 0) & (local < count)
+    if valid is not None:
+        held = held & valid[:, None]
+    # held pairs first, grouped by expert; the rest sort past the last group
+    key = jnp.where(held, local, count).reshape(kn)
+    order = jnp.argsort(key, stable=True)
+    inv = jnp.zeros((kn,), jnp.int32).at[order].set(
+        jnp.arange(kn, dtype=jnp.int32))  # int scatter, not a second sort
+    group_sizes = jnp.bincount(key, length=count + 1)[:count]
+
+    xs = jnp.take(x, order // top_k, axis=0)                  # [kn, h]
+    g_proj = grouped_matmul(xs, w_gate, group_sizes, tiling=_SHARE_TILING)
+    u_proj = grouped_matmul(xs, w_up, group_sizes, tiling=_SHARE_TILING)
+    act = jax.nn.silu(g_proj.astype(jnp.float32)) * u_proj
+    ys = grouped_matmul(act.astype(x.dtype), w_down, group_sizes,
+                        tiling=_SHARE_TILING)                 # [kn, h]
+    # rows past the groups are whatever the kernel left there: select, never
+    # multiply
+    y_tok = jnp.where(held[:, :, None],
+                      jnp.take(ys, inv, axis=0).reshape(n, top_k, h)
+                      .astype(jnp.float32), 0.0)
+    out = jnp.sum(y_tok * gate_v[:, :, None], axis=1)
+    real = jnp.int32(n) if valid is None else jnp.sum(valid, dtype=jnp.int32)
+    stats = {"pairs": real * top_k,
+             "held": jnp.sum(held, dtype=jnp.int32),
+             "experts_hit": jnp.sum(group_sizes > 0, dtype=jnp.int32)}
+    return out, stats
 
 
 def _moe_mlp_sort(x, wg, w_gate, w_up, w_down, *, top_k, capacity_factor,

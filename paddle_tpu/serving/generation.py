@@ -42,7 +42,7 @@ from ..observability.trace.request_trace import span
 from .base import (BadRequest, DeadlineExceeded, EngineBase, EngineClosed,
                    _oom_guard, _tracer)
 from .paged_kv import (HostPagePool, PagedKVPool, PoolExhausted,
-                       token_blocks)
+                       latent_width, token_blocks)
 from .served_model import (GPTServed, ServedModel, flatten_params,
                            nest_params)
 from .speculative import greedy_accept
@@ -236,7 +236,14 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
 
     ``step(params, k_arenas, v_arenas, tables, tokens, lengths,
     n_valid=None, state=None)`` returns ``(next, logprob, k_arenas,
-    v_arenas, state)``. ``n_valid`` (``[rows]``: real tokens in each row's
+    v_arenas, state)`` — and, for a model that declares ``program_counters``,
+    a sixth result ``counters``. A model whose ``cache_spec`` is a latent
+    keeps ONE arena a layer (``k_arenas``; ``v_arenas`` is empty) and its
+    blocks get ``attend(q_lat, q_rope, row)``: the window's rows are written
+    through the page table and ``kernels.pallas.mla_paged_attention`` walks
+    the pages each row's length covers. ``counters`` is the model's
+    ``program_counters`` summed over the layers (int32 scalars).
+    ``n_valid`` (``[rows]``: real tokens in each row's
     window) gives the blocks ``valid = arange(W) < n_valid``. A ``prefill``
     program (one fresh sequence a row, ``n_valid`` required) computes the
     head at the last real position only (``[rows, 1]`` outputs): an
@@ -262,6 +269,8 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     kvh, hd = sm.num_kv_heads, sm.head_dim
     scale = sm.attn_scale
     stateful = sm.state_spec is not None
+    latent = sm.cache_spec is not None
+    counter_names = sm.program_counters
     if stateful and not prefill and window != 1:
         raise ValueError(
             "a model with recurrent state decodes one token a round: "
@@ -283,6 +292,17 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     def paged_attend(q, kk, vv, tables, pos):
         return paged_attention(q, kk, vv, tables, pos, scale=scale)
 
+    if latent:
+        from ..kernels.pallas.mla_paged_attention import mla_paged_attention
+
+        dl, dv = sm.cache_spec["dim"], sm.cache_spec["value_dim"]
+        DL = latent_width(dl)
+
+        @jax.jit
+        def latent_attend(q, arena, tables, lengths):
+            return mla_paged_attention(q, arena, tables, lengths, dv=dv,
+                                       scale=scale)
+
     def step(params, k_arenas, v_arenas, tables, tokens, lengths,
              n_valid=None, state=None):
         # tables: [S, B] page ids; tokens: [S, W]; lengths: [S] (int32)
@@ -298,9 +318,23 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
         flat = (pidx * PL + pos % PL).reshape(-1)                  # [S*W]
         valid = None if n_valid is None else \
             jnp.arange(W)[None, :] < n_valid[:, None]              # [S, W]
-        new_k, new_v, new_state = [], [], []
-        for li, (p, kc, vc) in enumerate(zip(params["layers"], k_arenas,
-                                             v_arenas)):
+        new_k, new_v, new_state, counted = [], [], [], []
+        for li, (p, kc) in enumerate(zip(params["layers"], k_arenas)):
+            vc = None if latent else v_arenas[li]
+
+            def attend_latent(q_lat, q_rope, row):
+                # the window's rows land in their pages, then every head of
+                # a token rides as one slab against the pages its row's
+                # length covers (rows and queries padded to whole lanes)
+                lanes = [(0, 0)] * (row.ndim - 1) + [(0, DL - dl)]
+                arena = kc.reshape(P * PL, DL).at[flat].set(
+                    jnp.pad(row, lanes).reshape(S * W, DL)).reshape(P, PL, DL)
+                new_k.append(arena)
+                q = jnp.concatenate(
+                    [q_lat, q_rope, jnp.zeros(q_lat.shape[:-1] + (DL - dl,),
+                                              q_lat.dtype)], -1)
+                return latent_attend(q, arena, tables, lengths)
+
             def attend(q, k1, v1):
                 kk = kc.reshape(P * PL, kvh, hd).at[flat].set(
                     k1.reshape(S * W, kvh, hd)).reshape(P, PL, kvh, hd)
@@ -311,9 +345,12 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
                 # key j of the slot's pages is visible iff j <= pos[s, w]
                 return paged_attend(q, kk, vv, tables, pos)
 
-            x, st = sm.block(p, x, pos, attend,
-                             None if state is None else state[li], valid)
+            out = sm.block(p, x, pos, attend_latent if latent else attend,
+                           None if state is None else state[li], valid)
+            x, st = out[0], out[1]
             new_state.append(st)
+            if counter_names and len(out) > 2 and out[2] is not None:
+                counted.append(out[2])
         if prefill:
             # the head at the last real position only
             last = jnp.maximum(n_valid - 1, 0)[:, None, None]
@@ -326,7 +363,12 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
         lf = logits.astype(jnp.float32)
         logp = (jnp.max(lf, axis=-1) -
                 jax.scipy.special.logsumexp(lf, axis=-1))      # [S, W] f32
-        return nxt, logp, new_k, new_v, new_state if stateful else None
+        out = (nxt, logp, new_k, new_v, new_state if stateful else None)
+        if not counter_names:
+            return out
+        return out + ({name: sum((c[name] for c in counted[1:]),
+                                 counted[0][name])
+                       for name in counter_names if counted},)
 
     donate_argnums = (1, 2, 7) if stateful else (1, 2)
 
@@ -394,6 +436,14 @@ class GenerationEngine(EngineBase):
                     "slot: a rejected draft token would have advanced it "
                     "and it cannot be rolled back, so speculative decoding "
                     "is refused — pass draft_model=None")
+        self._latent = sm.cache_spec is not None
+        if self._latent and self.config.warm_pool_bytes:
+            # what moves K/V pages cannot take a latent row yet: refused in
+            # words (docs/serving.md, "Latent cache")
+            raise ValueError(
+                f"{type(model).__name__} caches one latent row a token: the "
+                "warm tier spills and restores K/V pages — pass "
+                "GenerationConfig(warm_pool_bytes=0)")
         for b in self.config.prefill_buckets:
             if b > self.max_len:
                 raise ValueError(
@@ -417,7 +467,10 @@ class GenerationEngine(EngineBase):
                                  sm.num_kv_heads, sm.head_dim, dtype,
                                  prefix_cache=self.config.prefix_cache,
                                  warm_pool=warm, state_spec=sm.state_spec,
-                                 max_slots=S)
+                                 max_slots=S, cache_spec=sm.cache_spec)
+        # device scalars the window programs handed back (the served model's
+        # ``program_counters``), read at the worker's next sync
+        self._program_counters: List[Dict[str, Any]] = []
         # cross-thread ops the worker must execute (the allocator and
         # the arenas are worker-owned): (fn, Future) pairs — the KV
         # export/install seam the page shipper rides
@@ -521,6 +574,7 @@ class GenerationEngine(EngineBase):
         self._t_start = time.monotonic()
         self.metrics.gauge("slot_occupancy", self.slot_occupancy)
         self.metrics.gauge("kv_headroom", self.kv_headroom)
+        self.metrics.gauge("kv_pool_bytes", self._kv_pool_bytes)
         if self._stateful:
             self.metrics.gauge("state_pool_bytes", self._state_pool_bytes)
         # prefix-cache truth (hits/misses/evictions) rides the snapshot
@@ -590,6 +644,7 @@ class GenerationEngine(EngineBase):
             # garbage rows are overwritten at the first real admit
             for b in self.config.prefill_buckets:
                 self._draft_prefill(0, np.zeros(b, dtype=np.int64))
+        self._program_counters.clear()  # the scratch runs routed nothing real
         self.metrics.inc("warmup_runs")
         return self
 
@@ -606,13 +661,26 @@ class GenerationEngine(EngineBase):
         import jax.numpy as jnp
 
         pool, fn = self._pool, self._window(rows, W, prefill)
-        nxt, lp, pool.k, pool.v, state = fn(
+        nxt, lp, pool.k, pool.v, state, *counters = fn(
             self._params, pool.k, pool.v, tables, tokens, lengths,
             jnp.asarray(n_valid), None if prefill else pool.state)
+        self._program_counters += counters
         if prefill:
             return nxt, lp, state
         pool.state = state
         return nxt, lp, None
+
+    def _read_program_counters(self) -> None:
+        """Add what the window programs since the last call counted to the
+        metrics. Called right after a sync the worker makes anyway (the
+        programs are done, their scalars are there): no round trip of its
+        own."""
+        import jax
+
+        pending, self._program_counters = self._program_counters, []
+        for counters in jax.device_get(pending):  # one transfer for all
+            for name, v in counters.items():
+                self.metrics.inc(name, int(v))
 
     def _state_pool_bytes(self) -> int:
         """Bytes held by the slot-indexed recurrent-state arenas."""
@@ -714,8 +782,11 @@ class GenerationEngine(EngineBase):
         # shape that keeps rejecting traffic is exactly what it fixes
         if self._hist_prompt is not None:
             self._hist_prompt.observe(len(prompt))
-        bucket = self._prefill_bucket(len(prompt))
-        if bucket is None:
+        if self._prefill_bucket(len(prompt)) is None and \
+                (self._stateful or self.spec_k):
+            # a longer prompt is prefilled in chunks against its own cached
+            # pages; a recurrent state (a prefill starts it from zero) and a
+            # draft model (its dense arena takes one whole bucket) cannot
             self.metrics.inc("errors_total")
             fut.set_exception(BadRequest(
                 f"prompt length {len(prompt)} exceeds the largest prefill "
@@ -770,6 +841,21 @@ class GenerationEngine(EngineBase):
             if b >= n:
                 return b if b <= self.max_len else None
         return None
+
+    def _prefill_chunks(self, start: int, end: int
+                        ) -> List[Tuple[int, int, int]]:
+        """The window calls that prefill prompt positions ``[start, end)``:
+        ``(lo, hi, W)`` each — whole chunks of the largest bucket, then the
+        smallest bucket that holds the rest. One call when the suffix fits
+        a bucket (every prompt of a model whose requests stay within the
+        buckets)."""
+        C = self.config.prefill_buckets[-1]
+        chunks = []
+        while end - start > C:
+            chunks.append((start, start + C, C))
+            start += C
+        chunks.append((start, end, self._prefill_bucket(end - start)))
+        return chunks
 
     def set_speculative(self, enabled: bool) -> None:
         """Brownout lever: toggle draft-model speculation per decode
@@ -923,6 +1009,11 @@ class GenerationEngine(EngineBase):
                     fut.set_result(res)
 
     def _refuse_kv_transfer(self, what: str) -> None:
+        if self._latent:
+            raise RuntimeError(
+                f"{what}: {type(self.model).__name__} caches one latent row "
+                "a token — the page shipper's wire format is K and V stacks "
+                "of [pages, page_len, heads, dim] and cannot carry it yet")
         if self._stateful:
             raise RuntimeError(
                 f"{what}: {type(self.model).__name__} carries recurrent "
@@ -1061,7 +1152,17 @@ class GenerationEngine(EngineBase):
                 if req is None:
                     break
                 try:
-                    self._admit(free, req)
+                    if self._admit(free, req) > 1:
+                        # a chunked prefill has held decode for several
+                        # window calls: the running sequences get a round
+                        # before the next prompt is admitted. (Admitting
+                        # every waiting prompt first starves decode past the
+                        # knee, and completed tokens/s then swing with the
+                        # arrivals' timing: PERF.md section 6, PR 32.) A
+                        # prompt that fits a bucket is one call, as ever.
+                        # A stopgap: it goes when a chunk runs between two
+                        # rounds (ROADMAP 2a R3)
+                        break
                 except PoolExhausted:
                     # transient: pages freed by in-flight releases will
                     # cover it — requeue at the front, decode meanwhile
@@ -1124,7 +1225,8 @@ class GenerationEngine(EngineBase):
         pages for the rest, prefill ONLY the uncached suffix through the
         window step, and adopt its full prompt blocks into the prefix
         cache. The first generated token is the window's argmax at the
-        last real prompt position (matching ``generate``'s contract)."""
+        last real prompt position (matching ``generate``'s contract).
+        Returns the number of window calls the prefill took."""
         import jax.numpy as jnp
 
         with span("pt.serve.admit", trace_id=req.trace, slot=slot_no,
@@ -1171,25 +1273,45 @@ class GenerationEngine(EngineBase):
                     pg, copied = self._pool.ensure_writable(int(s.table[bi]))
                     if copied:
                         s.table[bi] = pg
-            # suffix prefill: one call of the ONE-ROW window step — this
-            # request's tokens, table and start, nobody else's
+            # suffix prefill through the ONE-ROW window step — this request's
+            # tokens, table and start, nobody else's. A suffix that fits a
+            # bucket is one call; a longer one runs as successive chunks of
+            # the largest bucket at start, start + C, ... back to back, each
+            # attending to the pages the earlier ones wrote, and the head is
+            # read after the last
             start = m * pl
-            suffix = req.prompt[start:p]
-            W = self._prefill_bucket(len(suffix))
-            sp.args.update(bucket=W, prefix_blocks=m)
+            chunks = self._prefill_chunks(start, p)
+            W = chunks[-1][2]
+            table = jnp.asarray(s.table[None])
+            sp.args.update(bucket=W, prefix_blocks=m, chunks=len(chunks))
             with span("pt.serve.prefill_dispatch", bucket=W, prefix_blocks=m,
                       rows=1):
-                tokens = np.zeros((1, W), dtype=np.int32)
-                tokens[0, :len(suffix)] = suffix
-                with _oom_guard("generation",
-                                label=f"serving:{self.name}:prefill",
-                                engine=self.name, bucket=W):
-                    nxt, lp, row = self._run_window(
-                        1, W, jnp.asarray(s.table[None]),
-                        jnp.asarray(tokens),
-                        jnp.asarray(np.array([start], dtype=np.int32)),
-                        n_valid=np.array([len(suffix)], dtype=np.int32),
-                        prefill=True)
+                for lo, hi, Wc in chunks:
+                    with span("pt.serve.prefill_chunk", start=lo, W=Wc):
+                        tokens = np.zeros((1, Wc), dtype=np.int32)
+                        tokens[0, :hi - lo] = req.prompt[lo:hi]
+                        with _oom_guard("generation",
+                                        label=f"serving:{self.name}:prefill",
+                                        engine=self.name, bucket=Wc):
+                            nxt, lp, row = self._run_window(
+                                1, Wc, table, jnp.asarray(tokens),
+                                jnp.asarray(np.array([lo], dtype=np.int32)),
+                                n_valid=np.array([hi - lo], dtype=np.int32),
+                                prefill=True)
+                        if hi < p:
+                            # a chunk ends when the device has run it: the
+                            # span is the chunk's time, and the next chunk's
+                            # dispatch never queues behind it
+                            nxt.block_until_ready()
+                    self.metrics.inc("prefill_chunks_total")
+                    # token-rows the prefill program ran (rows x W): what
+                    # stats()["prefill_fill_rate"] divides the real tokens by
+                    self.metrics.inc("prefill_window_tokens_total", Wc)
+                    # cached positions the chunk's queries see, summed (token
+                    # w of the chunk sees lo + w + 1)
+                    n = hi - lo
+                    self.metrics.inc("attn_keys_prefill_total",
+                                     n * lo + n * (n + 1) // 2)
             if row is not None:
                 with span("pt.serve.state_install", slot=slot_no):
                     self._install_state(slot_no, row)
@@ -1198,6 +1320,7 @@ class GenerationEngine(EngineBase):
             with span("pt.serve.prefill_sync"):
                 first = int(np.asarray(nxt)[0, 0])
                 first_lp = float(np.asarray(lp)[0, 0])
+            self._read_program_counters()
             # draft model prefills the WHOLE prompt through its own forward
             # (the draft is small; its dense slot arena has no prefix cache)
             if self.spec_k:
@@ -1216,9 +1339,6 @@ class GenerationEngine(EngineBase):
                     self._fam_prefix.inc((self.name, "hit_tokens"), m * pl)
             self.metrics.inc("prompt_tokens_total", p)
             self.metrics.inc("prefills_total")
-            # token-rows the prefill program ran (rows x W): what
-            # stats()["prefill_fill_rate"] divides the real tokens by
-            self.metrics.inc("prefill_window_tokens_total", W)
             if m:
                 self.metrics.inc("prefix_hits")
             self.metrics.observe_queue_wait((t0 - req.t_submit) * 1e3)
@@ -1235,6 +1355,7 @@ class GenerationEngine(EngineBase):
             s.t0 = t1  # slot residency opens (occupancy track)
             self._note_token(req, first, first_lp)
             self._emit_finish_check(slot_no)
+            return len(chunks)
 
     def _note_token(self, req: _GenRequest, t: int, lp: float) -> None:
         """One emitted token: record it (token + behavior logprob) and
@@ -1327,6 +1448,7 @@ class GenerationEngine(EngineBase):
             with span("pt.serve.decode_sync"):
                 n = np.asarray(nxt)  # [S, W] target argmax at each position
                 lpn = np.asarray(lp)  # [S, W] its behavior logprob (f32)
+            self._read_program_counters()
             fr = self._flight()
             if fr is not None:  # decode steps land in the flight ring
                 fr.record_serving_step(self.name, "decode",
@@ -1334,6 +1456,9 @@ class GenerationEngine(EngineBase):
                                        len(active))
             self.metrics.inc("decode_steps")
             self.metrics.inc("slot_rounds", len(active))
+            # cached positions the round's queries see, summed over its rows
+            self.metrics.inc("attn_keys_decode_total",
+                             int(lengths.sum()) + len(active))
             self.metrics.observe_occupancy(len(active) / S)
             with span("pt.serve.emit"):
                 emitted_total = self._emit_round(active, k, tokens, n, lpn)
@@ -1480,6 +1605,10 @@ class GenerationEngine(EngineBase):
         rounds = c.get("slot_rounds", 0)  # per-SEQUENCE decode rounds
         snap["effective_tokens_per_step"] = round(
             c.get("tokens_total", 0) / rounds, 3) if rounds else 0.0
+        pairs = c.get("moe_pairs_total", 0)
+        if pairs:  # an expert layer that holds a share of its experts
+            snap["moe_held_share"] = round(
+                c.get("moe_held_pairs_total", 0) / pairs, 5)
         if self.spec_k:
             prop = c.get("spec_proposed", 0)
             snap["spec_acceptance"] = round(

@@ -426,6 +426,12 @@ class HostPagePool:
                     "evictions": self.evictions, "restores": self.restores}
 
 
+def latent_width(dim: int) -> int:
+    """Columns of a latent arena: ``dim`` rounded up to whole 128-lane
+    tiles (the TPU lays a row out so anyway; a page is DMA'd whole)."""
+    return -(-int(dim) // 128) * 128
+
+
 class PagedKVPool:
     """The device half: per-layer K/V page arenas + the control plane.
 
@@ -440,7 +446,7 @@ class PagedKVPool:
                  num_heads: int, head_dim: int, dtype,
                  prefix_cache: bool = True,
                  warm_pool: Optional[HostPagePool] = None,
-                 state_spec=None, max_slots: int = 0):
+                 state_spec=None, max_slots: int = 0, cache_spec=None):
         import jax.numpy as jnp
 
         self.page_len = int(page_len)
@@ -449,10 +455,19 @@ class PagedKVPool:
         self.trie: Optional[PrefixCache] = PrefixCache() if prefix_cache \
             else None
         self.warm = warm_pool
-        self.k = [jnp.zeros((num_pages, page_len, num_heads, head_dim),
-                            dtype) for _ in range(num_layers)]
-        self.v = [jnp.zeros((num_pages, page_len, num_heads, head_dim),
-                            dtype) for _ in range(num_layers)]
+        # what a token leaves in a layer (the served model's ``cache_spec``):
+        # K and V of [heads, head_dim] — or ONE latent row, kept in ``k``
+        # alone at a whole number of 128-lane tiles (a page is DMA'd whole)
+        self.cache_spec = cache_spec
+        if cache_spec is None:
+            shape = (num_pages, page_len, num_heads, head_dim)
+        elif cache_spec["kind"] == "latent":
+            shape = (num_pages, page_len, latent_width(cache_spec["dim"]))
+        else:
+            raise ValueError(f"unknown cache kind {cache_spec['kind']!r}")
+        self.k = [jnp.zeros(shape, dtype) for _ in range(num_layers)]
+        self.v = [] if cache_spec is not None else \
+            [jnp.zeros(shape, dtype) for _ in range(num_layers)]
         # the second kind of cache: per layer, one slot-indexed arena per
         # entry of a recurrent model's ``state_spec`` ({name: (per-slot
         # shape, dtype)}) — e.g. the SSM state [slots, heads, P, N] and the
@@ -602,6 +617,8 @@ class PagedKVPool:
     def stats(self) -> Dict[str, Any]:
         a = self.allocator
         out = {"pages_total": a.num_pages, "page_len": self.page_len,
+               "cache": "kv" if self.cache_spec is None
+               else self.cache_spec["kind"],
                "pages_free": a.free_pages, "pages_live": a.live_pages,
                "pool_bytes": self.bytes(),
                "state_bytes": self.state_bytes(),

@@ -26,11 +26,29 @@ contains no model. A model that can be served implements
   row);
 - ``head(params, x) -> logits``: final norm and output head;
 - ``state_spec``: ``None``, or ``{name: (per-slot shape, dtype)}`` — the
-  slot-indexed arenas the engine keeps per layer beside the paged K/V.
+  slot-indexed arenas the engine keeps per layer beside the paged K/V;
+- ``cache_spec``: what ONE token leaves in a layer's paged cache. ``None``
+  (GPT-2, Falcon-H1): a key and a value of ``[num_kv_heads, head_dim]``,
+  two arenas a layer. ``{"kind": "latent", "dim": d, "value_dim": dv}`` (a
+  latent-attention model): ONE row of ``d`` values — the arena is
+  ``[pages, page_len, d rounded up to 128 lanes]``, and ``attend`` takes
+  its latent form: ``attend(q_lat, q_rope, row)`` with ``q_lat`` ``[rows,
+  W, heads, dv]``, ``q_rope`` ``[rows, W, heads, d - dv]`` and the window's
+  own cache rows ``row`` ``[rows, W, d]``; the engine writes ``row``
+  through the page table and returns each head's softmax-weighted sum of
+  the cached rows' first ``dv`` columns, ``[rows, W, heads, dv]`` (the
+  absorbed form: the model carries it through its value up-projection);
+- ``program_counters``: ``None``, or the names of int32 scalars a block may
+  hand back as a THIRD result (``(x, state, {name: scalar})``, ``None`` from
+  a layer that has none). The window program sums them over its layers and
+  returns them beside the tokens; the worker adds them to its counters at
+  the sync it makes anyway.
 
 A model with recurrent state cannot use what assumes a cache is pages of
-K/V (the prefix trie, speculative verify, KV-page export/install): the
-engine refuses those in words (``docs/serving.md``).
+K/V (the prefix trie, speculative verify, KV-page export/install), and a
+latent cache cannot yet use what moves K/V pages (export/install and its
+wire format, the warm tier): the engine refuses those in words
+(``docs/serving.md``).
 """
 from __future__ import annotations
 
@@ -52,6 +70,10 @@ class ServedModel:
     attn_scale: float
     # None: the only cache is the paged K/V
     state_spec: Optional[Dict[str, Tuple[Tuple[int, ...], Any]]] = None
+    # None: a token leaves K and V of [num_kv_heads, head_dim] in a layer
+    cache_spec: Optional[Dict[str, Any]] = None
+    # None: the window programs hand back tokens and logprobs alone
+    program_counters: Optional[Tuple[str, ...]] = None
 
     def params(self, model) -> Dict[str, Any]:
         raise NotImplementedError

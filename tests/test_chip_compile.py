@@ -256,3 +256,45 @@ def test_moe_routing_dispatch(one_chip, monkeypatch):
              ((e, inter, h), BF16),
              names=("pt_moe_route", "pt_moe_dispatch", "pt_moe_combine"),
              foreign="gmm_")
+
+
+@pytest.mark.parametrize("S,W", [(128, 1), (1, 256), (1, 512)])
+def test_mla_paged_attention_published_widths(one_chip, S, W):
+    """openPangu-Ultra-MoE's absorbed latent attention: 128 heads against
+    rows of 576 (laid out at 640: whole lanes) in the cell's pool (4161
+    pages of 128 tokens, 32 blocks a row) — the 128-slot decode round (a
+    ``[128, 640]`` slab a row) and both one-row prefill chunks."""
+    from paddle_tpu.kernels.pallas import mla_paged_attention as kmla
+
+    def run(q, arena, tables, start):
+        return kmla.mla_paged_attention(q, arena, tables, start, dv=512,
+                                        scale=192 ** -0.5, impl="pallas")
+
+    _compile(run, one_chip, ((S, W, 128, 640), BF16),
+             ((4161, 128, 640), BF16), ((S, 32), jnp.int32),
+             ((S,), jnp.int32), names=("pt_mla_paged_attention",))
+
+
+@pytest.mark.parametrize("tokens", [128, 512])
+def test_held_experts_grouped_matmuls_published_widths(one_chip, monkeypatch,
+                                                       tokens):
+    """One chip's share of an openPangu-Ultra-MoE expert layer: 16 held
+    experts of 7680 x 2048 under a router of 256 outputs, top 8 — a decode
+    round's 128 tokens and a 512-token chunk. The three grouped matmuls ride
+    megablox ``gmm`` (JAX's own kernel: the trace shows it as ``gmm.N``),
+    which gates on the backend — steered to its TPU branch here."""
+    from paddle_tpu.kernels import grouped_matmul as gm
+    from paddle_tpu.nn.layer.moe import moe_held_experts_mlp
+
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    h, w, held = 7680, 2048, 16
+
+    def run(x, router, w_gate, w_up, w_down):
+        return moe_held_experts_mlp(x, router, w_gate, w_up, w_down, top_k=8,
+                                    first=0, scale=2.5)
+
+    text = _compile(run, one_chip, ((tokens, h), BF16),
+                    ((h, 256), jnp.float32), ((held, h, w), BF16),
+                    ((held, h, w), BF16), ((held, w, h), BF16), names=(),
+                    foreign="gmm")
+    assert sum(c.startswith("gmm") for c in _CUSTOM_CALL.findall(text)) == 3

@@ -417,3 +417,54 @@ def test_gpt2_through_the_seam_is_the_parents_program(rows, W, oracle):
     for a, b in zip(jax.tree_util.tree_leaves(ours(*args)),
                     jax.tree_util.tree_leaves(theirs(*args))):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+# sha256 (first 16 hex) of the window programs' lowered text at the PARENT of
+# PR 32 (commit 795d4c1, jax 0.9.0; regenerate with the body of the test from
+# a checkout of the commit a later PR is held to)
+_PARENT_TEXT = {
+    ("gpt2", 3, 1, False): "29433d40c5de7d35",
+    ("gpt2", 1, 8, True): "91dab69840d3029b",
+    ("gpt2", 3, 3, False): "089ae77d6a015f59",
+    ("falcon_h1", 3, 1, False): "eac7efc3b21685f6",
+    ("falcon_h1", 1, 8, True): "f99df1e2fa730513",
+}
+
+
+@pytest.mark.parametrize("arch,rows,W,prefill", sorted(_PARENT_TEXT))
+def test_window_programs_lower_to_the_parents_text(arch, rows, W, prefill):
+    """PR 32 widened the seam (a latent cache, program counters, chunked
+    prefill): GPT-2's and Falcon-H1's decode, verify and one-row prefill
+    programs still lower to the parent's text letter for letter, so a
+    compile-cache entry the parent wrote is still theirs."""
+    import hashlib
+
+    from paddle_tpu.jit import lowerable
+
+    if arch == "gpt2":
+        from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
+
+        paddle.seed(0)
+        model = GPTForCausalLM(GPTConfig(
+            vocab_size=64, hidden_size=32, num_hidden_layers=2,
+            num_attention_heads=4, max_position_embeddings=64,
+            dtype="float32"))
+        kvh = 4
+    else:
+        paddle.seed(3)
+        model = FalconH1ForCausalLM(FalconH1Config.tiny())
+        kvh = 2
+    sm = model.served_model()
+    B, PL = 4, 8
+    arenas = [jnp.zeros((3 * B + 1, PL, kvh, 8))] * 2
+    state = None if prefill or sm.state_spec is None else [
+        {name: jnp.zeros((rows,) + tuple(shape), dt)
+         for name, (shape, dt) in sm.state_spec.items()} for _ in range(2)]
+    args = (sm.params(model), arenas, arenas,
+            jnp.zeros((rows, B), jnp.int32), jnp.zeros((rows, W), jnp.int32),
+            jnp.zeros(rows, jnp.int32), jnp.ones(rows, jnp.int32), state)
+    step = gen._build_window_step(sm, rows, B, PL, W, donate=False,
+                                  label="t", prefill=prefill)
+    text = lowerable(step).lower(*args).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        _PARENT_TEXT[(arch, rows, W, prefill)]

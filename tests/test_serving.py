@@ -363,7 +363,9 @@ def test_generation_matches_model_generate(gen_engine):
 def test_generation_bad_prompt_isolated(gen_engine):
     eng, _model, pattern = gen_engine
     bad_shape = eng.submit(pattern[:6].reshape(2, 3), max_new_tokens=2)
-    too_long = eng.submit(np.zeros(40, np.int64), max_new_tokens=2)
+    # past max_seq_len 48 (a prompt past the largest bucket alone is
+    # prefilled in chunks: tests/test_openpangu_moe.py)
+    too_long = eng.submit(np.zeros(50, np.int64), max_new_tokens=2)
     # prompt fits a prefill bucket but prompt+max_new_tokens overruns the
     # slot arena: reject instead of silently truncating the continuation
     overrun = eng.submit(pattern[:16].astype("int64"), max_new_tokens=64)
