@@ -148,6 +148,42 @@ class _Slot:
         self.shared = 0   # leading entries borrowed from the prefix cache
 
 
+class _Admission:
+    """A prompt joining a slot: its pages, the window calls of its prefill
+    and what the host has not read of them yet."""
+
+    __slots__ = ("slot_no", "req", "chunks", "table", "m", "outs",
+                 "counters")
+
+    def __init__(self, slot_no: int, req: _GenRequest):
+        self.slot_no = slot_no
+        self.req = req
+        self.chunks: Optional[List[Tuple[int, int, int]]] = None  # by _join
+        self.table = None     # the slot's page table, on the device
+        self.m = 0            # leading pages borrowed from the prefix cache
+        self.outs: List[Tuple[Any, Any]] = []  # (next, logprob) a call sent
+        self.counters: List[Dict[str, Any]] = []
+
+    @property
+    def rows(self) -> List[Tuple[int, _GenRequest]]:
+        return [(self.slot_no, self.req)]
+
+
+class _Round:
+    """One decode round: the requests whose rows it advances, its operands
+    on the host and its outputs, unread, on the device."""
+
+    __slots__ = ("rows", "k", "tokens", "lengths", "tables", "nxt", "lp",
+                 "counters")
+
+    def __init__(self, rows, k, tokens, lengths, tables):
+        self.rows: List[Tuple[int, _GenRequest]] = rows
+        self.k = k            # draft proposals a row (0: classic decode)
+        self.tokens, self.lengths, self.tables = tokens, lengths, tables
+        self.nxt = self.lp = None
+        self.counters: List[Dict[str, Any]] = []
+
+
 def _served(model_or_cfg) -> ServedModel:
     """The protocol object of a model — or of a bare GPT config, which is
     what the callers that predate the seam (tests, the AOT rehearsal) hand
@@ -468,9 +504,6 @@ class GenerationEngine(EngineBase):
                                  prefix_cache=self.config.prefix_cache,
                                  warm_pool=warm, state_spec=sm.state_spec,
                                  max_slots=S, cache_spec=sm.cache_spec)
-        # device scalars the window programs handed back (the served model's
-        # ``program_counters``), read at the worker's next sync
-        self._program_counters: List[Dict[str, Any]] = []
         # cross-thread ops the worker must execute (the allocator and
         # the arenas are worker-owned): (fn, Future) pairs — the KV
         # export/install seam the page shipper rides
@@ -480,7 +513,15 @@ class GenerationEngine(EngineBase):
 
         donate = self.config.donate_cache and jax.default_backend() != "cpu"
         self._donate = donate
+        # a round's tokens are COMMITTED to the arenas' device, as a window
+        # program's outputs are: a round that takes its tokens from the
+        # round before it on the device (``_send_round``) and one that takes
+        # them from the host are then one signature to ``jax.jit``, lowered
+        # once (a second lowering of a 36-layer program is seconds)
+        self._device = next(iter(self._pool.k[0].devices()))
         self._state_install_fn = None
+        self._token_feed_fn = None
+        self._decode_no = -1  # rounds dispatched (the decode_fault site's step)
         # (rows, W, prefill) -> compiled window step
         self._windows: Dict[Tuple[int, int, bool], Any] = {}
 
@@ -615,26 +656,46 @@ class GenerationEngine(EngineBase):
 
     def warmup(self):
         """Compile the whole steady-state executable set up front (decode,
-        speculative verify, every one-row prefill bucket, draft steps)
-        against the scratch page — a warm replica restarting under the
-        persistent cache loads them all from disk with zero fresh XLA
-        compiles."""
+        speculative verify, every one-row prefill bucket, the token feed,
+        draft steps) against the scratch page — a warm replica restarting
+        under the persistent cache loads them all from disk with zero fresh
+        XLA compiles."""
+        import jax
         import jax.numpy as jnp
 
         S, B = self.config.max_slots, self._n_blocks
-        programs = [(S, 1, False)] + \
-            ([(S, self.spec_k + 1, False)] if self.spec_k else []) + \
-            [(1, b, True) for b in self.config.prefill_buckets]
-        for rows, W, prefill in programs:
+
+        def scratch(rows, W, prefill, tokens=None):
             # a decode round in which no row is valid; a prefill of one
-            # token, and the install of its row (slot 0 is free)
-            _n, _lp, row = self._run_window(
-                rows, W, jnp.zeros((rows, B), jnp.int32),
-                jnp.zeros((rows, W), jnp.int32), jnp.zeros(rows, jnp.int32),
+            # token, and the install of its row (slot 0 is free). What the
+            # scratch runs counted is dropped: they routed nothing real
+            if tokens is None:
+                tokens = np.zeros((rows, W), np.int32)
+                tokens = jnp.asarray(tokens) if prefill else \
+                    jax.device_put(tokens, self._device)
+            nxt, _lp, row, _counted = self._run_window(
+                rows, W, jnp.zeros((rows, B), jnp.int32), tokens,
+                jnp.zeros(rows, jnp.int32),
                 n_valid=np.full(rows, int(prefill), np.int32),
                 prefill=prefill)
             if row is not None:
                 self._install_state(0, row)
+            return nxt
+
+        after_round = scratch(S, 1, False)
+        if self.spec_k:
+            scratch(S, self.spec_k + 1, False)
+        for b in self.config.prefill_buckets:
+            after_prefill = scratch(1, b, True)
+        if not self.spec_k:
+            # a round that goes out ahead of a read takes its tokens from
+            # the device: the unread round's own output, or a prompt's first
+            # token fed into the host's rows (``_send_round``). Both forms
+            # run here: were either a signature of its own after all, its
+            # lowering would fall here and not on a request
+            scratch(S, 1, False, tokens=after_round)
+            scratch(S, 1, False, tokens=self._feed_token(
+                np.zeros((S, 1), np.int32), after_prefill, 0))
         if self.spec_k:
             zeros = jnp.zeros(S, jnp.int32)
             _n, self._dk, self._dv = self._draft_step(
@@ -644,7 +705,6 @@ class GenerationEngine(EngineBase):
             # garbage rows are overwritten at the first real admit
             for b in self.config.prefill_buckets:
                 self._draft_prefill(0, np.zeros(b, dtype=np.int64))
-        self._program_counters.clear()  # the scratch runs routed nothing real
         self.metrics.inc("warmup_runs")
         return self
 
@@ -656,31 +716,50 @@ class GenerationEngine(EngineBase):
         and, for a model with recurrent state, starts every layer from
         zero, stops the recurrence at ``n_valid`` and hands back the row's
         FINAL state; any other round of such a model advances the state
-        arenas in place. Returns ``(next, logprob, row)``; ``row`` is
-        ``None`` but for that prefill."""
+        arenas in place. Returns ``(next, logprob, row, counted)``; ``row``
+        is ``None`` but for that prefill, and ``counted`` holds the device
+        scalars of a model that declares ``program_counters`` (a list of at
+        most one dict: what ``_count_programs`` takes once the call is
+        done)."""
         import jax.numpy as jnp
 
         pool, fn = self._pool, self._window(rows, W, prefill)
-        nxt, lp, pool.k, pool.v, state, *counters = fn(
+        nxt, lp, pool.k, pool.v, state, *counted = fn(
             self._params, pool.k, pool.v, tables, tokens, lengths,
             jnp.asarray(n_valid), None if prefill else pool.state)
-        self._program_counters += counters
         if prefill:
-            return nxt, lp, state
+            return nxt, lp, state, counted
         pool.state = state
-        return nxt, lp, None
+        return nxt, lp, None, counted
 
-    def _read_program_counters(self) -> None:
-        """Add what the window programs since the last call counted to the
-        metrics. Called right after a sync the worker makes anyway (the
-        programs are done, their scalars are there): no round trip of its
-        own."""
+    def _count_programs(self, counted: List[Dict[str, Any]]) -> None:
+        """Add what window programs counted to the metrics. The caller hands
+        over the scalars of programs whose result it has just read — they
+        are done, so this waits for nothing, and above all not for the
+        program that went out after them."""
         import jax
 
-        pending, self._program_counters = self._program_counters, []
-        for counters in jax.device_get(pending):  # one transfer for all
+        for counters in jax.device_get(counted):  # one transfer for all
             for name, v in counters.items():
                 self.metrics.inc(name, int(v))
+
+    def _feed_token(self, tokens, first, slot_no: int):
+        """A decode round's ``[max_slots, 1]`` tokens with row ``slot_no``
+        taken from ``first``, the ``[1, 1]`` output of a prefill the host
+        has not read yet: the round can go out behind the prefill without
+        the token crossing the host."""
+        import jax
+
+        fn = self._token_feed_fn
+        if fn is None:
+            from .. import jit as jit_mod
+
+            label = f"serving:{self.name}:token_feed"
+            fn = self._token_feed_fn = jit_mod._maybe_audit(
+                label, jit_mod.persistent_cache.cached_jit(
+                    lambda tokens, first, slot: jax.lax.dynamic_update_slice(
+                        tokens, first, (slot, 0)), label=label))
+        return fn(tokens, first, np.int32(slot_no))
 
     def _state_pool_bytes(self) -> int:
         """Bytes held by the slot-indexed recurrent-state arenas."""
@@ -1130,57 +1209,60 @@ class GenerationEngine(EngineBase):
             _tracer().finish(r.trace, ok=False, error="DeadlineExceeded")
         return picked
 
+    def _free_slot(self) -> Optional[int]:
+        return next((i for i, s in enumerate(self._slots) if s.req is None),
+                    None)
+
     def _worker(self):
+        """The continuous-batching loop. Each turn takes ONE window program
+        (a prompt's prefill, one call or several, or a decode round) from its
+        dispatch to the host's read of its result, and dispatches the program
+        after it BEFORE that read wherever what comes next does not hang on
+        the result (``_run_ahead``): the device then starts the next program
+        the moment this one ends instead of idling for a host round trip.
+        ``prog`` is the program that went out ahead of its turn, if one did;
+        with none in flight the worker is at a boundary and decides with
+        everything read, as it always did."""
+        prog = None
+        # a chunked prefill has held decode for several window calls: the
+        # running sequences get a round before the next prompt is admitted.
+        # (Admitting every waiting prompt first starves decode past the
+        # knee, and completed tokens/s then swing with the arrivals' timing:
+        # PERF.md section 6, PR 32.) A prompt that fits a bucket is one
+        # call, as ever. A stopgap: it goes when a chunk runs between two
+        # rounds (ROADMAP 2a R3)
+        owe_round = False
         while True:
-            # cross-thread ops (KV export/install) land at the step
-            # boundary, before admission — an installed prefix is
-            # visible to the very next admit
-            self._drain_ops()
-            # a staged weight swap lands at the first zero-active step
-            # boundary (admission pauses below until it does, so the
-            # active set drains and in-flight work stays version-pure)
-            if self._pending_swap is not None and not self._active():
-                self._apply_swap()
-            # admit queued prompts into free slots (join mid-flight,
-            # earliest deadline first, bounded by KV page headroom)
-            while self._pending_swap is None:
-                free = next((i for i, s in enumerate(self._slots)
-                             if s.req is None), None)
-                if free is None:
-                    break
-                req = self._next_request()
-                if req is None:
-                    break
+            if prog is None:
+                # cross-thread ops (KV export/install) land at the step
+                # boundary, before admission — an installed prefix is
+                # visible to the very next admit
+                self._drain_ops()
+                # a staged weight swap lands at the first zero-active step
+                # boundary (admission pauses below until it does, so the
+                # active set drains and in-flight work stays version-pure)
+                if self._pending_swap is not None and not self._active():
+                    self._apply_swap()
+                # admit a queued prompt into a free slot (join mid-flight,
+                # earliest deadline first, bounded by KV page headroom)
+                if self._pending_swap is None and not owe_round:
+                    free = self._free_slot()
+                    req = None if free is None else self._next_request()
+                    if req is not None:
+                        prog = _Admission(free, req)
+            owe_round = False
+            if isinstance(prog, _Admission):
+                adm = prog
                 try:
-                    if self._admit(free, req) > 1:
-                        # a chunked prefill has held decode for several
-                        # window calls: the running sequences get a round
-                        # before the next prompt is admitted. (Admitting
-                        # every waiting prompt first starves decode past the
-                        # knee, and completed tokens/s then swing with the
-                        # arrivals' timing: PERF.md section 6, PR 32.) A
-                        # prompt that fits a bucket is one call, as ever.
-                        # A stopgap: it goes when a chunk runs between two
-                        # rounds (ROADMAP 2a R3)
-                        break
+                    prog = self._admit(adm)
+                    owe_round = prog is None and len(adm.chunks or ()) > 1
                 except PoolExhausted:
                     # transient: pages freed by in-flight releases will
                     # cover it — requeue at the front, decode meanwhile
-                    with self._cond:
-                        self._queue.appendleft(req)
-                    self.metrics.inc("admits_requeued")
-                    break
-                except Exception as e:  # isolate: fail this prompt only
-                    if not req.future.done():
-                        req.future.set_exception(e)
-                    _tracer().finish(req.trace, ok=False,
-                                     error=type(e).__name__)
-                    self.metrics.inc("errors_total")
-                    self._release_pages(self._slots[free])
-                    slot = self._slots[free]
-                    slot.req, slot.length, slot.last_token = None, 0, 0
-            active = self._active()
-            if not active:
+                    self._requeue(adm.req)
+                    prog, owe_round = None, True
+                continue
+            if prog is None and not self._active():
                 with self._cond:
                     if self._closed and not self._queue:
                         pend, self._pending_swap = self._pending_swap, None
@@ -1199,163 +1281,267 @@ class GenerationEngine(EngineBase):
                         with span("pt.serve.idle_wait"):
                             self._cond.wait()
                 continue
-            if self._hist_slots is not None:
-                # concurrent-occupancy sample per decode window: the
-                # distribution the tuner derives max_slots from
-                self._hist_slots.observe(len(active))
-            try:
-                self._decode_once(active)
-            except Exception as e:  # decode fault: fail the in-flight batch
-                now = time.monotonic()
-                for i in active:
-                    s = self._slots[i]
-                    if s.req is not None:
-                        if not s.req.future.done():
-                            s.req.future.set_exception(e)
-                        self._release_slot(i, now, failed=True,
-                                           error=type(e).__name__)
-                    else:
-                        self._release_pages(s)
-                        s.req, s.length, s.last_token = None, 0, 0
-                self.metrics.inc("errors_total", len(active))
-                self.metrics.inc("batch_failures")
+            prog = self._decode_once(prog)
 
-    def _admit(self, slot_no: int, req: _GenRequest):
-        """Join a prompt: borrow its cached prefix pages, allocate private
-        pages for the rest, prefill ONLY the uncached suffix through the
-        window step, and adopt its full prompt blocks into the prefix
-        cache. The first generated token is the window's argmax at the
-        last real prompt position (matching ``generate``'s contract).
-        Returns the number of window calls the prefill took."""
+    def _requeue(self, req: _GenRequest) -> None:
+        with self._cond:
+            self._queue.appendleft(req)
+        self.metrics.inc("admits_requeued")
+
+    def _run_ahead(self, flying):
+        """Dispatch the program that follows ``flying`` — a round or a
+        prompt's last prefill call, dispatched and not yet read — if what
+        follows is decided whatever ``flying`` returns; else ``None``, and
+        the worker reads, then decides. Decided means:
+
+        - a chunked admission's last call is followed by the round the
+          running sequences are owed (``_worker``); that round takes the
+          prompt's first token from the call's own output on the device;
+        - with a slot free and a prompt waiting whose pages the pool has,
+          that prompt's first prefill call comes next: slot and pages do not
+          hang on the unread result;
+        - with no slot free, and none to come free when ``flying`` is read, no
+          prompt can join whatever arrives, so a round comes next. Every
+          request's remaining budget is known here; one that ends on EOS
+          instead is the one wasted row: ``_emit_round`` drops its extra
+          token.
+
+        With a slot free and nothing to admit NOTHING goes out: the next
+        arrival would otherwise prefill behind a round dispatched on a guess
+        and pay up to that round in first-token latency. A draft model's
+        proposals cross the host, so speculative decoding keeps one program
+        at a time; a staged weight swap and cross-thread ops wait for a
+        boundary at which nothing is in flight, so they stop it too. A fault
+        here fails the requests of the program that was to go out, as it
+        would have a turn later."""
+        if self.spec_k or self._pending_swap is not None:
+            return None
+        with self._cond:
+            if self._ops:
+                return None
+        owed = isinstance(flying, _Admission) and len(flying.chunks) > 1
+        free = None if owed else self._free_slot()
+        if free is not None:
+            req = self._next_request()
+            if req is None:
+                return None
+            adm = _Admission(free, req)
+            try:
+                self._join(adm)
+                self._send_chunk(adm, ahead=True)
+            except PoolExhausted:
+                self._requeue(req)
+                return None
+            except Exception as e:  # isolate: fail this prompt only
+                self._fail_admission(adm, e)
+                return None
+            return adm
+        rnd = self._build_round(flying)
+        if not rnd.rows or (not owed
+                            and len(rnd.rows) < len(self._active())):
+            return None
+        try:
+            self._send_round(rnd, flying)
+        except Exception as e:
+            self._fail_rows(rnd.rows, e)
+            return None
+        return rnd
+
+    def _join(self, adm: _Admission) -> None:
+        """Give a prompt its slot and its pages: borrow its cached prefix
+        pages, allocate private pages for the rest, and lay out the window
+        calls that prefill ONLY the uncached suffix. ``PoolExhausted`` leaves
+        the slot free."""
         import jax.numpy as jnp
 
-        with span("pt.serve.admit", trace_id=req.trace, slot=slot_no,
-                  prompt_len=len(req.prompt)) as sp:
-            p = len(req.prompt)
-            pl = self._pl
-            total_blocks = req.total_blocks
-            t0 = time.monotonic()
-            s = self._slots[slot_no]
-            trie = self._pool.trie
-            all_blocks = req.blocks
-            with span("pt.serve.page_table"):
-                s.table[:] = 0
-                # prefix reuse: longest cached chain of full prompt blocks,
-                # capped so at least one suffix token remains to produce the
-                # first logits
-                shared_pages: List[int] = []
-                if trie is not None:
-                    if self._pool.warm is not None:
-                        # warm tier: restore spilled pages for this chain
-                        # before matching, so a previously-evicted prefix costs
-                        # a host dequantize instead of a re-prefill
-                        self._pool.warm_restore(all_blocks[: (p - 1) // pl])
-                    shared_pages = trie.match(all_blocks[: (p - 1) // pl], pl,
-                                              self._pool.allocator)
-                m = len(shared_pages)
-                try:
-                    private = self._pool.allocate(total_blocks - m)
-                except PoolExhausted:
-                    for pg in shared_pages:
-                        self._pool.allocator.release(pg)
-                    raise
-                # the queue span lands only once the join is certain — a
-                # PoolExhausted requeue above must not double-record queue time
-                _tracer().span(req.trace, "queue", req.t_submit, t0)
-                s.table[:m] = shared_pages
-                s.table[m:total_blocks] = private
-                s.blocks, s.shared = total_blocks, m
-                # COW hook: every block the decode path will write must be
-                # exclusively ours. By construction they already are (the trie
-                # shares FULL prompt blocks only), so this is a no-op guard —
-                # but a future partial-block sharing scheme lands here.
-                for bi in range(p // pl, total_blocks):
-                    pg, copied = self._pool.ensure_writable(int(s.table[bi]))
-                    if copied:
-                        s.table[bi] = pg
-            # suffix prefill through the ONE-ROW window step — this request's
-            # tokens, table and start, nobody else's. A suffix that fits a
-            # bucket is one call; a longer one runs as successive chunks of
-            # the largest bucket at start, start + C, ... back to back, each
-            # attending to the pages the earlier ones wrote, and the head is
-            # read after the last
-            start = m * pl
-            chunks = self._prefill_chunks(start, p)
-            W = chunks[-1][2]
-            table = jnp.asarray(s.table[None])
-            sp.args.update(bucket=W, prefix_blocks=m, chunks=len(chunks))
-            with span("pt.serve.prefill_dispatch", bucket=W, prefix_blocks=m,
-                      rows=1):
-                for lo, hi, Wc in chunks:
-                    with span("pt.serve.prefill_chunk", start=lo, W=Wc):
-                        tokens = np.zeros((1, Wc), dtype=np.int32)
-                        tokens[0, :hi - lo] = req.prompt[lo:hi]
-                        with _oom_guard("generation",
-                                        label=f"serving:{self.name}:prefill",
-                                        engine=self.name, bucket=Wc):
-                            nxt, lp, row = self._run_window(
-                                1, Wc, table, jnp.asarray(tokens),
-                                jnp.asarray(np.array([lo], dtype=np.int32)),
-                                n_valid=np.array([hi - lo], dtype=np.int32),
-                                prefill=True)
-                        if hi < p:
-                            # a chunk ends when the device has run it: the
-                            # span is the chunk's time, and the next chunk's
-                            # dispatch never queues behind it
-                            nxt.block_until_ready()
-                    self.metrics.inc("prefill_chunks_total")
-                    # token-rows the prefill program ran (rows x W): what
-                    # stats()["prefill_fill_rate"] divides the real tokens by
-                    self.metrics.inc("prefill_window_tokens_total", Wc)
-                    # cached positions the chunk's queries see, summed (token
-                    # w of the chunk sees lo + w + 1)
-                    n = hi - lo
-                    self.metrics.inc("attn_keys_prefill_total",
-                                     n * lo + n * (n + 1) // 2)
-            if row is not None:
-                with span("pt.serve.state_install", slot=slot_no):
-                    self._install_state(slot_no, row)
-                self.metrics.inc("state_installs_total")
-            # a prefill returns its last real position only
-            with span("pt.serve.prefill_sync"):
-                first = int(np.asarray(nxt)[0, 0])
-                first_lp = float(np.asarray(lp)[0, 0])
-            self._read_program_counters()
-            # draft model prefills the WHOLE prompt through its own forward
-            # (the draft is small; its dense slot arena has no prefix cache)
-            if self.spec_k:
-                self._draft_prefill(slot_no, req.prompt)
+        req, s = adm.req, self._slots[adm.slot_no]
+        p, pl = len(req.prompt), self._pl
+        total_blocks = req.total_blocks
+        trie = self._pool.trie
+        all_blocks = req.blocks
+        with span("pt.serve.page_table"):
+            s.table[:] = 0
+            # prefix reuse: longest cached chain of full prompt blocks,
+            # capped so at least one suffix token remains to produce the
+            # first logits
+            shared_pages: List[int] = []
+            if trie is not None:
+                if self._pool.warm is not None:
+                    # warm tier: restore spilled pages for this chain
+                    # before matching, so a previously-evicted prefix costs
+                    # a host dequantize instead of a re-prefill
+                    self._pool.warm_restore(all_blocks[: (p - 1) // pl])
+                shared_pages = trie.match(all_blocks[: (p - 1) // pl], pl,
+                                          self._pool.allocator)
+            m = len(shared_pages)
+            try:
+                private = self._pool.allocate(total_blocks - m)
+            except PoolExhausted:
+                for pg in shared_pages:
+                    self._pool.allocator.release(pg)
+                raise
+            s.table[:m] = shared_pages
+            s.table[m:total_blocks] = private
+            s.blocks, s.shared = total_blocks, m
+            # COW hook: every block the decode path will write must be
+            # exclusively ours. By construction they already are (the trie
+            # shares FULL prompt blocks only), so this is a no-op guard —
+            # but a future partial-block sharing scheme lands here.
+            for bi in range(p // pl, total_blocks):
+                pg, copied = self._pool.ensure_writable(int(s.table[bi]))
+                if copied:
+                    s.table[bi] = pg
+        # the slot is taken from here on; its first token comes with the
+        # read of the last prefill call
+        s.req, s.length, s.last_token = req, p, 0
+        # suffix prefill through the ONE-ROW window step — this request's
+        # tokens, table and start, nobody else's. A suffix that fits a
+        # bucket is one call; a longer one runs as successive chunks of
+        # the largest bucket at start, start + C, ... back to back, each
+        # attending to the pages the earlier ones wrote, and the head is
+        # read after the last
+        adm.m = m
+        adm.chunks = self._prefill_chunks(m * pl, p)
+        # a copy: the programs that read it may still be in flight when the
+        # slot's own table is written again
+        adm.table = jnp.asarray(s.table[None].copy())
+
+    def _send_chunk(self, adm: _Admission, ahead: bool = False) -> None:
+        """Dispatch the next window call of a prompt's prefill. ``ahead``:
+        the program before it is still unread. Behind a prompt's LAST call
+        go, dispatched and not waited for, what the next program may need of
+        it: the install of its recurrent state, and its full blocks' adoption
+        by the prefix cache (any program that reads those pages runs behind
+        the one that writes them)."""
+        import jax.numpy as jnp
+
+        req = adm.req
+        lo, hi, Wc = adm.chunks[len(adm.outs)]
+        tokens = np.zeros((1, Wc), dtype=np.int32)
+        tokens[0, :hi - lo] = req.prompt[lo:hi]
+        with _oom_guard("generation", label=f"serving:{self.name}:prefill",
+                        engine=self.name, bucket=Wc):
+            nxt, lp, row, counted = self._run_window(
+                1, Wc, adm.table, jnp.asarray(tokens),
+                jnp.asarray(np.array([lo], dtype=np.int32)),
+                n_valid=np.array([hi - lo], dtype=np.int32), prefill=True)
+        adm.outs.append((nxt, lp))
+        adm.counters += counted
+        self.metrics.inc("prefill_chunks_total")
+        if ahead:
+            self.metrics.inc("programs_run_ahead_total")
+        # token-rows the prefill program ran (rows x W): what
+        # stats()["prefill_fill_rate"] divides the real tokens by
+        self.metrics.inc("prefill_window_tokens_total", Wc)
+        # cached positions the chunk's queries see, summed (token w of the
+        # chunk sees lo + w + 1)
+        n = hi - lo
+        self.metrics.inc("attn_keys_prefill_total", n * lo + n * (n + 1) // 2)
+        if len(adm.outs) < len(adm.chunks):
+            return
+        if row is not None:
+            with span("pt.serve.state_install", slot=adm.slot_no):
+                self._install_state(adm.slot_no, row)
+            self.metrics.inc("state_installs_total")
+        trie = self._pool.trie
+        if trie is not None:
             # adopt this prompt's full blocks into the prefix cache so the
             # next same-prefix request skips their prefill
-            if trie is not None:
-                fp = p // pl
-                with span("pt.serve.page_table"):
-                    trie.insert(all_blocks[:fp],
-                                [int(x) for x in s.table[:fp]],
-                                self._pool.allocator)
-                self.metrics.inc("prefix_hit_tokens", m * pl)
-                if self._fam_prefix is not None:
-                    self._fam_prefix.inc((self.name, "lookup_tokens"), p)
-                    self._fam_prefix.inc((self.name, "hit_tokens"), m * pl)
-            self.metrics.inc("prompt_tokens_total", p)
-            self.metrics.inc("prefills_total")
-            if m:
-                self.metrics.inc("prefix_hits")
-            self.metrics.observe_queue_wait((t0 - req.t_submit) * 1e3)
-            t1 = time.monotonic()
-            _tracer().span(req.trace, "prefill", t0, t1, bucket=W,
-                           prompt_len=p, slot=slot_no, prefix_blocks=m)
-            if self._hist_ttft is not None:
-                self._hist_ttft.observe((t1 - req.t_submit) * 1e3)
-            req.t_decode0 = t1
+            fp = len(req.prompt) // self._pl
+            table = self._slots[adm.slot_no].table
+            with span("pt.serve.page_table"):
+                trie.insert(req.blocks[:fp], [int(x) for x in table[:fp]],
+                            self._pool.allocator)
 
-            s.req = req
-            s.length = p
-            s.last_token = first
-            s.t0 = t1  # slot residency opens (occupancy track)
-            self._note_token(req, first, first_lp)
-            self._emit_finish_check(slot_no)
-            return len(chunks)
+    def _fail_admission(self, adm: _Admission, e: Exception) -> None:
+        req, s = adm.req, self._slots[adm.slot_no]
+        if not req.future.done():
+            req.future.set_exception(e)
+        _tracer().finish(req.trace, ok=False, error=type(e).__name__)
+        self.metrics.inc("errors_total")
+        if s.req is req or s.req is None:
+            self._release_pages(s)
+            s.req, s.length, s.last_token = None, 0, 0
+
+    def _admit(self, adm: _Admission):
+        """Take a prompt from its join (made here at a boundary, in
+        ``_run_ahead`` when its first call went out ahead) to its first
+        token: every window call of its prefill is dispatched, the NEXT
+        program is dispatched behind it where that is decided — the next
+        chunk always, after the last call whatever ``_run_ahead`` finds —
+        and only then is the call waited for, so a ``pt.serve.prefill_chunk``
+        span is one call's time on the device, from the end of the program
+        before it (or its own dispatch, if later) to its own end. The first
+        generated token is the window's argmax at the last real prompt
+        position (matching ``generate``'s contract). Returns the program
+        that went out behind the last call, if one did."""
+        req, slot_no = adm.req, adm.slot_no
+        s = self._slots[slot_no]
+        p = len(req.prompt)
+        after = None
+        try:
+            with span("pt.serve.admit", trace_id=req.trace, slot=slot_no,
+                      prompt_len=p) as sp:
+                t0 = time.monotonic()
+                if adm.chunks is None:
+                    self._join(adm)
+                # the queue span lands only once the join is certain — a
+                # PoolExhausted requeue must not double-record queue time
+                _tracer().span(req.trace, "queue", req.t_submit, t0)
+                chunks, m = adm.chunks, adm.m
+                W = chunks[-1][2]
+                sp.args.update(bucket=W, prefix_blocks=m, chunks=len(chunks))
+                with span("pt.serve.prefill_dispatch", bucket=W,
+                          prefix_blocks=m, rows=1):
+                    for i, (lo, _hi, Wc) in enumerate(chunks):
+                        with span("pt.serve.prefill_chunk", start=lo, W=Wc,
+                                  ahead=int(i < len(adm.outs))):
+                            if i == len(adm.outs):
+                                self._send_chunk(adm)
+                            if i + 1 < len(chunks):
+                                self._send_chunk(adm, ahead=True)
+                            else:
+                                after = self._run_ahead(adm)
+                            adm.outs[i][0].block_until_ready()
+                if s.req is not req:
+                    return after  # failed with the round that went out ahead
+                # a prefill returns its last real position only
+                nxt, lp = adm.outs[-1]
+                with span("pt.serve.prefill_sync"):
+                    first = int(np.asarray(nxt)[0, 0])
+                    first_lp = float(np.asarray(lp)[0, 0])
+                self._count_programs(adm.counters)
+                # draft model prefills the WHOLE prompt through its own
+                # forward (the draft is small; its dense slot arena has no
+                # prefix cache)
+                if self.spec_k:
+                    self._draft_prefill(slot_no, req.prompt)
+                if self._pool.trie is not None:
+                    self.metrics.inc("prefix_hit_tokens", m * self._pl)
+                    if self._fam_prefix is not None:
+                        self._fam_prefix.inc((self.name, "lookup_tokens"), p)
+                        self._fam_prefix.inc((self.name, "hit_tokens"),
+                                             m * self._pl)
+                self.metrics.inc("prompt_tokens_total", p)
+                self.metrics.inc("prefills_total")
+                if m:
+                    self.metrics.inc("prefix_hits")
+                self.metrics.observe_queue_wait((t0 - req.t_submit) * 1e3)
+                t1 = time.monotonic()
+                _tracer().span(req.trace, "prefill", t0, t1, bucket=W,
+                               prompt_len=p, slot=slot_no, prefix_blocks=m)
+                if self._hist_ttft is not None:
+                    self._hist_ttft.observe((t1 - req.t_submit) * 1e3)
+                req.t_decode0 = t1
+                s.last_token = first
+                s.t0 = t1  # slot residency opens (occupancy track)
+                self._note_token(req, first, first_lp)
+                self._emit_finish_check(slot_no)
+        except PoolExhausted:
+            raise
+        except Exception as e:  # isolate: fail this prompt only
+            self._fail_admission(adm, e)
+        return after
 
     def _note_token(self, req: _GenRequest, t: int, lp: float) -> None:
         """One emitted token: record it (token + behavior logprob) and
@@ -1392,89 +1578,170 @@ class GenerationEngine(EngineBase):
             self._dk[li] = self._dinsert(self._dk[li], k.data, slot)
             self._dv[li] = self._dinsert(self._dv[li], v.data, slot)
 
-    def _decode_once(self, active: List[int]):
-        """One decode round. Without a draft model this is the classic
+    def _build_round(self, flying=None) -> _Round:
+        """The decode round that follows ``flying`` (a program dispatched and
+        not yet read; ``None``: everything is read): a row for every request
+        that is still running once ``flying`` is read, at the length it has
+        then. A request whose budget or context ``flying``'s token completes
+        gets no row; a row whose token is still on the device is left 0
+        (``_send_round`` feeds it)."""
+        S, B = self.config.max_slots, self._n_blocks
+        k = self.spec_k if self._spec_on else 0
+        with span("pt.serve.decode_build"):
+            tokens = np.zeros((S, k + 1), dtype=np.int32)
+            lengths = np.zeros(S, dtype=np.int32)
+            tables = np.zeros((S, B), dtype=np.int32)
+            unread = dict(flying.rows) if flying is not None else {}
+            # a round's token is cached at the row's length and moves it on;
+            # a prefill's first token is not cached yet
+            step = int(isinstance(flying, _Round))
+            rows = []
+            for i, s in enumerate(self._slots):
+                req, length = s.req, s.length
+                if req is None:
+                    continue
+                if unread.get(i) is req:
+                    length += step
+                    if len(req.generated) + 1 >= req.max_new_tokens \
+                            or length >= self.max_len - 1:
+                        continue
+                else:
+                    tokens[i, 0] = s.last_token
+                lengths[i] = min(length, self.max_len - 1)
+                tables[i] = s.table
+                rows.append((i, req))
+        return _Round(rows, k, tokens, lengths, tables)
+
+    def _send_round(self, rnd: _Round, flying=None) -> None:
+        """Dispatch a built round. Behind an unread ``flying`` its tokens
+        come from ``flying``'s own output: a round's ``[max_slots, 1]``
+        argmaxes as they are (every row of this round had one in that), a
+        prefill's first token through ``_feed_token``."""
+        import jax
+        import jax.numpy as jnp
+
+        S, k, tokens = self.config.max_slots, rnd.k, rnd.tokens
+        # chaos site: scripted decode fault at an exact decode-step index
+        # (PT_FAULTS="decode_fault@step=2") — the round's requests fail,
+        # their slots release, queued prompts keep being admitted
+        self._decode_no += 1
+        _injector().check("decode_fault", engine=self.name,
+                          step=self._decode_no)
+        with span("pt.serve.decode_dispatch"):
+            if k:  # draft proposal: k dense decode steps, all slots
+                cur = jnp.asarray(tokens[:, 0])
+                for j in range(k):
+                    with _oom_guard("generation",
+                                    label=f"serving:{self.name}:draft",
+                                    engine=self.name, step=self._decode_no):
+                        nd, self._dk, self._dv = self._draft_step(
+                            self._dparams, self._dk, self._dv, cur,
+                            jnp.asarray(rnd.lengths + j))
+                    tokens[:, j + 1] = np.asarray(nd)
+                    cur = nd
+            if isinstance(flying, _Round):
+                feed = flying.nxt
+            elif flying is not None:
+                feed = self._feed_token(tokens, flying.outs[-1][0],
+                                        flying.slot_no)
+            else:
+                feed = jax.device_put(tokens, self._device)
+            # every row of the round advances its state one step, in place;
+            # an idle row's n_valid is 0 and its state stays as it is
+            n_valid = np.zeros(S, dtype=np.int32)
+            n_valid[[i for i, _req in rnd.rows]] = 1
+            with _oom_guard("generation", label=f"serving:{self.name}:decode",
+                            engine=self.name, step=self._decode_no):
+                rnd.nxt, rnd.lp, _row, rnd.counters = self._run_window(
+                    S, k + 1, jnp.asarray(rnd.tables), feed,
+                    jnp.asarray(rnd.lengths), n_valid=n_valid)
+        if flying is not None:
+            self.metrics.inc("programs_run_ahead_total")
+
+    def _fail_rows(self, rows, e: Exception) -> None:
+        """A fault in a round fails the requests it was to advance (those
+        that have not ended since) and releases their slots."""
+        now, failed = time.monotonic(), 0
+        for i, req in rows:
+            if self._slots[i].req is not req:
+                continue
+            if not req.future.done():
+                req.future.set_exception(e)
+            self._release_slot(i, now, failed=True, error=type(e).__name__)
+            failed += 1
+        self.metrics.inc("errors_total", failed)
+        self.metrics.inc("batch_failures")
+
+    def _decode_once(self, rnd: Optional[_Round] = None):
+        """One decode round, to the emission of its tokens; ``rnd`` is the
+        round if it went out ahead of its turn, else it is built and
+        dispatched here. Without a draft model this is the classic
         W=1 step (one token per active slot). With one, the draft
         proposes ``k`` tokens per slot (k dense decode steps), the target
         scores all k+1 window positions in ONE verify call, and each slot
         advances by its accepted run plus the target's own next token —
         emitted tokens are target argmaxes, so greedy output is unchanged.
-        """
-        import jax.numpy as jnp
+        The program after this round is dispatched before this one is read
+        where ``_run_ahead`` finds it decided, and is returned."""
+        S = self.config.max_slots
+        ahead = rnd is not None
+        n_active = len(rnd.rows) if ahead else len(self._active())
+        k = rnd.k if ahead else self.spec_k if self._spec_on else 0
+        if self._hist_slots is not None:
+            # concurrent-occupancy sample per decode window: the
+            # distribution the tuner derives max_slots from
+            self._hist_slots.observe(n_active)
+        after = None
+        try:
+            with span("pt.serve.decode_round", n_active=n_active, W=k + 1,
+                      ahead=int(ahead)):
+                t_dec = time.monotonic()
+                if not ahead:
+                    rnd = self._build_round()
+                    self._send_round(rnd)
+                after = self._run_ahead(rnd)
+                with span("pt.serve.decode_sync"):
+                    n = np.asarray(rnd.nxt)  # [S, W] target argmaxes
+                    lpn = np.asarray(rnd.lp)  # [S, W] their logprobs (f32)
+                self._count_programs(rnd.counters)
+                fr = self._flight()
+                if fr is not None:  # decode steps land in the flight ring
+                    fr.record_serving_step(self.name, "decode",
+                                           (time.monotonic() - t_dec) * 1e3,
+                                           n_active)
+                self.metrics.inc("decode_steps")
+                self.metrics.inc("slot_rounds", n_active)
+                # cached positions the round's queries see, summed over its
+                # rows
+                self.metrics.inc("attn_keys_decode_total",
+                                 int(rnd.lengths.sum()) + n_active)
+                self.metrics.observe_occupancy(n_active / S)
+                with span("pt.serve.emit"):
+                    emitted_total = self._emit_round(rnd, n, lpn)
+                self.metrics.inc("tokens_total", emitted_total)
+                if k:
+                    self.metrics.inc("spec_rounds")
+                    if self._fam_spec is not None:
+                        self._fam_spec.inc((self.name, "rounds"))
+                        self._fam_spec.inc((self.name, "emitted"),
+                                           emitted_total)
+        except Exception as e:  # decode fault: fail the round's requests
+            self._fail_rows(rnd.rows if rnd is not None else
+                            [(i, self._slots[i].req) for i in self._active()],
+                            e)
+        return after
 
-        S, B = self.config.max_slots, self._n_blocks
-        k = self.spec_k if self._spec_on else 0
-        W = k + 1
-        with span("pt.serve.decode_round", n_active=len(active), W=W):
-            with span("pt.serve.decode_build"):
-                tokens = np.zeros((S, W), dtype=np.int32)
-                lengths = np.zeros(S, dtype=np.int32)
-                tables = np.zeros((S, B), dtype=np.int32)
-                for i in active:
-                    s = self._slots[i]
-                    tokens[i, 0] = s.last_token
-                    lengths[i] = min(s.length, self.max_len - 1)
-                    tables[i] = s.table
-            # chaos site: scripted decode fault at an exact decode-step index
-            # (PT_FAULTS="decode_fault@step=2") — the in-flight requests fail,
-            # their slots release, queued prompts keep being admitted
-            self._decode_no = getattr(self, "_decode_no", -1) + 1
-            _injector().check("decode_fault", engine=self.name,
-                              step=self._decode_no)
-            t_dec = time.monotonic()
-            with span("pt.serve.decode_dispatch"):
-                if k:  # draft proposal: k dense decode steps, all slots
-                    cur = jnp.asarray(tokens[:, 0])
-                    for j in range(k):
-                        with _oom_guard("generation",
-                                        label=f"serving:{self.name}:draft",
-                                        engine=self.name,
-                                        step=self._decode_no):
-                            nd, self._dk, self._dv = self._draft_step(
-                                self._dparams, self._dk, self._dv, cur,
-                                jnp.asarray(lengths + j))
-                        tokens[:, j + 1] = np.asarray(nd)
-                        cur = nd
-                # every active row advances its state one step, in place;
-                # an idle row's n_valid is 0 and its state stays as it is
-                n_valid = np.zeros(S, dtype=np.int32)
-                n_valid[active] = 1
-                with _oom_guard("generation",
-                                label=f"serving:{self.name}:decode",
-                                engine=self.name, step=self._decode_no):
-                    nxt, lp, _row = self._run_window(
-                        S, W, jnp.asarray(tables), jnp.asarray(tokens),
-                        jnp.asarray(lengths), n_valid=n_valid)
-            with span("pt.serve.decode_sync"):
-                n = np.asarray(nxt)  # [S, W] target argmax at each position
-                lpn = np.asarray(lp)  # [S, W] its behavior logprob (f32)
-            self._read_program_counters()
-            fr = self._flight()
-            if fr is not None:  # decode steps land in the flight ring
-                fr.record_serving_step(self.name, "decode",
-                                       (time.monotonic() - t_dec) * 1e3,
-                                       len(active))
-            self.metrics.inc("decode_steps")
-            self.metrics.inc("slot_rounds", len(active))
-            # cached positions the round's queries see, summed over its rows
-            self.metrics.inc("attn_keys_decode_total",
-                             int(lengths.sum()) + len(active))
-            self.metrics.observe_occupancy(len(active) / S)
-            with span("pt.serve.emit"):
-                emitted_total = self._emit_round(active, k, tokens, n, lpn)
-            self.metrics.inc("tokens_total", emitted_total)
-            if k:
-                self.metrics.inc("spec_rounds")
-                if self._fam_spec is not None:
-                    self._fam_spec.inc((self.name, "rounds"))
-                    self._fam_spec.inc((self.name, "emitted"), emitted_total)
-
-    def _emit_round(self, active: List[int], k: int, tokens, n, lpn) -> int:
-        """Accept, emit, finish and release, slot by slot; returns the
+    def _emit_round(self, rnd: _Round, n, lpn) -> int:
+        """Accept, emit, finish and release, row by row; returns the
         tokens emitted this round."""
+        k, tokens = rnd.k, rnd.tokens
         emitted_total = 0
-        for i in active:
+        for i, req in rnd.rows:
             s = self._slots[i]
+            if s.req is not req:
+                # it ended on EOS while this round, dispatched ahead, held
+                # a row for it: the extra token is dropped
+                continue
             if k:
                 a = greedy_accept(tokens[i, 1:k + 1], n[i, :k])
                 # cap the advance at k so the draft cache stays in sync
@@ -1605,6 +1872,12 @@ class GenerationEngine(EngineBase):
         rounds = c.get("slot_rounds", 0)  # per-SEQUENCE decode rounds
         snap["effective_tokens_per_step"] = round(
             c.get("tokens_total", 0) / rounds, 3) if rounds else 0.0
+        # share of the window programs that went out while the one before
+        # them was still unread (the device did not wait for the host there)
+        programs = c.get("decode_steps", 0) + c.get("prefill_chunks_total", 0)
+        snap["run_ahead_rate"] = round(
+            c.get("programs_run_ahead_total", 0) / programs, 4) \
+            if programs else 0.0
         pairs = c.get("moe_pairs_total", 0)
         if pairs:  # an expert layer that holds a share of its experts
             snap["moe_held_share"] = round(

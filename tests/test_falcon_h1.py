@@ -115,6 +115,41 @@ def test_a_slot_holds_the_references_final_state(tiny):
         ).slot_state(0)
 
 
+def test_a_prefill_run_ahead_leaves_its_state_for_the_round_behind_it(tiny):
+    """Three prompts wait for three slots. The second and third prompt's
+    prefill calls go out before the one in front is read, each with the
+    install of its final state dispatched behind it; the first round goes
+    out behind the third call unread — the slot's row of both state arenas
+    is the prefill's by the time the round steps it, on the device's own
+    order — and two more rounds behind that, until a budget ends. Logprobs
+    and the slots' final state are the reference's."""
+    cfg, model, _params, get = tiny
+    rng = np.random.default_rng(11)
+    lens, outs = (6, 19, 30), (5, 4, 6)
+    prompts = [rng.integers(0, cfg.vocab_size, n) for n in lens]
+    eng = _engine(model)
+    eng.start = lambda: eng  # all three queued before the worker's first turn
+    futs = [eng.submit(p, max_new_tokens=n, return_logprobs=True)
+            for p, n in zip(prompts, outs)]
+    del eng.start
+    with eng:
+        done = [f.result(timeout=300) for f in futs]
+    for slot, (p, (full, lps)) in enumerate(zip(lens, done)):
+        want, states = ref.next_token_logprobs(
+            get, _cfg_dict(cfg), full, 64, vocab_slices=3, with_state=True)
+        np.testing.assert_allclose(lps, want[p - 1:], atol=2e-5)
+        for got, st in zip(eng.slot_state(slot), states):
+            np.testing.assert_allclose(got["ssm"], st["ssm"], atol=2e-5)
+            np.testing.assert_allclose(got["conv"], st["conv"], atol=2e-5)
+    c = eng.stats()["counters"]
+    assert c["state_installs_total"] == 3
+    # two prefill calls and three rounds went out behind an unread program;
+    # the second request's budget ends with round three, so the worker reads
+    # that before it decides
+    assert c["programs_run_ahead_total"] == 2 + 3
+    assert c["decode_steps"] == 5 and c["slot_rounds"] == 4 + 3 + 5
+
+
 def _prefill(sm, params, prompt, W, B=8, PL=8):
     P = B + 1
     arena = [jnp.zeros((P, PL, sm.num_kv_heads, sm.head_dim), jnp.float32)
@@ -297,12 +332,22 @@ def test_state_install_span_sits_inside_admit(tiny):
     cfg, model, _params, _get = tiny
     eng = _engine(model)
     _serve(eng, [np.arange(1, 10)], [3])
-    rows = [r for r in tracer().worker_spans()]
+    rows = [r for r in tracer().worker_spans()
+            if r["thread"].endswith(eng.name)]
     installs = [r for r in rows if r["name"] == "pt.serve.state_install"]
     assert installs
     by_id = {r["id"]: r for r in rows}
-    assert all(by_id[r["parent"]]["name"] == "pt.serve.admit"
-               for r in installs if r["parent"] in by_id)
+
+    def ancestors(r):
+        while r["parent"] in by_id:
+            r = by_id[r["parent"]]
+            yield r["name"]
+
+    # dispatched behind the prompt's last prefill call, inside that call's
+    # span: a round that goes out ahead of the read finds the state there
+    assert all(list(ancestors(r))[:3] == [
+        "pt.serve.prefill_chunk", "pt.serve.prefill_dispatch",
+        "pt.serve.admit"] for r in installs if r["parent"] in by_id)
     assert eng.stats()["state_pool_bytes"] == eng._state_pool_bytes() > 0
 
 

@@ -95,6 +95,8 @@ def test_chunked_prefill_then_latent_decode_match_the_reference(
     c = st["counters"]
     # 40 tokens are 16 + 16 + 8, 50 are 16 + 16 + 16 + 8 (the bucket of 2)
     assert c["prefill_chunks_total"] == 1 + 3 + 2 + 4
+    # every chunk but a prompt's first went out behind the one before it
+    assert c["programs_run_ahead_total"] >= 2 + 1 + 3
     consumed = sum(len(p) for p in prompts) + (6 + 4 + 7 + 5) - 4
     experts_layers = cfg.num_hidden_layers - cfg.first_k_dense_replace
     # idle decode rows and a bucket's padding route nowhere
@@ -350,6 +352,64 @@ def test_prefill_chunk_spans_sit_inside_admit(tiny):
     assert [(r["args"]["start"], r["args"]["W"]) for r in mine] == \
         [(0, 16), (16, 16), (32, 8)]
     assert all(under_admit(r) for r in mine)
+
+
+def test_the_next_program_goes_out_before_the_last_is_read(tiny):
+    """Two short prompts decode; from the worker's own thread, between two
+    of their rounds, a prompt of three chunks and two short ones arrive. Of
+    the long prompt's calls the second and third go out behind the one
+    before (a chunk needs nothing of the last on the host), the round it owes
+    the running sequences behind its last call (the prompt's first token
+    reaches that round on the device), the next waiting prompt's call behind
+    that round (a slot is free), and with every slot taken a round behind a
+    prefill or a round — until a request's budget frees a slot, where the
+    worker reads before it decides. Logprobs are the reference's and every
+    routed pair is counted once."""
+    from paddle_tpu.observability.trace.request_trace import tracer
+
+    cfg, model, _params, get = tiny
+    rng = np.random.default_rng(7)
+    lens, outs = (5, 7, 40, 5, 6), (12, 12, 3, 3, 3)
+    prompts = [rng.integers(0, cfg.vocab_size, n) for n in lens]
+    eng = _engine(model)
+    late, seen = [], []
+
+    def the_rest_arrive(_tok, _lp):
+        seen.append(1)
+        if len(seen) == 2:  # in the emit of the first round
+            late.extend(eng.submit(p, max_new_tokens=n, return_logprobs=True)
+                        for p, n in zip(prompts[2:], outs[2:]))
+
+    eng.start = lambda: eng  # both queued before the worker's first turn
+    futs = [eng.submit(p, max_new_tokens=n, return_logprobs=True,
+                       on_token=None if i else the_rest_arrive)
+            for i, (p, n) in enumerate(zip(prompts[:2], outs[:2]))]
+    del eng.start
+    with eng:
+        done = [f.result(timeout=300) for f in futs]
+        done += [f.result(timeout=300) for f in late]
+        stats = eng.stats()
+    for p, (full, lps) in zip(prompts, done):
+        want = ref.next_token_logprobs(get, dataclasses.asdict(cfg), full, 64)
+        np.testing.assert_allclose(lps, want[len(p) - 1:], atol=2e-4)
+    c = stats["counters"]
+    assert c["prefill_chunks_total"] == 1 + 1 + 3 + 1 + 1
+    # behind a read: the second prompt's call (it waited, a slot was free);
+    # chunks two and three; the owed round; the fourth prompt's call; the
+    # round behind it (no slot free); the round behind the fifth's call
+    assert c["programs_run_ahead_total"] == 1 + 2 + 1 + 1 + 1 + 1
+    rows = [r for r in tracer().worker_spans()
+            if r["thread"].endswith(eng.name)]
+    chunks = [r["args"] for r in rows if r["name"] == "pt.serve.prefill_chunk"]
+    assert [(a["start"], a["ahead"]) for a in chunks] == [
+        (0, 0), (0, 1), (0, 0), (16, 1), (32, 1), (0, 1), (0, 0)]
+    rounds = [r["args"]["ahead"] for r in rows
+              if r["name"] == "pt.serve.decode_round"]
+    assert rounds == [0, 1, 1, 1] + [0] * (c["decode_steps"] - 4)
+    consumed = sum(lens) + sum(outs) - len(lens)
+    experts_layers = cfg.num_hidden_layers - cfg.first_k_dense_replace
+    assert c["moe_pairs_total"] == c["moe_held_pairs_total"] == \
+        consumed * cfg.num_experts_per_tok * experts_layers
 
 
 def test_config_says_what_it_cannot_do():

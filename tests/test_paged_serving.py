@@ -462,7 +462,8 @@ def _count_backend_compiles():
 def test_warmup_compiles_every_program_a_window_calls(tiny_lm, tmp_path,
                                                       draft):
     """After ``warmup()`` a mixed run over every bucket (cold prefills,
-    prefix-cache suffixes, decode, and verify with a draft model) records
+    prefix-cache suffixes, decode, programs dispatched ahead of a read, and
+    verify with a draft model) records
     zero retrace events, zero compile-cache misses and zero XLA compiles,
     and the engine holds exactly {(S, 1)} ∪ {(1, bucket)} — plus
     (S, k + 1) with a draft model: no all-slots prefill program exists."""
@@ -503,6 +504,9 @@ def test_warmup_compiles_every_program_a_window_calls(tiny_lm, tmp_path,
                 f"serving:{name}:prefill24"} <= labels
         assert not any(":window8" in k or ":window16" in k
                        or ":window24" in k for k in labels)
+        # the program that hands a round run ahead its prompt's first token
+        # (a draft's proposals cross the host: nothing runs ahead of them)
+        assert (f"serving:{name}:token_feed" in labels) == (not draft)
         warm, n_compiles = pc.stats(), len(compiles)
         assert n_compiles > 0           # the listener does hear compiles
         # (the draft's one insert label sees its three bucket shapes IN
@@ -523,6 +527,8 @@ def test_warmup_compiles_every_program_a_window_calls(tiny_lm, tmp_path,
                 f.result()
             stats = eng.stats()
         assert stats["counters"]["prefix_hits"] >= 1
+        # two slots, eight prompts: programs went out ahead of a read
+        assert (stats["run_ahead_rate"] > 0.3) == (not draft), stats
         assert set(eng._windows) == want
         assert stats["retrace_events"] == retraced, stats
         run = pc.stats()
@@ -566,6 +572,136 @@ def test_prefill_window_tokens_counter_and_fill_rate(tiny_lm):
     assert stats["prefill_fill_rate"] == round((43 - 16) / 40, 4)
     # the all-slots prefill would have run max_slots x W token-rows
     assert c["prefill_window_tokens_total"] == 40
+
+
+# -- one window program in flight ---------------------------------------------
+
+def _queued_then_started(eng, jobs, on_first_token=None):
+    """Every request is queued before the worker's first turn (``submit``
+    would start it on the first): the schedule is the same in every run.
+    ``on_first_token`` streams the first job's tokens."""
+    eng.start = lambda: eng
+    try:
+        futs = [eng.submit(p.astype("int64"), max_new_tokens=m,
+                           on_token=None if i else on_first_token)
+                for i, (p, m) in enumerate(jobs)]
+    finally:
+        del eng.start
+    eng.start()
+    return futs
+
+
+def _greedy(model, prompt, max_new):
+    return np.asarray(model.generate(
+        paddle.to_tensor(prompt.astype("int64")[None]),
+        max_new_tokens=max_new, use_cache=True).numpy())[0]
+
+
+def test_a_round_run_ahead_drops_the_token_past_an_eos(tiny_lm):
+    """Two slots, three requests queued. With no slot free a round follows a
+    round whatever arrives, so each goes out before the last is read; ``a``
+    ends on EOS two tokens before its budget while the next round already
+    holds a row for it. That row's token is dropped, the slot goes to ``c``
+    at once (its prefill call, and the round behind it, out ahead too), and
+    ``b`` and ``c`` read what ``generate`` gives."""
+    model, pattern = tiny_lm
+    eos = 5
+    jobs = [(pattern[:3], 6), (pattern[:6], 7), (pattern[:14], 4)]
+    want = [_greedy(model, p, m) for p, m in jobs]
+    assert want[0][3:].tolist() == [3, 4, 5, 6, 7, 0]  # 5 comes third
+    want[0] = want[0][:6]
+    assert all(eos not in w[len(p):] for w, (p, _m) in zip(want[1:], jobs[1:]))
+    eng = serving.GenerationEngine(
+        model, serving.GenerationConfig(
+            max_slots=2, max_seq_len=32, page_len=8, prefill_buckets=(8, 16),
+            prefix_cache=False, eos_token_id=eos), name="eosgen")
+    streamed = []
+    futs = _queued_then_started(eng, jobs, streamed.append)
+    with eng:
+        got = [f.result(timeout=300) for f in futs]
+        t0 = time.monotonic()
+        while eng.stats()["active_slots"] and time.monotonic() - t0 < 30:
+            time.sleep(0.01)
+        stats = eng.stats()
+    for g, w in zip(got, want):
+        assert g.tolist() == w.tolist()
+    assert streamed == [3, 4, 5]  # nothing past the EOS reached the client
+    c = stats["counters"]
+    # a, then b's call behind it; rounds 1-3 behind b's call and each other
+    # (the third holds a's dead row); c's call behind round 3, rounds 4-6
+    # behind it and each other; after round 6 nobody has a token left
+    assert c["prefill_chunks_total"] == 3 and c["decode_steps"] == 6
+    assert c["programs_run_ahead_total"] == 8
+    assert stats["run_ahead_rate"] == round(8 / 9, 4)
+    assert c["slot_rounds"] == 12 and c["tokens_total"] == 11  # one dropped
+    # slot 0 served a (3 tokens), then c (4)
+    assert [(s, n) for s, _t0, _t1, n in eng._slot_hist] == \
+        [(0, 3), (0, 4), (1, 7)]
+    assert eng._pool.allocator.live_pages == 0  # a's pages went back once
+
+
+def test_nothing_runs_ahead_of_a_free_slot_and_an_empty_queue(tiny_lm):
+    """Below the knee — a slot is free and nobody waits — the worker reads,
+    then decides, over admissions and rounds alike: an arrival never finds a
+    round dispatched on a guess in front of its prefill. ``b`` arrives from
+    the worker's own thread between two of ``a``'s rounds."""
+    model, pattern = tiny_lm
+    eng = serving.GenerationEngine(
+        model, serving.GenerationConfig(
+            max_slots=3, max_seq_len=32, page_len=8, prefill_buckets=(8, 16),
+            prefix_cache=False), name="calmgen")
+    futs, seen = {}, []
+
+    def b_arrives(_tok):
+        seen.append(1)
+        if len(seen) == 3:
+            futs["b"] = eng.submit(pattern[:11].astype("int64"),
+                                   max_new_tokens=4)
+
+    with eng:
+        a = eng.submit(pattern[:5].astype("int64"), max_new_tokens=8,
+                       on_token=b_arrives).result(timeout=300)
+        b = futs["b"].result(timeout=300)
+        c = eng.submit(pattern[:9].astype("int64"),
+                       max_new_tokens=3).result(timeout=300)
+        stats = eng.stats()
+    for got, (n, m) in zip((a, b, c), ((5, 8), (11, 4), (9, 3))):
+        assert got.tolist() == _greedy(model, pattern[:n], m).tolist()
+    assert stats["counters"]["prefills_total"] == 3
+    assert stats["counters"]["decode_steps"] >= 9
+    assert "programs_run_ahead_total" not in stats["counters"]
+    assert stats["run_ahead_rate"] == 0.0
+
+
+def test_a_fault_in_a_round_run_ahead_fails_that_rounds_requests(tiny_lm):
+    """``decode_fault@step=2``: round 2 is dispatched while round 1 is still
+    unread. Its two requests fail and their slots go back; round 1's tokens
+    for them are dropped with them; the prompt that waited is served."""
+    from paddle_tpu.distributed.resilience.faults import InjectedFault, injector
+
+    model, pattern = tiny_lm
+    eng = serving.GenerationEngine(
+        model, serving.GenerationConfig(
+            max_slots=2, max_seq_len=32, page_len=8, prefill_buckets=(8, 16),
+            prefix_cache=False), name="faultgen")
+    rule = injector().arm("decode_fault", engine=eng.name, step=2)
+    try:
+        *doomed, waiting = _queued_then_started(
+            eng, [(pattern[:9], 8), (pattern[:11], 8), (pattern[:6], 3)])
+        with eng:
+            for f in doomed:
+                with pytest.raises(InjectedFault):
+                    f.result(timeout=300)
+            assert waiting.result(timeout=300).tolist() == \
+                _greedy(model, pattern[:6], 3).tolist()
+            stats = eng.stats()
+    finally:
+        injector().disarm(rule)
+    c = stats["counters"]
+    assert c["batch_failures"] == 1 and c["errors_total"] == 2
+    assert c["responses_total"] == 1 and stats["active_slots"] == 0
+    # rounds 0 and 1 were read (the second for nobody), then the survivor's
+    assert c["decode_steps"] == 2 + 2 and c["tokens_total"] == 2 + 2
 
 
 # -- speculative decoding -----------------------------------------------------

@@ -352,17 +352,32 @@ def test_generation_worker_spans_nest_and_account():
     rounds, admits = of("pt.serve.decode_round"), of("pt.serve.admit")
     assert len(rounds) == counters["decode_steps"] >= 3
     assert len(admits) == 4 == counters["prefills_total"]
+    # a program's span holds its own dispatch unless that went out ahead of
+    # its turn (``ahead=1``: inside the span of the program before it), then
+    # whatever the worker ran ahead of THIS one's read, then the read
     for r in rounds:
-        assert [k["name"] for k in kids[r["id"]]] == [
-            "pt.serve.decode_build", "pt.serve.decode_dispatch",
-            "pt.serve.decode_sync", "pt.serve.emit"]
+        names = [k["name"] for k in kids[r["id"]]]
+        assert names[-2:] == ["pt.serve.decode_sync", "pt.serve.emit"]
+        if not r["args"]["ahead"]:
+            assert names[:2] == ["pt.serve.decode_build",
+                                 "pt.serve.decode_dispatch"]
         assert r["args"]["W"] == 1 and 1 <= r["args"]["n_active"] <= 2
+    assert len(of("pt.serve.decode_dispatch")) == len(rounds)
+    chunks = of("pt.serve.prefill_chunk")
+    assert len(chunks) == 4 == counters["prefill_chunks_total"]
     for a in admits:
-        assert [k["name"] for k in kids[a["id"]]] == [
-            "pt.serve.page_table", "pt.serve.prefill_dispatch",
-            "pt.serve.prefill_sync", "pt.serve.page_table"]
+        names = [k["name"] for k in kids[a["id"]]]
+        chunk, = [c for c in chunks
+                  if by_id[c["parent"]]["parent"] == a["id"]]
+        assert names == ["pt.serve.page_table"] * (1 - chunk["args"]["ahead"]) \
+            + ["pt.serve.prefill_dispatch", "pt.serve.prefill_sync"]
         assert a["args"]["prompt_len"] == 5 and a["args"]["bucket"] == 16
         assert a["args"]["prefix_blocks"] == 0 and a["args"]["slot"] in (0, 1)
+    # both slots hold a sequence with tokens left: a round follows a round
+    # whatever arrives, so rounds two and three of a pair go out ahead
+    assert sum(r["args"]["ahead"] for r in rounds) >= 4
+    assert sum(r["args"]["ahead"] for r in rounds + chunks) == \
+        counters["programs_run_ahead_total"]
     # the worker's row is tied to the requests' rows by the trace id
     traces = {t["trace_id"] for t in tracer().traces(engine="span_gen")}
     assert {a["args"]["trace_id"] for a in admits} == traces
