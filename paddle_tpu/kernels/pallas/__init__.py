@@ -22,13 +22,18 @@ Pallas TPU kernel and a plain ``jnp`` reference (``impl=None`` asks
 - ``mla_paged_attention``: absorbed latent attention (MLA) against a paged
   cache of ``[c_kv | k_r]`` rows — all heads of a token one slab against a
   page, walking only the pages a row's length covers (decode round and
-  prefill chunk of a latent-attention model).
+  prefill chunk of a latent-attention model);
+- ``ranged_paged_attention``: grouped-query attention against K/V page
+  arenas over the pages ``[lo, hi]`` of each row — ``hi`` from the row's
+  length, ``lo`` from a sliding window (0 in a full layer) — for a model
+  whose layers are of two kinds (decode round and prefill chunk).
 
 Import order matters only in that importing this package populates the
 registry.
 """
 from . import (mla_paged_attention, moe_dispatch,  # noqa: F401
-               paged_attention, rmsnorm, rope, ssm_step)
+               paged_attention, ranged_paged_attention, rmsnorm, rope,
+               ssm_step)
 
 __all__ = ["rmsnorm", "rope", "moe_dispatch", "paged_attention", "ssm_step",
-           "mla_paged_attention"]
+           "mla_paged_attention", "ranged_paged_attention"]

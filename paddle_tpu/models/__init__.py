@@ -20,3 +20,6 @@ from .falcon_h1 import (  # noqa: F401
 from .openpangu_moe import (  # noqa: F401
     OpenPanguMoEConfig, OpenPanguMoEForCausalLM, OpenPanguMoEBlock,
 )
+from .laguna import (  # noqa: F401
+    LagunaConfig, LagunaForCausalLM, LagunaBlock,
+)

@@ -368,7 +368,7 @@ _SHARE_TILING = (128, 512, 1024)
 
 def moe_held_experts_mlp(x, wr, w_gate, w_up, w_down, *, top_k, first,
                          score="sigmoid", norm_topk=True, scale=1.0,
-                         valid=None):
+                         valid=None, tiling=_SHARE_TILING, x_route=None):
     """One chip's SHARE of a routed expert layer under expert parallelism:
     route ``x`` [n, h] over all ``E`` router outputs (``wr`` [h, E]), keep
     the (token, choice) pairs whose expert lies in ``[first, first +
@@ -378,6 +378,11 @@ def moe_held_experts_mlp(x, wr, w_gate, w_up, w_down, *, top_k, first,
     covers only the rows that met a held expert) and sum each token's
     weighted results. What the other experts would have added is NOT here:
     under ``ep`` it arrives by the exchange; a chip alone returns its part.
+    With ``first = 0`` and ``count = E`` the share is the WHOLE layer: every
+    routed pair is held. ``tiling`` is the grouped matmuls' (row, k, n)
+    tile, clamped to the operands. ``x_route`` [n, h]: what the router
+    scores instead of ``x`` — the float32 input of a model whose router is
+    float32, where ``x`` is already rounded to the experts' dtype.
 
     ``valid`` [n] bool marks the rows that hold a real token (a padded
     prefill window, an idle decode row): the others route nowhere. Returns
@@ -390,7 +395,8 @@ def moe_held_experts_mlp(x, wr, w_gate, w_up, w_down, *, top_k, first,
     n, h = x.shape
     count = w_gate.shape[0]
     kn = top_k * n
-    gate_v, gate_i, _aux = _route(x, wr, top_k, score=score,
+    gate_v, gate_i, _aux = _route(x if x_route is None else x_route, wr,
+                                  top_k, score=score,
                                   norm_topk=norm_topk, scale=scale,
                                   precision=jax.lax.Precision.HIGHEST)
     local = gate_i - first                                    # [n, k]
@@ -405,11 +411,11 @@ def moe_held_experts_mlp(x, wr, w_gate, w_up, w_down, *, top_k, first,
     group_sizes = jnp.bincount(key, length=count + 1)[:count]
 
     xs = jnp.take(x, order // top_k, axis=0)                  # [kn, h]
-    g_proj = grouped_matmul(xs, w_gate, group_sizes, tiling=_SHARE_TILING)
-    u_proj = grouped_matmul(xs, w_up, group_sizes, tiling=_SHARE_TILING)
+    g_proj = grouped_matmul(xs, w_gate, group_sizes, tiling=tiling)
+    u_proj = grouped_matmul(xs, w_up, group_sizes, tiling=tiling)
     act = jax.nn.silu(g_proj.astype(jnp.float32)) * u_proj
     ys = grouped_matmul(act.astype(x.dtype), w_down, group_sizes,
-                        tiling=_SHARE_TILING)                 # [kn, h]
+                        tiling=tiling)                        # [kn, h]
     # rows past the groups are whatever the kernel left there: select, never
     # multiply
     y_tok = jnp.where(held[:, :, None],

@@ -42,7 +42,7 @@ from ..observability.trace.request_trace import span
 from .base import (BadRequest, DeadlineExceeded, EngineBase, EngineClosed,
                    _oom_guard, _tracer)
 from .paged_kv import (HostPagePool, PagedKVPool, PoolExhausted,
-                       latent_width, token_blocks)
+                       latent_width, token_blocks, window_page_bound)
 from .served_model import (GPTServed, ServedModel, flatten_params,
                            nest_params)
 from .speculative import greedy_accept
@@ -75,7 +75,15 @@ def _injector():
 
 
 class GenerationConfig:
-    """Page pool + prompt bucket + speculative-decode declaration."""
+    """Page pool + prompt bucket + speculative-decode declaration.
+
+    ``prefill_buckets``: the widths of the one-row prefill programs. A
+    prompt longer than the largest is prefilled in chunks of it, so the
+    largest bucket is also the most tokens one program writes: in a cache of
+    two layer kinds it sets how many pages a slot's window layers hold while
+    its chunk runs (``paged_kv.window_page_bound``), and ``window_pages``
+    (None: every slot's decode bound + three chunks' worth + scratch) must
+    cover every slot decoding plus one such chunk."""
 
     def __init__(self, max_slots: int = 4, max_seq_len: Optional[int] = None,
                  prefill_buckets: Tuple[int, ...] = (16, 32, 64, 128),
@@ -83,9 +91,11 @@ class GenerationConfig:
                  donate_cache: bool = True, page_len: int = 16,
                  num_pages: Optional[int] = None, prefix_cache: bool = True,
                  draft_model=None, spec_tokens: int = 4,
-                 warm_pool_bytes: int = 0, warm_admit_threshold: int = 2):
+                 warm_pool_bytes: int = 0, warm_admit_threshold: int = 2,
+                 window_pages: Optional[int] = None):
         self.max_slots = int(max_slots)
         self.max_seq_len = max_seq_len  # None: model max_position_embeddings
+        self.window_pages = window_pages
         self.prefill_buckets = tuple(sorted({int(b)
                                              for b in prefill_buckets}))
         self.max_queue = int(max_queue)
@@ -136,9 +146,14 @@ class _GenRequest:
 
 class _Slot:
     __slots__ = ("req", "length", "last_token", "t0", "table", "blocks",
-                 "shared")
+                 "shared", "wtable", "wlo", "whi")
 
     def __init__(self, n_blocks: int):
+        # a cache of two layer kinds: the window layers' page table, by
+        # ABSOLUTE block like ``table``; blocks [wlo, whi) hold a page, the
+        # blocks behind the window have given theirs back (entry 0)
+        self.wtable = np.zeros(n_blocks, dtype=np.int32)
+        self.wlo = self.whi = 0
         self.req: Optional[_GenRequest] = None
         self.length = 0
         self.last_token = 0
@@ -273,12 +288,28 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     ``step(params, k_arenas, v_arenas, tables, tokens, lengths,
     n_valid=None, state=None)`` returns ``(next, logprob, k_arenas,
     v_arenas, state)`` — and, for a model that declares ``program_counters``,
-    a sixth result ``counters``. A model whose ``cache_spec`` is a latent
-    keeps ONE arena a layer (``k_arenas``; ``v_arenas`` is empty) and its
-    blocks get ``attend(q_lat, q_rope, row)``: the window's rows are written
-    through the page table and ``kernels.pallas.mla_paged_attention`` walks
-    the pages each row's length covers. ``counters`` is the model's
-    ``program_counters`` summed over the layers (int32 scalars).
+    a sixth result ``counters``. The cache has one of three shapes, by the
+    model's ``cache_spec``:
+
+    - ``None``: K and V arenas ``[pages, page_len, heads, dim]`` a layer,
+      ``attend(q, k, v)``, ``kernels.pallas.paged_attention`` (below);
+    - ``"latent"``: ONE arena a layer (``k_arenas``; ``v_arenas`` is empty)
+      and the blocks get ``attend(q_lat, q_rope, row)``: the window's rows
+      are written through the page table and
+      ``kernels.pallas.mla_paged_attention`` walks the pages each row's
+      length covers;
+    - ``"kv_by_layer"``: K and V arenas ``[pages, heads, page_len, dim]`` a
+      layer, a "full" layer's of the pool's pages and a "window" layer's of
+      the window pool's; ``tables`` is ``[2, rows, B]`` (the full layers'
+      table, then the window layers', both by absolute block) and each layer
+      gets the ``attend(q, k, v)`` of its kind (``attend.kind``): the
+      window's keys and values are written through its kind's table and
+      ``kernels.pallas.ranged_paged_attention`` walks the pages from the
+      first that holds a visible key (0 in a full layer) to the row's last.
+      The query's own shape says how many heads the layer has.
+
+    ``counters`` is the model's ``program_counters`` summed over the layers
+    (int32 scalars).
     ``n_valid`` (``[rows]``: real tokens in each row's
     window) gives the blocks ``valid = arange(W) < n_valid``. A ``prefill``
     program (one fresh sequence a row, ``n_valid`` required) computes the
@@ -291,7 +322,7 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     donated like the K/V arenas) are advanced one step and returned
     updated, in place. A model without state gets and returns ``None``.
 
-    Attention is ``kernels.pallas.paged_attention``: on the TPU the Pallas
+    Attention is one of the three kernels above: on the TPU the Pallas
     kernel attends straight against the page table (the dense
     ``kc[tables]`` gathered context never materializes), elsewhere its jnp
     reference gathers and attends — ``kernels.registry.resolve`` decides
@@ -305,7 +336,9 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     kvh, hd = sm.num_kv_heads, sm.head_dim
     scale = sm.attn_scale
     stateful = sm.state_spec is not None
-    latent = sm.cache_spec is not None
+    cache_kind = None if sm.cache_spec is None else sm.cache_spec["kind"]
+    latent = cache_kind == "latent"
+    by_layer = cache_kind == "kv_by_layer"
     counter_names = sm.program_counters
     if stateful and not prefill and window != 1:
         raise ValueError(
@@ -339,6 +372,23 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
             return mla_paged_attention(q, arena, tables, lengths, dv=dv,
                                        scale=scale)
 
+    if by_layer:
+        from ..kernels.pallas.ranged_paged_attention import \
+            ranged_paged_attention
+
+        kinds = list(sm.cache_spec["layers"])
+        span = {"full": None, "window": int(sm.cache_spec["window"])}
+
+        def _ranged(kind):
+            @jax.jit
+            def ranged_attend(q, kk, vv, table, lengths):
+                return ranged_paged_attention(q, kk, vv, table, lengths,
+                                              window=span[kind], scale=scale)
+
+            return ranged_attend
+
+        ranged = {kind: _ranged(kind) for kind in sorted(set(kinds))}
+
     def step(params, k_arenas, v_arenas, tables, tokens, lengths,
              n_valid=None, state=None):
         # tables: [S, B] page ids; tokens: [S, W]; lengths: [S] (int32)
@@ -349,9 +399,23 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
         # blocks past the table (or past a request's allocation: table
         # entry 0) land in the scratch page — never another slot's pages
         blk = pos // PL
-        pidx = jnp.take_along_axis(tables, jnp.minimum(blk, B - 1), axis=1)
-        pidx = jnp.where(blk < B, pidx, 0)                         # [S, W]
-        flat = (pidx * PL + pos % PL).reshape(-1)                  # [S*W]
+
+        def pages_of(table):
+            pidx = jnp.take_along_axis(table, jnp.minimum(blk, B - 1),
+                                       axis=1)
+            return jnp.where(blk < B, pidx, 0)                     # [S, W]
+
+        if by_layer:
+            # a page is [kv heads, PL, dim]: token (page, offset) of head g
+            # is row (page * kvh + g) * PL + offset of the flattened arena
+            by_kind = {kind: tables[i] for i, kind in
+                       enumerate(("full", "window"))}
+            flat_of = {kind: (((pages_of(t)[..., None] * kvh
+                                + jnp.arange(kvh)) * PL
+                               + (pos % PL)[..., None]).reshape(-1))
+                       for kind, t in by_kind.items()}             # [S*W*kvh]
+        else:
+            flat = (pages_of(tables) * PL + pos % PL).reshape(-1)  # [S*W]
         valid = None if n_valid is None else \
             jnp.arange(W)[None, :] < n_valid[:, None]              # [S, W]
         new_k, new_v, new_state, counted = [], [], [], []
@@ -381,7 +445,22 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
                 # key j of the slot's pages is visible iff j <= pos[s, w]
                 return paged_attend(q, kk, vv, tables, pos)
 
-            out = sm.block(p, x, pos, attend_latent if latent else attend,
+            kind = kinds[li] if by_layer else None
+
+            def attend_ranged(q, k1, v1):
+                Pk = kc.shape[0]
+                idx = flat_of[kind]
+                kk = kc.reshape(Pk * kvh * PL, hd).at[idx].set(
+                    k1.reshape(S * W * kvh, hd)).reshape(Pk, kvh, PL, hd)
+                vv = vc.reshape(Pk * kvh * PL, hd).at[idx].set(
+                    v1.reshape(S * W * kvh, hd)).reshape(Pk, kvh, PL, hd)
+                new_k.append(kk)
+                new_v.append(vv)
+                return ranged[kind](q, kk, vv, by_kind[kind], lengths)
+
+            attend_ranged.kind = kind
+            out = sm.block(p, x, pos, attend_latent if latent else
+                           attend_ranged if by_layer else attend,
                            None if state is None else state[li], valid)
             x, st = out[0], out[1]
             new_state.append(st)
@@ -472,7 +551,31 @@ class GenerationEngine(EngineBase):
                     "slot: a rejected draft token would have advanced it "
                     "and it cannot be rolled back, so speculative decoding "
                     "is refused — pass draft_model=None")
-        self._latent = sm.cache_spec is not None
+        cache_kind = None if sm.cache_spec is None else sm.cache_spec["kind"]
+        self._latent = cache_kind == "latent"
+        self._by_layer = cache_kind == "kv_by_layer"
+        if self._by_layer:
+            # what assumes that a page, once written, stays: refused in
+            # words (docs/serving.md, "A cache of two layer kinds")
+            why = (f"{type(model).__name__} keeps a sliding window of "
+                   f"{sm.cache_spec['window']} keys in some of its layers, "
+                   "whose pages go back to the pool as the window passes "
+                   "them: ")
+            if self.config.prefix_cache:
+                raise ValueError(
+                    why + "a cached prefix's pages behind the window are "
+                    "gone, so the prefix cache cannot serve it — pass "
+                    "GenerationConfig(prefix_cache=False)")
+            if self.config.draft_model is not None:
+                raise ValueError(
+                    why + "a verify round that rejects draft tokens would "
+                    "have to take back pages already given away, so "
+                    "speculative decoding is refused — pass "
+                    "draft_model=None")
+            if self.config.warm_pool_bytes:
+                raise ValueError(
+                    why + "the warm tier spills and restores whole "
+                    "prefixes — pass GenerationConfig(warm_pool_bytes=0)")
         if self._latent and self.config.warm_pool_bytes:
             # what moves K/V pages cannot take a latent row yet: refused in
             # words (docs/serving.md, "Latent cache")
@@ -499,11 +602,33 @@ class GenerationEngine(EngineBase):
             warm = HostPagePool(
                 capacity_bytes=self.config.warm_pool_bytes,
                 admit_threshold=self.config.warm_admit_threshold)
+        window_pages = 0
+        if self._by_layer:
+            # a slot's window layers hold at most this many pages while it
+            # decodes, and this many while its largest chunk runs
+            self._win = int(sm.cache_spec["window"])
+            self._wbound = window_page_bound(self._win, 1, pl)
+            chunk = window_page_bound(
+                self._win, self.config.prefill_buckets[-1], pl)
+            window_pages = self.config.window_pages
+            if window_pages is None:
+                window_pages = S * self._wbound + 3 * chunk + 1
+            if window_pages < S * self._wbound + chunk + 1:
+                raise ValueError(
+                    f"window_pages {window_pages}: the window layers need "
+                    f"{self._wbound} pages for each of {S} slots that "
+                    f"decode, {chunk} for the one whose "
+                    f"{self.config.prefill_buckets[-1]}-token chunk is "
+                    "running, and the scratch page: "
+                    f"{S * self._wbound + chunk + 1}")
+            self._layers_of = {kind: sm.cache_spec["layers"].count(kind)
+                               for kind in ("full", "window")}
         self._pool = PagedKVPool(sm.num_layers, num_pages, pl,
                                  sm.num_kv_heads, sm.head_dim, dtype,
                                  prefix_cache=self.config.prefix_cache,
                                  warm_pool=warm, state_spec=sm.state_spec,
-                                 max_slots=S, cache_spec=sm.cache_spec)
+                                 max_slots=S, cache_spec=sm.cache_spec,
+                                 window_pages=window_pages)
         # cross-thread ops the worker must execute (the allocator and
         # the arenas are worker-owned): (fn, Future) pairs — the KV
         # export/install seam the page shipper rides
@@ -616,6 +741,11 @@ class GenerationEngine(EngineBase):
         self.metrics.gauge("slot_occupancy", self.slot_occupancy)
         self.metrics.gauge("kv_headroom", self.kv_headroom)
         self.metrics.gauge("kv_pool_bytes", self._kv_pool_bytes)
+        if self._by_layer:
+            self.metrics.gauge("kv_pool_bytes_by_kind",
+                               self._pool.bytes_by_kind)
+            self.metrics.gauge("kv_pages_live_by_kind",
+                               self._pool.live_pages_by_kind)
         if self._stateful:
             self.metrics.gauge("state_pool_bytes", self._state_pool_bytes)
         # prefix-cache truth (hits/misses/evictions) rides the snapshot
@@ -674,7 +804,8 @@ class GenerationEngine(EngineBase):
                 tokens = jnp.asarray(tokens) if prefill else \
                     jax.device_put(tokens, self._device)
             nxt, _lp, row, _counted = self._run_window(
-                rows, W, jnp.zeros((rows, B), jnp.int32), tokens,
+                rows, W, jnp.zeros(self._tables_shape(rows), jnp.int32),
+                tokens,
                 jnp.zeros(rows, jnp.int32),
                 n_valid=np.full(rows, int(prefill), np.int32),
                 prefill=prefill)
@@ -707,6 +838,63 @@ class GenerationEngine(EngineBase):
                 self._draft_prefill(0, np.zeros(b, dtype=np.int64))
         self.metrics.inc("warmup_runs")
         return self
+
+    def _tables_shape(self, rows: int) -> Tuple[int, ...]:
+        """A window program's page tables for ``rows`` rows: ``[rows, B]``,
+        or the full and the window layers' stacked, ``[2, rows, B]``."""
+        return ((2,) if self._by_layer else ()) + (rows, self._n_blocks)
+
+    def _slot_tables(self, s: _Slot) -> np.ndarray:
+        return np.stack([s.table, s.wtable]) if self._by_layer else s.table
+
+    def _window_pages(self, s: _Slot, lo: int, hi: int) -> None:
+        """The next program's queries of slot ``s`` sit at positions ``[lo,
+        hi]``: its window layers give back every page whose keys all lie
+        behind ``lo - (window - 1)``, the first key ``lo`` can see, and take
+        pages for the blocks up to ``hi``'s. Pages change hands in dispatch
+        order, which is the device's order: a program still in flight reads
+        its own copy of the table and runs before whatever writes the page
+        next."""
+        wa, pl = self._pool.window_allocator, self._pl
+        first = min(max(lo - (self._win - 1), 0) // pl, s.whi)
+        if first > s.wlo:
+            for b in range(s.wlo, first):
+                wa.release(int(s.wtable[b]))
+                s.wtable[b] = 0
+            self.metrics.inc("window_pages_released_total", first - s.wlo)
+            s.wlo = first
+        need = hi // pl + 1
+        if need > s.whi:    # positions are contiguous: wlo <= first <= whi
+            s.wtable[s.whi:need] = wa.alloc(need - s.whi)
+            s.whi = need
+
+    def _window_reserved(self) -> int:
+        """Window pages promised to the running slots beyond what they
+        hold: each may grow to its decode bound."""
+        return sum(max(self._wbound - (s.whi - s.wlo), 0)
+                   for s in self._slots if s.req is not None)
+
+    @staticmethod
+    def _keys_in_window(lo: int, hi: int, window: int) -> int:
+        """Keys the queries at positions ``[lo, hi)`` see within ``window``,
+        summed: position ``i`` sees ``min(i + 1, window)``."""
+        m = min(hi, max(lo, window - 1))
+        return (m * (m + 1) - lo * (lo + 1)) // 2 + (hi - m) * window
+
+    def _count_keys(self, full: int, windowed: int,
+                    decode: bool = False) -> None:
+        """``full``: cached positions a program's queries see, summed;
+        ``windowed``: the same within the window. Each kind of layer scored
+        its own, once a layer; a decode round's part of the window layers'
+        is counted apart too (a round reads its keys once a row, a chunk's
+        tokens share theirs: the two have different floors)."""
+        self.metrics.inc("attn_keys_full_total",
+                         full * self._layers_of["full"])
+        self.metrics.inc("attn_keys_window_total",
+                         windowed * self._layers_of["window"])
+        if decode:
+            self.metrics.inc("attn_keys_window_decode_total",
+                             windowed * self._layers_of["window"])
 
     def _run_window(self, rows: int, W: int, tables, tokens, lengths,
                     n_valid, prefill: bool = False):
@@ -1088,6 +1276,12 @@ class GenerationEngine(EngineBase):
                     fut.set_result(res)
 
     def _refuse_kv_transfer(self, what: str) -> None:
+        if self._by_layer:
+            raise RuntimeError(
+                f"{what}: {type(self.model).__name__} keeps a sliding "
+                "window in some of its layers — their pages behind the "
+                "window have gone back to the pool, so a prompt's cache "
+                "cannot be read out or installed page by page")
         if self._latent:
             raise RuntimeError(
                 f"{what}: {type(self.model).__name__} caches one latent row "
@@ -1184,6 +1378,15 @@ class GenerationEngine(EngineBase):
         m = trie.match_len(req.blocks[: (len(req.prompt) - 1) // self._pl])
         return req.total_blocks - m
 
+    def _window_needed(self, req: _GenRequest) -> int:
+        """Window pages a request must find free at its join: what its
+        widest prefill call holds, and never less than a decoding slot's."""
+        if not self._by_layer:
+            return 0
+        widest = min(len(req.prompt), self.config.prefill_buckets[-1])
+        return max(window_page_bound(self._win, widest, self._pl),
+                   self._wbound)
+
     def _next_request(self) -> Optional[_GenRequest]:
         """Shed expired queued requests, then pick the earliest-deadline
         queued request whose KV pages can be allocated right now."""
@@ -1196,8 +1399,10 @@ class GenerationEngine(EngineBase):
                     self._queue.remove(r)
                     shed.append(r)
             order = sorted(self._queue, key=_GenRequest.edf_key)
+            reserved = self._window_reserved() if self._by_layer else 0
             for r in order:
-                if self._pool.can_allocate(self._blocks_needed(r)):
+                if self._pool.can_allocate(self._blocks_needed(r),
+                                           self._window_needed(r), reserved):
                     self._queue.remove(r)
                     picked = r
                     break
@@ -1391,6 +1596,16 @@ class GenerationEngine(EngineBase):
                 pg, copied = self._pool.ensure_writable(int(s.table[bi]))
                 if copied:
                     s.table[bi] = pg
+        chunks = self._prefill_chunks(m * pl, p)
+        if self._by_layer:
+            # the first call's window pages; the later calls take theirs as
+            # they go out (``_send_chunk``), out of what ``_next_request``
+            # found free
+            try:
+                self._window_pages(s, chunks[0][0], chunks[0][1] - 1)
+            except PoolExhausted:
+                self._release_pages(s)
+                raise
         # the slot is taken from here on; its first token comes with the
         # read of the last prefill call
         s.req, s.length, s.last_token = req, p, 0
@@ -1401,10 +1616,10 @@ class GenerationEngine(EngineBase):
         # attending to the pages the earlier ones wrote, and the head is
         # read after the last
         adm.m = m
-        adm.chunks = self._prefill_chunks(m * pl, p)
+        adm.chunks = chunks
         # a copy: the programs that read it may still be in flight when the
         # slot's own table is written again
-        adm.table = jnp.asarray(s.table[None].copy())
+        adm.table = jnp.asarray(self._slot_tables(s)[..., None, :].copy())
 
     def _send_chunk(self, adm: _Admission, ahead: bool = False) -> None:
         """Dispatch the next window call of a prompt's prefill. ``ahead``:
@@ -1417,6 +1632,12 @@ class GenerationEngine(EngineBase):
 
         req = adm.req
         lo, hi, Wc = adm.chunks[len(adm.outs)]
+        if self._by_layer and adm.outs:
+            s = self._slots[adm.slot_no]
+            with span("pt.serve.page_table"):
+                self._window_pages(s, lo, hi - 1)
+                adm.table = jnp.asarray(
+                    self._slot_tables(s)[..., None, :].copy())
         tokens = np.zeros((1, Wc), dtype=np.int32)
         tokens[0, :hi - lo] = req.prompt[lo:hi]
         with _oom_guard("generation", label=f"serving:{self.name}:prefill",
@@ -1437,6 +1658,9 @@ class GenerationEngine(EngineBase):
         # chunk sees lo + w + 1)
         n = hi - lo
         self.metrics.inc("attn_keys_prefill_total", n * lo + n * (n + 1) // 2)
+        if self._by_layer:
+            self._count_keys(n * lo + n * (n + 1) // 2,
+                             self._keys_in_window(lo, hi, self._win))
         if len(adm.outs) < len(adm.chunks):
             return
         if row is not None:
@@ -1590,7 +1814,7 @@ class GenerationEngine(EngineBase):
         with span("pt.serve.decode_build"):
             tokens = np.zeros((S, k + 1), dtype=np.int32)
             lengths = np.zeros(S, dtype=np.int32)
-            tables = np.zeros((S, B), dtype=np.int32)
+            tables = np.zeros(self._tables_shape(S), dtype=np.int32)
             unread = dict(flying.rows) if flying is not None else {}
             # a round's token is cached at the row's length and moves it on;
             # a prefill's first token is not cached yet
@@ -1608,7 +1832,9 @@ class GenerationEngine(EngineBase):
                 else:
                     tokens[i, 0] = s.last_token
                 lengths[i] = min(length, self.max_len - 1)
-                tables[i] = s.table
+                if self._by_layer:
+                    self._window_pages(s, int(lengths[i]), int(lengths[i]))
+                tables[..., i, :] = self._slot_tables(s)
                 rows.append((i, req))
         return _Round(rows, k, tokens, lengths, tables)
 
@@ -1715,6 +1941,10 @@ class GenerationEngine(EngineBase):
                 # rows
                 self.metrics.inc("attn_keys_decode_total",
                                  int(rnd.lengths.sum()) + n_active)
+                if self._by_layer:
+                    seen = rnd.lengths[[i for i, _req in rnd.rows]] + 1
+                    self._count_keys(int(seen.sum()), int(
+                        np.minimum(seen, self._win).sum()), decode=True)
                 self.metrics.observe_occupancy(n_active / S)
                 with span("pt.serve.emit"):
                     emitted_total = self._emit_round(rnd, n, lpn)
@@ -1801,6 +2031,11 @@ class GenerationEngine(EngineBase):
             self._pool.allocator.release(int(s.table[bi]))
         s.table[:] = 0
         s.blocks = s.shared = 0
+        if self._by_layer:
+            for bi in range(s.wlo, s.whi):
+                self._pool.window_allocator.release(int(s.wtable[bi]))
+            s.wtable[:] = 0
+            s.wlo = s.whi = 0
 
     def _release_slot(self, slot_no: int, now: float, failed: bool,
                       error: Optional[str] = None):

@@ -34,7 +34,7 @@ import threading
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 __all__ = ["PoolExhausted", "PageAllocator", "PrefixCache", "PagedKVPool",
-           "HostPagePool", "token_blocks"]
+           "HostPagePool", "token_blocks", "window_page_bound"]
 
 
 class PoolExhausted(RuntimeError):
@@ -73,6 +73,7 @@ class PageAllocator:
         self.alloc_total = 0
         self.free_total = 0
         self.cow_total = 0
+        self.peak_live = 0    # the most pages ever live at once
 
     # -- queries --------------------------------------------------------------
     @property
@@ -108,6 +109,7 @@ class PageAllocator:
         for p in pages:
             self._ref[p] = 1
         self.alloc_total += n
+        self.peak_live = max(self.peak_live, self.live_pages)
         return pages
 
     def retain(self, page: int) -> None:
@@ -426,6 +428,16 @@ class HostPagePool:
                     "evictions": self.evictions, "restores": self.restores}
 
 
+def window_page_bound(window: int, tokens: int, page_len: int) -> int:
+    """The most pages a slot's window layers hold while a program of
+    ``tokens`` window tokens runs (1: a decode round; a chunk's width: a
+    prefill call): the ``window - 1`` keys behind its first query and its own
+    ``tokens`` keys span at most ``ceil((window + tokens) / page_len) + 1``
+    pages — 6 for a slot that decodes at window 512 and pages of 128, 21
+    while its 2048-token chunk runs."""
+    return -(-(int(window) + int(tokens)) // int(page_len)) + 1
+
+
 def latent_width(dim: int) -> int:
     """Columns of a latent arena: ``dim`` rounded up to whole 128-lane
     tiles (the TPU lays a row out so anyway; a page is DMA'd whole)."""
@@ -446,7 +458,8 @@ class PagedKVPool:
                  num_heads: int, head_dim: int, dtype,
                  prefix_cache: bool = True,
                  warm_pool: Optional[HostPagePool] = None,
-                 state_spec=None, max_slots: int = 0, cache_spec=None):
+                 state_spec=None, max_slots: int = 0, cache_spec=None,
+                 window_pages: int = 0):
         import jax.numpy as jnp
 
         self.page_len = int(page_len)
@@ -458,16 +471,45 @@ class PagedKVPool:
         # what a token leaves in a layer (the served model's ``cache_spec``):
         # K and V of [heads, head_dim] — or ONE latent row, kept in ``k``
         # alone at a whole number of 128-lane tiles (a page is DMA'd whole)
+        # — or K and V by the layer's KIND: a "full" layer keeps a page for
+        # every ``page_len`` tokens cached, from ``allocator``; a "window"
+        # layer only the pages that still hold a key some later query can
+        # see, from ``window_allocator`` (``window_pages`` of them, scratch
+        # included). Both kinds lay a page out [heads, page_len, head_dim]:
+        # one K/V head's tokens contiguous (``ranged_paged_attention``)
         self.cache_spec = cache_spec
+        self.window_allocator: Optional[PageAllocator] = None
+        self.layer_kinds: Optional[List[str]] = None
         if cache_spec is None:
-            shape = (num_pages, page_len, num_heads, head_dim)
+            shapes = [(num_pages, page_len, num_heads, head_dim)] * num_layers
         elif cache_spec["kind"] == "latent":
-            shape = (num_pages, page_len, latent_width(cache_spec["dim"]))
+            shapes = [(num_pages, page_len,
+                       latent_width(cache_spec["dim"]))] * num_layers
+        elif cache_spec["kind"] == "kv_by_layer":
+            kinds = list(cache_spec["layers"])
+            if len(kinds) != num_layers or set(kinds) - {"full", "window"}:
+                raise ValueError(
+                    f"cache_spec['layers'] must name {num_layers} layers "
+                    f"'full' or 'window', got {kinds}")
+            if prefix_cache or warm_pool is not None:
+                raise ValueError(
+                    "a cache of two layer kinds has no prefix cache and no "
+                    "warm tier: a shared page behind a window has been "
+                    "given back")
+            self.layer_kinds = kinds
+            self.window = int(cache_spec["window"])
+            self.window_allocator = PageAllocator(window_pages)
+            shapes = [(window_pages if kind == "window" else num_pages,
+                       num_heads, page_len, head_dim) for kind in kinds]
         else:
-            raise ValueError(f"unknown cache kind {cache_spec['kind']!r}")
-        self.k = [jnp.zeros(shape, dtype) for _ in range(num_layers)]
-        self.v = [] if cache_spec is not None else \
-            [jnp.zeros(shape, dtype) for _ in range(num_layers)]
+            raise ValueError(
+                f"unknown cache kind {cache_spec['kind']!r}: a served "
+                "model's cache_spec is None (K and V), 'latent' or "
+                "'kv_by_layer'")
+        self.k = [jnp.zeros(shape, dtype) for shape in shapes]
+        self.v = [] if cache_spec is not None and \
+            cache_spec["kind"] == "latent" else \
+            [jnp.zeros(shape, dtype) for shape in shapes]
         # the second kind of cache: per layer, one slot-indexed arena per
         # entry of a recurrent model's ``state_spec`` ({name: (per-slot
         # shape, dtype)}) — e.g. the SSM state [slots, heads, P, N] and the
@@ -539,7 +581,15 @@ class PagedKVPool:
             restored += 1
         return restored
 
-    def can_allocate(self, n: int) -> bool:
+    def can_allocate(self, n: int, n_window: int = 0,
+                     window_reserved: int = 0) -> bool:
+        """Can a request join that needs ``n`` pages of the full layers
+        and, in a cache of two layer kinds, ``n_window`` pages of the window
+        layers while ``window_reserved`` more stay promised to the slots
+        that are decoding (each may yet grow to its bound)?"""
+        if n_window and n_window > \
+                self.window_allocator.free_pages - window_reserved:
+            return False
         free = self.allocator.free_pages
         if n <= free:
             return True
@@ -610,6 +660,22 @@ class PagedKVPool:
         return sum(int(a.nbytes) for a in self.k) + \
             sum(int(a.nbytes) for a in self.v)
 
+    def bytes_by_kind(self) -> Dict[str, int]:
+        """The arenas' bytes by layer kind (one kind, "full", for a cache
+        that declares none)."""
+        kinds = self.layer_kinds or ["full"] * len(self.k)
+        out = {kind: 0 for kind in kinds}
+        for arenas in (self.k, self.v):
+            for kind, a in zip(kinds, arenas):
+                out[kind] += int(a.nbytes)
+        return out
+
+    def live_pages_by_kind(self) -> Dict[str, int]:
+        out = {"full": self.allocator.live_pages}
+        if self.window_allocator is not None:
+            out["window"] = self.window_allocator.live_pages
+        return out
+
     def state_bytes(self) -> int:
         return sum(int(a.nbytes) for layer in self.state or ()
                    for a in layer.values())
@@ -620,10 +686,19 @@ class PagedKVPool:
                "cache": "kv" if self.cache_spec is None
                else self.cache_spec["kind"],
                "pages_free": a.free_pages, "pages_live": a.live_pages,
-               "pool_bytes": self.bytes(),
+               "pages_peak": a.peak_live, "pool_bytes": self.bytes(),
                "state_bytes": self.state_bytes(),
                "alloc_total": a.alloc_total, "cow_total": a.cow_total,
                "headroom": round(a.free_pages / max(a.usable_pages, 1), 4)}
+        if self.window_allocator is not None:
+            w = self.window_allocator
+            out["window"] = {
+                "window": self.window, "pages_total": w.num_pages,
+                "pages_free": w.free_pages, "pages_live": w.live_pages,
+                "pages_peak": w.peak_live, "alloc_total": w.alloc_total,
+                "free_total": w.free_total,
+                "pool_bytes": self.bytes_by_kind()["window"],
+                "headroom": round(w.free_pages / max(w.usable_pages, 1), 4)}
         if self.trie is not None:
             out["prefix"] = self.trie.stats()
         if self.warm is not None:

@@ -37,7 +37,16 @@ contains no model. A model that can be served implements
   own cache rows ``row`` ``[rows, W, d]``; the engine writes ``row``
   through the page table and returns each head's softmax-weighted sum of
   the cached rows' first ``dv`` columns, ``[rows, W, heads, dv]`` (the
-  absorbed form: the model carries it through its value up-projection);
+  absorbed form: the model carries it through its value up-projection).
+  ``{"kind": "kv_by_layer", "layers": ["full", "window", ...], "window":
+  n}`` (a model whose layers are of two kinds): a key and a value of
+  ``[num_kv_heads, head_dim]`` in every layer, but a "window" layer's query
+  at position ``i`` sees only the keys ``i - n < j <= i``, so its pages go
+  back to their own pool as the window passes them while a "full" layer
+  keeps a page for every ``page_len`` tokens cached. ``attend(q, k, v)`` is
+  the K/V form, built for the layer it serves: it carries the layer's kind
+  as ``attend.kind`` (what a block needs to pick its RoPE), and the query's
+  own shape says how many heads the layer has — ``num_heads`` is not read;
 - ``program_counters``: ``None``, or the names of int32 scalars a block may
   hand back as a THIRD result (``(x, state, {name: scalar})``, ``None`` from
   a layer that has none). The window program sums them over its layers and
@@ -45,9 +54,11 @@ contains no model. A model that can be served implements
   the sync it makes anyway.
 
 A model with recurrent state cannot use what assumes a cache is pages of
-K/V (the prefix trie, speculative verify, KV-page export/install), and a
-latent cache cannot yet use what moves K/V pages (export/install and its
-wire format, the warm tier): the engine refuses those in words
+K/V (the prefix trie, speculative verify, KV-page export/install), a latent
+cache cannot yet use what moves K/V pages (export/install and its wire
+format, the warm tier), and a cache with window layers cannot use what
+assumes that a page, once written, stays (the prefix trie, speculative
+verify, export/install, the warm tier): the engine refuses those in words
 (``docs/serving.md``).
 """
 from __future__ import annotations
@@ -70,7 +81,8 @@ class ServedModel:
     attn_scale: float
     # None: the only cache is the paged K/V
     state_spec: Optional[Dict[str, Tuple[Tuple[int, ...], Any]]] = None
-    # None: a token leaves K and V of [num_kv_heads, head_dim] in a layer
+    # None: a token leaves K and V of [num_kv_heads, head_dim] in a layer;
+    # else {"kind": "latent", ...} or {"kind": "kv_by_layer", ...}
     cache_spec: Optional[Dict[str, Any]] = None
     # None: the window programs hand back tokens and logprobs alone
     program_counters: Optional[Tuple[str, ...]] = None

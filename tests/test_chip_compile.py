@@ -298,3 +298,27 @@ def test_held_experts_grouped_matmuls_published_widths(one_chip, monkeypatch,
                     ((held, h, w), BF16), ((held, w, h), BF16), names=(),
                     foreign="gmm")
     assert sum(c.startswith("gmm") for c in _CUSTOM_CALL.findall(text)) == 3
+
+
+@pytest.mark.parametrize("S,W,H,window", [
+    (128, 1, 48, None), (128, 1, 64, 512), (1, 2048, 48, None),
+    (1, 2048, 64, 512), (1, 256, 64, 512)])
+def test_ranged_paged_attention_published_widths(one_chip, S, W, H, window):
+    """Laguna-XS.2's attention over a range of pages: 48 / 64 query heads
+    over 8 K/V heads of 128 against ``[pages, 8, 128, 128]`` arenas, 128
+    blocks a row (16k tokens) — the 128-slot decode round in both kinds of
+    layer, and one-row chunks of up to 2048 tokens (``pt_paged_attention``
+    compiles no window above 256)."""
+    from paddle_tpu.kernels.pallas import ranged_paged_attention as kr
+
+    def run(q, ka, va, tables, start):
+        return kr.ranged_paged_attention(q, ka, va, tables, start,
+                                         window=window, scale=128 ** -0.5,
+                                         impl="pallas")
+
+    pages = 5121 if window is None else 832
+    _compile(run, one_chip, ((S, W, H, 128), BF16),
+             ((pages, 8, 128, 128), BF16), ((pages, 8, 128, 128), BF16),
+             ((S, 128), jnp.int32), ((S,), jnp.int32),
+             names=("pt_ranged_attention_" + ("full" if window is None
+                                              else "window"),))

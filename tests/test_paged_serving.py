@@ -1018,3 +1018,51 @@ def test_allocator_random_ops_invariants_property():
                 a.release(p)
         a.check()
         assert a.free_pages == a.usable_pages and a.live_pages == 0
+
+
+# -- a cache of two layer kinds (PR 34): the control plane ---------------------
+
+@pytest.mark.parametrize("window,tokens,page_len,want", [
+    (512, 1, 128, 6),       # a slot that decodes
+    (512, 2048, 128, 21),   # while its 2048-token chunk runs
+    (512, 256, 128, 7), (8, 1, 4, 4), (8, 16, 4, 7)])
+def test_window_page_bound(window, tokens, page_len, want):
+    """The bound is tight: a program of ``tokens`` tokens whose first query
+    sits anywhere in a page touches at most that many pages of the window."""
+    from paddle_tpu.serving.paged_kv import window_page_bound
+
+    assert window_page_bound(window, tokens, page_len) == want
+    worst = max((lo + tokens - 1) // page_len
+                - max(lo - (window - 1), 0) // page_len + 1
+                for lo in range(window, window + 3 * page_len))
+    assert worst <= want <= worst + 1
+
+
+def test_allocator_tracks_its_peak_and_a_two_kind_pool_reports_both():
+    import jax.numpy as jnp
+
+    from paddle_tpu.serving.paged_kv import PagedKVPool
+
+    a = PageAllocator(8)
+    held = a.alloc(5)
+    for p in held[:3]:
+        a.release(p)
+    a.alloc(2)
+    assert (a.peak_live, a.live_pages) == (5, 4)
+    pool = PagedKVPool(3, 10, 4, 2, 8, jnp.float32, prefix_cache=False,
+                       cache_spec={"kind": "kv_by_layer", "window": 8,
+                                   "layers": ["full", "window", "window"]},
+                       window_pages=6)
+    pool.allocate(3)
+    pool.window_allocator.alloc(2)
+    st = pool.stats()
+    assert st["cache"] == "kv_by_layer" and st["pages_live"] == 3
+    assert st["window"]["pages_live"] == st["window"]["pages_peak"] == 2
+    assert st["window"]["pages_total"] == 6 and st["window"]["window"] == 8
+    assert pool.live_pages_by_kind() == {"full": 3, "window": 2}
+    assert pool.bytes() == sum(pool.bytes_by_kind().values()) == \
+        st["pool_bytes"]
+    with pytest.raises(ValueError, match="must name 3 layers"):
+        PagedKVPool(3, 10, 4, 2, 8, jnp.float32, prefix_cache=False,
+                    cache_spec={"kind": "kv_by_layer", "window": 8,
+                                "layers": ["full", "ring", "window"]})

@@ -593,3 +593,81 @@ def test_calibrate_from_counters_reads_device_trace(monkeypatch):
     # over the 2 captured steps: (8e7/10) / (2000us/2 per step)
     assert lm.ici_bytes_per_s == pytest.approx((8e7 / 10) / 1e-3)
     assert lm.peak_flops == pytest.approx(7e9 / 7e-3)
+
+
+# -- ranged paged attention (PR 34) --------------------------------------------
+
+def _ranged_case(S, W, Hg, starts, G=2, d=128, PL=8, B=16, seed=0):
+    from paddle_tpu.kernels.pallas import ranged_paged_attention as kr
+
+    rng = np.random.default_rng(seed)
+    P = 1 + S * B
+    ka = jnp.asarray(rng.normal(size=(P, G, PL, d)), jnp.float32)
+    va = jnp.asarray(rng.normal(size=(P, G, PL, d)), jnp.float32)
+    tables = jnp.asarray(1 + np.arange(S * B).reshape(S, B), jnp.int32)
+    q = jnp.asarray(rng.normal(size=(S, W, G * Hg, d)), jnp.float32)
+    return kr, q, ka, va, tables, jnp.asarray(starts, jnp.int32)
+
+
+@pytest.mark.parametrize("S,W,Hg,window,starts", [
+    (3, 1, 6, None, [0, 37, 90]),      # decode, rows of unequal length
+    (3, 1, 8, 8, [0, 37, 90]),         # decode in a window layer: lo > 0
+    (1, 16, 8, 8, [21]),               # a chunk across the window
+    (1, 16, 6, None, [40]),            # a chunk in a full layer, 6 heads
+    (2, 4, 6, 8, [5, 60]),             # two rows, W = 4
+    (1, 64, 8, 24, [30]),              # a tiled chunk (TW = 32, two tiles)
+])
+def test_ranged_paged_attention_parity(S, W, Hg, window, starts):
+    """The Pallas kernel (interpreted) against its jnp reference: 6 and 8
+    query heads a K/V head, W = 1 and tiled chunks, a window whose first
+    page is not page 0."""
+    kr, q, ka, va, tables, st = _ranged_case(S, W, Hg, starts)
+    got = kr.ranged_paged_attention(q, ka, va, tables, st, window=window,
+                                    scale=0.09, impl="interpret")
+    want = kr.ranged_paged_attention(q, ka, va, tables, st, window=window,
+                                     scale=0.09, impl="reference")
+    _close(got, want)
+
+
+@pytest.mark.parametrize("impl", ["interpret", "reference"])
+def test_ranged_paged_attention_window_edges(impl):
+    """Off by one on neither end: the query at position i sees exactly the
+    keys i - window < j <= i. A key's value is its own position, so a head's
+    context under uniform scores is the mean of the positions it saw."""
+    from paddle_tpu.kernels.pallas import ranged_paged_attention as kr
+
+    G, Hg, d, PL, B, window = 1, 8, 128, 8, 8, 8
+    P = 1 + B
+    pos = np.arange(P * PL, dtype=np.float32).reshape(P, 1, PL, 1) - PL
+    va = jnp.asarray(np.broadcast_to(pos, (P, G, PL, d)))   # page 1 = 0..7
+    ka = jnp.zeros((P, G, PL, d), jnp.float32)              # uniform scores
+    tables = jnp.asarray(1 + np.arange(B)[None], jnp.int32)
+    q = jnp.ones((1, 4, G * Hg, d), jnp.float32)
+    out = kr.ranged_paged_attention(q, ka, va, tables, jnp.asarray([19]),
+                                    window=window, scale=1.0, impl=impl)
+    for w in range(4):   # position 19 + w sees 12 + w .. 19 + w
+        i = 19 + w
+        np.testing.assert_allclose(np.asarray(out)[0, w, :, 0],
+                                   np.mean(np.arange(i - 7, i + 1)),
+                                   rtol=1e-6)
+    full = kr.ranged_paged_attention(q, ka, va, tables, jnp.asarray([19]),
+                                     window=None, scale=1.0, impl=impl)
+    np.testing.assert_allclose(np.asarray(full)[0, 0, :, 0],
+                               np.mean(np.arange(0, 20)), rtol=1e-6)
+
+
+def test_ranged_paged_attention_ignores_pages_given_back():
+    """A window layer's table holds the scratch page for the blocks behind
+    the window: whatever lies there, the result does not move."""
+    kr, q, ka, va, tables, st = _ranged_case(2, 1, 8, [70, 100], seed=3)
+    want = kr.ranged_paged_attention(q, ka, va, tables, st, window=8,
+                                     scale=0.09, impl="interpret")
+    gone = np.asarray(tables).copy()
+    gone[0, :(70 - 7) // 8] = 0
+    gone[1, :(100 - 7) // 8] = 0
+    ka = ka.at[0].set(1e3)      # the scratch page holds anything
+    got = kr.ranged_paged_attention(q, ka, va, jnp.asarray(gone), st,
+                                    window=8, scale=0.09, impl="interpret")
+    _close(got, want)
+    with pytest.raises(ValueError, match="query heads over"):
+        kr.ranged_paged_attention(q[:, :, :7], ka, va, tables, st, scale=1.0)
