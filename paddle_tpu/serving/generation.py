@@ -168,7 +168,7 @@ class _Admission:
     and what the host has not read of them yet."""
 
     __slots__ = ("slot_no", "req", "chunks", "table", "m", "outs",
-                 "counters")
+                 "carried", "counters")
 
     def __init__(self, slot_no: int, req: _GenRequest):
         self.slot_no = slot_no
@@ -177,11 +177,16 @@ class _Admission:
         self.table = None     # the slot's page table, on the device
         self.m = 0            # leading pages borrowed from the prefix cache
         self.outs: List[Tuple[Any, Any]] = []  # (next, logprob) a call sent
+        # the decode round each call sent carried (None: it carried none),
+        # until the host has read it
+        self.carried: List[Optional["_Round"]] = []
         self.counters: List[Dict[str, Any]] = []
 
     @property
-    def rows(self) -> List[Tuple[int, _GenRequest]]:
-        return [(self.slot_no, self.req)]
+    def sent(self) -> bool:
+        """Every call of the prefill is dispatched: the last one's output
+        holds the prompt's first token."""
+        return self.chunks is not None and len(self.outs) == len(self.chunks)
 
 
 class _Round:
@@ -197,6 +202,23 @@ class _Round:
         self.tokens, self.lengths, self.tables = tokens, lengths, tables
         self.nxt = self.lp = None
         self.counters: List[Dict[str, Any]] = []
+
+
+def _unread(flying) -> Dict[int, Tuple[_GenRequest, int]]:
+    """The rows whose next token ``flying`` — a program dispatched and not
+    yet read, or ``None`` — still holds on the device: ``slot -> (request,
+    step)``. A round's token (a round of its own, or the one a prefill call
+    carried) is cached at the row's length and moves it on: step 1. The first
+    token of a prompt whose every call is out is not cached yet: step 0."""
+    if flying is None:
+        return {}
+    if isinstance(flying, _Round):
+        return {i: (req, 1) for i, req in flying.rows}
+    rnd = flying.carried[-1]
+    rows = {} if rnd is None else {i: (req, 1) for i, req in rnd.rows}
+    if flying.sent:
+        rows[flying.slot_no] = (flying.req, 0)
+    return rows
 
 
 def _served(model_or_cfg) -> ServedModel:
@@ -267,16 +289,75 @@ def _build_decode_step(cfg, max_slots: int, max_len: int, donate: bool,
         step, donate_argnums=(1, 2) if donate else (), label=label)
 
 
+def _attention(sm, attends: Optional[Dict], name: str):
+    """The jitted attention callable ``name`` of a window program of ``sm``:
+    ``"paged"`` (K/V arenas), ``"latent"``, or the ``"full"`` / ``"window"``
+    of a cache of two layer kinds. ONE jitted callable for every layer of a
+    program: the kernel is traced and lowered once a program and called L
+    times, not traced L times (the 36 kernel traces of a GPT-2-large program
+    were most of warmup's time, PERF.md section 6, PR 28); XLA inlines the
+    calls. And ONE for every program that is handed the same ``attends``
+    dict (an engine hands all of its programs one): ``jax.jit`` caches a
+    trace by the callable and its operands' shapes, so a kernel traced at a
+    shape by one program is not traced again at that shape by the next —
+    the program that carries a round finds the round's shape traced by the
+    decode program (tracing a Pallas kernel's body is most of a window
+    program's build: 0.7–1.4 s a kernel and shape on the chip's host,
+    PERF.md section 2). ``None``: the program keeps its own."""
+    import jax
+
+    if attends is not None and name in attends:
+        return attends[name]
+    scale = sm.attn_scale
+    if name == "paged":
+        from ..kernels.pallas.paged_attention import paged_attention
+
+        @jax.jit
+        def paged_attend(q, kk, vv, tables, pos):
+            return paged_attention(q, kk, vv, tables, pos, scale=scale)
+
+        fn = paged_attend
+    elif name == "latent":
+        from ..kernels.pallas.mla_paged_attention import mla_paged_attention
+
+        dv = sm.cache_spec["value_dim"]
+
+        @jax.jit
+        def latent_attend(q, arena, tables, lengths):
+            return mla_paged_attention(q, arena, tables, lengths, dv=dv,
+                                       scale=scale)
+
+        fn = latent_attend
+    else:
+        from ..kernels.pallas.ranged_paged_attention import \
+            ranged_paged_attention
+
+        window = None if name == "full" else int(sm.cache_spec["window"])
+
+        @jax.jit
+        def ranged_attend(q, kk, vv, table, lengths):
+            return ranged_paged_attention(q, kk, vv, table, lengths,
+                                          window=window, scale=scale)
+
+        fn = ranged_attend
+    if attends is not None:
+        attends[name] = fn
+    return fn
+
+
 def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
                        window: int, donate: bool, label: str,
-                       fused: bool = True, prefill: bool = False):
+                       fused: bool = True, prefill: bool = False,
+                       carry: int = 0, attends: Optional[Dict] = None):
     """The PAGED executable family: embed ``W = window`` tokens per slot
     at positions ``lengths + [0..W)``, run the served model's blocks — each
     block's ``attend(q, k, v)`` writes K/V through the page tables into the
     pool arenas and attends each window token causally against the page
     pool — and return the greedy argmax at every window position.
 
-    One body serves three roles, at two row counts. At ``max_slots`` rows,
+    One body serves three roles, at two row counts (the fourth role of the
+    family, a prefill that CARRIES a decode round, ``carry`` > 0, has a body
+    of its own: ``_build_carrying_step``). At ``max_slots`` rows,
     W=1 is the decode step and W=k+1 scores a draft model's k proposals
     (speculative verify); rows whose page table is all-zero write only the
     scratch page. At ONE row, W=bucket prefills a prompt suffix (cold
@@ -328,13 +409,23 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     reference gathers and attends — ``kernels.registry.resolve`` decides
     as the program is traced. ``fused`` selects nothing: it is accepted,
     as ``True`` only, for ``benchmark/rehearse_aot*.py`` (ROADMAP D14).
+    ``attends``: the dict an engine's programs share their jitted attention
+    callables in (``_attention``).
+
+    Keep ``step`` and the closures in it SHORT: whatever is added to their
+    bodies, dead or not, slows the trace of every program built from them
+    (+ 0.4–1.0 s a program on the chip's host for sixty dead lines: PERF.md
+    section 6, PR 36) — which is why the carried step is not a branch here.
     """
     import jax
     import jax.numpy as jnp
 
     sm = _served(served)
+    if carry:
+        return _build_carrying_step(sm, int(carry), max_slots, n_blocks,
+                                    page_len, window, donate, label, prefill,
+                                    attends)
     kvh, hd = sm.num_kv_heads, sm.head_dim
-    scale = sm.attn_scale
     stateful = sm.state_spec is not None
     cache_kind = None if sm.cache_spec is None else sm.cache_spec["kind"]
     latent = cache_kind == "latent"
@@ -351,43 +442,16 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
             "decides; for the jnp reference call kernels.pallas."
             "paged_attention.paged_attention(..., impl='reference')")
 
-    from ..kernels.pallas.paged_attention import paged_attention
-
-    # ONE jitted callable for every layer: the kernel is traced and
-    # lowered once a program and called L times, not traced L times
-    # (the 36 kernel traces of a GPT-2-large program were most of
-    # warmup's time, PERF.md section 6, PR 28); XLA inlines the calls
-    @jax.jit
-    def paged_attend(q, kk, vv, tables, pos):
-        return paged_attention(q, kk, vv, tables, pos, scale=scale)
-
     if latent:
-        from ..kernels.pallas.mla_paged_attention import mla_paged_attention
-
-        dl, dv = sm.cache_spec["dim"], sm.cache_spec["value_dim"]
+        dl = sm.cache_spec["dim"]
         DL = latent_width(dl)
-
-        @jax.jit
-        def latent_attend(q, arena, tables, lengths):
-            return mla_paged_attention(q, arena, tables, lengths, dv=dv,
-                                       scale=scale)
-
-    if by_layer:
-        from ..kernels.pallas.ranged_paged_attention import \
-            ranged_paged_attention
-
+        latent_attend = _attention(sm, attends, "latent")
+    elif by_layer:
         kinds = list(sm.cache_spec["layers"])
-        span = {"full": None, "window": int(sm.cache_spec["window"])}
-
-        def _ranged(kind):
-            @jax.jit
-            def ranged_attend(q, kk, vv, table, lengths):
-                return ranged_paged_attention(q, kk, vv, table, lengths,
-                                              window=span[kind], scale=scale)
-
-            return ranged_attend
-
-        ranged = {kind: _ranged(kind) for kind in sorted(set(kinds))}
+        ranged = {kind: _attention(sm, attends, kind)
+                  for kind in sorted(set(kinds))}
+    else:
+        paged_attend = _attention(sm, attends, "paged")
 
     def step(params, k_arenas, v_arenas, tables, tokens, lengths,
              n_valid=None, state=None):
@@ -491,6 +555,159 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
 
     return persistent_cache.cached_jit(
         step, donate_argnums=donate_argnums if donate else (), label=label)
+
+
+def _build_carrying_step(sm: ServedModel, carry: int, rows: int,
+                         n_blocks: int, page_len: int, window: int,
+                         donate: bool, label: str, prefill: bool,
+                         attends: Optional[Dict]):
+    """THE CARRIED STEP, the fourth role of the paged family: ONE program for
+    a prompt's row of ``W = window`` tokens (a prefill: the head at its last
+    real position) AND the ``R = carry`` rows of a decode round, so the
+    running sequences advance while a prompt is prefilled and the layers'
+    weights (the experts' above all) are read once for both. Only a one-row
+    prefill of a model whose ``carries_rounds`` is true has one: the cache's
+    kernel takes each row's own range of pages and nothing recurs.
+
+    ``step(params, k_arenas, v_arenas, tables, tokens, lengths, n_valid,
+    state=None)``: each of ``tables``, ``tokens``, ``lengths`` and
+    ``n_valid`` is a PAIR — the prompt's ``[1, ...]`` operand as a prefill
+    takes it, the round's ``[R, ...]`` operand as a decode step takes it —
+    and ``next`` / ``logprob`` come back as pairs too (``[1, 1]`` and ``[R,
+    1]``), beside the arenas, ``None`` for the state and the model's
+    ``program_counters`` over all the program's tokens, once. Everything
+    position-wise in a block (embedding, norms, projections, router,
+    experts) runs ONCE over the ``W + R`` tokens, one row ``[1, W + R, h]``
+    with the positions and the ``valid`` mask of both parts; ``attend``
+    alone splits it: it writes the chunk's keys and values (or latent rows)
+    through the prompt's table and the round's through theirs, calls the
+    layer's kernel twice (the chunk's shape, the round's: the same jitted
+    callable, ``_attention``) and joins the results. The head runs on ``1 +
+    R`` rows. A round row that is idle has ``n_valid`` 0 and an all-zero
+    table: it costs its grid step and no bytes."""
+    import jax
+    import jax.numpy as jnp
+
+    if not (prefill and rows == 1 and sm.carries_rounds):
+        raise ValueError(
+            "only a one-row prefill of a model whose cache's kernel takes "
+            "each row's own range, and that keeps no recurrent state, "
+            "carries a decode round (ServedModel.carries_rounds)")
+    kvh, hd = sm.num_kv_heads, sm.head_dim
+    latent = sm.cache_spec["kind"] == "latent"
+    counter_names = sm.program_counters
+    R, B, W, PL = carry, n_blocks, window, page_len
+    N = W + R    # the tokens a block sees
+    if latent:
+        dl = sm.cache_spec["dim"]
+        DL = latent_width(dl)
+        latent_attend = _attention(sm, attends, "latent")
+    else:
+        kinds = list(sm.cache_spec["layers"])
+        ranged = {kind: _attention(sm, attends, kind)
+                  for kind in sorted(set(kinds))}
+
+    def pages_of(table, pos):
+        # page-table lookup of each token's block; blocks past the table (or
+        # past a request's allocation: entry 0) land in the scratch page
+        blk = pos // PL
+        pidx = jnp.take_along_axis(table, jnp.minimum(blk, B - 1), axis=1)
+        return jnp.where(blk < B, pidx, 0)
+
+    def flat_rows(table, pos):          # a latent arena's rows
+        return (pages_of(table, pos) * PL + pos % PL).reshape(-1)
+
+    def flat_kv(table, pos):            # rows of a [P, kvh, PL, hd] arena
+        return (((pages_of(table, pos)[..., None] * kvh + jnp.arange(kvh))
+                 * PL + (pos % PL)[..., None]).reshape(-1))
+
+    def both(kernel, q, chunk, round_):
+        """The chunk's queries ``q[:, :W]`` against the prompt's ``(table,
+        start)``, the round's, one a row, against theirs; joined as the
+        block handed them in."""
+        ctx = kernel(q[:, :W], *chunk)
+        r_ctx = kernel(jnp.swapaxes(q[:, W:], 0, 1), *round_)      # [R, 1]
+        return jnp.concatenate([ctx, jnp.swapaxes(r_ctx, 0, 1)], 1)
+
+    def step(params, k_arenas, v_arenas, tables, tokens, lengths, n_valid,
+             state=None):
+        (tables, r_tables), (tokens, r_tokens) = tables, tokens
+        (lengths, r_lengths), (n_valid, r_valid) = lengths, n_valid
+        pos = lengths[:, None] + jnp.arange(W)                     # [1, W]
+        r_pos = r_lengths[:, None]                                 # [R, 1]
+        xpos = jnp.concatenate([pos, r_pos.reshape(1, R)], 1)      # [1, N]
+        x = sm.embed(params, jnp.concatenate(
+            [tokens, r_tokens.reshape(1, R)], 1), xpos)            # [1, N, h]
+        valid = jnp.concatenate(
+            [jnp.arange(W)[None, :] < n_valid[:, None],
+             (r_valid > 0)[None, :]], 1)                           # [1, N]
+        if latent:
+            flat = jnp.concatenate([flat_rows(tables, pos),
+                                    flat_rows(r_tables, r_pos)])   # [N]
+        else:
+            by_kind = {kind: (tables[i], r_tables[i]) for i, kind in
+                       enumerate(("full", "window"))}
+            flat_of = {kind: jnp.concatenate([flat_kv(t, pos),
+                                              flat_kv(rt, r_pos)])
+                       for kind, (t, rt) in by_kind.items()}       # [N*kvh]
+        new_k, new_v, counted = [], [], []
+        for li, (p, kc) in enumerate(zip(params["layers"], k_arenas)):
+            P = kc.shape[0]
+
+            def attend_latent(q_lat, q_rope, row):
+                lanes = [(0, 0)] * (row.ndim - 1) + [(0, DL - dl)]
+                arena = kc.reshape(P * PL, DL).at[flat].set(
+                    jnp.pad(row, lanes).reshape(N, DL)).reshape(P, PL, DL)
+                new_k.append(arena)
+                q = jnp.concatenate(
+                    [q_lat, q_rope, jnp.zeros(q_lat.shape[:-1] + (DL - dl,),
+                                              q_lat.dtype)], -1)
+                return both(lambda q, t, at: latent_attend(q, arena, t, at),
+                            q, (tables, lengths), (r_tables, r_lengths))
+
+            kind = None if latent else kinds[li]
+
+            def attend_ranged(q, k1, v1):
+                vc, idx = v_arenas[li], flat_of[kind]
+                kk = kc.reshape(P * kvh * PL, hd).at[idx].set(
+                    k1.reshape(N * kvh, hd)).reshape(P, kvh, PL, hd)
+                vv = vc.reshape(P * kvh * PL, hd).at[idx].set(
+                    v1.reshape(N * kvh, hd)).reshape(P, kvh, PL, hd)
+                new_k.append(kk)
+                new_v.append(vv)
+                table, r_table = by_kind[kind]
+                return both(lambda q, t, at: ranged[kind](q, kk, vv, t, at),
+                            q, (table, lengths), (r_table, r_lengths))
+
+            attend_ranged.kind = kind
+            out = sm.block(p, x, xpos,
+                           attend_latent if latent else attend_ranged,
+                           None, valid)
+            x = out[0]
+            if counter_names and len(out) > 2 and out[2] is not None:
+                counted.append(out[2])
+        # the head at the prompt's last real position and at every row of
+        # the round: [1, 1 + R, h]
+        last = jnp.maximum(n_valid - 1, 0)[:, None, None]
+        x = jnp.concatenate(
+            [jnp.take_along_axis(x[:, :W], last, axis=1), x[:, W:]], 1)
+        logits = sm.head(params, x)
+        lf = logits.astype(jnp.float32)
+        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        logp = jnp.max(lf, axis=-1) - jax.scipy.special.logsumexp(lf, axis=-1)
+        # ([1, 1], [R, 1]): the prompt's, then the round's
+        nxt, logp = ((a[:, :1], a[0, 1:, None]) for a in (nxt, logp))
+        out = (nxt, logp, new_k, new_v, None)
+        if not counter_names:
+            return out
+        return out + ({name: sum((c[name] for c in counted[1:]),
+                                 counted[0][name])
+                       for name in counter_names if counted},)
+
+    from ..jit import persistent_cache
+
+    return persistent_cache.cached_jit(
+        step, donate_argnums=(1, 2) if donate else (), label=label)
 
 
 class GenerationEngine(EngineBase):
@@ -644,6 +861,17 @@ class GenerationEngine(EngineBase):
         # them from the host are then one signature to ``jax.jit``, lowered
         # once (a second lowering of a 36-layer program is seconds)
         self._device = next(iter(self._pool.k[0].devices()))
+        # and so are the arenas, from the start, as every program hands them
+        # back: a program's FIRST call then has the signature of all its
+        # later ones (the decode program used to be built twice in every
+        # warm-up, the first build never called again). No copy: the
+        # committed array is the same buffer
+        pool = self._pool
+        pool.k, pool.v, pool.state = jax.device_put(
+            (pool.k, pool.v, pool.state), self._device)
+        # the jitted attention callables, shared by this engine's window
+        # programs (``_build_window_step``: a kernel traced at a shape once)
+        self._attends: Dict[str, Any] = {}
         self._state_install_fn = None
         self._token_feed_fn = None
         self._decode_no = -1  # rounds dispatched (the decode_fault site's step)
@@ -777,12 +1005,27 @@ class GenerationEngine(EngineBase):
             role = "prefill" if prefill else "window"
             label = f"serving:{self.name}:{role}{W}"
             fn = jit_mod._maybe_audit(
-                label, _build_window_step(self._sm, rows,
-                                          self._n_blocks, self._pl, W,
-                                          self._donate, label=label,
-                                          prefill=prefill))
+                label, _build_window_step(
+                    self._sm, rows, self._n_blocks, self._pl, W,
+                    self._donate, label=label, prefill=prefill,
+                    carry=self._carried_rows(W) if prefill else 0,
+                    attends=self._attends))
             self._windows[key] = fn
         return fn
+
+    def _carried_rows(self, W: int) -> int:
+        """The decode rows the ``W``-token prefill program carries: a whole
+        round's, ``max_slots``, in the LARGEST bucket's program of a model
+        that qualifies (``ServedModel.carries_rounds``), else none. The
+        carrying program takes the row-only program's place, it does not
+        stand beside it, and the smaller buckets' programs stay what they
+        were: set-up builds as many programs as ever and only one of them
+        grew. A draft model's proposals cross the host between two rounds,
+        so with one nothing is carried."""
+        if W == self.config.prefill_buckets[-1] and self._sm.carries_rounds \
+                and not self.spec_k:
+            return self.config.max_slots
+        return 0
 
     def warmup(self):
         """Compile the whole steady-state executable set up front (decode,
@@ -793,40 +1036,64 @@ class GenerationEngine(EngineBase):
         import jax
         import jax.numpy as jnp
 
-        S, B = self.config.max_slots, self._n_blocks
+        S = self.config.max_slots
 
-        def scratch(rows, W, prefill, tokens=None):
-            # a decode round in which no row is valid; a prefill of one
-            # token, and the install of its row (slot 0 is free). What the
-            # scratch runs counted is dropped: they routed nothing real
+        def operands(rows, W, prefill, tokens=None):
             if tokens is None:
                 tokens = np.zeros((rows, W), np.int32)
                 tokens = jnp.asarray(tokens) if prefill else \
                     jax.device_put(tokens, self._device)
-            nxt, _lp, row, _counted = self._run_window(
-                rows, W, jnp.zeros(self._tables_shape(rows), jnp.int32),
-                tokens,
-                jnp.zeros(rows, jnp.int32),
-                n_valid=np.full(rows, int(prefill), np.int32),
-                prefill=prefill)
+            return (jnp.zeros(self._tables_shape(rows), jnp.int32), tokens,
+                    jnp.zeros(rows, jnp.int32),
+                    np.full(rows, int(prefill), np.int32))
+
+        def scratch(rows, W, prefill, feed=None):
+            # a decode round in which no row is valid (its tokens ``feed``);
+            # a prefill of one token — with the round it carries, no row of
+            # it valid, where it carries one — and the install of its row
+            # (slot 0 is free). What the scratch runs counted is dropped:
+            # they routed nothing real. Returns (a prefill's [1, 1] token, a
+            # round's [S, 1] tokens), None for what the program has not
+            carries = prefill and self._carried_rows(W)
+            ops = operands(rows, W, prefill, None if carries else feed)
+            if carries:
+                ops = tuple(zip(ops, operands(S, 1, False, feed)))
+            tables, tokens, lengths, n_valid = ops
+            # a program's first call is its build: trace, lower, load or
+            # compile (``n_valid`` a pair: a carrying program)
+            with span("pt.serve.warmup_program",
+                      label=f"{'prefill' if prefill else 'window'}{W}",
+                      rows=rows + int(carries)):
+                nxt, _lp, row, _counted = self._run_window(
+                    rows, W, tables, tokens, lengths, n_valid=n_valid,
+                    prefill=prefill)
+                jax.block_until_ready(nxt)
             if row is not None:
                 self._install_state(0, row)
-            return nxt
+            return nxt if carries else (nxt, None) if prefill else (None, nxt)
 
-        after_round = scratch(S, 1, False)
+        _first, after_round = scratch(S, 1, False)
         if self.spec_k:
             scratch(S, self.spec_k + 1, False)
         for b in self.config.prefill_buckets:
-            after_prefill = scratch(1, b, True)
+            after_prefill, after_carried = scratch(1, b, True)
         if not self.spec_k:
-            # a round that goes out ahead of a read takes its tokens from
-            # the device: the unread round's own output, or a prompt's first
-            # token fed into the host's rows (``_send_round``). Both forms
-            # run here: were either a signature of its own after all, its
-            # lowering would fall here and not on a request
-            scratch(S, 1, False, tokens=after_round)
-            scratch(S, 1, False, tokens=self._feed_token(
-                np.zeros((S, 1), np.int32), after_prefill, 0))
+            # a round that goes out ahead of a read — one of its own, or the
+            # one the largest bucket's prefill call carries — takes its
+            # tokens from the device: the unread round's own output, or a
+            # prompt's first token fed into the host's rows or into that
+            # output (``_round_feed``). Every form runs here: were one a
+            # signature of its own after all, its lowering would fall here
+            # and not on a request
+            feeds = [after_round, self._feed_token(
+                np.zeros((S, 1), np.int32), after_prefill, 0)]
+            if after_carried is not None:
+                feeds += [after_carried, self._feed_token(
+                    after_carried, after_prefill, 0)]
+            for feed in feeds:
+                scratch(S, 1, False, feed)
+                if after_carried is not None:
+                    scratch(1, self.config.prefill_buckets[-1], True, feed)
         if self.spec_k:
             zeros = jnp.zeros(S, jnp.int32)
             _n, self._dk, self._dv = self._draft_step(
@@ -908,13 +1175,17 @@ class GenerationEngine(EngineBase):
         is ``None`` but for that prefill, and ``counted`` holds the device
         scalars of a model that declares ``program_counters`` (a list of at
         most one dict: what ``_count_programs`` takes once the call is
-        done)."""
+        done). For a prefill that carries a round (``_carried_rows``) every
+        operand after ``W`` is a pair, the prompt's then the round's, and so
+        are ``next`` and ``logprob``."""
+        import jax
         import jax.numpy as jnp
 
         pool, fn = self._pool, self._window(rows, W, prefill)
         nxt, lp, pool.k, pool.v, state, *counted = fn(
             self._params, pool.k, pool.v, tables, tokens, lengths,
-            jnp.asarray(n_valid), None if prefill else pool.state)
+            jax.tree_util.tree_map(jnp.asarray, n_valid),
+            None if prefill else pool.state)
         if prefill:
             return nxt, lp, state, counted
         pool.state = state
@@ -1427,16 +1698,11 @@ class GenerationEngine(EngineBase):
         the moment this one ends instead of idling for a host round trip.
         ``prog`` is the program that went out ahead of its turn, if one did;
         with none in flight the worker is at a boundary and decides with
-        everything read, as it always did."""
+        everything read, as it always did. Prompts are admitted back to back
+        while slots and pages last, chunked or not: a prefill call of the
+        largest bucket carries the running sequences' round
+        (``_carried_round``), so an admission holds nobody."""
         prog = None
-        # a chunked prefill has held decode for several window calls: the
-        # running sequences get a round before the next prompt is admitted.
-        # (Admitting every waiting prompt first starves decode past the
-        # knee, and completed tokens/s then swing with the arrivals' timing:
-        # PERF.md section 6, PR 32.) A prompt that fits a bucket is one
-        # call, as ever. A stopgap: it goes when a chunk runs between two
-        # rounds (ROADMAP 2a R3)
-        owe_round = False
         while True:
             if prog is None:
                 # cross-thread ops (KV export/install) land at the step
@@ -1450,23 +1716,22 @@ class GenerationEngine(EngineBase):
                     self._apply_swap()
                 # admit a queued prompt into a free slot (join mid-flight,
                 # earliest deadline first, bounded by KV page headroom)
-                if self._pending_swap is None and not owe_round:
+                if self._pending_swap is None:
                     free = self._free_slot()
                     req = None if free is None else self._next_request()
                     if req is not None:
                         prog = _Admission(free, req)
-            owe_round = False
             if isinstance(prog, _Admission):
                 adm = prog
                 try:
                     prog = self._admit(adm)
-                    owe_round = prog is None and len(adm.chunks or ()) > 1
+                    continue
                 except PoolExhausted:
                     # transient: pages freed by in-flight releases will
-                    # cover it — requeue at the front, decode meanwhile
+                    # cover it — requeue at the front, and a decode round
+                    # (below) before it is tried again
                     self._requeue(adm.req)
-                    prog, owe_round = None, True
-                continue
+                    prog = None
             if prog is None and not self._active():
                 with self._cond:
                     if self._closed and not self._queue:
@@ -1499,17 +1764,20 @@ class GenerationEngine(EngineBase):
         follows is decided whatever ``flying`` returns; else ``None``, and
         the worker reads, then decides. Decided means:
 
-        - a chunked admission's last call is followed by the round the
-          running sequences are owed (``_worker``); that round takes the
-          prompt's first token from the call's own output on the device;
         - with a slot free and a prompt waiting whose pages the pool has,
           that prompt's first prefill call comes next: slot and pages do not
-          hang on the unread result;
+          hang on the unread result. A call of the largest bucket CARRIES
+          the round that would otherwise have to wait behind it
+          (``_carried_round``), so a chunked admission holds nobody and
+          owes nobody a round;
         - with no slot free, and none to come free when ``flying`` is read, no
           prompt can join whatever arrives, so a round comes next. Every
           request's remaining budget is known here; one that ends on EOS
           instead is the one wasted row: ``_emit_round`` drops its extra
           token.
+
+        Either takes the tokens ``flying`` has not handed over from its own
+        output on the device (``_round_feed``).
 
         With a slot free and nothing to admit NOTHING goes out: the next
         arrival would otherwise prefill behind a round dispatched on a guess
@@ -1524,8 +1792,7 @@ class GenerationEngine(EngineBase):
         with self._cond:
             if self._ops:
                 return None
-        owed = isinstance(flying, _Admission) and len(flying.chunks) > 1
-        free = None if owed else self._free_slot()
+        free = self._free_slot()
         if free is not None:
             req = self._next_request()
             if req is None:
@@ -1533,7 +1800,8 @@ class GenerationEngine(EngineBase):
             adm = _Admission(free, req)
             try:
                 self._join(adm)
-                self._send_chunk(adm, ahead=True)
+                self._send_chunk(adm, flying,
+                                 self._carried_round(adm, flying))
             except PoolExhausted:
                 self._requeue(req)
                 return None
@@ -1542,8 +1810,7 @@ class GenerationEngine(EngineBase):
                 return None
             return adm
         rnd = self._build_round(flying)
-        if not rnd.rows or (not owed
-                            and len(rnd.rows) < len(self._active())):
+        if not rnd.rows or len(rnd.rows) < len(self._active()):
             return None
         try:
             self._send_round(rnd, flying)
@@ -1621,9 +1888,24 @@ class GenerationEngine(EngineBase):
         # slot's own table is written again
         adm.table = jnp.asarray(self._slot_tables(s)[..., None, :].copy())
 
-    def _send_chunk(self, adm: _Admission, ahead: bool = False) -> None:
-        """Dispatch the next window call of a prompt's prefill. ``ahead``:
-        the program before it is still unread. Behind a prompt's LAST call
+    def _carried_round(self, adm: _Admission, flying) -> Optional[_Round]:
+        """The decode round the next window call of ``adm``'s prefill
+        carries, built as the round that would go out behind ``flying`` is
+        (``_build_round``; the joining prompt has no token yet and gets no
+        row); ``None`` for a call that carries none (``_carried_rows``). With
+        no sequence running the round has no row and the program runs all
+        the same: every one of its decode rows is idle."""
+        if not self._carried_rows(adm.chunks[len(adm.outs)][2]):
+            return None
+        return self._build_round(flying, joining=adm)
+
+    def _send_chunk(self, adm: _Admission, flying=None,
+                    rnd: Optional[_Round] = None) -> None:
+        """Dispatch the next window call of a prompt's prefill, behind
+        ``flying`` if that program is still unread (``adm`` itself, while
+        its call before this one is), and with it the round ``rnd`` it
+        carries (``_carried_round``, built by the caller: what it carries is
+        an argument of the call's span). Behind a prompt's LAST call
         go, dispatched and not waited for, what the next program may need of
         it: the install of its recurrent state, and its full blocks' adoption
         by the prefix cache (any program that reads those pages runs behind
@@ -1640,16 +1922,28 @@ class GenerationEngine(EngineBase):
                     self._slot_tables(s)[..., None, :].copy())
         tokens = np.zeros((1, Wc), dtype=np.int32)
         tokens[0, :hi - lo] = req.prompt[lo:hi]
+        tables, tokens = adm.table, jnp.asarray(tokens)
+        lengths = jnp.asarray(np.array([lo], dtype=np.int32))
+        n_valid = np.array([hi - lo], dtype=np.int32)
+        if rnd is not None:
+            # the prompt's operand, then the round's: as ``_send_round``
+            # hands them to a round of its own
+            tables = (tables, jnp.asarray(rnd.tables))
+            tokens = (tokens, self._round_feed(rnd, flying))
+            lengths = (lengths, jnp.asarray(rnd.lengths))
+            n_valid = (n_valid, self._round_valid(rnd))
         with _oom_guard("generation", label=f"serving:{self.name}:prefill",
                         engine=self.name, bucket=Wc):
             nxt, lp, row, counted = self._run_window(
-                1, Wc, adm.table, jnp.asarray(tokens),
-                jnp.asarray(np.array([lo], dtype=np.int32)),
-                n_valid=np.array([hi - lo], dtype=np.int32), prefill=True)
+                1, Wc, tables, tokens, lengths, n_valid=n_valid,
+                prefill=True)
+        if rnd is not None:
+            (nxt, rnd.nxt), (lp, rnd.lp) = nxt, lp
         adm.outs.append((nxt, lp))
+        adm.carried.append(rnd)
         adm.counters += counted
         self.metrics.inc("prefill_chunks_total")
-        if ahead:
+        if flying is not None:
             self.metrics.inc("programs_run_ahead_total")
         # token-rows the prefill program ran (rows x W): what
         # stats()["prefill_fill_rate"] divides the real tokens by
@@ -1695,8 +1989,11 @@ class GenerationEngine(EngineBase):
         chunk always, after the last call whatever ``_run_ahead`` finds —
         and only then is the call waited for, so a ``pt.serve.prefill_chunk``
         span is one call's time on the device, from the end of the program
-        before it (or its own dispatch, if later) to its own end. The first
-        generated token is the window's argmax at the last real prompt
+        before it (or its own dispatch, if later) to its own end. A call
+        that carried a round (``_carried_round``; the span's ``carried`` is
+        its live rows) hands the round's tokens over as soon as it is read:
+        they are emitted and counted as a round's are (``_read_round``). The
+        first generated token is the window's argmax at the last real prompt
         position (matching ``generate``'s contract). Returns the program
         that went out behind the last call, if one did."""
         req, slot_no = adm.req, adm.slot_no
@@ -1718,15 +2015,23 @@ class GenerationEngine(EngineBase):
                 with span("pt.serve.prefill_dispatch", bucket=W,
                           prefix_blocks=m, rows=1):
                     for i, (lo, _hi, Wc) in enumerate(chunks):
+                        ahead = i < len(adm.outs)
+                        rnd = adm.carried[i] if ahead else \
+                            self._carried_round(adm, None)
                         with span("pt.serve.prefill_chunk", start=lo, W=Wc,
-                                  ahead=int(i < len(adm.outs))):
-                            if i == len(adm.outs):
-                                self._send_chunk(adm)
+                                  ahead=int(ahead), carried=0 if rnd is None
+                                  else len(rnd.rows)):
+                            if not ahead:
+                                self._send_chunk(adm, None, rnd)
                             if i + 1 < len(chunks):
-                                self._send_chunk(adm, ahead=True)
+                                self._send_chunk(
+                                    adm, adm, self._carried_round(adm, adm))
                             else:
                                 after = self._run_ahead(adm)
                             adm.outs[i][0].block_until_ready()
+                            adm.carried[i] = None
+                            if rnd is not None and rnd.rows:
+                                self._read_round(rnd, carried=True)
                 if s.req is not req:
                     return after  # failed with the round that went out ahead
                 # a prefill returns its last real position only
@@ -1765,6 +2070,9 @@ class GenerationEngine(EngineBase):
             raise
         except Exception as e:  # isolate: fail this prompt only
             self._fail_admission(adm, e)
+            for rnd in adm.carried:  # and the rows whose tokens it held
+                if rnd is not None:
+                    self._fail_rows(rnd.rows, e)
         return after
 
     def _note_token(self, req: _GenRequest, t: int, lp: float) -> None:
@@ -1802,30 +2110,30 @@ class GenerationEngine(EngineBase):
             self._dk[li] = self._dinsert(self._dk[li], k.data, slot)
             self._dv[li] = self._dinsert(self._dv[li], v.data, slot)
 
-    def _build_round(self, flying=None) -> _Round:
+    def _build_round(self, flying=None,
+                     joining: Optional[_Admission] = None) -> _Round:
         """The decode round that follows ``flying`` (a program dispatched and
         not yet read; ``None``: everything is read): a row for every request
         that is still running once ``flying`` is read, at the length it has
         then. A request whose budget or context ``flying``'s token completes
         gets no row; a row whose token is still on the device is left 0
-        (``_send_round`` feeds it)."""
+        (``_round_feed`` feeds it). ``joining``: the prompt whose prefill
+        call carries this round; it has no token yet and gets no row."""
         S, B = self.config.max_slots, self._n_blocks
         k = self.spec_k if self._spec_on else 0
         with span("pt.serve.decode_build"):
             tokens = np.zeros((S, k + 1), dtype=np.int32)
             lengths = np.zeros(S, dtype=np.int32)
             tables = np.zeros(self._tables_shape(S), dtype=np.int32)
-            unread = dict(flying.rows) if flying is not None else {}
-            # a round's token is cached at the row's length and moves it on;
-            # a prefill's first token is not cached yet
-            step = int(isinstance(flying, _Round))
+            unread = _unread(flying)
             rows = []
             for i, s in enumerate(self._slots):
                 req, length = s.req, s.length
-                if req is None:
+                if req is None or \
+                        (joining is not None and i == joining.slot_no):
                     continue
-                if unread.get(i) is req:
-                    length += step
+                if i in unread and unread[i][0] is req:
+                    length += unread[i][1]
                     if len(req.generated) + 1 >= req.max_new_tokens \
                             or length >= self.max_len - 1:
                         continue
@@ -1838,12 +2146,36 @@ class GenerationEngine(EngineBase):
                 rows.append((i, req))
         return _Round(rows, k, tokens, lengths, tables)
 
-    def _send_round(self, rnd: _Round, flying=None) -> None:
-        """Dispatch a built round. Behind an unread ``flying`` its tokens
-        come from ``flying``'s own output: a round's ``[max_slots, 1]``
-        argmaxes as they are (every row of this round had one in that), a
-        prefill's first token through ``_feed_token``."""
+    def _round_feed(self, rnd: _Round, flying=None):
+        """A built round's ``[max_slots, 1]`` tokens, on the device. Behind
+        an unread ``flying`` they come from ``flying``'s own output: a
+        round's argmaxes as they are — a round of its own or the one a
+        prefill call carried (every row of this round had one in that) — and
+        the first token of a prompt whose last call is out through
+        ``_feed_token``. All are committed to the arenas' device: one
+        signature of the program that takes them."""
         import jax
+
+        if flying is None:
+            return jax.device_put(rnd.tokens, self._device)
+        if isinstance(flying, _Round):
+            return flying.nxt
+        carried = flying.carried[-1]
+        feed = rnd.tokens if carried is None else carried.nxt
+        if flying.sent:
+            return self._feed_token(feed, flying.outs[-1][0], flying.slot_no)
+        return feed
+
+    def _round_valid(self, rnd: _Round) -> np.ndarray:
+        """``n_valid`` of a round's program: 1 for a row the round advances,
+        0 for an idle one (its state, if the model has one, stays as it
+        is)."""
+        n_valid = np.zeros(self.config.max_slots, dtype=np.int32)
+        n_valid[[i for i, _req in rnd.rows]] = 1
+        return n_valid
+
+    def _send_round(self, rnd: _Round, flying=None) -> None:
+        """Dispatch a built round, its tokens from ``_round_feed``."""
         import jax.numpy as jnp
 
         S, k, tokens = self.config.max_slots, rnd.k, rnd.tokens
@@ -1865,22 +2197,12 @@ class GenerationEngine(EngineBase):
                             jnp.asarray(rnd.lengths + j))
                     tokens[:, j + 1] = np.asarray(nd)
                     cur = nd
-            if isinstance(flying, _Round):
-                feed = flying.nxt
-            elif flying is not None:
-                feed = self._feed_token(tokens, flying.outs[-1][0],
-                                        flying.slot_no)
-            else:
-                feed = jax.device_put(tokens, self._device)
-            # every row of the round advances its state one step, in place;
-            # an idle row's n_valid is 0 and its state stays as it is
-            n_valid = np.zeros(S, dtype=np.int32)
-            n_valid[[i for i, _req in rnd.rows]] = 1
             with _oom_guard("generation", label=f"serving:{self.name}:decode",
                             engine=self.name, step=self._decode_no):
                 rnd.nxt, rnd.lp, _row, rnd.counters = self._run_window(
-                    S, k + 1, jnp.asarray(rnd.tables), feed,
-                    jnp.asarray(rnd.lengths), n_valid=n_valid)
+                    S, k + 1, jnp.asarray(rnd.tables),
+                    self._round_feed(rnd, flying), jnp.asarray(rnd.lengths),
+                    n_valid=self._round_valid(rnd))
         if flying is not None:
             self.metrics.inc("programs_run_ahead_total")
 
@@ -1909,14 +2231,9 @@ class GenerationEngine(EngineBase):
         emitted tokens are target argmaxes, so greedy output is unchanged.
         The program after this round is dispatched before this one is read
         where ``_run_ahead`` finds it decided, and is returned."""
-        S = self.config.max_slots
         ahead = rnd is not None
         n_active = len(rnd.rows) if ahead else len(self._active())
         k = rnd.k if ahead else self.spec_k if self._spec_on else 0
-        if self._hist_slots is not None:
-            # concurrent-occupancy sample per decode window: the
-            # distribution the tuner derives max_slots from
-            self._hist_slots.observe(n_active)
         after = None
         try:
             with span("pt.serve.decode_round", n_active=n_active, W=k + 1,
@@ -1926,40 +2243,55 @@ class GenerationEngine(EngineBase):
                     rnd = self._build_round()
                     self._send_round(rnd)
                 after = self._run_ahead(rnd)
-                with span("pt.serve.decode_sync"):
-                    n = np.asarray(rnd.nxt)  # [S, W] target argmaxes
-                    lpn = np.asarray(rnd.lp)  # [S, W] their logprobs (f32)
-                self._count_programs(rnd.counters)
-                fr = self._flight()
-                if fr is not None:  # decode steps land in the flight ring
-                    fr.record_serving_step(self.name, "decode",
-                                           (time.monotonic() - t_dec) * 1e3,
-                                           n_active)
-                self.metrics.inc("decode_steps")
-                self.metrics.inc("slot_rounds", n_active)
-                # cached positions the round's queries see, summed over its
-                # rows
-                self.metrics.inc("attn_keys_decode_total",
-                                 int(rnd.lengths.sum()) + n_active)
-                if self._by_layer:
-                    seen = rnd.lengths[[i for i, _req in rnd.rows]] + 1
-                    self._count_keys(int(seen.sum()), int(
-                        np.minimum(seen, self._win).sum()), decode=True)
-                self.metrics.observe_occupancy(n_active / S)
-                with span("pt.serve.emit"):
-                    emitted_total = self._emit_round(rnd, n, lpn)
-                self.metrics.inc("tokens_total", emitted_total)
-                if k:
-                    self.metrics.inc("spec_rounds")
-                    if self._fam_spec is not None:
-                        self._fam_spec.inc((self.name, "rounds"))
-                        self._fam_spec.inc((self.name, "emitted"),
-                                           emitted_total)
+                self._read_round(rnd, t_dec)
         except Exception as e:  # decode fault: fail the round's requests
             self._fail_rows(rnd.rows if rnd is not None else
                             [(i, self._slots[i].req) for i in self._active()],
                             e)
         return after
+
+    def _read_round(self, rnd: _Round, t_dec: Optional[float] = None,
+                    carried: bool = False) -> None:
+        """Read a dispatched round's tokens, count what a round counts and
+        emit. ``carried``: the round rode a prefill call (the program's own
+        counters are the admission's). It is counted as a decode step like
+        any other — occupancy and the kernels' roofline readers see the work
+        that was done — and in ``rounds_carried_total`` besides."""
+        S, k, n_active = self.config.max_slots, rnd.k, len(rnd.rows)
+        if self._hist_slots is not None:
+            # concurrent-occupancy sample per decode window: the
+            # distribution the tuner derives max_slots from
+            self._hist_slots.observe(n_active)
+        with span("pt.serve.decode_sync"):
+            n = np.asarray(rnd.nxt)  # [S, W] target argmaxes
+            lpn = np.asarray(rnd.lp)  # [S, W] their logprobs (f32)
+        self._count_programs(rnd.counters)
+        fr = self._flight()
+        if fr is not None and t_dec is not None:
+            # decode steps land in the flight ring
+            fr.record_serving_step(self.name, "decode",
+                                   (time.monotonic() - t_dec) * 1e3,
+                                   n_active)
+        self.metrics.inc("decode_steps")
+        if carried:
+            self.metrics.inc("rounds_carried_total")
+        self.metrics.inc("slot_rounds", n_active)
+        # cached positions the round's queries see, summed over its rows
+        self.metrics.inc("attn_keys_decode_total",
+                         int(rnd.lengths.sum()) + n_active)
+        if self._by_layer:
+            seen = rnd.lengths[[i for i, _req in rnd.rows]] + 1
+            self._count_keys(int(seen.sum()), int(
+                np.minimum(seen, self._win).sum()), decode=True)
+        self.metrics.observe_occupancy(n_active / S)
+        with span("pt.serve.emit"):
+            emitted_total = self._emit_round(rnd, n, lpn)
+        self.metrics.inc("tokens_total", emitted_total)
+        if k:
+            self.metrics.inc("spec_rounds")
+            if self._fam_spec is not None:
+                self._fam_spec.inc((self.name, "rounds"))
+                self._fam_spec.inc((self.name, "emitted"), emitted_total)
 
     def _emit_round(self, rnd: _Round, n, lpn) -> int:
         """Accept, emit, finish and release, row by row; returns the
@@ -2113,6 +2445,11 @@ class GenerationEngine(EngineBase):
         snap["run_ahead_rate"] = round(
             c.get("programs_run_ahead_total", 0) / programs, 4) \
             if programs else 0.0
+        # share of the decode steps that rode a prefill call of the largest
+        # bucket (the carried step) instead of holding the device alone
+        steps = c.get("decode_steps", 0)
+        snap["carried_round_rate"] = round(
+            c.get("rounds_carried_total", 0) / steps, 4) if steps else 0.0
         pairs = c.get("moe_pairs_total", 0)
         if pairs:  # an expert layer that holds a share of its experts
             snap["moe_held_share"] = round(

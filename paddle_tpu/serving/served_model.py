@@ -51,7 +51,20 @@ contains no model. A model that can be served implements
   hand back as a THIRD result (``(x, state, {name: scalar})``, ``None`` from
   a layer that has none). The window program sums them over its layers and
   returns them beside the tokens; the worker adds them to its counters at
-  the sync it makes anyway.
+  the sync it makes anyway;
+- ``carries_rounds`` (derived, never set): whether the prefill program of
+  the engine's largest bucket also runs the running sequences' decode step
+  (``generation._build_window_step``, "the carried step"). True when the
+  cache's kernel takes each row's own range of pages and nothing recurs:
+  ``cache_spec`` of kind ``"latent"`` or ``"kv_by_layer"`` and no
+  ``state_spec``. Then a chunk's row and a round's rows are just ``C + S``
+  tokens to everything position-wise in ``block``, and only ``attend``
+  tells them apart. Falcon-H1 is out: a prefill starts its state from zero
+  while a round advances the slot arenas in place — two conventions in one
+  program. GPT-2 is out: ``pt_paged_attention`` walks every page of every
+  slot whatever the lengths and each of its window programs copies the
+  whole arenas between two layouts; once it moves onto the ranged kernel's
+  layout (ROADMAP S2) it inherits the carried step through this property.
 
 A model with recurrent state cannot use what assumes a cache is pages of
 K/V (the prefix trie, speculative verify, KV-page export/install), a latent
@@ -86,6 +99,13 @@ class ServedModel:
     cache_spec: Optional[Dict[str, Any]] = None
     # None: the window programs hand back tokens and logprobs alone
     program_counters: Optional[Tuple[str, ...]] = None
+
+    @property
+    def carries_rounds(self) -> bool:
+        """Whether a prefill call of the largest bucket carries the running
+        sequences' decode step (module docstring)."""
+        return self.state_spec is None and self.cache_spec is not None \
+            and self.cache_spec["kind"] in ("latent", "kv_by_layer")
 
     def params(self, model) -> Dict[str, Any]:
         raise NotImplementedError

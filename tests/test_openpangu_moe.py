@@ -358,13 +358,13 @@ def test_the_next_program_goes_out_before_the_last_is_read(tiny):
     """Two short prompts decode; from the worker's own thread, between two
     of their rounds, a prompt of three chunks and two short ones arrive. Of
     the long prompt's calls the second and third go out behind the one
-    before (a chunk needs nothing of the last on the host), the round it owes
-    the running sequences behind its last call (the prompt's first token
-    reaches that round on the device), the next waiting prompt's call behind
-    that round (a slot is free), and with every slot taken a round behind a
-    prefill or a round — until a request's budget frees a slot, where the
-    worker reads before it decides. Logprobs are the reference's and every
-    routed pair is counted once."""
+    before (a chunk needs nothing of the last on the host) and the two of the
+    largest bucket each carry the running sequences' round, so nobody is owed
+    one: the next waiting prompt's call goes out behind its last (a slot is
+    free), and with every slot taken a round behind a prefill (the prompt's
+    first token reaches that round on the device) or a round — until a
+    request's budget frees a slot, where the worker reads before it decides.
+    Logprobs are the reference's and every routed pair is counted once."""
     from paddle_tpu.observability.trace.request_trace import tracer
 
     cfg, model, _params, get = tiny
@@ -395,17 +395,21 @@ def test_the_next_program_goes_out_before_the_last_is_read(tiny):
     c = stats["counters"]
     assert c["prefill_chunks_total"] == 1 + 1 + 3 + 1 + 1
     # behind a read: the second prompt's call (it waited, a slot was free);
-    # chunks two and three; the owed round; the fourth prompt's call; the
-    # round behind it (no slot free); the round behind the fifth's call
-    assert c["programs_run_ahead_total"] == 1 + 2 + 1 + 1 + 1 + 1
+    # chunks two and three; the fourth prompt's call; the round behind it
+    # (no slot free) and the round behind that
+    assert c["programs_run_ahead_total"] == 1 + 2 + 1 + 1 + 1
     rows = [r for r in tracer().worker_spans()
             if r["thread"].endswith(eng.name)]
     chunks = [r["args"] for r in rows if r["name"] == "pt.serve.prefill_chunk"]
     assert [(a["start"], a["ahead"]) for a in chunks] == [
         (0, 0), (0, 1), (0, 0), (16, 1), (32, 1), (0, 1), (0, 0)]
+    # the long prompt's two 16-token calls carried the two running rows
+    assert [a["carried"] for a in chunks] == [0, 0, 2, 2, 0, 0, 0]
+    assert c["rounds_carried_total"] == 2
+    assert stats["carried_round_rate"] == round(2 / c["decode_steps"], 4)
     rounds = [r["args"]["ahead"] for r in rows
               if r["name"] == "pt.serve.decode_round"]
-    assert rounds == [0, 1, 1, 1] + [0] * (c["decode_steps"] - 4)
+    assert rounds == [0, 1, 1] + [0] * (c["decode_steps"] - 2 - 3)
     consumed = sum(lens) + sum(outs) - len(lens)
     experts_layers = cfg.num_hidden_layers - cfg.first_k_dense_replace
     assert c["moe_pairs_total"] == c["moe_held_pairs_total"] == \
@@ -435,14 +439,16 @@ def test_the_benchmarks_reference_is_the_repos():
         assert f.read() == g.read()
 
 
-@pytest.mark.parametrize("prompt_len,rounds_between", [(40, True),
-                                                       (12, False)])
-def test_a_chunked_admission_yields_to_one_decode_round(tiny, prompt_len,
-                                                        rounds_between):
-    """Three prompts wait when the worker starts. Prompts of three chunks
-    are admitted ONE between two decode rounds (a chunked prefill has held
-    decode for several window calls already); prompts that fit a bucket
-    are admitted back to back before the first round, as ever."""
+@pytest.mark.parametrize("prompt_len,live", [
+    (40, [0, 0, 0, 1, 1, 0, 2, 2, 0]), (12, [0, 1, 2]), (6, [0, 0, 0])])
+def test_a_chunked_admission_holds_nobody(tiny, prompt_len, live):
+    """Three prompts wait when the worker starts and are admitted back to
+    back, chunked or not: a prompt of three chunks owes the running sequences
+    no round, because its two calls of the largest bucket CARRIED one each
+    (the second prompt's the first prompt's row, the third's both; the first
+    prompt found nobody running and its calls carried no live row). A prompt
+    that fits the largest bucket is one such call; prompts that fit a smaller
+    bucket carry nothing, as ever."""
     from paddle_tpu.observability.trace.request_trace import tracer
 
     cfg, model, _params, _get = tiny
@@ -453,16 +459,16 @@ def test_a_chunked_admission_yields_to_one_decode_round(tiny, prompt_len,
     with eng:  # the worker starts with all three queued
         for f in futs:
             f.result(timeout=300)
-    names = [r["name"].rsplit(".", 1)[-1] for r in sorted(
-        (r for r in tracer().worker_spans()
-         if r["thread"].endswith(eng.name)
-         and r["name"] in ("pt.serve.admit", "pt.serve.decode_round")),
-        key=lambda r: r["t0"])]
+        c = eng.stats()["counters"]
+    rows = sorted((r for r in tracer().worker_spans()
+                   if r["thread"].endswith(eng.name)), key=lambda r: r["t0"])
+    names = [r["name"].rsplit(".", 1)[-1] for r in rows
+             if r["name"] in ("pt.serve.admit", "pt.serve.decode_round")]
     admits = [i for i, n in enumerate(names) if n == "admit"]
     assert len(admits) == 3
-    between = names[admits[0]:admits[2] + 1]
-    if rounds_between:
-        assert between == ["admit", "decode_round", "admit", "decode_round",
-                           "admit"]
-    else:
-        assert between == ["admit", "admit", "admit"]
+    assert names[admits[0]:admits[2] + 1] == ["admit", "admit", "admit"]
+    assert [r["args"]["carried"] for r in rows
+            if r["name"] == "pt.serve.prefill_chunk"] == live
+    assert c.get("rounds_carried_total", 0) == sum(1 for n in live if n)
+    # every token but a request's first came from a round, carried or not
+    assert c["tokens_total"] == c["slot_rounds"] == 3 * (6 - 1)
