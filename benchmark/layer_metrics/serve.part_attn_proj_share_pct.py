@@ -1,0 +1,14 @@
+"""Model step: device self time in the traced window under the attention
+projections (q / k / v / gate / o, RoPE, MLA's down- and up-projections and the
+absorbed value up-projection), over device busy time. The program names the
+part (``jax.named_scope("pt.<part>")``:
+``paddle_tpu.observability.trace.parts``) and ``benchmark/lib/part_time.py``
+reads it from the device trace's op metadata; a program that names no part
+reads as nothing."""
+from benchmark.lib import part_time
+
+UNIT = "%"
+
+
+def reduce(trace, counters, spans, shapes):
+    return part_time.share(shapes, "attn_proj")

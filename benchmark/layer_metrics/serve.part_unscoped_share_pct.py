@@ -1,0 +1,13 @@
+"""Device: device self time in the traced window under NO part: the ops of a
+program run in which nothing names one — the readers' own honesty; the ten
+shares add up to 100, over device busy time. The program names the part
+(``jax.named_scope("pt.<part>")``: ``paddle_tpu.observability.trace.parts``)
+and ``benchmark/lib/part_time.py`` reads it from the device trace's op
+metadata; a program that names no part reads as nothing."""
+from benchmark.lib import part_time
+
+UNIT = "%"
+
+
+def reduce(trace, counters, spans, shapes):
+    return part_time.share(shapes, "unscoped")

@@ -48,7 +48,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 __all__ = ["enable", "disable", "is_enabled", "cache_dir", "stats",
            "reset_stats", "cached_jit", "CachedJit", "clear", "default_dir",
-           "enable_jax_compilation_cache"]
+           "enable_jax_compilation_cache", "in_one_stack_chunk"]
 
 _JAX_ENV = "JAX_COMPILATION_CACHE_DIR"
 _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -304,6 +304,33 @@ def _abstract_sig(args: Tuple) -> Tuple:
     return (tuple(sig), str(treedef))
 
 
+def in_one_stack_chunk(fn, *args, **kwargs):
+    """``fn(*args, **kwargs)`` with every Python frame above this one in ONE
+    chunk of the interpreter's stack — what a warm build should run under.
+
+    CPython (3.11 on) keeps a thread's frames in "data stack" chunks of
+    16 KiB and UNMAPS a chunk the moment its first frame returns. A call
+    site that happens to sit at a chunk's end therefore maps and unmaps 16
+    KiB on every call it makes: 6 us where a call costs 40 ns (a loop of 200
+    000 trivial calls at recursion depth 268, this sandbox). A ``jax.jit``
+    trace is a few hundred frames deep with hot loops at many depths, so
+    some sit on a boundary, and WHICH do moves with the size of every frame
+    below them: the same window programs of ``laguna-xs2-d5`` built warm in
+    7.7 to 17.5 s on the chip's host as nothing but the number of 1 KiB
+    frames under ``warmup()`` went from 0 to 7, and in 6.2 s under this
+    function (PERF.md section 6, PR 37: what PR 36 measured as "lines added
+    to a traced function slow every trace under it").
+
+    The cure is this frame's size: it asks for 2**16 and a few stack slots,
+    CPython gives it a chunk of the next power of two (1 MiB, mapped, not
+    touched), and the half it does not use holds a few thousand frames."""
+    return fn(*args, **kwargs)
+
+
+in_one_stack_chunk.__code__ = in_one_stack_chunk.__code__.replace(
+    co_stacksize=(1 << 16) + 256)
+
+
 class CachedJit:
     """``jax.jit`` with a persistent per-signature compile step.
 
@@ -365,7 +392,7 @@ class CachedJit:
         return h.hexdigest()
 
     def _build(self, args, sig) -> Callable:
-        lowered = self._jitted.lower(*args)
+        lowered = in_one_stack_chunk(self._jitted.lower, *args)
         key = self._key(lowered, sig)
         # serialize_broken gates WRITES only: one program that cannot
         # round-trip must not stop other programs' valid on-disk entries

@@ -36,13 +36,13 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-def grouped_matmul(lhs, rhs, group_sizes, *, tiling=None):
+def grouped_matmul(lhs, rhs, group_sizes, *, tiling=None, out_dtype=None):
     """lhs[m, k] @ rhs[g, k, n] per contiguous row group -> [m, n].
 
     Rows of `lhs` must be grouped by expert: rows
     [sum(group_sizes[:i]), sum(group_sizes[:i+1])) multiply rhs[i].
-    sum(group_sizes) must equal m. Accumulates fp32, returns lhs.dtype.
-    Differentiable on both backends.
+    sum(group_sizes) must equal m. Accumulates fp32, returns ``out_dtype``
+    (``lhs.dtype`` where none is given). Differentiable on both backends.
     """
     group_sizes = group_sizes.astype(jnp.int32)
     m, k = lhs.shape
@@ -61,4 +61,4 @@ def grouped_matmul(lhs, rhs, group_sizes, *, tiling=None):
     else:
         out = jax.lax.ragged_dot(lhs, rhs, group_sizes,
                                  preferred_element_type=jnp.float32)
-    return out.astype(lhs.dtype)
+    return out.astype(lhs.dtype if out_dtype is None else out_dtype)

@@ -33,6 +33,7 @@ from ..core.dispatch import primitive
 from ..framework import dtype as dtype_mod
 from ..framework import random as random_mod
 from ..nn import functional as F
+from ..observability.trace.parts import part
 from ..serving.served_model import ServedModel
 
 F32 = jnp.float32
@@ -150,6 +151,7 @@ def mup_vector(cfg: FalconH1Config):
 # multiplier rounded to bfloat16 would be off by up to 0.4 %, the same way at
 # every position). The matmuls accumulate and hand back float32.
 
+@part("norm")
 def _rms(x, w, eps):
     """RMSNorm of a float32 stream; float32 out."""
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * \
@@ -213,6 +215,7 @@ def ssd_chunked(x, dt, a, b, c, chunk: int):
     return y.reshape(R, W + pad, H, P)[:, :W], s
 
 
+@part("mixer")
 def _ssm_branch(cfg: FalconH1Config, p, u, state, valid):
     """The Mamba-2 mixer on the normed input ``u`` [R, W, h] (float32). ``state``
     None: a fresh sequence (chunked scan from zero; returns the final
@@ -261,31 +264,57 @@ def _ssm_branch(cfg: FalconH1Config, p, u, state, valid):
     return y, {"ssm": ssm, "conv": new_tail}
 
 
+# The parts of the block (``observability.trace.parts``: what a device trace
+# says of a served step). They sit on helpers so that ``block_fn``, which
+# every window program traces once a layer, stays short.
+
+@part("attn_proj")
+def _qkv(cfg: FalconH1Config, p, u, pos):
+    """The attention branch's q, k, v of the normed input, roped, in the
+    weights' dtype."""
+    R, W, _ = u.shape
+    nh, kvh, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
+                   cfg.head_dim)
+    wd = p["q_w"].dtype
+    ua = u * cfg.attention_in_multiplier
+    q = _mm(ua, p["q_w"]).reshape(R, W, nh, hd)
+    k = (_mm(ua, p["k_w"]) * cfg.key_multiplier).reshape(R, W, kvh, hd)
+    v = _mm(ua, p["v_w"]).reshape(R, W, kvh, hd)
+    return (_rope(q, pos, cfg.rope_theta).astype(wd),
+            _rope(k, pos, cfg.rope_theta).astype(wd), v.astype(wd))
+
+
+@part("attn_proj")
+def _attn_out(cfg: FalconH1Config, p, ctx):
+    R, W = ctx.shape[:2]
+    return _mm(ctx.reshape(R, W, -1), p["o_w"]) * \
+        cfg.attention_out_multiplier
+
+
+@part("mixer")
+def _join(x, a, y):
+    """The two branches' outputs meet the stream."""
+    return x + a + y
+
+
+@part("mlp")
+def _mlp(cfg: FalconH1Config, p, x, v2):
+    g0, g1 = cfg.mlp_multipliers
+    m = _mm(v2, p["up_w"]) * jax.nn.silu(_mm(v2, p["gate_w"]) * g0)
+    return x + _mm(m, p["down_w"]) * g1
+
+
 def block_fn(cfg: FalconH1Config, p, x, pos, attend, state, valid):
     """One Falcon-H1 block. ``x`` [R, W, h], the float32 residual stream;
     ``pos`` [R, W] global positions; ``attend(q, k, v) -> ctx`` causal
     attention of ``q`` [R, W, heads, d] given this window's ``k``/``v``
     [R, W, kv_heads, d] (all in the weights' dtype); ``state``/``valid`` as
     in ``serving.served_model``."""
-    R, W, _ = x.shape
-    nh, kvh, hd = (cfg.num_attention_heads, cfg.num_key_value_heads,
-                   cfg.head_dim)
-    wd = p["q_w"].dtype
     u = _rms(x, p["input_norm"], cfg.rms_norm_eps)
-    ua = u * cfg.attention_in_multiplier
-    q = _mm(ua, p["q_w"]).reshape(R, W, nh, hd)
-    k = (_mm(ua, p["k_w"]) * cfg.key_multiplier).reshape(R, W, kvh, hd)
-    v = _mm(ua, p["v_w"]).reshape(R, W, kvh, hd)
-    ctx = attend(_rope(q, pos, cfg.rope_theta).astype(wd),
-                 _rope(k, pos, cfg.rope_theta).astype(wd), v.astype(wd))
-    a = _mm(ctx.reshape(R, W, nh * hd), p["o_w"]) * \
-        cfg.attention_out_multiplier
+    a = _attn_out(cfg, p, attend(*_qkv(cfg, p, u, pos)))
     y, state = _ssm_branch(cfg, p, u, state, valid)
-    x = x + a + y
-    v2 = _rms(x, p["ff_norm"], cfg.rms_norm_eps)
-    g0, g1 = cfg.mlp_multipliers
-    m = _mm(v2, p["up_w"]) * jax.nn.silu(_mm(v2, p["gate_w"]) * g0)
-    return x + _mm(m, p["down_w"]) * g1, state
+    x = _join(x, a, y)
+    return _mlp(cfg, p, x, _rms(x, p["ff_norm"], cfg.rms_norm_eps)), state
 
 
 BLOCK_KEYS = ("input_norm", "q_w", "k_w", "v_w", "o_w", "in_w", "conv_w",

@@ -36,6 +36,7 @@ from ..framework import dtype as dtype_mod
 from ..kernels.pallas.rmsnorm import rms_norm
 from ..nn import functional as F
 from ..nn.layer.moe import moe_held_experts_mlp
+from ..observability.trace.parts import part
 from ..serving.served_model import ServedModel
 from .falcon_h1 import F32, _mm, _Weights
 from .reference.laguna import rope_of
@@ -162,6 +163,7 @@ MOE_KEYS = ATTN_KEYS + ("router", "experts_gate", "experts_up",
 _EXPERT_TILING = (128, 512, 512)
 
 
+@part("mlp")
 def _swiglu(u, gate, up, down):
     return _mm(jax.nn.silu(_mm(u, gate)) * _mm(u, up), down)
 
@@ -171,6 +173,7 @@ def _swiglu(u, gate, up, down):
 # lowered once a program, not once a call (2.4 s of a program's lowering
 # before: PERF.md section 6, PR 34); XLA inlines the calls
 
+@part("norm")
 @functools.partial(jax.jit, static_argnames=("eps",))
 def _norm(x, w, eps):
     return rms_norm(x, w.astype(F32), eps)
@@ -198,30 +201,42 @@ def _rope(x, pos, inv_freq, dim, scale):
                             x[..., dim:]], -1)
 
 
-def block_fn(cfg: LagunaConfig, p, x, pos, attend, valid):
-    """One block. ``x`` [R, W, h], the float32 residual stream; ``pos`` [R,
-    W] global positions; ``attend(q, k, v) -> ctx``: causal attention of the
-    window's queries ``q`` [R, W, H_l, d] given the window's own keys and
-    values [R, W, G, d], within the layer's window if ``attend.kind`` is
-    sliding; ``valid`` [R, W] bool or None (every position real). The head
-    count is the query projection's; a dense layer's ``p`` holds ``gate_w``,
-    a sparse layer's ``router``. Returns ``(x, stats)``: the expert layer's
-    routed-pair counts, ``None`` for a dense layer."""
-    R, W, _ = x.shape
+# The parts of the block (``observability.trace.parts``) sit on helpers, so
+# that ``block_fn``, which every window program traces once a layer, stays
+# short; ``_experts`` is ``router`` and its grouped matmuls ``experts``
+# (``moe_held_experts_mlp``).
+
+@part("attn_proj")
+def _qkvg(cfg: LagunaConfig, p, u, pos, kind):
+    """The layer's roped queries and keys, its values (in the weights'
+    dtype) and its gate a head [R, W, H] of the normed input ``u``; the head
+    count is the query projection's."""
+    R, W, _ = u.shape
     G, d = cfg.num_key_value_heads, cfg.head_dim
     H = p["q"].shape[-1] // d
-    eps, wd = cfg.rms_norm_eps, p["q"].dtype
+    wd = p["q"].dtype
     inv, dim, fac = rope_of(as_dict(cfg),
-                            SLIDING if attend.kind == "window" else FULL)
-    u = _norm(x, p["input_norm"], eps)
+                            SLIDING if kind == "window" else FULL)
     q = _rope(_mm(u, p["q"]).reshape(R, W, H, d), pos, inv, dim, fac)
     k = _rope(_mm(u, p["k"]).reshape(R, W, G, d), pos, inv, dim, fac)
     v = _mm(u, p["v"]).reshape(R, W, G, d)
     gate = jax.nn.sigmoid(_mm(u, p["g"]))                      # [R, W, H]
-    ctx = attend(q.astype(wd), k.astype(wd), v.astype(wd))     # [R, W, H, d]
-    ctx = ctx.astype(F32) * gate[..., None]
-    x = x + _mm(ctx.reshape(R, W, H * d), p["o"])
-    u = _norm(x, p["post_attn_norm"], eps)
+    return q.astype(wd), k.astype(wd), v.astype(wd), gate
+
+
+@part("attn_proj")
+def _attn_out(p, x, ctx, gate):
+    R, W = ctx.shape[:2]
+    ctx = ctx.astype(F32) * gate[..., None]                    # [R, W, H, d]
+    return x + _mm(ctx.reshape(R, W, -1), p["o"])
+
+
+@part("mlp")
+def _ffn(cfg: LagunaConfig, p, x, u, valid):
+    """The layer's MLP on the normed stream ``u`` — dense, or the routed
+    experts (``router`` / ``experts`` inside it) beside the shared one —
+    onto the stream. Returns ``(x, stats)``."""
+    R, W, _ = x.shape
     if "gate_w" in p:
         return x + _swiglu(u, p["gate_w"], p["up_w"], p["down_w"]), None
     routed, stats = _experts(
@@ -231,6 +246,22 @@ def block_fn(cfg: LagunaConfig, p, x, pos, attend, valid):
         top_k=cfg.num_experts_per_tok, scale=cfg.moe_routed_scaling_factor)
     return x + routed.reshape(R, W, -1) + _swiglu(
         u, p["shared_gate"], p["shared_up"], p["shared_down"]), stats
+
+
+def block_fn(cfg: LagunaConfig, p, x, pos, attend, valid):
+    """One block. ``x`` [R, W, h], the float32 residual stream; ``pos`` [R,
+    W] global positions; ``attend(q, k, v) -> ctx``: causal attention of the
+    window's queries ``q`` [R, W, H_l, d] given the window's own keys and
+    values [R, W, G, d], within the layer's window if ``attend.kind`` is
+    sliding; ``valid`` [R, W] bool or None (every position real). The head
+    count is the query projection's; a dense layer's ``p`` holds ``gate_w``,
+    a sparse layer's ``router``. Returns ``(x, stats)``: the expert layer's
+    routed-pair counts, ``None`` for a dense layer."""
+    eps = cfg.rms_norm_eps
+    q, k, v, gate = _qkvg(cfg, p, _norm(x, p["input_norm"], eps), pos,
+                          attend.kind)
+    x = _attn_out(p, x, attend(q, k, v), gate)
+    return _ffn(cfg, p, x, _norm(x, p["post_attn_norm"], eps), valid)
 
 
 def attn_scale(cfg: LagunaConfig) -> float:

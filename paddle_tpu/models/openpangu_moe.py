@@ -37,6 +37,7 @@ from ..core.dispatch import primitive
 from ..framework import dtype as dtype_mod
 from ..nn import functional as F
 from ..nn.layer.moe import moe_held_experts_mlp
+from ..observability.trace.parts import part
 from ..serving.served_model import ServedModel
 from .falcon_h1 import F32, _mm, _rms, _rope, _Weights
 
@@ -147,26 +148,21 @@ MOE_KEYS = ATTN_KEYS + ("router", "experts_gate", "experts_up",
                         "shared_down")
 
 
-def _swiglu(u, gate, up, down):
-    return _mm(jax.nn.silu(_mm(u, gate)) * _mm(u, up), down)
+# The parts of the block (``observability.trace.parts``) sit on helpers, so
+# that ``block_fn``, which every window program traces once a layer, stays
+# short; a norm inside one of them is ``norm`` (the innermost part owns).
 
-
-def block_fn(cfg: OpenPanguMoEConfig, p, x, pos, attend, valid):
-    """One block. ``x`` [R, W, h], the float32 residual stream; ``pos`` [R,
-    W] global positions; ``attend(q_lat, q_rope, row) -> ctx``: causal
-    absorbed attention of the window's queries (``q_lat`` [R, W, H,
-    kv_lora_rank], ``q_rope`` [R, W, H, qk_rope_head_dim]) given the
-    window's own cache rows ``row`` [R, W, latent_dim], returning each head's
-    weighted sum of ``c_kv`` [R, W, H, kv_lora_rank]; ``valid`` [R, W] bool
-    or None (every position real). A dense layer's ``p`` holds ``gate_w``,
-    an expert layer's ``router``. Returns ``(x, stats)``: the expert layer's
-    routed-pair counts, ``None`` for a dense layer."""
-    R, W, _ = x.shape
+@part("attn_proj")
+def _mla_in(cfg: OpenPanguMoEConfig, p, u, pos):
+    """Latent attention's projections of the normed input ``u``: the
+    absorbed queries ``q_lat`` [R, W, H, kv_lora_rank], their rotary half
+    ``q_rope``, the window's own cache rows ``row`` [R, W, latent_dim] and
+    the KV up-projection by head (its value half comes after ``attend``)."""
+    R, W, _ = u.shape
     H, dn, dr, dv, dc = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
                          cfg.qk_rope_head_dim, cfg.v_head_dim,
                          cfg.kv_lora_rank)
     eps, wd = cfg.rms_norm_eps, p["q_b"].dtype
-    u = _rms(x, p["input_norm"], eps)
     c_q = _rms(_mm(u, p["q_a"]), p["q_a_norm"], eps)
     q = _mm(c_q, p["q_b"]).reshape(R, W, H, dn + dr)
     q_rope = _rope(q[..., dn:], pos, cfg.rope_theta)
@@ -179,25 +175,63 @@ def block_fn(cfg: OpenPanguMoEConfig, p, x, pos, attend, valid):
     kv_b = p["kv_b"].reshape(dc, H, dn + dv)
     q_lat = jnp.einsum("rwhn,chn->rwhc", q[..., :dn].astype(wd),
                        kv_b[..., :dn], preferred_element_type=F32)
-    ctx = attend(q_lat.astype(wd), q_rope.astype(wd), row)   # [R, W, H, dc]
-    o = jnp.einsum("rwhc,chv->rwhv", ctx.astype(wd), kv_b[..., dn:],
+    return q_lat.astype(wd), q_rope.astype(wd), row, kv_b
+
+
+@part("attn_proj")
+def _mla_out(cfg: OpenPanguMoEConfig, p, x, ctx, kv_b):
+    """The context ``ctx`` [R, W, H, kv_lora_rank] through the value half of
+    the KV up-projection and the output projection, onto the stream."""
+    R, W, H = ctx.shape[:3]
+    o = jnp.einsum("rwhc,chv->rwhv", ctx.astype(kv_b.dtype),
+                   kv_b[..., cfg.qk_nope_head_dim:],
                    preferred_element_type=F32)
-    a = _mm(o.reshape(R, W, H * dv), p["o"])
-    x = x + _rms(a, p["post_attn_norm"], eps)
-    v = _rms(x, p["pre_mlp_norm"], eps)
+    a = _mm(o.reshape(R, W, H * cfg.v_head_dim), p["o"])
+    return x + _rms(a, p["post_attn_norm"], cfg.rms_norm_eps)
+
+
+@part("mlp")
+def _swiglu(u, gate, up, down):
+    return _mm(jax.nn.silu(_mm(u, gate)) * _mm(u, up), down)
+
+
+@part("mlp")
+def _ffn(cfg: OpenPanguMoEConfig, p, x, v, valid):
+    """The layer's MLP on the normed stream ``v`` — dense, or this chip's
+    share of the routed experts (``router`` / ``experts`` inside it) beside
+    the shared one — onto the stream. Returns ``(x, stats)``."""
+    R, W, _ = x.shape
     if "gate_w" in p:
         m, stats = _swiglu(v, p["gate_w"], p["up_w"], p["down_w"]), None
     else:
         flat = v.reshape(R * W, -1)
         routed, stats = moe_held_experts_mlp(
-            flat.astype(wd), p["router"], p["experts_gate"], p["experts_up"],
-            p["experts_down"], top_k=cfg.num_experts_per_tok,
-            first=cfg.held_experts_first, score="sigmoid",
-            norm_topk=cfg.norm_topk_prob, scale=cfg.routed_scaling_factor,
+            flat.astype(p["q_b"].dtype), p["router"], p["experts_gate"],
+            p["experts_up"], p["experts_down"],
+            top_k=cfg.num_experts_per_tok, first=cfg.held_experts_first,
+            score="sigmoid", norm_topk=cfg.norm_topk_prob,
+            scale=cfg.routed_scaling_factor,
             valid=None if valid is None else valid.reshape(R * W))
         m = routed.reshape(R, W, -1) + _swiglu(
             v, p["shared_gate"], p["shared_up"], p["shared_down"])
-    return x + _rms(m, p["post_mlp_norm"], eps), stats
+    return x + _rms(m, p["post_mlp_norm"], cfg.rms_norm_eps), stats
+
+
+def block_fn(cfg: OpenPanguMoEConfig, p, x, pos, attend, valid):
+    """One block. ``x`` [R, W, h], the float32 residual stream; ``pos`` [R,
+    W] global positions; ``attend(q_lat, q_rope, row) -> ctx``: causal
+    absorbed attention of the window's queries (``q_lat`` [R, W, H,
+    kv_lora_rank], ``q_rope`` [R, W, H, qk_rope_head_dim]) given the
+    window's own cache rows ``row`` [R, W, latent_dim], returning each head's
+    weighted sum of ``c_kv`` [R, W, H, kv_lora_rank]; ``valid`` [R, W] bool
+    or None (every position real). A dense layer's ``p`` holds ``gate_w``,
+    an expert layer's ``router``. Returns ``(x, stats)``: the expert layer's
+    routed-pair counts, ``None`` for a dense layer."""
+    eps = cfg.rms_norm_eps
+    q_lat, q_rope, row, kv_b = _mla_in(
+        cfg, p, _rms(x, p["input_norm"], eps), pos)
+    x = _mla_out(cfg, p, x, attend(q_lat, q_rope, row), kv_b)
+    return _ffn(cfg, p, x, _rms(x, p["pre_mlp_norm"], eps), valid)
 
 
 def attn_scale(cfg: OpenPanguMoEConfig) -> float:

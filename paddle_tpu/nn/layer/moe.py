@@ -25,6 +25,7 @@ from jax.sharding import PartitionSpec as P
 
 from ...core.dispatch import primitive
 from ...core.tensor import Tensor
+from ...observability.trace.parts import part
 from .layers import Layer
 from .. import initializer as I
 
@@ -366,6 +367,7 @@ def _moe_mlp_gmm(x, wg, w_gate, w_up, w_down, *, top_k):
 _SHARE_TILING = (128, 512, 1024)
 
 
+@part("router")
 def moe_held_experts_mlp(x, wr, w_gate, w_up, w_down, *, top_k, first,
                          score="sigmoid", norm_topk=True, scale=1.0,
                          valid=None, tiling=_SHARE_TILING, x_route=None):
@@ -389,8 +391,21 @@ def moe_held_experts_mlp(x, wr, w_gate, w_up, w_down, *, top_k, first,
     ``(y [n, h] float32, stats)``, ``stats`` int32 scalars: ``pairs`` (routed
     pairs of real tokens), ``held`` (those that met a held expert),
     ``experts_hit`` (held experts that got a row: those whose weights the
-    call streams)."""
+    call streams).
+
+    In a device trace (``observability.trace.parts``) the three grouped
+    matmuls are the ``experts`` part of the step and everything else here —
+    scores, top-k, the sort and gather into their layout, the casts and the
+    activation between them, the weighted combine back — its ``router``."""
     from ...kernels.grouped_matmul import grouped_matmul
+
+    def gmm(lhs, rhs):
+        # the kernel's call alone is ``experts``: it hands back what it
+        # accumulated, and the cast to the rows' dtype is out here
+        with part("experts"):
+            out = grouped_matmul(lhs, rhs, group_sizes, tiling=tiling,
+                                 out_dtype=jnp.float32)
+        return out.astype(lhs.dtype)
 
     n, h = x.shape
     count = w_gate.shape[0]
@@ -411,11 +426,9 @@ def moe_held_experts_mlp(x, wr, w_gate, w_up, w_down, *, top_k, first,
     group_sizes = jnp.bincount(key, length=count + 1)[:count]
 
     xs = jnp.take(x, order // top_k, axis=0)                  # [kn, h]
-    g_proj = grouped_matmul(xs, w_gate, group_sizes, tiling=tiling)
-    u_proj = grouped_matmul(xs, w_up, group_sizes, tiling=tiling)
+    g_proj, u_proj = gmm(xs, w_gate), gmm(xs, w_up)
     act = jax.nn.silu(g_proj.astype(jnp.float32)) * u_proj
-    ys = grouped_matmul(act.astype(x.dtype), w_down, group_sizes,
-                        tiling=tiling)                        # [kn, h]
+    ys = gmm(act.astype(x.dtype), w_down)                     # [kn, h]
     # rows past the groups are whatever the kernel left there: select, never
     # multiply
     y_tok = jnp.where(held[:, :, None],

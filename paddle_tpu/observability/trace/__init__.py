@@ -1,13 +1,18 @@
 """paddle_tpu.observability.trace — device-truth tracing.
 
-Three layers on top of the PR-4 telemetry hub (see docs/observability.md,
+Layers on top of the PR-4 telemetry hub (see docs/observability.md,
 "Device-truth tracing"):
 
 - **XPlane ingestion** (``capture_steps`` / ``xplane``): capture a
   ``jax.profiler`` trace around a step window, read its ``.xplane.pb``,
   correlate device events back to StepTimeline steps/phases — real
   ``device_compute_us`` (every mode), a top-k device op table, and
-  host/device overlap efficiency;
+  host/device overlap efficiency, and device time by part of a served
+  model step (``by_part``; ``tools/program_parts.py`` on a file);
+- **parts** (``part``, ``PARTS``): the one vocabulary of
+  ``jax.named_scope`` names (``pt.norm``, ``pt.attn_proj``, ...) the engine
+  and the served blocks put on their work, so that a device trace says
+  which part of the model asked for each op;
 - **spans** (``span``): the program's one span primitive — a
   ``jax.profiler.TraceAnnotation`` in whatever profiler trace is running,
   and a row in the tracer's worker ring (``pt.serve.*``, ``pt.train.*``);
@@ -26,6 +31,7 @@ from .capture import (  # noqa: F401
     StepTraceCapture, capture_steps, device_trace_provider, last_correlation,
 )
 from .flight import FlightRecorder, dump_bundle, flight_recorder  # noqa: F401
+from .parts import PARTS, part  # noqa: F401
 from .request_trace import RequestTracer, span, tracer  # noqa: F401
 from .xplane import (  # noqa: F401
     CorrelatedTrace, correlate, correlate_logdir, find_xplane, read_xplane,
@@ -36,5 +42,5 @@ __all__ = [
     "device_trace_provider", "CorrelatedTrace", "correlate",
     "correlate_logdir", "find_xplane", "read_xplane", "span",
     "RequestTracer", "tracer", "FlightRecorder", "flight_recorder",
-    "dump_bundle",
+    "dump_bundle", "PARTS", "part",
 ]

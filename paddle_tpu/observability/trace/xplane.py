@@ -1,9 +1,11 @@
 """XPlane ingestion: device truth for the step timeline.
 
 ``jax.profiler.start_trace`` writes ``plugins/profile/<ts>/*.xplane.pb``
-under its log directory; ``jax.profiler.ProfileData`` reads it with nothing
-but JAX: planes, their lines, and events with a start, a duration and their
-stats. What this layer needs from it:
+under its log directory; ``jax.profiler.ProfileData`` reads the host planes
+with nothing but JAX (planes, their lines, and events with a start, a
+duration and their stats), ``xspace.read_planes`` the device planes with
+what the profiler keeps in an event's METADATA (``tf_op``: the name stack;
+``program_id``). What this layer needs from it:
 
 - the program's own spans on the host plane: ``StepTimeline`` brackets every
   step and phase with ``trace.span`` — ``pt.train.step`` (its ``step_num``
@@ -13,9 +15,10 @@ stats. What this layer needs from it:
   ``/device:TPU:<n>`` plane, one event per executed HLO instruction, NESTED
   where an instruction contains others (a ``while`` spans its body), named
   by the instruction's whole HLO text (``%fusion.3 = bf16[...] fusion(...)``;
-  the op's name is what stands before `` = ``). CPU backend: events that
-  carry an ``hlo_op`` stat, on the ``tf_XLA*`` executor threads of the host
-  plane.
+  the op's name is what stands before `` = ``), and the ``XLA Modules``
+  line, one event per program run (``jit_pt_window1(<program id>)``). CPU
+  backend: events that carry an ``hlo_op`` stat, on the ``tf_XLA*``
+  executor threads of the host plane.
 
 ``correlate`` assigns device events to step windows by time (host and device
 share the trace clock), unions overlapping intervals per line so nested
@@ -23,7 +26,11 @@ spans never double-count, gives the op table each op's SELF time (its
 interval less what its children cover), and splits each step's device time
 into *exposed* (overlapping a ``device_block``/``stream_wait``/``data_wait``
 host span — the host was waiting for it) vs *hidden* (overlapped by useful
-host work) — the device-truth ``overlap_efficiency``.
+host work) — the device-truth ``overlap_efficiency``. And it splits the
+device's self time BY PART of a served model step (``by_part``): the
+programs say which part of the model asked for each op
+(``observability.trace.parts``), so the table says where a window program's
+milliseconds go in the model's own words, program by program.
 """
 from __future__ import annotations
 
@@ -33,6 +40,9 @@ import os
 import re
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
+from . import xspace
+from .parts import part_of
+
 __all__ = ["find_xplane", "read_xplane", "correlate", "correlate_logdir",
            "CorrelatedTrace", "TraceEvent"]
 
@@ -41,8 +51,10 @@ PHASE_PREFIX = "pt.train."
 # blocking host phases: device time under these was NOT hidden behind
 # useful host work (stall, not overlap)
 _BLOCKING_PHASES = ("device_block", "stream_wait", "data_wait")
-_DEVICE_PLANE = re.compile(r"^/device:(TPU|GPU):\d+")
+_DEVICE_PLANE = xspace.DEVICE_PLANE
 _OPS_LINE = "XLA Ops"
+_MODULES_LINE = "XLA Modules"
+UNSCOPED = "unscoped"
 
 
 class TraceEvent(NamedTuple):
@@ -63,26 +75,125 @@ def find_xplane(logdir: str) -> Optional[str]:
 
 def read_xplane(path: str) -> List[TraceEvent]:
     """The events this layer reads, out of one ``.xplane.pb``: the
-    ``pt.train.*`` spans, the ops of the device planes, and host-plane
-    events with an ``hlo_op`` stat (the CPU backend's device events)."""
+    ``pt.train.*`` spans, host-plane events with an ``hlo_op`` stat (the CPU
+    backend's device events), and the device planes' ops and program runs,
+    each with its instruction's stats (shared by the events of one
+    instruction: do not write to them)."""
     from jax.profiler import ProfileData
 
     out: List[TraceEvent] = []
     for plane in ProfileData.from_file(path).planes:
-        device = bool(_DEVICE_PLANE.match(plane.name))
+        if _DEVICE_PLANE.match(plane.name):
+            continue
         for line in plane.lines:
-            if device and line.name != _OPS_LINE:
-                continue
             for e in line.events:
                 name = e.name
-                stats = {} if device else dict(e.stats)
-                if not (device or name.startswith(PHASE_PREFIX)
-                        or "hlo_op" in stats):
+                stats = dict(e.stats)
+                if not (name.startswith(PHASE_PREFIX) or "hlo_op" in stats):
                     continue
                 out.append(TraceEvent(plane.name, line.name, name,
                                       e.start_ns / 1e3, e.duration_ns / 1e3,
                                       stats))
+    for pname, plane in xspace.read_planes(
+            path, lines=(_OPS_LINE, _MODULES_LINE)).items():
+        for lname, evs in plane["lines"].items():
+            for mid, t0, t1 in evs:
+                md = plane["metadata"].get(mid) or {"name": str(mid),
+                                                    "stats": {}}
+                out.append(TraceEvent(pname, lname, md["name"], t0 / 1e3,
+                                      (t1 - t0) / 1e3, md["stats"]))
     return out
+
+
+_OPCODE = re.compile(r" [a-z][a-z0-9\-]*\(")
+
+
+def _op_and_shape(name: str) -> Tuple[str, str]:
+    """``%fusion.3 = bf16[640,18432]{1,0:T(8,128)} fusion(...)`` ->
+    ``("fusion.3", "bf16[640,18432]")``."""
+    head, _, rest = name.partition(" = ")
+    m = _OPCODE.search(rest)
+    shape = re.sub(r"\{[^}]*\}", "", rest[:m.start()]) if m else ""
+    return head.lstrip("%"), shape
+
+
+def _own_part(stats: Dict[str, Any]) -> Optional[str]:
+    """The part an instruction's own name stack gives it. A fusion that XLA
+    made of ops of several name stacks lists them all (``a;b``): the first
+    that names a part says it."""
+    for stack in str(stats.get("tf_op", "")).split(";"):
+        part = part_of(stack.rstrip(":"))
+        if part:
+            return part
+    return None
+
+
+def by_part(ops: Sequence[TraceEvent], own_us: Sequence[float],
+            modules: Sequence[TraceEvent], top: int = 5) -> Dict[str, Any]:
+    """Device self time by part of the model step, over all the ops and per
+    program. ``ops`` are the events of ONE device's ops line, ``own_us``
+    their self times, ``modules`` its program runs.
+
+    An op belongs to the innermost ``pt.<part>`` of its own name stack
+    (``_own_part``). An op with no name of its own (a layout copy the
+    compiler put in, the wait for an asynchronous slice) belongs with the
+    next op of the same program run that has one — the compiler schedules
+    such an op where its consumer needs it — else with the one before it;
+    an op of a program that names no part at all is ``unscoped``. A run is
+    an ``XLA Modules`` event; without that line (an old trace) all the ops
+    of one ``program_id`` count as one run."""
+    runs = sorted((m.ts, m.ts + m.dur, m.name) for m in modules)
+    starts = [r[0] for r in runs]
+
+    def run_of(e: TraceEvent):
+        i = bisect.bisect_right(starts, e.ts) - 1
+        if i >= 0 and e.ts < runs[i][1]:
+            return i
+        return "program " + str(e.stats.get("program_id", "?"))
+
+    groups: Dict[Any, List[int]] = {}
+    for i in sorted(range(len(ops)), key=lambda i: ops[i].ts):
+        groups.setdefault(run_of(ops[i]), []).append(i)
+    progs: Dict[str, Dict[str, Any]] = {}
+    for key, idx in groups.items():
+        name = re.sub(r"\(\d+\)$", "", runs[key][2]) \
+            if isinstance(key, int) else key
+        parts = [_own_part(ops[i].stats) for i in idx]
+        nxt = None
+        for k in range(len(idx) - 1, -1, -1):       # the next that has one
+            nxt = parts[k] or nxt
+            parts[k] = nxt
+        prev = None
+        for k in range(len(idx)):                   # else the one before
+            prev = parts[k] or prev
+            parts[k] = prev or UNSCOPED
+        row = progs.setdefault(name, {"calls": 0, "device_us": 0.0,
+                                      "parts": {}, "ops": {}})
+        row["calls"] += 1
+        for i, part in zip(idx, parts):
+            us = own_us[i]
+            row["device_us"] += us
+            row["parts"][part] = row["parts"].get(part, 0.0) + us
+            cell = row["ops"].setdefault(part, {}).setdefault(
+                _op_and_shape(ops[i].name), [0, 0.0])
+            cell[0] += 1
+            cell[1] += us
+    total: Dict[str, float] = {}
+    for row in progs.values():
+        for part, us in row["parts"].items():
+            total[part] = total.get(part, 0.0) + us
+        row["top_ops"] = {
+            part: [{"op": op, "shape": shape, "calls": c,
+                    "us": round(us, 1)} for (op, shape), (c, us) in
+                   sorted(table.items(), key=lambda kv: -kv[1][1])[:top]]
+            for part, table in row.pop("ops").items()}
+        row["device_us"] = round(row["device_us"], 1)
+        row["parts"] = {k: round(v, 1) for k, v in sorted(
+            row["parts"].items(), key=lambda kv: -kv[1])}
+    return {"parts": {k: round(v, 1) for k, v in sorted(
+                total.items(), key=lambda kv: -kv[1])},
+            "device_us": round(sum(total.values()), 1),
+            "programs": progs}
 
 
 def _overlap_us(intervals: List[Tuple[float, float]],
@@ -115,18 +226,43 @@ def _self_us(events: Sequence[TraceEvent]) -> List[float]:
     return [max(v, 0.0) for v in own]
 
 
+def _add_parts(total: Optional[Dict[str, Any]], one: Dict[str, Any]
+               ) -> Dict[str, Any]:
+    """``by_part`` of one more device added to the others' (several chips
+    run the same programs: times and calls add, a program's widest ops are
+    the first device's)."""
+    if total is None:
+        return one
+    for part, us in one["parts"].items():
+        total["parts"][part] = round(total["parts"].get(part, 0.0) + us, 1)
+    total["device_us"] = round(total["device_us"] + one["device_us"], 1)
+    for name, row in one["programs"].items():
+        have = total["programs"].setdefault(name, row)
+        if have is not row:
+            have["calls"] += row["calls"]
+            have["device_us"] = round(have["device_us"] + row["device_us"], 1)
+            for part, us in row["parts"].items():
+                have["parts"][part] = round(
+                    have["parts"].get(part, 0.0) + us, 1)
+    return total
+
+
 class CorrelatedTrace:
     """The parsed + correlated view of one capture: per-step device time,
     per-phase attribution, and the device op table."""
 
     def __init__(self, steps: List[Dict], op_table: List[Dict],
                  unattributed_device_us: float, device_threads: List[str],
-                 source: Optional[str] = None):
+                 source: Optional[str] = None,
+                 by_part: Optional[Dict[str, Any]] = None):
         self.steps = steps
         self.op_table = op_table
         self.unattributed_device_us = unattributed_device_us
         self.device_threads = device_threads
         self.source = source
+        # device self time by part of a served model step and by program
+        # (``by_part``); None for a trace with no device ops line
+        self.by_part = by_part
 
     @property
     def steps_correlated(self) -> int:
@@ -159,6 +295,13 @@ class CorrelatedTrace:
             "unattributed_device_us": round(self.unattributed_device_us, 1),
             "device_threads": self.device_threads[:8],
             "op_table": self.op_table[:top],
+            "by_part": None if self.by_part is None else {
+                "parts": self.by_part["parts"],
+                "device_us": self.by_part["device_us"],
+                "programs": {name: {k: row[k] for k in
+                                    ("calls", "device_us", "parts")}
+                             for name, row in
+                             self.by_part["programs"].items()}},
             "steps": [
                 {k: (round(v, 1) if isinstance(v, float) else v)
                  for k, v in s.items() if k != "window"}
@@ -167,13 +310,16 @@ class CorrelatedTrace:
         }
 
 
-def correlate(events: Sequence[TraceEvent],
-              source: Optional[str] = None) -> CorrelatedTrace:
+def correlate(events: Sequence[TraceEvent], source: Optional[str] = None,
+              top_ops: int = 5) -> CorrelatedTrace:
     """Correlate one trace's events (``read_xplane``): device events ->
-    ``pt.train.step`` / ``pt.train.<phase>`` windows by time."""
+    ``pt.train.step`` / ``pt.train.<phase>`` windows by time, and device
+    self time by part of a served step (``by_part``, its ``top_ops`` widest
+    ops a part)."""
     steps: List[Dict] = []
     phase_spans: List[Tuple[str, float, float]] = []  # (name, t0, t1)
     by_line: Dict[Tuple[str, str], List[TraceEvent]] = {}
+    modules: Dict[str, List[TraceEvent]] = {}
     for e in events:
         if e.name == STEP_SPAN:
             steps.append({"step": e.stats.get("step_num"),
@@ -181,6 +327,8 @@ def correlate(events: Sequence[TraceEvent],
         elif e.name.startswith(PHASE_PREFIX):
             phase_spans.append((e.name[len(PHASE_PREFIX):], e.ts,
                                 e.ts + e.dur))
+        elif e.line == _MODULES_LINE and _DEVICE_PLANE.match(e.plane):
+            modules.setdefault(e.plane, []).append(e)
         elif e.dur > 0.01:
             by_line.setdefault((e.plane, e.line), []).append(e)
     steps.sort(key=lambda s: s["window"][0])
@@ -190,8 +338,13 @@ def correlate(events: Sequence[TraceEvent],
 
     # op table: self time by op (a ``while`` owns only its own overhead)
     agg: Dict[Tuple[str, str], List[float]] = {}
-    for evs in by_line.values():
-        for e, own in zip(evs, _self_us(evs)):
+    parts: Optional[Dict[str, Any]] = None
+    for (plane, line), evs in by_line.items():
+        own_us = _self_us(evs)
+        if line == _OPS_LINE and _DEVICE_PLANE.match(plane):
+            parts = _add_parts(parts, by_part(
+                evs, own_us, modules.get(plane, ()), top=top_ops))
+        for e, own in zip(evs, own_us):
             op = e.name.split(" = ", 1)[0].lstrip("%")
             row = agg.setdefault((op, str(e.stats.get("hlo_module", ""))),
                                  [0, 0.0])
@@ -261,7 +414,7 @@ def correlate(events: Sequence[TraceEvent],
 
     dev_threads = sorted({f"{plane}/{line}" for plane, line in by_line})
     return CorrelatedTrace(steps, op_table, unattributed, dev_threads,
-                           source=source)
+                           source=source, by_part=parts)
 
 
 def correlate_logdir(logdir: str) -> CorrelatedTrace:

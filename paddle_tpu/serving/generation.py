@@ -38,6 +38,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
+from ..observability.trace.parts import part
 from ..observability.trace.request_trace import span
 from .base import (BadRequest, DeadlineExceeded, EngineBase, EngineClosed,
                    _oom_guard, _tracer)
@@ -289,6 +290,78 @@ def _build_decode_step(cfg, max_slots: int, max_len: int, donate: bool,
         step, donate_argnums=(1, 2) if donate else (), label=label)
 
 
+class _InParts:
+    """A served model as a window program calls it, every call under its
+    part of the step (``observability.trace.parts``): ``embed`` and ``head``
+    under theirs, and the engine's own ``attend`` handed to ``block`` under
+    ``cache_write`` — the rows' scatter into the arenas; the attention
+    callables inside it are ``attention`` themselves, and the innermost
+    part owns an op. What a block does between is the block's to name.
+    Wrapped here, once a build, so that the traced ``step`` bodies hold no
+    line for it."""
+
+    def __init__(self, sm: ServedModel):
+        self._sm = sm
+        self.embed = part("embed")(sm.embed)
+        self.head = part("head")(sm.head)
+
+    def __getattr__(self, name):
+        return getattr(self._sm, name)
+
+    def block(self, p, x, pos, attend, state, valid):
+        return self._sm.block(p, x, pos, part("cache_write")(attend), state,
+                              valid)
+
+
+@part("head")
+def _pick(logits):
+    """The greedy pick at every position and its behavior logprob — the
+    post-training ledger rides it (f32: bf16 logits renormalize poorly and
+    these numbers cross processes)."""
+    import jax
+    import jax.numpy as jnp
+
+    nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    lf = logits.astype(jnp.float32)
+    return nxt, (jnp.max(lf, axis=-1) -
+                 jax.scipy.special.logsumexp(lf, axis=-1))
+
+
+@part("head")
+def _last_real(x, n_valid, W: Optional[int] = None):
+    """The stream at the last real position of each row's (first ``W``)
+    tokens, ``[rows, 1, h]``: a prefill computes its head there alone — an
+    admission reads nothing else, and a 256 x 261120 float32 logits tensor
+    is 267 MB."""
+    import jax.numpy as jnp
+
+    last = jnp.maximum(n_valid - 1, 0)[:, None, None]
+    return jnp.take_along_axis(x if W is None else x[:, :W], last, axis=1)
+
+
+def _latent_query(pad: int):
+    """``(q_lat, q_rope) -> q``: every head of a token as one slab against
+    the latent arena's rows, padded with ``pad`` zeros to whole lanes."""
+    import jax.numpy as jnp
+
+    @part("attention")
+    def latent_query(q_lat, q_rope):
+        return jnp.concatenate(
+            [q_lat, q_rope, jnp.zeros(q_lat.shape[:-1] + (pad,),
+                                      q_lat.dtype)], -1)
+
+    return latent_query
+
+
+def _program_name(label: str, carries: bool = False) -> str:
+    """A window program's jitted name, from the label it already has
+    (``serving:<engine>:prefill2048`` -> ``pt_prefill2048``, ``_carry`` where
+    it carries a round): what a device trace's ``XLA Modules`` line calls
+    it, ``jit_pt_prefill2048_carry``."""
+    tail = "".join(c if c.isalnum() else "_" for c in label.rsplit(":", 1)[-1])
+    return f"pt_{tail}" + ("_carry" if carries else "")
+
+
 def _attention(sm, attends: Optional[Dict], name: str):
     """The jitted attention callable ``name`` of a window program of ``sm``:
     ``"paged"`` (K/V arenas), ``"latent"``, or the ``"full"`` / ``"window"``
@@ -303,12 +376,16 @@ def _attention(sm, attends: Optional[Dict], name: str):
     the program that carries a round finds the round's shape traced by the
     decode program (tracing a Pallas kernel's body is most of a window
     program's build: 0.7–1.4 s a kernel and shape on the chip's host,
-    PERF.md section 2). ``None``: the program keeps its own."""
+    PERF.md section 2). ``None``: the program keeps its own. Every call of
+    the callable is the ``attention`` part of the step
+    (``observability.trace.parts``): the kernel, and in the program that
+    carries a round the slices and the join around its two calls."""
     import jax
 
     if attends is not None and name in attends:
         return attends[name]
     scale = sm.attn_scale
+    attention = part("attention")
     if name == "paged":
         from ..kernels.pallas.paged_attention import paged_attention
 
@@ -316,7 +393,7 @@ def _attention(sm, attends: Optional[Dict], name: str):
         def paged_attend(q, kk, vv, tables, pos):
             return paged_attention(q, kk, vv, tables, pos, scale=scale)
 
-        fn = paged_attend
+        fn = attention(paged_attend)
     elif name == "latent":
         from ..kernels.pallas.mla_paged_attention import mla_paged_attention
 
@@ -327,7 +404,7 @@ def _attention(sm, attends: Optional[Dict], name: str):
             return mla_paged_attention(q, arena, tables, lengths, dv=dv,
                                        scale=scale)
 
-        fn = latent_attend
+        fn = attention(latent_attend)
     else:
         from ..kernels.pallas.ranged_paged_attention import \
             ranged_paged_attention
@@ -339,7 +416,7 @@ def _attention(sm, attends: Optional[Dict], name: str):
             return ranged_paged_attention(q, kk, vv, table, lengths,
                                           window=window, scale=scale)
 
-        fn = ranged_attend
+        fn = attention(ranged_attend)
     if attends is not None:
         attends[name] = fn
     return fn
@@ -412,15 +489,19 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     ``attends``: the dict an engine's programs share their jitted attention
     callables in (``_attention``).
 
-    Keep ``step`` and the closures in it SHORT: whatever is added to their
-    bodies, dead or not, slows the trace of every program built from them
-    (+ 0.4–1.0 s a program on the chip's host for sixty dead lines: PERF.md
-    section 6, PR 36) — which is why the carried step is not a branch here.
+    The parts of the step (``observability.trace.parts``: what a device
+    trace says of this program) are set OUTSIDE ``step``'s body — the
+    protocol's methods once a build (``_InParts``), the attention callables
+    (``_attention``), the helpers above — so that the body reads as the
+    model step and nothing else. (PR 36 measured sixty dead lines in ``step``
+    as + 0.4–1.0 s a program's trace on the chip's host and made that a rule;
+    the cause was where the Python stack's 16 KiB chunks ended, which any
+    change of a frame's size moves: ``persistent_cache.in_one_stack_chunk``,
+    under which every program is built, PERF.md section 6, PR 37.)
     """
-    import jax
     import jax.numpy as jnp
 
-    sm = _served(served)
+    sm = _InParts(_served(served))
     if carry:
         return _build_carrying_step(sm, int(carry), max_slots, n_blocks,
                                     page_len, window, donate, label, prefill,
@@ -446,6 +527,7 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
         dl = sm.cache_spec["dim"]
         DL = latent_width(dl)
         latent_attend = _attention(sm, attends, "latent")
+        latent_query = _latent_query(DL - dl)
     elif by_layer:
         kinds = list(sm.cache_spec["layers"])
         ranged = {kind: _attention(sm, attends, kind)
@@ -453,33 +535,32 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     else:
         paged_attend = _attention(sm, attends, "paged")
 
+    @part("cache_write")
+    def pages_of(table, blk):
+        # write positions: page-table lookup of each window token's block;
+        # blocks past the table (or past a request's allocation: table
+        # entry 0) land in the scratch page — never another slot's pages
+        pidx = jnp.take_along_axis(table, jnp.minimum(blk, B - 1), axis=1)
+        return jnp.where(blk < B, pidx, 0)                         # [S, W]
+
     def step(params, k_arenas, v_arenas, tables, tokens, lengths,
              n_valid=None, state=None):
         # tables: [S, B] page ids; tokens: [S, W]; lengths: [S] (int32)
         P = k_arenas[0].shape[0]
         pos = lengths[:, None] + jnp.arange(W)                     # [S, W]
         x = sm.embed(params, tokens, pos)                          # [S, W, h]
-        # write positions: page-table lookup of each window token's block;
-        # blocks past the table (or past a request's allocation: table
-        # entry 0) land in the scratch page — never another slot's pages
         blk = pos // PL
-
-        def pages_of(table):
-            pidx = jnp.take_along_axis(table, jnp.minimum(blk, B - 1),
-                                       axis=1)
-            return jnp.where(blk < B, pidx, 0)                     # [S, W]
-
         if by_layer:
             # a page is [kv heads, PL, dim]: token (page, offset) of head g
             # is row (page * kvh + g) * PL + offset of the flattened arena
             by_kind = {kind: tables[i] for i, kind in
                        enumerate(("full", "window"))}
-            flat_of = {kind: (((pages_of(t)[..., None] * kvh
+            flat_of = {kind: (((pages_of(t, blk)[..., None] * kvh
                                 + jnp.arange(kvh)) * PL
                                + (pos % PL)[..., None]).reshape(-1))
                        for kind, t in by_kind.items()}             # [S*W*kvh]
         else:
-            flat = (pages_of(tables) * PL + pos % PL).reshape(-1)  # [S*W]
+            flat = (pages_of(tables, blk) * PL + pos % PL).reshape(-1)
         valid = None if n_valid is None else \
             jnp.arange(W)[None, :] < n_valid[:, None]              # [S, W]
         new_k, new_v, new_state, counted = [], [], [], []
@@ -494,10 +575,8 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
                 arena = kc.reshape(P * PL, DL).at[flat].set(
                     jnp.pad(row, lanes).reshape(S * W, DL)).reshape(P, PL, DL)
                 new_k.append(arena)
-                q = jnp.concatenate(
-                    [q_lat, q_rope, jnp.zeros(q_lat.shape[:-1] + (DL - dl,),
-                                              q_lat.dtype)], -1)
-                return latent_attend(q, arena, tables, lengths)
+                return latent_attend(latent_query(q_lat, q_rope), arena,
+                                     tables, lengths)
 
             def attend(q, k1, v1):
                 kk = kc.reshape(P * PL, kvh, hd).at[flat].set(
@@ -531,17 +610,8 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
             if counter_names and len(out) > 2 and out[2] is not None:
                 counted.append(out[2])
         if prefill:
-            # the head at the last real position only
-            last = jnp.maximum(n_valid - 1, 0)[:, None, None]
-            x = jnp.take_along_axis(x, last, axis=1)               # [S, 1, h]
-        logits = sm.head(params, x)                            # [S, W, vocab]
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        # behavior logprob of the greedy pick at every window position —
-        # the post-training ledger rides it (f32: bf16 logits renormalize
-        # poorly and these numbers cross processes)
-        lf = logits.astype(jnp.float32)
-        logp = (jnp.max(lf, axis=-1) -
-                jax.scipy.special.logsumexp(lf, axis=-1))      # [S, W] f32
+            x = _last_real(x, n_valid)                             # [S, 1, h]
+        nxt, logp = _pick(sm.head(params, x))       # [S, W], [S, W] f32
         out = (nxt, logp, new_k, new_v, new_state if stateful else None)
         if not counter_names:
             return out
@@ -550,6 +620,7 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
                        for name in counter_names if counted},)
 
     donate_argnums = (1, 2, 7) if stateful else (1, 2)
+    step.__name__ = _program_name(label)
 
     from ..jit import persistent_cache
 
@@ -585,7 +656,6 @@ def _build_carrying_step(sm: ServedModel, carry: int, rows: int,
     callable, ``_attention``) and joins the results. The head runs on ``1 +
     R`` rows. A round row that is idle has ``n_valid`` 0 and an all-zero
     table: it costs its grid step and no bytes."""
-    import jax
     import jax.numpy as jnp
 
     if not (prefill and rows == 1 and sm.carries_rounds):
@@ -602,11 +672,13 @@ def _build_carrying_step(sm: ServedModel, carry: int, rows: int,
         dl = sm.cache_spec["dim"]
         DL = latent_width(dl)
         latent_attend = _attention(sm, attends, "latent")
+        latent_query = _latent_query(DL - dl)
     else:
         kinds = list(sm.cache_spec["layers"])
         ranged = {kind: _attention(sm, attends, kind)
                   for kind in sorted(set(kinds))}
 
+    @part("cache_write")
     def pages_of(table, pos):
         # page-table lookup of each token's block; blocks past the table (or
         # past a request's allocation: entry 0) land in the scratch page
@@ -621,6 +693,7 @@ def _build_carrying_step(sm: ServedModel, carry: int, rows: int,
         return (((pages_of(table, pos)[..., None] * kvh + jnp.arange(kvh))
                  * PL + (pos % PL)[..., None]).reshape(-1))
 
+    @part("attention")
     def both(kernel, q, chunk, round_):
         """The chunk's queries ``q[:, :W]`` against the prompt's ``(table,
         start)``, the round's, one a row, against theirs; joined as the
@@ -659,11 +732,9 @@ def _build_carrying_step(sm: ServedModel, carry: int, rows: int,
                 arena = kc.reshape(P * PL, DL).at[flat].set(
                     jnp.pad(row, lanes).reshape(N, DL)).reshape(P, PL, DL)
                 new_k.append(arena)
-                q = jnp.concatenate(
-                    [q_lat, q_rope, jnp.zeros(q_lat.shape[:-1] + (DL - dl,),
-                                              q_lat.dtype)], -1)
                 return both(lambda q, t, at: latent_attend(q, arena, t, at),
-                            q, (tables, lengths), (r_tables, r_lengths))
+                            latent_query(q_lat, q_rope), (tables, lengths),
+                            (r_tables, r_lengths))
 
             kind = None if latent else kinds[li]
 
@@ -688,13 +759,8 @@ def _build_carrying_step(sm: ServedModel, carry: int, rows: int,
                 counted.append(out[2])
         # the head at the prompt's last real position and at every row of
         # the round: [1, 1 + R, h]
-        last = jnp.maximum(n_valid - 1, 0)[:, None, None]
-        x = jnp.concatenate(
-            [jnp.take_along_axis(x[:, :W], last, axis=1), x[:, W:]], 1)
-        logits = sm.head(params, x)
-        lf = logits.astype(jnp.float32)
-        nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        logp = jnp.max(lf, axis=-1) - jax.scipy.special.logsumexp(lf, axis=-1)
+        x = jnp.concatenate([_last_real(x, n_valid, W), x[:, W:]], 1)
+        nxt, logp = _pick(sm.head(params, x))
         # ([1, 1], [R, 1]): the prompt's, then the round's
         nxt, logp = ((a[:, :1], a[0, 1:, None]) for a in (nxt, logp))
         out = (nxt, logp, new_k, new_v, None)
@@ -703,6 +769,8 @@ def _build_carrying_step(sm: ServedModel, carry: int, rows: int,
         return out + ({name: sum((c[name] for c in counted[1:]),
                                  counted[0][name])
                        for name in counter_names if counted},)
+
+    step.__name__ = _program_name(label, carries=True)
 
     from ..jit import persistent_cache
 
@@ -1032,7 +1100,15 @@ class GenerationEngine(EngineBase):
         speculative verify, every one-row prefill bucket, the token feed,
         draft steps) against the scratch page — a warm replica restarting
         under the persistent cache loads them all from disk with zero fresh
-        XLA compiles."""
+        XLA compiles. What is left of such a start is Python, tracing and
+        lowering each program, so the whole of it runs in one chunk of the
+        interpreter's stack (``persistent_cache.in_one_stack_chunk``: 6 s
+        where it took 8 to 18, PERF.md section 6, PR 37)."""
+        from ..jit import persistent_cache
+
+        return persistent_cache.in_one_stack_chunk(self._warmup)
+
+    def _warmup(self):
         import jax
         import jax.numpy as jnp
 
@@ -1103,7 +1179,6 @@ class GenerationEngine(EngineBase):
             # garbage rows are overwritten at the first real admit
             for b in self.config.prefill_buckets:
                 self._draft_prefill(0, np.zeros(b, dtype=np.int64))
-        self.metrics.inc("warmup_runs")
         return self
 
     def _tables_shape(self, rows: int) -> Tuple[int, ...]:

@@ -66,6 +66,14 @@ contains no model. A model that can be served implements
   whole arenas between two layouts; once it moves onto the ranged kernel's
   layout (ROADMAP S2) it inherits the carried step through this property.
 
+A block names the PARTS of its work (``observability.trace.parts``: ``norm``,
+``attn_proj``, ``mlp``, and ``router`` / ``experts`` / ``mixer`` where it has
+them) by decorating its helpers, residual adds included; the engine names
+``embed``, ``cache_write`` (the ``attend`` it hands a block), ``attention``
+and ``head`` itself. A device trace then says where a window program's time
+goes in the model's own words (``tools/program_parts.py``), and
+``tests/test_step_parts.py`` holds every served model to it.
+
 A model with recurrent state cannot use what assumes a cache is pages of
 K/V (the prefix trie, speculative verify, KV-page export/install), a latent
 cache cannot yet use what moves K/V pages (export/install and its wire
@@ -78,6 +86,8 @@ from __future__ import annotations
 
 import math
 from typing import Any, Dict, Optional, Tuple
+
+from ..observability.trace.parts import part
 
 __all__ = ["ServedModel", "GPTServed", "flatten_params", "nest_params"]
 
@@ -187,6 +197,7 @@ class GPTServed(ServedModel):
                 for L in g.layers],
         }
 
+    @part("norm")
     def _ln(self, x, w, b):
         import jax
         import jax.numpy as jnp
@@ -201,19 +212,33 @@ class GPTServed(ServedModel):
         pos_idx = jnp.minimum(pos, params["pos"].shape[0] - 1)
         return params["embed"][tokens] + params["pos"][pos_idx]
 
-    def block(self, p, x, pos, attend, state, valid):
+    # the parts of the block (``observability.trace.parts``) sit on helpers,
+    # so that ``block``, which a window program traces once a layer, stays
+    # short
+
+    @part("attn_proj")
+    def _qkv(self, p, h1):
+        S, W = h1.shape[0], h1.shape[1]
+        qkv = (h1 @ p["qkv_w"] + p["qkv_b"]).reshape(
+            S, W, 3, self.num_heads, self.head_dim)
+        return qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
+
+    @part("attn_proj")
+    def _attn_out(self, p, x, ctx):
+        ctx = ctx.reshape(ctx.shape[0], ctx.shape[1], -1)
+        return x + (ctx @ p["out_w"] + p["out_b"])
+
+    @part("mlp")
+    def _mlp(self, p, x, h2):
         import jax
 
-        S, W = x.shape[0], x.shape[1]
-        nh, hd = self.num_heads, self.head_dim
-        h1 = self._ln(x, p["ln1_w"], p["ln1_b"])
-        qkv = (h1 @ p["qkv_w"] + p["qkv_b"]).reshape(S, W, 3, nh, hd)
-        ctx = attend(qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2])
-        ctx = ctx.reshape(S, W, nh * hd)
-        x = x + (ctx @ p["out_w"] + p["out_b"])
-        h2 = self._ln(x, p["ln2_w"], p["ln2_b"])
         m = jax.nn.gelu(h2 @ p["fc_in_w"] + p["fc_in_b"], approximate=True)
-        return x + (m @ p["fc_out_w"] + p["fc_out_b"]), None
+        return x + (m @ p["fc_out_w"] + p["fc_out_b"])
+
+    def block(self, p, x, pos, attend, state, valid):
+        x = self._attn_out(p, x, attend(*self._qkv(
+            p, self._ln(x, p["ln1_w"], p["ln1_b"]))))
+        return self._mlp(p, x, self._ln(x, p["ln2_w"], p["ln2_b"])), None
 
     def head(self, params, x):
         xf = self._ln(x, params["lnf_w"], params["lnf_b"])

@@ -71,3 +71,88 @@ def test_second_process_hits_the_env_dir(tmp_path):
     d2, hits2 = _run(env)
     assert d1 == d2 == str(tmp_path / "cc")
     assert hits1 == 0 and hits2 > 0
+
+
+# -- a build runs in one chunk of the interpreter's stack ----------------------
+
+def test_a_build_runs_under_one_stack_chunk(monkeypatch, tmp_path):
+    """``CachedJit._build`` traces and lowers under
+    ``in_one_stack_chunk``: a frame whose size makes CPython hand it a chunk
+    with room for every frame of the trace above it."""
+    assert pcache.in_one_stack_chunk.__code__.co_stacksize > 1 << 16
+    assert pcache.in_one_stack_chunk(lambda a, b=2: (a, b), 1, b=5) == (1, 5)
+    seen = []
+    real = pcache.in_one_stack_chunk
+
+    def spy(fn, *args, **kwargs):
+        seen.append(getattr(fn, "__name__", ""))
+        return real(fn, *args, **kwargs)
+
+    monkeypatch.setattr(pcache, "in_one_stack_chunk", spy)
+    prior_dir, prior_on = pcache._STATE.dir, pcache._STATE.enabled
+    try:
+        pcache.enable(str(tmp_path / "c"))
+        f = pcache.cached_jit(lambda x: x * 2 + 1, label="one_chunk")
+        assert float(f(jax.numpy.ones(()))) == 3.0
+        assert float(f(jax.numpy.ones(()))) == 3.0     # no second build
+    finally:
+        pcache._STATE.dir, pcache._STATE.enabled = prior_dir, prior_on
+    assert len(seen) == 1 and "lower" in seen[0]
+
+
+def test_an_engines_warmup_runs_under_one_stack_chunk(monkeypatch):
+    """``GenerationEngine.warmup()`` builds its programs under the helper
+    whichever cache is on (the benchmark runs with JAX's own, where
+    ``CachedJit`` builds nothing itself)."""
+    import paddle_tpu as paddle
+    from paddle_tpu import serving
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+
+    seen = []
+    real = pcache.in_one_stack_chunk
+
+    def spy(fn, *args, **kwargs):
+        seen.append(fn.__name__)
+        return real(fn, *args, **kwargs)
+
+    monkeypatch.setattr(pcache, "in_one_stack_chunk", spy)
+    paddle.seed(0)
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=32, hidden_size=32, num_hidden_layers=1,
+        num_attention_heads=2, max_position_embeddings=64,
+        intermediate_size=64))
+    eng = serving.GenerationEngine(model, serving.GenerationConfig(
+        max_slots=2, max_seq_len=32, prefill_buckets=(8,)))
+    assert eng.warmup() is eng
+    assert seen[0] == "_warmup" and len(eng._windows) == 2
+    eng.close()
+
+
+def test_no_call_depth_is_a_hundred_times_slower_under_one_chunk():
+    """The pathology the helper is for, where this interpreter has it
+    (CPython 3.11 on: a call site at the end of a 16 KiB data-stack chunk
+    maps and unmaps a chunk on every call): some recursion depth makes a
+    loop of trivial calls tens of times slower; under
+    ``in_one_stack_chunk`` none does."""
+    import statistics
+    import time
+
+    def leaf():
+        return 1
+
+    def hot(n):
+        t = time.perf_counter()
+        for _ in range(n):
+            leaf()
+        return time.perf_counter() - t
+
+    def rec(d, n):
+        return hot(n) if d == 0 else rec(d - 1, n)
+
+    depths = range(0, 360)
+    plain = [rec(d, 20000) for d in depths]
+    under = [pcache.in_one_stack_chunk(rec, d, 20000) for d in depths]
+    med = statistics.median(plain)
+    assert max(under) < 10 * med, (max(under), med)
+    if max(plain) > 30 * med:        # this interpreter thrashes somewhere
+        assert max(under) < max(plain) / 5

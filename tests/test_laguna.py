@@ -209,6 +209,11 @@ def test_chunked_prefill_across_the_window_then_decode_past_three_windows(
     assert c["window_pages_released_total"] > 40
     w = st["kv_pages"]["window"]
     assert w["pages_live"] == 0 and st["kv_pages"]["pages_live"] == 0
+    # the two gauges of a cache of two layer kinds (docs/serving.md)
+    assert st["kv_pages_live_by_kind"] == {"full": 0, "window": 0}
+    by_kind = st["kv_pool_bytes_by_kind"]
+    assert by_kind["full"] > by_kind["window"] > 0
+    assert by_kind["full"] + by_kind["window"] == st["kv_pool_bytes"]
     assert w["alloc_total"] == w["free_total"]
     # a slot never held more than its chunk's bound: 3 slots at a time
     assert w["pages_peak"] <= 3 * window_page_bound(8, 16, 4)

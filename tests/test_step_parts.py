@@ -1,0 +1,190 @@
+"""The parts of a served model step (``observability.trace.parts``): every
+window program of the four served models names the part of the model that
+asked for each piece of work, the names change nothing but metadata, and the
+programs tell themselves apart by name."""
+import re
+
+import jax
+import pytest
+
+import paddle_tpu as paddle
+from paddle_tpu import serving
+from paddle_tpu.models import (FalconH1Config, FalconH1ForCausalLM, GPTConfig,
+                               GPTForCausalLM, LagunaConfig,
+                               LagunaForCausalLM, OpenPanguMoEConfig,
+                               OpenPanguMoEForCausalLM)
+from paddle_tpu.observability.trace import parts
+
+MODELS = {
+    "gpt2": (GPTForCausalLM, lambda: GPTConfig(
+        vocab_size=64, hidden_size=32, num_hidden_layers=2,
+        num_attention_heads=4, intermediate_size=64,
+        max_position_embeddings=64, hidden_dropout_prob=0.0,
+        attention_probs_dropout_prob=0.0)),
+    "falcon_h1": (FalconH1ForCausalLM, FalconH1Config.tiny),
+    "openpangu": (OpenPanguMoEForCausalLM, OpenPanguMoEConfig.tiny),
+    "laguna": (LagunaForCausalLM, LagunaConfig.tiny),
+}
+# decode, the largest prefill bucket (which carries the round where the
+# model's ``carries_rounds``), and a smaller bucket
+PROGRAMS = {"decode": (3, 1, False), "prefill_largest": (1, 16, True),
+            "prefill_small": (1, 8, True)}
+HEAVY = ("dot_general", "scatter", "custom_call", "reduce", "sort")
+
+
+def lowered_programs(name):
+    """``{program: jax Lowered}`` of a tiny engine of model ``name``: the
+    window programs ``warmup()`` builds, each lowered from the operands of
+    its first call."""
+    cls, cfg = MODELS[name]
+    paddle.seed(3)
+    model = cls(cfg())
+    model.eval()
+    eng = serving.GenerationEngine(model, serving.GenerationConfig(
+        max_slots=3, max_seq_len=64, page_len=4, prefill_buckets=(8, 16),
+        prefix_cache=False))
+    seen, window = {}, eng._window
+
+    def recording(rows, W, prefill=False):
+        fn = window(rows, W, prefill)
+
+        def call(*args):
+            seen.setdefault((rows, W, prefill), (fn, jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args)))
+            return fn(*args)
+
+        return call
+
+    eng._window = recording
+    eng.warmup()
+    del eng._window
+    return eng, {prog: seen[key][0].lower(*seen[key][1])
+                 for prog, key in PROGRAMS.items()}
+
+
+@pytest.fixture(scope="module")
+def lowered():
+    cache = {}
+
+    def get(name):
+        if name not in cache:
+            cache[name] = lowered_programs(name)
+        return cache[name]
+
+    return get
+
+
+_LOC_DEF = re.compile(r'^(#loc\d+) = loc\((.*)\)$')
+_LOC_REF = re.compile(r'loc\((#loc\d+)\)\s*$')
+_NAME = re.compile(r'^"([^"]*)"')
+
+
+def ops_with_name_stacks(text):
+    """``[(op, name stack)]`` of a lowered module printed with its debug
+    info. An op's location is a ``#loc`` alias whose definition starts with
+    the jaxpr name stack in quotes; the ops of a private function (an inner
+    ``jax.jit``) carry their stack from the function down, and the ``call``
+    the rest — XLA joins the two when it inlines the call, and so does
+    this, once a call site."""
+    defs = {m.group(1): m.group(2) for line in text.splitlines()
+            if (m := _LOC_DEF.match(line))}
+
+    def stack(ref):
+        for _ in range(8):       # an alias of an alias: callsite / fused
+            body = defs.get(ref, "")
+            if (m := _NAME.match(body)):
+                return m.group(1)
+            nxt = re.search(r"#loc\d+", body)
+            if not nxt:
+                return ""
+            ref = nxt.group(0)
+        return ""
+
+    funcs, cur = {}, None
+    for line in text.splitlines():
+        if (m := re.match(r"\s*func\.func \w+ @(\w+)\(", line)):
+            cur = funcs.setdefault(m.group(1), [])
+            continue
+        ref = _LOC_REF.search(line)
+        if cur is None or not ref:
+            continue
+        if (m := re.search(r"\bcall @(\w+)\(", line)):
+            cur.append(("call", m.group(1), stack(ref.group(1))))
+        elif (m := re.search(r"\b(?:stablehlo|chlo|mhlo)\.([a-z_]+)", line)):
+            cur.append(("op", m.group(1), stack(ref.group(1))))
+
+    out = []
+
+    def walk(fn, prefix):
+        for kind, name, own in funcs[fn]:
+            full = "/".join(s for s in (prefix, own) if s)
+            if kind == "call":
+                walk(name, full)
+            else:
+                out.append((name, full))
+
+    walk("main", "")
+    return out
+
+
+@pytest.mark.parametrize("program", list(PROGRAMS))
+@pytest.mark.parametrize("model", list(MODELS))
+def test_every_heavy_op_sits_under_a_part(lowered, model, program):
+    _eng, progs = lowered(model)
+    ops = ops_with_name_stacks(progs[program].as_text(debug_info=True))
+    heavy = [(op, st) for op, st in ops if op in HEAVY]
+    assert len(heavy) > 10
+    bare = [(op, st) for op, st in heavy if parts.part_of(st) is None]
+    assert not bare, bare[:5]
+    used = {parts.part_of(st) for _op, st in heavy}
+    want = {"attn_proj", "cache_write", "norm", "head"}
+    want |= {"mixer"} if model == "falcon_h1" else set()
+    want |= {"router", "experts"} if model in ("openpangu", "laguna") \
+        else set()
+    assert want <= used <= set(parts.PARTS), used
+
+
+def _strip(text):
+    return re.sub(r"module @\S+", "module @m", text)
+
+
+@pytest.mark.parametrize("model", list(MODELS))
+def test_the_parts_are_names_and_nothing_else(lowered, model, monkeypatch):
+    """With the scopes off, every program lowers to the same text letter
+    for letter (locations and the module's name apart)."""
+    _eng, scoped = lowered(model)
+    # both forms of the helper, wherever it was imported to, open no scope
+    monkeypatch.setattr(parts.part, "__enter__", lambda self: None)
+    monkeypatch.setattr(parts.part, "__exit__", lambda self, *exc: False)
+    jax.clear_caches()      # a jitted helper keeps the jaxpr it traced
+    try:
+        _eng2, plain = lowered_programs(model)
+    finally:
+        jax.clear_caches()
+    named = re.compile(r"pt\.(%s)\b" % "|".join(parts.PARTS))
+    for prog in PROGRAMS:
+        assert named.search(scoped[prog].as_text(debug_info=True))
+        assert not named.search(plain[prog].as_text(debug_info=True))
+        assert _strip(scoped[prog].as_text()) == _strip(plain[prog].as_text())
+
+
+def test_a_name_outside_the_vocabulary_raises():
+    with pytest.raises(ValueError, match="not a part"):
+        parts.part("softmax")
+    with pytest.raises(ValueError, match="not a part"):
+        parts.part("pt.norm")
+    assert parts.part_of("jit(pt_window1)/pt.attn_proj/pt.norm/mul") == "norm"
+    assert parts.part_of("jit(pt_window1)/pt.nothing/mul") is None
+    assert parts.part_of("") is None
+
+
+@pytest.mark.parametrize("model", list(MODELS))
+def test_window_programs_have_distinct_names(lowered, model):
+    eng, progs = lowered(model)
+    names = [re.search(r"module @(\S+)", p.as_text()).group(1)
+             for p in progs.values()]
+    assert len(set(names)) == len(names), names
+    assert all(n.startswith("jit_pt_") for n in names), names
+    carries = eng._sm.carries_rounds
+    assert names[1].endswith("_carry") == carries
+    assert "jit_pt_window1" in names and "jit_pt_prefill8" in names

@@ -36,28 +36,38 @@ def _pb(field, value):
 
 
 def _xspace(planes):
-    """``{plane: {line: [(name, start_us, dur_us, {stat: value})]}}`` as the
-    bytes of an XSpace (XPlane: name=2 lines=3 event_metadata=4
-    stat_metadata=5; XLine: name=2 events=4; XEvent: metadata_id=1
-    offset_ps=2 duration_ps=3 stats=4; XStat: metadata_id=1 int64_value=4
-    str_value=5; the metadata maps: key=1 value=2, value: id=1 name=2)."""
+    """``{plane: {line: [(name, start_us, dur_us, {stat: value}[, {stat of
+    the event's METADATA: value}])]}}`` as the bytes of an XSpace (XPlane:
+    name=2 lines=3 event_metadata=4 stat_metadata=5; XLine: name=2 events=4;
+    XEvent: metadata_id=1 offset_ps=2 duration_ps=3 stats=4; XStat:
+    metadata_id=1 int64_value=4 str_value=5; the metadata maps: key=1
+    value=2, value: id=1 name=2, an event's metadata also stats=5). Events
+    of one name with different metadata stats are different instructions."""
     space = b""
     for pname, lines in planes.items():
         names, stat_names, body = {}, {}, _pb(2, pname)
+
+        def stat(k, v):
+            sid = stat_names.setdefault(k, len(stat_names) + 1)
+            return _pb(1, sid) + _pb(4 if isinstance(v, int) else 5, v)
+
         for lname, events in lines.items():
             line = _pb(2, lname)
-            for name, ts, dur, stats in events:
-                ev = _pb(1, names.setdefault(name, len(names) + 1)) \
+            for name, ts, dur, stats, *md in events:
+                key = (name, json.dumps(md))
+                ev = _pb(1, names.setdefault(key, len(names) + 1)) \
                     + _pb(2, int(ts * 1e6)) + _pb(3, int(dur * 1e6))
                 for k, v in stats.items():
-                    sid = stat_names.setdefault(k, len(stat_names) + 1)
-                    ev += _pb(4, _pb(1, sid) + _pb(4 if isinstance(v, int)
-                                                   else 5, v))
+                    ev += _pb(4, stat(k, v))
                 line += _pb(4, ev)
             body += _pb(3, line)
-        for field, table in ((4, names), (5, stat_names)):
-            for n, i in table.items():
-                body += _pb(field, _pb(1, i) + _pb(2, _pb(1, i) + _pb(2, n)))
+        for (n, md), i in names.items():
+            meta = _pb(1, i) + _pb(2, n)
+            for k, v in (json.loads(md) or [{}])[0].items():
+                meta += _pb(5, stat(k, v))
+            body += _pb(4, _pb(1, i) + _pb(2, meta))
+        for n, i in stat_names.items():
+            body += _pb(5, _pb(1, i) + _pb(2, _pb(1, i) + _pb(2, n)))
         space += _pb(1, body)
     return space
 
@@ -132,6 +142,83 @@ def test_synthetic_trace_parse_and_correlate(tmp_path):
                                   "/host:CPU/tf_XLAEigen/2"]
     # summary is JSON-able and carries the op table + digest
     json.dumps(cor.summary())
+
+
+def test_device_time_by_part_of_a_served_step(tmp_path):
+    """``by_part``: an op belongs to the innermost ``pt.<part>`` of the name
+    stack in its METADATA (two programs share the instruction name
+    ``fusion.1``), an op with none to the next of its run that has one, else
+    the one before; a ``while`` owns only its self time; a run that names
+    nothing is unscoped."""
+    T = "bf16[4,8]{1,0:T(8,128)(2,1)}"
+
+    def op(short, ts, dur, tf_op=None, prog=5):
+        md = {"program_id": prog}
+        if tf_op:
+            md["tf_op"] = f"jit(pt_window1)/{tf_op}:"
+        return (f"%{short} = {T} fusion({T} %p.1)", ts, dur, {}, md)
+
+    tr = {"/host:CPU": {"python3": [("pt.train.step", 0, 10, {})]},
+          "/device:TPU:0": {
+        "XLA Modules": [("jit_pt_window1(5)", 100, 100, {}),
+                        ("jit_pt_window1(5)", 300, 100, {}),
+                        ("jit_pt_prefill8_carry(6)", 500, 100, {}),
+                        ("jit_step(7)", 700, 50, {})],
+        "XLA Ops": [
+            op("copy.1", 100, 10),                       # -> attn_proj
+            op("fusion.1", 110, 30, "pt.attn_proj/dot_general"),
+            op("while.2", 140, 40, "pt.mixer/while"),
+            op("fusion.3", 150, 10, "pt.mixer/while/body/pt.norm/mul"),
+            op("copy.4", 180, 20),       # the last: the one before, norm
+            op("fusion.1", 310, 30, "pt.attn_proj/dot_general"),
+            op("fusion.1", 500, 60, "pt.cache_write/pt.attention/"
+                                    "jit(paged_attend)/pallas_call", prog=6),
+            op("fusion.9", 560, 40, "pt.cache_write/reshape;jit(x)/"
+                                    "pt.attn_proj/squeeze", prog=6),
+            op("fusion.1", 700, 50, prog=7)]}}           # the draft's step
+    p = tmp_path / "plugins" / "profile" / "t" / "vm.xplane.pb"
+    p.parent.mkdir(parents=True)
+    p.write_bytes(_xspace(tr))
+    cor = otrace.correlate_logdir(str(tmp_path))
+    bp = cor.by_part
+    assert bp["parts"] == {"attn_proj": 70.0, "attention": 60.0,
+                           "unscoped": 50.0, "cache_write": 40.0,
+                           "mixer": 30.0, "norm": 30.0}
+    assert bp["device_us"] == pytest.approx(280.0)
+    w1 = bp["programs"]["jit_pt_window1"]
+    assert w1["calls"] == 2 and w1["device_us"] == pytest.approx(130.0)
+    assert w1["parts"] == {"attn_proj": 70.0, "mixer": 30.0, "norm": 30.0}
+    assert w1["top_ops"]["attn_proj"][0] == {
+        "op": "fusion.1", "shape": "bf16[4,8]", "calls": 2, "us": 60.0}
+    carry = bp["programs"]["jit_pt_prefill8_carry"]
+    assert carry["parts"] == {"attention": 60.0, "cache_write": 40.0}
+    assert bp["programs"]["jit_step"]["parts"] == {"unscoped": 50.0}
+    digest = cor.summary()["by_part"]
+    assert digest["parts"] == bp["parts"]
+    assert "top_ops" not in digest["programs"]["jit_pt_window1"]
+    json.dumps(cor.summary())
+    # the operator's tool prints the same table
+    import importlib.util
+
+    spec = importlib.util.spec_from_file_location(
+        "program_parts", os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "tools", "program_parts.py"))
+    tool = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(tool)
+    text = tool.render(bp)
+    assert "jit_pt_window1: 2 calls, 0.065 ms a call" in text
+    assert "jit_pt_prefill8_carry: 1 calls" in text and "unscoped" in text
+    assert tool.find(str(tmp_path)) == str(p)
+
+
+def test_a_trace_without_a_device_ops_line_has_no_by_part(tmp_path):
+    tr = _synthetic_trace()
+    del tr["/device:TPU:0"]
+    p = tmp_path / "plugins" / "profile" / "t" / "vm.xplane.pb"
+    p.parent.mkdir(parents=True)
+    p.write_bytes(_xspace(tr))
+    cor = otrace.correlate_logdir(str(tmp_path))
+    assert cor.by_part is None and cor.summary()["by_part"] is None
 
 
 def test_find_xplane_empty_and_step_order_without_the_stat(tmp_path):
@@ -532,6 +619,50 @@ def test_flight_recorder_fault_burst_and_events(tmp_path):
     rec.record_event("stream_retry", direction="h2d", group=0)
     assert rec.snapshot()["events"][-1]["kind"] == "stream_retry"
     rec.detach()
+
+
+def test_record_serving_step_lands_in_the_events_ring(tmp_path):
+    """What the generation worker calls once a decode round: the step is in
+    the events ring with its engine, kind, time and rows, memory-stamped
+    like a train step, and a stamper that fails costs the stamp, not the
+    round."""
+    stamps = iter([{"in_use": 10, "watermark": 12}, None])
+    rec = otrace.FlightRecorder(auto_dump=False, dump_dir=str(tmp_path),
+                                timeline_obj=StepTimeline(),
+                                mem_stamp_fn=lambda: next(stamps))
+    rec.record_serving_step("gen", "decode", 12.3456, 7)
+    rec.record_serving_step("gen", "decode", 1.0, 0)    # no stamp this time
+    rec.record_serving_step("gen", "decode", 2.0, 3)    # the stamper raises
+    evs = rec.snapshot()["events"]
+    assert [e["kind"] for e in evs] == ["serving_step"] * 3
+    assert {k: evs[0][k] for k in ("engine", "op", "ms", "n", "mem")} == {
+        "engine": "gen", "op": "decode", "ms": 12.346, "n": 7,
+        "mem": {"in_use": 10, "watermark": 12}}
+    assert "mem" not in evs[1] and "mem" not in evs[2]
+    assert evs[2]["n"] == 3 and evs[0]["t"] <= evs[2]["t"]
+
+
+def test_a_decode_round_records_its_serving_step():
+    """``GenerationEngine._read_round`` puts every decode round in the
+    process flight recorder's ring (``docs/observability.md``, "The flight
+    recorder")."""
+    from paddle_tpu.models import GPTConfig, GPTForCausalLM
+    from paddle_tpu.serving import GenerationConfig, GenerationEngine
+
+    model = GPTForCausalLM(GPTConfig(
+        vocab_size=32, hidden_size=32, num_hidden_layers=1,
+        num_attention_heads=2, max_position_embeddings=64,
+        intermediate_size=64))
+    eng = GenerationEngine(model, GenerationConfig(
+        max_slots=2, max_seq_len=32, prefill_buckets=(8,)), name="flightgen")
+    with eng:
+        out = eng.submit(np.arange(4), max_new_tokens=4).result(timeout=120)
+    assert len(out) == 8
+    steps = [e for e in otrace.flight_recorder().snapshot()["events"]
+             if e["kind"] == "serving_step" and e.get("engine") == "flightgen"]
+    assert len(steps) >= 3, steps
+    assert all(e["op"] == "decode" and e["n"] >= 1 and e["ms"] > 0
+               for e in steps)
 
 
 def test_preemption_fires_flight_callbacks():
