@@ -29,6 +29,7 @@ Greedy decoding (matching ``generate``'s argmax contract).
 """
 from __future__ import annotations
 
+import functools
 import itertools
 import math
 import time
@@ -353,6 +354,102 @@ def _latent_query(pad: int):
     return latent_query
 
 
+# -- where a window program's keys and values land ----------------------------
+# ONE set of helpers for every builder and cache kind: two granularities of one
+# write. ``write_rows`` is the scatter of single rows that every program had:
+# a decode round, a verify window, a prefill that starts or ends inside a
+# page. ``write_pages`` is a one-row prefill's whose ``W`` tokens are exactly
+# ``W / page_len`` whole, fresh pages (``_whole_pages``): XLA walks a scatter's
+# indices one by one (67 ns a 256-byte row, a fifth of Laguna's carrying call,
+# PERF.md section 6, PR 40), so it is handed one index a page, not one a
+# (token, head). All of it is the ``cache_write`` part of the step.
+
+
+def _whole_pages(rows: int, W: int, page_len: int, prefill: bool,
+                 paged: bool = False) -> int:
+    """Pages a window program writes WHOLE: ``W / page_len`` for a one-row
+    prefill of whole pages into a latent or by-layer cache, else 0 (the
+    program scatters rows). ``paged``: the ``[pages, page_len, heads, dim]``
+    arenas of GPT-2 and Falcon-H1, whose ``attend`` is not this path's. The
+    shapes alone decide; that the call STARTS on a page boundary is the
+    engine's to keep (``GenerationEngine._chunk_pages``)."""
+    return W // page_len if prefill and rows == 1 and not paged \
+        and W % page_len == 0 else 0
+
+
+@part("cache_write")
+def pages_of(table, blk):
+    """Page ids of blocks ``blk`` ``[rows, n]`` through ``table`` ``[rows,
+    B]``: blocks past the table (or past a request's allocation: table entry
+    0) land in the scratch page — never another slot's pages."""
+    import jax.numpy as jnp
+
+    B = table.shape[1]
+    pidx = jnp.take_along_axis(table, jnp.minimum(blk, B - 1), axis=1)
+    return jnp.where(blk < B, pidx, 0)
+
+
+@part("cache_write")
+def flat_rows(table, pos, PL: int):
+    """Rows of a ``[P, PL, ...]`` arena (K/V pages of whole tokens, latent
+    rows) seen flat, one a token at ``pos`` ``[rows, n]``."""
+    return (pages_of(table, pos // PL) * PL + pos % PL).reshape(-1)
+
+
+@part("cache_write")
+def flat_kv(table, pos, PL: int, kvh: int):
+    """Rows of a ``[P, kvh, PL, hd]`` arena seen flat, one a (token, K/V
+    head): a page is ``[kv heads, PL, dim]``, so token (page, offset) of
+    head g is row ``(page * kvh + g) * PL + offset``."""
+    import jax.numpy as jnp
+
+    return (((pages_of(table, pos // PL)[..., None] * kvh + jnp.arange(kvh))
+             * PL + (pos % PL)[..., None]).reshape(-1))
+
+
+@part("cache_write")
+def chunk_pages(table, lengths, n: int, PL: int):
+    """The ``n`` page ids of a one-row call that starts at ``lengths`` ``[1]``,
+    a page boundary. Blocks past the allocation are the scratch page, several
+    of a call's maybe: harmless, it is never read unmasked."""
+    import jax.numpy as jnp
+
+    return pages_of(table, lengths[:, None] // PL + jnp.arange(n)).reshape(-1)
+
+
+def chunk_where(table, lengths, pos, n_pages: int, PL: int, kvh: int = 0):
+    """Where a window's tokens land, as their write takes it: the ``n_pages``
+    page ids of a program that writes whole pages (``write_pages``), else a
+    flat row a token (``kvh`` 0: a ``[P, PL, ...]`` arena) or a (token, K/V
+    head) (``write_rows``)."""
+    if n_pages:
+        return chunk_pages(table, lengths, n_pages, PL)
+    return flat_kv(table, pos, PL, kvh) if kvh else flat_rows(table, pos, PL)
+
+
+def write_rows(arena, idx, rows, lead: int = 2):
+    """``rows`` at rows ``idx`` ``[n]`` of the arena seen flat over its first
+    ``lead`` axes: 2 for ``[P, PL, ...]`` (a row a token), 3 for ``[P, kvh,
+    PL, hd]`` (a row a (token, K/V head), ``flat_kv``)."""
+    row = arena.shape[lead:]
+    return arena.reshape((-1,) + row).at[idx].set(
+        rows.reshape((-1,) + row)).reshape(arena.shape)
+
+
+def write_pages(arena, page_ids, toks):
+    """The ``n * PL`` tokens ``toks`` of whole pages — latent rows ``[..,
+    DL]`` for an arena ``[P, PL, DL]``; keys or values ``[.., kvh, hd]``,
+    re-laid once to ``[n, kvh, PL, hd]``, for an arena ``[P, kvh, PL, hd]`` —
+    at ``page_ids`` ``[n]``: what ``write_rows`` leaves at those positions."""
+    import jax.numpy as jnp
+
+    if arena.ndim == 3:
+        return arena.at[page_ids].set(toks.reshape((-1,) + arena.shape[1:]))
+    _P, kvh, PL, hd = arena.shape
+    return arena.at[page_ids].set(
+        jnp.swapaxes(toks.reshape(-1, PL, kvh, hd), 1, 2))
+
+
 def _program_name(label: str, carries: bool = False) -> str:
     """A window program's jitted name, from the label it already has
     (``serving:<engine>:prefill2048`` -> ``pt_prefill2048``, ``_carry`` where
@@ -425,7 +522,8 @@ def _attention(sm, attends: Optional[Dict], name: str):
 def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
                        window: int, donate: bool, label: str,
                        fused: bool = True, prefill: bool = False,
-                       carry: int = 0, attends: Optional[Dict] = None):
+                       carry: int = 0, attends: Optional[Dict] = None,
+                       aligned: bool = True):
     """The PAGED executable family: embed ``W = window`` tokens per slot
     at positions ``lengths + [0..W)``, run the served model's blocks — each
     block's ``attend(q, k, v)`` writes K/V through the page tables into the
@@ -466,6 +564,14 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
       first that holds a visible key (0 in a full layer) to the row's last.
       The query's own shape says how many heads the layer has.
 
+    The window's keys and values (or latent rows) land through
+    ``write_rows``, one scatter index a token — but a ONE-ROW prefill whose
+    window is whole pages, into a latent or by-layer cache, writes them
+    through ``write_pages``, one index a page (``_whole_pages``: the shapes
+    decide). ``aligned`` is the caller's word that every prefill call starts
+    on a page boundary (``GenerationEngine._aligned``); ``False`` keeps every
+    program to rows.
+
     ``counters`` is the model's ``program_counters`` summed over the layers
     (int32 scalars).
     ``n_valid`` (``[rows]``: real tokens in each row's
@@ -505,8 +611,8 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     if carry:
         return _build_carrying_step(sm, int(carry), max_slots, n_blocks,
                                     page_len, window, donate, label, prefill,
-                                    attends)
-    kvh, hd = sm.num_kv_heads, sm.head_dim
+                                    attends, aligned)
+    kvh = sm.num_kv_heads
     stateful = sm.state_spec is not None
     cache_kind = None if sm.cache_spec is None else sm.cache_spec["kind"]
     latent = cache_kind == "latent"
@@ -516,7 +622,7 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
         raise ValueError(
             "a model with recurrent state decodes one token a round: "
             f"no {window}-token window over live state")
-    S, B, W, PL = max_slots, n_blocks, window, page_len
+    S, W, PL = max_slots, window, page_len     # (the tables say n_blocks)
     if fused is not True:
         raise ValueError(
             "fused= no longer selects a path: kernels.registry.resolve "
@@ -535,32 +641,25 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     else:
         paged_attend = _attention(sm, attends, "paged")
 
-    @part("cache_write")
-    def pages_of(table, blk):
-        # write positions: page-table lookup of each window token's block;
-        # blocks past the table (or past a request's allocation: table
-        # entry 0) land in the scratch page — never another slot's pages
-        pidx = jnp.take_along_axis(table, jnp.minimum(blk, B - 1), axis=1)
-        return jnp.where(blk < B, pidx, 0)                         # [S, W]
+    # a one-row prefill of whole pages writes them whole (``write_pages``),
+    # every other program its rows (``write_rows``)
+    n_pages = _whole_pages(S, W, PL, prefill, cache_kind is None) \
+        if aligned else 0
+    rows_of = functools.partial(write_rows, lead=3) if by_layer else write_rows
+    write = write_pages if n_pages else rows_of
 
     def step(params, k_arenas, v_arenas, tables, tokens, lengths,
              n_valid=None, state=None):
         # tables: [S, B] page ids; tokens: [S, W]; lengths: [S] (int32)
-        P = k_arenas[0].shape[0]
         pos = lengths[:, None] + jnp.arange(W)                     # [S, W]
         x = sm.embed(params, tokens, pos)                          # [S, W, h]
-        blk = pos // PL
         if by_layer:
-            # a page is [kv heads, PL, dim]: token (page, offset) of head g
-            # is row (page * kvh + g) * PL + offset of the flattened arena
             by_kind = {kind: tables[i] for i, kind in
                        enumerate(("full", "window"))}
-            flat_of = {kind: (((pages_of(t, blk)[..., None] * kvh
-                                + jnp.arange(kvh)) * PL
-                               + (pos % PL)[..., None]).reshape(-1))
-                       for kind, t in by_kind.items()}             # [S*W*kvh]
+            where_of = {kind: chunk_where(t, lengths, pos, n_pages, PL, kvh)
+                        for kind, t in by_kind.items()}
         else:
-            flat = (pages_of(tables, blk) * PL + pos % PL).reshape(-1)
+            where = chunk_where(tables, lengths, pos, n_pages, PL)
         valid = None if n_valid is None else \
             jnp.arange(W)[None, :] < n_valid[:, None]              # [S, W]
         new_k, new_v, new_state, counted = [], [], [], []
@@ -572,17 +671,14 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
                 # a token rides as one slab against the pages its row's
                 # length covers (rows and queries padded to whole lanes)
                 lanes = [(0, 0)] * (row.ndim - 1) + [(0, DL - dl)]
-                arena = kc.reshape(P * PL, DL).at[flat].set(
-                    jnp.pad(row, lanes).reshape(S * W, DL)).reshape(P, PL, DL)
+                arena = write(kc, where, jnp.pad(row, lanes))
                 new_k.append(arena)
                 return latent_attend(latent_query(q_lat, q_rope), arena,
                                      tables, lengths)
 
             def attend(q, k1, v1):
-                kk = kc.reshape(P * PL, kvh, hd).at[flat].set(
-                    k1.reshape(S * W, kvh, hd)).reshape(P, PL, kvh, hd)
-                vv = vc.reshape(P * PL, kvh, hd).at[flat].set(
-                    v1.reshape(S * W, kvh, hd)).reshape(P, PL, kvh, hd)
+                kk = write_rows(kc, where, k1)
+                vv = write_rows(vc, where, v1)
                 new_k.append(kk)
                 new_v.append(vv)
                 # key j of the slot's pages is visible iff j <= pos[s, w]
@@ -591,12 +687,8 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
             kind = kinds[li] if by_layer else None
 
             def attend_ranged(q, k1, v1):
-                Pk = kc.shape[0]
-                idx = flat_of[kind]
-                kk = kc.reshape(Pk * kvh * PL, hd).at[idx].set(
-                    k1.reshape(S * W * kvh, hd)).reshape(Pk, kvh, PL, hd)
-                vv = vc.reshape(Pk * kvh * PL, hd).at[idx].set(
-                    v1.reshape(S * W * kvh, hd)).reshape(Pk, kvh, PL, hd)
+                kk = write(kc, where_of[kind], k1)
+                vv = write(vc, where_of[kind], v1)
                 new_k.append(kk)
                 new_v.append(vv)
                 return ranged[kind](q, kk, vv, by_kind[kind], lengths)
@@ -631,7 +723,7 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
 def _build_carrying_step(sm: ServedModel, carry: int, rows: int,
                          n_blocks: int, page_len: int, window: int,
                          donate: bool, label: str, prefill: bool,
-                         attends: Optional[Dict]):
+                         attends: Optional[Dict], aligned: bool = True):
     """THE CARRIED STEP, the fourth role of the paged family: ONE program for
     a prompt's row of ``W = window`` tokens (a prefill: the head at its last
     real position) AND the ``R = carry`` rows of a decode round, so the
@@ -663,11 +755,11 @@ def _build_carrying_step(sm: ServedModel, carry: int, rows: int,
             "only a one-row prefill of a model whose cache's kernel takes "
             "each row's own range, and that keeps no recurrent state, "
             "carries a decode round (ServedModel.carries_rounds)")
-    kvh, hd = sm.num_kv_heads, sm.head_dim
+    kvh = sm.num_kv_heads
     latent = sm.cache_spec["kind"] == "latent"
     counter_names = sm.program_counters
-    R, B, W, PL = carry, n_blocks, window, page_len
-    N = W + R    # the tokens a block sees
+    R, W, PL = carry, window, page_len         # (the tables say n_blocks)
+    # a block sees N = W + R tokens
     if latent:
         dl = sm.cache_spec["dim"]
         DL = latent_width(dl)
@@ -678,20 +770,11 @@ def _build_carrying_step(sm: ServedModel, carry: int, rows: int,
         ranged = {kind: _attention(sm, attends, kind)
                   for kind in sorted(set(kinds))}
 
-    @part("cache_write")
-    def pages_of(table, pos):
-        # page-table lookup of each token's block; blocks past the table (or
-        # past a request's allocation: entry 0) land in the scratch page
-        blk = pos // PL
-        pidx = jnp.take_along_axis(table, jnp.minimum(blk, B - 1), axis=1)
-        return jnp.where(blk < B, pidx, 0)
-
-    def flat_rows(table, pos):          # a latent arena's rows
-        return (pages_of(table, pos) * PL + pos % PL).reshape(-1)
-
-    def flat_kv(table, pos):            # rows of a [P, kvh, PL, hd] arena
-        return (((pages_of(table, pos)[..., None] * kvh + jnp.arange(kvh))
-                 * PL + (pos % PL)[..., None]).reshape(-1))
+    # the chunk's tokens land as whole pages where they are whole pages, the
+    # round's always as rows: the two share no page but the scratch one
+    n_pages = _whole_pages(rows, W, PL, prefill) if aligned else 0
+    rows_of = write_rows if latent else functools.partial(write_rows, lead=3)
+    write = write_pages if n_pages else rows_of
 
     @part("attention")
     def both(kernel, q, chunk, round_):
@@ -715,22 +798,22 @@ def _build_carrying_step(sm: ServedModel, carry: int, rows: int,
             [jnp.arange(W)[None, :] < n_valid[:, None],
              (r_valid > 0)[None, :]], 1)                           # [1, N]
         if latent:
-            flat = jnp.concatenate([flat_rows(tables, pos),
-                                    flat_rows(r_tables, r_pos)])   # [N]
+            where = chunk_where(tables, lengths, pos, n_pages, PL)
+            r_where = flat_rows(r_tables, r_pos, PL)               # [R]
         else:
             by_kind = {kind: (tables[i], r_tables[i]) for i, kind in
                        enumerate(("full", "window"))}
-            flat_of = {kind: jnp.concatenate([flat_kv(t, pos),
-                                              flat_kv(rt, r_pos)])
-                       for kind, (t, rt) in by_kind.items()}       # [N*kvh]
+            where_of = {kind: (chunk_where(t, lengths, pos, n_pages, PL, kvh),
+                               flat_kv(rt, r_pos, PL, kvh))        # [R*kvh]
+                        for kind, (t, rt) in by_kind.items()}
         new_k, new_v, counted = [], [], []
         for li, (p, kc) in enumerate(zip(params["layers"], k_arenas)):
-            P = kc.shape[0]
 
             def attend_latent(q_lat, q_rope, row):
                 lanes = [(0, 0)] * (row.ndim - 1) + [(0, DL - dl)]
-                arena = kc.reshape(P * PL, DL).at[flat].set(
-                    jnp.pad(row, lanes).reshape(N, DL)).reshape(P, PL, DL)
+                row = jnp.pad(row, lanes)                          # [1, N, DL]
+                arena = rows_of(write(kc, where, row[:, :W]), r_where,
+                                row[:, W:])
                 new_k.append(arena)
                 return both(lambda q, t, at: latent_attend(q, arena, t, at),
                             latent_query(q_lat, q_rope), (tables, lengths),
@@ -738,12 +821,12 @@ def _build_carrying_step(sm: ServedModel, carry: int, rows: int,
 
             kind = None if latent else kinds[li]
 
+            def land(arena, kv):         # [1, N, kvh, hd]: chunk, round
+                at, r_at = where_of[kind]
+                return rows_of(write(arena, at, kv[:, :W]), r_at, kv[:, W:])
+
             def attend_ranged(q, k1, v1):
-                vc, idx = v_arenas[li], flat_of[kind]
-                kk = kc.reshape(P * kvh * PL, hd).at[idx].set(
-                    k1.reshape(N * kvh, hd)).reshape(P, kvh, PL, hd)
-                vv = vc.reshape(P * kvh * PL, hd).at[idx].set(
-                    v1.reshape(N * kvh, hd)).reshape(P, kvh, PL, hd)
+                kk, vv = land(kc, k1), land(v_arenas[li], v1)
                 new_k.append(kk)
                 new_v.append(vv)
                 table, r_table = by_kind[kind]
@@ -877,6 +960,12 @@ class GenerationEngine(EngineBase):
         S = self.config.max_slots
         pl = self.config.page_len
         self._pl = pl
+        # every prefill call starts on a page boundary: at 0 or behind whole
+        # cached blocks (``_join``), then in steps of the largest bucket
+        # (``_prefill_chunks``) — where that bucket is whole pages. What a
+        # one-row prefill program's page write rests on (``_whole_pages``);
+        # with any other bucket list every program scatters rows
+        self._aligned = self.config.prefill_buckets[-1] % pl == 0
         self._n_blocks = B = -(-self.max_len // pl)  # ceil
         num_pages = self.config.num_pages
         if num_pages is None:
@@ -1077,9 +1166,16 @@ class GenerationEngine(EngineBase):
                     self._sm, rows, self._n_blocks, self._pl, W,
                     self._donate, label=label, prefill=prefill,
                     carry=self._carried_rows(W) if prefill else 0,
-                    attends=self._attends))
+                    attends=self._attends, aligned=self._aligned))
             self._windows[key] = fn
         return fn
+
+    def _chunk_pages(self, W: int) -> int:
+        """Pages the ``W``-token prefill program writes whole (0: it
+        scatters rows), as its builder decided from the same facts."""
+        return _whole_pages(1, W, self._pl, True,
+                            self._sm.cache_spec is None) \
+            if self._aligned else 0
 
     def _carried_rows(self, W: int) -> int:
         """The decode rows the ``W``-token prefill program carries: a whole
@@ -1989,6 +2085,13 @@ class GenerationEngine(EngineBase):
 
         req = adm.req
         lo, hi, Wc = adm.chunks[len(adm.outs)]
+        pages = self._chunk_pages(Wc)
+        if pages and lo % self._pl:
+            # the invariant ``_aligned`` states; it fails this prompt only
+            raise AssertionError(
+                f"a {Wc}-token prefill call that writes whole pages starts "
+                f"at {lo}, inside a page of {self._pl}: it would overwrite "
+                "cached keys")
         if self._by_layer and adm.outs:
             s = self._slots[adm.slot_no]
             with span("pt.serve.page_table"):
@@ -2023,6 +2126,12 @@ class GenerationEngine(EngineBase):
         # token-rows the prefill program ran (rows x W): what
         # stats()["prefill_fill_rate"] divides the real tokens by
         self.metrics.inc("prefill_window_tokens_total", Wc)
+        # how the call's tokens reached the cache: whole pages, or one row a
+        # token (the chunk's where its program scatters, the carried round's)
+        self.metrics.inc("kv_pages_written_total", pages)
+        self.metrics.inc("kv_rows_written_total",
+                         (0 if pages else Wc)
+                         + (0 if rnd is None else self.config.max_slots))
         # cached positions the chunk's queries see, summed (token w of the
         # chunk sees lo + w + 1)
         n = hi - lo
@@ -2095,7 +2204,8 @@ class GenerationEngine(EngineBase):
                             self._carried_round(adm, None)
                         with span("pt.serve.prefill_chunk", start=lo, W=Wc,
                                   ahead=int(ahead), carried=0 if rnd is None
-                                  else len(rnd.rows)):
+                                  else len(rnd.rows),
+                                  pages=self._chunk_pages(Wc)):
                             if not ahead:
                                 self._send_chunk(adm, None, rnd)
                             if i + 1 < len(chunks):
@@ -2278,6 +2388,7 @@ class GenerationEngine(EngineBase):
                     S, k + 1, jnp.asarray(rnd.tables),
                     self._round_feed(rnd, flying), jnp.asarray(rnd.lengths),
                     n_valid=self._round_valid(rnd))
+        self.metrics.inc("kv_rows_written_total", S * (k + 1))
         if flying is not None:
             self.metrics.inc("programs_run_ahead_total")
 
