@@ -26,14 +26,21 @@ Pallas TPU kernel and a plain ``jnp`` reference (``impl=None`` asks
 - ``ranged_paged_attention``: grouped-query attention against K/V page
   arenas over the pages ``[lo, hi]`` of each row — ``hi`` from the row's
   length, ``lo`` from a sliding window (0 in a full layer) — for a model
-  whose layers are of two kinds (decode round and prefill chunk).
+  whose layers are of two kinds (decode round and prefill chunk);
+- ``dsa_index``: the lightning indexer of a learned sparse attention —
+  ``dsa_index_scores`` (sum over index heads of ``w * relu(q . k)`` against
+  a paged cache of index keys) and ``exact_topk_bias`` (the exact top-k of a
+  row of scores as an additive mask; plain ``jnp`` on every backend);
+- ``mla_sparse_attention``: ``mla_paged_attention`` over the keys each
+  query selected (that mask), for a latent cache with an index row.
 
 Import order matters only in that importing this package populates the
 registry.
 """
-from . import (mla_paged_attention, moe_dispatch,  # noqa: F401
-               paged_attention, ranged_paged_attention, rmsnorm, rope,
-               ssm_step)
+from . import (dsa_index, mla_paged_attention,  # noqa: F401
+               mla_sparse_attention, moe_dispatch, paged_attention,
+               ranged_paged_attention, rmsnorm, rope, ssm_step)
 
 __all__ = ["rmsnorm", "rope", "moe_dispatch", "paged_attention", "ssm_step",
-           "mla_paged_attention", "ranged_paged_attention"]
+           "mla_paged_attention", "ranged_paged_attention", "dsa_index",
+           "mla_sparse_attention"]

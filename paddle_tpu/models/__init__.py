@@ -23,3 +23,6 @@ from .openpangu_moe import (  # noqa: F401
 from .laguna import (  # noqa: F401
     LagunaConfig, LagunaForCausalLM, LagunaBlock,
 )
+from .glm_moe_dsa import (  # noqa: F401
+    GlmMoeDsaConfig, GlmMoeDsaForCausalLM, GlmMoeDsaBlock,
+)

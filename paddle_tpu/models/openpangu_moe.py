@@ -25,6 +25,7 @@ Weights are created on the device, in the configuration's dtype, from
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from dataclasses import dataclass
 from typing import Optional
@@ -153,41 +154,49 @@ MOE_KEYS = ATTN_KEYS + ("router", "experts_gate", "experts_up",
 # short; a norm inside one of them is ``norm`` (the innermost part owns).
 
 @part("attn_proj")
-def _mla_in(cfg: OpenPanguMoEConfig, p, u, pos):
-    """Latent attention's projections of the normed input ``u``: the
-    absorbed queries ``q_lat`` [R, W, H, kv_lora_rank], their rotary half
-    ``q_rope``, the window's own cache rows ``row`` [R, W, latent_dim] and
-    the KV up-projection by head (its value half comes after ``attend``)."""
+def mla_in(p, u, pos, rope, *, heads, dn, dr, dv, dc, eps):
+    """Latent attention's projections of the normed input ``u`` (the ONE pair
+    of absorbed-MLA helpers: openPangu-Ultra-MoE and GLM-5 both call it, with
+    their head widths, ``rope(x, pos)`` their rotary form): the absorbed
+    queries ``q_lat`` [R, W, H, dc], their rotary half ``q_rope``, the
+    window's own cache rows ``row`` [R, W, dc + dr], the KV up-projection by
+    head (its value half comes after ``attend``) and the query latent ``c_q``
+    (what an indexer projects its own queries from)."""
     R, W, _ = u.shape
-    H, dn, dr, dv, dc = (cfg.num_attention_heads, cfg.qk_nope_head_dim,
-                         cfg.qk_rope_head_dim, cfg.v_head_dim,
-                         cfg.kv_lora_rank)
-    eps, wd = cfg.rms_norm_eps, p["q_b"].dtype
+    wd = p["q_b"].dtype
     c_q = _rms(_mm(u, p["q_a"]), p["q_a_norm"], eps)
-    q = _mm(c_q, p["q_b"]).reshape(R, W, H, dn + dr)
-    q_rope = _rope(q[..., dn:], pos, cfg.rope_theta)
+    q = _mm(c_q, p["q_b"]).reshape(R, W, heads, dn + dr)
+    q_rope = rope(q[..., dn:], pos)
     kva = _mm(u, p["kv_a"])                                  # [R, W, dc+dr]
     c_kv = _rms(kva[..., :dc], p["kv_a_norm"], eps)
-    k_r = _rope(kva[..., None, dc:], pos, cfg.rope_theta)[:, :, 0]
+    k_r = rope(kva[..., None, dc:], pos)[:, :, 0]
     row = jnp.concatenate([c_kv, k_r], -1).astype(wd)
     # absorbed: carry q_nope through the head's key half of the KV
     # up-projection, attend against latents, then through its value half
-    kv_b = p["kv_b"].reshape(dc, H, dn + dv)
+    kv_b = p["kv_b"].reshape(dc, heads, dn + dv)
     q_lat = jnp.einsum("rwhn,chn->rwhc", q[..., :dn].astype(wd),
                        kv_b[..., :dn], preferred_element_type=F32)
-    return q_lat.astype(wd), q_rope.astype(wd), row, kv_b
+    return q_lat.astype(wd), q_rope.astype(wd), row, kv_b, c_q
 
 
 @part("attn_proj")
-def _mla_out(cfg: OpenPanguMoEConfig, p, x, ctx, kv_b):
-    """The context ``ctx`` [R, W, H, kv_lora_rank] through the value half of
-    the KV up-projection and the output projection, onto the stream."""
+def mla_out(p, x, ctx, kv_b, *, dn, dv, post_norm_eps=None):
+    """The context ``ctx`` [R, W, H, dc] through the value half of the KV
+    up-projection and the output projection, onto the stream — through the
+    layer's ``post_attn_norm`` first where ``post_norm_eps`` is given (a
+    sandwich-norm block), as it is in a pre-norm block."""
     R, W, H = ctx.shape[:3]
-    o = jnp.einsum("rwhc,chv->rwhv", ctx.astype(kv_b.dtype),
-                   kv_b[..., cfg.qk_nope_head_dim:],
+    o = jnp.einsum("rwhc,chv->rwhv", ctx.astype(kv_b.dtype), kv_b[..., dn:],
                    preferred_element_type=F32)
-    a = _mm(o.reshape(R, W, H * cfg.v_head_dim), p["o"])
-    return x + _rms(a, p["post_attn_norm"], cfg.rms_norm_eps)
+    a = _mm(o.reshape(R, W, H * dv), p["o"])
+    return x + (a if post_norm_eps is None
+                else _rms(a, p["post_attn_norm"], post_norm_eps))
+
+
+def _mla_dims(cfg):
+    return dict(heads=cfg.num_attention_heads, dn=cfg.qk_nope_head_dim,
+                dr=cfg.qk_rope_head_dim, dv=cfg.v_head_dim,
+                dc=cfg.kv_lora_rank, eps=cfg.rms_norm_eps)
 
 
 @part("mlp")
@@ -228,9 +237,11 @@ def block_fn(cfg: OpenPanguMoEConfig, p, x, pos, attend, valid):
     an expert layer's ``router``. Returns ``(x, stats)``: the expert layer's
     routed-pair counts, ``None`` for a dense layer."""
     eps = cfg.rms_norm_eps
-    q_lat, q_rope, row, kv_b = _mla_in(
-        cfg, p, _rms(x, p["input_norm"], eps), pos)
-    x = _mla_out(cfg, p, x, attend(q_lat, q_rope, row), kv_b)
+    q_lat, q_rope, row, kv_b, _c_q = mla_in(
+        p, _rms(x, p["input_norm"], eps), pos,
+        functools.partial(_rope, theta=cfg.rope_theta), **_mla_dims(cfg))
+    x = mla_out(p, x, attend(q_lat, q_rope, row), kv_b,
+                dn=cfg.qk_nope_head_dim, dv=cfg.v_head_dim, post_norm_eps=eps)
     return _ffn(cfg, p, x, _rms(x, p["pre_mlp_norm"], eps), valid)
 
 

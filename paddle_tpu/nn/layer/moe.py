@@ -63,12 +63,15 @@ def drain_aux(bucket):
 
 
 def _route(xt, wg, top_k, score="softmax", norm_topk=True, scale=1.0,
-           precision=None):
+           precision=None, bias=None):
     """Router: fp32 scores over ALL ``e`` router outputs (``score``:
     'softmax', or 'sigmoid' as the DeepSeek-V3 family scores), top-k,
     renormalized over the chosen k when ``norm_topk``, times ``scale`` (the
     family's ``routed_scaling_factor``) — and the Switch/GShard
-    load-balancing aux (e * sum(frac_tokens * frac_probs))."""
+    load-balancing aux (e * sum(frac_tokens * frac_probs)). ``bias`` [e]
+    (the family's ``noaux_tc`` correction) moves which k are CHOSEN — the
+    top-k of ``score + bias`` — and nothing else: a chosen expert's gate is
+    its own score."""
     n, _ = xt.shape
     e = wg.shape[1]
     logits = jnp.matmul(xt.astype(jnp.float32), wg.astype(jnp.float32),
@@ -79,7 +82,12 @@ def _route(xt, wg, top_k, score="softmax", norm_topk=True, scale=1.0,
         probs = jax.nn.sigmoid(logits)
     else:
         raise ValueError(f"router score {score!r}: 'softmax' or 'sigmoid'")
-    gate_v, gate_i = jax.lax.top_k(probs, top_k)  # [n, k]
+    if bias is None:
+        gate_v, gate_i = jax.lax.top_k(probs, top_k)  # [n, k]
+    else:
+        _chosen, gate_i = jax.lax.top_k(probs + bias.astype(jnp.float32),
+                                        top_k)
+        gate_v = jnp.take_along_axis(probs, gate_i, axis=-1)
     if norm_topk:
         total = jnp.sum(gate_v, -1, keepdims=True)
         # the softmax form as it always was; the sigmoid family's own epsilon
@@ -370,7 +378,8 @@ _SHARE_TILING = (128, 512, 1024)
 @part("router")
 def moe_held_experts_mlp(x, wr, w_gate, w_up, w_down, *, top_k, first,
                          score="sigmoid", norm_topk=True, scale=1.0,
-                         valid=None, tiling=_SHARE_TILING, x_route=None):
+                         valid=None, tiling=_SHARE_TILING, x_route=None,
+                         bias=None):
     """One chip's SHARE of a routed expert layer under expert parallelism:
     route ``x`` [n, h] over all ``E`` router outputs (``wr`` [h, E]), keep
     the (token, choice) pairs whose expert lies in ``[first, first +
@@ -384,7 +393,8 @@ def moe_held_experts_mlp(x, wr, w_gate, w_up, w_down, *, top_k, first,
     routed pair is held. ``tiling`` is the grouped matmuls' (row, k, n)
     tile, clamped to the operands. ``x_route`` [n, h]: what the router
     scores instead of ``x`` — the float32 input of a model whose router is
-    float32, where ``x`` is already rounded to the experts' dtype.
+    float32, where ``x`` is already rounded to the experts' dtype. ``bias``
+    [E] float32: the router's selection bias (``_route``); ``None``: none.
 
     ``valid`` [n] bool marks the rows that hold a real token (a padded
     prefill window, an idle decode row): the others route nowhere. Returns
@@ -413,7 +423,8 @@ def moe_held_experts_mlp(x, wr, w_gate, w_up, w_down, *, top_k, first,
     gate_v, gate_i, _aux = _route(x if x_route is None else x_route, wr,
                                   top_k, score=score,
                                   norm_topk=norm_topk, scale=scale,
-                                  precision=jax.lax.Precision.HIGHEST)
+                                  precision=jax.lax.Precision.HIGHEST,
+                                  bias=bias)
     local = gate_i - first                                    # [n, k]
     held = (local >= 0) & (local < count)
     if valid is not None:

@@ -13,15 +13,24 @@ name at all (a layout copy) to the op that consumes it — the readers'
 rule: ``observability.trace.xplane.correlate().by_part``,
 ``tools/program_parts.py``, and the benchmark's
 ``serve.part_<part>_share_pct``.
+
+A SUBPART is a second, nested vocabulary (``SUBPARTS``; ``pt.indexer``: the
+index projections, scores and top-k of a learned sparse attention): it marks
+work INSIDE parts without being one. The ten parts stay a partition of the
+step — a reader of ``PARTS`` skips a ``pt.`` name outside its vocabulary and
+finds the part around it, so the indexer's projections are still ``attn_proj``
+and its scores ``attention`` — and one reader of its own
+(``serve.indexer_share_pct``) asks what the subpart costs across them.
 """
 from __future__ import annotations
 
 import functools
 
-__all__ = ["PARTS", "PREFIX", "part", "part_of"]
+__all__ = ["PARTS", "SUBPARTS", "PREFIX", "part", "subpart", "part_of"]
 
 PARTS = ("embed", "norm", "attn_proj", "cache_write", "attention", "mlp",
          "router", "experts", "mixer", "head")
+SUBPARTS = ("indexer",)
 PREFIX = "pt."
 
 
@@ -29,10 +38,12 @@ class part:
     """``with part("norm"): ...`` or ``@part("norm")``: the work traced
     inside is named ``pt.norm``. A name outside ``PARTS`` raises."""
 
+    _names, _what = PARTS, "a part of a model step"
+
     def __init__(self, name: str):
-        if name not in PARTS:
+        if name not in self._names:
             raise ValueError(
-                f"{name!r} is not a part of a model step: {PARTS}")
+                f"{name!r} is not {self._what}: {self._names}")
         self.name = name
         self._scope = None
 
@@ -49,12 +60,21 @@ class part:
     def __call__(self, fn):
         name = self.name
 
+        cls = type(self)
+
         @functools.wraps(fn)
         def scoped(*args, **kwargs):
-            with part(name):
+            with cls(name):
                 return fn(*args, **kwargs)
 
         return scoped
+
+
+class subpart(part):
+    """``part``'s twin for the nested vocabulary ``SUBPARTS``: used INSIDE a
+    part (``@part("attn_proj")`` above ``@subpart("indexer")``)."""
+
+    _names, _what = SUBPARTS, "a subpart of a model step"
 
 
 def part_of(name_stack: str):

@@ -39,7 +39,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from ..observability.trace.parts import part
+from ..observability.trace.parts import part, subpart
 from ..observability.trace.request_trace import span
 from .base import (BadRequest, DeadlineExceeded, EngineBase, EngineClosed,
                    _oom_guard, _tracer)
@@ -461,9 +461,10 @@ def _program_name(label: str, carries: bool = False) -> str:
 
 def _attention(sm, attends: Optional[Dict], name: str):
     """The jitted attention callable ``name`` of a window program of ``sm``:
-    ``"paged"`` (K/V arenas), ``"latent"``, or the ``"full"`` / ``"window"``
-    of a cache of two layer kinds. ONE jitted callable for every layer of a
-    program: the kernel is traced and lowered once a program and called L
+    ``"paged"`` (K/V arenas), ``"latent"``, the ``"index_select"`` and
+    ``"sparse"`` of a latent cache with an index row (``_Sparse``), or the
+    ``"full"`` / ``"window"`` of a cache of two layer kinds. ONE jitted
+    callable for every layer of a program: the kernel is traced and lowered once a program and called L
     times, not traced L times (the 36 kernel traces of a GPT-2-large program
     were most of warmup's time, PERF.md section 6, PR 28); XLA inlines the
     calls. And ONE for every program that is handed the same ``attends``
@@ -502,6 +503,39 @@ def _attention(sm, attends: Optional[Dict], name: str):
                                        scale=scale)
 
         fn = attention(latent_attend)
+    elif name == "index_select":
+        import jax.numpy as jnp
+
+        from ..kernels.pallas.dsa_index import (NEG, dsa_index_scores,
+                                                exact_topk_bias)
+
+        topk = int(sm.cache_spec["index"]["topk"])
+
+        @jax.jit
+        def index_select(qi, wi, index_arena, tables, lengths, live):
+            # the window's index scores against the paged index keys, then
+            # the exact top-k of each token's as the bias the selected
+            # attention reads, and how many keys the LIVE tokens selected
+            W = qi.shape[1]
+            scores = dsa_index_scores(qi, wi, index_arena, tables, lengths)
+            bias, n = exact_topk_bias(scores[:, :W], topk,
+                                      jnp.max(lengths) + W)
+            rest = ((0, 0), (0, scores.shape[1] - W), (0, 0))
+            return (jnp.pad(bias, rest, constant_values=NEG),
+                    jnp.sum(jnp.where(live, n, 0)))
+
+        fn = attention(subpart("indexer")(index_select))
+    elif name == "sparse":
+        from ..kernels.pallas.mla_sparse_attention import mla_sparse_attention
+
+        dv = sm.cache_spec["value_dim"]
+
+        @jax.jit
+        def sparse_attend(q, arena, tables, lengths, bias):
+            return mla_sparse_attention(q, arena, tables, lengths, bias,
+                                        dv=dv, scale=scale)
+
+        fn = attention(sparse_attend)
     else:
         from ..kernels.pallas.ranged_paged_attention import \
             ranged_paged_attention
@@ -519,11 +553,65 @@ def _attention(sm, attends: Optional[Dict], name: str):
     return fn
 
 
+class _Sparse:
+    """What a latent cache that declares an index row (``cache_spec["index"]``:
+    a learned sparse attention) adds to a window program. A ``full`` layer's
+    ``attend`` gets ``index = (qI, wI, kI)``: the key row lands in the layer's
+    index arena (``v_arenas[arena_of[layer]]``: the SAME page table and
+    allocator as the latent rows), ``select`` scores the window's tokens
+    against the paged index keys and takes each token's exact top-k — as the
+    additive bias ``attend`` (``mla_sparse_attention``) reads, and the number
+    of keys the live tokens selected — and the program KEEPS both for the
+    ``shared`` layers that follow (the layer loop is unrolled Python).
+    ``counters`` names what the program hands back beside the model's own."""
+
+    def __init__(self, sm, attends: Optional[Dict], prefill: bool,
+                 carries: bool = False):
+        kinds = list(sm.cache_spec["index"]["layers"])
+        self.arena_of = {li: n for n, li in enumerate(
+            i for i, kind in enumerate(kinds) if kind == "full")}
+        self.select = _attention(sm, attends, "index_select")
+        self.attend = _attention(sm, attends, "sparse")
+        self.counters = ("attn_keys_selected_prefill_total",
+                         "attn_keys_selected_decode_total") if carries else \
+            ("attn_keys_selected_prefill_total" if prefill
+             else "attn_keys_selected_decode_total",)
+
+
+def packed_selection(bias):
+    """A selection's additive bias ``[.., L]`` (0 at a selected key) as bits,
+    ``[.., L // 8]`` uint8: bit ``s % 8`` of byte ``s // 8`` is key ``s``
+    (``np.unpackbits(.., bitorder="little")``)."""
+    import jax.numpy as jnp
+
+    on = (bias == 0).reshape(bias.shape[:-1] + (-1, 8))
+    return jnp.sum(on * (1 << jnp.arange(8, dtype=jnp.uint8)), -1,
+                   dtype=jnp.uint8)
+
+
+def _counted(counter_names, counted, sparse=None, selected=(), picked=()):
+    """A window program's last result: the model's ``program_counters``
+    summed over its layers and, with an index row, the keys its live tokens
+    selected summed over the layers (``selected``: a scalar a layer, or a
+    (chunk, round) pair a layer in the program that carries a round) — and
+    under ``"selection"`` WHICH keys, where the program was built to say
+    (``picked``: ``packed_selection`` of every "full" layer's)."""
+    out = {name: sum((c[name] for c in counted[1:]), counted[0][name])
+           for name in counter_names or () if counted}
+    if sparse is not None:
+        pairs = [n if isinstance(n, tuple) else (n,) for n in selected]
+        for i, name in enumerate(sparse.counters):
+            out[name] = sum(p[i] for p in pairs)
+    if picked:
+        out["selection"] = list(picked)
+    return out
+
+
 def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
                        window: int, donate: bool, label: str,
                        fused: bool = True, prefill: bool = False,
                        carry: int = 0, attends: Optional[Dict] = None,
-                       aligned: bool = True):
+                       aligned: bool = True, selection: bool = False):
     """The PAGED executable family: embed ``W = window`` tokens per slot
     at positions ``lengths + [0..W)``, run the served model's blocks — each
     block's ``attend(q, k, v)`` writes K/V through the page tables into the
@@ -553,7 +641,12 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
       and the blocks get ``attend(q_lat, q_rope, row)``: the window's rows
       are written through the page table and
       ``kernels.pallas.mla_paged_attention`` walks the pages each row's
-      length covers;
+      length covers. With an index row (``cache_spec["index"]``: a learned
+      sparse attention) ``v_arenas`` holds the index keys of the layers that
+      own an indexer, and each layer attends the keys its query SELECTED
+      (``_Sparse``); built with ``selection`` the program also names them
+      (``counters["selection"]``: ``GenerationEngine.selected_keys``, a
+      check's — no program that serves a request is built so);
     - ``"kv_by_layer"``: K and V arenas ``[pages, heads, page_len, dim]`` a
       layer, a "full" layer's of the pool's pages and a "window" layer's of
       the window pool's; ``tables`` is ``[2, rows, B]`` (the full layers'
@@ -609,6 +702,7 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
 
     sm = _InParts(_served(served))
     if carry:
+        assert not selection, "a carrying program serves requests"
         return _build_carrying_step(sm, int(carry), max_slots, n_blocks,
                                     page_len, window, donate, label, prefill,
                                     attends, aligned)
@@ -629,11 +723,17 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
             "decides; for the jnp reference call kernels.pallas."
             "paged_attention.paged_attention(..., impl='reference')")
 
+    sparse = None
+    if selection and not (latent and sm.cache_spec.get("index")):
+        raise ValueError("selection=True: the model's cache_spec declares no "
+                         "index row, so nothing is selected")
     if latent:
         dl = sm.cache_spec["dim"]
         DL = latent_width(dl)
         latent_attend = _attention(sm, attends, "latent")
         latent_query = _latent_query(DL - dl)
+        if sm.cache_spec.get("index"):
+            sparse = _Sparse(sm, attends, prefill)
     elif by_layer:
         kinds = list(sm.cache_spec["layers"])
         ranged = {kind: _attention(sm, attends, kind)
@@ -663,18 +763,31 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
         valid = None if n_valid is None else \
             jnp.arange(W)[None, :] < n_valid[:, None]              # [S, W]
         new_k, new_v, new_state, counted = [], [], [], []
+        held, selected, picked = [None, None], [], []
         for li, (p, kc) in enumerate(zip(params["layers"], k_arenas)):
             vc = None if latent else v_arenas[li]
 
-            def attend_latent(q_lat, q_rope, row):
+            def attend_latent(q_lat, q_rope, row, index=None):
                 # the window's rows land in their pages, then every head of
                 # a token rides as one slab against the pages its row's
                 # length covers (rows and queries padded to whole lanes)
                 lanes = [(0, 0)] * (row.ndim - 1) + [(0, DL - dl)]
                 arena = write(kc, where, jnp.pad(row, lanes))
                 new_k.append(arena)
-                return latent_attend(latent_query(q_lat, q_rope), arena,
-                                     tables, lengths)
+                q = latent_query(q_lat, q_rope)
+                if sparse is None:
+                    return latent_attend(q, arena, tables, lengths)
+                if index is not None:     # a "full" layer scores and selects
+                    keys = write(v_arenas[sparse.arena_of[li]], where,
+                                 index[2])
+                    new_v.append(keys)
+                    held[:] = sparse.select(
+                        index[0], index[1], keys, tables, lengths,
+                        jnp.ones((S, W), bool) if valid is None else valid)
+                    if selection:
+                        picked.append(packed_selection(held[0][:, :W]))
+                selected.append(held[1])
+                return sparse.attend(q, arena, tables, lengths, held[0])
 
             def attend(q, k1, v1):
                 kk = write_rows(kc, where, k1)
@@ -705,11 +818,10 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
             x = _last_real(x, n_valid)                             # [S, 1, h]
         nxt, logp = _pick(sm.head(params, x))       # [S, W], [S, W] f32
         out = (nxt, logp, new_k, new_v, new_state if stateful else None)
-        if not counter_names:
+        if not counter_names and sparse is None:
             return out
-        return out + ({name: sum((c[name] for c in counted[1:]),
-                                 counted[0][name])
-                       for name in counter_names if counted},)
+        return out + (_counted(counter_names, counted, sparse, selected,
+                               picked),)
 
     donate_argnums = (1, 2, 7) if stateful else (1, 2)
     step.__name__ = _program_name(label)
@@ -760,11 +872,14 @@ def _build_carrying_step(sm: ServedModel, carry: int, rows: int,
     counter_names = sm.program_counters
     R, W, PL = carry, window, page_len         # (the tables say n_blocks)
     # a block sees N = W + R tokens
+    sparse = None
     if latent:
         dl = sm.cache_spec["dim"]
         DL = latent_width(dl)
         latent_attend = _attention(sm, attends, "latent")
         latent_query = _latent_query(DL - dl)
+        if sm.cache_spec.get("index"):
+            sparse = _Sparse(sm, attends, prefill, carries=True)
     else:
         kinds = list(sm.cache_spec["layers"])
         ranged = {kind: _attention(sm, attends, kind)
@@ -807,17 +922,38 @@ def _build_carrying_step(sm: ServedModel, carry: int, rows: int,
                                flat_kv(rt, r_pos, PL, kvh))        # [R*kvh]
                         for kind, (t, rt) in by_kind.items()}
         new_k, new_v, counted = [], [], []
+        held, selected = [None, None], []
         for li, (p, kc) in enumerate(zip(params["layers"], k_arenas)):
 
-            def attend_latent(q_lat, q_rope, row):
+            def attend_latent(q_lat, q_rope, row, index=None):
                 lanes = [(0, 0)] * (row.ndim - 1) + [(0, DL - dl)]
                 row = jnp.pad(row, lanes)                          # [1, N, DL]
                 arena = rows_of(write(kc, where, row[:, :W]), r_where,
                                 row[:, W:])
                 new_k.append(arena)
-                return both(lambda q, t, at: latent_attend(q, arena, t, at),
-                            latent_query(q_lat, q_rope), (tables, lengths),
-                            (r_tables, r_lengths))
+                q = latent_query(q_lat, q_rope)
+                if sparse is None:
+                    return both(
+                        lambda q, t, at: latent_attend(q, arena, t, at), q,
+                        (tables, lengths), (r_tables, r_lengths))
+                if index is not None:     # a "full" layer scores and selects
+                    qi, wi, ki = index
+                    keys = rows_of(write(v_arenas[sparse.arena_of[li]],
+                                         where, ki[:, :W]), r_where,
+                                   ki[:, W:])
+                    new_v.append(keys)
+                    rows = functools.partial(jnp.swapaxes, axis1=0, axis2=1)
+                    held[:] = (
+                        sparse.select(qi[:, :W], wi[:, :W], keys, tables,
+                                      lengths, valid[:, :W]),
+                        sparse.select(rows(qi[:, W:]), rows(wi[:, W:]), keys,
+                                      r_tables, r_lengths,
+                                      rows(valid[:, W:])))
+                (bias, n), (r_bias, r_n) = held
+                selected.append((n, r_n))
+                return both(
+                    lambda q, t, at, b: sparse.attend(q, arena, t, at, b), q,
+                    (tables, lengths, bias), (r_tables, r_lengths, r_bias))
 
             kind = None if latent else kinds[li]
 
@@ -847,11 +983,9 @@ def _build_carrying_step(sm: ServedModel, carry: int, rows: int,
         # ([1, 1], [R, 1]): the prompt's, then the round's
         nxt, logp = ((a[:, :1], a[0, 1:, None]) for a in (nxt, logp))
         out = (nxt, logp, new_k, new_v, None)
-        if not counter_names:
+        if not counter_names and sparse is None:
             return out
-        return out + ({name: sum((c[name] for c in counted[1:]),
-                                 counted[0][name])
-                       for name in counter_names if counted},)
+        return out + (_counted(counter_names, counted, sparse, selected),)
 
     step.__name__ = _program_name(label, carries=True)
 
@@ -944,6 +1078,20 @@ class GenerationEngine(EngineBase):
                 raise ValueError(
                     why + "the warm tier spills and restores whole "
                     "prefixes — pass GenerationConfig(warm_pool_bytes=0)")
+        # a latent cache with an index row (a learned sparse attention): the
+        # index keys live in arenas of their own on the SAME page table, so a
+        # page the prefix trie shares carries a token's two rows together and
+        # the trie serves both; what it cannot take yet is refused in words
+        # (docs/serving.md, "Latent cache with an index row")
+        self._index = sm.cache_spec.get("index") if self._latent else None
+        self._indexers = self._index["layers"].count("full") \
+            if self._index else 0
+        if self._index and self.config.draft_model is not None:
+            raise ValueError(
+                f"{type(model).__name__} attends the keys an indexer selects: "
+                "a verify window of draft tokens would select with them in "
+                "the cache and no test holds that path yet, so speculative "
+                "decoding is refused — pass draft_model=None")
         if self._latent and self.config.warm_pool_bytes:
             # what moves K/V pages cannot take a latent row yet: refused in
             # words (docs/serving.md, "Latent cache")
@@ -1126,9 +1274,10 @@ class GenerationEngine(EngineBase):
         self.metrics.gauge("slot_occupancy", self.slot_occupancy)
         self.metrics.gauge("kv_headroom", self.kv_headroom)
         self.metrics.gauge("kv_pool_bytes", self._kv_pool_bytes)
-        if self._by_layer:
+        if self._index or self._by_layer:
             self.metrics.gauge("kv_pool_bytes_by_kind",
                                self._pool.bytes_by_kind)
+        if self._by_layer:
             self.metrics.gauge("kv_pages_live_by_kind",
                                self._pool.live_pages_by_kind)
         if self._stateful:
@@ -1431,6 +1580,56 @@ class GenerationEngine(EngineBase):
                              "recurrent state")
         return [{name: arena[slot_no] for name, arena in layer.items()}
                 for layer in self._pool.state]
+
+    def selected_keys(self, tokens) -> List[np.ndarray]:
+        """WHICH keys each position of ``tokens`` attends, for a check of a
+        learned sparse attention's selection (a latent cache with an index
+        row; ``attn_keys_selected_*_total`` count the keys, this names them):
+        one ``[len(tokens), Lp // 8]`` uint8 array a ``"full"`` layer, bit
+        ``s % 8`` of byte ``s // 8`` of row ``t`` set where position ``t``
+        attends position ``s`` (``np.unpackbits(.., bitorder="little")``;
+        ``Lp``: the positions a row's page table covers,
+        ``dsa_index.padded_context``). ``tokens`` go as ONE prompt through a
+        build of the largest prefill bucket's program that also hands its
+        selections back — the same ``_build_window_step``, page table, arenas,
+        kernels and chunk offsets as a served prompt's, on the caller's thread
+        and through pages 1, 2, ... of the pool. So only a closed engine may
+        (as ``release_caches``), and what the pool held is overwritten."""
+        import jax
+        import jax.numpy as jnp
+
+        if not self._index:
+            raise ValueError("selected_keys: the model's cache declares no "
+                             "index row, so nothing is selected")
+        if not self._closed or self._thread is not None:
+            raise RuntimeError("selected_keys: close() the engine first")
+        tokens = np.asarray(tokens, np.int32).reshape(-1)
+        W, PL, pool = self.config.prefill_buckets[-1], self._pl, self._pool
+        n_pages = -(-len(tokens) // PL)
+        if n_pages > min(self._n_blocks, pool.num_pages - 1):
+            raise ValueError(f"selected_keys: {len(tokens)} tokens are past "
+                             "max_seq_len or the pool")
+        label = f"serving:{self.name}:selection{W}"
+        fn = self._windows.get((1, W, "selection"))
+        if fn is None:
+            fn = self._windows[1, W, "selection"] = _build_window_step(
+                self._sm, 1, self._n_blocks, PL, W, self._donate, label=label,
+                prefill=True, attends=self._attends, aligned=self._aligned,
+                selection=True)
+        table = np.zeros(self._tables_shape(1), np.int32)
+        table[0, :n_pages] = 1 + np.arange(n_pages)
+        table, out = jnp.asarray(table), []
+        for lo in range(0, len(tokens), W):
+            chunk = np.zeros((1, W), np.int32)
+            n = min(W, len(tokens) - lo)
+            chunk[0, :n] = tokens[lo:lo + n]
+            _nxt, _lp, pool.k, pool.v, _state, counted = fn(
+                self._params, pool.k, pool.v, table, jnp.asarray(chunk),
+                jnp.asarray([lo], jnp.int32), jnp.asarray([n], jnp.int32),
+                None)
+            out.append([np.asarray(a)[0, :n]
+                        for a in jax.device_get(counted["selection"])])
+        return [np.concatenate(layer) for layer in zip(*out)]
 
     def release_caches(self) -> None:
         """Give both caches' device buffers back (K/V arenas, state arenas,
@@ -2136,6 +2335,10 @@ class GenerationEngine(EngineBase):
         # chunk sees lo + w + 1)
         n = hi - lo
         self.metrics.inc("attn_keys_prefill_total", n * lo + n * (n + 1) // 2)
+        if self._index:
+            # every "full" layer's indexer scored them all, once a layer
+            self.metrics.inc("index_keys_scored_prefill_total",
+                             (n * lo + n * (n + 1) // 2) * self._indexers)
         if self._by_layer:
             self._count_keys(n * lo + n * (n + 1) // 2,
                              self._keys_in_window(lo, hi, self._win))
@@ -2465,6 +2668,10 @@ class GenerationEngine(EngineBase):
         # cached positions the round's queries see, summed over its rows
         self.metrics.inc("attn_keys_decode_total",
                          int(rnd.lengths.sum()) + n_active)
+        if self._index:
+            self.metrics.inc("index_keys_scored_decode_total",
+                             (int(rnd.lengths.sum()) + n_active)
+                             * self._indexers)
         if self._by_layer:
             seen = rnd.lengths[[i for i, _req in rnd.rows]] + 1
             self._count_keys(int(seen.sum()), int(

@@ -507,9 +507,18 @@ class PagedKVPool:
                 "model's cache_spec is None (K and V), 'latent' or "
                 "'kv_by_layer'")
         self.k = [jnp.zeros(shape, dtype) for shape in shapes]
-        self.v = [] if cache_spec is not None and \
-            cache_spec["kind"] == "latent" else \
-            [jnp.zeros(shape, dtype) for shape in shapes]
+        if cache_spec is not None and cache_spec["kind"] == "latent":
+            # a latent cache keeps no V; with an index row (a learned sparse
+            # attention: ``cache_spec["index"]``) ``v`` holds the index keys
+            # instead, one arena a layer that owns an indexer ("full"), on
+            # the same pages: page p of a sequence holds its tokens' latent
+            # rows in ``k`` and their index keys in ``v``
+            index = cache_spec.get("index")
+            self.v = [] if not index else [
+                jnp.zeros((num_pages, page_len, int(index["dim"])), dtype)
+                for kind in index["layers"] if kind == "full"]
+        else:
+            self.v = [jnp.zeros(shape, dtype) for shape in shapes]
         # the second kind of cache: per layer, one slot-indexed arena per
         # entry of a recurrent model's ``state_spec`` ({name: (per-slot
         # shape, dtype)}) — e.g. the SSM state [slots, heads, P, N] and the
@@ -662,7 +671,11 @@ class PagedKVPool:
 
     def bytes_by_kind(self) -> Dict[str, int]:
         """The arenas' bytes by layer kind (one kind, "full", for a cache
-        that declares none)."""
+        that declares none); the latent rows and the index keys apart for a
+        latent cache with an index row."""
+        if self.cache_spec is not None and self.cache_spec.get("index"):
+            return {"latent": sum(int(a.nbytes) for a in self.k),
+                    "index": sum(int(a.nbytes) for a in self.v)}
         kinds = self.layer_kinds or ["full"] * len(self.k)
         out = {kind: 0 for kind in kinds}
         for arenas in (self.k, self.v):

@@ -38,6 +38,17 @@ contains no model. A model that can be served implements
   through the page table and returns each head's softmax-weighted sum of
   the cached rows' first ``dv`` columns, ``[rows, W, heads, dv]`` (the
   absorbed form: the model carries it through its value up-projection).
+  With an ``"index"`` group — ``{"dim": di, "heads": hi, "topk": k, "layers":
+  ["full", "shared", ...]}``: a learned sparse attention — a token leaves a
+  SECOND row of ``di`` values (its index key) in every ``"full"`` layer, in
+  arenas of their own on the same page table, and ``attend`` of a ``"full"``
+  layer takes ``index=(qI, wI, kI)`` (index queries ``[rows, W, hi, di]``,
+  their float32 weights ``[rows, W, hi]``, the window's own index keys
+  ``[rows, W, di]``): the engine writes ``kI``, scores every visible key
+  (``sum_j wI_j relu(qI_j . kI)``), takes each token's EXACT top-``k`` and
+  attends those keys alone; ``attend`` of a ``"shared"`` layer takes no
+  ``index`` and attends the set the last ``"full"`` layer selected, which
+  the window program keeps.
   ``{"kind": "kv_by_layer", "layers": ["full", "window", ...], "window":
   n}`` (a model whose layers are of two kinds): a key and a value of
   ``[num_kv_heads, head_dim]`` in every layer, but a "window" layer's query
@@ -77,7 +88,9 @@ goes in the model's own words (``tools/program_parts.py``), and
 A model with recurrent state cannot use what assumes a cache is pages of
 K/V (the prefix trie, speculative verify, KV-page export/install), a latent
 cache cannot yet use what moves K/V pages (export/install and its wire
-format, the warm tier), and a cache with window layers cannot use what
+format, the warm tier) — with an index row it shares index keys through the
+prefix trie like latent rows (one page table) but refuses a draft model too —
+and a cache with window layers cannot use what
 assumes that a page, once written, stays (the prefix trie, speculative
 verify, export/install, the warm tier): the engine refuses those in words
 (``docs/serving.md``).

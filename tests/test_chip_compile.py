@@ -275,6 +275,45 @@ def test_mla_paged_attention_published_widths(one_chip, S, W):
              ((S,), jnp.int32), names=("pt_mla_paged_attention",))
 
 
+# GLM-5.2 (``glm-5.2-d5e16``): 64 heads against latent rows of 576 laid out at
+# 640, 32 index heads of 128, contexts to 49 664 tokens (388 pages of 128): the
+# 32-slot decode round, the tail buckets and the 2048-token chunk
+_DSA_WINDOWS = [(32, 1), (1, 256), (1, 2048)]
+_DSA_POOL, _DSA_BLOCKS, _DSA_LP = 3300, 388, 49664
+
+
+@pytest.mark.parametrize("S,W", _DSA_WINDOWS)
+def test_dsa_index_scores_published_widths(one_chip, S, W):
+    """The lightning indexer's scores: a tile of 8 tokens x 32 index heads
+    against blocks of 512 paged index keys, the tile's ``[8, 49664]`` float32
+    scores resident in VMEM."""
+    from paddle_tpu.kernels.pallas import dsa_index
+
+    def run(qi, wi, arena, tables, start):
+        return dsa_index.dsa_index_scores(qi, wi, arena, tables, start,
+                                          impl="pallas")
+
+    _compile(run, one_chip, ((S, W, 32, 128), BF16), ((S, W, 32), jnp.float32),
+             ((_DSA_POOL, 128, 128), BF16), ((S, _DSA_BLOCKS), jnp.int32),
+             ((S,), jnp.int32), names=("pt_dsa_index_scores",))
+
+
+@pytest.mark.parametrize("S,W", _DSA_WINDOWS)
+def test_mla_sparse_attention_published_widths(one_chip, S, W):
+    """Selected latent attention: ``mla_paged_attention``'s slab of 64 heads
+    with the selection's ``[8, 512]`` bias tile landing beside each block."""
+    from paddle_tpu.kernels.pallas import mla_sparse_attention as ksp
+
+    def run(q, arena, tables, start, bias):
+        return ksp.mla_sparse_attention(q, arena, tables, start, bias, dv=512,
+                                        scale=256 ** -0.5, impl="pallas")
+
+    _compile(run, one_chip, ((S, W, 64, 640), BF16),
+             ((_DSA_POOL, 128, 640), BF16), ((S, _DSA_BLOCKS), jnp.int32),
+             ((S,), jnp.int32), ((S, -(-W // 8) * 8, _DSA_LP), jnp.float32),
+             names=("pt_mla_sparse_attention",))
+
+
 @pytest.mark.parametrize("tokens", [128, 512])
 def test_held_experts_grouped_matmuls_published_widths(one_chip, monkeypatch,
                                                        tokens):

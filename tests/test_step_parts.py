@@ -9,8 +9,9 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu import serving
-from paddle_tpu.models import (FalconH1Config, FalconH1ForCausalLM, GPTConfig,
-                               GPTForCausalLM, LagunaConfig,
+from paddle_tpu.models import (FalconH1Config, FalconH1ForCausalLM,
+                               GlmMoeDsaConfig, GlmMoeDsaForCausalLM,
+                               GPTConfig, GPTForCausalLM, LagunaConfig,
                                LagunaForCausalLM, OpenPanguMoEConfig,
                                OpenPanguMoEForCausalLM)
 from paddle_tpu.observability.trace import parts
@@ -24,6 +25,7 @@ MODELS = {
     "falcon_h1": (FalconH1ForCausalLM, FalconH1Config.tiny),
     "openpangu": (OpenPanguMoEForCausalLM, OpenPanguMoEConfig.tiny),
     "laguna": (LagunaForCausalLM, LagunaConfig.tiny),
+    "glm_dsa": (GlmMoeDsaForCausalLM, GlmMoeDsaConfig.tiny),
 }
 # decode, the largest prefill bucket (which carries the round where the
 # model's ``carries_rounds``), and a smaller bucket
@@ -139,9 +141,25 @@ def test_every_heavy_op_sits_under_a_part(lowered, model, program):
     used = {parts.part_of(st) for _op, st in heavy}
     want = {"attn_proj", "cache_write", "norm", "head"}
     want |= {"mixer"} if model == "falcon_h1" else set()
-    want |= {"router", "experts"} if model in ("openpangu", "laguna") \
-        else set()
+    want |= {"router", "experts"} if model in ("openpangu", "laguna",
+                                               "glm_dsa") else set()
     assert want <= used <= set(parts.PARTS), used
+
+
+@pytest.mark.parametrize("program", list(PROGRAMS))
+def test_the_indexer_is_a_scope_inside_parts_not_a_part(lowered, program):
+    """The nested ``pt.indexer`` scope marks the index projections (inside
+    ``attn_proj``) and the scores and top-k (inside ``attention``): every op
+    under it still has one of the ten parts, and both kinds are there."""
+    _eng, progs = lowered("glm_dsa")
+    ops = ops_with_name_stacks(progs[program].as_text(debug_info=True))
+    inside = [(op, st) for op, st in ops if "pt.indexer" in st.split("/")]
+    assert len(inside) > 10
+    around = {parts.part_of(st) for _op, st in inside}
+    assert {"attn_proj", "attention"} <= around <= set(parts.PARTS), around
+    assert "indexer" not in parts.PARTS and parts.SUBPARTS == ("indexer",)
+    with pytest.raises(ValueError, match="not a subpart"):
+        parts.subpart("attention")
 
 
 def _strip(text):
