@@ -35,7 +35,8 @@ from ..core.dispatch import primitive
 from ..framework import dtype as dtype_mod
 from ..kernels.pallas.rmsnorm import rms_norm
 from ..nn import functional as F
-from ..nn.layer.moe import moe_held_experts_mlp
+from ..nn.layer.moe import (HELD_EXPERTS_COUNTERS, held_experts_counters,
+                            moe_held_experts_mlp)
 from ..observability.trace.parts import part
 from ..serving.served_model import ServedModel
 from .falcon_h1 import F32, _mm, _Weights
@@ -157,11 +158,6 @@ MOE_KEYS = ATTN_KEYS + ("router", "experts_gate", "experts_up",
                         "experts_down", "shared_gate", "shared_up",
                         "shared_down")
 
-# a decode round of 128 slots gives one of 256 experts ~4 rows and a
-# 2048-token chunk ~64: the MXU's own 128 rows a tile; k and n tiles divide
-# 2048 and 512 (gate/up: k 2048, n 512; down: k 512, n 2048)
-_EXPERT_TILING = (128, 512, 512)
-
 
 @part("mlp")
 def _swiglu(u, gate, up, down):
@@ -187,7 +183,7 @@ def _experts(flat, valid, router, gate, up, down, *, top_k, scale):
     return moe_held_experts_mlp(
         flat.astype(gate.dtype), router, gate, up, down, top_k=top_k,
         first=0, score="sigmoid", norm_topk=True, scale=scale, valid=valid,
-        tiling=_EXPERT_TILING, x_route=flat)
+        x_route=flat)
 
 
 def _rope(x, pos, inv_freq, dim, scale):
@@ -415,8 +411,7 @@ class LagunaServed(ServedModel):
     window program hands back the expert layers' routed-pair counts
     (``program_counters``: all experts are held, so held = routed)."""
 
-    program_counters = ("moe_pairs_total", "moe_held_pairs_total",
-                        "moe_experts_hit_total")
+    program_counters = tuple(HELD_EXPERTS_COUNTERS)
 
     def __init__(self, cfg: LagunaConfig):
         self.cfg = cfg
@@ -456,11 +451,7 @@ class LagunaServed(ServedModel):
 
     def block(self, p, x, pos, attend, state, valid):
         x, stats = block_fn(self.cfg, p, x, pos, attend, valid)
-        counters = None if stats is None else {
-            "moe_pairs_total": stats["pairs"],
-            "moe_held_pairs_total": stats["held"],
-            "moe_experts_hit_total": stats["experts_hit"]}
-        return x, None, counters
+        return x, None, held_experts_counters(stats)
 
     def head(self, params, x):
         return _mm(_norm(x, params["final_norm"], self.cfg.rms_norm_eps),

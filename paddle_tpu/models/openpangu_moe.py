@@ -37,7 +37,8 @@ from .. import nn
 from ..core.dispatch import primitive
 from ..framework import dtype as dtype_mod
 from ..nn import functional as F
-from ..nn.layer.moe import moe_held_experts_mlp
+from ..nn.layer.moe import (HELD_EXPERTS_COUNTERS, held_experts_counters,
+                            moe_held_experts_mlp)
 from ..observability.trace.parts import part
 from ..serving.served_model import ServedModel
 from .falcon_h1 import F32, _mm, _rms, _rope, _Weights
@@ -369,8 +370,7 @@ class OpenPanguMoEServed(ServedModel):
     token (``cache_spec``), no recurrent state; every window program hands
     back the expert layers' routed-pair counts (``program_counters``)."""
 
-    program_counters = ("moe_pairs_total", "moe_held_pairs_total",
-                        "moe_experts_hit_total")
+    program_counters = tuple(HELD_EXPERTS_COUNTERS)
 
     def __init__(self, cfg: OpenPanguMoEConfig):
         self.cfg = cfg
@@ -407,11 +407,7 @@ class OpenPanguMoEServed(ServedModel):
 
     def block(self, p, x, pos, attend, state, valid):
         x, stats = block_fn(self.cfg, p, x, pos, attend, valid)
-        counters = None if stats is None else {
-            "moe_pairs_total": stats["pairs"],
-            "moe_held_pairs_total": stats["held"],
-            "moe_experts_hit_total": stats["experts_hit"]}
-        return x, None, counters
+        return x, None, held_experts_counters(stats)
 
     def head(self, params, x):
         # float32 logits, as Falcon-H1's

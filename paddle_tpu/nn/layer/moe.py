@@ -368,18 +368,25 @@ def _moe_mlp_gmm(x, wg, w_gate, w_up, w_down, *, top_k):
     return out.reshape(b, s, h), aux
 
 
-# grouped-matmul tiles of the held-experts path: a round's rows are few (a
-# decode round of 128 slots gives a held expert ~4), so the row tile is the
-# MXU's own 128, not DEFAULT_TILING's 512 (every (expert, row tile) visit
-# multiplies the whole tile); k and n tiles divide 7680 and 2048
-_SHARE_TILING = (128, 512, 1024)
+# ``moe_held_experts_mlp``'s stats under the names a served model hands them
+# back by (``ServedModel.program_counters``)
+HELD_EXPERTS_COUNTERS = {"moe_pairs_total": "pairs",
+                         "moe_held_pairs_total": "held",
+                         "moe_experts_hit_total": "experts_hit",
+                         "moe_weight_streams_total": "weight_streams"}
+
+
+def held_experts_counters(stats):
+    """A block's third result from ``moe_held_experts_mlp``'s ``stats``
+    (``None`` for a layer with no routed experts)."""
+    return None if stats is None else {
+        name: stats[key] for name, key in HELD_EXPERTS_COUNTERS.items()}
 
 
 @part("router")
 def moe_held_experts_mlp(x, wr, w_gate, w_up, w_down, *, top_k, first,
                          score="sigmoid", norm_topk=True, scale=1.0,
-                         valid=None, tiling=_SHARE_TILING, x_route=None,
-                         bias=None):
+                         valid=None, x_route=None, bias=None):
     """One chip's SHARE of a routed expert layer under expert parallelism:
     route ``x`` [n, h] over all ``E`` router outputs (``wr`` [h, E]), keep
     the (token, choice) pairs whose expert lies in ``[first, first +
@@ -390,36 +397,51 @@ def moe_held_experts_mlp(x, wr, w_gate, w_up, w_down, *, top_k, first,
     weighted results. What the other experts would have added is NOT here:
     under ``ep`` it arrives by the exchange; a chip alone returns its part.
     With ``first = 0`` and ``count = E`` the share is the WHOLE layer: every
-    routed pair is held. ``tiling`` is the grouped matmuls' (row, k, n)
-    tile, clamped to the operands. ``x_route`` [n, h]: what the router
-    scores instead of ``x`` — the float32 input of a model whose router is
-    float32, where ``x`` is already rounded to the experts' dtype. ``bias``
-    [E] float32: the router's selection bias (``_route``); ``None``: none.
+    routed pair is held. The grouped matmuls' tiles follow from the shapes
+    (``grouped_matmul.choose_tiling``: the rows a held expert can expect are
+    the routed pairs ÷ ``E``), and each hands back the rows' dtype: the
+    kernel rounds its float32 accumulator once. ``x_route`` [n, h]: what the
+    router scores instead of ``x`` — the float32 input of a model whose
+    router is float32, where ``x`` is already rounded to the experts' dtype.
+    ``bias`` [E] float32: the router's selection bias (``_route``); ``None``:
+    none.
 
     ``valid`` [n] bool marks the rows that hold a real token (a padded
     prefill window, an idle decode row): the others route nowhere. Returns
     ``(y [n, h] float32, stats)``, ``stats`` int32 scalars: ``pairs`` (routed
     pairs of real tokens), ``held`` (those that met a held expert),
     ``experts_hit`` (held experts that got a row: those whose weights the
-    call streams).
+    call streams), ``weight_streams`` (the times an expert's gate / up
+    weights are streamed: ``experts_hit`` where their tiling holds the
+    contraction in one tile, else each such expert once for every row tile
+    its rows span — the kernel re-reads a weight block whenever its index
+    changed, and a tiled k changes it inside every visit).
 
     In a device trace (``observability.trace.parts``) the three grouped
     matmuls are the ``experts`` part of the step and everything else here —
-    scores, top-k, the sort and gather into their layout, the casts and the
-    activation between them, the weighted combine back — its ``router``."""
-    from ...kernels.grouped_matmul import grouped_matmul
-
-    def gmm(lhs, rhs):
-        # the kernel's call alone is ``experts``: it hands back what it
-        # accumulated, and the cast to the rows' dtype is out here
-        with part("experts"):
-            out = grouped_matmul(lhs, rhs, group_sizes, tiling=tiling,
-                                 out_dtype=jnp.float32)
-        return out.astype(lhs.dtype)
+    scores, top-k, the sort and gather into their layout, the activation
+    between them, the weighted combine back — its ``router``."""
+    from ...kernels.grouped_matmul import choose_tiling, grouped_matmul
 
     n, h = x.shape
-    count = w_gate.shape[0]
+    count, _, w = w_gate.shape
     kn = top_k * n
+
+    def tiles(k_dim, n_dim):
+        return choose_tiling(kn, k_dim, n_dim, groups=count,
+                             rows_per_group=kn / wr.shape[1],
+                             lhs_item=x.dtype.itemsize,
+                             rhs_item=w_gate.dtype.itemsize,
+                             out_item=x.dtype.itemsize)
+
+    tile_in, tile_out = tiles(h, w), tiles(w, h)
+
+    def gmm(lhs, rhs, tiling):
+        # the kernel's call alone is ``experts``
+        with part("experts"):
+            return grouped_matmul(lhs, rhs, group_sizes, tiling=tiling,
+                                  out_dtype=lhs.dtype)
+
     gate_v, gate_i, _aux = _route(x if x_route is None else x_route, wr,
                                   top_k, score=score,
                                   norm_topk=norm_topk, scale=scale,
@@ -437,9 +459,9 @@ def moe_held_experts_mlp(x, wr, w_gate, w_up, w_down, *, top_k, first,
     group_sizes = jnp.bincount(key, length=count + 1)[:count]
 
     xs = jnp.take(x, order // top_k, axis=0)                  # [kn, h]
-    g_proj, u_proj = gmm(xs, w_gate), gmm(xs, w_up)
+    g_proj, u_proj = gmm(xs, w_gate, tile_in), gmm(xs, w_up, tile_in)
     act = jax.nn.silu(g_proj.astype(jnp.float32)) * u_proj
-    ys = gmm(act.astype(x.dtype), w_down)                     # [kn, h]
+    ys = gmm(act.astype(x.dtype), w_down, tile_out)           # [kn, h]
     # rows past the groups are whatever the kernel left there: select, never
     # multiply
     y_tok = jnp.where(held[:, :, None],
@@ -447,9 +469,18 @@ def moe_held_experts_mlp(x, wr, w_gate, w_up, w_down, *, top_k, first,
                       .astype(jnp.float32), 0.0)
     out = jnp.sum(y_tok * gate_v[:, :, None], axis=1)
     real = jnp.int32(n) if valid is None else jnp.sum(valid, dtype=jnp.int32)
+    hit = group_sizes > 0
+    experts_hit = jnp.sum(hit, dtype=jnp.int32)
+    tm, tk, _ = tile_in
+    if tk >= h:
+        streams = experts_hit
+    else:  # the row tiles each held expert's rows [start, end) span
+        ends = jnp.cumsum(group_sizes)
+        spans = (ends - 1) // tm - (ends - group_sizes) // tm + 1
+        streams = jnp.sum(jnp.where(hit, spans, 0), dtype=jnp.int32)
     stats = {"pairs": real * top_k,
              "held": jnp.sum(held, dtype=jnp.int32),
-             "experts_hit": jnp.sum(group_sizes > 0, dtype=jnp.int32)}
+             "experts_hit": experts_hit, "weight_streams": streams}
     return out, stats
 
 

@@ -182,7 +182,9 @@ class _Admission:
         # the decode round each call sent carried (None: it carried none),
         # until the host has read it
         self.carried: List[Optional["_Round"]] = []
-        self.counters: List[Dict[str, Any]] = []
+        # what each call sent counted on the device (``_run_window``'s
+        # ``counted``), added to the metrics when THAT call is read
+        self.counters: List[List[Dict[str, Any]]] = []
 
     @property
     def sent(self) -> bool:
@@ -2318,7 +2320,7 @@ class GenerationEngine(EngineBase):
             (nxt, rnd.nxt), (lp, rnd.lp) = nxt, lp
         adm.outs.append((nxt, lp))
         adm.carried.append(rnd)
-        adm.counters += counted
+        adm.counters.append(counted)
         self.metrics.inc("prefill_chunks_total")
         if flying is not None:
             self.metrics.inc("programs_run_ahead_total")
@@ -2417,6 +2419,10 @@ class GenerationEngine(EngineBase):
                             else:
                                 after = self._run_ahead(adm)
                             adm.outs[i][0].block_until_ready()
+                            # a call's counts land with the call, not with
+                            # the prompt's last: a stretch of the counters
+                            # then holds the programs that ran in it
+                            self._count_programs(adm.counters[i])
                             adm.carried[i] = None
                             if rnd is not None and rnd.rows:
                                 self._read_round(rnd, carried=True)
@@ -2427,7 +2433,6 @@ class GenerationEngine(EngineBase):
                 with span("pt.serve.prefill_sync"):
                     first = int(np.asarray(nxt)[0, 0])
                     first_lp = float(np.asarray(lp)[0, 0])
-                self._count_programs(adm.counters)
                 # draft model prefills the WHOLE prompt through its own
                 # forward (the draft is small; its dense slot arena has no
                 # prefix cache)
@@ -2847,6 +2852,11 @@ class GenerationEngine(EngineBase):
         if pairs:  # an expert layer that holds a share of its experts
             snap["moe_held_share"] = round(
                 c.get("moe_held_pairs_total", 0) / pairs, 5)
+        hit = c.get("moe_experts_hit_total", 0)
+        if hit:  # times a held expert's gate / up weights were streamed a
+            # call: 1 where the grouped matmul holds its contraction whole
+            snap["moe_weight_streams_per_expert"] = round(
+                c.get("moe_weight_streams_total", 0) / hit, 4)
         if self.spec_k:
             prop = c.get("spec_proposed", 0)
             snap["spec_acceptance"] = round(

@@ -314,19 +314,27 @@ def test_mla_sparse_attention_published_widths(one_chip, S, W):
              names=("pt_mla_sparse_attention",))
 
 
-@pytest.mark.parametrize("tokens", [128, 512])
+@pytest.mark.parametrize("h,w,held,tokens", [
+    (7680, 2048, 16, 128), (7680, 2048, 16, 512), (7680, 2048, 16, 640),
+    (2048, 512, 256, 2176), (2048, 512, 256, 128), (6144, 2048, 16, 2080)],
+    ids=["openpangu-round", "openpangu-chunk", "openpangu-carry",
+         "laguna-carry", "laguna-round", "glm-carry"])
 def test_held_experts_grouped_matmuls_published_widths(one_chip, monkeypatch,
-                                                       tokens):
-    """One chip's share of an openPangu-Ultra-MoE expert layer: 16 held
-    experts of 7680 x 2048 under a router of 256 outputs, top 8 — a decode
-    round's 128 tokens and a 512-token chunk. The three grouped matmuls ride
-    megablox ``gmm`` (JAX's own kernel: the trace shows it as ``gmm.N``),
-    which gates on the backend — steered to its TPU branch here."""
+                                                       h, w, held, tokens):
+    """An expert layer's held share at the three served configurations'
+    widths, under a router of 256 outputs, top 8: openPangu-Ultra-MoE's 16
+    held experts of 7680 x 2048 (a decode round's 128 tokens, a 512-token
+    chunk, the chunk that carries a round), Laguna's WHOLE layer (256 of
+    2048 x 512: the carrying call's 2048 + 128 tokens, a round) and
+    GLM-5.2's 16 of 6144 x 2048 (2048 + 32 tokens). The three grouped
+    matmuls ride megablox ``gmm`` (JAX's own kernel: the trace shows it as
+    ``gmm.N``), which gates on the backend — steered to its TPU branch here
+    — under the tiles ``choose_tiling`` takes from these shapes: a tile the
+    chip's VMEM refuses fails here, not in a chip call."""
     from paddle_tpu.kernels import grouped_matmul as gm
     from paddle_tpu.nn.layer.moe import moe_held_experts_mlp
 
     monkeypatch.setattr(gm, "_on_tpu", lambda: True)
-    h, w, held = 7680, 2048, 16
 
     def run(x, router, w_gate, w_up, w_down):
         return moe_held_experts_mlp(x, router, w_gate, w_up, w_down, top_k=8,
@@ -337,6 +345,10 @@ def test_held_experts_grouped_matmuls_published_widths(one_chip, monkeypatch,
                     ((held, h, w), BF16), ((held, w, h), BF16), names=(),
                     foreign="gmm")
     assert sum(c.startswith("gmm") for c in _CUSTOM_CALL.findall(text)) == 3
+    # the kernels hand back the rows' dtype: no float32 result the width of
+    # the routed pairs is written for a pass outside to round
+    assert not re.search(rf"= f32\[{tokens * 8},({h}|{w})\]\S* custom-call",
+                         text)
 
 
 @pytest.mark.parametrize("S,W,H,window", [

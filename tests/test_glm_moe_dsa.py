@@ -151,6 +151,10 @@ def test_chunked_prefill_then_decode_through_both_caches_match_the_reference(
     consumed = [len(p) + n - 1 for p, n in zip(prompts, new)]
     assert c["moe_pairs_total"] == sum(consumed) * cfg.num_experts_per_tok * 4
     assert c["moe_held_pairs_total"] == held
+    # tiny widths hold the contraction in one tile: an expert's weights are
+    # streamed once a call it gets a row in
+    assert c["moe_weight_streams_total"] == c["moe_experts_hit_total"] > 0
+    assert st["moe_weight_streams_per_expert"] == 1.0
     # the keys attended: min(t + 1, topk) a position a layer, counted on the
     # device from the selection itself, prefill and decode apart
     assert c["attn_keys_selected_prefill_total"] == _selected_keys(

@@ -205,6 +205,10 @@ def test_chunked_prefill_across_the_window_then_decode_past_three_windows(
     consumed = sum(len(p) for p in prompts) + sum(new) - len(new)
     assert c["moe_pairs_total"] == consumed * 2 * 4 == pairs
     assert c["moe_held_pairs_total"] == c["moe_pairs_total"]
+    # tiny widths hold the contraction in one tile: an expert's weights are
+    # streamed once a call it gets a row in
+    assert c["moe_weight_streams_total"] == c["moe_experts_hit_total"] > 0
+    assert st["moe_weight_streams_per_expert"] == 1.0
     # pages of the window layers went back while their slots ran on
     assert c["window_pages_released_total"] > 40
     w = st["kv_pages"]["window"]

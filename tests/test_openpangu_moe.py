@@ -62,6 +62,32 @@ def _serve(eng, prompts, max_new):
         return [f.result(timeout=300) for f in futs]
 
 
+def test_a_prefill_calls_counts_land_with_the_call(tiny):
+    """A prompt of four chunks: what each call counted on the device is
+    added to the metrics when THAT call is read — four reads of one
+    program's scalars, each before the prompt's first token, not one read of
+    four at its end — so a stretch of the counters (the benchmark's traced
+    second) holds the programs that ran in it."""
+    cfg, model, _params, _get = tiny
+    eng = _engine(model)
+    seen, count = [], eng._count_programs
+
+    def recording(counted):  # (programs read, pairs counted before them)
+        seen.append((len(counted),
+                     eng.stats()["counters"].get("moe_pairs_total", 0)))
+        count(counted)
+
+    eng._count_programs = recording
+    prompt = np.random.default_rng(2).integers(0, cfg.vocab_size, 50)
+    _serve(eng, [prompt], [2])
+    reads = [(n, pairs) for n, pairs in seen if n][:4]
+    layers = cfg.num_hidden_layers - cfg.first_k_dense_replace
+    # 50 tokens are 16 + 16 + 16 + 8: before each read the metrics hold the
+    # chunks read so far
+    assert reads == [(1, k * cfg.num_experts_per_tok * layers)
+                     for k in (0, 16, 32, 48)]
+
+
 def test_absorbed_forward_matches_the_non_absorbed_reference(tiny):
     """The ``nn.Layer`` forward scores heads against ``[c_kv | k_r]`` rows
     (absorbed); the reference up-projects keys and values per head."""
@@ -104,6 +130,10 @@ def test_chunked_prefill_then_latent_decode_match_the_reference(
         consumed * cfg.num_experts_per_tok * experts_layers
     assert c["moe_held_pairs_total"] == held
     assert st["moe_held_share"] == round(held / c["moe_pairs_total"], 5)
+    # tiny widths hold the contraction in one tile: an expert's weights are
+    # streamed once a call it gets a row in
+    assert c["moe_weight_streams_total"] == c["moe_experts_hit_total"] > 0
+    assert st["moe_weight_streams_per_expert"] == 1.0
     if which == "tiny":
         assert held == c["moe_pairs_total"]  # every expert is held
     assert st["kv_pool_bytes"] == eng._kv_pool_bytes() == \

@@ -162,6 +162,21 @@ def test_the_indexer_is_a_scope_inside_parts_not_a_part(lowered, program):
         parts.subpart("attention")
 
 
+@pytest.mark.parametrize("program", list(PROGRAMS))
+@pytest.mark.parametrize("model", ["openpangu", "laguna", "glm_dsa"])
+def test_expert_models_programs_hand_back_their_weight_streams(
+        lowered, model, program):
+    """Every window program of a model with expert layers hands back, beside
+    the routed pairs, how often its grouped matmuls streamed an expert's
+    weights: an int32 scalar, summed over the layers on the device."""
+    eng, programs = lowered(model)
+    counted = programs[program].out_info[-1]
+    assert set(counted) >= set(eng._sm.program_counters) >= {
+        "moe_experts_hit_total", "moe_weight_streams_total"}
+    streams = counted["moe_weight_streams_total"]
+    assert streams.shape == () and streams.dtype == "int32"
+
+
 def _strip(text):
     return re.sub(r"module @\S+", "module @m", text)
 
