@@ -32,15 +32,22 @@ Pallas TPU kernel and a plain ``jnp`` reference (``impl=None`` asks
   a paged cache of index keys) and ``exact_topk_bias`` (the exact top-k of a
   row of scores as an additive mask; plain ``jnp`` on every backend);
 - ``mla_sparse_attention``: ``mla_paged_attention`` over the keys each
-  query selected (that mask), for a latent cache with an index row.
+  query selected (that mask), for a latent cache with an index row;
+- ``power_retention``: the two ops of a power retention layer (degree-2
+  gated linear attention; a model with NO K/V cache) — ``retention_step``
+  (a decode round over the slot-indexed state arenas, each head's state read
+  and written once, in place, ``phi`` built in VMEM) and ``retention_chunk``
+  (a prefill window from a given state in inner chunks, the state resident
+  in VMEM).
 
 Import order matters only in that importing this package populates the
 registry.
 """
 from . import (dsa_index, mla_paged_attention,  # noqa: F401
                mla_sparse_attention, moe_dispatch, paged_attention,
-               ranged_paged_attention, rmsnorm, rope, ssm_step)
+               power_retention, ranged_paged_attention, rmsnorm, rope,
+               ssm_step)
 
 __all__ = ["rmsnorm", "rope", "moe_dispatch", "paged_attention", "ssm_step",
            "mla_paged_attention", "ranged_paged_attention", "dsa_index",
-           "mla_sparse_attention"]
+           "mla_sparse_attention", "power_retention"]

@@ -26,3 +26,6 @@ from .laguna import (  # noqa: F401
 from .glm_moe_dsa import (  # noqa: F401
     GlmMoeDsaConfig, GlmMoeDsaForCausalLM, GlmMoeDsaBlock,
 )
+from .brumby import (  # noqa: F401
+    BrumbyConfig, BrumbyForCausalLM, BrumbyBlock, BrumbyServed,
+)

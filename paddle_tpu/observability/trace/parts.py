@@ -15,8 +15,9 @@ rule: ``observability.trace.xplane.correlate().by_part``,
 ``serve.part_<part>_share_pct``.
 
 A SUBPART is a second, nested vocabulary (``SUBPARTS``; ``pt.indexer``: the
-index projections, scores and top-k of a learned sparse attention): it marks
-work INSIDE parts without being one. The ten parts stay a partition of the
+index projections, scores and top-k of a learned sparse attention;
+``pt.retention``: the kernel calls of a power retention layer and their glue,
+inside ``attention``): it marks work INSIDE parts without being one. The ten parts stay a partition of the
 step — a reader of ``PARTS`` skips a ``pt.`` name outside its vocabulary and
 finds the part around it, so the indexer's projections are still ``attn_proj``
 and its scores ``attention`` — and one reader of its own
@@ -30,7 +31,7 @@ __all__ = ["PARTS", "SUBPARTS", "PREFIX", "part", "subpart", "part_of"]
 
 PARTS = ("embed", "norm", "attn_proj", "cache_write", "attention", "mlp",
          "router", "experts", "mixer", "head")
-SUBPARTS = ("indexer",)
+SUBPARTS = ("indexer", "retention")
 PREFIX = "pt."
 
 

@@ -170,7 +170,7 @@ class _Admission:
     and what the host has not read of them yet."""
 
     __slots__ = ("slot_no", "req", "chunks", "table", "m", "outs",
-                 "carried", "counters")
+                 "carried", "counters", "state")
 
     def __init__(self, slot_no: int, req: _GenRequest):
         self.slot_no = slot_no
@@ -185,6 +185,10 @@ class _Admission:
         # what each call sent counted on the device (``_run_window``'s
         # ``counted``), added to the metrics when THAT call is read
         self.counters: List[List[Dict[str, Any]]] = []
+        # a model whose block resumes: the recurrent state the last call
+        # sent left the row in (per layer, ``[1, ...]`` arrays on the
+        # device), which the next call starts from and is donated
+        self.state = None
 
     @property
     def sent(self) -> bool:
@@ -311,9 +315,10 @@ class _InParts:
     def __getattr__(self, name):
         return getattr(self._sm, name)
 
-    def block(self, p, x, pos, attend, state, valid):
-        return self._sm.block(p, x, pos, part("cache_write")(attend), state,
-                              valid)
+    def block(self, p, x, pos, attend, state, valid, **step):
+        return self._sm.block(p, x, pos, None if attend is None else
+                              part("cache_write")(attend), state, valid,
+                              **step)
 
 
 @part("head")
@@ -657,7 +662,10 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
       window's keys and values are written through its kind's table and
       ``kernels.pallas.ranged_paged_attention`` walks the pages from the
       first that holds a visible key (0 in a full layer) to the row's last.
-      The query's own shape says how many heads the layer has.
+      The query's own shape says how many heads the layer has;
+    - ``"none"``: NOTHING paged — ``k_arenas`` and ``v_arenas`` are empty,
+      ``tables`` is ``None`` and the blocks get ``attend=None``: every
+      layer's memory is its recurrent ``state``.
 
     The window's keys and values (or latent rows) land through
     ``write_rows``, one scatter index a token — but a ONE-ROW prefill whose
@@ -679,7 +687,11 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     start from zero (a prefill, which returns the rows' FINAL state for
     the engine to install); the per-layer slot arenas (the decode program:
     donated like the K/V arenas) are advanced one step and returned
-    updated, in place. A model without state gets and returns ``None``.
+    updated, in place. A model whose block RESUMES
+    (``ServedModel.resumes_state``) may be handed a ``state`` in a prefill
+    too — what the prompt's previous chunk returned, one row, donated — and
+    is told which it holds by ``step=`` (true in a round). A model without
+    state gets and returns ``None``.
 
     Attention is one of the three kernels above: on the TPU the Pallas
     kernel attends straight against the page table (the dense
@@ -713,7 +725,11 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     cache_kind = None if sm.cache_spec is None else sm.cache_spec["kind"]
     latent = cache_kind == "latent"
     by_layer = cache_kind == "kv_by_layer"
+    unpaged = cache_kind == "none"
     counter_names = sm.program_counters
+    # a model whose block resumes is told which of its two state conventions
+    # a program uses: the slot arenas of a round, or a row's own state
+    step_kw = {"step": not prefill} if sm.resumes_state else {}
     if stateful and not prefill and window != 1:
         raise ValueError(
             "a model with recurrent state decodes one token a round: "
@@ -740,7 +756,7 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
         kinds = list(sm.cache_spec["layers"])
         ranged = {kind: _attention(sm, attends, kind)
                   for kind in sorted(set(kinds))}
-    else:
+    elif not unpaged:
         paged_attend = _attention(sm, attends, "paged")
 
     # a one-row prefill of whole pages writes them whole (``write_pages``),
@@ -760,14 +776,17 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
                        enumerate(("full", "window"))}
             where_of = {kind: chunk_where(t, lengths, pos, n_pages, PL, kvh)
                         for kind, t in by_kind.items()}
-        else:
+        elif not unpaged:
             where = chunk_where(tables, lengths, pos, n_pages, PL)
         valid = None if n_valid is None else \
             jnp.arange(W)[None, :] < n_valid[:, None]              # [S, W]
         new_k, new_v, new_state, counted = [], [], [], []
         held, selected, picked = [None, None], [], []
-        for li, (p, kc) in enumerate(zip(params["layers"], k_arenas)):
-            vc = None if latent else v_arenas[li]
+        # nothing paged: no arena a layer, no table, and no ``attend``
+        for li, (p, kc) in enumerate(zip(
+                params["layers"],
+                [None] * len(params["layers"]) if unpaged else k_arenas)):
+            vc = None if latent or unpaged else v_arenas[li]
 
             def attend_latent(q_lat, q_rope, row, index=None):
                 # the window's rows land in their pages, then every head of
@@ -810,8 +829,10 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
 
             attend_ranged.kind = kind
             out = sm.block(p, x, pos, attend_latent if latent else
-                           attend_ranged if by_layer else attend,
-                           None if state is None else state[li], valid)
+                           attend_ranged if by_layer else
+                           None if unpaged else attend,
+                           None if state is None else state[li], valid,
+                           **step_kw)
             x, st = out[0], out[1]
             new_state.append(st)
             if counter_names and len(out) > 2 and out[2] is not None:
@@ -1058,6 +1079,22 @@ class GenerationEngine(EngineBase):
         cache_kind = None if sm.cache_spec is None else sm.cache_spec["kind"]
         self._latent = cache_kind == "latent"
         self._by_layer = cache_kind == "kv_by_layer"
+        # nothing paged: every layer's memory is its recurrent state. No K/V
+        # arena, no page table in the programs, admission by slots alone, and
+        # ``max_seq_len`` bounds positions only (no memory grows with it).
+        # What needs pages was refused above, as for every state model; the
+        # warm tier is refused here (docs/serving.md, "Nothing paged")
+        self._unpaged = cache_kind == "none"
+        if self._unpaged:
+            if not self._stateful:
+                raise ValueError(
+                    f"{type(model).__name__} declares a cache of kind "
+                    "'none' and no state_spec: it would remember nothing")
+            if self.config.warm_pool_bytes:
+                raise ValueError(
+                    f"{type(model).__name__} keeps no K/V pages at all: "
+                    "the warm tier has nothing to spill or restore — pass "
+                    "GenerationConfig(warm_pool_bytes=0)")
         if self._by_layer:
             # what assumes that a page, once written, stays: refused in
             # words (docs/serving.md, "A cache of two layer kinds")
@@ -1116,8 +1153,11 @@ class GenerationEngine(EngineBase):
         # one-row prefill program's page write rests on (``_whole_pages``);
         # with any other bucket list every program scatters rows
         self._aligned = self.config.prefill_buckets[-1] % pl == 0
-        self._n_blocks = B = -(-self.max_len // pl)  # ceil
-        num_pages = self.config.num_pages
+        self._n_blocks = B = 0 if self._unpaged else \
+            -(-self.max_len // pl)  # ceil
+        # (the allocator wants a usable page beside the scratch one; nobody
+        # takes it)
+        num_pages = 2 if self._unpaged else self.config.num_pages
         if num_pages is None:
             # every slot's worst case + two cached prefixes' worth + scratch
             num_pages = S * B + 2 * B + 1
@@ -1167,7 +1207,8 @@ class GenerationEngine(EngineBase):
         # round before it on the device (``_send_round``) and one that takes
         # them from the host are then one signature to ``jax.jit``, lowered
         # once (a second lowering of a 36-layer program is seconds)
-        self._device = next(iter(self._pool.k[0].devices()))
+        self._device = next(iter(jax.tree_util.tree_leaves(
+            (self._pool.k, self._pool.state))[0].devices()))
         # and so are the arenas, from the start, as every program hands them
         # back: a program's FIRST call then has the signature of all its
         # later ones (the decode program used to be built twice in every
@@ -1326,7 +1367,7 @@ class GenerationEngine(EngineBase):
         scatters rows), as its builder decided from the same facts."""
         return _whole_pages(1, W, self._pl, True,
                             self._sm.cache_spec is None) \
-            if self._aligned else 0
+            if self._aligned and not self._unpaged else 0
 
     def _carried_rows(self, W: int) -> int:
         """The decode rows the ``W``-token prefill program carries: a whole
@@ -1391,6 +1432,15 @@ class GenerationEngine(EngineBase):
                     rows, W, tables, tokens, lengths, n_valid=n_valid,
                     prefill=prefill)
                 jax.block_until_ready(nxt)
+            if row is not None and self._sm.resumes_state:
+                # the same bucket from the state a chunk left (``row`` is
+                # donated): the later chunks of a long prompt
+                with span("pt.serve.warmup_program",
+                          label=f"prefill{W}:resume", rows=rows):
+                    nxt, _lp, row, _counted = self._run_window(
+                        rows, W, tables, tokens, lengths, n_valid=n_valid,
+                        prefill=True, state=row)
+                    jax.block_until_ready(nxt)
             if row is not None:
                 self._install_state(0, row)
             return nxt if carries else (nxt, None) if prefill else (None, nxt)
@@ -1486,15 +1536,18 @@ class GenerationEngine(EngineBase):
                              windowed * self._layers_of["window"])
 
     def _run_window(self, rows: int, W: int, tables, tokens, lengths,
-                    n_valid, prefill: bool = False):
+                    n_valid, prefill: bool = False, state=None):
         """Call the ``(rows, W)`` window program on the pool's arenas and
         rebind what it donates. ``n_valid`` is ``[rows]`` int32, host side.
         A ``prefill`` returns its outputs at the last real position only
         and, for a model with recurrent state, starts every layer from
         zero, stops the recurrence at ``n_valid`` and hands back the row's
-        FINAL state; any other round of such a model advances the state
-        arenas in place. Returns ``(next, logprob, row, counted)``; ``row``
-        is ``None`` but for that prefill, and ``counted`` holds the device
+        FINAL state — or, handed ``state`` (what the prompt's previous chunk
+        returned; only a model whose block resumes: it is donated), goes on
+        from it; any other round of such a model advances the state
+        arenas in place. A model with nothing paged gets no tables. Returns
+        ``(next, logprob, row, counted)``; ``row`` is ``None`` but for that
+        prefill, and ``counted`` holds the device
         scalars of a model that declares ``program_counters`` (a list of at
         most one dict: what ``_count_programs`` takes once the call is
         done). For a prefill that carries a round (``_carried_rows``) every
@@ -1505,9 +1558,10 @@ class GenerationEngine(EngineBase):
 
         pool, fn = self._pool, self._window(rows, W, prefill)
         nxt, lp, pool.k, pool.v, state, *counted = fn(
-            self._params, pool.k, pool.v, tables, tokens, lengths,
+            self._params, pool.k, pool.v,
+            None if self._unpaged else tables, tokens, lengths,
             jax.tree_util.tree_map(jnp.asarray, n_valid),
-            None if prefill else pool.state)
+            state if prefill else pool.state)
         if prefill:
             return nxt, lp, state, counted
         pool.state = state
@@ -1693,10 +1747,13 @@ class GenerationEngine(EngineBase):
         if self._hist_prompt is not None:
             self._hist_prompt.observe(len(prompt))
         if self._prefill_bucket(len(prompt)) is None and \
-                (self._stateful or self.spec_k):
+                ((self._stateful and not self._sm.resumes_state)
+                 or self.spec_k):
             # a longer prompt is prefilled in chunks against its own cached
-            # pages; a recurrent state (a prefill starts it from zero) and a
-            # draft model (its dense arena takes one whole bucket) cannot
+            # pages, or from the state its previous chunk left where the
+            # model's block resumes; a recurrent state that cannot (a prefill
+            # starts it from zero) and a draft model (its dense arena takes
+            # one whole bucket) cannot
             self.metrics.inc("errors_total")
             fut.set_exception(BadRequest(
                 f"prompt length {len(prompt)} exceeds the largest prefill "
@@ -1711,7 +1768,8 @@ class GenerationEngine(EngineBase):
                 f"prompt ({len(prompt)}) + max_new_tokens "
                 f"({max_new_tokens}) exceeds max_seq_len {self.max_len}"))
             return fut
-        needed = -(-(len(prompt) + max_new_tokens) // self._pl)
+        needed = 0 if self._unpaged else \
+            -(-(len(prompt) + max_new_tokens) // self._pl)
         if needed > self._pool.allocator.usable_pages:
             # paged admission bound: POOL capacity, not slot length — a
             # request that could never hold enough pages is rejected; one
@@ -1727,7 +1785,8 @@ class GenerationEngine(EngineBase):
         req = _GenRequest(prompt.astype(np.int64), int(max_new_tokens), fut,
                           t_submit, deadline, on_token=on_token,
                           want_logprobs=return_logprobs)
-        req.blocks = token_blocks(req.prompt, self._pl)
+        req.blocks = [] if self._unpaged else \
+            token_blocks(req.prompt, self._pl)
         req.total_blocks = needed
         # ``trace_parent`` is the fleet-minted context carried over the
         # submit frame: this engine's spans nest under it when the
@@ -1919,6 +1978,11 @@ class GenerationEngine(EngineBase):
                     fut.set_result(res)
 
     def _refuse_kv_transfer(self, what: str) -> None:
+        if self._unpaged:
+            raise RuntimeError(
+                f"{what}: {type(self.model).__name__} keeps no K/V pages at "
+                "all — a sequence is its recurrent state, and no state "
+                "snapshot is shipped")
         if self._by_layer:
             raise RuntimeError(
                 f"{what}: {type(self.model).__name__} keeps a sliding "
@@ -2311,11 +2375,17 @@ class GenerationEngine(EngineBase):
             tokens = (tokens, self._round_feed(rnd, flying))
             lengths = (lengths, jnp.asarray(rnd.lengths))
             n_valid = (n_valid, self._round_valid(rnd))
+        # a later chunk of a model whose block resumes starts from the state
+        # the chunk before it left (donated to this call), the first from
+        # zero; what this one leaves goes to the next, or into the slot's row
+        resumed, adm.state = adm.state, None
         with _oom_guard("generation", label=f"serving:{self.name}:prefill",
                         engine=self.name, bucket=Wc):
             nxt, lp, row, counted = self._run_window(
                 1, Wc, tables, tokens, lengths, n_valid=n_valid,
-                prefill=True)
+                prefill=True, state=resumed)
+        if resumed is not None:
+            self.metrics.inc("state_resumes_total")
         if rnd is not None:
             (nxt, rnd.nxt), (lp, rnd.lp) = nxt, lp
         adm.outs.append((nxt, lp))
@@ -2330,7 +2400,7 @@ class GenerationEngine(EngineBase):
         # how the call's tokens reached the cache: whole pages, or one row a
         # token (the chunk's where its program scatters, the carried round's)
         self.metrics.inc("kv_pages_written_total", pages)
-        self.metrics.inc("kv_rows_written_total",
+        self.metrics.inc("kv_rows_written_total", 0 if self._unpaged else
                          (0 if pages else Wc)
                          + (0 if rnd is None else self.config.max_slots))
         # cached positions the chunk's queries see, summed (token w of the
@@ -2345,6 +2415,7 @@ class GenerationEngine(EngineBase):
             self._count_keys(n * lo + n * (n + 1) // 2,
                              self._keys_in_window(lo, hi, self._win))
         if len(adm.outs) < len(adm.chunks):
+            adm.state = row if self._sm.resumes_state else None
             return
         if row is not None:
             with span("pt.serve.state_install", slot=adm.slot_no):
@@ -2359,6 +2430,27 @@ class GenerationEngine(EngineBase):
             with span("pt.serve.page_table"):
                 trie.insert(req.blocks[:fp], [int(x) for x in table[:fp]],
                             self._pool.allocator)
+
+    def _round_between(self, adm: _Admission) -> Optional[_Round]:
+        """A decode round of the running sequences, dispatched between two
+        chunks of ``adm``'s prompt: for a model with recurrent state whose
+        block resumes, where no call carries a round (``carries_rounds`` is
+        False for every state model) and a prompt of many chunks would else
+        hold every running sequence for all of them. The joining slot has no
+        row; everything before this round is read, so its tokens are the
+        host's. ``None`` where the model is another kind or nothing runs; a
+        fault fails the round's requests alone."""
+        if not (self._stateful and self._sm.resumes_state):
+            return None
+        rnd = self._build_round(None, joining=adm)
+        if not rnd.rows:
+            return None
+        try:
+            self._send_round(rnd)
+        except Exception as e:
+            self._fail_rows(rnd.rows, e)
+            return None
+        return rnd
 
     def _fail_admission(self, adm: _Admission, e: Exception) -> None:
         req, s = adm.req, self._slots[adm.slot_no]
@@ -2413,7 +2505,9 @@ class GenerationEngine(EngineBase):
                                   pages=self._chunk_pages(Wc)):
                             if not ahead:
                                 self._send_chunk(adm, None, rnd)
+                            between = None
                             if i + 1 < len(chunks):
+                                between = self._round_between(adm)
                                 self._send_chunk(
                                     adm, adm, self._carried_round(adm, adm))
                             else:
@@ -2426,6 +2520,10 @@ class GenerationEngine(EngineBase):
                             adm.carried[i] = None
                             if rnd is not None and rnd.rows:
                                 self._read_round(rnd, carried=True)
+                        if between is not None:
+                            # outside the chunk's span: that is one call's
+                            # time on the device, and this is a round's
+                            self._read_round(between)
                 if s.req is not req:
                     return after  # failed with the round that went out ahead
                 # a prefill returns its last real position only
@@ -2596,7 +2694,8 @@ class GenerationEngine(EngineBase):
                     S, k + 1, jnp.asarray(rnd.tables),
                     self._round_feed(rnd, flying), jnp.asarray(rnd.lengths),
                     n_valid=self._round_valid(rnd))
-        self.metrics.inc("kv_rows_written_total", S * (k + 1))
+        if not self._unpaged:
+            self.metrics.inc("kv_rows_written_total", S * (k + 1))
         if flying is not None:
             self.metrics.inc("programs_run_ahead_total")
 

@@ -501,11 +501,24 @@ class PagedKVPool:
             self.window_allocator = PageAllocator(window_pages)
             shapes = [(window_pages if kind == "window" else num_pages,
                        num_heads, page_len, head_dim) for kind in kinds]
+        elif cache_spec["kind"] == "none":
+            # NOTHING paged: every layer's memory is its recurrent state
+            # (``state_spec``), whatever the context. No arena, no page a
+            # request could hold; the allocator keeps the scratch page alone
+            if state_spec is None:
+                raise ValueError(
+                    "cache_spec of kind 'none' with no state_spec: the "
+                    "model would remember nothing")
+            if prefix_cache or warm_pool is not None:
+                raise ValueError(
+                    "a model with nothing paged has no prefix cache and no "
+                    "warm tier: there is no page to share or spill")
+            shapes = []
         else:
             raise ValueError(
                 f"unknown cache kind {cache_spec['kind']!r}: a served "
-                "model's cache_spec is None (K and V), 'latent' or "
-                "'kv_by_layer'")
+                "model's cache_spec is None (K and V), 'latent', "
+                "'kv_by_layer' or 'none'")
         self.k = [jnp.zeros(shape, dtype) for shape in shapes]
         if cache_spec is not None and cache_spec["kind"] == "latent":
             # a latent cache keeps no V; with an index row (a learned sparse

@@ -18,12 +18,20 @@ contains no model. A model that can be served implements
   the engine decides. ``state`` is the layer's recurrent state for the
   program's rows, or ``None``: a stateless model (GPT-2) always gets and
   returns ``None``; a model that declares ``state_spec`` gets ``None`` in a
-  prefill (a fresh sequence: start from zero and return the rows' FINAL
-  state) and its slot arenas in a decode round (advance one step, return
-  them updated). ``valid`` (``[rows, W]`` bool; a stateless model ignores
-  it) marks the window positions that hold a real token: the recurrence
-  must not advance on the others (a padded prefill bucket, an idle decode
-  row);
+  prompt's FIRST prefill call (a fresh sequence: start from zero and return
+  the rows' FINAL state) and its slot arenas in a decode round (advance one
+  step, return them updated). A model whose ``resumes_state`` is true gets,
+  in a prompt's LATER prefill calls, the state its previous call returned
+  (go on from it: the chunks of a prompt longer than the largest bucket),
+  and is told which of the two it holds by ``step=`` (true: the slot arenas
+  of a round; false: a row's own state, or ``None``) — ``block(p, x, pos,
+  attend, state, valid, step=...)``; the engine installs the LAST call's
+  state in the slot's row. A state model that does not resume (Falcon-H1:
+  its chunked scan starts from zero) never sees a state in a prefill, and
+  the engine refuses it a prompt longer than its largest bucket. ``valid``
+  (``[rows, W]`` bool; a stateless model ignores it) marks the window
+  positions that hold a real token: the recurrence must not advance on the
+  others (a padded prefill bucket, an idle decode row);
 - ``head(params, x) -> logits``: final norm and output head;
 - ``state_spec``: ``None``, or ``{name: (per-slot shape, dtype)}`` — the
   slot-indexed arenas the engine keeps per layer beside the paged K/V;
@@ -57,7 +65,12 @@ contains no model. A model that can be served implements
   keeps a page for every ``page_len`` tokens cached. ``attend(q, k, v)`` is
   the K/V form, built for the layer it serves: it carries the layer's kind
   as ``attend.kind`` (what a block needs to pick its RoPE), and the query's
-  own shape says how many heads the layer has — ``num_heads`` is not read;
+  own shape says how many heads the layer has — ``num_heads`` is not read.
+  ``{"kind": "none"}`` (a model whose every layer keeps a recurrent state
+  and NOTHING else: Brumby's power retention): a token leaves nothing in
+  pages. The pool builds no K/V arena, the window programs take no page
+  table, admission counts slots alone, ``max_seq_len`` bounds positions only
+  and ``block`` is handed ``attend=None``; it needs a ``state_spec``;
 - ``program_counters``: ``None``, or the names of int32 scalars a block may
   hand back as a THIRD result (``(x, state, {name: scalar})``, ``None`` from
   a layer that has none). The window program sums them over its layers and
@@ -70,9 +83,11 @@ contains no model. A model that can be served implements
   ``cache_spec`` of kind ``"latent"`` or ``"kv_by_layer"`` and no
   ``state_spec``. Then a chunk's row and a round's rows are just ``C + S``
   tokens to everything position-wise in ``block``, and only ``attend``
-  tells them apart. Falcon-H1 is out: a prefill starts its state from zero
-  while a round advances the slot arenas in place — two conventions in one
-  program. GPT-2 is out: ``pt_paged_attention`` walks every page of every
+  tells them apart. A state model is out (Falcon-H1, Brumby): a prefill
+  starts its state from zero or from its previous chunk's while a round
+  advances the slot arenas in place — two conventions in one program; the
+  engine sends a round of its own BETWEEN two chunks of such a prompt
+  instead (``GenerationEngine._round_between``). GPT-2 is out: ``pt_paged_attention`` walks every page of every
   slot whatever the lengths and each of its window programs copies the
   whole arenas between two layouts; once it moves onto the ranged kernel's
   layout (ROADMAP S2) it inherits the carried step through this property.
@@ -86,7 +101,9 @@ goes in the model's own words (``tools/program_parts.py``), and
 ``tests/test_step_parts.py`` holds every served model to it.
 
 A model with recurrent state cannot use what assumes a cache is pages of
-K/V (the prefix trie, speculative verify, KV-page export/install), a latent
+K/V (the prefix trie, speculative verify, KV-page export/install) — one with
+nothing paged least of all, and the warm tier neither: there is no page to
+share, spill or ship, and no cache of state snapshots is built — a latent
 cache cannot yet use what moves K/V pages (export/install and its wire
 format, the warm tier) — with an index row it shares index keys through the
 prefix trie like latent rows (one page table) but refuses a draft model too —
@@ -118,10 +135,14 @@ class ServedModel:
     # None: the only cache is the paged K/V
     state_spec: Optional[Dict[str, Tuple[Tuple[int, ...], Any]]] = None
     # None: a token leaves K and V of [num_kv_heads, head_dim] in a layer;
-    # else {"kind": "latent", ...} or {"kind": "kv_by_layer", ...}
+    # else {"kind": "latent", ...}, {"kind": "kv_by_layer", ...} or
+    # {"kind": "none"} (nothing paged: the state is the model's memory)
     cache_spec: Optional[Dict[str, Any]] = None
     # None: the window programs hand back tokens and logprobs alone
     program_counters: Optional[Tuple[str, ...]] = None
+    # True: ``block`` goes on from a state a previous prefill chunk returned
+    # (and takes ``step=`` to tell that from the slot arenas of a round)
+    resumes_state: bool = False
 
     @property
     def carries_rounds(self) -> bool:

@@ -234,6 +234,40 @@ def test_ssm_step_published_widths(one_chip):
     assert "output_to_operand_aliasing" in text
 
 
+def test_retention_step_published_widths(one_chip):
+    """One decode step of power retention at Brumby-14B's widths (40 query
+    heads x 128 over 8 K/V heads, the tiled phi's 8704 rows) over a 20-slot
+    arena: both states go in and come out of the SAME buffers."""
+    from paddle_tpu.kernels.pallas import power_retention as pr
+
+    f32 = jnp.float32
+    R, H, Hk, d = 20, 40, 8, 128
+    text = _compile(
+        lambda S, Z, q, k, v, lg, valid: pr.retention_step(
+            S, Z, q, k, v, lg, valid, impl="pallas"),
+        one_chip, ((R, Hk, pr.phi_dim(d), d), f32), ((R, Hk, d, d), f32),
+        ((R, H, d), BF16), ((R, Hk, d), BF16), ((R, Hk, d), BF16),
+        ((R, Hk), f32), ((R,), jnp.bool_), names=("pt_retention_step",))
+    assert "output_to_operand_aliasing" in text
+
+
+def test_retention_chunk_published_widths(one_chip):
+    """A 2048-token prefill chunk of power retention at Brumby-14B's widths
+    from a given state, inner chunks of 128, the state resident in VMEM."""
+    from paddle_tpu.kernels.pallas import power_retention as pr
+
+    f32 = jnp.float32
+    W, H, Hk, d = 2048, 40, 8, 128
+    text = _compile(
+        lambda S, Z, q, k, v, lg, valid: pr.retention_chunk(
+            S, Z, q, k, v, lg, valid, chunk=128, impl="pallas"),
+        one_chip, ((1, Hk, pr.phi_dim(d), d), f32), ((1, Hk, d, d), f32),
+        ((1, W, H, d), BF16), ((1, W, Hk, d), BF16), ((1, W, Hk, d), BF16),
+        ((1, W, Hk), f32), ((1, W), jnp.bool_),
+        names=("pt_retention_chunk",))
+    assert "output_to_operand_aliasing" in text
+
+
 def test_moe_routing_dispatch(one_chip, monkeypatch):
     """The ``moe`` recipe's layer (hidden 1536, 8 experts, top-2, expert
     MLP 2048) through the fused routing/dispatch kernels, fwd + bwd.

@@ -1,5 +1,5 @@
 """The parts of a served model step (``observability.trace.parts``): every
-window program of the four served models names the part of the model that
+window program of the served models names the part of the model that
 asked for each piece of work, the names change nothing but metadata, and the
 programs tell themselves apart by name."""
 import re
@@ -9,7 +9,8 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu import serving
-from paddle_tpu.models import (FalconH1Config, FalconH1ForCausalLM,
+from paddle_tpu.models import (BrumbyConfig, BrumbyForCausalLM,
+                               FalconH1Config, FalconH1ForCausalLM,
                                GlmMoeDsaConfig, GlmMoeDsaForCausalLM,
                                GPTConfig, GPTForCausalLM, LagunaConfig,
                                LagunaForCausalLM, OpenPanguMoEConfig,
@@ -26,6 +27,7 @@ MODELS = {
     "openpangu": (OpenPanguMoEForCausalLM, OpenPanguMoEConfig.tiny),
     "laguna": (LagunaForCausalLM, LagunaConfig.tiny),
     "glm_dsa": (GlmMoeDsaForCausalLM, GlmMoeDsaConfig.tiny),
+    "brumby": (BrumbyForCausalLM, BrumbyConfig.tiny),
 }
 # decode, the largest prefill bucket (which carries the round where the
 # model's ``carries_rounds``), and a smaller bucket
@@ -140,6 +142,9 @@ def test_every_heavy_op_sits_under_a_part(lowered, model, program):
     assert not bare, bare[:5]
     used = {parts.part_of(st) for _op, st in heavy}
     want = {"attn_proj", "cache_write", "norm", "head"}
+    if model == "brumby":   # nothing paged: no cache to write
+        want = {"attn_proj", "attention", "norm", "mlp", "head"}
+        assert "cache_write" not in used and "mixer" not in used
     want |= {"mixer"} if model == "falcon_h1" else set()
     want |= {"router", "experts"} if model in ("openpangu", "laguna",
                                                "glm_dsa") else set()
@@ -157,9 +162,27 @@ def test_the_indexer_is_a_scope_inside_parts_not_a_part(lowered, program):
     assert len(inside) > 10
     around = {parts.part_of(st) for _op, st in inside}
     assert {"attn_proj", "attention"} <= around <= set(parts.PARTS), around
-    assert "indexer" not in parts.PARTS and parts.SUBPARTS == ("indexer",)
+    assert "indexer" not in parts.PARTS and "indexer" in parts.SUBPARTS
     with pytest.raises(ValueError, match="not a subpart"):
         parts.subpart("attention")
+
+
+@pytest.mark.parametrize("program", list(PROGRAMS))
+def test_the_retention_is_a_scope_inside_attention_not_a_part(lowered,
+                                                              program):
+    """The nested ``pt.retention`` scope marks a power retention layer's
+    kernel calls and their glue: every op under it is ``attention``'s (not
+    ``mixer``'s: that part is Falcon-H1's), and the projections, the head
+    norms, RoPE and the gate are ``attn_proj``'s."""
+    _eng, progs = lowered("brumby")
+    ops = ops_with_name_stacks(progs[program].as_text(debug_info=True))
+    inside = [(op, st) for op, st in ops if "pt.retention" in st.split("/")]
+    assert len(inside) > 10
+    assert {parts.part_of(st) for _op, st in inside} == {"attention"}
+    assert parts.SUBPARTS == ("indexer", "retention")
+    assert "retention" not in parts.PARTS
+    gate = [st for op, st in ops if op == "logistic" or "log_sigmoid" in st]
+    assert gate and all(parts.part_of(st) == "attn_proj" for st in gate)
 
 
 @pytest.mark.parametrize("program", list(PROGRAMS))
