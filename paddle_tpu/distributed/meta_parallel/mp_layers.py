@@ -31,15 +31,21 @@ def _mp_degree():
     return env.get_dim("mp") if env is not None else 1
 
 
-def mark_sharding(x: Tensor, *spec) -> Tensor:
-    """with_sharding_constraint wrapper (annotation no-op off-mesh)."""
+def mark_sharding(x: Tensor, *spec, name=None) -> Tensor:
+    """with_sharding_constraint wrapper (annotation no-op off-mesh); ``name``
+    is a ``checkpoint_name`` for the constrained value."""
     env = get_mesh_env()
     if env is None:
         return x
-    return _shard_constraint(x, spec=tuple(spec), _env_id=id(env))
+    return _shard_constraint(x, spec=tuple(spec), _env_id=id(env), name=name)
 
 
-def _mark_feature(x: Tensor, feature) -> Tensor:
+# what a row-parallel layer's all-reduce over mp produced: the layer scan's
+# recompute keeps it under every policy (stage_stack.remat_wrap)
+MP_OUT = "mp_out"
+
+
+def _mark_feature(x: Tensor, feature, name=None) -> Tensor:
     """Constrain only the dim a tensor-parallel layer owns: the last
     (feature) dim is on ``"mp"`` or replicated (``None``). The leading dims
     are left ``UNCONSTRAINED`` — the layer cannot know which of them is the
@@ -47,7 +53,8 @@ def _mark_feature(x: Tensor, feature) -> Tensor:
     (``models/llama.py:_mark_seq``) and GSPMD propagates them. A ``None``
     there would say "whole on every device" and make every data replica
     gather the global batch and repeat the others' work."""
-    return mark_sharding(x, *([P.UNCONSTRAINED] * (x.ndim - 1) + [feature]))
+    return mark_sharding(x, *([P.UNCONSTRAINED] * (x.ndim - 1) + [feature]),
+                         name=name)
 
 
 def constrain_spec(arr, spec):
@@ -95,8 +102,13 @@ def constrain_spec(arr, spec):
 
 
 @primitive("shard_constraint")
-def _shard_constraint(x, *, spec, _env_id):
-    return constrain_spec(x, spec)
+def _shard_constraint(x, *, spec, _env_id, name=None):
+    out = constrain_spec(x, spec)
+    if name is not None:
+        from jax.ad_checkpoint import checkpoint_name
+
+        out = checkpoint_name(out, name)
+    return out
 
 
 class VocabParallelEmbedding(nn.Layer):
@@ -171,8 +183,11 @@ class RowParallelLinear(nn.Layer):
         if self.input_is_parallel:
             x = _mark_feature(x, "mp")
         out = F.linear(x, self.weight, None)
-        # partial sums reduce here (XLA inserts the all-reduce / reduce-scatter)
-        out = _mark_feature(out, None)
+        # partial sums reduce here (XLA inserts the all-reduce / reduce-scatter).
+        # Where mp really splits the rows the sum crossed the wire: it is
+        # named, so a recompute keeps it instead of reducing again
+        out = _mark_feature(out, None,
+                            name=MP_OUT if _mp_degree() > 1 else None)
         if self.bias is not None:
             out = out + self.bias
         return out

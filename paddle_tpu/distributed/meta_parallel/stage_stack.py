@@ -25,6 +25,7 @@ from ...core.dispatch import primitive
 from ...core.tensor import Tensor
 from ...nn.layer.layers import Layer, Parameter
 from ..mesh import get_mesh_env
+from .mp_layers import MP_OUT
 
 _RUN_REGISTRY = {}
 
@@ -58,8 +59,12 @@ def _memory_sharding(kind: str):
 
 
 def remat_wrap(fn):
-    """jax.checkpoint with the policy chosen by FLAGS_remat_policy:
-    '' = full remat (save inputs only, recompute everything — min memory),
+    """jax.checkpoint with the policy chosen by FLAGS_remat_policy. Every
+    policy keeps what crossed ``mp`` (a row-parallel layer's all-reduced
+    output, named ``mp_layers.MP_OUT``: 2 x batch x seq x hidden bytes a
+    layer; off a mesh with ``mp`` nothing carries the name and nothing is kept):
+    '' = full remat (save the inputs and that, recompute everything else —
+    min memory),
     'dots' = save dot/matmul outputs without batch dims (skip re-running the
     MXU work in backward at the cost of activation HBM — the reference's
     selective-recompute tier), 'dots_all' = save every matmul output,
@@ -72,30 +77,26 @@ def remat_wrap(fn):
         pol = flags_mod.get_flags("FLAGS_remat_policy")["FLAGS_remat_policy"]
     except Exception:
         pol = ""
-    policy = None
-    if pol == "dots":
-        policy = jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-    elif pol == "dots_all":
-        policy = jax.checkpoint_policies.dots_saveable
-    elif pol == "flash":
-        # save the flash-attention outputs (o + lse, named in
-        # kernels/flash_attention.py) so the backward recompute skips the
-        # forward Pallas kernel — ~50MB/layer for the fwd kernel's time
-        policy = jax.checkpoint_policies.save_only_these_names(
-            "flash_o", "flash_lse")
-    elif pol == "moe":
-        # MoE-selective: pin the expert capacity buffer + expert outputs
-        # (named in nn/layer/moe.py) and the flash residuals; the backward
-        # recompute then rebuilds only the g/u projections from the saved
-        # buffer instead of re-running routing + dispatch + down-proj
-        policy = jax.checkpoint_policies.save_only_these_names(
-            "flash_o", "flash_lse", "moe_buf", "moe_out", "moe_route")
-    elif pol == "route":
-        # pin ONLY the routing decisions (slot/keep/src maps + gates,
-        # ~1MB/layer): the backward recompute replays the expert matmuls
-        # but skips the router matmul/softmax/top_k/cumsum/int-scatter
-        # chain — near-zero memory for the routing chain's time
-        policy = jax.checkpoint_policies.save_only_these_names("moe_route")
+    policies = jax.checkpoint_policies
+    # 'flash': the flash-attention outputs (o + lse, named in
+    # kernels/flash_attention.py) so the backward recompute skips the forward
+    # Pallas kernel — ~50MB/layer for the fwd kernel's time.
+    # 'moe': the expert capacity buffer + expert outputs (named in
+    # nn/layer/moe.py) and the flash residuals; the backward recompute then
+    # rebuilds only the g/u projections from the saved buffer instead of
+    # re-running routing + dispatch + down-proj.
+    # 'route': ONLY the routing decisions (slot/keep/src maps + gates,
+    # ~1MB/layer): the backward recompute replays the expert matmuls but skips
+    # the router matmul/softmax/top_k/cumsum/int-scatter chain — near-zero
+    # memory for the routing chain's time
+    names = {"flash": ("flash_o", "flash_lse"),
+             "moe": ("flash_o", "flash_lse", "moe_buf", "moe_out", "moe_route"),
+             "route": ("moe_route",)}.get(pol, ())
+    policy = policies.save_only_these_names(MP_OUT, *names)
+    dots = {"dots": policies.dots_with_no_batch_dims_saveable,
+            "dots_all": policies.dots_saveable}.get(pol)
+    if dots is not None:
+        policy = policies.save_from_both_policies(dots, policy)
     return jax.checkpoint(fn, policy=policy)
 
 

@@ -120,7 +120,8 @@ define_flag("flash_bwd_block_q", 0,
 define_flag("flash_bwd_block_k", 0,
             "flash-attention BACKWARD k block size (0 = same as forward)")
 define_flag("remat_policy", "",
-            "recompute policy for scanned stacks: ''=full remat, 'dots'=save "
+            "recompute policy for scanned stacks: ''=full remat (every policy "
+            "keeps what a row-parallel layer all-reduced over mp), 'dots'=save "
             "non-batch matmul outputs, 'dots_all'=save all matmul outputs, "
             "'flash'=save flash-attention o+lse (skips the fwd kernel in "
             "the backward recompute), 'moe'=also pin the MoE capacity "

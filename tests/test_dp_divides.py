@@ -24,6 +24,7 @@ from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
 from paddle_tpu.models.llama import _fused_linear_ce
 
 B, S, HIDDEN = 8, 32, 64
+ACTIVATION = f"f32[{B // 2},{S},{HIDDEN}]"   # of one of two data replicas
 DATA_AXES = {"dp", "sdp"}
 
 
@@ -75,6 +76,14 @@ def one_device_losses():
     return [float(step(x, x)) for _ in range(2)]
 
 
+def _mp_activation_reduces(rows):
+    """(operands, count) of each ``mp`` all-reduce that carries a replica's
+    activation."""
+    return {(len(r["shapes"]), r["count"]) for r in rows
+            if r["op"] == "all-reduce" and r["axes"] == ("mp",)
+            and ACTIVATION in r["shapes"]}
+
+
 MESHES = {"dp2-mp2": dict(dp=2, mp=2), "sdp2-mp2": dict(sharding=2, mp=2),
           "dp2-cp2": dict(dp=2, cp=2)}
 
@@ -108,17 +117,14 @@ def test_data_axes_divide_the_compiled_step(mesh, norms, one_device_losses,
     assert not too_many, f"mp all-reduces on more than a replica's rows: {too_many}"
     if env.get_dim("mp") > 1:
         # a replica's activation crosses mp where the math sums it (the
-        # embedding, o_proj and down_proj, o_proj again in the recompute,
-        # the column-parallel layers' input gradients) and nowhere else,
-        # whichever implementation the norms take: four lone instructions
-        # and two XLA combined, nine operands. A custom_vjp INSIDE a
-        # check_vma=False shard_map cost three more, named psum: JAX's
-        # transpose adding equal copies of dx together
-        activation = f"f32[{B // 2},{S},{HIDDEN}]"
-        assert {(len(r["shapes"]), r["count"]) for r in rows
-                if r["op"] == "all-reduce" and r["axes"] == ("mp",)
-                and activation in r["shapes"]} == {(1, 4), (2, 1), (3, 1)}, rows
-        assert not re.findall(rf"%psum[\w.\-]* = {re.escape(activation)}\S* "
+        # embedding, o_proj and down_proj, the column-parallel layers'
+        # input gradients) and nowhere else — the recompute keeps o_proj's
+        # sum (ISSUE 47) — whichever implementation the norms take: three
+        # lone instructions and two XLA combined, eight operands. A
+        # custom_vjp INSIDE a check_vma=False shard_map cost three more,
+        # named psum: JAX's transpose adding equal copies of dx together
+        assert _mp_activation_reduces(rows) == {(1, 3), (2, 1), (3, 1)}, rows
+        assert not re.findall(rf"%psum[\w.\-]* = {re.escape(ACTIVATION)}\S* "
                               r"all-reduce", text)
     # both replicas computing identical gradients need no reduction over the
     # data axes; dividing the batch does
@@ -135,6 +141,75 @@ def test_data_axes_divide_the_compiled_step(mesh, norms, one_device_losses,
 
     losses = [float(step(x, x)) for _ in range(2)]
     np.testing.assert_allclose(losses, one_device_losses, rtol=1e-5)
+
+
+# -- the recompute keeps what crossed mp (ISSUE 47) ---------------------------
+
+def _plain_checkpoint(monkeypatch):
+    """The layer scan's recompute as it was: ``jax.checkpoint``'s own policy."""
+    from paddle_tpu.distributed.meta_parallel import stage_stack
+
+    monkeypatch.setattr(stage_stack, "remat_wrap", jax.checkpoint)
+
+
+def _traced_text(step, x):
+    """The step's jaxpr as text: ``checkpoint_name`` shows as ``name=``."""
+    from paddle_tpu.jit import _batch_arrays, step_args
+
+    arrays = _batch_arrays((x, x))
+    step._ensure_built(arrays)
+    fn = step._jitted
+    while not hasattr(fn, "trace"):
+        fn = fn.__wrapped__
+    return str(fn.trace(*step_args(step, arrays, jax.random.key(0))).jaxpr)
+
+
+@pytest.mark.dist
+@pytest.mark.parametrize("mesh", ["dp2-mp2", "sdp2-mp2"])
+def test_recompute_keeps_what_crossed_mp(mesh, monkeypatch):
+    """o_proj's all-reduce is not sent again in the recompute, under any
+    policy, and nothing else about the step changes: its losses are
+    ``jax.checkpoint``'s plain policy's, bit for bit."""
+    def losses_and_rows(policy="", steps=3):
+        paddle.set_flags({"FLAGS_remat_policy": policy})
+        try:
+            dist.reset_mesh()
+            dist.init_mesh(**MESHES[mesh], devices=jax.devices()[:4])
+            step, x = _step_and_batch(dist.ShardedTrainStep)
+            rows = step.collectives(x, x)
+            return ([float(step(x, x)) for _ in range(steps)], rows,
+                    _traced_text(step, x))
+        finally:
+            paddle.set_flags({"FLAGS_remat_policy": ""})
+
+    kept, rows, traced = losses_and_rows()
+    assert "name=mp_out" in traced
+    assert _mp_activation_reduces(rows) == {(1, 3), (2, 1), (3, 1)}, rows
+    for policy in ("dots", "flash"):
+        _, rows, _ = losses_and_rows(policy, steps=0)
+        assert (1, 3) in _mp_activation_reduces(rows), (policy, rows)
+
+    _plain_checkpoint(monkeypatch)
+    plain, rows, _ = losses_and_rows()
+    assert (1, 4) in _mp_activation_reduces(rows), rows
+    assert kept == plain
+
+
+@pytest.mark.dist
+def test_no_mp_no_name_and_the_same_collectives(monkeypatch):
+    """The mesh decides: with no ``mp`` axis nothing carries the name, the
+    policy keeps nothing, and the compiled collectives are the plain
+    recompute's."""
+    dist.init_mesh(**MESHES["dp2-cp2"], devices=jax.devices()[:4])
+    step, x = _step_and_batch(dist.ShardedTrainStep)
+    assert "mp_out" not in _traced_text(step, x)
+    rows = step.collectives(x, x)
+
+    _plain_checkpoint(monkeypatch)
+    dist.reset_mesh()
+    dist.init_mesh(**MESHES["dp2-cp2"], devices=jax.devices()[:4])
+    step, x = _step_and_batch(dist.ShardedTrainStep)
+    assert step.collectives(x, x) == rows
 
 
 @pytest.mark.dist
