@@ -21,6 +21,21 @@ window layer's pages behind the window may have gone back to the allocator
 (their table entries are 0, the scratch page): they lie before ``lo`` or are
 masked by position.
 
+The tiles are a function of the call's shape (``choose_tiles``; no flag, no
+model's name): every tile of a row walks its range again and computes the
+block's every key, seen or masked, so ``walk_cost`` counts what a tiling
+walks — grid steps, iterations, pages DMA'd, the buffers' VMEM — and the
+chooser takes the least reckoned work that fits ``VMEM_BUDGET``, blocks
+reckoned in KEYS (``KP x PL``) and, in a window layer, bounded by the
+window's own width. The call asks Mosaic for the VMEM its tiles count
+(``vmem_limit_bytes``). At Laguna's shapes: a chunk of 256 to 2048 tokens
+rides 128 tokens a tile (768 or 1024 query rows a K/V head) over blocks of
+1024 keys in a full layer and of 256 in a window layer (a 512-key window
+plus the tile's own 128 tokens is 3.5 such blocks); a decode round (one
+token a row: nothing shares a block) blocks of 512 keys in a full layer and
+of ONE page in a window layer, which then DMAs the five pages that hold a
+visible key and no sixth.
+
 Layouts (``_build_window_step``):
 
 - ``q``:        [S, W, H, d], W window tokens a row
@@ -42,6 +57,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -50,13 +66,32 @@ from ..registry import register_kernel, resolve
 __all__ = ["ranged_paged_attention"]
 
 _NEG = -1e30
-# query rows (tile tokens x the query heads of ONE K/V head) a grid step holds
-# for each K/V head, and the pages a loop iteration folds in: a full layer
-# walks 512 tokens an iteration, a window layer 256 (a window of 512 then
-# spans at most three blocks: 6 pages DMA'd for the 5 that hold a visible key)
-_ROWS = 256
-_PAGES_FULL = 4
-_PAGES_WINDOW = 2
+# Mosaic's scoped default is 16 MB of a v5e's 128; a call asks for what
+# `walk_cost` counts for its tiles (``vmem_limit_bytes``), under this budget
+VMEM_BUDGET = 48 * 2 ** 20
+# float32 [rows, block] tiles the compiler holds at once: the scores, their
+# exponentials and the bfloat16 copy, of the two or three K/V heads it keeps
+# in flight (bisecting the limit at the published shapes found 2 to 7)
+_SCORE_TILES = 8
+# what `choose_tiles` reckons a tiling's work with, in (query row x key)
+# units; measured on a v5e over 90 (shape, context, tiling) points (PERF.md
+# section 6, PR 48). A K/V head's slab rides the MXU in passes of 128 rows, so
+# fewer rows cost 128. An iteration costs its block's keys and a fixed part
+# worth `_ITER_KEYS` more; where the slab fills a pass, the softmax's
+# reductions and the rescale of the running state — work a row, whatever the
+# block — hide behind the matmuls only from `_ITER_FLOOR_KEYS` keys a block
+# on. A grid step costs its first block's DMA, which nothing hides, and
+# `_STEP_KEYS` keys of one pass. The tokens cached in front of the window the
+# work is reckoned at: `_REFERENCE_KEYS` (and 15 more spread over the next
+# 2048, so that no block size sits on a lucky boundary).
+_MXU_ROWS = 128
+_ITER_KEYS = 128
+_ITER_FLOOR_KEYS = 768
+_STEP_KEYS = 2048
+_REFERENCE_KEYS = 4096 + 131 * np.arange(16)
+# a window's worst walk DMAs at most this share of the window's own keys (6
+# pages of 128 for a window of 512, of which a row sees 5)
+_WINDOW_WALK = 1.5
 
 
 def _kernel(tbl_ref, start_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
@@ -113,45 +148,106 @@ def _kernel(tbl_ref, start_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem,
                 q_ref[0, g], k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
             sc = jnp.where(seen, sc, _NEG)
-            m_prev = m_ref[g, :, :1]
+            m_prev = m_ref[g]
             m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
             alpha = jnp.exp(m_prev - m_new)
             p = jnp.where(sc > _NEG * 0.5, jnp.exp(sc - m_new), 0.0)
-            l_new = alpha * l_ref[g, :, :1] + \
-                jnp.sum(p, axis=-1, keepdims=True)
+            l_new = alpha * l_ref[g] + jnp.sum(p, axis=-1, keepdims=True)
             acc_ref[g] = acc_ref[g] * alpha + jax.lax.dot_general(
                 p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
-            m_ref[g] = jnp.broadcast_to(m_new, m_ref.shape[1:])
-            l_ref[g] = jnp.broadcast_to(l_new, l_ref.shape[1:])
+            m_ref[g] = m_new
+            l_ref[g] = l_new
         return carry
 
     jax.lax.fori_loop(lo, hi + 1, body, 0)
     for g in range(G):
-        o_ref[0, g] = (acc_ref[g] / jnp.maximum(l_ref[g, :, :1], 1e-30)) \
+        o_ref[0, g] = (acc_ref[g] / jnp.maximum(l_ref[g], 1e-30)) \
             .astype(o_ref.dtype)
 
 
-def _tile_tokens(W: int, Hg: int) -> int:
-    """Window tokens a grid step holds: the largest power of two that keeps
-    ``TW x Hg`` query rows within ``_ROWS`` and divides ``W``."""
-    tw = 1
-    while tw * 2 * Hg <= _ROWS and W % (tw * 2) == 0:
-        tw *= 2
-    return tw
+def _padded_rows(Hg: int, TW: int) -> int:
+    """Query rows a K/V head's slab holds in a grid step: ``Hg x TW`` in
+    whole (16, 128) tiles of a 16-bit query."""
+    return -(-Hg * TW // 16) * 16
+
+
+def walk_cost(S, W, Hg, G, PL, d, window, keys, tiling, itemsize=2):
+    """What one call of ``S`` rows x ``W`` window tokens does under
+    ``tiling = (TW, KP)``, reckoned from shapes and lengths alone. ``keys``:
+    the tokens cached in front of a row's window (a number, or one a row).
+    Returns a dict: ``steps`` (grid steps), ``iterations`` (blocks folded in,
+    over all steps: every tile walks ``[lo, hi]`` again), ``pages`` and
+    ``bytes`` (K and V pages DMA'd), ``pages_in_range`` (the pages that hold
+    a key some query of the row sees: what one walk a row would DMA),
+    ``work`` (what `choose_tiles` compares: the module's constants) and
+    ``vmem`` (bytes of the call's buffers: the K / V double buffers, the
+    pipeline's two ``q`` and two ``o`` blocks, ``acc``, ``m`` / ``l`` — a
+    float32 a row each, which Mosaic pads to a lane tile — and the score
+    tiles in flight)."""
+    TW, KP = tiling
+    KB, T, Rp = KP * PL, W // TW, _padded_rows(Hg, TW)
+    keys = np.broadcast_to(np.asarray(keys, np.int64), (S,))
+    base = keys[:, None] + np.arange(T) * TW                    # [S, T]
+    first = np.zeros_like(base) if window is None else \
+        np.maximum(base - (window - 1), 0)
+    iterations = int(((base + TW - 1) // KB - first // KB + 1).sum())
+    in_range = int(((keys + W - 1) // PL - first[:, 0] // PL + 1).sum())
+    page = 2 * G * PL * d * itemsize                # a page's K and V
+    floor = _ITER_FLOOR_KEYS if Rp >= _MXU_ROWS else 0
+    work = max(Rp, _MXU_ROWS) * (iterations * (max(KB, floor) + _ITER_KEYS)
+                                 + S * T * KB) + S * T * _MXU_ROWS * _STEP_KEYS
+    vmem = 2 * KP * page + 4 * G * Rp * d * itemsize \
+        + G * Rp * (d + 2 * 128) * 4 + _SCORE_TILES * Rp * KB * 4
+    return {"steps": S * T, "iterations": iterations,
+            "pages": iterations * KP, "bytes": iterations * KP * page,
+            "pages_in_range": in_range, "work": work, "vmem": vmem}
+
+
+@functools.lru_cache(maxsize=None)
+def choose_tiles(W, Hg, G, PL, d, window, itemsize=2):
+    """The ``(TW, KP)`` of a call of ``W`` window tokens a row — ``TW``
+    tokens a grid step (``Hg x TW`` query rows a K/V head) and ``KP`` pages a
+    block — from the call's shape alone: of the powers of two ``TW`` that
+    divide ``W`` and the blocks of ``2^n`` pages up to 2048 keys, whose
+    buffers fit `VMEM_BUDGET` and, in a window layer, whose worst walk of
+    one token's window stays within `_WINDOW_WALK` of the window, the pair
+    of the least `walk_cost` ``work`` at the reference contexts; the fewest
+    DMA'd bytes, then the fewest iterations among equals. The block is
+    reckoned in KEYS, so pages of 16 tokens get blocks of many pages by the
+    same rule. At Laguna's shapes (pages of 128, 6 or 8 query heads a K/V
+    head of 128): a chunk of 256 to 2048 tokens ``(128, 8)`` in a full layer
+    and ``(128, 2)`` in a window layer, a decode round ``(1, 4)`` and
+    ``(1, 1)``."""
+    fits = []
+    for TW in (1 << n for n in range(W.bit_length()) if W % (1 << n) == 0):
+        for KP in (1 << n for n in range(12) if n == 0 or PL << n <= 2048):
+            KB = KP * PL
+            c = walk_cost(len(_REFERENCE_KEYS), W, Hg, G, PL, d, window,
+                          _REFERENCE_KEYS, (TW, KP), itemsize)
+            # a block of one page is the least a walk can DMA
+            walk = 0 if window is None or KP == 1 else \
+                (-(-(window - 1) // KB) + 1) * KB
+            if c["vmem"] <= VMEM_BUDGET and walk <= _WINDOW_WALK * (
+                    window or 1):
+                fits.append(((c["work"], c["bytes"], c["iterations"]),
+                             (TW, KP)))
+    # one token a step over one page a block fits whatever the head
+    return min(fits)[1]
 
 
 def _pallas(q, k_arena, v_arena, tables, start, window, scale, interpret):
     S, W, H, d = q.shape
     _P, G, PL, _ = k_arena.shape
     Hg, B = H // G, tables.shape[1]
-    KP = _PAGES_FULL if window is None else _PAGES_WINDOW
+    tiling = TW, KP = choose_tiles(W, Hg, G, PL, d, window,
+                                   k_arena.dtype.itemsize)
+    vmem = walk_cost(S, W, Hg, G, PL, d, window, 0, tiling,
+                     k_arena.dtype.itemsize)["vmem"]
     n_blk = -(-B // KP)
     # whole blocks: the pages past a row's table are the scratch page
     tables = jnp.pad(tables, ((0, 0), (0, n_blk * KP - B)))
-    TW = _tile_tokens(W, Hg)
-    T, R = W // TW, Hg * TW
-    Rp = -(-R // 16) * 16    # whole (16, 128) tiles of a 16-bit query
+    T, R, Rp = W // TW, Hg * TW, _padded_rows(Hg, TW)
     # [S, W, H, d] -> for each K/V head the tiles' rows, head-major in a tile
     qt = q.reshape(S, T, TW, G, Hg, d).transpose(0, 3, 1, 4, 2, 5) \
         .reshape(S, G, T, R, d)
@@ -173,12 +269,13 @@ def _pallas(q, k_arena, v_arena, tables, start, window, scale, interpret):
                 pltpu.VMEM((2, G, KP * PL, d), k_arena.dtype),
                 pltpu.VMEM((2, G, KP * PL, d), v_arena.dtype),
                 pltpu.SemaphoreType.DMA((2, 2, KP)),
-                pltpu.VMEM((G, Rp, 128), jnp.float32),
-                pltpu.VMEM((G, Rp, 128), jnp.float32),
+                pltpu.VMEM((G, Rp, 1), jnp.float32),
+                pltpu.VMEM((G, Rp, 1), jnp.float32),
                 pltpu.VMEM((G, Rp, d), jnp.float32),
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((S, G, T * Rp, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=vmem),
         interpret=interpret,
     )(tables, start, qt, k_arena, v_arena)
     out = out.reshape(S, G, T, Rp, d)[:, :, :, :R]

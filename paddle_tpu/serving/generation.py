@@ -548,13 +548,20 @@ def _attention(sm, attends: Optional[Dict], name: str):
             ranged_paged_attention
 
         window = None if name == "full" else int(sm.cache_spec["window"])
+        # window tokens a row -> query heads a K/V head, noted as a shape is
+        # traced (the query's own shape says how many heads the layer has):
+        # what ``_count_walk`` needs to ask the kernel's chooser for the tiles
+        # of the calls it dispatches
+        walks: Dict[int, int] = {}
 
         @jax.jit
         def ranged_attend(q, kk, vv, table, lengths):
+            walks[q.shape[1]] = q.shape[2] // kk.shape[1]
             return ranged_paged_attention(q, kk, vv, table, lengths,
                                           window=window, scale=scale)
 
         fn = attention(ranged_attend)
+        fn.walks = walks
     if attends is not None:
         attends[name] = fn
     return fn
@@ -1187,6 +1194,10 @@ class GenerationEngine(EngineBase):
                     f"{S * self._wbound + chunk + 1}")
             self._layers_of = {kind: sm.cache_spec["layers"].count(kind)
                                for kind in ("full", "window")}
+            # a page as the ranged kernel sees it: K/V heads, tokens, head
+            # size, bytes an element (``_count_walk``)
+            self._page = (sm.num_kv_heads, pl, sm.head_dim,
+                          np.dtype(dtype).itemsize)
         self._pool = PagedKVPool(sm.num_layers, num_pages, pl,
                                  sm.num_kv_heads, sm.head_dim, dtype,
                                  prefix_cache=self.config.prefix_cache,
@@ -1534,6 +1545,30 @@ class GenerationEngine(EngineBase):
         if decode:
             self.metrics.inc("attn_keys_window_decode_total",
                              windowed * self._layers_of["window"])
+
+    def _count_walk(self, W: int, keys, decode: bool = False) -> None:
+        """What the two kinds' kernel calls of a program dispatched walk:
+        ``keys`` cached tokens in front of each row's ``W`` window tokens (one
+        number a row). The pages the kernel's tiles DMA for them
+        (``walk_cost``: every tile of a row walks its range again) and the
+        pages that hold a key in range, once a layer of the kind; a decode
+        round's part is counted apart too, as its keys are."""
+        from ..kernels.pallas.ranged_paged_attention import \
+            choose_tiles, walk_cost
+
+        G, PL, d, itemsize = self._page
+        for kind, layers in self._layers_of.items():
+            shape = (W, self._attends[kind].walks[W], G, PL, d,
+                     None if kind == "full" else self._win)
+            cost = walk_cost(len(keys), *shape, keys,
+                             choose_tiles(*shape, itemsize), itemsize)
+            for what in ("walked", "in_range"):
+                n = cost["pages" if what == "walked" else "pages_in_range"]
+                self.metrics.inc(f"attn_pages_{what}_{kind}_total",
+                                 n * layers)
+                if decode:
+                    self.metrics.inc(
+                        f"attn_pages_{what}_{kind}_decode_total", n * layers)
 
     def _run_window(self, rows: int, W: int, tables, tokens, lengths,
                     n_valid, prefill: bool = False, state=None):
@@ -2414,6 +2449,7 @@ class GenerationEngine(EngineBase):
         if self._by_layer:
             self._count_keys(n * lo + n * (n + 1) // 2,
                              self._keys_in_window(lo, hi, self._win))
+            self._count_walk(Wc, [lo])
         if len(adm.outs) < len(adm.chunks):
             adm.state = row if self._sm.resumes_state else None
             return
@@ -2780,6 +2816,8 @@ class GenerationEngine(EngineBase):
             seen = rnd.lengths[[i for i, _req in rnd.rows]] + 1
             self._count_keys(int(seen.sum()), int(
                 np.minimum(seen, self._win).sum()), decode=True)
+            # every row of the call walks, a live sequence's or not
+            self._count_walk(k + 1, rnd.lengths, decode=True)
         self.metrics.observe_occupancy(n_active / S)
         with span("pt.serve.emit"):
             emitted_total = self._emit_round(rnd, n, lpn)
@@ -2956,6 +2994,20 @@ class GenerationEngine(EngineBase):
             # call: 1 where the grouped matmul holds its contraction whole
             snap["moe_weight_streams_per_expert"] = round(
                 c.get("moe_weight_streams_total", 0) / hit, 4)
+        def pages(what, kind):  # the prefill calls', the decode rounds'
+            decode = c.get(f"attn_pages_{what}_{kind}_decode_total", 0)
+            return c.get(f"attn_pages_{what}_{kind}_total", 0) - decode, decode
+
+        for kind in ("full", "window"):
+            # pages the ranged kernel's tiles DMA'd for every page that held
+            # a key in range: 1 where a row's range is walked once, T / 2 and
+            # more where the T tiles of a chunk each walk the context again
+            for part, walked, held in zip(("prefill", "decode"),
+                                          pages("walked", kind),
+                                          pages("in_range", kind)):
+                if held:
+                    snap.setdefault("attn_walk_amplification", {}).setdefault(
+                        kind, {})[part] = round(walked / held, 3)
         if self.spec_k:
             prop = c.get("spec_proposed", 0)
             snap["spec_acceptance"] = round(

@@ -388,13 +388,25 @@ def test_held_experts_grouped_matmuls_published_widths(one_chip, monkeypatch,
 @pytest.mark.parametrize("S,W,H,window", [
     (128, 1, 48, None), (128, 1, 64, 512), (1, 2048, 48, None),
     (1, 2048, 64, 512), (1, 256, 64, 512)])
-def test_ranged_paged_attention_published_widths(one_chip, S, W, H, window):
+def test_ranged_paged_attention_published_widths(one_chip, monkeypatch, S, W,
+                                                 H, window):
     """Laguna-XS.2's attention over a range of pages: 48 / 64 query heads
     over 8 K/V heads of 128 against ``[pages, 8, 128, 128]`` arenas, 128
     blocks a row (16k tokens) — the 128-slot decode round in both kinds of
     layer, and one-row chunks of up to 2048 tokens (``pt_paged_attention``
-    compiles no window above 256)."""
+    compiles no window above 256) — under the tiles ``choose_tiles`` takes
+    from each shape and the ``vmem_limit_bytes`` ``walk_cost`` counts for
+    them: tiles the chip's VMEM refuses fail here, not in a chip call."""
     from paddle_tpu.kernels.pallas import ranged_paged_attention as kr
+
+    asked = []
+    cost = kr.walk_cost
+
+    def spy(*args, **kwargs):
+        asked.append((args[-2], cost(*args, **kwargs)))
+        return asked[-1][1]
+
+    monkeypatch.setattr(kr, "walk_cost", spy)
 
     def run(q, ka, va, tables, start):
         return kr.ranged_paged_attention(q, ka, va, tables, start,
@@ -402,8 +414,20 @@ def test_ranged_paged_attention_published_widths(one_chip, S, W, H, window):
                                          impl="pallas")
 
     pages = 5121 if window is None else 832
-    _compile(run, one_chip, ((S, W, H, 128), BF16),
-             ((pages, 8, 128, 128), BF16), ((pages, 8, 128, 128), BF16),
-             ((S, 128), jnp.int32), ((S,), jnp.int32),
-             names=("pt_ranged_attention_" + ("full" if window is None
-                                              else "window"),))
+    shapes = (((S, W, H, 128), BF16), ((pages, 8, 128, 128), BF16),
+              ((pages, 8, 128, 128), BF16), ((S, 128), jnp.int32),
+              ((S,), jnp.int32))
+    name = "pt_ranged_attention_" + ("full" if window is None else "window")
+    _compile(run, one_chip, *shapes, names=(name,))
+    # the call compiled under the limit the chooser's tiles asked for (the
+    # last reckoning is the call's own, the ones before it the chooser's)
+    tiles, c = asked[-1]
+    assert tiles == kr.choose_tiles(W, H // 8, 8, 128, 128, window, 2)
+    assert c["vmem"] <= kr.VMEM_BUDGET
+    if (S, W) == (1, 256):
+        # and the limit is the kernel's: a call (traced afresh) that asks
+        # for a tenth of what its buffers take is refused
+        monkeypatch.setattr(kr, "walk_cost", lambda *a, **k: dict(
+            cost(*a, **k), vmem=c["vmem"] // 10))
+        with pytest.raises(Exception, match="vmem"):
+            _compile(lambda *a: run(*a), one_chip, *shapes, names=(name,))

@@ -229,6 +229,59 @@ def test_chunked_prefill_across_the_window_then_decode_past_three_windows(
         c["attn_keys_window_total"]
 
 
+def test_the_engine_counts_the_pages_its_attention_calls_walk(tiny):
+    """``attn_pages_walked_<kind>_total`` / ``attn_pages_in_range_<kind>_total``
+    and ``stats()["attn_walk_amplification"]``: ``walk_cost`` of the tiles
+    ``choose_tiles`` gives each traced shape, over the calls dispatched — a prompt of three
+    chunks (behind 0, 16 and 32 tokens) and the rounds that follow, every
+    row of a round counted, idle ones too."""
+    from paddle_tpu.kernels.pallas.ranged_paged_attention import \
+        choose_tiles, walk_cost
+
+    cfg, model, _params, _get = tiny
+    eng = _engine(model)
+    prompt = np.random.default_rng(2).integers(0, cfg.vocab_size, 37)
+    _serve(eng, [prompt], [4])
+    st = eng.stats()
+    c = st["counters"]
+    G, PL, d = cfg.num_key_value_heads, 4, cfg.head_dim
+    layers = {"full": 2, "window": 3}
+    for kind, window in (("full", None), ("window", 8)):
+        walks = eng._attends[kind].walks
+        assert {1, 8, 16} <= set(walks)     # a round, the buckets called
+        want = {"pages": 0, "pages_in_range": 0}
+        # chunks of 16, 16 and 5 tokens (the last in the bucket of 8), then
+        # the three rounds that emit tokens 2..4: the prompt's slot at 37,
+        # 38, 39 and two idle rows at 0
+        calls = [(16, [0]), (16, [16]), (8, [32])] + \
+            [(1, [n, 0, 0]) for n in (37, 38, 39)]
+        Hg = cfg.num_attention_heads_per_layer[0 if kind == "full" else 1] // G
+        for W, keys in calls:
+            assert walks[W] == Hg
+            cost = walk_cost(len(keys), W, Hg, G, PL, d, window, keys,
+                             choose_tiles(W, Hg, G, PL, d, window, 4), 4)
+            for k in want:
+                want[k] += cost[k] * layers[kind]
+        walked = c[f"attn_pages_walked_{kind}_total"]
+        held = c[f"attn_pages_in_range_{kind}_total"]
+        assert (walked, held) == (want["pages"], want["pages_in_range"])
+        # the rounds' part apart: three rounds of three rows
+        dec = {"pages": 0, "pages_in_range": 0}
+        for W, keys in calls[3:]:
+            cost = walk_cost(3, 1, Hg, G, PL, d, window, keys,
+                             choose_tiles(1, Hg, G, PL, d, window, 4), 4)
+            for k in dec:
+                dec[k] += cost[k] * layers[kind]
+        assert c[f"attn_pages_walked_{kind}_decode_total"] == dec["pages"]
+        assert c[f"attn_pages_in_range_{kind}_decode_total"] == \
+            dec["pages_in_range"]
+        assert st["attn_walk_amplification"][kind] == {
+            "prefill": round((walked - dec["pages"])
+                             / (held - dec["pages_in_range"]), 3),
+            "decode": round(dec["pages"] / dec["pages_in_range"], 3)}
+        assert walked >= held > 0
+
+
 def test_keys_in_window_arithmetic():
     f = serving.GenerationEngine._keys_in_window
     for lo, hi, w in [(0, 3, 2), (0, 20, 8), (5, 9, 8), (7, 8, 8), (30, 46, 8)]:

@@ -629,6 +629,47 @@ def test_ranged_paged_attention_parity(S, W, Hg, window, starts):
     _close(got, want)
 
 
+@pytest.mark.parametrize("tiles,S,W,Hg,window,starts,given_back", [
+    # a chunk whose tile spans several blocks: 4 tiles of 16 tokens, blocks
+    # of 2 pages, 150 tokens cached in front (blocks 0..12)
+    ((16, 2), 1, 64, 6, None, [150], False),
+    # ... and whose tile is the whole window (one grid step a row)
+    ((64, 4), 1, 64, 8, None, [40], False),
+    # a window edge that falls inside a larger block: blocks of 4 pages (32
+    # keys) against a window of 24, tiles that start in the middle of one
+    ((8, 4), 1, 32, 8, 24, [53], False),
+    ((32, 4), 1, 64, 8, 24, [30], False),
+    # a block of ONE page, the window three pages wide
+    ((1, 1), 3, 1, 8, 24, [0, 37, 90], False),
+    ((4, 1), 2, 4, 6, 24, [5, 60], False),
+    # pages given back before lo: the table's entries behind the window are
+    # the scratch page, inside the first block walked too
+    ((1, 4), 2, 1, 8, 24, [70, 100], True),
+    ((16, 2), 1, 32, 8, 24, [77], True),
+    # a decode round over blocks of 3 pages: no power of two is asked of KP
+    ((1, 3), 3, 1, 6, None, [0, 37, 90], False),
+])
+def test_ranged_paged_attention_parity_over_tiles(
+        monkeypatch, tiles, S, W, Hg, window, starts, given_back):
+    """The kernel under the KINDS of tiling ``choose_tiles`` hands out at the
+    served shapes — many tokens a tile, blocks of one page and of several,
+    a whole window in one step — at this file's tiny pages: every one must
+    give the reference's result."""
+    kr, q, ka, va, tables, st = _ranged_case(S, W, Hg, starts, B=32, seed=5)
+    monkeypatch.setattr(kr, "choose_tiles", lambda *a, **k: tiles)
+    want = kr.ranged_paged_attention(q, ka, va, tables, st, window=window,
+                                     scale=0.09, impl="reference")
+    if given_back:
+        gone = np.asarray(tables).copy()
+        for r, s0 in enumerate(starts):
+            gone[r, :(s0 - (window - 1)) // 8] = 0
+        tables = jnp.asarray(gone)
+        ka = ka.at[0].set(1e3)      # the scratch page holds anything
+    got = kr.ranged_paged_attention(q, ka, va, tables, st, window=window,
+                                    scale=0.09, impl="interpret")
+    _close(got, want)
+
+
 @pytest.mark.parametrize("impl", ["interpret", "reference"])
 def test_ranged_paged_attention_window_edges(impl):
     """Off by one on neither end: the query at position i sees exactly the
