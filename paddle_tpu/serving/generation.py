@@ -580,16 +580,15 @@ class _Sparse:
     ``counters`` names what the program hands back beside the model's own."""
 
     def __init__(self, sm, attends: Optional[Dict], prefill: bool,
-                 carries: bool = False):
+                 decode: bool):
         kinds = list(sm.cache_spec["index"]["layers"])
         self.arena_of = {li: n for n, li in enumerate(
             i for i, kind in enumerate(kinds) if kind == "full")}
         self.select = _attention(sm, attends, "index_select")
         self.attend = _attention(sm, attends, "sparse")
-        self.counters = ("attn_keys_selected_prefill_total",
-                         "attn_keys_selected_decode_total") if carries else \
-            ("attn_keys_selected_prefill_total" if prefill
-             else "attn_keys_selected_decode_total",)
+        # a prompt's row, a round's rows, or (a carrying program) both
+        self.counters = ("attn_keys_selected_prefill_total",) * prefill + \
+            ("attn_keys_selected_decode_total",) * decode
 
 
 def packed_selection(bias):
@@ -606,16 +605,14 @@ def packed_selection(bias):
 def _counted(counter_names, counted, sparse=None, selected=(), picked=()):
     """A window program's last result: the model's ``program_counters``
     summed over its layers and, with an index row, the keys its live tokens
-    selected summed over the layers (``selected``: a scalar a layer, or a
-    (chunk, round) pair a layer in the program that carries a round) — and
-    under ``"selection"`` WHICH keys, where the program was built to say
-    (``picked``: ``packed_selection`` of every "full" layer's)."""
+    selected summed over the layers (``selected``: the layers' scalars, once
+    for each of ``sparse.counters``) — and under ``"selection"`` WHICH keys,
+    where the program was built to say (``picked``: ``packed_selection`` of
+    every "full" layer's)."""
     out = {name: sum((c[name] for c in counted[1:]), counted[0][name])
            for name in counter_names or () if counted}
     if sparse is not None:
-        pairs = [n if isinstance(n, tuple) else (n,) for n in selected]
-        for i, name in enumerate(sparse.counters):
-            out[name] = sum(p[i] for p in pairs)
+        out.update(zip(sparse.counters, map(sum, selected)))
     if picked:
         out["selection"] = list(picked)
     return out
@@ -632,22 +629,40 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     pool arenas and attends each window token causally against the page
     pool — and return the greedy argmax at every window position.
 
-    One body serves three roles, at two row counts (the fourth role of the
-    family, a prefill that CARRIES a decode round, ``carry`` > 0, has a body
-    of its own: ``_build_carrying_step``). At ``max_slots`` rows,
+    One body serves four roles, at two row counts. At ``max_slots`` rows,
     W=1 is the decode step and W=k+1 scores a draft model's k proposals
     (speculative verify); rows whose page table is all-zero write only the
     scratch page. At ONE row, W=bucket prefills a prompt suffix (cold
     prefill is the zero-prefix special case): an admission serves exactly
     one request, so its program has that request's row and no other, and
     touches that request's pages only. The arenas are the whole pool at
-    either row count.
+    either row count. And THE CARRIED STEP, ``R = carry`` > 0: ONE program
+    for a prompt's row of W tokens AND the ``R`` rows of a decode round, so
+    the running sequences advance while a prompt is prefilled and the
+    layers' weights (the experts' above all) are read once for both. Only a
+    one-row prefill of a model whose ``carries_rounds`` is true has one: the
+    cache's kernel takes each row's own range of pages and nothing recurs.
 
     ``step(params, k_arenas, v_arenas, tables, tokens, lengths,
     n_valid=None, state=None)`` returns ``(next, logprob, k_arenas,
     v_arenas, state)`` — and, for a model that declares ``program_counters``,
-    a sixth result ``counters``. The cache has one of three shapes, by the
-    model's ``cache_spec``:
+    a sixth result ``counters``. Under a carry each of ``tables``,
+    ``tokens``, ``lengths`` and ``n_valid`` is a PAIR — the prompt's ``[1,
+    ...]`` operand as a prefill takes it, the round's ``[R, ...]`` operand
+    as a decode step takes it — and ``next`` / ``logprob`` come back as
+    pairs too (``[1, 1]`` and ``[R, 1]``), the counters over all the
+    program's tokens, once. Everything position-wise in a block (embedding,
+    norms, projections, router, experts) runs ONCE over the ``W + R``
+    tokens, one row ``[1, W + R, h]`` with the positions and the ``valid``
+    mask of both parts; ``attend`` alone splits it, through two helpers:
+    ``land`` writes the chunk's keys and values (or latent rows) through the
+    prompt's table and the round's through theirs, ``call`` runs the layer's
+    kernel twice (the chunk's shape, the round's) and joins the results. The
+    head runs on ``1 + R`` rows. A round row that is idle has ``n_valid`` 0
+    and an all-zero table: it costs its grid step and no bytes. All of that
+    is Python at trace time (``if R:``): a program that carries nothing
+    traces none of it. The cache has one of four shapes, by the model's
+    ``cache_spec``:
 
     - ``None``: K and V arenas ``[pages, page_len, heads, dim]`` a layer,
       ``attend(q, k, v)``, ``kernels.pallas.paged_attention`` (below);
@@ -722,12 +737,15 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     import jax.numpy as jnp
 
     sm = _InParts(_served(served))
-    if carry:
+    # (the tables say n_blocks; under a carry a block sees N = W + R tokens)
+    R, S, W, PL = int(carry), max_slots, window, page_len
+    if R:
         assert not selection, "a carrying program serves requests"
-        return _build_carrying_step(sm, int(carry), max_slots, n_blocks,
-                                    page_len, window, donate, label, prefill,
-                                    attends, aligned)
-    kvh = sm.num_kv_heads
+        if not (prefill and S == 1 and sm.carries_rounds):
+            raise ValueError(
+                "only a one-row prefill of a model whose cache's kernel "
+                "takes each row's own range, and that keeps no recurrent "
+                "state, carries a decode round (ServedModel.carries_rounds)")
     stateful = sm.state_spec is not None
     cache_kind = None if sm.cache_spec is None else sm.cache_spec["kind"]
     latent = cache_kind == "latent"
@@ -741,7 +759,6 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
         raise ValueError(
             "a model with recurrent state decodes one token a round: "
             f"no {window}-token window over live state")
-    S, W, PL = max_slots, window, page_len     # (the tables say n_blocks)
     if fused is not True:
         raise ValueError(
             "fused= no longer selects a path: kernels.registry.resolve "
@@ -758,7 +775,7 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
         latent_attend = _attention(sm, attends, "latent")
         latent_query = _latent_query(DL - dl)
         if sm.cache_spec.get("index"):
-            sparse = _Sparse(sm, attends, prefill)
+            sparse = _Sparse(sm, attends, prefill, bool(R) or not prefill)
     elif by_layer:
         kinds = list(sm.cache_spec["layers"])
         ranged = {kind: _attention(sm, attends, kind)
@@ -772,21 +789,79 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
         if aligned else 0
     rows_of = functools.partial(write_rows, lead=3) if by_layer else write_rows
     write = write_pages if n_pages else rows_of
+    # a by-layer arena's row is a (token, K/V head), every other's a token
+    heads = sm.num_kv_heads if by_layer else 0
+
+    # Under a carry what is a row's own — its table, its length, where its
+    # tokens land, what it selected — is a (chunk, round) PAIR, as the
+    # operands arrive; ``land`` and ``call`` split the pairs, so the
+    # ``attend`` of a cache kind is written once
+    def land(arena, where, rows):
+        """The window's ``rows`` into ``arena`` at ``where``
+        (``chunk_where``). Under a carry the chunk's ``[:, :W]`` as the
+        program writes, whole pages where they are whole pages, and the
+        round's ``[:, W:]`` as rows, always: the two share no page but the
+        scratch one."""
+        if not R:
+            return write(arena, where, rows)
+        return rows_of(write(arena, where[0], rows[:, :W]), where[1],
+                       rows[:, W:])
+
+    @part("attention")
+    def both(kernel, q, chunk, round_):
+        """The chunk's queries ``q[:, :W]`` against the prompt's ``(table,
+        start)``, the round's, one a row, against theirs; joined as the
+        block handed them in."""
+        ctx = kernel(q[:, :W], *chunk)
+        r_ctx = kernel(jnp.swapaxes(q[:, W:], 0, 1), *round_)      # [R, 1]
+        return jnp.concatenate([ctx, jnp.swapaxes(r_ctx, 0, 1)], 1)
+
+    def call(kernel, q, *own):
+        """The layer's kernel, once: ``kernel(q, *own)``. Under a carry
+        twice, the chunk's shape and the round's (the same jitted callable,
+        ``_attention``), each against its own of every pair (``both``)."""
+        return both(kernel, q, *zip(*own)) if R else kernel(q, *own)
 
     def step(params, k_arenas, v_arenas, tables, tokens, lengths,
              n_valid=None, state=None):
         # tables: [S, B] page ids; tokens: [S, W]; lengths: [S] (int32)
-        pos = lengths[:, None] + jnp.arange(W)                     # [S, W]
-        x = sm.embed(params, tokens, pos)                          # [S, W, h]
+        if R:
+            # each of them and ``n_valid`` a pair, the prompt's [1, ..] and
+            # the round's [R, ..]; ONE row of N = W + R tokens to everything
+            # position-wise in a block
+            (tokens, r_tokens), (n_valid, r_valid) = tokens, n_valid
+            pos = lengths[0][:, None] + jnp.arange(W)              # [1, W]
+            r_pos = lengths[1][:, None]                            # [R, 1]
+            xpos = jnp.concatenate([pos, r_pos.reshape(1, R)], 1)  # [1, N]
+            tokens = jnp.concatenate([tokens, r_tokens.reshape(1, R)], 1)
+        else:
+            pos = xpos = lengths[:, None] + jnp.arange(W)          # [S, W]
+        x = sm.embed(params, tokens, xpos)                         # [S, W, h]
+        # (a carrying program makes its mask BEFORE its tokens' places, a
+        # row-only one after: the order is the lowered text's, which keys the
+        # compile cache)
+        if R:
+            valid = jnp.concatenate(
+                [jnp.arange(W)[None, :] < n_valid[:, None],
+                 (r_valid > 0)[None, :]], 1)                       # [1, N]
+
+        def places(table):
+            # where the window's tokens land through ``table`` (``land``)
+            if not R:
+                return chunk_where(table, lengths, pos, n_pages, PL, heads)
+            return (
+                chunk_where(table[0], lengths[0], pos, n_pages, PL, heads),
+                chunk_where(table[1], lengths[1], r_pos, 0, PL, heads))
+
         if by_layer:
-            by_kind = {kind: tables[i] for i, kind in
-                       enumerate(("full", "window"))}
-            where_of = {kind: chunk_where(t, lengths, pos, n_pages, PL, kvh)
-                        for kind, t in by_kind.items()}
+            by_kind = {kind: tuple(t[i] for t in tables) if R else tables[i]
+                       for i, kind in enumerate(("full", "window"))}
+            where_of = {kind: places(t) for kind, t in by_kind.items()}
         elif not unpaged:
-            where = chunk_where(tables, lengths, pos, n_pages, PL)
-        valid = None if n_valid is None else \
-            jnp.arange(W)[None, :] < n_valid[:, None]              # [S, W]
+            where = places(tables)
+        if not R:
+            valid = None if n_valid is None else \
+                jnp.arange(W)[None, :] < n_valid[:, None]          # [S, W]
         new_k, new_v, new_state, counted = [], [], [], []
         held, selected, picked = [None, None], [], []
         # nothing paged: no arena a layer, no table, and no ``attend``
@@ -800,22 +875,39 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
                 # a token rides as one slab against the pages its row's
                 # length covers (rows and queries padded to whole lanes)
                 lanes = [(0, 0)] * (row.ndim - 1) + [(0, DL - dl)]
-                arena = write(kc, where, jnp.pad(row, lanes))
+                arena = land(kc, where, jnp.pad(row, lanes))
                 new_k.append(arena)
                 q = latent_query(q_lat, q_rope)
                 if sparse is None:
-                    return latent_attend(q, arena, tables, lengths)
+                    return call(
+                        lambda q, t, at: latent_attend(q, arena, t, at),
+                        q, tables, lengths)
                 if index is not None:     # a "full" layer scores and selects
-                    keys = write(v_arenas[sparse.arena_of[li]], where,
-                                 index[2])
+                    qi, wi, ki = index
+                    keys = land(v_arenas[sparse.arena_of[li]], where, ki)
                     new_v.append(keys)
-                    held[:] = sparse.select(
-                        index[0], index[1], keys, tables, lengths,
-                        jnp.ones((S, W), bool) if valid is None else valid)
+                    if R:       # the chunk's tokens, then the round's rows
+                        rows = functools.partial(jnp.swapaxes, axis1=0,
+                                                 axis2=1)
+                        held[:] = zip(
+                            sparse.select(qi[:, :W], wi[:, :W], keys,
+                                          tables[0], lengths[0],
+                                          valid[:, :W]),
+                            sparse.select(rows(qi[:, W:]), rows(wi[:, W:]),
+                                          keys, tables[1], lengths[1],
+                                          rows(valid[:, W:])))
+                    else:
+                        held[:] = sparse.select(
+                            qi, wi, keys, tables, lengths,
+                            jnp.ones((S, W), bool) if valid is None
+                            else valid)
                     if selection:
                         picked.append(packed_selection(held[0][:, :W]))
                 selected.append(held[1])
-                return sparse.attend(q, arena, tables, lengths, held[0])
+                return call(
+                    lambda q, t, at, bias: sparse.attend(q, arena, t, at,
+                                                         bias),
+                    q, tables, lengths, held[0])
 
             def attend(q, k1, v1):
                 kk = write_rows(kc, where, k1)
@@ -828,14 +920,15 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
             kind = kinds[li] if by_layer else None
 
             def attend_ranged(q, k1, v1):
-                kk = write(kc, where_of[kind], k1)
-                vv = write(vc, where_of[kind], v1)
+                kk = land(kc, where_of[kind], k1)
+                vv = land(vc, where_of[kind], v1)
                 new_k.append(kk)
                 new_v.append(vv)
-                return ranged[kind](q, kk, vv, by_kind[kind], lengths)
+                return call(lambda q, t, at: ranged[kind](q, kk, vv, t, at),
+                            q, by_kind[kind], lengths)
 
             attend_ranged.kind = kind
-            out = sm.block(p, x, pos, attend_latent if latent else
+            out = sm.block(p, x, xpos, attend_latent if latent else
                            attend_ranged if by_layer else
                            None if unpaged else attend,
                            None if state is None else state[li], valid,
@@ -845,184 +938,26 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
             if counter_names and len(out) > 2 and out[2] is not None:
                 counted.append(out[2])
         if prefill:
-            x = _last_real(x, n_valid)                             # [S, 1, h]
+            # the head at the last real position alone, [S, 1, h] — and at
+            # every row of the round a prompt carries, [1, 1 + R, h]
+            x = jnp.concatenate([_last_real(x, n_valid, W), x[:, W:]], 1) \
+                if R else _last_real(x, n_valid)
         nxt, logp = _pick(sm.head(params, x))       # [S, W], [S, W] f32
+        if R:       # ([1, 1], [R, 1]): the prompt's, then the round's
+            nxt, logp = ((a[:, :1], a[0, 1:, None]) for a in (nxt, logp))
         out = (nxt, logp, new_k, new_v, new_state if stateful else None)
         if not counter_names and sparse is None:
             return out
-        return out + (_counted(counter_names, counted, sparse, selected,
-                               picked),)
+        return out + (_counted(counter_names, counted, sparse,
+                               zip(*selected) if R else [selected], picked),)
 
     donate_argnums = (1, 2, 7) if stateful else (1, 2)
-    step.__name__ = _program_name(label)
+    step.__name__ = _program_name(label, carries=bool(R))
 
     from ..jit import persistent_cache
 
     return persistent_cache.cached_jit(
         step, donate_argnums=donate_argnums if donate else (), label=label)
-
-
-def _build_carrying_step(sm: ServedModel, carry: int, rows: int,
-                         n_blocks: int, page_len: int, window: int,
-                         donate: bool, label: str, prefill: bool,
-                         attends: Optional[Dict], aligned: bool = True):
-    """THE CARRIED STEP, the fourth role of the paged family: ONE program for
-    a prompt's row of ``W = window`` tokens (a prefill: the head at its last
-    real position) AND the ``R = carry`` rows of a decode round, so the
-    running sequences advance while a prompt is prefilled and the layers'
-    weights (the experts' above all) are read once for both. Only a one-row
-    prefill of a model whose ``carries_rounds`` is true has one: the cache's
-    kernel takes each row's own range of pages and nothing recurs.
-
-    ``step(params, k_arenas, v_arenas, tables, tokens, lengths, n_valid,
-    state=None)``: each of ``tables``, ``tokens``, ``lengths`` and
-    ``n_valid`` is a PAIR — the prompt's ``[1, ...]`` operand as a prefill
-    takes it, the round's ``[R, ...]`` operand as a decode step takes it —
-    and ``next`` / ``logprob`` come back as pairs too (``[1, 1]`` and ``[R,
-    1]``), beside the arenas, ``None`` for the state and the model's
-    ``program_counters`` over all the program's tokens, once. Everything
-    position-wise in a block (embedding, norms, projections, router,
-    experts) runs ONCE over the ``W + R`` tokens, one row ``[1, W + R, h]``
-    with the positions and the ``valid`` mask of both parts; ``attend``
-    alone splits it: it writes the chunk's keys and values (or latent rows)
-    through the prompt's table and the round's through theirs, calls the
-    layer's kernel twice (the chunk's shape, the round's: the same jitted
-    callable, ``_attention``) and joins the results. The head runs on ``1 +
-    R`` rows. A round row that is idle has ``n_valid`` 0 and an all-zero
-    table: it costs its grid step and no bytes."""
-    import jax.numpy as jnp
-
-    if not (prefill and rows == 1 and sm.carries_rounds):
-        raise ValueError(
-            "only a one-row prefill of a model whose cache's kernel takes "
-            "each row's own range, and that keeps no recurrent state, "
-            "carries a decode round (ServedModel.carries_rounds)")
-    kvh = sm.num_kv_heads
-    latent = sm.cache_spec["kind"] == "latent"
-    counter_names = sm.program_counters
-    R, W, PL = carry, window, page_len         # (the tables say n_blocks)
-    # a block sees N = W + R tokens
-    sparse = None
-    if latent:
-        dl = sm.cache_spec["dim"]
-        DL = latent_width(dl)
-        latent_attend = _attention(sm, attends, "latent")
-        latent_query = _latent_query(DL - dl)
-        if sm.cache_spec.get("index"):
-            sparse = _Sparse(sm, attends, prefill, carries=True)
-    else:
-        kinds = list(sm.cache_spec["layers"])
-        ranged = {kind: _attention(sm, attends, kind)
-                  for kind in sorted(set(kinds))}
-
-    # the chunk's tokens land as whole pages where they are whole pages, the
-    # round's always as rows: the two share no page but the scratch one
-    n_pages = _whole_pages(rows, W, PL, prefill) if aligned else 0
-    rows_of = write_rows if latent else functools.partial(write_rows, lead=3)
-    write = write_pages if n_pages else rows_of
-
-    @part("attention")
-    def both(kernel, q, chunk, round_):
-        """The chunk's queries ``q[:, :W]`` against the prompt's ``(table,
-        start)``, the round's, one a row, against theirs; joined as the
-        block handed them in."""
-        ctx = kernel(q[:, :W], *chunk)
-        r_ctx = kernel(jnp.swapaxes(q[:, W:], 0, 1), *round_)      # [R, 1]
-        return jnp.concatenate([ctx, jnp.swapaxes(r_ctx, 0, 1)], 1)
-
-    def step(params, k_arenas, v_arenas, tables, tokens, lengths, n_valid,
-             state=None):
-        (tables, r_tables), (tokens, r_tokens) = tables, tokens
-        (lengths, r_lengths), (n_valid, r_valid) = lengths, n_valid
-        pos = lengths[:, None] + jnp.arange(W)                     # [1, W]
-        r_pos = r_lengths[:, None]                                 # [R, 1]
-        xpos = jnp.concatenate([pos, r_pos.reshape(1, R)], 1)      # [1, N]
-        x = sm.embed(params, jnp.concatenate(
-            [tokens, r_tokens.reshape(1, R)], 1), xpos)            # [1, N, h]
-        valid = jnp.concatenate(
-            [jnp.arange(W)[None, :] < n_valid[:, None],
-             (r_valid > 0)[None, :]], 1)                           # [1, N]
-        if latent:
-            where = chunk_where(tables, lengths, pos, n_pages, PL)
-            r_where = flat_rows(r_tables, r_pos, PL)               # [R]
-        else:
-            by_kind = {kind: (tables[i], r_tables[i]) for i, kind in
-                       enumerate(("full", "window"))}
-            where_of = {kind: (chunk_where(t, lengths, pos, n_pages, PL, kvh),
-                               flat_kv(rt, r_pos, PL, kvh))        # [R*kvh]
-                        for kind, (t, rt) in by_kind.items()}
-        new_k, new_v, counted = [], [], []
-        held, selected = [None, None], []
-        for li, (p, kc) in enumerate(zip(params["layers"], k_arenas)):
-
-            def attend_latent(q_lat, q_rope, row, index=None):
-                lanes = [(0, 0)] * (row.ndim - 1) + [(0, DL - dl)]
-                row = jnp.pad(row, lanes)                          # [1, N, DL]
-                arena = rows_of(write(kc, where, row[:, :W]), r_where,
-                                row[:, W:])
-                new_k.append(arena)
-                q = latent_query(q_lat, q_rope)
-                if sparse is None:
-                    return both(
-                        lambda q, t, at: latent_attend(q, arena, t, at), q,
-                        (tables, lengths), (r_tables, r_lengths))
-                if index is not None:     # a "full" layer scores and selects
-                    qi, wi, ki = index
-                    keys = rows_of(write(v_arenas[sparse.arena_of[li]],
-                                         where, ki[:, :W]), r_where,
-                                   ki[:, W:])
-                    new_v.append(keys)
-                    rows = functools.partial(jnp.swapaxes, axis1=0, axis2=1)
-                    held[:] = (
-                        sparse.select(qi[:, :W], wi[:, :W], keys, tables,
-                                      lengths, valid[:, :W]),
-                        sparse.select(rows(qi[:, W:]), rows(wi[:, W:]), keys,
-                                      r_tables, r_lengths,
-                                      rows(valid[:, W:])))
-                (bias, n), (r_bias, r_n) = held
-                selected.append((n, r_n))
-                return both(
-                    lambda q, t, at, b: sparse.attend(q, arena, t, at, b), q,
-                    (tables, lengths, bias), (r_tables, r_lengths, r_bias))
-
-            kind = None if latent else kinds[li]
-
-            def land(arena, kv):         # [1, N, kvh, hd]: chunk, round
-                at, r_at = where_of[kind]
-                return rows_of(write(arena, at, kv[:, :W]), r_at, kv[:, W:])
-
-            def attend_ranged(q, k1, v1):
-                kk, vv = land(kc, k1), land(v_arenas[li], v1)
-                new_k.append(kk)
-                new_v.append(vv)
-                table, r_table = by_kind[kind]
-                return both(lambda q, t, at: ranged[kind](q, kk, vv, t, at),
-                            q, (table, lengths), (r_table, r_lengths))
-
-            attend_ranged.kind = kind
-            out = sm.block(p, x, xpos,
-                           attend_latent if latent else attend_ranged,
-                           None, valid)
-            x = out[0]
-            if counter_names and len(out) > 2 and out[2] is not None:
-                counted.append(out[2])
-        # the head at the prompt's last real position and at every row of
-        # the round: [1, 1 + R, h]
-        x = jnp.concatenate([_last_real(x, n_valid, W), x[:, W:]], 1)
-        nxt, logp = _pick(sm.head(params, x))
-        # ([1, 1], [R, 1]): the prompt's, then the round's
-        nxt, logp = ((a[:, :1], a[0, 1:, None]) for a in (nxt, logp))
-        out = (nxt, logp, new_k, new_v, None)
-        if not counter_names and sparse is None:
-            return out
-        return out + (_counted(counter_names, counted, sparse, selected),)
-
-    step.__name__ = _program_name(label, carries=True)
-
-    from ..jit import persistent_cache
-
-    return persistent_cache.cached_jit(
-        step, donate_argnums=(1, 2) if donate else (), label=label)
 
 
 class GenerationEngine(EngineBase):

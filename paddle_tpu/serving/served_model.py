@@ -78,12 +78,14 @@ contains no model. A model that can be served implements
   the sync it makes anyway;
 - ``carries_rounds`` (derived, never set): whether the prefill program of
   the engine's largest bucket also runs the running sequences' decode step
-  (``generation._build_window_step``, "the carried step"). True when the
+  (``generation._build_window_step``, ``carry`` > 0 of its one ``step``
+  body: "the carried step"). True when the
   cache's kernel takes each row's own range of pages and nothing recurs:
   ``cache_spec`` of kind ``"latent"`` or ``"kv_by_layer"`` and no
   ``state_spec``. Then a chunk's row and a round's rows are just ``C + S``
   tokens to everything position-wise in ``block``, and only ``attend``
-  tells them apart. A state model is out (Falcon-H1, Brumby): a prefill
+  tells them apart (each cache kind's is written once; the builder's
+  ``land`` and ``call`` split the row). A state model is out (Falcon-H1, Brumby): a prefill
   starts its state from zero or from its previous chunk's while a round
   advances the slot arenas in place — two conventions in one program; the
   engine sends a round of its own BETWEEN two chunks of such a prompt

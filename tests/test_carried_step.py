@@ -14,6 +14,8 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu import serving
 from paddle_tpu.models.falcon_h1 import FalconH1Config, FalconH1ForCausalLM
+from paddle_tpu.models.glm_moe_dsa import (GlmMoeDsaConfig,
+                                           GlmMoeDsaForCausalLM)
 from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 from paddle_tpu.models.laguna import LagunaConfig, LagunaForCausalLM
 from paddle_tpu.models.openpangu_moe import (OpenPanguMoEConfig,
@@ -33,6 +35,9 @@ def _tiny(kind):
     if kind == "openpangu":
         cfg = OpenPanguMoEConfig.tiny()
         return cfg, OpenPanguMoEForCausalLM(cfg)
+    if kind == "glm_dsa":    # 6 index keys a query: every context outgrows them
+        cfg = GlmMoeDsaConfig.tiny()
+        return cfg, GlmMoeDsaForCausalLM(cfg)
     if kind == "falcon_h1":
         cfg = FalconH1Config.tiny()
         return cfg, FalconH1ForCausalLM(cfg)
@@ -40,7 +45,7 @@ def _tiny(kind):
     return cfg, GPTForCausalLM(cfg)
 
 
-@pytest.fixture(scope="module", params=["laguna", "openpangu"])
+@pytest.fixture(scope="module", params=["laguna", "openpangu", "glm_dsa"])
 def served(request):
     return (request.param,) + _tiny(request.param)
 

@@ -2,6 +2,7 @@
 set (and then no code sets another), else the fixed
 ``<checkout>/.cache/jax`` — never a temp dir, a pid or a time (the path is
 part of the cache key: a directory that moves never hits)."""
+import functools
 import os
 import subprocess
 import sys
@@ -149,9 +150,15 @@ def test_no_call_depth_is_a_hundred_times_slower_under_one_chunk():
     def rec(d, n):
         return hot(n) if d == 0 else rec(d - 1, n)
 
+    def best(measure, d):
+        # the thrash belongs to a depth and is there on every repeat; a
+        # sample that was slow because the machine was busy is not
+        return min(measure(d, 20000) for _ in range(3))
+
     depths = range(0, 360)
-    plain = [rec(d, 20000) for d in depths]
-    under = [pcache.in_one_stack_chunk(rec, d, 20000) for d in depths]
+    plain = [best(rec, d) for d in depths]
+    under = [best(functools.partial(pcache.in_one_stack_chunk, rec), d)
+             for d in depths]
     med = statistics.median(plain)
     assert max(under) < 10 * med, (max(under), med)
     if max(plain) > 30 * med:        # this interpreter thrashes somewhere

@@ -84,7 +84,8 @@ def test_a_chunk_written_as_pages_is_the_row_scatter(kind, chunk):
 @pytest.mark.parametrize("kind", list(TABLES))
 def test_a_carrying_call_writes_pages_then_the_rounds_rows(kind):
     """The chunk's pages and the round's rows, one of them idle (an all-zero
-    table: the scratch page), as ``_build_carrying_step`` lands them."""
+    table: the scratch page), as ``_build_window_step``'s ``land`` does under a
+    carry."""
     import jax.numpy as jnp
 
     rng = np.random.default_rng(9)
