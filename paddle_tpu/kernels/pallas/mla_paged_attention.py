@@ -32,6 +32,17 @@ one-grid-step-a-page walk costs 0.85 us a step whatever the lengths). An idle
 decode row (``start`` 0, table all scratch) costs one block. One kernel serves
 the decode round (W = 1: a ``[H, dl]`` slab a row) and the prefill chunk (one
 row, ``TW x H`` query rows a tile).
+
+With a ``window`` (a latent layer of the SLIDING kind: dots3-note's, whose row
+is ``[c_kv (1024) | k_r (64)]`` and whose query at position ``i`` sees the
+``window`` keys ``i - window < j <= i``) the walk starts at the block that
+holds the first key the tile's first query can see — the design of
+``ranged_paged_attention.py`` — and keys behind a query's window are masked by
+position: pages the engine has given back (table entry 0, the scratch page)
+lie before that block or are masked. ``window=None`` is the kernel above, the
+same program as before the argument existed; a trace tells the two apart
+(``pt_mla_paged_attention`` / ``pt_mla_window_attention``). ``window_walk``
+counts what the window kernel's tiles read against what their windows hold.
 """
 from __future__ import annotations
 
@@ -44,7 +55,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..registry import register_kernel, resolve
 
-__all__ = ["mla_paged_attention"]
+__all__ = ["mla_paged_attention", "window_walk"]
 
 _NEG = -1e30
 # query rows (window tokens x heads) a grid step holds, and cached tokens a
@@ -55,15 +66,22 @@ _BLOCK_TOKENS = 512
 
 
 def _mla_kernel(tbl_ref, start_ref, q_ref, arena_ref, o_ref, buf, sem,
-                m_ref, l_ref, acc_ref, *, H, TW, PL, KP, dv, scale):
+                m_ref, l_ref, acc_ref, *, H, TW, PL, KP, dv, scale, window=None):
     """One (row, window tile) grid step. ``q``/``o`` blocks [1, TW x H, .]
     (token-major: query row ``r`` is head ``r % H`` of tile token
     ``r // H``); ``arena_ref`` is the whole arena in HBM; ``buf`` [2, KP x
-    PL, dl] is the double buffer a block of ``KP`` pages lands in."""
+    PL, dl] is the double buffer a block of ``KP`` pages lands in. With a
+    ``window`` the walk starts at block ``lo``, the one that holds the first
+    key the tile's first query sees (0 without, a Python int that folds away:
+    ``window=None`` lowers to the text it had before the argument existed,
+    which is what keeps cells 6 and 10 on the program they were measured
+    with). A block lands in the buffer of its own parity wherever the walk
+    starts."""
     s, t = pl.program_id(0), pl.program_id(1)
     R, KB = TW * H, KP * PL
     base = start_ref[s] + t * TW       # position of the tile's first token
     n_blocks = (base + TW - 1) // KB + 1
+    lo = 0 if window is None else jnp.maximum(base - (window - 1), 0) // KB
 
     def copies(slot, blk):
         return [pltpu.make_async_copy(
@@ -74,7 +92,7 @@ def _mla_kernel(tbl_ref, start_ref, q_ref, arena_ref, o_ref, buf, sem,
     acc_ref[...] = jnp.zeros_like(acc_ref)
     m_ref[...] = jnp.full_like(m_ref, _NEG)
     l_ref[...] = jnp.zeros_like(l_ref)
-    for c in copies(0, 0):
+    for c in copies(lo % 2, lo):
         c.start()
     q = q_ref[0]                                               # [R, dl]
     qpos = base + jax.lax.broadcasted_iota(jnp.int32, (R, 1), 0) // H
@@ -93,7 +111,10 @@ def _mla_kernel(tbl_ref, start_ref, q_ref, arena_ref, o_ref, buf, sem,
         sc = jax.lax.dot_general(q, kv, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32) * scale
         kpos = blk * KB + jax.lax.broadcasted_iota(jnp.int32, (1, KB), 1)
-        sc = jnp.where(kpos <= qpos, sc, _NEG)                 # [R, KB]
+        seen = kpos <= qpos
+        if window is not None:
+            seen = seen & (kpos > qpos - window)
+        sc = jnp.where(seen, sc, _NEG)                         # [R, KB]
         m_prev = m_ref[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(sc, axis=-1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
@@ -106,7 +127,7 @@ def _mla_kernel(tbl_ref, start_ref, q_ref, arena_ref, o_ref, buf, sem,
         l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
         return carry
 
-    jax.lax.fori_loop(0, n_blocks, body, 0)
+    jax.lax.fori_loop(lo, n_blocks, body, 0)
     o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[:, :1], 1e-30)) \
         .astype(o_ref.dtype)
 
@@ -120,20 +141,61 @@ def _tile_tokens(W: int, H: int) -> int:
     return tw
 
 
-def _mla_pallas(q, arena, tables, start, dv, scale, interpret):
+def _tiles(W: int, H: int, PL: int):
+    """``(TW, KP)`` of a call: window tokens a grid step, pages a block —
+    from the call's shape under the module's two bounds."""
+    return _tile_tokens(W, H), max(1, _BLOCK_TOKENS // PL)
+
+
+# Mosaic's scoped default: what a call gets that asks for nothing
+VMEM_DEFAULT = 16 * 2 ** 20
+
+
+def vmem_limit(R: int, KB: int, dl: int, dv: int, itemsize: int) -> dict:
+    """``pallas_call`` arguments for a call whose buffers pass Mosaic's
+    scoped default, ``{}`` for one whose do not (its program is then what it
+    was before anyone counted): the page double buffer and the block as the
+    body holds it, the pipeline's two ``q`` and two ``o`` blocks, ``acc``,
+    ``m`` / ``l`` (a lane tile a row) and the float32 score tiles the
+    compiler keeps in flight (the scores, their exponentials and the 16-bit
+    copy, twice over: what AOT compiles at dots3-note's widths ask for)."""
+    need = 3 * KB * dl * itemsize + 2 * R * (dl + dv) * itemsize \
+        + R * (dv + 2 * 128) * 4 + 6 * R * KB * 4
+    if need <= VMEM_DEFAULT:
+        return {}
+    return {"compiler_params": pltpu.CompilerParams(vmem_limit_bytes=need)}
+
+
+def window_walk(W: int, H: int, PL: int, window: int, keys):
+    """What the window kernel's tiles read for one call of ``len(keys)`` rows
+    x ``W`` window tokens, ``keys`` the tokens cached in front of each row's
+    window: ``(walked, in_window)`` latent rows — the rows of the blocks every
+    grid step DMAs, and the rows some query of the step sees (the union of
+    its tile's windows). Equal when the walk reads nothing it masks."""
+    import numpy as np
+
+    TW, KP = _tiles(W, H, PL)
+    KB = KP * PL
+    base = np.asarray(keys, np.int64).reshape(-1, 1) + np.arange(W // TW) * TW
+    first = np.maximum(base - (window - 1), 0)
+    walked = ((base + TW - 1) // KB - first // KB + 1) * KB
+    return int(walked.sum()), int((base + TW - first).sum())
+
+
+def _mla_pallas(q, arena, tables, start, dv, scale, interpret, window=None):
     S, W, H, dl = q.shape
     _P, PL, _ = arena.shape
     B = tables.shape[1]
-    KP = max(1, _BLOCK_TOKENS // PL)
+    TW, KP = _tiles(W, H, PL)
     n_blk = -(-B // KP)
     # whole blocks: the pages past a row's table are the scratch page
     tables = jnp.pad(tables, ((0, 0), (0, n_blk * KP - B)))
-    TW = _tile_tokens(W, H)
     R = TW * H
     out = pl.pallas_call(
         functools.partial(_mla_kernel, H=H, TW=TW, PL=PL, KP=KP, dv=dv,
-                          scale=scale),
-        name="pt_mla_paged_attention",
+                          scale=scale, window=window),
+        name="pt_mla_paged_attention" if window is None
+        else "pt_mla_window_attention",
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(S, W // TW),
@@ -152,11 +214,14 @@ def _mla_pallas(q, arena, tables, start, dv, scale, interpret):
         ),
         out_shape=jax.ShapeDtypeStruct((S, W * H, dv), q.dtype),
         interpret=interpret,
+        # dv 1024 and rows of 1152 lanes (dots3-note's window layers) pass
+        # Mosaic's default at a chunk's tile; dv 512 and 640 lanes do not
+        **vmem_limit(R, KP * PL, dl, dv, arena.dtype.itemsize),
     )(tables, start, q.reshape(S, W * H, dl), arena)
     return out.reshape(S, W, H, dv)
 
 
-def _reference(q, arena, tables, start, dv, scale):
+def _reference(q, arena, tables, start, dv, scale, window=None):
     """Gather the rows' pages, then attend: the same math in plain jnp."""
     S, W, H, dl = q.shape
     _P, PL, _ = arena.shape
@@ -164,6 +229,8 @@ def _reference(q, arena, tables, start, dv, scale):
     kv = arena[tables].reshape(S, L, dl)
     pos = start[:, None] + jnp.arange(W)                       # [S, W]
     mask = jnp.arange(L)[None, None, :] <= pos[:, :, None]     # [S, W, L]
+    if window is not None:
+        mask = mask & (jnp.arange(L)[None, None, :] > pos[:, :, None] - window)
     logits = jnp.einsum("swhd,sLd->swhL", q, kv,
                         preferred_element_type=jnp.float32) * scale
     logits = jnp.where(mask[:, :, None, :], logits, _NEG)
@@ -173,10 +240,12 @@ def _reference(q, arena, tables, start, dv, scale):
 
 
 def mla_paged_attention(q, arena, tables, start, *, dv: int, scale: float,
-                        impl: str = None):
+                        window: int = None, impl: str = None):
     """Absorbed latent attention of ``q`` [S, W, H, dl] against the latent
     page ``arena`` [P, PL, dl] through ``tables`` [S, B]; window token ``w``
-    of row ``s`` sees the cached rows at positions ``<= start[s] + w``.
+    of row ``s``, at position ``i = start[s] + w``, sees the cached rows at
+    positions ``j <= i`` and, with a ``window``, ``j > i - window`` (tables by
+    ABSOLUTE block either way).
     Returns ``[S, W, H, dv]`` in ``q.dtype``: each head's softmax-weighted sum
     of the rows' first ``dv`` columns (the caller carries it through the
     value up-projection). ``impl``: None (``registry.resolve``), 'pallas',
@@ -185,14 +254,16 @@ def mla_paged_attention(q, arena, tables, start, *, dv: int, scale: float,
     if impl is None:
         impl = resolve("mla_paged_attention")
     tables, start = tables.astype(jnp.int32), start.astype(jnp.int32)
+    window = None if window is None else int(window)
     if impl == "reference":
-        return _reference(q, arena, tables, start, dv, scale)
+        return _reference(q, arena, tables, start, dv, scale, window)
     return _mla_pallas(q, arena, tables, start, int(dv), float(scale),
-                       impl == "interpret")
+                       impl == "interpret", window)
 
 
 register_kernel(
     "mla_paged_attention",
     doc="absorbed latent attention (MLA) against a paged latent cache: all "
         "heads of a token one slab against a page of [c_kv | k_r] rows, "
-        "walking only the pages a row's length covers")
+        "walking only the pages a row's length covers — or, with a window, "
+        "the pages that hold a key the row's queries can see")

@@ -32,6 +32,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..registry import register_kernel, resolve
 from .dsa_index import BLOCK_TOKENS, NEG, TILE, padded_context
+from .mla_paged_attention import vmem_limit
 
 __all__ = ["mla_sparse_attention"]
 
@@ -139,6 +140,8 @@ def _sparse_pallas(q, arena, tables, start, bias, dv, scale, interpret):
         ),
         out_shape=jax.ShapeDtypeStruct((S, Wq * H, dv), q.dtype),
         interpret=interpret,
+        # 128 heads a tile (dots3-note) pass Mosaic's default; 64 do not
+        **vmem_limit(R, KP * PL, dl, dv, arena.dtype.itemsize),
     )(tables, start, q.reshape(S, Wq * H, dl), arena, bias)
     return out.reshape(S, Wq, H, dv)[:, :W]
 

@@ -26,6 +26,9 @@ from .laguna import (  # noqa: F401
 from .glm_moe_dsa import (  # noqa: F401
     GlmMoeDsaConfig, GlmMoeDsaForCausalLM, GlmMoeDsaBlock,
 )
+from .dots3_note import (  # noqa: F401
+    Dots3NoteConfig, Dots3NoteForCausalLM, Dots3NoteBlock, Dots3NoteServed,
+)
 from .brumby import (  # noqa: F401
     BrumbyConfig, BrumbyForCausalLM, BrumbyBlock, BrumbyServed,
 )

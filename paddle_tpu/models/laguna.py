@@ -203,6 +203,14 @@ def _rope(x, pos, inv_freq, dim, scale):
 # (``moe_held_experts_mlp``).
 
 @part("attn_proj")
+def head_gate(u, w):
+    """The sigmoid gate a head on a layer's attention output, float32 ``[R,
+    W, H]`` of the normed input ``u``: over K/V heads here, over latent
+    attention's in ``dots3_note``."""
+    return jax.nn.sigmoid(_mm(u, w))
+
+
+@part("attn_proj")
 def _qkvg(cfg: LagunaConfig, p, u, pos, kind):
     """The layer's roped queries and keys, its values (in the weights'
     dtype) and its gate a head [R, W, H] of the normed input ``u``; the head
@@ -216,8 +224,7 @@ def _qkvg(cfg: LagunaConfig, p, u, pos, kind):
     q = _rope(_mm(u, p["q"]).reshape(R, W, H, d), pos, inv, dim, fac)
     k = _rope(_mm(u, p["k"]).reshape(R, W, G, d), pos, inv, dim, fac)
     v = _mm(u, p["v"]).reshape(R, W, G, d)
-    gate = jax.nn.sigmoid(_mm(u, p["g"]))                      # [R, W, H]
-    return q.astype(wd), k.astype(wd), v.astype(wd), gate
+    return q.astype(wd), k.astype(wd), v.astype(wd), head_gate(u, p["g"])
 
 
 @part("attn_proj")

@@ -155,21 +155,27 @@ MOE_KEYS = ATTN_KEYS + ("router", "experts_gate", "experts_up",
 # short; a norm inside one of them is ``norm`` (the innermost part owns).
 
 @part("attn_proj")
-def mla_in(p, u, pos, rope, *, heads, dn, dr, dv, dc, eps):
+def mla_in(p, u, pos, rope, *, heads, dn, dr, dv, dc, eps, rescale=None):
     """Latent attention's projections of the normed input ``u`` (the ONE pair
-    of absorbed-MLA helpers: openPangu-Ultra-MoE and GLM-5 both call it, with
-    their head widths, ``rope(x, pos)`` their rotary form): the absorbed
-    queries ``q_lat`` [R, W, H, dc], their rotary half ``q_rope``, the
-    window's own cache rows ``row`` [R, W, dc + dr], the KV up-projection by
-    head (its value half comes after ``attend``) and the query latent ``c_q``
-    (what an indexer projects its own queries from)."""
+    of absorbed-MLA helpers: openPangu-Ultra-MoE, GLM-5 and dots3-note all
+    call it, with their head widths, ``rope(x, pos)`` their rotary form): the
+    absorbed queries ``q_lat`` [R, W, H, dc], their rotary half ``q_rope``,
+    the window's own cache rows ``row`` [R, W, dc + dr], the KV up-projection
+    by head (its value half comes after ``attend``) and the query latent
+    ``c_q`` (what an indexer projects its own queries from). ``rescale``
+    ``(a_q, a_kv)``: the normed latents are scaled where they enter ``W_qb``
+    and ``W_kvb`` (dots3-note's ``apply_mla_qkv_lora_rescale``) — the cached
+    row holds ``a_kv RMSNorm(c_kv)``, and ``c_q`` is handed on unscaled."""
     R, W, _ = u.shape
     wd = p["q_b"].dtype
     c_q = _rms(_mm(u, p["q_a"]), p["q_a_norm"], eps)
-    q = _mm(c_q, p["q_b"]).reshape(R, W, heads, dn + dr)
+    q = _mm(c_q if rescale is None else c_q * rescale[0],
+            p["q_b"]).reshape(R, W, heads, dn + dr)
     q_rope = rope(q[..., dn:], pos)
     kva = _mm(u, p["kv_a"])                                  # [R, W, dc+dr]
     c_kv = _rms(kva[..., :dc], p["kv_a_norm"], eps)
+    if rescale is not None:
+        c_kv = c_kv * rescale[1]
     k_r = rope(kva[..., None, dc:], pos)[:, :, 0]
     row = jnp.concatenate([c_kv, k_r], -1).astype(wd)
     # absorbed: carry q_nope through the head's key half of the KV
@@ -181,14 +187,18 @@ def mla_in(p, u, pos, rope, *, heads, dn, dr, dv, dc, eps):
 
 
 @part("attn_proj")
-def mla_out(p, x, ctx, kv_b, *, dn, dv, post_norm_eps=None):
+def mla_out(p, x, ctx, kv_b, *, dn, dv, post_norm_eps=None, gate=None):
     """The context ``ctx`` [R, W, H, dc] through the value half of the KV
     up-projection and the output projection, onto the stream — through the
     layer's ``post_attn_norm`` first where ``post_norm_eps`` is given (a
-    sandwich-norm block), as it is in a pre-norm block."""
+    sandwich-norm block), as it is in a pre-norm block. ``gate`` [R, W, H]
+    float32: each head's output times its gate before the output projection
+    (``laguna.head_gate``)."""
     R, W, H = ctx.shape[:3]
     o = jnp.einsum("rwhc,chv->rwhv", ctx.astype(kv_b.dtype), kv_b[..., dn:],
                    preferred_element_type=F32)
+    if gate is not None:
+        o = o * gate[..., None]
     a = _mm(o.reshape(R, W, H * dv), p["o"])
     return x + (a if post_norm_eps is None
                 else _rms(a, p["post_attn_norm"], post_norm_eps))

@@ -468,7 +468,8 @@ def _program_name(label: str, carries: bool = False) -> str:
 
 def _attention(sm, attends: Optional[Dict], name: str):
     """The jitted attention callable ``name`` of a window program of ``sm``:
-    ``"paged"`` (K/V arenas), ``"latent"``, the ``"index_select"`` and
+    ``"paged"`` (K/V arenas), ``"latent"`` (and ``"latent_window"``, the
+    window layers' of a latent cache of two layer kinds), the ``"index_select"`` and
     ``"sparse"`` of a latent cache with an index row (``_Sparse``), or the
     ``"full"`` / ``"window"`` of a cache of two layer kinds. ONE jitted
     callable for every layer of a program: the kernel is traced and lowered once a program and called L
@@ -510,6 +511,21 @@ def _attention(sm, attends: Optional[Dict], name: str):
                                        scale=scale)
 
         fn = attention(latent_attend)
+    elif name == "latent_window":
+        # a latent cache's window layers: their own row (its value width and
+        # softmax scale) and the walk from the window's first block
+        from ..kernels.pallas.mla_paged_attention import mla_paged_attention
+
+        own = sm.cache_spec.get("window_row", sm.cache_spec)
+        dv, window = own["value_dim"], int(sm.cache_spec["window"])
+        own_scale = float(own.get("scale", scale))
+
+        @jax.jit
+        def latent_window_attend(q, arena, tables, lengths):
+            return mla_paged_attention(q, arena, tables, lengths, dv=dv,
+                                       scale=own_scale, window=window)
+
+        fn = attention(latent_window_attend)
     elif name == "index_select":
         import jax.numpy as jnp
 
@@ -584,6 +600,9 @@ class _Sparse:
         kinds = list(sm.cache_spec["index"]["layers"])
         self.arena_of = {li: n for n, li in enumerate(
             i for i, kind in enumerate(kinds) if kind == "full")}
+        # a layer the list names nothing for (``None``: a window layer of a
+        # cache of two kinds) selects nothing and attends its own range
+        self.selects = [kind is not None for kind in kinds]
         self.select = _attention(sm, attends, "index_select")
         self.attend = _attention(sm, attends, "sparse")
         # a prompt's row, a round's rows, or (a carrying program) both
@@ -675,7 +694,12 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
       own an indexer, and each layer attends the keys its query SELECTED
       (``_Sparse``); built with ``selection`` the program also names them
       (``counters["selection"]``: ``GenerationEngine.selected_keys``, a
-      check's — no program that serves a request is built so);
+      check's — no program that serves a request is built so). Where the
+      cache also declares its layers' KINDS (``"layers"``, as below:
+      ``tables`` ``[2, rows, B]``) a window layer's rows land through its
+      kind's table in its own, wider arena and
+      ``mla_paged_attention(window=)`` walks from the first block that holds
+      a visible key; such a layer selects nothing;
     - ``"kv_by_layer"``: K and V arenas ``[pages, heads, page_len, dim]`` a
       layer, a "full" layer's of the pool's pages and a "window" layer's of
       the window pool's; ``tables`` is ``[2, rows, B]`` (the full layers'
@@ -751,6 +775,9 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     latent = cache_kind == "latent"
     by_layer = cache_kind == "kv_by_layer"
     unpaged = cache_kind == "none"
+    # two kinds of layer, one pool and one table each (``"layers"``: the K/V
+    # form, or a latent cache that declares its layers' kinds)
+    kinds = list((sm.cache_spec or {}).get("layers") or ())
     counter_names = sm.program_counters
     # a model whose block resumes is told which of its two state conventions
     # a program uses: the slot arenas of a round, or a row's own state
@@ -770,14 +797,19 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
         raise ValueError("selection=True: the model's cache_spec declares no "
                          "index row, so nothing is selected")
     if latent:
-        dl = sm.cache_spec["dim"]
-        DL = latent_width(dl)
-        latent_attend = _attention(sm, attends, "latent")
-        latent_query = _latent_query(DL - dl)
+        # (row width, lanes, kernel, query slab) by layer kind: a window
+        # layer's rows may have a width of their own (``"window_row"``)
+        lat = {}
+        for kind in sorted(set(kinds)) or [None]:
+            dl = (sm.cache_spec.get("window_row", sm.cache_spec)
+                  if kind == "window" else sm.cache_spec)["dim"]
+            DL = latent_width(dl)
+            lat[kind] = (dl, DL, _attention(
+                sm, attends, "latent_window" if kind == "window"
+                else "latent"), _latent_query(DL - dl))
         if sm.cache_spec.get("index"):
             sparse = _Sparse(sm, attends, prefill, bool(R) or not prefill)
     elif by_layer:
-        kinds = list(sm.cache_spec["layers"])
         ranged = {kind: _attention(sm, attends, kind)
                   for kind in sorted(set(kinds))}
     elif not unpaged:
@@ -853,7 +885,7 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
                 chunk_where(table[0], lengths[0], pos, n_pages, PL, heads),
                 chunk_where(table[1], lengths[1], r_pos, 0, PL, heads))
 
-        if by_layer:
+        if kinds:
             by_kind = {kind: tuple(t[i] for t in tables) if R else tables[i]
                        for i, kind in enumerate(("full", "window"))}
             where_of = {kind: places(t) for kind, t in by_kind.items()}
@@ -869,36 +901,41 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
                 params["layers"],
                 [None] * len(params["layers"]) if unpaged else k_arenas)):
             vc = None if latent or unpaged else v_arenas[li]
+            # the layer's own table and places: its kind's, where there are two
+            kind = kinds[li] if kinds else None
+            own_where, own_tables = (where_of[kind], by_kind[kind]) if kinds \
+                else (None, None) if unpaged else (where, tables)
 
             def attend_latent(q_lat, q_rope, row, index=None):
                 # the window's rows land in their pages, then every head of
                 # a token rides as one slab against the pages its row's
                 # length covers (rows and queries padded to whole lanes)
+                dl, DL, latent_attend, latent_query = lat[kind]
                 lanes = [(0, 0)] * (row.ndim - 1) + [(0, DL - dl)]
-                arena = land(kc, where, jnp.pad(row, lanes))
+                arena = land(kc, own_where, jnp.pad(row, lanes))
                 new_k.append(arena)
                 q = latent_query(q_lat, q_rope)
-                if sparse is None:
+                if sparse is None or not sparse.selects[li]:
                     return call(
                         lambda q, t, at: latent_attend(q, arena, t, at),
-                        q, tables, lengths)
+                        q, own_tables, lengths)
                 if index is not None:     # a "full" layer scores and selects
                     qi, wi, ki = index
-                    keys = land(v_arenas[sparse.arena_of[li]], where, ki)
+                    keys = land(v_arenas[sparse.arena_of[li]], own_where, ki)
                     new_v.append(keys)
                     if R:       # the chunk's tokens, then the round's rows
                         rows = functools.partial(jnp.swapaxes, axis1=0,
                                                  axis2=1)
                         held[:] = zip(
                             sparse.select(qi[:, :W], wi[:, :W], keys,
-                                          tables[0], lengths[0],
+                                          own_tables[0], lengths[0],
                                           valid[:, :W]),
                             sparse.select(rows(qi[:, W:]), rows(wi[:, W:]),
-                                          keys, tables[1], lengths[1],
+                                          keys, own_tables[1], lengths[1],
                                           rows(valid[:, W:])))
                     else:
                         held[:] = sparse.select(
-                            qi, wi, keys, tables, lengths,
+                            qi, wi, keys, own_tables, lengths,
                             jnp.ones((S, W), bool) if valid is None
                             else valid)
                     if selection:
@@ -907,7 +944,7 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
                 return call(
                     lambda q, t, at, bias: sparse.attend(q, arena, t, at,
                                                          bias),
-                    q, tables, lengths, held[0])
+                    q, own_tables, lengths, held[0])
 
             def attend(q, k1, v1):
                 kk = write_rows(kc, where, k1)
@@ -917,17 +954,15 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
                 # key j of the slot's pages is visible iff j <= pos[s, w]
                 return paged_attend(q, kk, vv, tables, pos)
 
-            kind = kinds[li] if by_layer else None
-
             def attend_ranged(q, k1, v1):
-                kk = land(kc, where_of[kind], k1)
-                vv = land(vc, where_of[kind], v1)
+                kk = land(kc, own_where, k1)
+                vv = land(vc, own_where, v1)
                 new_k.append(kk)
                 new_v.append(vv)
                 return call(lambda q, t, at: ranged[kind](q, kk, vv, t, at),
-                            q, by_kind[kind], lengths)
+                            q, own_tables, lengths)
 
-            attend_ranged.kind = kind
+            attend_ranged.kind = attend_latent.kind = kind
             out = sm.block(p, x, xpos, attend_latent if latent else
                            attend_ranged if by_layer else
                            None if unpaged else attend,
@@ -1020,7 +1055,9 @@ class GenerationEngine(EngineBase):
                     "is refused — pass draft_model=None")
         cache_kind = None if sm.cache_spec is None else sm.cache_spec["kind"]
         self._latent = cache_kind == "latent"
-        self._by_layer = cache_kind == "kv_by_layer"
+        # two kinds of layer, one pool each: the K/V form, or a latent cache
+        # that declares its layers' kinds (``cache_spec["layers"]``)
+        self._by_layer = bool((sm.cache_spec or {}).get("layers"))
         # nothing paged: every layer's memory is its recurrent state. No K/V
         # arena, no page table in the programs, admission by slots alone, and
         # ``max_seq_len`` bounds positions only (no memory grows with it).
@@ -1130,7 +1167,7 @@ class GenerationEngine(EngineBase):
             self._layers_of = {kind: sm.cache_spec["layers"].count(kind)
                                for kind in ("full", "window")}
             # a page as the ranged kernel sees it: K/V heads, tokens, head
-            # size, bytes an element (``_count_walk``)
+            # size, bytes an element (``_count_walk``; a latent page is rows)
             self._page = (sm.num_kv_heads, pl, sm.head_dim,
                           np.dtype(dtype).itemsize)
         self._pool = PagedKVPool(sm.num_layers, num_pages, pl,
@@ -1451,6 +1488,7 @@ class GenerationEngine(EngineBase):
         need = hi // pl + 1
         if need > s.whi:    # positions are contiguous: wlo <= first <= whi
             s.wtable[s.whi:need] = wa.alloc(need - s.whi)
+            self.metrics.inc("window_pages_taken_total", need - s.whi)
             s.whi = need
 
     def _window_reserved(self) -> int:
@@ -1477,9 +1515,9 @@ class GenerationEngine(EngineBase):
                          full * self._layers_of["full"])
         self.metrics.inc("attn_keys_window_total",
                          windowed * self._layers_of["window"])
-        if decode:
-            self.metrics.inc("attn_keys_window_decode_total",
-                             windowed * self._layers_of["window"])
+        self.metrics.inc(
+            f"attn_keys_window_{'decode' if decode else 'prefill'}_total",
+            windowed * self._layers_of["window"])
 
     def _count_walk(self, W: int, keys, decode: bool = False) -> None:
         """What the two kinds' kernel calls of a program dispatched walk:
@@ -1488,6 +1526,19 @@ class GenerationEngine(EngineBase):
         (``walk_cost``: every tile of a row walks its range again) and the
         pages that hold a key in range, once a layer of the kind; a decode
         round's part is counted apart too, as its keys are."""
+        if self._latent:
+            # a latent cache's window kernel alone walks a range (a full
+            # layer attends what it selected, over every visible page): the
+            # latent rows its tiles DMA, and the rows inside their windows
+            from ..kernels.pallas.mla_paged_attention import window_walk
+
+            heads = self._sm.cache_spec.get("window_row", {}).get(
+                "heads", self._sm.num_heads)
+            walked, inside = window_walk(W, heads, self._pl, self._win, keys)
+            for what, n in (("walked", walked), ("in", inside)):
+                self.metrics.inc(f"attn_rows_{what}_window_total",
+                                 n * self._layers_of["window"])
+            return
         from ..kernels.pallas.ranged_paged_attention import \
             choose_tiles, walk_cost
 
@@ -1643,14 +1694,24 @@ class GenerationEngine(EngineBase):
                 prefill=True, attends=self._attends, aligned=self._aligned,
                 selection=True)
         table = np.zeros(self._tables_shape(1), np.int32)
-        table[0, :n_pages] = 1 + np.arange(n_pages)
-        table, out = jnp.asarray(table), []
+        (table[0] if self._by_layer else table)[0, :n_pages] = \
+            1 + np.arange(n_pages)
+        out = []
         for lo in range(0, len(tokens), W):
             chunk = np.zeros((1, W), np.int32)
             n = min(W, len(tokens) - lo)
             chunk[0, :n] = tokens[lo:lo + n]
+            if self._by_layer:
+                # the window layers' pages of this call, by absolute block
+                # round the window pool (it holds a chunk's bound and more)
+                first = max(lo - (self._win - 1), 0) // PL
+                blocks = np.arange(first, (lo + n - 1) // PL + 1)
+                table[1, 0] = 0
+                table[1, 0, blocks] = 1 + blocks % \
+                    pool.window_allocator.usable_pages
             _nxt, _lp, pool.k, pool.v, _state, counted = fn(
-                self._params, pool.k, pool.v, table, jnp.asarray(chunk),
+                self._params, pool.k, pool.v, jnp.asarray(table),
+                jnp.asarray(chunk),
                 jnp.asarray([lo], jnp.int32), jnp.asarray([n], jnp.int32),
                 None)
             out.append([np.asarray(a)[0, :n]
@@ -2943,6 +3004,11 @@ class GenerationEngine(EngineBase):
                 if held:
                     snap.setdefault("attn_walk_amplification", {}).setdefault(
                         kind, {})[part] = round(walked / held, 3)
+        inside = c.get("attn_rows_in_window_total", 0)
+        if inside:  # a latent cache's window kernel: the rows its tiles
+            # DMA'd for every row inside their queries' windows
+            snap.setdefault("attn_walk_amplification", {})["latent_window"] = \
+                round(c.get("attn_rows_walked_window_total", 0) / inside, 3)
         if self.spec_k:
             prop = c.get("spec_proposed", 0)
             snap["spec_acceptance"] = round(

@@ -476,31 +476,31 @@ class PagedKVPool:
         # layer only the pages that still hold a key some later query can
         # see, from ``window_allocator`` (``window_pages`` of them, scratch
         # included). Both kinds lay a page out [heads, page_len, head_dim]:
-        # one K/V head's tokens contiguous (``ranged_paged_attention``)
+        # one K/V head's tokens contiguous (``ranged_paged_attention``) — or
+        # ONE latent row by the layer's kind (a latent spec with ``"layers"``:
+        # the same two allocators, a window layer's rows of their own width)
         self.cache_spec = cache_spec
         self.window_allocator: Optional[PageAllocator] = None
         self.layer_kinds: Optional[List[str]] = None
+        # a cache of two layer KINDS, K/V or latent (``cache_spec["layers"]``):
+        # the window layers' arenas hold ``window_pages`` pages of an
+        # allocator of their own
+        kinds = self._two_kinds(cache_spec, num_layers, window_pages,
+                                prefix_cache or warm_pool is not None) \
+            or [None] * num_layers
+        pages_of = [window_pages if kind == "window" else num_pages
+                    for kind in kinds]
         if cache_spec is None:
             shapes = [(num_pages, page_len, num_heads, head_dim)] * num_layers
         elif cache_spec["kind"] == "latent":
-            shapes = [(num_pages, page_len,
-                       latent_width(cache_spec["dim"]))] * num_layers
+            # a window layer's row may have a width of its own
+            # (``cache_spec["window_row"]``)
+            wide = {"window": cache_spec.get("window_row", cache_spec)["dim"]}
+            shapes = [(n, page_len, latent_width(
+                wide.get(kind, cache_spec["dim"])))
+                for n, kind in zip(pages_of, kinds)]
         elif cache_spec["kind"] == "kv_by_layer":
-            kinds = list(cache_spec["layers"])
-            if len(kinds) != num_layers or set(kinds) - {"full", "window"}:
-                raise ValueError(
-                    f"cache_spec['layers'] must name {num_layers} layers "
-                    f"'full' or 'window', got {kinds}")
-            if prefix_cache or warm_pool is not None:
-                raise ValueError(
-                    "a cache of two layer kinds has no prefix cache and no "
-                    "warm tier: a shared page behind a window has been "
-                    "given back")
-            self.layer_kinds = kinds
-            self.window = int(cache_spec["window"])
-            self.window_allocator = PageAllocator(window_pages)
-            shapes = [(window_pages if kind == "window" else num_pages,
-                       num_heads, page_len, head_dim) for kind in kinds]
+            shapes = [(n, num_heads, page_len, head_dim) for n in pages_of]
         elif cache_spec["kind"] == "none":
             # NOTHING paged: every layer's memory is its recurrent state
             # (``state_spec``), whatever the context. No arena, no page a
@@ -542,6 +542,29 @@ class PagedKVPool:
             {name: jnp.zeros((int(max_slots),) + tuple(shape), dt)
              for name, (shape, dt) in state_spec.items()}
             for _ in range(num_layers)]
+
+    def _two_kinds(self, cache_spec, num_layers: int, window_pages: int,
+                   shares_pages: bool) -> Optional[List[str]]:
+        """The layers' kinds where the cache declares them (``"layers"``: a
+        K/V cache ``kv_by_layer``, or a latent one), and the window layers'
+        allocator with them; ``None`` for a cache of one kind."""
+        kinds = (cache_spec or {}).get("layers")
+        if kinds is None:
+            return None
+        kinds = list(kinds)
+        if len(kinds) != num_layers or set(kinds) - {"full", "window"}:
+            raise ValueError(
+                f"cache_spec['layers'] must name {num_layers} layers "
+                f"'full' or 'window', got {kinds}")
+        if shares_pages:
+            raise ValueError(
+                "a cache of two layer kinds has no prefix cache and no "
+                "warm tier: a shared page behind a window has been "
+                "given back")
+        self.layer_kinds = kinds
+        self.window = int(cache_spec["window"])
+        self.window_allocator = PageAllocator(window_pages)
+        return kinds
 
     # -- control plane --------------------------------------------------------
     def allocate(self, n: int) -> List[int]:
@@ -685,11 +708,16 @@ class PagedKVPool:
     def bytes_by_kind(self) -> Dict[str, int]:
         """The arenas' bytes by layer kind (one kind, "full", for a cache
         that declares none); the latent rows and the index keys apart for a
-        latent cache with an index row."""
-        if self.cache_spec is not None and self.cache_spec.get("index"):
-            return {"latent": sum(int(a.nbytes) for a in self.k),
-                    "index": sum(int(a.nbytes) for a in self.v)}
+        latent cache with an index row, and the latent rows by layer kind
+        where it declares two (``latent_full`` / ``latent_window``)."""
         kinds = self.layer_kinds or ["full"] * len(self.k)
+        if self.cache_spec is not None and self.cache_spec.get("index"):
+            names = [f"latent_{kind}" for kind in kinds] \
+                if self.layer_kinds else ["latent"] * len(self.k)
+            out = {name: 0 for name in names}
+            for name, a in zip(names, self.k):
+                out[name] += int(a.nbytes)
+            return {**out, "index": sum(int(a.nbytes) for a in self.v)}
         out = {kind: 0 for kind in kinds}
         for arenas in (self.k, self.v):
             for kind, a in zip(kinds, arenas):
@@ -717,13 +745,14 @@ class PagedKVPool:
                "alloc_total": a.alloc_total, "cow_total": a.cow_total,
                "headroom": round(a.free_pages / max(a.usable_pages, 1), 4)}
         if self.window_allocator is not None:
-            w = self.window_allocator
+            w, by_kind = self.window_allocator, self.bytes_by_kind()
             out["window"] = {
                 "window": self.window, "pages_total": w.num_pages,
                 "pages_free": w.free_pages, "pages_live": w.live_pages,
                 "pages_peak": w.peak_live, "alloc_total": w.alloc_total,
                 "free_total": w.free_total,
-                "pool_bytes": self.bytes_by_kind()["window"],
+                "pool_bytes": by_kind.get("window",
+                                          by_kind.get("latent_window")),
                 "headroom": round(w.free_pages / max(w.usable_pages, 1), 4)}
         if self.trie is not None:
             out["prefix"] = self.trie.stats()

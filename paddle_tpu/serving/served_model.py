@@ -56,7 +56,18 @@ contains no model. A model that can be served implements
   (``sum_j wI_j relu(qI_j . kI)``), takes each token's EXACT top-``k`` and
   attends those keys alone; ``attend`` of a ``"shared"`` layer takes no
   ``index`` and attends the set the last ``"full"`` layer selected, which
-  the window program keeps.
+  the window program keeps. A latent cache may also be OF TWO LAYER KINDS —
+  ``"layers": ["full", "window", ...]`` and ``"window": n`` as in
+  ``kv_by_layer`` below (two tables a row, two allocators, a window layer's
+  pages given back behind the window; ``attend.kind`` says the layer's), with
+  ``"window_row": {"dim": dw, "value_dim": dvw, "scale": s, "heads": hw}``,
+  a window layer's own row, value width, softmax scale and query heads (its
+  arena is ``[window pages, page_len, dw rounded up to 128 lanes]`` and its
+  ``attend`` the same latent form at those widths, over the keys ``i - n < j
+  <= i``; the heads say what tiles its kernel walks: ``stats()``), and an
+  ``"index"`` group whose ``layers`` says ``"full"`` for a layer that owns an
+  indexer and ``None`` for a window layer, which selects nothing
+  (``Dots3NoteForCausalLM``).
   ``{"kind": "kv_by_layer", "layers": ["full", "window", ...], "window":
   n}`` (a model whose layers are of two kinds): a key and a value of
   ``[num_kv_heads, head_dim]`` in every layer, but a "window" layer's query
@@ -109,7 +120,7 @@ share, spill or ship, and no cache of state snapshots is built — a latent
 cache cannot yet use what moves K/V pages (export/install and its wire
 format, the warm tier) — with an index row it shares index keys through the
 prefix trie like latent rows (one page table) but refuses a draft model too —
-and a cache with window layers cannot use what
+and a cache with window layers, K/V or latent, cannot use what
 assumes that a page, once written, stays (the prefix trie, speculative
 verify, export/install, the warm tier): the engine refuses those in words
 (``docs/serving.md``).

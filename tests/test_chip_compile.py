@@ -348,6 +348,59 @@ def test_mla_sparse_attention_published_widths(one_chip, S, W):
              names=("pt_mla_sparse_attention",))
 
 
+# dots3-note-prev (``dots3-note-prev-d5e16``): latent attention of two kinds in
+# one cache. Full layers: 128 heads against rows of 576 (640 lanes) under 64
+# index heads of 128; window layers: 64 heads against rows of 1088 (1152
+# lanes), values 1024 wide, a window of 513 keys. Contexts to 67 584 tokens
+# (528 pages of 128): the 32-slot decode round, the tail buckets, the chunk
+_D3_WINDOWS = [(32, 1), (1, 256), (1, 512), (1, 2048)]
+_D3_POOL, _D3_WINDOW_POOL, _D3_BLOCKS, _D3_LP = 11400, 400, 528, 67584
+
+
+@pytest.mark.parametrize("S,W", _D3_WINDOWS)
+def test_mla_window_attention_published_widths(one_chip, S, W):
+    """``mla_paged_attention`` with a window at the shapes no other call has:
+    ``dv`` 1024, rows of 1152 lanes, 64 heads — its own name in the program."""
+    from paddle_tpu.kernels.pallas import mla_paged_attention as kmla
+
+    def run(q, arena, tables, start):
+        return kmla.mla_paged_attention(q, arena, tables, start, dv=1024,
+                                        scale=256 ** -0.5, window=513,
+                                        impl="pallas")
+
+    _compile(run, one_chip, ((S, W, 64, 1152), BF16),
+             ((_D3_WINDOW_POOL, 128, 1152), BF16), ((S, _D3_BLOCKS), jnp.int32),
+             ((S,), jnp.int32), names=("pt_mla_window_attention",))
+
+
+@pytest.mark.parametrize("S,W", [(32, 1), (1, 256), (1, 2048)])
+def test_dots3_sparse_layer_kernels_published_widths(one_chip, S, W):
+    """The full layers' two kernels at sizes they had never run: 64 index
+    heads (the tile's ``[8, 67584]`` float32 scores resident) and 128 query
+    heads a slab under the selection's bias."""
+    from paddle_tpu.kernels.pallas import dsa_index
+    from paddle_tpu.kernels.pallas import mla_sparse_attention as ksp
+
+    def scores(qi, wi, arena, tables, start):
+        return dsa_index.dsa_index_scores(qi, wi, arena, tables, start,
+                                          impl="pallas")
+
+    _compile(scores, one_chip, ((S, W, 64, 128), BF16),
+             ((S, W, 64), jnp.float32), ((_D3_POOL, 128, 128), BF16),
+             ((S, _D3_BLOCKS), jnp.int32), ((S,), jnp.int32),
+             names=("pt_dsa_index_scores",))
+
+    def attend(q, arena, tables, start, bias):
+        return ksp.mla_sparse_attention(q, arena, tables, start, bias, dv=512,
+                                        scale=192 ** -0.5, impl="pallas")
+
+    _compile(attend, one_chip, ((S, W, 128, 640), BF16),
+             ((_D3_POOL, 128, 640), BF16), ((S, _D3_BLOCKS), jnp.int32),
+             ((S,), jnp.int32), ((S, -(-W // 8) * 8, _D3_LP), jnp.float32),
+             names=("pt_mla_sparse_attention",))
+
+
+
 @pytest.mark.parametrize("h,w,held,tokens", [
     (7680, 2048, 16, 128), (7680, 2048, 16, 512), (7680, 2048, 16, 640),
     (2048, 512, 256, 2176), (2048, 512, 256, 128), (6144, 2048, 16, 2080)],

@@ -10,6 +10,7 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu import serving
 from paddle_tpu.models import (BrumbyConfig, BrumbyForCausalLM,
+                               Dots3NoteConfig, Dots3NoteForCausalLM,
                                FalconH1Config, FalconH1ForCausalLM,
                                GlmMoeDsaConfig, GlmMoeDsaForCausalLM,
                                GPTConfig, GPTForCausalLM, LagunaConfig,
@@ -28,6 +29,7 @@ MODELS = {
     "laguna": (LagunaForCausalLM, LagunaConfig.tiny),
     "glm_dsa": (GlmMoeDsaForCausalLM, GlmMoeDsaConfig.tiny),
     "brumby": (BrumbyForCausalLM, BrumbyConfig.tiny),
+    "dots3_note": (Dots3NoteForCausalLM, Dots3NoteConfig.tiny),
 }
 # decode, the largest prefill bucket (which carries the round where the
 # model's ``carries_rounds``), and a smaller bucket
@@ -146,8 +148,8 @@ def test_every_heavy_op_sits_under_a_part(lowered, model, program):
         want = {"attn_proj", "attention", "norm", "mlp", "head"}
         assert "cache_write" not in used and "mixer" not in used
     want |= {"mixer"} if model == "falcon_h1" else set()
-    want |= {"router", "experts"} if model in ("openpangu", "laguna",
-                                               "glm_dsa") else set()
+    want |= {"router", "experts"} if model in (
+        "openpangu", "laguna", "glm_dsa", "dots3_note") else set()
     assert want <= used <= set(parts.PARTS), used
 
 
@@ -186,7 +188,8 @@ def test_the_retention_is_a_scope_inside_attention_not_a_part(lowered,
 
 
 @pytest.mark.parametrize("program", list(PROGRAMS))
-@pytest.mark.parametrize("model", ["openpangu", "laguna", "glm_dsa"])
+@pytest.mark.parametrize("model", ["openpangu", "laguna", "glm_dsa",
+                                   "dots3_note"])
 def test_expert_models_programs_hand_back_their_weight_streams(
         lowered, model, program):
     """Every window program of a model with expert layers hands back, beside
