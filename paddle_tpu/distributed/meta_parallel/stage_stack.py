@@ -60,17 +60,25 @@ def _memory_sharding(kind: str):
 
 def remat_wrap(fn):
     """jax.checkpoint with the policy chosen by FLAGS_remat_policy. Every
-    policy keeps what crossed ``mp`` (a row-parallel layer's all-reduced
-    output, named ``mp_layers.MP_OUT``: 2 x batch x seq x hidden bytes a
-    layer; off a mesh with ``mp`` nothing carries the name and nothing is kept):
-    '' = full remat (save the inputs and that, recompute everything else —
-    min memory),
+    policy keeps two things by name. What crossed ``mp`` (a row-parallel
+    layer's all-reduced output, ``mp_layers.MP_OUT``: 2 x batch x seq x
+    hidden bytes a layer; off a mesh with ``mp`` nothing carries the name and
+    nothing is kept). And what the flash-attention forward kernel produced
+    (``flash_o`` / ``flash_lse``, the residuals its backward reads, named in
+    ``kernels/flash_attention.py`` by the dense entry: 2 x batch x seq x
+    hidden bytes + 4 x batch x heads x seq a layer; where attention takes
+    the XLA softmax or the ring, nothing carries the names), so the replayed
+    layer never runs that kernel again. Beyond those:
+    '' = full remat (save the inputs, recompute everything else — min
+    memory),
     'dots' = save dot/matmul outputs without batch dims (skip re-running the
     MXU work in backward at the cost of activation HBM — the reference's
     selective-recompute tier), 'dots_all' = save every matmul output,
-    'flash' = pin flash-attention o+lse, 'moe'/'route' = pin the named MoE
-    buffers/routing maps (names exist only on the default 'index' dispatch
-    path — under sort/einsum/gmm these two degrade to full remat)."""
+    'flash' = the same policy as '' (a value kept accepted), 'moe'/'route' =
+    pin the named MoE buffers/routing maps (names exist only on the default 'index' dispatch
+    path — under sort/einsum/gmm these two degrade to full remat).
+    The unscanned layer list (``distributed/utils_recompute.py``: a plain
+    ``jax.checkpoint``, no policy) keeps nothing by name."""
     try:
         from ...framework import flags as flags_mod
 
@@ -78,21 +86,18 @@ def remat_wrap(fn):
     except Exception:
         pol = ""
     policies = jax.checkpoint_policies
-    # 'flash': the flash-attention outputs (o + lse, named in
-    # kernels/flash_attention.py) so the backward recompute skips the forward
-    # Pallas kernel — ~50MB/layer for the fwd kernel's time.
     # 'moe': the expert capacity buffer + expert outputs (named in
-    # nn/layer/moe.py) and the flash residuals; the backward recompute then
-    # rebuilds only the g/u projections from the saved buffer instead of
-    # re-running routing + dispatch + down-proj.
+    # nn/layer/moe.py); the backward recompute then rebuilds only the g/u
+    # projections from the saved buffer instead of re-running routing +
+    # dispatch + down-proj.
     # 'route': ONLY the routing decisions (slot/keep/src maps + gates,
     # ~1MB/layer): the backward recompute replays the expert matmuls but skips
     # the router matmul/softmax/top_k/cumsum/int-scatter chain — near-zero
     # memory for the routing chain's time
-    names = {"flash": ("flash_o", "flash_lse"),
-             "moe": ("flash_o", "flash_lse", "moe_buf", "moe_out", "moe_route"),
+    names = {"moe": ("moe_buf", "moe_out", "moe_route"),
              "route": ("moe_route",)}.get(pol, ())
-    policy = policies.save_only_these_names(MP_OUT, *names)
+    policy = policies.save_only_these_names(MP_OUT, "flash_o", "flash_lse",
+                                            *names)
     dots = {"dots": policies.dots_with_no_batch_dims_saveable,
             "dots_all": policies.dots_saveable}.get(pol)
     if dots is not None:

@@ -116,16 +116,20 @@ define_flag("flash_block_k", 0,
 define_flag("flash_bwd_block_q", 0,
             "flash-attention BACKWARD q block size (0 = same as forward); "
             "the bwd kernels hold more f32 VMEM operands so smaller blocks "
-            "can pipeline better")
+            "can pipeline better; on a TPU a multiple of 128 or the whole "
+            "sequence (lse and delta are read in row blocks)")
 define_flag("flash_bwd_block_k", 0,
             "flash-attention BACKWARD k block size (0 = same as forward)")
 define_flag("remat_policy", "",
-            "recompute policy for scanned stacks: ''=full remat (every policy "
-            "keeps what a row-parallel layer all-reduced over mp), 'dots'=save "
+            "recompute policy for scanned stacks: ''=full remat; every policy "
+            "keeps what a row-parallel layer all-reduced over mp and the "
+            "flash-attention kernel's o+lse (its backward's residuals: the "
+            "replayed layer never runs the forward kernel again; 2 x batch x "
+            "seq x hidden bytes + an lse a layer), 'dots'=save "
             "non-batch matmul outputs, 'dots_all'=save all matmul outputs, "
-            "'flash'=save flash-attention o+lse (skips the fwd kernel in "
-            "the backward recompute), 'moe'=also pin the MoE capacity "
-            "buffer/expert outputs/routing maps, 'route'=pin only the MoE "
+            "'flash'=the same policy as '' (kept accepted), 'moe'=also pin "
+            "the MoE capacity buffer/expert outputs/routing maps, "
+            "'route'=pin only the MoE "
             "routing decisions (~1MB/layer); 'moe'/'route' names exist "
             "only on the default index dispatch path")
 define_flag("moe_dispatch", "index",

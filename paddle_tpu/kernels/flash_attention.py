@@ -11,7 +11,8 @@ paddle/fluid/operators/fused/), re-designed for the MXU:
   block merging.
 - backward: two Pallas kernels (dk/dv with a q-block inner grid, dq with a
   k-block inner grid) using the saved lse — the standard flash backward; the
-  full [sq, sk] probability matrix is never materialized in HBM.
+  full [sq, sk] probability matrix is never materialized in HBM. lse and
+  delta reach them as dense rows, not as lane-padded columns.
 - `q_offset`: global-position offset added to q positions for the causal
   mask, so a context-parallel rank can attend a remote K/V chunk with the
   correct global causality (paddle_tpu.distributed.context_parallel rides
@@ -27,6 +28,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -170,8 +172,8 @@ def _bwd_dkv_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0]
         v = v_ref[0]
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]  # [block_q, 1]
-        delta = delta_ref[0]
+        lse = jnp.expand_dims(lse_ref[0, 0], -1)  # [block_q, 1]
+        delta = jnp.expand_dims(delta_ref[0, 0], -1)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if causal:
@@ -220,8 +222,8 @@ def _bwd_dq_kernel(off_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         k = k_ref[0]
         v = v_ref[0]
         do = do_ref[0].astype(jnp.float32)
-        lse = lse_ref[0]  # [block_q, 1]
-        delta = delta_ref[0]
+        lse = jnp.expand_dims(lse_ref[0, 0], -1)  # [block_q, 1]
+        delta = jnp.expand_dims(delta_ref[0, 0], -1)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if causal:
@@ -262,8 +264,19 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, offset, causal, scale,
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     if dlse is not None:
         delta = delta - dlse.astype(jnp.float32)
-    lse = lse[..., None]
-    delta = delta[..., None]
+    # lse and delta go in as ROWS ([bh, 1, sq], a block [1, 1, block_q]) and
+    # the kernels turn a block into the column they subtract: as columns
+    # [bh, sq, 1] Mosaic's operands are padded to 128 lanes (64 MiB each at
+    # 64 x 2048), which XLA wrote out of the dense delta — and, where the
+    # recompute hands lse back from its stack, out of the dense lse — once a
+    # layer (3.1 ms of cell 1's step, PERF.md section 6, PR 51)
+    if block_q % 128 and block_q != sq and not _interpret():
+        raise ValueError(
+            f"flash attention's backward reads lse in row blocks: its q "
+            f"block ({block_q}) must be a multiple of 128 or the whole "
+            f"sequence ({sq})")
+    lse = lse[:, None, :]
+    delta = delta[:, None, :]
 
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, scale=scale, causal=causal,
@@ -276,8 +289,8 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, offset, causal, scale,
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
             pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, j, i: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),
+            pl.BlockSpec((1, 1, block_q), lambda b, j, i: (b, 0, i)),
         ],
         out_specs=[
             pl.BlockSpec((1, block_k, d), lambda b, j, i: (b, j, 0)),
@@ -305,8 +318,8 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, offset, causal, scale,
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, block_k, d), lambda b, i, j: (b, j, 0)),
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda b, i, j: (b, i, 0)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
+            pl.BlockSpec((1, 1, block_q), lambda b, i, j: (b, 0, i)),
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
@@ -318,20 +331,28 @@ def _flash_bwd(q, k, v, o, lse, do, dlse, offset, causal, scale,
 
 # -- differentiable wrapper (bh, s, d layout) ---------------------------------
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
 def _flash_lse_bhsd(q, k, v, offset, causal, scale, block_q, block_k,
-                    bwd_block_q, bwd_block_k):
+                    bwd_block_q, bwd_block_k, named):
     return _flash_fwd(q, k, v, offset, causal, scale, block_q, block_k)
 
 
 def _flash_lse_fwd(q, k, v, offset, causal, scale, block_q, block_k,
-                   bwd_block_q, bwd_block_k):
+                   bwd_block_q, bwd_block_k, named):
     o, lse = _flash_fwd(q, k, v, offset, causal, scale, block_q, block_k)
+    if named:
+        # the names sit on the values the backward READS (the residuals
+        # below), so a recompute whose policy saves them (stage_stack.
+        # remat_wrap: every policy) finds its residuals without the forward
+        # kernel and drops it from the replayed layer. A name on the op's
+        # outputs would save copies nothing reads.
+        o = checkpoint_name(o, "flash_o")
+        lse = checkpoint_name(lse, "flash_lse")
     return (o, lse), (q, k, v, o, lse, offset)
 
 
 def _flash_lse_bwd(causal, scale, block_q, block_k, bwd_block_q, bwd_block_k,
-                   res, cts):
+                   named, res, cts):
     q, k, v, o, lse, offset = res
     do, dlse = cts
     dq, dk, dv = _flash_bwd(q, k, v, o, lse, do, dlse, offset, causal, scale,
@@ -364,23 +385,22 @@ def _default_blocks():
 
 
 def flash_attention_with_lse(q, k, v, offset=0, causal=False, scale=None,
-                             block_q: int = None, block_k: int = None):
+                             block_q: int = None, block_k: int = None,
+                             named: bool = False):
     """q/k/v: [bh, s, d]. Returns (out [bh, sq, d], lse [bh, sq] fp32).
-    `offset` shifts q's global positions for the causal mask (ring attention)."""
+    `offset` shifts q's global positions for the causal mask (ring attention).
+    ``named``: when differentiated, the backward's residuals ``o`` / ``lse``
+    carry the names ``flash_o`` / ``flash_lse``, which a layer's recompute
+    keeps (one layer's attention a call: the dense entry below asks for it;
+    the ring's ``cp`` partial calls a layer do not)."""
     if scale is None:
         scale = 1.0 / math.sqrt(q.shape[-1])
     dq_, dk_, bbq, bbk = _default_blocks()
     block_q = dq_ if block_q is None else block_q
     block_k = dk_ if block_k is None else block_k
-    o, lse = _flash_lse_bhsd(q, k, v, jnp.asarray(offset, jnp.int32),
-                             bool(causal), float(scale), int(block_q),
-                             int(block_k), int(bbq), int(bbk))
-    # named for selective remat (FLAGS_remat_policy='flash'): saving o+lse
-    # lets jax.checkpoint DCE the forward Pallas kernel from the backward
-    # recompute (its custom-vjp residuals become available without it)
-    from jax.ad_checkpoint import checkpoint_name
-
-    return checkpoint_name(o, "flash_o"), checkpoint_name(lse, "flash_lse")
+    return _flash_lse_bhsd(q, k, v, jnp.asarray(offset, jnp.int32),
+                           bool(causal), float(scale), int(block_q),
+                           int(block_k), int(bbq), int(bbk), bool(named))
 
 
 def flash_attention(q, k, v, causal: bool = False, scale: float = None,
@@ -396,5 +416,5 @@ def flash_attention(q, k, v, causal: bool = False, scale: float = None,
     # self-attention with sk>=sq: rows see the key prefix plus the diagonal
     offset = sk - sq if causal else 0
     om, _ = flash_attention_with_lse(qm, km, vm, offset, causal, float(scale),
-                                     block_q, block_k)
+                                     block_q, block_k, named=True)
     return jnp.moveaxis(om.reshape(b, h, sq, d), 1, 2)

@@ -95,6 +95,78 @@ def test_flash_fwd_bwd(one_chip, monkeypatch, b, s, h, d):
              names=("pt_flash_fwd", "pt_flash_bwd_dkv", "pt_flash_bwd_dq"))
 
 
+def _recomputed_attention_scan(attend):
+    """The loss of two scanned, recomputed attention layers (projections,
+    ``attend``, output projection, residual) at cell 1's widths: 16 heads of
+    128 over hidden 2048, stacked weights as the layer scan holds them."""
+    from paddle_tpu.distributed.meta_parallel.stage_stack import remat_wrap
+
+    def layer(x, w):
+        wqkv, wo = w
+        q, k, v = jnp.einsum("bsh,hcnd->cbsnd", x, wqkv)
+        return x + jnp.einsum("bsnd,ndh->bsh", attend(q, k, v), wo), None
+
+    def loss(x, wqkv, wo):
+        y, _ = jax.lax.scan(remat_wrap(layer), x, (wqkv, wo))
+        return jnp.sum(y.astype(jnp.float32))
+
+    shapes = [(4, 2048, 2048), (2, 2048, 3, 16, 128), (2, 16, 128, 2048)]
+    return jax.grad(loss, argnums=(0, 1, 2)), shapes
+
+
+def _flash_forward_calls(text):
+    return [c for c in _CUSTOM_CALL.findall(text) if "pt_flash_fwd" in c]
+
+
+def test_recomputed_scan_holds_one_flash_forward(one_chip, monkeypatch):
+    """The layer recompute keeps the forward kernel's ``o`` / ``lse`` (its
+    backward's residuals, named; every policy saves them): the gradient of
+    the scan holds ONE ``pt_flash_fwd`` custom call — the forward scan's —
+    where the replayed layer used to hold a second (ISSUE 51)."""
+    from paddle_tpu.kernels import flash_attention as fa
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    grad, shapes = _recomputed_attention_scan(
+        lambda q, k, v: fa.flash_attention(q, k, v, causal=True))
+    text = _compile(grad, one_chip, *[(s, BF16) for s in shapes],
+                    names=("pt_flash_fwd", "pt_flash_bwd_dkv",
+                           "pt_flash_bwd_dq"))
+    assert len(_flash_forward_calls(text)) == 1, _flash_forward_calls(text)
+
+
+def test_recomputed_scan_on_the_mesh_holds_one_flash_forward(topo,
+                                                             monkeypatch):
+    """Cell 3's form: on ``dp=2 x mp=2`` flash rides
+    ``run_forward_kernel_on_mesh``, the custom-vjp INSIDE the manual region.
+    ``shard_map``'s partial evaluation hands the recompute's policy to the
+    inner program, so the names are honoured there too: one ``pt_flash_fwd``
+    custom call for the four described chips, not two."""
+    import paddle_tpu.distributed as dist
+    from paddle_tpu.distributed.mesh import activation_spec
+    from paddle_tpu.kernels import flash_attention as fa
+    from paddle_tpu.nn.functional.attention import _sdpa
+    from jax.sharding import PartitionSpec as P
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    grad, shapes = _recomputed_attention_scan(
+        lambda q, k, v: _sdpa.fn(q, k, v, causal=True, scale=128 ** -0.5,
+                                 impl="flash"))
+    dist.reset_mesh()
+    env = dist.init_mesh(dp=2, mp=2, devices=list(topo.devices))
+    try:
+        held = [env.sharding_for(activation_spec(shapes[0], "rows")),
+                env.sharding_for(P(None, None, None, "mp", None)),
+                env.sharding_for(P(None, "mp", None, None))]
+        args = [jax.ShapeDtypeStruct(s, BF16, sharding=sh)
+                for s, sh in zip(shapes, held)]
+        text = jax.jit(grad, out_shardings=tuple(held)).lower(*args) \
+            .compile().as_text()
+    finally:
+        dist.reset_mesh()
+    assert len(_flash_forward_calls(text)) == 1, _flash_forward_calls(text)
+    assert any("pt_flash_bwd_dkv" in c for c in _CUSTOM_CALL.findall(text))
+
+
 @pytest.mark.parametrize("residual", [False, True])
 def test_rmsnorm_fwd_bwd(one_chip, residual):
     from paddle_tpu.kernels.pallas import rmsnorm as krms
