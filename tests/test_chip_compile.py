@@ -191,15 +191,16 @@ def test_rmsnorm_fwd_bwd(one_chip, residual):
     _compile(jax.grad(loss, argnums=argnums), one_chip, *shapes, names=names)
 
 
-@pytest.mark.parametrize("b", [4, 16])
-def test_rope_fwd_inverse(one_chip, b):
+# cell 1's q / k, a larger batch, and cell 3's per-chip q / k (mp = 2)
+@pytest.mark.parametrize("b,h", [(4, 16), (16, 16), (4, 8), (4, 4)])
+def test_rope_fwd_inverse(one_chip, b, h):
     from paddle_tpu.kernels.pallas import rope as krope
 
     def loss(x):  # grad = the inverse rotation through the same kernel
         return jnp.sum(krope.rope_apply(x, 1e4, 0, impl="pallas")
                        .astype(jnp.float32))
 
-    _compile(jax.value_and_grad(loss), one_chip, ((b, 2048, 16, 128), BF16),
+    _compile(jax.value_and_grad(loss), one_chip, ((b, 2048, h, 128), BF16),
              names=("pt_rope",))
 
 

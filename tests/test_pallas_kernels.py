@@ -131,24 +131,64 @@ def test_rms_norm_functional_routes_through_the_seam(monkeypatch):
 
 # -- RoPE ---------------------------------------------------------------------
 
-@pytest.mark.parametrize("shape,offset", [((2, 12, 3, 8), 0),
-                                          ((1, 10, 5, 6), 7),
-                                          ((2, 16, 4, 64), 3)])
-def test_rope_parity_fwd_and_grad(shape, offset):
-    x = jax.random.normal(jax.random.key(2), shape, jnp.float32)
+# d = 128 is the lane-roll path; ``rows`` caps the sequence block so that a
+# short sequence spans several blocks, the last one ragged (80 = 32+32+16)
+@pytest.mark.parametrize("shape,offset,dtype,rows", [
+    ((2, 12, 3, 8), 0, jnp.float32, None),
+    ((1, 10, 5, 6), 7, jnp.float32, None),
+    ((2, 16, 4, 64), 3, jnp.float32, None),
+    ((2, 80, 4, 128), 0, jnp.float32, 32),
+    ((2, 80, 8, 128), 5, jnp.float32, 32),
+    ((1, 64, 16, 128), 0, jnp.float32, 32),
+    ((2, 40, 3, 128), 9, jnp.float32, 32),
+    ((2, 24, 5, 128), 2, jnp.float32, None),
+    ((2, 80, 4, 128), 3, jnp.bfloat16, 32),
+    ((1, 48, 8, 128), 0, jnp.bfloat16, 32),
+    ((1, 40, 16, 128), 11, jnp.bfloat16, 32),
+    ((2, 36, 3, 64), 4, jnp.bfloat16, 32),
+])
+def test_rope_parity_fwd_and_grad(shape, offset, dtype, rows, monkeypatch):
+    if rows:
+        monkeypatch.setattr(krope, "_MAX_ROWS", rows)
+        assert -(-shape[1] // krope._pick_seq_block(
+            *shape[1:], jnp.dtype(dtype).itemsize)) > 1
+    # 1 ulp of inv_freq between exp() and the reference's pow() is an angle
+    # error of position x 6e-8: the float32 tolerance grows with the span
+    tol = dict(rtol=2e-2, atol=2e-2) if dtype == jnp.bfloat16 else \
+        dict(rtol=1e-4, atol=1e-4) if shape[1] + offset > 32 else TOL
+    x = jax.random.normal(jax.random.key(2), shape, jnp.float32).astype(dtype)
     oi = krope.rope_apply(x, 1e4, offset, impl="interpret")
     oc = krope.rope_apply(x, 1e4, offset, impl="reference")
     from paddle_tpu.models.llama import _rope
 
-    _close(oi, oc)
-    _close(_rope.fn(x, theta=1e4, pos_offset=offset, impl="interpret"), oc)
+    assert oi.dtype == dtype and oi.shape == shape
+    _close(oi, oc, **tol)
+    _close(_rope.fn(x, theta=1e4, pos_offset=offset, impl="interpret"), oc,
+           **tol)
 
     def loss(impl):
-        return lambda z: jnp.sum(
-            jnp.sin(krope.rope_apply(z, 1e4, offset, impl=impl)))
+        return lambda z: jnp.sum(jnp.sin(krope.rope_apply(
+            z, 1e4, offset, impl=impl).astype(jnp.float32)))
 
     # the kernel's inverse-rotation VJP against autodiff of the reference
-    _close(jax.grad(loss("interpret"))(x), jax.grad(loss("reference"))(x))
+    _close(jax.grad(loss("interpret"))(x), jax.grad(loss("reference"))(x),
+           **tol)
+
+
+@pytest.mark.parametrize("shape,offset,rows", [((2, 80, 4, 128), 6, 32),
+                                               ((1, 10, 5, 6), 7, None)])
+def test_rope_inverse_undoes_forward(shape, offset, rows, monkeypatch):
+    """The residual-free VJP rests on it: the rotation is orthogonal, so
+    the backward half (the inverse rotation) applied to the forward's
+    output is the input."""
+    if rows:
+        monkeypatch.setattr(krope, "_MAX_ROWS", rows)
+    fwd, bwd = krope.rope_halves(1e4, offset, "interpret")
+    x = jax.random.normal(jax.random.key(3), shape, jnp.float32)
+    y, res = fwd(x)
+    assert res == ()
+    assert float(jnp.abs(y - x).max()) > 0.1      # it did rotate
+    _close(bwd(res, y)[0], x, rtol=1e-5, atol=1e-5)
 
 
 def test_rope_rejects_odd_head_dim():
