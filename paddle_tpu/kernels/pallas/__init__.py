@@ -38,16 +38,20 @@ Pallas TPU kernel and a plain ``jnp`` reference (``impl=None`` asks
   (a decode round over the slot-indexed state arenas, each head's state read
   and written once, in place, ``phi`` built in VMEM) and ``retention_chunk``
   (a prefill window from a given state in inner chunks, the state resident
-  in VMEM).
+  in VMEM);
+- ``mhc``: the residual path of manifold-constrained hyper-connections (a
+  token's stream is ``n`` rows) — ``mhc_pre`` (a sublayer's three mixing
+  maps, Sinkhorn-projected, and its input from ONE read of the stream) and
+  ``mhc_post`` (the streams mixed and the sublayer's output added).
 
 Import order matters only in that importing this package populates the
 registry.
 """
-from . import (dsa_index, mla_paged_attention,  # noqa: F401
+from . import (dsa_index, mhc, mla_paged_attention,  # noqa: F401
                mla_sparse_attention, moe_dispatch, paged_attention,
                power_retention, ranged_paged_attention, rmsnorm, rope,
                ssm_step)
 
 __all__ = ["rmsnorm", "rope", "moe_dispatch", "paged_attention", "ssm_step",
            "mla_paged_attention", "ranged_paged_attention", "dsa_index",
-           "mla_sparse_attention", "power_retention"]
+           "mla_sparse_attention", "power_retention", "mhc"]

@@ -32,3 +32,6 @@ from .dots3_note import (  # noqa: F401
 from .brumby import (  # noqa: F401
     BrumbyConfig, BrumbyForCausalLM, BrumbyBlock, BrumbyServed,
 )
+from .xing4 import (  # noqa: F401
+    Xing4Config, Xing4ForCausalLM, Xing4Block, Xing4Served,
+)

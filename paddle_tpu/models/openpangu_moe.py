@@ -191,17 +191,19 @@ def mla_out(p, x, ctx, kv_b, *, dn, dv, post_norm_eps=None, gate=None):
     """The context ``ctx`` [R, W, H, dc] through the value half of the KV
     up-projection and the output projection, onto the stream — through the
     layer's ``post_attn_norm`` first where ``post_norm_eps`` is given (a
-    sandwich-norm block), as it is in a pre-norm block. ``gate`` [R, W, H]
-    float32: each head's output times its gate before the output projection
-    (``laguna.head_gate``)."""
+    sandwich-norm block), as it is in a pre-norm block; ``x`` ``None``: the
+    branch alone (a model whose residual path is not ``x + F``: ``xing4``).
+    ``gate`` [R, W, H] float32: each head's output times its gate before the
+    output projection (``laguna.head_gate``)."""
     R, W, H = ctx.shape[:3]
     o = jnp.einsum("rwhc,chv->rwhv", ctx.astype(kv_b.dtype), kv_b[..., dn:],
                    preferred_element_type=F32)
     if gate is not None:
         o = o * gate[..., None]
     a = _mm(o.reshape(R, W, H * dv), p["o"])
-    return x + (a if post_norm_eps is None
-                else _rms(a, p["post_attn_norm"], post_norm_eps))
+    if post_norm_eps is not None:
+        a = _rms(a, p["post_attn_norm"], post_norm_eps)
+    return a if x is None else x + a
 
 
 def _mla_dims(cfg):

@@ -172,6 +172,11 @@ class MetricsRegistry:
         with self._lock:
             return self._latency.percentiles((q,))[f"p{q}"]
 
+    def uptime_s(self) -> float:
+        """Seconds since the registry was made or last reset: what a
+        counter divides by to read as a rate."""
+        return max(time.monotonic() - self._t0, 1e-6)
+
     def qps(self) -> float:
         """Completions per second over the sliding window (or since start
         when the process is younger than the window)."""

@@ -17,7 +17,9 @@ rule: ``observability.trace.xplane.correlate().by_part``,
 A SUBPART is a second, nested vocabulary (``SUBPARTS``; ``pt.indexer``: the
 index projections, scores and top-k of a learned sparse attention;
 ``pt.retention``: the kernel calls of a power retention layer and their glue,
-inside ``attention``): it marks work INSIDE parts without being one. The ten parts stay a partition of the
+inside ``attention``; ``pt.mhc``: the residual path of a model whose stream is
+several rows — a sublayer's mixing maps and the mix itself, inside
+``attn_proj`` and ``mlp``): it marks work INSIDE parts without being one. The ten parts stay a partition of the
 step — a reader of ``PARTS`` skips a ``pt.`` name outside its vocabulary and
 finds the part around it, so the indexer's projections are still ``attn_proj``
 and its scores ``attention`` — and one reader of its own
@@ -31,7 +33,7 @@ __all__ = ["PARTS", "SUBPARTS", "PREFIX", "part", "subpart", "part_of"]
 
 PARTS = ("embed", "norm", "attn_proj", "cache_write", "attention", "mlp",
          "router", "experts", "mixer", "head")
-SUBPARTS = ("indexer", "retention")
+SUBPARTS = ("indexer", "retention", "mhc")
 PREFIX = "pt."
 
 

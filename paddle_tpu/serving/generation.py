@@ -1588,6 +1588,12 @@ class GenerationEngine(EngineBase):
         pool.state = state
         return nxt, lp, None, counted
 
+    def _count_tokens(self, real: int) -> None:
+        """What the ``real`` tokens of a program just dispatched add to the
+        served model's ``token_counters``."""
+        for name, each in self._sm.token_counters.items():
+            self.metrics.inc(name, each * real)
+
     def _count_programs(self, counted: List[Dict[str, Any]]) -> None:
         """Add what window programs counted to the metrics. The caller hands
         over the scalars of programs whose result it has just read — they
@@ -2437,6 +2443,7 @@ class GenerationEngine(EngineBase):
         # cached positions the chunk's queries see, summed (token w of the
         # chunk sees lo + w + 1)
         n = hi - lo
+        self._count_tokens(n + (0 if rnd is None else len(rnd.rows)))
         self.metrics.inc("attn_keys_prefill_total", n * lo + n * (n + 1) // 2)
         if self._index:
             # every "full" layer's indexer scored them all, once a layer
@@ -2728,6 +2735,7 @@ class GenerationEngine(EngineBase):
                     n_valid=self._round_valid(rnd))
         if not self._unpaged:
             self.metrics.inc("kv_rows_written_total", S * (k + 1))
+        self._count_tokens(len(rnd.rows) * (k + 1))
         if flying is not None:
             self.metrics.inc("programs_run_ahead_total")
 
@@ -2990,6 +2998,11 @@ class GenerationEngine(EngineBase):
             # call: 1 where the grouped matmul holds its contraction whole
             snap["moe_weight_streams_per_expert"] = round(
                 c.get("moe_weight_streams_total", 0) / hit, 4)
+        for name in self._sm.token_counters:
+            # "<what>_total" -> "<what>_per_s", since the engine's start
+            snap[name[:-len("_total")] + "_per_s"] = round(
+                c.get(name, 0) / self.metrics.uptime_s(), 3)
+
         def pages(what, kind):  # the prefill calls', the decode rounds'
             decode = c.get(f"attn_pages_{what}_{kind}_decode_total", 0)
             return c.get(f"attn_pages_{what}_{kind}_total", 0) - decode, decode

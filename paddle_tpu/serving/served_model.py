@@ -87,6 +87,12 @@ contains no model. A model that can be served implements
   a layer that has none). The window program sums them over its layers and
   returns them beside the tokens; the worker adds them to its counters at
   the sync it makes anyway;
+- ``token_counters``: ``{counter: n}`` — every REAL token a window program
+  runs through the blocks adds ``n`` to ``counter``, counted by the worker
+  on the host where it dispatches the program (it knows the chunk's tokens
+  and the round's live rows there; nothing is read back). What a model's
+  own path does a fixed number of times a token, whatever the engine knows
+  of it (``Xing4Served``: the residual path's mixes, two a layer);
 - ``carries_rounds`` (derived, never set): whether the prefill program of
   the engine's largest bucket also runs the running sequences' decode step
   (``generation._build_window_step``, ``carry`` > 0 of its one ``step``
@@ -153,6 +159,8 @@ class ServedModel:
     cache_spec: Optional[Dict[str, Any]] = None
     # None: the window programs hand back tokens and logprobs alone
     program_counters: Optional[Tuple[str, ...]] = None
+    # counters every real token of a dispatched program adds to, by how much
+    token_counters: Dict[str, int] = {}
     # True: ``block`` goes on from a state a previous prefill chunk returned
     # (and takes ``step=`` to tell that from the slot arenas of a round)
     resumes_state: bool = False

@@ -557,3 +557,72 @@ def test_ranged_paged_attention_published_widths(one_chip, monkeypatch, S, W,
             cost(*a, **k), vmem=c["vmem"] // 10))
         with pytest.raises(Exception, match="vmem"):
             _compile(lambda *a: run(*a), one_chip, *shapes, names=(name,))
+
+
+# Xing4.0 (``xing4.0-29b-a4b-d5``): a stream of 4 x 3584 float32 a token, 32
+# heads against latent rows of 576 laid out at 640 in a pool of 6400 pages (66
+# blocks a row: contexts to 8448), ALL 64 experts of 3584 x 1024 under a
+# router of 64 outputs, top 4: the 128-slot decode round, a tail bucket and
+# the 2048-token chunk that carries a round
+@pytest.mark.parametrize("tokens", [128, 256, 2176])
+def test_mhc_kernel_pair_published_widths(one_chip, tokens):
+    """The residual path's two kernels: a tile of 128 tokens' whole stream
+    (7.3 MB) resident in VMEM beside the packed projection, the Sinkhorn loop
+    on its ``[128, 128]`` logits, the mix into a stream of its own (an
+    aliased one read wrong inside a program on the chip: the kernel's
+    docstring)."""
+    from paddle_tpu.kernels.pallas import mhc
+
+    def pre(x, proj, bias):
+        return mhc.mhc_pre(x, proj, bias, n=4, iters=20, eps=1e-6, lo=-30.0,
+                           hi=30.0, impl="pallas")
+
+    _compile(pre, one_chip, ((tokens, 14336), jnp.float32),
+             ((2, 14336, 128), BF16), ((1, 128), jnp.float32),
+             names=("pt_mhc_pre",))
+
+    def post(x, y, maps):
+        return mhc.mhc_post(x, y, maps, n=4, impl="pallas")
+
+    text = _compile(post, one_chip, ((tokens, 14336), jnp.float32),
+                    ((tokens, 3584), jnp.float32),
+                    ((tokens, 128), jnp.float32), names=("pt_mhc_post",))
+    assert '"aliasing_operands":{"lists":[]}' in text
+
+
+@pytest.mark.parametrize("S,W", [(128, 1), (1, 256), (1, 2048)])
+def test_xing4_latent_attention_published_widths(one_chip, S, W):
+    from paddle_tpu.kernels.pallas import mla_paged_attention as kmla
+
+    def run(q, arena, tables, start):
+        return kmla.mla_paged_attention(
+            q, arena, tables, start, dv=512,
+            scale=192 ** -0.5 * 1.41589 ** 2, impl="pallas")
+
+    _compile(run, one_chip, ((S, W, 32, 640), BF16),
+             ((6400, 128, 640), BF16), ((S, 66), jnp.int32),
+             ((S,), jnp.int32), names=("pt_mla_paged_attention",))
+
+
+@pytest.mark.parametrize("tokens", [128, 2176], ids=["round", "carry"])
+def test_xing4_whole_expert_layer_published_widths(one_chip, monkeypatch,
+                                                   tokens):
+    """Every one of the 64 experts held, chosen by the top 4 of score + bias:
+    three ``gmm`` calls under the tiles ``choose_tiling`` takes from these
+    shapes."""
+    from paddle_tpu.kernels import grouped_matmul as gm
+    from paddle_tpu.nn.layer.moe import moe_held_experts_mlp
+
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+
+    def run(x, x32, router, bias, w_gate, w_up, w_down):
+        return moe_held_experts_mlp(x, router, w_gate, w_up, w_down, top_k=4,
+                                    first=0, scale=2.0, x_route=x32,
+                                    bias=bias)
+
+    text = _compile(run, one_chip, ((tokens, 3584), BF16),
+                    ((tokens, 3584), jnp.float32), ((3584, 64), jnp.float32),
+                    ((64,), jnp.float32), ((64, 3584, 1024), BF16),
+                    ((64, 3584, 1024), BF16), ((64, 1024, 3584), BF16),
+                    names=(), foreign="gmm")
+    assert sum(c.startswith("gmm") for c in _CUSTOM_CALL.findall(text)) == 3

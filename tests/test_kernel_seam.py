@@ -17,8 +17,8 @@ from paddle_tpu.distributed import mesh as mesh_mod
 from paddle_tpu.kernels import registry
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-OPS = ("dsa_index_scores", "mla_paged_attention", "mla_sparse_attention",
-       "moe_dispatch", "paged_attention",
+OPS = ("dsa_index_scores", "mhc_post", "mhc_pre", "mla_paged_attention",
+       "mla_sparse_attention", "moe_dispatch", "paged_attention",
        "ranged_paged_attention", "retention_chunk", "retention_step",
        "rms_norm", "rope", "ssm_step")
 

@@ -15,7 +15,8 @@ from paddle_tpu.models import (BrumbyConfig, BrumbyForCausalLM,
                                GlmMoeDsaConfig, GlmMoeDsaForCausalLM,
                                GPTConfig, GPTForCausalLM, LagunaConfig,
                                LagunaForCausalLM, OpenPanguMoEConfig,
-                               OpenPanguMoEForCausalLM)
+                               OpenPanguMoEForCausalLM, Xing4Config,
+                               Xing4ForCausalLM)
 from paddle_tpu.observability.trace import parts
 
 MODELS = {
@@ -30,6 +31,7 @@ MODELS = {
     "glm_dsa": (GlmMoeDsaForCausalLM, GlmMoeDsaConfig.tiny),
     "brumby": (BrumbyForCausalLM, BrumbyConfig.tiny),
     "dots3_note": (Dots3NoteForCausalLM, Dots3NoteConfig.tiny),
+    "xing4": (Xing4ForCausalLM, Xing4Config.tiny),
 }
 # decode, the largest prefill bucket (which carries the round where the
 # model's ``carries_rounds``), and a smaller bucket
@@ -149,7 +151,7 @@ def test_every_heavy_op_sits_under_a_part(lowered, model, program):
         assert "cache_write" not in used and "mixer" not in used
     want |= {"mixer"} if model == "falcon_h1" else set()
     want |= {"router", "experts"} if model in (
-        "openpangu", "laguna", "glm_dsa", "dots3_note") else set()
+        "openpangu", "laguna", "glm_dsa", "dots3_note", "xing4") else set()
     assert want <= used <= set(parts.PARTS), used
 
 
@@ -181,15 +183,39 @@ def test_the_retention_is_a_scope_inside_attention_not_a_part(lowered,
     inside = [(op, st) for op, st in ops if "pt.retention" in st.split("/")]
     assert len(inside) > 10
     assert {parts.part_of(st) for _op, st in inside} == {"attention"}
-    assert parts.SUBPARTS == ("indexer", "retention")
+    assert parts.SUBPARTS == ("indexer", "retention", "mhc")
     assert "retention" not in parts.PARTS
     gate = [st for op, st in ops if op == "logistic" or "log_sigmoid" in st]
     assert gate and all(parts.part_of(st) == "attn_proj" for st in gate)
 
 
 @pytest.mark.parametrize("program", list(PROGRAMS))
+def test_the_residual_path_is_a_scope_inside_parts_not_a_part(lowered,
+                                                              program):
+    """The nested ``pt.mhc`` scope marks the residual path of a model whose
+    stream is several rows — a sublayer's maps and its mix: the attention
+    sublayer's sit inside ``attn_proj``, the MLP sublayer's inside ``mlp``,
+    every op under it still has one of the ten parts, and the Sinkhorn
+    iterations are ONE loop a sublayer, not twenty unrolled."""
+    eng, progs = lowered("xing4")
+    ops = ops_with_name_stacks(progs[program].as_text(debug_info=True))
+    inside = [(op, st) for op, st in ops if "pt.mhc" in st.split("/")]
+    assert len(inside) > 10
+    around = {parts.part_of(st) for _op, st in inside}
+    assert around == {"attn_proj", "mlp"}, around
+    assert "mhc" not in parts.PARTS and "mhc" in parts.SUBPARTS
+    # a loop's body is traced once: two divisions (columns, rows) a sublayer
+    divides = [st for op, st in inside
+               if op == "divide" and "while/body" in st]
+    assert len(divides) == 2 * 2 * eng._sm.num_layers
+    # the router, the experts and the latent kernel are not the path's
+    assert not any("pt.router" in st or "pt.experts" in st
+                   or "pt.attention" in st for _op, st in inside)
+
+
+@pytest.mark.parametrize("program", list(PROGRAMS))
 @pytest.mark.parametrize("model", ["openpangu", "laguna", "glm_dsa",
-                                   "dots3_note"])
+                                   "dots3_note", "xing4"])
 def test_expert_models_programs_hand_back_their_weight_streams(
         lowered, model, program):
     """Every window program of a model with expert layers hands back, beside
