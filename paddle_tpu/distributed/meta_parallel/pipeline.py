@@ -29,7 +29,7 @@ from ..mesh import MeshEnv, require_mesh_env
 
 
 def ppermute_pipeline(run_stage: Callable, x_mb, pp_size: int, axis: str = "pp",
-                      remat: bool = True, with_aux: bool = False):
+                      remat: bool = True, with_aux: bool = False, keep=()):
     """Run the microbatch pipeline for THIS device's stage (call inside a
     shard_map manual over `axis`).
 
@@ -46,7 +46,7 @@ def ppermute_pipeline(run_stage: Callable, x_mb, pp_size: int, axis: str = "pp",
     if remat:
         from .stage_stack import remat_wrap
 
-        run_stage = remat_wrap(run_stage)
+        run_stage = remat_wrap(run_stage, keep)
 
     def tick(carry, t):
         state, outs, aux_acc = carry
@@ -168,7 +168,7 @@ def unmicrobatch(x_mb, env=None):
 
 
 def pipeline_shard_map(stage_fn: Callable, env: MeshEnv, n_stage_args: int,
-                       remat: bool = True, with_aux: bool = False):
+                       remat: bool = True, with_aux: bool = False, keep=()):
     """Wrap `stage_fn(x_local, *stage_params_local)` into the full pipelined
     [M, mb, ...] -> [M, mb, ...] function.
 
@@ -182,7 +182,7 @@ def pipeline_shard_map(stage_fn: Callable, env: MeshEnv, n_stage_args: int,
         def local(x_mb_l, *params_l):
             return ppermute_pipeline(
                 lambda h: stage_fn(h, *params_l), x_mb_l, pp, remat=remat,
-                with_aux=with_aux)
+                with_aux=with_aux, keep=keep)
 
         out_specs = (P(), P()) if with_aux else P()
         return jax.shard_map(

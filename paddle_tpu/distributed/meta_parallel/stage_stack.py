@@ -16,10 +16,12 @@ body bounds live activations to O(microbatch) exactly like early-backward.
 """
 from __future__ import annotations
 
+import contextlib
 from typing import List, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 
 from ...core.dispatch import primitive
 from ...core.tensor import Tensor
@@ -58,7 +60,45 @@ def _memory_sharding(kind: str):
     return SingleDeviceSharding(dev, memory_kind=kind)
 
 
-def remat_wrap(fn):
+# The projection outputs a layer may name where it produces them
+# (``name_for_recompute``; models/llama.py does): q and k after RoPE, v, a
+# row-parallel attention output no ``mp`` names, and the MLP's up / gate.
+# A name is inert until a recompute's policy keeps it; WHICH of them the
+# default policy keeps is chosen against the compiled step's memory
+# (jit/remat_fit.py) and reaches the stack as ``keep``.
+ATTN_Q, ATTN_K, ATTN_V, ATTN_O = "attn_q", "attn_k", "attn_v", "attn_o"
+MLP_UP, MLP_GATE = "mlp_up", "mlp_gate"
+
+_KEEP = [()]
+
+
+@contextlib.contextmanager
+def keeping(names):
+    """The step traced inside keeps ``names`` through its stacks' recompute,
+    besides what every policy keeps (``StackedStageRun.forward`` reads it
+    and hands it to the stack's primitive as an attribute)."""
+    prior, _KEEP[0] = _KEEP[0], tuple(names)
+    try:
+        yield
+    finally:
+        _KEEP[0] = prior
+
+
+def name_for_recompute(t: Tensor, name: str) -> Tensor:
+    """``t`` under ``name`` for a recompute's policy, where ``t`` is traced
+    with the tape off — as a stack's body runs its template, and a compiled
+    step its model. The name lowers to nothing: a program no policy reads it
+    in is the program without it, to the letter. Anywhere else there is no
+    recompute to read it and ``t`` comes back as it is."""
+    from ...core import autograd
+
+    if autograd.is_grad_enabled() or not isinstance(t.data, jax.core.Tracer):
+        return t
+    return Tensor(checkpoint_name(t.data, name),
+                  stop_gradient=t.stop_gradient)
+
+
+def remat_wrap(fn, keep=()):
     """jax.checkpoint with the policy chosen by FLAGS_remat_policy. Every
     policy keeps two things by name. What crossed ``mp`` (a row-parallel
     layer's all-reduced output, ``mp_layers.MP_OUT``: 2 x batch x seq x
@@ -69,12 +109,14 @@ def remat_wrap(fn):
     hidden bytes + 4 x batch x heads x seq a layer; where attention takes
     the XLA softmax or the ring, nothing carries the names), so the replayed
     layer never runs that kernel again. Beyond those:
-    '' = full remat (save the inputs, recompute everything else — min
-    memory),
+    '' = what fits: ``keep``, the projection outputs (the names above) that
+    ``jit/remat_fit.py`` found the compiled step has memory for — none of
+    them (full remat: save the inputs, recompute everything else) where the
+    device states no memory limit, as on the CPU, or nothing chose;
     'dots' = save dot/matmul outputs without batch dims (skip re-running the
     MXU work in backward at the cost of activation HBM — the reference's
     selective-recompute tier), 'dots_all' = save every matmul output,
-    'flash' = the same policy as '' (a value kept accepted), 'moe'/'route' =
+    'flash' = full remat (a value kept accepted), 'moe'/'route' =
     pin the named MoE buffers/routing maps (names exist only on the default 'index' dispatch
     path — under sort/einsum/gmm these two degrade to full remat).
     The unscanned layer list (``distributed/utils_recompute.py``: a plain
@@ -94,7 +136,8 @@ def remat_wrap(fn):
     # ~1MB/layer): the backward recompute replays the expert matmuls but skips
     # the router matmul/softmax/top_k/cumsum/int-scatter chain — near-zero
     # memory for the routing chain's time
-    names = {"moe": ("moe_buf", "moe_out", "moe_route"),
+    names = {"": tuple(keep),
+             "moe": ("moe_buf", "moe_out", "moe_route"),
              "route": ("moe_route",)}.get(pol, ())
     policy = policies.save_only_these_names(MP_OUT, "flash_o", "flash_lse",
                                             *names)
@@ -177,7 +220,7 @@ class StackedStageRun(Layer):
         out, aux = _run_stack(hidden, *stacked, _run_id=id(self),
                               use_recompute=self.recompute and self.training,
                               microbatches=self.num_microbatches or 0,
-                              stream=_STREAM_MODE[0])
+                              stream=_STREAM_MODE[0], keep=_KEEP[0])
         from ...nn.layer import moe as moe_mod
 
         moe_mod.record_aux(aux)
@@ -186,7 +229,7 @@ class StackedStageRun(Layer):
 
 @primitive("pp_stage_stack")
 def _run_stack_fn(hidden, *stacked, _run_id, use_recompute, microbatches,
-                  stream=False):
+                  stream=False, keep=()):
     from ...core import autograd
     from ...nn.layer import moe as moe_mod
 
@@ -225,7 +268,7 @@ def _run_stack_fn(hidden, *stacked, _run_id, use_recompute, microbatches,
                              "feature; it cannot combine with pp")
         devm = _memory_sharding("device")
         shapes = getattr(run, "_slice_shapes", [None] * len(stacked))
-        body_c = remat_wrap(body) if use_recompute else body
+        body_c = remat_wrap(body, keep) if use_recompute else body
         out = hidden
         aux_total = jnp.zeros((), jnp.float32)
         for i in range(run.depth):
@@ -260,21 +303,22 @@ def _run_stack_fn(hidden, *stacked, _run_id, use_recompute, microbatches,
 
         x_mb = microbatch(hidden, M, env)
         piped = pipeline_shard_map(stage_fn, env, len(stacked),
-                                   remat=use_recompute, with_aux=True)
+                                   remat=use_recompute, with_aux=True,
+                                   keep=keep)
         out_mb, aux = piped(x_mb, *stacked)
         return unmicrobatch(out_mb, env), aux / M
 
     if use_recompute:
-        body = remat_wrap(body)
+        body = remat_wrap(body, keep)
     out, aux = jax.lax.scan(body, hidden, tuple(stacked))
     return out, jnp.sum(aux)
 
 
 def _run_stack(hidden, *stacked, _run_id, use_recompute, microbatches,
-               stream=False):
+               stream=False, keep=()):
     return _run_stack_fn(hidden, *stacked, _run_id=_run_id,
                          use_recompute=use_recompute, microbatches=microbatches,
-                         stream=stream)
+                         stream=stream, keep=keep)
 
 
 def find_homogeneous_run(layers: List[Layer], min_len: int = 2):

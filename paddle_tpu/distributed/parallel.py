@@ -860,12 +860,16 @@ class ShardedTrainStep:
 
     def _ensure_built(self, arrays):
         if self._jitted is None:
-            from ..jit import _audit_instance_label, _maybe_audit, _obs
+            from ..jit import (_audit_instance_label, _maybe_audit, _obs,
+                               remat_fit)
 
             _obs()[1].inc(("sharded_train_step", "build"))
+            # a build reads the batch's ranks alone: hold no batch for it
+            like = [jax.ShapeDtypeStruct(a.shape, a.dtype) for a in arrays]
             self._jitted = _maybe_audit(
                 _audit_instance_label("ShardedTrainStep"),
-                self._build(arrays))
+                remat_fit.fitted(lambda: self._build(like),
+                                 "ShardedTrainStep"))
 
     def lower(self, *batch):
         """AOT-lower the plain sharded step for this batch's shapes without

@@ -121,13 +121,19 @@ define_flag("flash_bwd_block_q", 0,
 define_flag("flash_bwd_block_k", 0,
             "flash-attention BACKWARD k block size (0 = same as forward)")
 define_flag("remat_policy", "",
-            "recompute policy for scanned stacks: ''=full remat; every policy "
-            "keeps what a row-parallel layer all-reduced over mp and the "
-            "flash-attention kernel's o+lse (its backward's residuals: the "
-            "replayed layer never runs the forward kernel again; 2 x batch x "
-            "seq x hidden bytes + an lse a layer), 'dots'=save "
+            "recompute policy for scanned stacks. Every policy keeps what a "
+            "row-parallel layer all-reduced over mp and the flash-attention "
+            "kernel's o+lse (its backward's residuals: the replayed layer "
+            "never runs the forward kernel again; 2 x batch x seq x hidden "
+            "bytes + an lse a layer). ''=what fits: the layer's named "
+            "projection outputs (q/k/v, o, up, gate) as far as the compiled "
+            "step's memory_analysis() leaves room under the device's "
+            "bytes_limit (jit/remat_fit.py; chosen at the step's first call "
+            "and remembered beside the compile cache), nothing more where "
+            "the device states no limit (CPU); 'flash'=that minimum on "
+            "every device, no ladder; 'dots'=save "
             "non-batch matmul outputs, 'dots_all'=save all matmul outputs, "
-            "'flash'=the same policy as '' (kept accepted), 'moe'=also pin "
+            "'moe'=also pin "
             "the MoE capacity buffer/expert outputs/routing maps, "
             "'route'=pin only the MoE "
             "routing decisions (~1MB/layer); 'moe'/'route' names exist "

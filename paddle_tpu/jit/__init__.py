@@ -399,9 +399,12 @@ class TrainStep:
 
     def _ensure_built(self):
         if self._jitted is None:
+            from . import remat_fit
+
             _obs()[1].inc(("train_step", "build"))
             self._jitted = _maybe_audit(
-                _audit_instance_label("TrainStep"), self._build())
+                _audit_instance_label("TrainStep"),
+                remat_fit.fitted(self._build, "TrainStep"))
 
     def __call__(self, *batch):
         tl, _tc = _obs()

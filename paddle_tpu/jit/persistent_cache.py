@@ -393,6 +393,14 @@ class CachedJit:
 
     def _build(self, args, sig) -> Callable:
         lowered = in_one_stack_chunk(self._jitted.lower, *args)
+        return self.compile_lowered(lowered, sig)
+
+    def compile_lowered(self, lowered, sig) -> Callable:
+        """``lowered`` (this function's, for arguments of signature ``sig``)
+        as an executable: from the disk entry where the cache is enabled and
+        has one, else compiled (and written there)."""
+        if not _STATE.enabled:
+            return lowered.compile()
         key = self._key(lowered, sig)
         # serialize_broken gates WRITES only: one program that cannot
         # round-trip must not stop other programs' valid on-disk entries
@@ -441,6 +449,9 @@ class CachedJit:
 
     def lower(self, *args, **kwargs):
         return self._jitted.lower(*args, **kwargs)
+
+    def trace(self, *args, **kwargs):
+        return self._jitted.trace(*args, **kwargs)
 
 
 def cached_jit(fun: Callable, label: Optional[str] = None,

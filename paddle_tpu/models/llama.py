@@ -24,10 +24,14 @@ from ..core.tensor import Tensor
 from ..nn import functional as F
 from ..ops import creation, manipulation
 from ..distributed.meta_parallel.mp_layers import (
-    ColumnParallelLinear, RowParallelLinear, VocabParallelEmbedding, mark_sharding,
+    ColumnParallelLinear, RowParallelLinear, VocabParallelEmbedding,
+    _mp_degree, mark_sharding,
 )
 from ..distributed.mesh import get_mesh_env
-from ..distributed.meta_parallel.stage_stack import StackedStageRun
+from ..distributed.meta_parallel.stage_stack import (
+    ATTN_K, ATTN_O, ATTN_Q, ATTN_V, MLP_GATE, MLP_UP, StackedStageRun,
+    name_for_recompute,
+)
 
 
 @dataclass
@@ -166,6 +170,12 @@ class LlamaAttention(nn.Layer):
             new_cache = (k, v)
         else:
             new_cache = None
+            # what attention reads, named where it is whole: a recompute
+            # that keeps these (stage_stack.remat_wrap) replays neither the
+            # three projections nor RoPE
+            q = name_for_recompute(q, ATTN_Q)
+            k = name_for_recompute(k, ATTN_K)
+            v = name_for_recompute(v, ATTN_V)
         if self.num_kv_heads != self.num_heads:
             rep = self.num_heads // self.num_kv_heads
             k = manipulation.repeat_interleave(k, rep, axis=2)
@@ -187,7 +197,10 @@ class LlamaAttention(nn.Layer):
                                                  training=self.training)
         out = manipulation.reshape(out, [b, s, self.num_heads * self.head_dim])
         out = self.o_proj(out)
-        return (out, new_cache) if cache is not None else out
+        if cache is not None:
+            return out, new_cache
+        # where mp splits the rows, o_proj named its sum itself (MP_OUT)
+        return out if _mp_degree() > 1 else name_for_recompute(out, ATTN_O)
 
 
 class LlamaMLP(nn.Layer):
@@ -199,7 +212,9 @@ class LlamaMLP(nn.Layer):
         self.down_proj = RowParallelLinear(i, h, has_bias=False, input_is_parallel=True)
 
     def forward(self, x):
-        return self.down_proj(F.silu(self.gate_proj(x)) * self.up_proj(x))
+        act = F.silu(name_for_recompute(self.gate_proj(x), MLP_GATE))
+        return self.down_proj(
+            act * name_for_recompute(self.up_proj(x), MLP_UP))
 
 
 class LlamaDecoderLayer(nn.Layer):

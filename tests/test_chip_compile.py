@@ -167,6 +167,118 @@ def test_recomputed_scan_on_the_mesh_holds_one_flash_forward(topo,
     assert any("pt_flash_bwd_dkv" in c for c in _CUSTOM_CALL.findall(text))
 
 
+def _recomputed_llama_scan(rope, attend, keep, o_named=True):
+    """The loss of two scanned, recomputed Llama layers at cell 1's widths
+    (hidden 2048, 16 x 128 query / 8 x 128 K/V heads, MLP 8192) with the
+    projection outputs named as ``models/llama.py`` names them, and the
+    recompute keeping ``keep`` (``stage_stack.remat_wrap``)."""
+    from jax.ad_checkpoint import checkpoint_name as named
+
+    from paddle_tpu.distributed.meta_parallel import stage_stack as ss
+
+    def layer(x, w):
+        wq, wk, wv, wo, wg, wu, wd = w
+        q = named(rope(jnp.einsum("bsh,hnd->bsnd", x, wq)), ss.ATTN_Q)
+        k = named(rope(jnp.einsum("bsh,hnd->bsnd", x, wk)), ss.ATTN_K)
+        v = named(jnp.einsum("bsh,hnd->bsnd", x, wv), ss.ATTN_V)
+        o = attend(q, jnp.repeat(k, 2, axis=2), jnp.repeat(v, 2, axis=2))
+        o = jnp.einsum("bsnd,ndh->bsh", o, wo)
+        x = x + (named(o, ss.ATTN_O) if o_named else o)
+        gate = named(x @ wg, ss.MLP_GATE)
+        return x + (jax.nn.silu(gate) * named(x @ wu, ss.MLP_UP)) @ wd, None
+
+    def loss(x, *ws):
+        y, _ = jax.lax.scan(ss.remat_wrap(layer, keep), x, ws)
+        return jnp.sum(y.astype(jnp.float32))
+
+    shapes = [(4, 2048, 2048), (2, 2048, 16, 128), (2, 2048, 8, 128),
+              (2, 2048, 8, 128), (2, 16, 128, 2048), (2, 2048, 8192),
+              (2, 2048, 8192), (2, 8192, 2048)]
+    return jax.grad(loss, argnums=tuple(range(8))), shapes
+
+
+def _rung_programs(grad_of, labels, args, **jit_kwargs):
+    """{label: (pt_flash_fwd calls, pt_rope calls, program bytes)} of the
+    scan's gradient compiled at each rung."""
+    from paddle_tpu.jit import remat_fit
+
+    out = {}
+    for label in labels:
+        grad, _ = grad_of(dict(remat_fit.RUNGS)[label])
+        compiled = jax.jit(grad, **jit_kwargs).lower(*args).compile()
+        calls = _CUSTOM_CALL.findall(compiled.as_text())
+        out[label] = (len([c for c in calls if "pt_flash_fwd" in c]),
+                      len([c for c in calls if "pt_rope" in c]),
+                      remat_fit.program_bytes(compiled))
+    return out
+
+
+def test_recomputed_scan_keeps_the_projections_a_rung_names(one_chip,
+                                                            monkeypatch):
+    """The layer scan at cell 1's widths compiles for the described chip at
+    the lean, the q / k / v and the top rung (ISSUE 54): one ``pt_flash_fwd``
+    at each; ``pt_rope`` six times lean (q and k in the forward, in the
+    replay and in the backward) and four where q and k are kept; and a
+    ``memory_analysis()`` that grows with the rung."""
+    from paddle_tpu.kernels import flash_attention as fa
+    from paddle_tpu.kernels.pallas import rope as krope
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+
+    def grad_of(keep):
+        return _recomputed_llama_scan(
+            lambda t: krope.rope_apply(t, 1e6, 0, impl="pallas"),
+            lambda q, k, v: fa.flash_attention(q, k, v, causal=True), keep)
+
+    args = [jax.ShapeDtypeStruct(s, BF16, sharding=one_chip)
+            for s in grad_of(())[1]]
+    got = _rung_programs(grad_of, ("lean", "qkv", "qkvo_up_gate"), args)
+    assert [got[r][:2] for r in got] == [(1, 6), (1, 4), (1, 4)], got
+    assert got["lean"][2] < got["qkv"][2] < got["qkvo_up_gate"][2], got
+
+
+def test_recomputed_scan_on_the_mesh_keeps_the_projections(topo, monkeypatch):
+    """Cell 3's form of the same: on ``dp=2 x mp=2`` RoPE and flash each
+    ride their manual region inside the recomputed layer, o_proj's sum is
+    ``mp``'s to name, and the rungs are read there too: one ``pt_flash_fwd``
+    for the four described chips, fewer ``pt_rope`` calls with q and k kept,
+    more bytes a chip with the rung."""
+    import paddle_tpu.distributed as dist
+    from paddle_tpu.distributed.mesh import activation_spec
+    from paddle_tpu.kernels import flash_attention as fa
+    from paddle_tpu.models.llama import _rope
+    from paddle_tpu.nn.functional.attention import _sdpa
+    from jax.sharding import PartitionSpec as P
+
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+
+    def grad_of(keep):
+        return _recomputed_llama_scan(
+            lambda t: _rope.fn(t, theta=1e6, pos_offset=0, impl="pallas"),
+            lambda q, k, v: _sdpa.fn(q, k, v, causal=True, scale=128 ** -0.5,
+                                     impl="flash"), keep, o_named=False)
+
+    dist.reset_mesh()
+    env = dist.init_mesh(dp=2, mp=2, devices=list(topo.devices))
+    try:
+        shapes = grad_of(())[1]
+        shapes[0] = (8,) + shapes[0][1:]   # four rows a data replica
+        by_head, rows, cols = P(None, None, "mp", None), \
+            P(None, "mp", None, None), P(None, None, "mp")
+        held = [env.sharding_for(p) for p in (
+            activation_spec(shapes[0], "rows"), by_head, by_head, by_head,
+            rows, cols, cols, P(None, "mp", None))]
+        args = [jax.ShapeDtypeStruct(s, BF16, sharding=sh)
+                for s, sh in zip(shapes, held)]
+        got = _rung_programs(grad_of, ("lean", "qkv", "qkv_up_gate"), args,
+                             out_shardings=tuple(held))
+    finally:
+        dist.reset_mesh()
+    assert all(got[r][0] == 1 for r in got), got
+    assert got["lean"][1] > got["qkv"][1] == got["qkv_up_gate"][1], got
+    assert got["lean"][2] < got["qkv"][2] < got["qkv_up_gate"][2], got
+
+
 @pytest.mark.parametrize("residual", [False, True])
 def test_rmsnorm_fwd_bwd(one_chip, residual):
     from paddle_tpu.kernels.pallas import rmsnorm as krms

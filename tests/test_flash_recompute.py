@@ -153,9 +153,12 @@ def test_train_step_keeps_flash_residuals_bit_equal_to_plain_recompute(
     kept_losses, kept_params, step, x = _train()
     jaxpr = _traced(step, x)
     assert len(_kernels(jaxpr, "pt_flash_fwd")) == 1
-    assert _names(jaxpr) == ["flash_lse", "flash_o"]
+    # the layer's projection outputs carry names too (tests/test_remat_fit.py)
+    assert [n for n in _names(jaxpr) if n.startswith("flash_")] == \
+        ["flash_lse", "flash_o"]
 
-    monkeypatch.setattr(stage_stack, "remat_wrap", jax.checkpoint)
+    monkeypatch.setattr(stage_stack, "remat_wrap",
+                        lambda fn, keep=(): jax.checkpoint(fn))
     plain_losses, plain_params, step, x = _train()
     assert len(_kernels(_traced(step, x), "pt_flash_fwd")) == 2
     assert kept_losses == plain_losses
