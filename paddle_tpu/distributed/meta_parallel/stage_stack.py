@@ -26,6 +26,7 @@ from jax.ad_checkpoint import checkpoint_name
 from ...core.dispatch import primitive
 from ...core.tensor import Tensor
 from ...nn.layer.layers import Layer, Parameter
+from ...observability.trace.parts import step_part
 from ..mesh import get_mesh_env
 from .mp_layers import MP_OUT
 
@@ -217,10 +218,15 @@ class StackedStageRun(Layer):
                                   if isinstance(hidden, Tensor) else hidden)
             return Tensor(out) if not isinstance(out, Tensor) else out
         stacked = [self._parameters[safe] for safe, _ in self._names]
-        out, aux = _run_stack(hidden, *stacked, _run_id=id(self),
-                              use_recompute=self.recompute and self.training,
-                              microbatches=self.num_microbatches or 0,
-                              stream=_STREAM_MODE[0], keep=_KEEP[0])
+        # the run's own plumbing (the scan's carries, a kept value's write
+        # into its stack and its read back, the pipeline's handoffs) is
+        # ``stack``; a model op inside keeps its innermost part
+        with step_part("stack"):
+            out, aux = _run_stack(
+                hidden, *stacked, _run_id=id(self),
+                use_recompute=self.recompute and self.training,
+                microbatches=self.num_microbatches or 0,
+                stream=_STREAM_MODE[0], keep=_KEEP[0])
         from ...nn.layer import moe as moe_mod
 
         moe_mod.record_aux(aux)

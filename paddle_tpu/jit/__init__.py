@@ -26,6 +26,7 @@ from ..core.tensor import Tensor
 from ..core import autograd
 from ..framework import random as random_mod
 from ..nn.layer.layers import Layer
+from ..observability.trace.parts import step_part
 from . import persistent_cache
 
 
@@ -102,6 +103,7 @@ def make_param_updater(opt, train_params):
         1.0 if (opt._decay_param_fn is None or opt._decay_param_fn(p)) else 0.0
         for p in train_params)
 
+    @step_part("optimizer")
     def apply(params, grads, states, lr, step_no):
         new_p, new_s = [], []
         for p, g, s, flag in zip(params, grads, states, wd_flags):

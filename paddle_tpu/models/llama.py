@@ -32,6 +32,7 @@ from ..distributed.meta_parallel.stage_stack import (
     ATTN_K, ATTN_O, ATTN_Q, ATTN_V, MLP_GATE, MLP_UP, StackedStageRun,
     name_for_recompute,
 )
+from ..observability.trace.parts import part
 
 
 @dataclass
@@ -156,6 +157,7 @@ class LlamaAttention(nn.Layer):
         self.o_proj = RowParallelLinear(self.num_heads * self.head_dim, h,
                                         has_bias=False, input_is_parallel=True)
 
+    @part("attn_proj")
     def forward(self, hidden, cache=None):
         b, s = hidden.shape[0], hidden.shape[1]
         q = manipulation.reshape(self.q_proj(hidden), [b, s, self.num_heads, self.head_dim])
@@ -181,20 +183,21 @@ class LlamaAttention(nn.Layer):
             k = manipulation.repeat_interleave(k, rep, axis=2)
             v = manipulation.repeat_interleave(v, rep, axis=2)
         env = get_mesh_env()
-        if cache is None and env is not None and env.get_dim("cp") > 1:
-            # context parallel over the cp axis: K/V ring (default) or
-            # Ulysses a2a head sharding, per config.cp_impl
-            if getattr(self.config, "cp_impl", "ring") == "ulysses":
-                from ..distributed.context_parallel import ulysses_attention
+        with part("attention"):
+            if cache is None and env is not None and env.get_dim("cp") > 1:
+                # context parallel over the cp axis: K/V ring (default) or
+                # Ulysses a2a head sharding, per config.cp_impl
+                if getattr(self.config, "cp_impl", "ring") == "ulysses":
+                    from ..distributed.context_parallel import ulysses_attention
 
-                out = ulysses_attention(q, k, v, causal=True)
+                    out = ulysses_attention(q, k, v, causal=True)
+                else:
+                    from ..distributed.context_parallel import ring_attention
+
+                    out = ring_attention(q, k, v, causal=True)
             else:
-                from ..distributed.context_parallel import ring_attention
-
-                out = ring_attention(q, k, v, causal=True)
-        else:
-            out = F.scaled_dot_product_attention(q, k, v, is_causal=cache is None,
-                                                 training=self.training)
+                out = F.scaled_dot_product_attention(
+                    q, k, v, is_causal=cache is None, training=self.training)
         out = manipulation.reshape(out, [b, s, self.num_heads * self.head_dim])
         out = self.o_proj(out)
         if cache is not None:
@@ -239,11 +242,17 @@ class LlamaDecoderLayer(nn.Layer):
         # kernel runs, the attn output, the residual stream and the norm's
         # read/write collapse into one HBM pass (kernels/pallas/rmsnorm.py);
         # the first norm of the layer has no preceding add
-        attn_out = self.self_attn(self.input_layernorm(hidden))
-        mlp_in, hidden = F.rms_norm_residual(
-            attn_out, hidden, self.post_attention_layernorm.weight,
-            self.post_attention_layernorm._epsilon)
-        hidden = hidden + self.mlp(mlp_in)
+        with part("norm"):
+            normed = self.input_layernorm(hidden)
+        attn_out = self.self_attn(normed)
+        with part("norm"):
+            mlp_in, hidden = F.rms_norm_residual(
+                attn_out, hidden, self.post_attention_layernorm.weight,
+                self.post_attention_layernorm._epsilon)
+        # the MLP and the residual add behind it; an expert layer names its
+        # own router / experts inside
+        with part("mlp"):
+            hidden = hidden + self.mlp(mlp_in)
         return _mark_seq(hidden)
 
 
@@ -280,7 +289,8 @@ class LlamaModel(nn.Layer):
         self.norm = nn.RMSNorm(config.hidden_size, config.rms_norm_eps)
 
     def forward(self, input_ids):
-        hidden = self.embed_tokens(input_ids)
+        with part("embed"):
+            hidden = self.embed_tokens(input_ids)
         hidden = _mark_seq(hidden)
         if self.config.scan_layers:
             hidden = self.layers(hidden)
@@ -292,7 +302,8 @@ class LlamaModel(nn.Layer):
                     hidden = recompute(layer, hidden)
                 else:
                     hidden = layer(hidden)
-        return self.norm(hidden)
+        with part("norm"):
+            return self.norm(hidden)
 
 
 def _data_degree() -> int:
@@ -369,22 +380,27 @@ class LlamaForCausalLM(nn.Layer):
             hidden = self.llama(input_ids)
         aux = moe_mod.drain_aux(bucket)
         if labels is not None:
-            # fused chunked lm_head+CE: full logits never hit HBM
-            h = hidden[:, :-1, :]
-            lab = labels[:, 1:]
-            h2 = manipulation.reshape(h, [-1, self.config.hidden_size])
-            lab1 = manipulation.reshape(lab, [-1])
-            loss = _fused_linear_ce(h2, self.lm_head.weight, lab1,
-                                    chunk=getattr(self.config, "ce_chunk",
-                                                  2048),
-                                    ignore_index=-100, groups=_data_degree())
-            if aux is not None:
-                loss = loss + getattr(self.config, "aux_loss_weight", 0.0) * aux
-            return loss
+            return self._linear_ce(hidden, labels, aux)
         if aux is not None:
             moe_mod.record_aux(aux)  # re-raise for an outer collector
-        return self.lm_head(hidden)
+        with part("head"):
+            return self.lm_head(hidden)
 
+    @part("head")
+    def _linear_ce(self, hidden, labels, aux):
+        # fused chunked lm_head+CE: full logits never hit HBM
+        h = hidden[:, :-1, :]
+        lab = labels[:, 1:]
+        h2 = manipulation.reshape(h, [-1, self.config.hidden_size])
+        lab1 = manipulation.reshape(lab, [-1])
+        loss = _fused_linear_ce(h2, self.lm_head.weight, lab1,
+                                chunk=getattr(self.config, "ce_chunk", 2048),
+                                ignore_index=-100, groups=_data_degree())
+        if aux is not None:
+            loss = loss + getattr(self.config, "aux_loss_weight", 0.0) * aux
+        return loss
+
+    @part("head")
     def loss_from_logits(self, logits, labels):
         v = self.config.vocab_size
         shift_logits = logits[:, :-1, :]

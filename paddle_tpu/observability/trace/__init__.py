@@ -7,12 +7,14 @@ Layers on top of the PR-4 telemetry hub (see docs/observability.md,
   ``jax.profiler`` trace around a step window, read its ``.xplane.pb``,
   correlate device events back to StepTimeline steps/phases — real
   ``device_compute_us`` (every mode), a top-k device op table, and
-  host/device overlap efficiency, and device time by part of a served
-  model step (``by_part``; ``tools/program_parts.py`` on a file);
+  host/device overlap efficiency, and device time by part of a model step
+  — a served window program's or a train step's, the latter by phase too
+  (``by_part``; ``tools/program_parts.py`` on a file);
 - **parts** (``part``, ``PARTS``): the one vocabulary of
-  ``jax.named_scope`` names (``pt.norm``, ``pt.attn_proj``, ...) the engine
-  and the served blocks put on their work, so that a device trace says
-  which part of the model asked for each op;
+  ``jax.named_scope`` names (``pt.norm``, ``pt.attn_proj``, ...) the engine,
+  the served blocks and ``models/llama.py``'s layers put on their work, so
+  that a device trace says which part of the model asked for each op —
+  and, of a train step, which pass ran it (``parts.phase_of``);
 - **spans** (``span``): the program's one span primitive — a
   ``jax.profiler.TraceAnnotation`` in whatever profiler trace is running,
   and a row in the tracer's worker ring (``pt.serve.*``, ``pt.train.*``);

@@ -27,10 +27,11 @@ interval less what its children cover), and splits each step's device time
 into *exposed* (overlapping a ``device_block``/``stream_wait``/``data_wait``
 host span — the host was waiting for it) vs *hidden* (overlapped by useful
 host work) — the device-truth ``overlap_efficiency``. And it splits the
-device's self time BY PART of a served model step (``by_part``): the
-programs say which part of the model asked for each op
-(``observability.trace.parts``), so the table says where a window program's
-milliseconds go in the model's own words, program by program.
+device's self time BY PART of a model step (``by_part``): the programs say
+which part of the model asked for each op (``observability.trace.parts``),
+so the table says where a program's milliseconds go in the model's own
+words, program by program — and, for a train step, BY PHASE beside it
+(forward, recompute, backward: ``parts.phase_of`` of the same name stack).
 """
 from __future__ import annotations
 
@@ -41,7 +42,7 @@ import re
 from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 from . import xspace
-from .parts import part_of
+from .parts import part_of, phase_of
 
 __all__ = ["find_xplane", "read_xplane", "correlate", "correlate_logdir",
            "CorrelatedTrace", "TraceEvent"]
@@ -117,15 +118,22 @@ def _op_and_shape(name: str) -> Tuple[str, str]:
     return head.lstrip("%"), shape
 
 
-def _own_part(stats: Dict[str, Any]) -> Optional[str]:
-    """The part an instruction's own name stack gives it. A fusion that XLA
-    made of ops of several name stacks lists them all (``a;b``): the first
-    that names a part says it."""
-    for stack in str(stats.get("tf_op", "")).split(";"):
-        part = part_of(stack.rstrip(":"))
+NO_PHASE = "none"
+
+
+def _own_part(stats: Dict[str, Any]
+              ) -> Tuple[Optional[str], Optional[str]]:
+    """The part and the phase an instruction's own name stack gives it. A
+    fusion that XLA made of ops of several name stacks lists them all
+    (``a;b``): the first that names a part says both. An op with a name but
+    no part is ``unscoped`` in the phase its first stack says; one with no
+    name at all is ``(None, None)``."""
+    stacks = [s.rstrip(":") for s in str(stats.get("tf_op", "")).split(";")]
+    for stack in stacks:
+        part = part_of(stack)
         if part:
-            return part
-    return None
+            return part, phase_of(stack)
+    return (UNSCOPED, phase_of(stacks[0])) if stacks[0] else (None, None)
 
 
 def by_part(ops: Sequence[TraceEvent], own_us: Sequence[float],
@@ -141,7 +149,17 @@ def by_part(ops: Sequence[TraceEvent], own_us: Sequence[float],
     such an op where its consumer needs it — else with the one before it;
     an op of a program that names no part at all is ``unscoped``. A run is
     an ``XLA Modules`` event; without that line (an old trace) all the ops
-    of one ``program_id`` count as one run."""
+    of one ``program_id`` count as one run.
+
+    A program in which some op has a phase (a train step) gets one more key
+    a row, ``phases``: ``{part: {phase: us}}`` with the phases of
+    ``parts.PHASES`` and ``"none"`` (the optimizer, anything outside the
+    gradient); an op with no name takes the phase with the part, and an op
+    that HAS a name stack with no part in it is ``unscoped`` there, in the
+    phase its stack says (a train step has such ops — what the step does
+    around the model and the optimizer — and hiding them in a neighbour's
+    part would hide a hole in the vocabulary). A served program's rows are
+    as they were."""
     runs = sorted((m.ts, m.ts + m.dur, m.name) for m in modules)
     starts = [r[0] for r in runs]
 
@@ -159,23 +177,33 @@ def by_part(ops: Sequence[TraceEvent], own_us: Sequence[float],
         name = re.sub(r"\(\d+\)$", "", runs[key][2]) \
             if isinstance(key, int) else key
         parts = [_own_part(ops[i].stats) for i in idx]
+        phased = any(ph for _p, ph in parts)
+        if not phased:  # a served program: a name with no part inherits
+            parts = [(None if p == UNSCOPED else p, None)
+                     for p, _ph in parts]
         nxt = None
         for k in range(len(idx) - 1, -1, -1):       # the next that has one
-            nxt = parts[k] or nxt
+            nxt = parts[k] if parts[k][0] else nxt
             parts[k] = nxt
         prev = None
         for k in range(len(idx)):                   # else the one before
             prev = parts[k] or prev
-            parts[k] = prev or UNSCOPED
+            parts[k] = prev or (UNSCOPED, None)
         row = progs.setdefault(name, {"calls": 0, "device_us": 0.0,
                                       "parts": {}, "ops": {}})
         row["calls"] += 1
-        for i, part in zip(idx, parts):
+        for i, (part, phase) in zip(idx, parts):
             us = own_us[i]
             row["device_us"] += us
             row["parts"][part] = row["parts"].get(part, 0.0) + us
+            op_key = _op_and_shape(ops[i].name)
+            if phased:
+                phase = phase or NO_PHASE
+                cell = row.setdefault("phases", {}).setdefault(part, {})
+                cell[phase] = cell.get(phase, 0.0) + us
+                op_key += (phase,)
             cell = row["ops"].setdefault(part, {}).setdefault(
-                _op_and_shape(ops[i].name), [0, 0.0])
+                op_key, [0, 0.0])
             cell[0] += 1
             cell[1] += us
     total: Dict[str, float] = {}
@@ -183,13 +211,17 @@ def by_part(ops: Sequence[TraceEvent], own_us: Sequence[float],
         for part, us in row["parts"].items():
             total[part] = total.get(part, 0.0) + us
         row["top_ops"] = {
-            part: [{"op": op, "shape": shape, "calls": c,
-                    "us": round(us, 1)} for (op, shape), (c, us) in
+            part: [dict(zip(("op", "shape", "phase"), key), calls=c,
+                        us=round(us, 1)) for key, (c, us) in
                    sorted(table.items(), key=lambda kv: -kv[1][1])[:top]]
             for part, table in row.pop("ops").items()}
         row["device_us"] = round(row["device_us"], 1)
         row["parts"] = {k: round(v, 1) for k, v in sorted(
             row["parts"].items(), key=lambda kv: -kv[1])}
+        if "phases" in row:
+            row["phases"] = {part: {ph: round(us, 1)
+                                    for ph, us in by.items()}
+                             for part, by in row["phases"].items()}
     return {"parts": {k: round(v, 1) for k, v in sorted(
                 total.items(), key=lambda kv: -kv[1])},
             "device_us": round(sum(total.values()), 1),
@@ -229,8 +261,8 @@ def _self_us(events: Sequence[TraceEvent]) -> List[float]:
 def _add_parts(total: Optional[Dict[str, Any]], one: Dict[str, Any]
                ) -> Dict[str, Any]:
     """``by_part`` of one more device added to the others' (several chips
-    run the same programs: times and calls add, a program's widest ops are
-    the first device's)."""
+    run the same programs: times and calls add, a program's widest ops
+    too, instruction by instruction)."""
     if total is None:
         return one
     for part, us in one["parts"].items():
@@ -244,6 +276,22 @@ def _add_parts(total: Optional[Dict[str, Any]], one: Dict[str, Any]
             for part, us in row["parts"].items():
                 have["parts"][part] = round(
                     have["parts"].get(part, 0.0) + us, 1)
+            for part, by in row.get("phases", {}).items():
+                cell = have.setdefault("phases", {}).setdefault(part, {})
+                for ph, us in by.items():
+                    cell[ph] = round(cell.get(ph, 0.0) + us, 1)
+            for part, ops in row["top_ops"].items():
+                mine = have["top_ops"].setdefault(part, [])
+                seen = {(o["op"], o["shape"], o.get("phase")): o
+                        for o in mine}
+                for op in ops:
+                    o = seen.get((op["op"], op["shape"], op.get("phase")))
+                    if o is None:
+                        mine.append(dict(op))
+                    else:
+                        o["calls"] += op["calls"]
+                        o["us"] = round(o["us"] + op["us"], 1)
+                mine.sort(key=lambda o: -o["us"])
     return total
 
 
@@ -260,8 +308,9 @@ class CorrelatedTrace:
         self.unattributed_device_us = unattributed_device_us
         self.device_threads = device_threads
         self.source = source
-        # device self time by part of a served model step and by program
-        # (``by_part``); None for a trace with no device ops line
+        # device self time by part of a model step and by program — by
+        # phase too for a train step (``by_part``); None for a trace with no
+        # device ops line
         self.by_part = by_part
 
     @property
@@ -299,7 +348,8 @@ class CorrelatedTrace:
                 "parts": self.by_part["parts"],
                 "device_us": self.by_part["device_us"],
                 "programs": {name: {k: row[k] for k in
-                                    ("calls", "device_us", "parts")}
+                                    ("calls", "device_us", "parts", "phases")
+                                    if k in row}
                              for name, row in
                              self.by_part["programs"].items()}},
             "steps": [
@@ -314,7 +364,7 @@ def correlate(events: Sequence[TraceEvent], source: Optional[str] = None,
               top_ops: int = 5) -> CorrelatedTrace:
     """Correlate one trace's events (``read_xplane``): device events ->
     ``pt.train.step`` / ``pt.train.<phase>`` windows by time, and device
-    self time by part of a served step (``by_part``, its ``top_ops`` widest
+    self time by part of a model step (``by_part``, its ``top_ops`` widest
     ops a part)."""
     steps: List[Dict] = []
     phase_spans: List[Tuple[str, float, float]] = []  # (name, t0, t1)

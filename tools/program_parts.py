@@ -1,5 +1,6 @@
-"""Where a window program's device time goes, by the part of the model that
-asked for it (the twin of ``tools/warmup_spans.py``, for the device side):
+"""Where a program's device time goes — a served window program's or a train
+step's — by the part of the model that asked for it (the twin of
+``tools/warmup_spans.py``, for the device side):
 
     python3 tools/program_parts.py <trace dir or .xplane.pb> [--top N] [--json]
 
@@ -12,8 +13,13 @@ window program): its calls in the trace, device ms a call, ms a call and
 share by part (``observability.trace.parts``: the innermost ``pt.<part>`` of
 an op's name stack; an op the compiler put in with no name goes with the
 next op of its run that has one), and the widest ops of each part by short
-name and shape. ``--json`` prints ``by_part`` as it is. Needs no chip: a
-trace is a file.
+name and shape. A train step (``jit_step``: ``models/llama.py`` under
+``jit.TrainStep`` / ``ShardedTrainStep``) prints part x PHASE besides —
+forward, recompute, backward (``parts.phase_of``: the ``jvp(`` /
+``transpose(`` / ``rematted_computation`` of the same name stack) and
+``none`` for what lies outside the gradient, the optimizer first — in ms a
+call, summed over the devices, and each wide op's phase. ``--json`` prints
+``by_part`` as it is. Needs no chip: a trace is a file.
 """
 import argparse
 import json
@@ -35,6 +41,9 @@ def find(path: str) -> str:
 
 
 def render(by_part, top: int = 5) -> str:
+    from paddle_tpu.observability.trace import parts, xplane
+
+    columns = parts.PHASES + (xplane.NO_PHASE,)
     lines = []
     total = by_part["device_us"] or 1.0
     lines.append("all programs: %.1f ms of device time; " % (total / 1e3)
@@ -46,13 +55,25 @@ def render(by_part, top: int = 5) -> str:
         lines.append("")
         lines.append(f"{name}: {calls} calls, {dev / calls / 1e3:.3f} ms a "
                      f"call, {100 * dev / total:.1f} % of the device time")
+        phases = row.get("phases")
+        if phases:      # a train step: part x phase, ms a call
+            table = [(part, phases[part]) for part in row["parts"]]
+            table.append(("all", {ph: sum(by.get(ph, 0.0) for _p, by in table)
+                                  for ph in columns}))
+            lines.append("  %-12s %s %10s" % ("ms a call", " ".join(
+                f"{ph:>10}" for ph in columns), "all"))
+            for part, by in table:
+                lines.append("  %-12s %s %10.3f" % (part, " ".join(
+                    f"{by.get(ph, 0.0) / calls / 1e3:10.3f}"
+                    for ph in columns), sum(by.values()) / calls / 1e3))
         for part, us in row["parts"].items():
             lines.append(f"  {part:<12} {us / calls / 1e3:8.3f} ms a call "
                          f"{100 * us / dev:5.1f} %")
             for op in row["top_ops"].get(part, [])[:top]:
                 lines.append(
                     f"      {op['us'] / calls / 1e3:8.3f} ms  "
-                    f"x{op['calls'] / calls:<4g} {op['op']}  {op['shape']}")
+                    f"x{op['calls'] / calls:<4g} {op['op']}  {op['shape']}"
+                    + (f"  [{op['phase']}]" if "phase" in op else ""))
     return "\n".join(lines)
 
 
