@@ -68,12 +68,23 @@ def _axes_if_divisible(env, names, n):
 
 
 def activation_spec(shape, layout: str) -> PartitionSpec:
-    """How an activation a kernel consumes is laid out on the live mesh:
+    """How an activation lies on the live mesh — what a kernel call hands
+    ``run_kernel_on_mesh`` and what a model anchors its residual stream to
+    (``models/llama.py:_mark_seq``): ONE source, so the two agree.
     ``"bshd"`` = [batch, seq, heads, head_dim] (batch over dp/sdp, heads
-    over mp); ``"rows"`` = [batch, (seq,) ..., hidden] (batch over dp/sdp,
-    seq over cp). A dim the degree does not divide is left unsplit. None
-    when there is no multi-device mesh (``run_kernel_on_mesh`` then needs
-    no spec)."""
+    over mp, the sequence whole: what attention and RoPE see).
+    ``"rows"`` = [batch, (seq,) ..., hidden], the stream BETWEEN sublayers:
+    batch over dp/sdp, seq over cp AND mp. With the sequence over ``mp`` a
+    row-parallel layer's partial sums leave as a reduce-scatter and the next
+    column-parallel layer gathers its input (Megatron's sequence-parallel
+    form: the same bytes as the all-reduce, but the gather half is a
+    collective this chip's compiler runs under matmuls, and the norms and
+    residual adds between them touch 1/mp of the rows). A dim the degree
+    does not divide is left unsplit (a sequence cp x mp does not divide
+    keeps cp alone if that divides). ``"gathered"`` = the same stream where
+    the vocabulary-parallel embedding hands it over: seq over cp alone, so
+    the embedding's sum lands whole and the slice to ``"rows"`` behind it is
+    local. None when there is no multi-device mesh (``run_kernel_on_mesh`` then needs no spec)."""
     env = kernel_mesh()
     if env is None:
         return None
@@ -83,7 +94,9 @@ def activation_spec(shape, layout: str) -> PartitionSpec:
                              _axes_if_divisible(env, ("mp",), shape[2]), None)
     rest = [None] * (len(shape) - 1)
     if len(shape) >= 3:
-        rest[0] = _axes_if_divisible(env, ("cp",), shape[1])
+        rest[0] = (layout == "rows" and _axes_if_divisible(
+            env, ("cp", "mp"), shape[1])) or _axes_if_divisible(
+            env, ("cp",), shape[1])
     return PartitionSpec(data, *rest)
 
 
@@ -225,29 +238,66 @@ def _device_groups(written: str, n: int) -> np.ndarray:
     return ids.reshape(ints(shape))
 
 
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%([\w.\-]+) \(.*\) -> (.*) \{$")
+_CALLER = re.compile(r"^\s*(?:ROOT )?%([\w.\-]+) = .*\bcalls=%([\w.\-]+)")
+
+
 def compiled_collectives(text: str, mesh: Mesh) -> List[dict]:
     """The collectives of one compiled SPMD program, read from its text
     (``lowered.compile().as_text()``): rows ``{"axes", "op", "shapes",
-    "count"}`` — the mesh axes a group of the instruction spans, its opcode,
-    its result shapes as written (``"f32[4,32,64]"``; several where XLA
-    combined operands) and how many such instructions the program holds. An
-    instruction inside a loop body counts once, whatever the trip count.
-    Partition ``i`` is ``mesh.devices.flat[i]``, jit's device assignment."""
-    counts = collections.Counter()
+    "count", "async"}`` — the mesh axes a group of the instruction spans,
+    its opcode, its result shapes as written (``"f32[4,32,64]"``; several
+    where XLA combined operands), how many such instructions the program
+    holds, and how many of those are asynchronous start / done PAIRS (other
+    work can be scheduled between the two; the rest hold the device until
+    they end). An instruction inside a loop body counts once, whatever the
+    trip count. Partition ``i`` is ``mesh.devices.flat[i]``, jit's device
+    assignment.
+
+    The TPU compiler writes two forms no opcode names, and both are read
+    for what they are. A reduce-scatter is a ``fusion`` calling a
+    computation ``%all-reduce-scatter*`` (an all-reduce and the slice of
+    it): one synchronous ``reduce-scatter`` of the computation's result. An
+    asynchronous collective is a fusion NAMED ``async-collective-start``
+    whose computation holds the collective; the fusions that carry it on
+    under other work (``%async_collective_fusion*``) and the one named
+    ``async-collective-done`` repeat the instruction and are not counted
+    again. The CPU backend writes neither (a reduce-scatter stays an
+    all-reduce and a ``dynamic-slice``; nothing is asynchronous), so there
+    the rows say the kinds alone."""
+    caller, found, comp, result = {}, [], "", ""
     for line in text.splitlines():
-        m = _COLLECTIVE.search(line)
-        if m is None:
+        head = _COMPUTATION.match(line)
+        if head is not None:
+            comp, result = head.groups()
             continue
+        called = _CALLER.match(line)
+        if called is not None:
+            caller[called.group(2)] = called.group(1)
+        m = _COLLECTIVE.search(line)
+        if m is not None:
+            found.append((comp, result, line, m))
+    counts, pairs = collections.Counter(), collections.Counter()
+    for comp, result, line, m in found:
+        by = caller.get(comp, "")
+        if by.startswith("async-collective-done") or \
+                comp.startswith("async_collective_fusion"):
+            continue  # a pair's later steps: its start was counted
         op, shapes = m.group(2), tuple(_SHAPE.findall(m.group(1)))
         if m.group(3) and op in ("all-gather", "collective-permute"):
             shapes = shapes[len(shapes) // 2:]  # a start's type: (operands, results)
+        if comp.startswith("all-reduce-scatter"):
+            op, shapes = "reduce-scatter", tuple(_SHAPE.findall(result))
         g = _GROUPS.search(line)
         groups = _device_groups(g.group(1) if g else "{}", mesh.devices.size)
         coords = np.stack(np.unravel_index(groups, mesh.devices.shape), -1)
         spans = (coords != coords[:, :1]).any(axis=(0, 1))
         axes = tuple(ax for ax, on in zip(mesh.axis_names, spans) if on)
         counts[axes, op, shapes] += 1
-    return [{"axes": a, "op": o, "shapes": s, "count": c}
+        pairs[axes, op, shapes] += bool(
+            m.group(3) or by.startswith("async-collective-start"))
+    return [{"axes": a, "op": o, "shapes": s, "count": c,
+             "async": pairs[a, o, s]}
             for (a, o, s), c in sorted(counts.items())]
 
 

@@ -8,9 +8,20 @@ c_embedding collective ops.
 TPU-native: the layers hold GSPMD shard specs instead of doing explicit
 communication. Weight math is ordinary matmul/gather; placement annotations
 (`dist_spec` on parameters + with_sharding_constraint on activations) make XLA
-insert the same all-reduce/all-gather pattern Megatron does — over ICI, fused
-into the surrounding compute where profitable. The classes keep the reference's
-constructor surface so model code ports unchanged.
+insert the collectives Megatron does — over ICI, fused into the surrounding
+compute where profitable. The classes keep the reference's constructor
+surface so model code ports unchanged.
+
+How activations lie on an ``mp`` mesh: a layer here constrains only its own
+FEATURE dim; the model anchors the leading dims (``models/llama.py:
+_mark_seq`` -> ``mesh.activation_spec(shape, "rows")``). Between sublayers
+that anchor puts the SEQUENCE over ``mp`` (where ``mp`` divides it), so a
+row-parallel layer's partial sums leave as a reduce-scatter over the sequence
+and the next column-parallel layer all-gathers its input — Megatron's
+sequence-parallel form: the wire carries what the all-reduce carried, but the
+gather half is a collective this chip's compiler runs under matmuls, and the
+norms and residual adds in between touch 1/mp of the rows. Where ``mp`` does
+not divide the sequence the stream stays whole and the sum is an all-reduce.
 """
 from __future__ import annotations
 
@@ -40,7 +51,9 @@ def mark_sharding(x: Tensor, *spec, name=None) -> Tensor:
     return _shard_constraint(x, spec=tuple(spec), _env_id=id(env), name=name)
 
 
-# what a row-parallel layer's all-reduce over mp produced: the layer scan's
+# what a row-parallel layer's sum over mp produced — the scattered shard
+# where the model anchors the stream sequence-sharded over mp (1/mp of the
+# all-reduced value's bytes), the whole value otherwise: the layer scan's
 # recompute keeps it under every policy (stage_stack.remat_wrap)
 MP_OUT = "mp_out"
 
@@ -183,9 +196,11 @@ class RowParallelLinear(nn.Layer):
         if self.input_is_parallel:
             x = _mark_feature(x, "mp")
         out = F.linear(x, self.weight, None)
-        # partial sums reduce here (XLA inserts the all-reduce / reduce-scatter).
-        # Where mp really splits the rows the sum crossed the wire: it is
-        # named, so a recompute keeps it instead of reducing again
+        # partial sums reduce here: the feature dim whole, the leading dims
+        # left to the model's anchor behind — sequence over mp there, so XLA
+        # inserts a reduce-scatter (an all-reduce where the stream stays
+        # whole). Where mp really splits the rows the sum crossed the wire:
+        # it is named, so a recompute keeps it instead of reducing again
         out = _mark_feature(out, None,
                             name=MP_OUT if _mp_degree() > 1 else None)
         if self.bias is not None:

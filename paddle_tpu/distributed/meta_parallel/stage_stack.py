@@ -102,9 +102,10 @@ def name_for_recompute(t: Tensor, name: str) -> Tensor:
 def remat_wrap(fn, keep=()):
     """jax.checkpoint with the policy chosen by FLAGS_remat_policy. Every
     policy keeps two things by name. What crossed ``mp`` (a row-parallel
-    layer's all-reduced output, ``mp_layers.MP_OUT``: 2 x batch x seq x
-    hidden bytes a layer; off a mesh with ``mp`` nothing carries the name and
-    nothing is kept). And what the flash-attention forward kernel produced
+    layer's summed output, ``mp_layers.MP_OUT``: 2 x batch x seq x hidden
+    bytes a layer, ÷ ``mp`` where the stream lies sequence-sharded and the
+    sum is a reduce-scatter; off a mesh with ``mp`` nothing carries the name
+    and nothing is kept). And what the flash-attention forward kernel produced
     (``flash_o`` / ``flash_lse``, the residuals its backward reads, named in
     ``kernels/flash_attention.py`` by the dense entry: 2 x batch x seq x
     hidden bytes + 4 x batch x heads x seq a layer; where attention takes
@@ -159,6 +160,50 @@ def layer_signature(layer: Layer):
     return (type(layer).__qualname__, params)
 
 
+def _row_groups(carry):
+    """The scan body's rows as TWO independent groups, or None for one.
+
+    Where ``mp`` splits the stream's sequence (``mesh.activation_spec``'s
+    ``"rows"``: ``mp`` > 1 divides it) every sublayer boundary is a
+    collective over ``mp`` — a reduce-scatter behind each row-parallel
+    matmul, an all-gather in front of each column-parallel one — and a
+    decoder layer is one serial chain, so each of them would hold the chip
+    with nothing to run beside it. Two halves of the rows are two chains:
+    the scheduler puts one half's all-gather under the other's matmuls
+    (this chip's compiler makes an all-gather asynchronous, never an
+    all-reduce: PERF.md section 7). Taken where a data replica (``dp`` x
+    ``sdp``) holds an even number of rows; each half keeps rows of EVERY
+    replica (``[D, 2, b/2D, ...]``, index the second dim), so no row
+    changes device. Not under ``pp`` (a stage already walks microbatches and
+    its kernels are the jnp references), nor for another rank than
+    [batch, seq, hidden]. Off such a mesh nothing of this traces.
+
+    The same mathematics but one rounding: a weight's gradient becomes
+    ``dW(half 0) + dW(half 1)``, one more add in the parameter's dtype than
+    one contraction over all rows — the order every gradient-accumulating
+    step already has."""
+    from .pipeline import _batch_shard_degree
+
+    env = get_mesh_env()
+    if env is None or carry.ndim != 3:
+        return None
+    mp, d = env.get_dim("mp"), _batch_shard_degree(env)
+    b, s = carry.shape[:2]
+    if mp == 1 or env.get_dim("pp") > 1 or s % mp or b % (2 * d):
+        return None
+    rows = carry.reshape(d, 2, b // (2 * d), *carry.shape[1:])
+    return [rows[:, i].reshape(b // 2, *carry.shape[1:]) for i in range(2)]
+
+
+def _join_rows(halves, shape):
+    """``_row_groups``'s inverse: the two halves back in the rows' order."""
+    from .pipeline import _batch_shard_degree
+
+    d = _batch_shard_degree(get_mesh_env())
+    return jnp.stack([h.reshape(d, -1, *shape[1:]) for h in halves],
+                     axis=1).reshape(shape)
+
+
 class StackedStageRun(Layer):
     """A run of structurally identical layers executed as a stacked scan —
     pipelined over 'pp' when the mesh has that axis, plain lax.scan otherwise.
@@ -166,6 +211,14 @@ class StackedStageRun(Layer):
     Takes ALREADY-BUILT layers (each independently initialized so the stacked
     init matches building them separately); keeps layers[0] as the traced
     template and re-registers the stacked arrays as this Layer's Parameters.
+
+    The scan's body runs the template once on the carry — or, where ``mp``
+    splits the stream's sequence and a data replica holds an even number of
+    rows, once on each HALF of every replica's rows (``_row_groups``): two
+    independent chains in one body, so one half's all-gather over ``mp``
+    runs under the other half's matmuls. Chosen from the mesh and the
+    carry's shape alone; a layer that reports an auxiliary loss (a router:
+    it couples its rows) sees them whole.
     """
 
     def __init__(self, layers: List[Layer], num_microbatches: Optional[int] = None,
@@ -243,13 +296,24 @@ def _run_stack_fn(hidden, *stacked, _run_id, use_recompute, microbatches,
     template = run._template[0]
     tparams = [dict(template.named_parameters())[orig] for _, orig in run._names]
 
+    def run_template(rows):
+        with moe_mod.collect_aux() as bucket, autograd.no_grad():
+            return template(Tensor(rows)).data, bucket
+
     def body(carry, slices):
         saved = [p.data for p in tparams]
         try:
             for p, s in zip(tparams, slices):
                 p.data = s
-            with moe_mod.collect_aux() as bucket, autograd.no_grad():
-                out = template(Tensor(carry)).data
+            groups = _row_groups(carry)
+            out, bucket = run_template(groups[0] if groups else carry)
+            if groups and bucket:
+                # the layer reported an auxiliary loss (a router's): it
+                # couples its rows, so it sees them whole
+                out, bucket = run_template(carry)
+            elif groups:
+                out = _join_rows([out, run_template(groups[1])[0]],
+                                 carry.shape)
         finally:
             for p, a in zip(tparams, saved):
                 p.data = a

@@ -886,10 +886,14 @@ class ShardedTrainStep:
             *step_args(self, arrays, jax.random.key(0)))
 
     def collectives(self, *batch):
-        """Does ``dp`` divide my step? The compiled step's collectives for
-        this batch's shapes, by mesh axis, opcode and shape with their
-        count (``mesh.compiled_collectives``). It compiles; nothing runs,
-        and the step's own path never calls it."""
+        """Does ``dp`` divide my step, and which of its collectives can
+        hide? The compiled step's collectives for this batch's shapes, by
+        mesh axis, kind and shape with their ``count`` and how many of
+        them are ``async`` start / done pairs — the rest are synchronous
+        (``mesh.compiled_collectives``: a TPU reduce-scatter fusion reads
+        as ``reduce-scatter``; on the CPU the kinds alone, ``async`` 0).
+        It compiles; nothing runs, and the step's own path never calls
+        it."""
         from .mesh import compiled_collectives
 
         return compiled_collectives(

@@ -136,10 +136,14 @@ def named_bytes(jaxpr, devices: int = 1) -> Dict[str, int]:
     the lengths of the scans around them, over the ``devices`` the step
     spans (a mesh's axes each split the activations; where one does not, a
     kept byte measures dearer and the price says so). A name the forward
-    alone holds (no recompute, or kept already) is not a candidate."""
+    alone holds (no recompute, or kept already) is not a candidate. Values
+    that share a name in ONE body (a stack that walks its rows as two
+    halves names each half's) add up; the same body met again (the forward's
+    trace and the backward's) is the same bytes."""
     found: Dict[str, int] = {}
 
     def walk(jp, times, replayed):
+        here: Dict[str, int] = {}
         for eqn in jp.eqns:
             prim = eqn.primitive.name
             if prim == "name" and replayed \
@@ -147,10 +151,12 @@ def named_bytes(jaxpr, devices: int = 1) -> Dict[str, int]:
                 aval = eqn.outvars[0].aval
                 size = times * aval.size * aval.dtype.itemsize
                 name = eqn.params["name"]
-                found[name] = max(found.get(name, 0), int(size))
+                here[name] = here.get(name, 0) + int(size)
             inner = times * eqn.params["length"] if prim == "scan" else times
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 walk(sub, inner, replayed or prim == "remat2")
+        for name, size in here.items():
+            found[name] = max(found.get(name, 0), size)
 
     walk(jaxpr, 1, False)
     return {name: size // devices for name, size in found.items()}

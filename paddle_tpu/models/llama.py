@@ -3,7 +3,10 @@
 Built TPU-first on the framework's own layers:
 - tensor parallel via Column/RowParallelLinear + VocabParallelEmbedding
   (GSPMD shard specs over the 'mp' axis),
-- sequence/context parallel via activation shard constraints on the 'cp' axis,
+- sequence/context parallel via activation shard constraints: between
+  sublayers the sequence lies over 'cp' and over 'mp' (Megatron's sequence-
+  parallel form: reduce-scatter + all-gather around the tensor-parallel
+  matmuls, mesh.activation_spec's "rows"); attention sees it whole per head,
 - attention through F.scaled_dot_product_attention -> Pallas flash kernel,
 - activation recompute per decoder layer (jax.checkpoint),
 - GQA (num_key_value_heads < num_attention_heads).
@@ -27,7 +30,7 @@ from ..distributed.meta_parallel.mp_layers import (
     ColumnParallelLinear, RowParallelLinear, VocabParallelEmbedding,
     _mp_degree, mark_sharding,
 )
-from ..distributed.mesh import get_mesh_env
+from ..distributed.mesh import activation_spec, get_mesh_env
 from ..distributed.meta_parallel.stage_stack import (
     ATTN_K, ATTN_O, ATTN_Q, ATTN_V, MLP_GATE, MLP_UP, StackedStageRun,
     name_for_recompute,
@@ -120,24 +123,16 @@ def apply_rotary_pos_emb(x: Tensor, theta: float = 10000.0, pos_offset: int = 0)
                  impl=resolve("rope"))
 
 
-def _cp_axes():
-    env = get_mesh_env()
-    if env is None:
-        return None
-    data = tuple(ax for ax in ("dp", "sdp") if env.get_dim(ax) > 1) or None
-    cp = "cp" if env.get_dim("cp") > 1 else None
-    return data, cp
-
-
-def _mark_seq(h: Tensor) -> Tensor:
-    """Constrain [b, s, d] activations: batch over dp/sdp, seq over cp."""
-    axes = _cp_axes()
-    if axes is None:
-        return h
-    data, cp = axes
-    if data is None and cp is None:
-        return h
-    return mark_sharding(h, data, cp, None)
+def _mark_seq(h: Tensor, layout: str = "rows") -> Tensor:
+    """Anchor [b, s, d] activations between sublayers to the mesh's
+    ``"rows"`` layout (``mesh.activation_spec``: batch over dp/sdp, seq over
+    cp and mp) — the same spec the norm kernels run under — or, behind the
+    vocabulary-parallel embedding, to ``"gathered"``."""
+    spec = activation_spec(h.shape, layout)
+    if spec is None or all(part is None for part in spec) or (
+            layout != "rows" and spec == activation_spec(h.shape, "rows")):
+        return h  # no mesh, nothing to split, or the anchor behind says it
+    return mark_sharding(h, *spec)
 
 
 class LlamaAttention(nn.Layer):
@@ -290,7 +285,9 @@ class LlamaModel(nn.Layer):
 
     def forward(self, input_ids):
         with part("embed"):
-            hidden = self.embed_tokens(input_ids)
+            # the vocabulary-parallel sum lands whole; the stream's shard of
+            # it is then a local slice
+            hidden = _mark_seq(self.embed_tokens(input_ids), "gathered")
         hidden = _mark_seq(hidden)
         if self.config.scan_layers:
             hidden = self.layers(hidden)

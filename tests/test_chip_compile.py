@@ -353,7 +353,95 @@ def test_norms_and_rope_on_the_mesh(topo):
                        "pt_rope"}
     reduced = [(r["axes"], r["shapes"])
                for r in compiled_collectives(text, env.mesh)]
-    assert reduced == [(("dp",), ("bf16[2048]",))], reduced   # dw, over dp
+    # dw alone: the rows lie over dp and, the sequence, over mp (ISSUE 56)
+    assert reduced == [(("dp", "mp"), ("bf16[2048]",))], reduced
+
+
+def test_mesh_step_hides_its_gathers_and_sends_no_all_reduce(topo,
+                                                             monkeypatch):
+    """The pin for the next compiler upgrade (ISSUE 56): a two-layer Llama at
+    cell 3's widths through ``ShardedTrainStep`` on ``dp=2 x mp=2``, compiled
+    for the four described chips. The stream between sublayers lies
+    sequence-sharded over ``mp`` and the scan body walks a replica's rows as
+    two halves, so every sum over ``mp`` of an activation is a reduce-scatter
+    (an ``all-reduce-scatter`` fusion) of HALF the rows, no plain ``mp``
+    all-reduce of an activation is left, and all-gathers ride
+    ``async-collective-start`` / ``-done`` pairs in the forward loop's body
+    AND in the backward's — this compiler makes an all-gather asynchronous
+    where independent work exists, never an all-reduce (PERF.md section 7)."""
+    import paddle_tpu as paddle
+    import paddle_tpu.distributed as dist
+    import paddle_tpu.optimizer as opt
+    from paddle_tpu import jit
+    from paddle_tpu.distributed.mesh import compiled_collectives
+    from paddle_tpu.kernels import flash_attention as fa
+    from paddle_tpu.kernels import registry
+    from paddle_tpu.models import LlamaConfig, LlamaForCausalLM
+    from paddle_tpu.nn.functional import attention
+
+    # this process's backend is the CPU: steer the program's backend
+    # branches to their TPU side here, and hold no array on a described chip
+    monkeypatch.setattr(registry, "_backend", lambda: "tpu")
+    monkeypatch.setattr(fa, "_interpret", lambda: False)
+    backend = attention.attention_backend
+    monkeypatch.setattr(attention, "attention_backend",
+                        lambda sq, sk, hd, platform=None:
+                        backend(sq, sk, hd, "tpu"))
+    monkeypatch.setattr(jax, "device_put", lambda x, *a, **k: x)
+    b, s, h = 8, 2048, 2048
+    dist.reset_mesh()
+    env = dist.init_mesh(dp=2, mp=2, devices=list(topo.devices))
+    try:
+        paddle.seed(0)
+        model = LlamaForCausalLM(LlamaConfig(
+            vocab_size=4096, hidden_size=h, intermediate_size=8192,
+            num_hidden_layers=2, num_attention_heads=16,
+            num_key_value_heads=8, max_position_embeddings=s,
+            rope_theta=1e6, use_recompute=True))
+        step = dist.ShardedTrainStep(
+            model, lambda m, x, y: m(x, labels=y),
+            opt.AdamW(learning_rate=3e-4, parameters=model.parameters()))
+        ids = [jnp.zeros((b, s), jnp.int32)] * 2
+        param_sh, state_sh, frozen_sh, batch_sh = step._sharding_plan(ids)
+        repl = env.replicated()
+        structs = jax.tree_util.tree_map(
+            lambda a, sh: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh),
+            tuple(jit.step_args(step, ids, jax.random.key(0))),
+            (param_sh, state_sh, frozen_sh, repl, repl, repl, *batch_sh))
+        text = jit.lowerable(step._build(ids)).lower(*structs) \
+            .compile().as_text()
+    finally:
+        dist.reset_mesh()
+    rows = compiled_collectives(text, env.mesh)
+    over_mp = [r for r in rows if r["axes"] == ("mp",)]
+    activation = re.compile(rf"bf16\[\d+,\d+,{h}\]")
+
+    plain = [r for r in over_mp if r["op"] == "all-reduce"
+             and any(activation.fullmatch(sh) for sh in r["shapes"])]
+    assert not plain, plain
+    scattered = {sh: r["count"] for r in over_mp
+                 if r["op"] == "reduce-scatter" for sh in r["shapes"]}
+    # o_proj's and down_proj's sums forward, the input gradients of q / k / v
+    # and of gate / up backward, each for both halves; the embedding's whole
+    assert scattered == {f"bf16[{b // 4},{s // 2},{h}]": 8,
+                         f"bf16[{b // 2},{s // 2},{h}]": 1}, rows
+    gathers = [r for r in over_mp if r["op"] == "all-gather"]
+    assert sum(r["async"] for r in gathers) >= 6, gathers
+    assert all(r["async"] == 0 for r in rows if r["op"] != "all-gather")
+
+    # where the pairs sit: a start's computation holds the all-gather, whose
+    # name stack says which loop body asked for it
+    started = set(re.findall(
+        r"%async-collective-start[\w.\-]* = .*\bcalls=%([\w.\-]+)", text))
+    body, phases = "", set()
+    for line in text.splitlines():
+        head = re.match(r"%([\w.\-]+) \(", line)
+        body = head.group(1) if head else body
+        if body in started and " all-gather(" in line:
+            stack = re.search(r'op_name="([^"]*)"', line).group(1)
+            assert "pt.stack" in stack and "/while/body/" in stack, stack
+            phases.add("backward" if "transpose(jvp(" in stack else "forward")
+    assert phases == {"forward", "backward"}, phases
 
 
 def _compile_paged(one_chip, S, W, nh, hd, PL, P, B, dtype):

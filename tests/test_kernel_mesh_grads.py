@@ -97,3 +97,38 @@ def test_mesh_gradients_are_the_one_device_gradients(op, mesh, monkeypatch):
         reduced = [s for r in rows if r["op"] == "all-reduce"
                    for s in r["shapes"] if s.count(",") >= 2]
         assert not reduced, reduced
+
+
+@pytest.mark.dist
+@pytest.mark.parametrize("mesh,seq,over", [
+    ("dp2-mp2", S, ("dp", "mp")), ("sdp2-mp2", S, ("sdp", "mp")),
+    ("mp4", S, ("mp",)),
+    # mp does not divide the sequence: the rows stay whole over mp and each
+    # mp shard computes the same dw
+    ("dp2-mp2", S - 1, ("dp",))])
+@pytest.mark.parametrize("op", ["rms_norm", "rms_norm_residual"])
+def test_norm_dw_sums_over_mp_where_mp_splits_the_rows(op, mesh, seq, over,
+                                                       monkeypatch):
+    """Between sublayers the rows lie sequence-sharded over ``mp``
+    (ISSUE 56): a norm kernel then sees 1/mp of the sequence, and the seam
+    sums its ``dw`` — ``[hidden]`` floats — over ``mp`` besides the data
+    axes, by its own rule (the axes that split another operand)."""
+    make, argnums, name, layout = OPS[op]
+    loss, args = make("reference")
+    args = tuple(a[:, :seq] if a.ndim == 3 else a for a in args)
+    want = jax.grad(loss, argnums)(*args)
+
+    monkeypatch.setenv("PT_PALLAS_INTERPRET", "1")
+    env = dist.init_mesh(**MESHES[mesh], devices=jax.devices()[:4])
+    spec = activation_spec(args[0].shape, layout)
+    assert ("mp" in str(spec[1])) == ("mp" in over), spec
+    loss, _ = make(registry.resolve(name))
+    held = [env.sharding_for(spec if a.ndim > 1 else P()) for a in args]
+    compiled = jax.jit(jax.grad(loss, argnums), in_shardings=held,
+                       out_shardings=tuple(held[i] for i in argnums)
+                       ).lower(*args).compile()
+    for g, ref in zip(compiled(*args), want):
+        np.testing.assert_allclose(g, ref, rtol=2e-5, atol=2e-5)
+    rows = compiled_collectives(compiled.as_text(), env.mesh)
+    assert [(r["axes"], r["op"], r["shapes"]) for r in rows] == \
+        [(over, "all-reduce", (f"f32[{H}]",))], rows
