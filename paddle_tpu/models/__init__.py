@@ -35,3 +35,6 @@ from .brumby import (  # noqa: F401
 from .xing4 import (  # noqa: F401
     Xing4Config, Xing4ForCausalLM, Xing4Block, Xing4Served,
 )
+from .nemotron_h import (  # noqa: F401
+    NemotronHConfig, NemotronHForCausalLM, NemotronHBlock, NemotronHServed,
+)

@@ -11,7 +11,9 @@ runs it with a dense causal ``attend`` and a fresh state, and
 seam (``FalconH1Served``) with its paged ``attend`` and its slot-indexed
 state arenas. Two programs of one recurrence: a window of tokens from a zero
 state runs the chunked scan (chunks of ``mamba_chunk_size``, matmuls in
-composed ``jnp``) and hands back the FINAL state; one token over a live state
+composed ``jnp``; ``ssd_chunked`` also goes on from a given state, which
+this model does not ask of it) and hands back the FINAL state; one token
+over a live state
 runs one step (``kernels/pallas/ssm_step.py``).
 
 Weights are created on the device, in the configuration's dtype, from
@@ -174,10 +176,12 @@ def _rope(x, pos, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
 
 
-def ssd_chunked(x, dt, a, b, c, chunk: int):
-    """The Mamba-2 recurrence over a window from a ZERO state, by chunks
-    (the state-space-duality form: within a chunk a masked matmul, between
-    chunks the recurrence on the chunk states). ``x`` [R, W, H, P]; ``dt``
+def ssd_chunked(x, dt, a, b, c, chunk: int, initial=None):
+    """The Mamba-2 recurrence over a window, by chunks (the
+    state-space-duality form: within a chunk a masked matmul, between
+    chunks the recurrence on the chunk states), from ``initial`` [R, H, P,
+    N] — the state a previous window left — or from ZERO (``None``). ``x``
+    [R, W, H, P]; ``dt``
     [R, W, H] (after softplus; 0 where the position holds no token: the
     state passes through it unchanged); ``a`` [H]; ``b``, ``c``
     [R, W, H, N]. float32 throughout, matmuls at full precision (they are
@@ -204,7 +208,8 @@ def ssd_chunked(x, dt, a, b, c, chunk: int):
     states = jnp.einsum("rcsh,rcshp,rcshn->rchpn", to_end, xdt, br,
                         precision=_HI)
     chunk_decay = jnp.exp(cum[:, :, -1, :])                # [R, nC, H]
-    s = jnp.zeros((R, H, P, b.shape[-1]), F32)
+    s = jnp.zeros((R, H, P, b.shape[-1]), F32) if initial is None \
+        else initial.astype(F32)
     before = []
     for ci in range(nC):
         before.append(s)
@@ -213,6 +218,54 @@ def ssd_chunked(x, dt, a, b, c, chunk: int):
     y = y + jnp.einsum("rclhn,rchpn,rclh->rclhp", cr, before, jnp.exp(cum),
                        precision=_HI)
     return y.reshape(R, W + pad, H, P)[:, :W], s
+
+
+def causal_conv(xbc, tail, conv_w, conv_b, valid):
+    """The depthwise causal conv over ``xbc`` [R, W, C] (float32) behind the
+    ``tail`` [R, kc - 1, C] its previous window left (``None``: a fresh
+    sequence, zeros), bias added, before the activation. Returns it and the
+    conv's NEXT tail: the ``kc - 1`` inputs that end at the last REAL token
+    (``valid`` [R, W])."""
+    R, W, C = xbc.shape
+    kc = conv_w.shape[1]
+    if tail is None:
+        tail = jnp.zeros((R, kc - 1, C), F32)
+    seq = jnp.concatenate([tail, xbc], axis=1)             # [R, kc-1+W, C]
+    conv = sum(seq[:, j:j + W] * conv_w[:, j].astype(F32)
+               for j in range(kc)) + conv_b.astype(F32)
+    n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)     # [R]
+    new_tail = jax.vmap(lambda sq, n: jax.lax.dynamic_slice_in_dim(
+        sq, n, kc - 1, axis=0))(seq, n_valid)
+    return conv, new_tail
+
+
+def mamba_scan(xh, dt, a, bg, cg, d, ssm, chunk: int, step: bool):
+    """The recurrence on ``xh`` [R, W, H, P] with ``bg``, ``cg`` [R, W, G,
+    N] by group. ``step``: ONE token over the live arena ``ssm`` (the
+    kernel, ``kernels/pallas/ssm_step.py``, in place). Else the chunked scan
+    from ``ssm`` — a row's own state, or ``None``: zero. Returns ``(y [R, W,
+    H, P], the state after it)``."""
+    H, G = xh.shape[2], bg.shape[2]
+    if not step:
+        bh, ch = (jnp.repeat(t, H // G, axis=2) for t in (bg, cg))
+        y, ssm = ssd_chunked(xh, dt, a, bh, ch, chunk, ssm)
+        return y + d[None, None, :, None] * xh, ssm
+    if xh.shape[1] != 1:
+        raise ValueError("one token a step over a live state")
+    from ..kernels.pallas.ssm_step import ssm_step
+
+    ssm, y = ssm_step(ssm, xh[:, 0], dt[:, 0], a, bg[:, 0], cg[:, 0], d)
+    return y[:, None], ssm
+
+
+def gated_norm(y, z, groups: int, eps: float, w):
+    """``RMSNorm(y silu(z))`` over each of ``groups`` contiguous slices of
+    the last axis, the weight after."""
+    R, W, d = y.shape
+    y = y * jax.nn.silu(z)
+    yg = y.reshape(R, W, groups, d // groups)
+    yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, -1, keepdims=True) + eps)
+    return yg.reshape(R, W, d) * w.astype(F32)
 
 
 @part("mixer")
@@ -224,18 +277,12 @@ def _ssm_branch(cfg: FalconH1Config, p, u, state, valid):
     R, W, _ = u.shape
     H, P, N, G = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state,
                   cfg.mamba_n_groups)
-    d_ssm, kc, gn = cfg.mamba_d_ssm, cfg.mamba_d_conv, G * cfg.mamba_d_state
+    d_ssm, gn = cfg.mamba_d_ssm, G * cfg.mamba_d_state
     zxbcdt = _mm(u * cfg.ssm_in_multiplier, p["in_w"]) * mup_vector(cfg)
     z, xbc, dt = jnp.split(zxbcdt, [d_ssm, d_ssm + cfg.conv_dim], -1)
-    tail = jnp.zeros((R, kc - 1, cfg.conv_dim), F32) \
-        if state is None else state["conv"]
-    seq = jnp.concatenate([tail, xbc], axis=1)             # [R, kc-1+W, C]
-    conv = sum(seq[:, j:j + W] * p["conv_w"][:, j].astype(F32)
-               for j in range(kc)) + p["conv_b"].astype(F32)
-    # the conv's next tail: the kc-1 inputs that end at the last REAL token
-    n_valid = jnp.sum(valid, axis=1).astype(jnp.int32)     # [R]
-    new_tail = jax.vmap(lambda sq, n: jax.lax.dynamic_slice_in_dim(
-        sq, n, kc - 1, axis=0))(seq, n_valid)
+    conv, new_tail = causal_conv(
+        xbc, None if state is None else state["conv"], p["conv_w"],
+        p["conv_b"], valid)
     xs, b, c = jnp.split(jax.nn.silu(conv), [d_ssm, d_ssm + gn], -1)
     dt = jax.nn.softplus(dt + p["dt_bias"].astype(F32))
     dt = jnp.where(valid[..., None], dt, 0.0)              # [R, W, H]
@@ -243,23 +290,11 @@ def _ssm_branch(cfg: FalconH1Config, p, u, state, valid):
     d = p["D"].astype(F32)
     xh = xs.reshape(R, W, H, P)
     bg, cg = (t.reshape(R, W, G, N) for t in (b, c))
-    if state is None:
-        bh, ch = (jnp.repeat(t, H // G, axis=2) for t in (bg, cg))
-        y, ssm = ssd_chunked(xh, dt, a, bh, ch, cfg.mamba_chunk_size)
-        y = y + d[None, None, :, None] * xh
-    else:
-        if W != 1:
-            raise ValueError("one token a step over a live state")
-        from ..kernels.pallas.ssm_step import ssm_step
-
-        ssm, y = ssm_step(state["ssm"], xh[:, 0], dt[:, 0], a, bg[:, 0],
-                          cg[:, 0], d)
-        y = y[:, None]
-    y = y.reshape(R, W, d_ssm) * jax.nn.silu(z)
-    yg = y.reshape(R, W, G, d_ssm // G)
-    yg = yg * jax.lax.rsqrt(jnp.mean(yg * yg, -1, keepdims=True)
-                            + cfg.rms_norm_eps)
-    y = yg.reshape(R, W, d_ssm) * p["ssm_norm"].astype(F32)
+    y, ssm = mamba_scan(xh, dt, a, bg, cg, d,
+                        None if state is None else state["ssm"],
+                        cfg.mamba_chunk_size, step=state is not None)
+    y = gated_norm(y.reshape(R, W, d_ssm), z, G, cfg.rms_norm_eps,
+                   p["ssm_norm"])
     y = _mm(y, p["out_w"]) * cfg.ssm_out_multiplier
     return y, {"ssm": ssm, "conv": new_tail}
 

@@ -394,7 +394,11 @@ def moe_held_experts_mlp(x, wr, w_gate, w_up, w_down, *, top_k, first,
     stacked weights — sort them by expert, run them through three grouped
     matmuls (``kernels/grouped_matmul.py``: megablox on the TPU, whose grid
     covers only the rows that met a held expert) and sum each token's
-    weighted results. What the other experts would have added is NOT here:
+    weighted results. ``w_gate`` ``None``: an UNGATED expert, two matrices and
+    ``down(relu(up(x))^2)`` (two grouped matmuls; up's result leaves the
+    kernel float32, is squared there and rounded once into down: a square
+    doubles a rounding's relative error). What the other experts would have
+    added is NOT here:
     under ``ep`` it arrives by the exchange; a chip alone returns its part.
     With ``first = 0`` and ``count = E`` the share is the WHOLE layer: every
     routed pair is held. The grouped matmuls' tiles follow from the shapes
@@ -424,23 +428,26 @@ def moe_held_experts_mlp(x, wr, w_gate, w_up, w_down, *, top_k, first,
     from ...kernels.grouped_matmul import choose_tiling, grouped_matmul
 
     n, h = x.shape
-    count, _, w = w_gate.shape
+    count, _, w = w_up.shape
     kn = top_k * n
+    # what leaves the first matmul(s): the rows' dtype into a gate's product,
+    # float32 into an ungated expert's square
+    mid = x.dtype if w_gate is not None else jnp.dtype(jnp.float32)
 
-    def tiles(k_dim, n_dim):
+    def tiles(k_dim, n_dim, out):
         return choose_tiling(kn, k_dim, n_dim, groups=count,
                              rows_per_group=kn / wr.shape[1],
                              lhs_item=x.dtype.itemsize,
-                             rhs_item=w_gate.dtype.itemsize,
-                             out_item=x.dtype.itemsize)
+                             rhs_item=w_up.dtype.itemsize,
+                             out_item=out.itemsize)
 
-    tile_in, tile_out = tiles(h, w), tiles(w, h)
+    tile_in, tile_out = tiles(h, w, mid), tiles(w, h, x.dtype)
 
-    def gmm(lhs, rhs, tiling):
+    def gmm(lhs, rhs, tiling, out=None):
         # the kernel's call alone is ``experts``
         with part("experts"):
             return grouped_matmul(lhs, rhs, group_sizes, tiling=tiling,
-                                  out_dtype=lhs.dtype)
+                                  out_dtype=lhs.dtype if out is None else out)
 
     gate_v, gate_i, _aux = _route(x if x_route is None else x_route, wr,
                                   top_k, score=score,
@@ -459,8 +466,11 @@ def moe_held_experts_mlp(x, wr, w_gate, w_up, w_down, *, top_k, first,
     group_sizes = jnp.bincount(key, length=count + 1)[:count]
 
     xs = jnp.take(x, order // top_k, axis=0)                  # [kn, h]
-    g_proj, u_proj = gmm(xs, w_gate, tile_in), gmm(xs, w_up, tile_in)
-    act = jax.nn.silu(g_proj.astype(jnp.float32)) * u_proj
+    if w_gate is None:
+        act = jnp.square(jax.nn.relu(gmm(xs, w_up, tile_in, mid)))
+    else:
+        g_proj, u_proj = gmm(xs, w_gate, tile_in), gmm(xs, w_up, tile_in)
+        act = jax.nn.silu(g_proj.astype(jnp.float32)) * u_proj
     ys = gmm(act.astype(x.dtype), w_down, tile_out)           # [kn, h]
     # rows past the groups are whatever the kernel left there: select, never
     # multiply

@@ -20,7 +20,9 @@ index projections, scores and top-k of a learned sparse attention;
 ``pt.retention``: the kernel calls of a power retention layer and their glue,
 inside ``attention``; ``pt.mhc``: the residual path of a model whose stream is
 several rows — a sublayer's mixing maps and the mix itself, inside
-``attn_proj`` and ``mlp``): it marks work INSIDE parts without being one. The ten parts stay a partition of the
+``attn_proj`` and ``mlp``; ``pt.ssm_scan``: a Mamba-2 layer's recurrence —
+the chunked scan of a prefill, the one-step kernel of a round — inside
+``mixer``): it marks work INSIDE parts without being one. The ten parts stay a partition of the
 step — a reader of ``PARTS`` skips a ``pt.`` name outside its vocabulary and
 finds the part around it, so the indexer's projections are still ``attn_proj``
 and its scores ``attention`` — and one reader of its own
@@ -56,7 +58,7 @@ __all__ = ["PARTS", "SUBPARTS", "STEP_PARTS", "PHASES", "PREFIX", "part",
 
 PARTS = ("embed", "norm", "attn_proj", "cache_write", "attention", "mlp",
          "router", "experts", "mixer", "head")
-SUBPARTS = ("indexer", "retention", "mhc")
+SUBPARTS = ("indexer", "retention", "mhc", "ssm_scan")
 STEP_PARTS = ("stack", "optimizer")
 PHASES = ("forward", "recompute", "backward")
 PREFIX = "pt."

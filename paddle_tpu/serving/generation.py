@@ -775,9 +775,14 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     latent = cache_kind == "latent"
     by_layer = cache_kind == "kv_by_layer"
     unpaged = cache_kind == "none"
-    # two kinds of layer, one pool and one table each (``"layers"``: the K/V
-    # form, or a latent cache that declares its layers' kinds)
+    # layers of several kinds (``"layers"``: the K/V form, or a latent cache
+    # that declares its layers' kinds): the paging kinds have a pool and a
+    # table each ("full", then "window" where there is one), a "state" layer
+    # keeps a row of the state arenas and a "none" layer nothing — and
+    # ``k_arenas`` / ``v_arenas`` hold the paging layers' arenas alone,
+    # ``state`` the "state" layers' (every layer's where no kind is declared)
     kinds = list((sm.cache_spec or {}).get("layers") or ())
+    table_kinds = ("full", "window") if "window" in kinds else ("full",)
     counter_names = sm.program_counters
     # a model whose block resumes is told which of its two state conventions
     # a program uses: the slot arenas of a round, or a row's own state
@@ -811,7 +816,7 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
             sparse = _Sparse(sm, attends, prefill, bool(R) or not prefill)
     elif by_layer:
         ranged = {kind: _attention(sm, attends, kind)
-                  for kind in sorted(set(kinds))}
+                  for kind in sorted(set(kinds) & set(table_kinds))}
     elif not unpaged:
         paged_attend = _attention(sm, attends, "paged")
 
@@ -887,7 +892,7 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
 
         if kinds:
             by_kind = {kind: tuple(t[i] for t in tables) if R else tables[i]
-                       for i, kind in enumerate(("full", "window"))}
+                       for i, kind in enumerate(table_kinds)}
             where_of = {kind: places(t) for kind, t in by_kind.items()}
         elif not unpaged:
             where = places(tables)
@@ -896,15 +901,20 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
                 jnp.arange(W)[None, :] < n_valid[:, None]          # [S, W]
         new_k, new_v, new_state, counted = [], [], [], []
         held, selected, picked = [None, None], [], []
-        # nothing paged: no arena a layer, no table, and no ``attend``
-        for li, (p, kc) in enumerate(zip(
-                params["layers"],
-                [None] * len(params["layers"]) if unpaged else k_arenas)):
-            vc = None if latent or unpaged else v_arenas[li]
-            # the layer's own table and places: its kind's, where there are two
+        arenas, values, states = iter(k_arenas), iter(v_arenas), \
+            iter(state or ())
+        for li, p in enumerate(params["layers"]):
             kind = kinds[li] if kinds else None
-            own_where, own_tables = (where_of[kind], by_kind[kind]) if kinds \
-                else (None, None) if unpaged else (where, tables)
+            # a layer that pages nothing (every layer of a cache of kind
+            # "none"; a "state" or "none" layer): no arena, no table, and no
+            # ``attend``
+            pages = not unpaged and kind not in ("state", "none")
+            keeps = stateful and kind in (None, "state")
+            kc = next(arenas) if pages else None
+            vc = None if latent or not pages else next(values)
+            # the layer's own table and places: its kind's, where there are two
+            own_where, own_tables = (None, None) if not pages else \
+                (where_of[kind], by_kind[kind]) if kinds else (where, tables)
 
             def attend_latent(q_lat, q_rope, row, index=None):
                 # the window's rows land in their pages, then every head of
@@ -963,13 +973,14 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
                             q, own_tables, lengths)
 
             attend_ranged.kind = attend_latent.kind = kind
-            out = sm.block(p, x, xpos, attend_latent if latent else
-                           attend_ranged if by_layer else
-                           None if unpaged else attend,
-                           None if state is None else state[li], valid,
-                           **step_kw)
+            out = sm.block(p, x, xpos, None if not pages else
+                           attend_latent if latent else
+                           attend_ranged if by_layer else attend,
+                           next(states) if keeps and state is not None
+                           else None, valid, **step_kw)
             x, st = out[0], out[1]
-            new_state.append(st)
+            if keeps:
+                new_state.append(st)
             if counter_names and len(out) > 2 and out[2] is not None:
                 counted.append(out[2])
         if prefill:
@@ -1057,7 +1068,11 @@ class GenerationEngine(EngineBase):
         self._latent = cache_kind == "latent"
         # two kinds of layer, one pool each: the K/V form, or a latent cache
         # that declares its layers' kinds (``cache_spec["layers"]``)
-        self._by_layer = bool((sm.cache_spec or {}).get("layers"))
+        kinds = list((sm.cache_spec or {}).get("layers") or ())
+        self._by_layer = bool(kinds)
+        # ... of which some keep a sliding window, whose pages come from a
+        # second allocator through a second table
+        self._windowed = "window" in kinds
         # nothing paged: every layer's memory is its recurrent state. No K/V
         # arena, no page table in the programs, admission by slots alone, and
         # ``max_seq_len`` bounds positions only (no memory grows with it).
@@ -1074,7 +1089,17 @@ class GenerationEngine(EngineBase):
                     f"{type(model).__name__} keeps no K/V pages at all: "
                     "the warm tier has nothing to spill or restore — pass "
                     "GenerationConfig(warm_pool_bytes=0)")
-        if self._by_layer:
+        if self._by_layer and not self._windowed and \
+                self.config.warm_pool_bytes:
+            # (the prefix cache and a draft model were refused above: such a
+            # cache has a "state" layer; docs/serving.md, "Memory by layer
+            # kind")
+            raise ValueError(
+                f"{type(model).__name__} keeps pages in some of its layers "
+                "and a recurrent state in others: the warm tier spills and "
+                "restores prefixes of pages, and a prefix's state is in none "
+                "— pass GenerationConfig(warm_pool_bytes=0)")
+        if self._windowed:
             # what assumes that a page, once written, stays: refused in
             # words (docs/serving.md, "A cache of two layer kinds")
             why = (f"{type(model).__name__} keeps a sliding window of "
@@ -1146,7 +1171,8 @@ class GenerationEngine(EngineBase):
                 capacity_bytes=self.config.warm_pool_bytes,
                 admit_threshold=self.config.warm_admit_threshold)
         window_pages = 0
-        if self._by_layer:
+        self._win = 0       # no window layer: no key is counted inside one
+        if self._windowed:
             # a slot's window layers hold at most this many pages while it
             # decodes, and this many while its largest chunk runs
             self._win = int(sm.cache_spec["window"])
@@ -1164,8 +1190,11 @@ class GenerationEngine(EngineBase):
                     f"{self.config.prefill_buckets[-1]}-token chunk is "
                     "running, and the scratch page: "
                     f"{S * self._wbound + chunk + 1}")
-            self._layers_of = {kind: sm.cache_spec["layers"].count(kind)
-                               for kind in ("full", "window")}
+        if self._by_layer:
+            # the layers of each paging kind (a table and a pool each)
+            self._layers_of = {
+                kind: kinds.count(kind)
+                for kind in ("full", "window")[:1 + self._windowed]}
             # a page as the ranged kernel sees it: K/V heads, tokens, head
             # size, bytes an element (``_count_walk``; a latent page is rows)
             self._page = (sm.num_kv_heads, pl, sm.head_dim,
@@ -1464,10 +1493,12 @@ class GenerationEngine(EngineBase):
     def _tables_shape(self, rows: int) -> Tuple[int, ...]:
         """A window program's page tables for ``rows`` rows: ``[rows, B]``,
         or the full and the window layers' stacked, ``[2, rows, B]``."""
-        return ((2,) if self._by_layer else ()) + (rows, self._n_blocks)
+        return ((len(self._layers_of),) if self._by_layer else ()) + \
+            (rows, self._n_blocks)
 
     def _slot_tables(self, s: _Slot) -> np.ndarray:
-        return np.stack([s.table, s.wtable]) if self._by_layer else s.table
+        return np.stack([s.table, s.wtable][:len(self._layers_of)]) \
+            if self._by_layer else s.table
 
     def _window_pages(self, s: _Slot, lo: int, hi: int) -> None:
         """The next program's queries of slot ``s`` sit at positions ``[lo,
@@ -1513,6 +1544,8 @@ class GenerationEngine(EngineBase):
         tokens share theirs: the two have different floors)."""
         self.metrics.inc("attn_keys_full_total",
                          full * self._layers_of["full"])
+        if not self._windowed:
+            return
         self.metrics.inc("attn_keys_window_total",
                          windowed * self._layers_of["window"])
         self.metrics.inc(
@@ -2020,7 +2053,7 @@ class GenerationEngine(EngineBase):
                 f"{what}: {type(self.model).__name__} keeps no K/V pages at "
                 "all — a sequence is its recurrent state, and no state "
                 "snapshot is shipped")
-        if self._by_layer:
+        if self._windowed:
             raise RuntimeError(
                 f"{what}: {type(self.model).__name__} keeps a sliding "
                 "window in some of its layers — their pages behind the "
@@ -2125,7 +2158,7 @@ class GenerationEngine(EngineBase):
     def _window_needed(self, req: _GenRequest) -> int:
         """Window pages a request must find free at its join: what its
         widest prefill call holds, and never less than a decoding slot's."""
-        if not self._by_layer:
+        if not self._windowed:
             return 0
         widest = min(len(req.prompt), self.config.prefill_buckets[-1])
         return max(window_page_bound(self._win, widest, self._pl),
@@ -2143,7 +2176,7 @@ class GenerationEngine(EngineBase):
                     self._queue.remove(r)
                     shed.append(r)
             order = sorted(self._queue, key=_GenRequest.edf_key)
-            reserved = self._window_reserved() if self._by_layer else 0
+            reserved = self._window_reserved() if self._windowed else 0
             for r in order:
                 if self._pool.can_allocate(self._blocks_needed(r),
                                            self._window_needed(r), reserved):
@@ -2337,7 +2370,7 @@ class GenerationEngine(EngineBase):
                 if copied:
                     s.table[bi] = pg
         chunks = self._prefill_chunks(m * pl, p)
-        if self._by_layer:
+        if self._windowed:
             # the first call's window pages; the later calls take theirs as
             # they go out (``_send_chunk``), out of what ``_next_request``
             # found free
@@ -2394,7 +2427,7 @@ class GenerationEngine(EngineBase):
                 f"a {Wc}-token prefill call that writes whole pages starts "
                 f"at {lo}, inside a page of {self._pl}: it would overwrite "
                 "cached keys")
-        if self._by_layer and adm.outs:
+        if self._windowed and adm.outs:
             s = self._slots[adm.slot_no]
             with span("pt.serve.page_table"):
                 self._window_pages(s, lo, hi - 1)
@@ -2670,7 +2703,7 @@ class GenerationEngine(EngineBase):
                 else:
                     tokens[i, 0] = s.last_token
                 lengths[i] = min(length, self.max_len - 1)
-                if self._by_layer:
+                if self._windowed:
                     self._window_pages(s, int(lengths[i]), int(lengths[i]))
                 tables[..., i, :] = self._slot_tables(s)
                 rows.append((i, req))
@@ -2902,7 +2935,7 @@ class GenerationEngine(EngineBase):
             self._pool.allocator.release(int(s.table[bi]))
         s.table[:] = 0
         s.blocks = s.shared = 0
-        if self._by_layer:
+        if self._windowed:
             for bi in range(s.wlo, s.whi):
                 self._pool.window_allocator.release(int(s.wtable[bi]))
             s.wtable[:] = 0

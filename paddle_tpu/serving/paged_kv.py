@@ -482,12 +482,15 @@ class PagedKVPool:
         self.cache_spec = cache_spec
         self.window_allocator: Optional[PageAllocator] = None
         self.layer_kinds: Optional[List[str]] = None
-        # a cache of two layer KINDS, K/V or latent (``cache_spec["layers"]``):
-        # the window layers' arenas hold ``window_pages`` pages of an
-        # allocator of their own
-        kinds = self._two_kinds(cache_spec, num_layers, window_pages,
-                                prefix_cache or warm_pool is not None) \
-            or [None] * num_layers
+        # a cache that declares its layers' KINDS, K/V or latent
+        # (``cache_spec["layers"]``): a "full" layer's arenas hold the pool's
+        # pages, a "window" layer's ``window_pages`` pages of an allocator of
+        # their own; a "state" layer keeps no page but a row of the state
+        # arenas below, a "none" layer nothing at all
+        kinds = self._kinds(cache_spec, num_layers, window_pages,
+                            prefix_cache or warm_pool is not None,
+                            state_spec) or [None] * num_layers
+        kinds = [kind for kind in kinds if kind not in ("state", "none")]
         pages_of = [window_pages if kind == "window" else num_pages
                     for kind in kinds]
         if cache_spec is None:
@@ -500,6 +503,7 @@ class PagedKVPool:
                 wide.get(kind, cache_spec["dim"])))
                 for n, kind in zip(pages_of, kinds)]
         elif cache_spec["kind"] == "kv_by_layer":
+            # (an arena for each layer that pages, in the layers' order)
             shapes = [(n, num_heads, page_len, head_dim) for n in pages_of]
         elif cache_spec["kind"] == "none":
             # NOTHING paged: every layer's memory is its recurrent state
@@ -537,14 +541,17 @@ class PagedKVPool:
         # shape, dtype)}) — e.g. the SSM state [slots, heads, P, N] and the
         # conv tail [slots, d_conv - 1, channels]. Donated into every
         # program like the K/V arenas and updated in place; a row is
-        # overwritten whole when its slot is admitted. None: K/V only.
+        # overwritten whole when its slot is admitted. None: K/V only. Where
+        # the cache declares its layers' kinds, the "state" layers alone have
+        # one (in their order), else every layer.
         self.state = None if state_spec is None else [
             {name: jnp.zeros((int(max_slots),) + tuple(shape), dt)
              for name, (shape, dt) in state_spec.items()}
-            for _ in range(num_layers)]
+            for _ in range(num_layers if self.layer_kinds is None
+                           else self.layer_kinds.count("state"))]
 
-    def _two_kinds(self, cache_spec, num_layers: int, window_pages: int,
-                   shares_pages: bool) -> Optional[List[str]]:
+    def _kinds(self, cache_spec, num_layers: int, window_pages: int,
+               shares_pages: bool, state_spec) -> Optional[List[str]]:
         """The layers' kinds where the cache declares them (``"layers"``: a
         K/V cache ``kv_by_layer``, or a latent one), and the window layers'
         allocator with them; ``None`` for a cache of one kind."""
@@ -552,18 +559,29 @@ class PagedKVPool:
         if kinds is None:
             return None
         kinds = list(kinds)
-        if len(kinds) != num_layers or set(kinds) - {"full", "window"}:
+        if len(kinds) != num_layers or \
+                set(kinds) - {"full", "window", "state", "none"}:
             raise ValueError(
                 f"cache_spec['layers'] must name {num_layers} layers "
-                f"'full' or 'window', got {kinds}")
+                f"'full' or 'window' (pages), 'state' or 'none', got {kinds}")
+        if ("state" in kinds) != (state_spec is not None):
+            raise ValueError(
+                "cache_spec['layers'] names a 'state' layer exactly where the "
+                f"model declares a state_spec: got {kinds} and state_spec "
+                f"{state_spec}")
+        if not set(kinds) & {"full", "window"}:
+            raise ValueError(
+                f"cache_spec['layers'] {kinds} pages nothing: a model with "
+                "nothing paged declares a cache_spec of kind 'none'")
         if shares_pages:
             raise ValueError(
                 "a cache of two layer kinds has no prefix cache and no "
                 "warm tier: a shared page behind a window has been "
-                "given back")
+                "given back, and a layer's recurrent state is in no page")
         self.layer_kinds = kinds
-        self.window = int(cache_spec["window"])
-        self.window_allocator = PageAllocator(window_pages)
+        if "window" in kinds:
+            self.window = int(cache_spec["window"])
+            self.window_allocator = PageAllocator(window_pages)
         return kinds
 
     # -- control plane --------------------------------------------------------
@@ -709,8 +727,11 @@ class PagedKVPool:
         """The arenas' bytes by layer kind (one kind, "full", for a cache
         that declares none); the latent rows and the index keys apart for a
         latent cache with an index row, and the latent rows by layer kind
-        where it declares two (``latent_full`` / ``latent_window``)."""
-        kinds = self.layer_kinds or ["full"] * len(self.k)
+        where it declares two (``latent_full`` / ``latent_window``); the
+        state arenas as ``"state"`` where some layers are declared to keep
+        one."""
+        kinds = [kind for kind in self.layer_kinds or ["full"] * len(self.k)
+                 if kind in ("full", "window")]
         if self.cache_spec is not None and self.cache_spec.get("index"):
             names = [f"latent_{kind}" for kind in kinds] \
                 if self.layer_kinds else ["latent"] * len(self.k)
@@ -722,7 +743,19 @@ class PagedKVPool:
         for arenas in (self.k, self.v):
             for kind, a in zip(kinds, arenas):
                 out[kind] += int(a.nbytes)
+        if "state" in (self.layer_kinds or ()):
+            out["state"] = self.state_bytes()
         return out
+
+    def layers_by_kind(self) -> Dict[str, int]:
+        """How many layers keep what: ``{"full": 1, "state": 4, "none": 4}``
+        for a cache that declares its layers' kinds, else every layer under
+        the one kind the cache has."""
+        if self.layer_kinds is None:
+            one = "kv" if self.cache_spec is None else self.cache_spec["kind"]
+            return {one: len(self.k) or len(self.state or ())}
+        return {kind: self.layer_kinds.count(kind)
+                for kind in dict.fromkeys(self.layer_kinds)}
 
     def live_pages_by_kind(self) -> Dict[str, int]:
         out = {"full": self.allocator.live_pages}
@@ -742,6 +775,9 @@ class PagedKVPool:
                "pages_free": a.free_pages, "pages_live": a.live_pages,
                "pages_peak": a.peak_live, "pool_bytes": self.bytes(),
                "state_bytes": self.state_bytes(),
+               "layers_by_kind": self.layers_by_kind(),
+               "arenas": {"kv": len(self.k),
+                          "state": len(self.state or ())},
                "alloc_total": a.alloc_total, "cow_total": a.cow_total,
                "headroom": round(a.free_pages / max(a.usable_pages, 1), 4)}
         if self.window_allocator is not None:

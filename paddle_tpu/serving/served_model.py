@@ -77,6 +77,19 @@ contains no model. A model that can be served implements
   the K/V form, built for the layer it serves: it carries the layer's kind
   as ``attend.kind`` (what a block needs to pick its RoPE), and the query's
   own shape says how many heads the layer has — ``num_heads`` is not read.
+  ``"layers"`` may name two more kinds — memory BY LAYER KIND, for a stack
+  whose layers are one mixer each (``NemotronHForCausalLM``): ``"state"``, a
+  layer that keeps a row of the ``state_spec`` arenas and no page, and
+  ``"none"``, a layer that keeps nothing (a position-wise layer: routed
+  experts). The pool builds K/V arenas for the paging layers alone and state
+  arenas for the ``"state"`` layers alone; ``block`` is handed the ``attend``
+  (a paging layer) or the ``state`` (a ``"state"`` layer) its kind has and
+  ``None`` for the other; a page id is one page in each PAGING layer's
+  arena, so admission counts pages for those layers alone. ``"window"`` (and
+  the key of that name) is then optional: with no window layer there is no
+  second allocator and the tables are ``[1, rows, B]``. At least one layer
+  pages (else the spec is ``{"kind": "none"}``), and ``"state"`` layers are
+  named exactly where a ``state_spec`` is declared;
   ``{"kind": "none"}`` (a model whose every layer keeps a recurrent state
   and NOTHING else: Brumby's power retention): a token leaves nothing in
   pages. The pool builds no K/V arena, the window programs take no page
@@ -102,7 +115,7 @@ contains no model. A model that can be served implements
   ``state_spec``. Then a chunk's row and a round's rows are just ``C + S``
   tokens to everything position-wise in ``block``, and only ``attend``
   tells them apart (each cache kind's is written once; the builder's
-  ``land`` and ``call`` split the row). A state model is out (Falcon-H1, Brumby): a prefill
+  ``land`` and ``call`` split the row). A state model is out (Falcon-H1, Brumby, Nemotron-H): a prefill
   starts its state from zero or from its previous chunk's while a round
   advances the slot arenas in place — two conventions in one program; the
   engine sends a round of its own BETWEEN two chunks of such a prompt
@@ -122,7 +135,9 @@ goes in the model's own words (``tools/program_parts.py``), and
 A model with recurrent state cannot use what assumes a cache is pages of
 K/V (the prefix trie, speculative verify, KV-page export/install) — one with
 nothing paged least of all, and the warm tier neither: there is no page to
-share, spill or ship, and no cache of state snapshots is built — a latent
+share, spill or ship, and no cache of state snapshots is built; one that
+keeps state in some layers and pages in others is refused the same four (a
+prefix's pages hold no state) — a latent
 cache cannot yet use what moves K/V pages (export/install and its wire
 format, the warm tier) — with an index row it shares index keys through the
 prefix trie like latent rows (one page table) but refuses a draft model too —
@@ -154,8 +169,10 @@ class ServedModel:
     # None: the only cache is the paged K/V
     state_spec: Optional[Dict[str, Tuple[Tuple[int, ...], Any]]] = None
     # None: a token leaves K and V of [num_kv_heads, head_dim] in a layer;
-    # else {"kind": "latent", ...}, {"kind": "kv_by_layer", ...} or
-    # {"kind": "none"} (nothing paged: the state is the model's memory)
+    # else {"kind": "latent", ...}, {"kind": "kv_by_layer", ...} (with
+    # "layers": what each layer keeps — "full" / "window" pages, "state",
+    # "none") or {"kind": "none"} (nothing paged: the state is the model's
+    # memory)
     cache_spec: Optional[Dict[str, Any]] = None
     # None: the window programs hand back tokens and logprobs alone
     program_counters: Optional[Tuple[str, ...]] = None

@@ -826,3 +826,67 @@ def test_xing4_whole_expert_layer_published_widths(one_chip, monkeypatch,
                     ((64, 3584, 1024), BF16), ((64, 1024, 3584), BF16),
                     names=(), foreign="gmm")
     assert sum(c.startswith("gmm") for c in _CUSTOM_CALL.findall(text)) == 3
+
+
+# Nemotron-3-Nano (``nemotron-3-nano-30b-a3b-d9``): layers of ONE mixer each.
+# A Mamba-2 layer steps a state of [128 slots, 64 heads, 64, 128] with 8 heads
+# a group (a block a (slot, group) of 262 kB where Falcon-H1's is 2.1 MB); the
+# attention layer reads 2 K/V heads of 128 under 32 query heads (16 a K/V
+# head) in a pool of 4097 pages, 64 blocks a row; an expert layer holds ALL
+# 128 two-matrix experts of 2688 x 1856, stored at 1920 lanes, top 6
+def test_nemotron_ssm_step_published_widths(one_chip):
+    from paddle_tpu.kernels.pallas import ssm_step as kssm
+
+    f32 = jnp.float32
+    R, H, P, N, G = 128, 64, 64, 128, 8
+    text = _compile(
+        lambda s, x, dt, a, b, c, d: kssm.ssm_step(s, x, dt, a, b, c, d,
+                                                   impl="pallas"),
+        one_chip, ((R, H, P, N), f32), ((R, H, P), f32), ((R, H), f32),
+        ((H,), f32), ((R, G, N), f32), ((R, G, N), f32), ((H,), f32),
+        names=("pt_ssm_step",))
+    assert "output_to_operand_aliasing" in text
+
+
+@pytest.mark.parametrize("S,W", [(128, 1), (1, 256), (1, 2048)])
+def test_nemotron_ranged_attention_published_widths(one_chip, S, W):
+    from paddle_tpu.kernels.pallas import ranged_paged_attention as kr
+
+    def run(q, ka, va, tables, start):
+        return kr.ranged_paged_attention(q, ka, va, tables, start,
+                                         window=None, scale=128 ** -0.5,
+                                         impl="pallas")
+
+    _compile(run, one_chip, ((S, W, 32, 128), BF16),
+             ((4097, 2, 128, 128), BF16), ((4097, 2, 128, 128), BF16),
+             ((S, 64), jnp.int32), ((S,), jnp.int32),
+             names=("pt_ranged_attention_full",))
+
+
+@pytest.mark.parametrize("tokens", [128, 2048], ids=["round", "chunk"])
+def test_nemotron_ungated_expert_layer_published_widths(one_chip, monkeypatch,
+                                                        tokens):
+    """Every one of the 128 experts held, chosen by the top 6 of score +
+    bias: TWO ``gmm`` calls (no gate), the first handing back float32 — and no
+    copy of a layer's stacked matrices in front of them (at 1856 columns the
+    chip lays ``up`` out with another axis last and copies 1.28 GB a call:
+    ``NemotronHConfig.expert_lanes``)."""
+    from paddle_tpu.kernels import grouped_matmul as gm
+    from paddle_tpu.models import NemotronHConfig
+    from paddle_tpu.nn.layer.moe import moe_held_experts_mlp
+
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    lanes = NemotronHConfig().expert_lanes
+    assert lanes == 1920
+
+    def run(x, x32, router, bias, w_up, w_down):
+        return moe_held_experts_mlp(x, router, None, w_up, w_down, top_k=6,
+                                    first=0, scale=2.5, x_route=x32,
+                                    bias=bias)
+
+    text = _compile(run, one_chip, ((tokens, 2688), BF16),
+                    ((tokens, 2688), jnp.float32), ((2688, 128), jnp.float32),
+                    ((128,), jnp.float32), ((128, 2688, lanes), BF16),
+                    ((128, lanes, 2688), BF16), names=(), foreign="gmm")
+    assert sum(c.startswith("gmm") for c in _CUSTOM_CALL.findall(text)) == 2
+    assert not re.search(r"bf16\[128,\d+,\d+\]\S* copy\(", text)

@@ -14,7 +14,8 @@ from paddle_tpu.models import (BrumbyConfig, BrumbyForCausalLM,
                                FalconH1Config, FalconH1ForCausalLM,
                                GlmMoeDsaConfig, GlmMoeDsaForCausalLM,
                                GPTConfig, GPTForCausalLM, LagunaConfig,
-                               LagunaForCausalLM, OpenPanguMoEConfig,
+                               LagunaForCausalLM, NemotronHConfig,
+                               NemotronHForCausalLM, OpenPanguMoEConfig,
                                OpenPanguMoEForCausalLM, Xing4Config,
                                Xing4ForCausalLM)
 from paddle_tpu.observability.trace import parts
@@ -32,6 +33,7 @@ MODELS = {
     "brumby": (BrumbyForCausalLM, BrumbyConfig.tiny),
     "dots3_note": (Dots3NoteForCausalLM, Dots3NoteConfig.tiny),
     "xing4": (Xing4ForCausalLM, Xing4Config.tiny),
+    "nemotron_h": (NemotronHForCausalLM, NemotronHConfig.tiny),
 }
 # decode, the largest prefill bucket (which carries the round where the
 # model's ``carries_rounds``), and a smaller bucket
@@ -149,9 +151,12 @@ def test_every_heavy_op_sits_under_a_part(lowered, model, program):
     if model == "brumby":   # nothing paged: no cache to write
         want = {"attn_proj", "attention", "norm", "mlp", "head"}
         assert "cache_write" not in used and "mixer" not in used
-    want |= {"mixer"} if model == "falcon_h1" else set()
+    want |= {"mixer"} if model in ("falcon_h1", "nemotron_h") else set()
     want |= {"router", "experts"} if model in (
-        "openpangu", "laguna", "glm_dsa", "dots3_note", "xing4") else set()
+        "openpangu", "laguna", "glm_dsa", "dots3_note", "xing4",
+        "nemotron_h") else set()
+    # the shared expert of a stack whose layers are one mixer each
+    want |= {"mlp"} if model == "nemotron_h" else set()
     assert want <= used <= set(parts.PARTS), used
 
 
@@ -183,7 +188,7 @@ def test_the_retention_is_a_scope_inside_attention_not_a_part(lowered,
     inside = [(op, st) for op, st in ops if "pt.retention" in st.split("/")]
     assert len(inside) > 10
     assert {parts.part_of(st) for _op, st in inside} == {"attention"}
-    assert parts.SUBPARTS == ("indexer", "retention", "mhc")
+    assert parts.SUBPARTS[:3] == ("indexer", "retention", "mhc")
     assert "retention" not in parts.PARTS
     gate = [st for op, st in ops if op == "logistic" or "log_sigmoid" in st]
     assert gate and all(parts.part_of(st) == "attn_proj" for st in gate)
@@ -214,8 +219,30 @@ def test_the_residual_path_is_a_scope_inside_parts_not_a_part(lowered,
 
 
 @pytest.mark.parametrize("program", list(PROGRAMS))
+def test_the_state_space_scan_is_a_scope_inside_the_mixer(lowered, program):
+    """The nested ``pt.ssm_scan`` scope marks a Mamba-2 layer's recurrence —
+    the chunked scan of a prefill, the one step of a round — and nothing
+    else of the layer: every op under it is ``mixer``'s, the in / out
+    projections and the conv are ``mixer``'s outside it, and the expert
+    layers and the attention layer hold none of it."""
+    eng, progs = lowered("nemotron_h")
+    ops = ops_with_name_stacks(progs[program].as_text(debug_info=True))
+    inside = [(op, st) for op, st in ops if "pt.ssm_scan" in st.split("/")]
+    assert len(inside) > 10
+    assert {parts.part_of(st) for _op, st in inside} == {"mixer"}
+    assert "ssm_scan" not in parts.PARTS and "ssm_scan" in parts.SUBPARTS
+    outside = [op for op, st in ops if parts.part_of(st) == "mixer"
+               and "pt.ssm_scan" not in st.split("/")]
+    # in_proj and out_proj of each Mamba-2 layer
+    assert outside.count("dot_general") == 2 * eng._pool.layer_kinds.count(
+        "state")
+    assert not any("pt.router" in st or "pt.experts" in st
+                   or "pt.attention" in st for _op, st in inside)
+
+
+@pytest.mark.parametrize("program", list(PROGRAMS))
 @pytest.mark.parametrize("model", ["openpangu", "laguna", "glm_dsa",
-                                   "dots3_note", "xing4"])
+                                   "dots3_note", "xing4", "nemotron_h"])
 def test_expert_models_programs_hand_back_their_weight_streams(
         lowered, model, program):
     """Every window program of a model with expert layers hands back, beside
