@@ -39,7 +39,7 @@ from ..nn import functional as F
 from ..nn.layer.moe import (HELD_EXPERTS_COUNTERS, _route,
                             held_experts_counters, moe_held_experts_mlp)
 from ..observability.trace.parts import part, subpart
-from ..serving.served_model import ServedModel
+from ..serving.served_model import ServedModel, recur
 from .falcon_h1 import (_mm, _rms, _Weights, causal_conv, gated_norm,
                         mamba_scan)
 
@@ -228,28 +228,37 @@ def layer_keys(cfg: NemotronHConfig, layer: int):
 @part("mixer")
 def _mamba(cfg: NemotronHConfig, p, x, u, state, valid, step: bool):
     """``x + Mamba2(u)``. ``state``: ``None`` (a fresh sequence), a row's own
-    ``{"ssm", "conv"}`` from its previous chunk, or — ``step`` — the slot
-    arenas of a round (one token a row). Returns the stream and the state
-    after the last real token."""
-    R, W, _ = u.shape
+    ``{"ssm", "conv"}`` from its previous chunk, — ``step`` — the slot arenas
+    of a round (one token a row), or the ``Carried`` pair of a program that
+    carries a round: the projections, the gate and its norm run once over the
+    window, the conv and the scan through ``served_model.recur``. Returns the
+    stream and the state after the last real token (of a pair: both)."""
     H, P, N, G = (cfg.mamba_num_heads, cfg.mamba_head_dim,
                   cfg.ssm_state_size, cfg.n_groups)
     d_in, gn = cfg.mamba_inner, G * N
     z, xbc, dt = jnp.split(_mm(u, p["in_w"]), [d_in, d_in + cfg.conv_dim], -1)
-    conv, tail = causal_conv(xbc, None if state is None else state["conv"],
-                             p["conv_w"], p["conv_b"], valid)
-    xs, b, c = jnp.split(jax.nn.silu(conv), [d_in, d_in + gn], -1)
-    dt = jax.nn.softplus(dt + p["dt_bias"].astype(F32))
-    dt = jnp.where(valid[..., None], dt, 0.0)              # [R, W, H]
-    with subpart("ssm_scan"):
-        y, ssm = mamba_scan(
-            xs.reshape(R, W, H, P), dt, -jnp.exp(p["A_log"].astype(F32)),
-            b.reshape(R, W, G, N), c.reshape(R, W, G, N),
-            p["D"].astype(F32), None if state is None else state["ssm"],
-            cfg.chunk_size, step)
-    y = gated_norm(y.reshape(R, W, d_in), z, G, cfg.layer_norm_epsilon,
-                   p["ssm_norm"])
-    return x + _mm(y, p["out_w"]), {"ssm": ssm, "conv": tail}
+
+    def scan(state, step, xbc, dt, valid):
+        # the conv behind its tail, then the recurrence from its state (the
+        # steps' widths between them, in the order every program had them)
+        R, W, _ = xbc.shape
+        conv, tail = causal_conv(xbc, None if state is None
+                                 else state["conv"], p["conv_w"],
+                                 p["conv_b"], valid)
+        xs, b, c = jnp.split(jax.nn.silu(conv), [d_in, d_in + gn], -1)
+        dt = jax.nn.softplus(dt + p["dt_bias"].astype(F32))
+        dt = jnp.where(valid[..., None], dt, 0.0)          # [R, W, H]
+        with subpart("ssm_scan"):
+            y, ssm = mamba_scan(
+                xs.reshape(R, W, H, P), dt, -jnp.exp(p["A_log"].astype(F32)),
+                b.reshape(R, W, G, N), c.reshape(R, W, G, N),
+                p["D"].astype(F32), None if state is None else state["ssm"],
+                cfg.chunk_size, step)
+        return y.reshape(R, W, d_in), {"ssm": ssm, "conv": tail}
+
+    y, state = recur(scan, state, step, xbc, dt, valid)
+    y = gated_norm(y, z, G, cfg.layer_norm_epsilon, p["ssm_norm"])
+    return x + _mm(y, p["out_w"]), state
 
 
 @part("attn_proj")

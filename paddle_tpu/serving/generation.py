@@ -45,7 +45,7 @@ from .base import (BadRequest, DeadlineExceeded, EngineBase, EngineClosed,
                    _oom_guard, _tracer)
 from .paged_kv import (HostPagePool, PagedKVPool, PoolExhausted,
                        latent_width, token_blocks, window_page_bound)
-from .served_model import (GPTServed, ServedModel, flatten_params,
+from .served_model import (Carried, GPTServed, ServedModel, flatten_params,
                            nest_params)
 from .speculative import greedy_accept
 
@@ -148,9 +148,10 @@ class _GenRequest:
 
 class _Slot:
     __slots__ = ("req", "length", "last_token", "t0", "table", "blocks",
-                 "shared", "wtable", "wlo", "whi")
+                 "shared", "wtable", "wlo", "whi", "freed")
 
     def __init__(self, n_blocks: int):
+        self.freed = 0   # which release of the engine's freed it (0: none)
         # a cache of two layer kinds: the window layers' page table, by
         # ABSOLUTE block like ``table``; blocks [wlo, whi) hold a page, the
         # blocks behind the window have given theirs back (entry 0)
@@ -660,7 +661,8 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     the running sequences advance while a prompt is prefilled and the
     layers' weights (the experts' above all) are read once for both. Only a
     one-row prefill of a model whose ``carries_rounds`` is true has one: the
-    cache's kernel takes each row's own range of pages and nothing recurs.
+    cache's kernel takes each row's own range of pages, and what recurs
+    resumes.
 
     ``step(params, k_arenas, v_arenas, tables, tokens, lengths,
     n_valid=None, state=None)`` returns ``(next, logprob, k_arenas,
@@ -678,10 +680,20 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     prompt's table and the round's through theirs, ``call`` runs the layer's
     kernel twice (the chunk's shape, the round's) and joins the results. The
     head runs on ``1 + R`` rows. A round row that is idle has ``n_valid`` 0
-    and an all-zero table: it costs its grid step and no bytes. All of that
-    is Python at trace time (``if R:``): a program that carries nothing
-    traces none of it. The cache has one of four shapes, by the model's
-    ``cache_spec``:
+    and an all-zero table: it costs its grid step and no bytes. For a model
+    that keeps recurrent state ``state`` is a pair under a carry as well, in
+    and out — the prompt's own row from its previous chunk (``None``: the
+    from-zero program) and the slot arenas, both donated: a ``"state"``
+    layer's block is handed its own of both as a ``served_model.Carried``
+    and hands back the chunk's final row and its arenas advanced one step in
+    place (``served_model.recur``: the conv and the scan split at ``W`` as
+    ``call`` splits an attention, nothing else in the block does); a row of
+    the arenas whose ``r_valid`` is 0 — an idle slot, the JOINING one — keeps
+    its state and its tail. All of that is Python at trace time (``if R:``,
+    ``if R and stateful``): a program that carries nothing traces none of
+    it, and one that carries and keeps no state lowers the text it lowered
+    before a state could ride. The cache has one of four shapes, by the
+    model's ``cache_spec``:
 
     - ``None``: K and V arenas ``[pages, page_len, heads, dim]`` a layer,
       ``attend(q, k, v)``, ``kernels.pallas.paged_attention`` (below);
@@ -736,7 +748,8 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     updated, in place. A model whose block RESUMES
     (``ServedModel.resumes_state``) may be handed a ``state`` in a prefill
     too — what the prompt's previous chunk returned, one row, donated — and
-    is told which it holds by ``step=`` (true in a round). A model without
+    is told which it holds by ``step=`` (true in a round); its carrying
+    program takes and returns the pair of both (above). A model without
     state gets and returns ``None``.
 
     Attention is one of the three kernels above: on the TPU the Pallas
@@ -768,8 +781,9 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
         if not (prefill and S == 1 and sm.carries_rounds):
             raise ValueError(
                 "only a one-row prefill of a model whose cache's kernel "
-                "takes each row's own range, and that keeps no recurrent "
-                "state, carries a decode round (ServedModel.carries_rounds)")
+                "takes each row's own range, and whose recurrent state, if "
+                "it keeps one, resumes, carries a decode round "
+                "(ServedModel.carries_rounds)")
     stateful = sm.state_spec is not None
     cache_kind = None if sm.cache_spec is None else sm.cache_spec["kind"]
     latent = cache_kind == "latent"
@@ -859,6 +873,21 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
         ``_attention``), each against its own of every pair (``both``)."""
         return both(kernel, q, *zip(*own)) if R else kernel(q, *own)
 
+    # ``state`` too is a pair under a carry, of a model that keeps one: the
+    # prompt's own row from its previous chunk (``None``: from zero) and the
+    # slot arenas. A state layer's block gets its own of both (``Carried``)
+    # and hands back the chunk's final row and the arenas, advanced
+    def states_of(state):
+        if not (R and stateful):
+            return iter(state or ())
+        return (Carried(row, arenas, W) for row, arenas in zip(
+            state[0] or itertools.repeat(None), state[1]))
+
+    def state_out(new_state):
+        if not stateful:
+            return None
+        return tuple(map(list, zip(*new_state))) if R else new_state
+
     def step(params, k_arenas, v_arenas, tables, tokens, lengths,
              n_valid=None, state=None):
         # tables: [S, B] page ids; tokens: [S, W]; lengths: [S] (int32)
@@ -902,7 +931,7 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
         new_k, new_v, new_state, counted = [], [], [], []
         held, selected, picked = [None, None], [], []
         arenas, values, states = iter(k_arenas), iter(v_arenas), \
-            iter(state or ())
+            states_of(state)
         for li, p in enumerate(params["layers"]):
             kind = kinds[li] if kinds else None
             # a layer that pages nothing (every layer of a cache of kind
@@ -991,7 +1020,7 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
         nxt, logp = _pick(sm.head(params, x))       # [S, W], [S, W] f32
         if R:       # ([1, 1], [R, 1]): the prompt's, then the round's
             nxt, logp = ((a[:, :1], a[0, 1:, None]) for a in (nxt, logp))
-        out = (nxt, logp, new_k, new_v, new_state if stateful else None)
+        out = (nxt, logp, new_k, new_v, state_out(new_state))
         if not counter_names and sparse is None:
             return out
         return out + (_counted(counter_names, counted, sparse,
@@ -1283,6 +1312,7 @@ class GenerationEngine(EngineBase):
                     donate_argnums=(0,) if donate else (), label=ilabel))
 
         self._slots = [_Slot(B) for _ in range(S)]
+        self._releases = itertools.count(1)   # stamps ``_Slot.freed``
         # in-place weight push (post-training): a pending swap applies at
         # the first ZERO-ACTIVE step boundary — admission pauses while it
         # pends so in-flight requests finish on the version they started
@@ -1448,7 +1478,8 @@ class GenerationEngine(EngineBase):
                 # the same bucket from the state a chunk left (``row`` is
                 # donated): the later chunks of a long prompt
                 with span("pt.serve.warmup_program",
-                          label=f"prefill{W}:resume", rows=rows):
+                          label=f"prefill{W}:resume",
+                          rows=rows + int(carries)):
                     nxt, _lp, row, _counted = self._run_window(
                         rows, W, tables, tokens, lengths, n_valid=n_valid,
                         prefill=True, state=row)
@@ -1606,20 +1637,26 @@ class GenerationEngine(EngineBase):
         most one dict: what ``_count_programs`` takes once the call is
         done). For a prefill that carries a round (``_carried_rows``) every
         operand after ``W`` is a pair, the prompt's then the round's, and so
-        are ``next`` and ``logprob``."""
+        are ``next`` and ``logprob`` — and, made here, the state of a model
+        that keeps one: ``state`` beside the slot arenas, which the call
+        advances one step in place as a round of its own does."""
         import jax
         import jax.numpy as jnp
 
         pool, fn = self._pool, self._window(rows, W, prefill)
+        # a state model's carrying call: the row's state AND the arenas
+        pair = bool(prefill and self._stateful and self._carried_rows(W))
         nxt, lp, pool.k, pool.v, state, *counted = fn(
             self._params, pool.k, pool.v,
             None if self._unpaged else tables, tokens, lengths,
             jax.tree_util.tree_map(jnp.asarray, n_valid),
-            state if prefill else pool.state)
-        if prefill:
-            return nxt, lp, state, counted
-        pool.state = state
-        return nxt, lp, None, counted
+            (state, pool.state) if pair else state if prefill
+            else pool.state)
+        if pair:
+            state, pool.state = state
+        elif not prefill:
+            pool.state, state = state, None
+        return nxt, lp, state, counted
 
     def _count_tokens(self, real: int) -> None:
         """What the ``real`` tokens of a program just dispatched add to the
@@ -2192,8 +2229,16 @@ class GenerationEngine(EngineBase):
         return picked
 
     def _free_slot(self) -> Optional[int]:
-        return next((i for i, s in enumerate(self._slots) if s.req is None),
-                    None)
+        """The free slot that has been free the longest (one never used
+        first, the lowest index among equals). So at most ``max_slots``
+        requests that reach a drained engine together are served in slots of
+        their own whatever ends while they join — a carried round can end a
+        short request before the last of them is admitted — and a finished
+        request's row of the state arenas (``slot_state``) is the last one
+        overwritten."""
+        free = [i for i, s in enumerate(self._slots) if s.req is None]
+        return min(free, key=lambda i: (self._slots[i].freed, i),
+                   default=None)
 
     def _worker(self):
         """The continuous-batching loop. Each turn takes ONE window program
@@ -2506,13 +2551,17 @@ class GenerationEngine(EngineBase):
     def _round_between(self, adm: _Admission) -> Optional[_Round]:
         """A decode round of the running sequences, dispatched between two
         chunks of ``adm``'s prompt: for a model with recurrent state whose
-        block resumes, where no call carries a round (``carries_rounds`` is
-        False for every state model) and a prompt of many chunks would else
-        hold every running sequence for all of them. The joining slot has no
-        row; everything before this round is read, so its tokens are the
-        host's. ``None`` where the model is another kind or nothing runs; a
-        fault fails the round's requests alone."""
-        if not (self._stateful and self._sm.resumes_state):
+        block resumes and whose calls carry no round (``carries_rounds``:
+        Brumby), where a prompt of many chunks would else hold every running
+        sequence for all of them. The joining slot has no row; everything
+        before this round is read, so its tokens are the host's — which is
+        why NOTHING goes out behind a call that carried a round: that round
+        is unread, and a second one from the host's tokens and lengths would
+        advance every running sequence's state twice on one token. ``None``
+        too where the model is another kind or nothing runs; a fault fails
+        the round's requests alone."""
+        if not (self._stateful and self._sm.resumes_state) or \
+                adm.carried[-1] is not None:
             return None
         rnd = self._build_round(None, joining=adm)
         if not rnd.rows:
@@ -2970,6 +3019,7 @@ class GenerationEngine(EngineBase):
         s.length = 0
         s.last_token = 0
         s.t0 = 0.0
+        s.freed = next(self._releases)
 
     # -- observability --------------------------------------------------------
     def slot_occupancy(self, window_s: float = 60.0) -> Dict[str, Any]:

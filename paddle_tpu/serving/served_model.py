@@ -109,20 +109,34 @@ contains no model. A model that can be served implements
 - ``carries_rounds`` (derived, never set): whether the prefill program of
   the engine's largest bucket also runs the running sequences' decode step
   (``generation._build_window_step``, ``carry`` > 0 of its one ``step``
-  body: "the carried step"). True when the
-  cache's kernel takes each row's own range of pages and nothing recurs:
-  ``cache_spec`` of kind ``"latent"`` or ``"kv_by_layer"`` and no
-  ``state_spec``. Then a chunk's row and a round's rows are just ``C + S``
-  tokens to everything position-wise in ``block``, and only ``attend``
-  tells them apart (each cache kind's is written once; the builder's
-  ``land`` and ``call`` split the row). A state model is out (Falcon-H1, Brumby, Nemotron-H): a prefill
-  starts its state from zero or from its previous chunk's while a round
-  advances the slot arenas in place — two conventions in one program; the
-  engine sends a round of its own BETWEEN two chunks of such a prompt
-  instead (``GenerationEngine._round_between``). GPT-2 is out: ``pt_paged_attention`` walks every page of every
-  slot whatever the lengths and each of its window programs copies the
-  whole arenas between two layouts; once it moves onto the ranged kernel's
-  layout (ROADMAP S2) it inherits the carried step through this property.
+  body: "the carried step"). The rule, from three declarations and nothing
+  else: the cache's kernel takes each row's own range of pages
+  (``cache_spec`` of kind ``"latent"`` or ``"kv_by_layer"``), and what
+  recurs, if anything does, RESUMES (no ``state_spec``, or
+  ``resumes_state``). Then a chunk's row and a round's rows are just ``C +
+  S`` tokens to everything position-wise in ``block``, and only what keeps
+  memory tells them apart, each written once: ``attend`` (the builder's
+  ``land`` and ``call`` split the row) and, in a ``"state"`` layer, the
+  recurrence — ``block`` is handed ``state`` as a :class:`Carried` PAIR (the
+  prompt's own row from its previous chunk, or ``None``, and the slot
+  arenas; ``step=False``), runs what recurs through :func:`recur`, which
+  splits the window at the chunk's last token — the chunk from the row's
+  state, the round's tokens one step each through the arenas, in place —
+  and returns the pair ``(the chunk's final row, the arenas)``
+  (``NemotronHServed``). A block that resumes already speaks both
+  conventions (``step=``); the pair is the two in one program. Who stays
+  out, and why: Falcon-H1 (``cache_spec`` ``None``: ``pt_paged_attention``
+  walks every page of every slot, and its scan starts from zero, so it does
+  not resume) and GPT-2 (the same kernel; each of its window programs
+  copies the whole arenas between two layouts: once it moves onto the ranged
+  kernel's layout, ROADMAP S2, it inherits the carried step through this
+  property); Brumby (cache kind ``"none"``: it resumes, but no kernel of its
+  takes a round's rows beside a chunk's, its 2048-token program from a state
+  is 15.16 of the chip's 15.2 GB as it is, and its cell sits at its knee:
+  ROADMAP S16). For a state model that resumes and does not carry the engine
+  sends a round of its own BETWEEN two chunks of a prompt
+  (``GenerationEngine._round_between``); behind a call that carried one it
+  sends none.
 
 A block names the PARTS of its work (``observability.trace.parts``: ``norm``,
 ``attn_proj``, ``mlp``, and ``router`` / ``experts`` / ``mixer`` where it has
@@ -149,11 +163,12 @@ verify, export/install, the warm tier): the engine refuses those in words
 from __future__ import annotations
 
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 from ..observability.trace.parts import part
 
-__all__ = ["ServedModel", "GPTServed", "flatten_params", "nest_params"]
+__all__ = ["ServedModel", "GPTServed", "Carried", "recur", "flatten_params",
+           "nest_params"]
 
 
 class ServedModel:
@@ -186,8 +201,9 @@ class ServedModel:
     def carries_rounds(self) -> bool:
         """Whether a prefill call of the largest bucket carries the running
         sequences' decode step (module docstring)."""
-        return self.state_spec is None and self.cache_spec is not None \
-            and self.cache_spec["kind"] in ("latent", "kv_by_layer")
+        ranged = self.cache_spec is not None and \
+            self.cache_spec["kind"] in ("latent", "kv_by_layer")
+        return ranged and (self.state_spec is None or self.resumes_state)
 
     def params(self, model) -> Dict[str, Any]:
         raise NotImplementedError
@@ -200,6 +216,36 @@ class ServedModel:
 
     def head(self, params, x):
         raise NotImplementedError
+
+
+class Carried(NamedTuple):
+    """A ``"state"`` layer's ``state`` in a program that carries a round
+    (``ServedModel.carries_rounds``): the window is the chunk's ``W`` tokens,
+    then one token for each slot of the arenas."""
+    chunk: Any   # the prompt's own row from its previous chunk; None: zero
+    round: Any   # the slot arenas, advanced one step in place
+    W: int
+
+
+def recur(run, state, step, *seqs):
+    """What RECURS in a state layer, under whichever convention the program
+    has: ``run(state, step, *seqs) -> (y, state)`` over ``seqs`` of ``[rows,
+    W, ...]``. A row's own state (or ``None``) or, with ``step``, the slot
+    arenas: ``run`` as it is. A :class:`Carried` pair: the chunk's ``[:, :W]``
+    from the row's state, the round's ``[:, W:]`` turned to ``[R, 1, ...]``
+    one step through the arenas; ``y`` joined as the block handed the window
+    in, and the pair ``(the chunk's final row, the arenas)`` for a state —
+    the split written once, as ``_build_window_step``'s ``call`` is for
+    ``attend``."""
+    if not isinstance(state, Carried):
+        return run(state, step, *seqs)
+    import jax.numpy as jnp
+
+    W = state.W
+    y, row = run(state.chunk, False, *(s[:, :W] for s in seqs))
+    r_y, arenas = run(state.round, True,
+                      *(jnp.swapaxes(s[:, W:], 0, 1) for s in seqs))
+    return jnp.concatenate([y, jnp.swapaxes(r_y, 0, 1)], 1), (row, arenas)
 
 
 def flatten_params(tree) -> Dict[str, Any]:

@@ -1,8 +1,8 @@
 """The carried step (``serving.generation._build_window_step(carry=...)``):
 in an engine whose served model qualifies (``ServedModel.carries_rounds``: a
-latent cache or a cache of two layer kinds, no recurrent state) a prompt's
-prefill call of the LARGEST bucket also runs the running sequences' decode
-step. Greedy token streams must be the ones the same requests get from
+latent cache or a cache by layer kind, and a recurrent state only if it
+resumes) a prompt's prefill call of the LARGEST bucket also runs the running
+sequences' decode step. Greedy token streams must be the ones the same requests get from
 row-only prefill calls and rounds of their own; the counters must add up to
 what was served; and set-up must build as many window programs as it did,
 one signature each, with GPT-2's and Falcon-H1's untouched."""
@@ -13,14 +13,18 @@ import pytest
 
 import paddle_tpu as paddle
 from paddle_tpu import serving
+from paddle_tpu.models.brumby import BrumbyConfig, BrumbyForCausalLM
 from paddle_tpu.models.falcon_h1 import FalconH1Config, FalconH1ForCausalLM
 from paddle_tpu.models.glm_moe_dsa import (GlmMoeDsaConfig,
                                            GlmMoeDsaForCausalLM)
 from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM
 from paddle_tpu.models.laguna import LagunaConfig, LagunaForCausalLM
+from paddle_tpu.models.nemotron_h import (NemotronHConfig,
+                                          NemotronHForCausalLM)
 from paddle_tpu.models.openpangu_moe import (OpenPanguMoEConfig,
                                              OpenPanguMoEForCausalLM)
 from paddle_tpu.serving import generation as gen
+from paddle_tpu.serving import served_model
 from paddle_tpu.serving.paged_kv import PoolExhausted
 
 C = 16                  # the largest bucket: its program carries
@@ -41,6 +45,12 @@ def _tiny(kind):
     if kind == "falcon_h1":
         cfg = FalconH1Config.tiny()
         return cfg, FalconH1ForCausalLM(cfg)
+    if kind == "brumby":     # a state that resumes, NOTHING paged
+        cfg = BrumbyConfig.tiny()
+        return cfg, BrumbyForCausalLM(cfg)
+    if kind == "nemotron_h":  # a state that resumes, pages by layer kind
+        cfg = NemotronHConfig.tiny()
+        return cfg, NemotronHForCausalLM(cfg)
     cfg = GPTConfig.tiny()
     return cfg, GPTForCausalLM(cfg)
 
@@ -234,6 +244,24 @@ def test_an_eos_row_is_the_one_wasted_row(served):
     assert max(a["carried"] for a in chunks) == 2
 
 
+def test_requests_that_arrive_together_get_slots_of_their_own(served):
+    """As many requests as slots, queued together on a drained engine: a
+    carried round ends the first (one token left) while the second's chunks
+    go by, and the third still gets a slot nobody has used — a free slot is
+    the one longest free, so what a request left in its slot (``slot_state``,
+    a check's) outlives its neighbours' admission."""
+    kind, cfg, model = served
+    prompts = _prompts(cfg, (5, 3 * C + 5, 6), 9)
+    eng = _engine(model, max_slots=3)
+    _serve(eng, list(zip(prompts, (2, 3, 3))), [])
+    joined = _spans(eng, "pt.serve.admit")
+    assert [a["prompt_len"] for a in joined] == [len(p) for p in prompts]
+    assert sorted(a["slot"] for a in joined) == [0, 1, 2]
+    # the first had ended before the third joined: its slot was free then
+    assert eng.stats()["counters"]["rounds_carried_total"] >= 1
+    assert eng._free_slot() == 0
+
+
 # -- the set-up: as many programs as ever, one signature each -------------------
 
 def _digest(fn, args) -> str:
@@ -256,13 +284,25 @@ def _operands(eng, rows, W, prefill):
         return jnp.zeros(shape, jnp.int32)
 
     return (eng._params, eng._pool.k, eng._pool.v,
-            i32(*eng._tables_shape(rows)), i32(rows, W), i32(rows),
-            i32(rows), None if prefill else eng._pool.state)
+            None if eng._unpaged else i32(*eng._tables_shape(rows)),
+            i32(rows, W), i32(rows), i32(rows),
+            None if prefill else eng._pool.state)
+
+
+def _carrying_operands(eng):
+    """The largest bucket's operands as its carrying program takes them:
+    every one after the arenas a (prompt's, round's) pair — the state too,
+    of a model that keeps one (from zero: no row beside the arenas)."""
+    S = eng.config.max_slots
+    lone, rnd = _operands(eng, 1, C, True), _operands(eng, S, 1, False)
+    return lone[:3] + tuple(zip(lone[3:7], rnd[3:7])) + (
+        (None, rnd[7]) if eng._stateful else None,)
 
 
 @pytest.mark.parametrize("kind,draft", [
     ("gpt2", False), ("gpt2", True), ("falcon_h1", False),
-    ("openpangu", False), ("laguna", False)])
+    ("openpangu", False), ("laguna", False), ("brumby", False),
+    ("nemotron_h", False)])
 def test_warmup_builds_the_parents_programs_and_requests_none(kind, draft,
                                                               tmp_path):
     """``warmup()`` builds one window program a bucket and one a round (two
@@ -304,7 +344,7 @@ def test_warmup_builds_the_parents_programs_and_requests_none(kind, draft,
         want = {(S, 1, False)} | {(1, b, True) for b in BUCKETS} | \
             ({(S, 4, False)} if draft else set())
         assert set(eng._windows) == want
-        carries = kind in ("openpangu", "laguna")
+        carries = kind in ("openpangu", "laguna", "nemotron_h")
         assert eng._sm.carries_rounds == carries
         assert [eng._carried_rows(b) for b in BUCKETS] == \
             [0, S if carries else 0]
@@ -312,8 +352,11 @@ def test_warmup_builds_the_parents_programs_and_requests_none(kind, draft,
         for key in want:
             role = "prefill" if key[2] else "window"
             row = by_label[f"serving:{eng.name}:{role}{key[1]}"]
-            # one signature: one lookup, whatever form its tokens came in
-            assert row["hits"] + row["misses"] == 1, (key, row)
+            # one signature: one lookup, whatever form its tokens came in —
+            # and a second for a prefill program whose block resumes (from
+            # zero; from the state a chunk left): carrying or not, no more
+            forms = 2 if key[2] and eng._sm.resumes_state else 1
+            assert row["hits"] + row["misses"] == forms, (key, row)
         warm, n_compiles = pc.stats(), len(compiles)
         assert n_compiles > 0
         lens = [(5, 6), (7, 9), (3 * C + 2, 3), (12, 4), (2 * C, 3), (6, 3)]
@@ -336,14 +379,52 @@ def test_warmup_builds_the_parents_programs_and_requests_none(kind, draft,
             pc.enable(old_dir)
 
 
-@pytest.mark.parametrize("kind", ["gpt2", "falcon_h1"])
+# what a served model declares -> whether its largest bucket carries
+QUALIFIES = {
+    "gpt2": (False, False, None, False),
+    "falcon_h1": (True, False, None, False),
+    "brumby": (True, True, "none", False),
+    "nemotron_h": (True, True, "kv_by_layer", True),
+    "openpangu": (False, False, "latent", True),
+    "laguna": (False, False, "kv_by_layer", True),
+    "glm_dsa": (False, False, "latent", True),
+}
+
+
+@pytest.mark.parametrize("kind", sorted(QUALIFIES))
+def test_who_carries_follows_from_three_declarations(kind):
+    """``carries_rounds`` is derived: a cache whose kernel takes each row's
+    own range (``cache_spec["kind"]`` latent or by layer), and a recurrent
+    state only if the block resumes it. Nemotron-H carries; Falcon-H1 (no
+    ranged cache, does not resume), Brumby (resumes, nothing paged) and GPT-2
+    do not. The property reads nothing else: the same three facts on a bare
+    ``ServedModel`` give the same answer, and no attribute sets it."""
+    _cfg, model = _tiny(kind)
+    sm = gen._served(model)
+    stateful, resumes, cache, carries = QUALIFIES[kind]
+    assert (sm.state_spec is not None, sm.resumes_state,
+            (sm.cache_spec or {}).get("kind")) == (stateful, resumes, cache)
+    assert sm.carries_rounds is carries
+    bare = served_model.ServedModel()
+    bare.state_spec, bare.resumes_state = sm.state_spec, resumes
+    bare.cache_spec = None if cache is None else {"kind": cache}
+    assert bare.carries_rounds is carries
+    with pytest.raises(AttributeError):
+        bare.carries_rounds = True
+    # a state that does NOT resume keeps any cache out
+    bare.state_spec, bare.resumes_state = {"s": ((1,), "float32")}, False
+    assert not bare.carries_rounds
+
+
+@pytest.mark.parametrize("kind", ["gpt2", "falcon_h1", "brumby"])
 def test_models_that_do_not_qualify_keep_their_programs(kind):
-    """GPT-2 (``pt_paged_attention`` walks every page of every slot) and
-    Falcon-H1 (a prefill starts its state from zero, a round advances it in
-    place) do not qualify: the text the engine lowers for each of their
-    window programs is the text of the builder with the carry switched off
-    at the call, letter for letter — and asking the builder for a carrying
-    program of theirs is refused."""
+    """GPT-2 (``pt_paged_attention`` walks every page of every slot),
+    Falcon-H1 (the same kernel, and a prefill starts its state from zero)
+    and Brumby (a state that resumes, but nothing paged: no kernel of its
+    takes a round's rows beside a chunk's) do not qualify: the text the
+    engine lowers for each of their window programs is the text of the
+    builder with the carry switched off at the call, letter for letter — and
+    asking the builder for a carrying program of theirs is refused."""
     cfg, model = _tiny(kind)
     eng = _engine(model, prefix_cache=kind == "gpt2")
     assert not eng._sm.carries_rounds
@@ -378,11 +459,83 @@ def test_only_the_largest_bucket_of_a_qualifying_model_grew(served):
     lone = _operands(eng, 1, C, True)
     plain = gen._build_window_step(eng._sm, 1, eng._n_blocks, eng._pl, C,
                                    eng._donate, label="plain", prefill=True)
-    pair = lone[:3] + tuple(zip(lone[3:7], _operands(eng, S, 1, False)[3:7])) \
-        + (None,)
+    pair = _carrying_operands(eng)
     assert _digest(eng._window(1, C, True), pair) != _digest(plain, lone)
     with pytest.raises(Exception):
         eng._window(1, C, True)(*lone)   # no row-only form stands beside it
+
+
+def _unreachable(*_a, **_kw):
+    raise AssertionError("the state arm was reached")
+
+
+def test_a_carrying_program_without_state_is_what_it_was(served, monkeypatch):
+    """The state pair is one more arm of the ONE ``step`` body, Python at
+    trace time: with that arm made unreachable — whatever builds a state
+    layer's pair raises — the builder still gives a latent and a by-layer
+    model WITHOUT state their carrying program, and its lowered text is the
+    engine's own, letter for letter: nothing of the arm is traced for them."""
+    kind, cfg, model = served
+    eng = _engine(model)
+    S = eng.config.max_slots
+    pair = _carrying_operands(eng)
+    mine = _digest(eng._window(1, C, True), pair)
+
+    monkeypatch.setattr(gen, "Carried", _unreachable)
+    sealed = gen._build_window_step(
+        eng._sm, 1, eng._n_blocks, eng._pl, C, eng._donate, label="sealed",
+        prefill=True, carry=S, aligned=eng._aligned)
+    assert _digest(sealed, pair) == mine
+
+
+def test_a_state_models_carrying_program_takes_the_state_as_a_pair(
+        monkeypatch):
+    """Nemotron-H's largest bucket: ONE program in the row-only one's place,
+    in both its forms (from zero; from the state a chunk left), each taking
+    the (row, arenas) pair and handing back the chunk's final row and the
+    arenas; its decode program and its smaller bucket are the builder's with
+    the carry off; and with the arm unreachable its build fails — the arm is
+    what carries its state."""
+    import jax
+    import jax.numpy as jnp
+
+    cfg, model = _tiny("nemotron_h")
+    eng = _engine(model, page_len=4)
+    S = eng.config.max_slots
+    assert [eng._carried_rows(b) for b in BUCKETS] == [0, S]
+    for rows, W, prefill in [(S, 1, False), (1, BUCKETS[0], True)]:
+        args = _operands(eng, rows, W, prefill)
+        plain = gen._build_window_step(
+            eng._sm, rows, eng._n_blocks, eng._pl, W, eng._donate,
+            label="plain", prefill=prefill, carry=0)
+        assert _digest(eng._window(rows, W, prefill), args) == \
+            _digest(plain, args)
+    from paddle_tpu.jit import lowerable
+
+    zero = _carrying_operands(eng)
+    fn = eng._window(1, C, True)
+
+    def state_out(args):
+        return jax.tree_util.tree_map(
+            lambda a: str(a.shape), lowerable(fn).lower(*args).out_info[4])
+
+    arenas = jax.tree_util.tree_map(lambda a: str(a.shape), eng._pool.state)
+    a_row = jax.tree_util.tree_map(lambda a: str((1,) + a.shape[1:]),
+                                   eng._pool.state)
+    assert state_out(zero) == (a_row, arenas)
+    row = jax.tree_util.tree_map(
+        lambda a: jnp.zeros((1,) + a.shape[1:], a.dtype), eng._pool.state)
+    resumed = zero[:7] + ((row, zero[7][1]),)
+    assert _digest(fn, resumed) != _digest(fn, zero)
+    assert state_out(resumed) == (a_row, arenas)
+    with pytest.raises(Exception):
+        fn(*_operands(eng, 1, C, True))   # no row-only form beside it
+    monkeypatch.setattr(gen, "Carried", _unreachable)
+    sealed = gen._build_window_step(
+        eng._sm, 1, eng._n_blocks, eng._pl, C, eng._donate, label="sealed",
+        prefill=True, carry=S, aligned=eng._aligned)
+    with pytest.raises(AssertionError, match="state arm was reached"):
+        lowerable(sealed).lower(*zero)
 
 
 def test_pool_exhausted_is_what_the_lie_raises(served):

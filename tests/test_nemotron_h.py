@@ -2,7 +2,8 @@
 relu^2 experts beside a shared one, or a grouped-query attention with no
 rotary embedding) at ``NemotronHConfig.tiny()`` on seeded weights: the model,
 the engine's memory by layer kind with chunks that resume the state-space
-state, and the ungated expert layer's held share, against the plain reference
+state and, in the largest bucket, CARRY the running sequences' round, and the
+ungated expert layer's held share, against the plain reference
 (``paddle_tpu/models/reference/nemotron_h.py``: the recurrence a token at a
 time)."""
 import hashlib
@@ -18,6 +19,7 @@ from paddle_tpu.models import NemotronHConfig, NemotronHForCausalLM
 from paddle_tpu.models import falcon_h1, nemotron_h
 from paddle_tpu.models.reference import nemotron_h as ref
 from paddle_tpu.nn.layer import moe
+from test_carried_step import _spans
 
 PARITY = 2e-4      # the tolerance of every comparison with the reference
 
@@ -40,11 +42,18 @@ def tiny():
     return (cfg, nemotron_h.as_dict(cfg)) + _build(cfg)
 
 
-def _engine(model, **over):
+def _engine(model, carry=True, **over):
     kw = dict(max_slots=4, max_seq_len=128, page_len=4,
               prefill_buckets=(8, 12), prefix_cache=False)
     kw.update(over)
-    return serving.GenerationEngine(model, serving.GenerationConfig(**kw))
+    eng = serving.GenerationEngine(model, serving.GenerationConfig(**kw))
+    if not carry:
+        # switched off at the call (no user-facing flag): every prefill
+        # program is built row-only and a round goes out BETWEEN two chunks
+        eng._carried_rows = lambda W: 0
+    return eng
+
+
 
 
 def _state_close(got, want):
@@ -204,22 +213,28 @@ def served(tiny):
     cfg, _c, model, _params, _get = tiny
     eng = _engine(model)
     eng.warmup()
-    eng.start()
     rng = np.random.default_rng(1)
     prompts = [rng.integers(0, cfg.vocab_size, n)
                for n in (5, 40, 23, 49, 17, 33)]
+    # all six are queued before the worker's first turn: what rides which
+    # call is then the schedule's and not the clock's
+    eng.start = lambda: eng
     futs = [eng.submit(p, max_new_tokens=4 + i, return_logprobs=True)
             for i, p in enumerate(prompts)]
+    del eng.start
+    eng.start()
     results = [f.result(timeout=300) for f in futs]
     stats = eng.stats()
     eng.close()
-    return eng, prompts, results, stats
+    return eng, prompts, results, stats, \
+        _spans(eng, "pt.serve.prefill_chunk"), \
+        _spans(eng, "pt.serve.decode_round")
 
 
 def test_prefill_then_decode_through_the_by_layer_pool_is_the_references_forward(
         tiny, served):
     _cfg, c, _model, _params, get = tiny
-    _eng, prompts, results, _stats = served
+    _eng, prompts, results, _stats, _chunks, _rounds = served
     for (full, lps), p in zip(results, prompts):
         assert len(full) == len(p) + len(lps)
         want, _chosen, _st = ref.next_token_logprobs(get, c, np.asarray(full),
@@ -233,7 +248,7 @@ def test_the_pool_keeps_memory_by_layer_kind(tiny, served):
     on the three Mamba-2 layers, nothing on the expert layers; admission
     counts pages for the attention layer alone."""
     cfg, _c, _model, _params, _get = tiny
-    eng, prompts, results, stats = served
+    eng, prompts, results, stats, chunks, rounds = served
     pool, kv = eng._pool, stats["kv_pages"]
     assert pool.layer_kinds == ["state", "none", "state", "full", "none",
                                 "state"]
@@ -260,7 +275,19 @@ def test_the_pool_keeps_memory_by_layer_kind(tiny, served):
     assert c["prefill_chunks_total"] == 1 + 4 + 2 + 5 + 2 + 3
     assert c["state_resumes_total"] == 3 + 1 + 4 + 1 + 2
     assert c["state_installs_total"] == c["prefills_total"] == 6
-    assert c.get("rounds_carried_total", 0) == 0
+    # every call of the largest bucket is the carrying program's — 40 -> 3,
+    # 23 -> 2 (its remainder of 11 too), 49 -> 4, 17 -> 1, 33 -> 3 — and with
+    # the six queued behind one another each found a sequence running: a
+    # round rode it, and no round went out between two chunks
+    assert [a["W"] for a in chunks].count(12) == 3 + 2 + 4 + 1 + 3
+    assert all(a["carried"] == 0 for a in chunks if a["W"] == 8)
+    carried = [a["carried"] for a in chunks if a["carried"]]
+    assert c["rounds_carried_total"] == len(carried) == 13
+    assert c["decode_steps"] == len(carried) + len(rounds)
+    assert c["slot_rounds"] == sum(carried) + sum(a["n_active"]
+                                                  for a in rounds)
+    assert c["tokens_total"] == c["slot_rounds"] == \
+        sum(len(lps) - 1 for _full, lps in results)
     consumed = sum(len(p) for p in prompts) + \
         sum(len(lps) - 1 for _full, lps in results)
     assert c["moe_pairs_total"] == c["moe_held_pairs_total"] == \
@@ -275,23 +302,29 @@ def test_the_pool_keeps_memory_by_layer_kind(tiny, served):
 
 
 def _one(model, prompt, new, **over):
-    eng = _engine(model, max_slots=1, **over)
+    eng = _engine(model, **{"max_slots": 1, **over})
     with eng:
         full, lps = eng.submit(prompt, max_new_tokens=new,
                                return_logprobs=True).result(timeout=300)
     return eng, np.asarray(full), np.asarray(lps)
 
 
-def test_a_prompt_in_chunks_is_the_prompt_whole(tiny):
+@pytest.mark.parametrize("carry", [True, False])
+def test_a_prompt_in_chunks_is_the_prompt_whole(tiny, carry):
     """37 tokens through buckets of 12 — chunk edges at 12, 24, 36: off the
     scan's chunks of 8, the last call ONE token — against the same prompt in
     one 64-token call: the same tokens, logprobs and final state (SSM state
-    and conv tail) to float32 rounding, and both the reference's."""
+    and conv tail) to float32 rounding, and both the reference's. ``carry``:
+    every chunk is the CARRYING program's (two idle slots' rows ride it, and
+    the joining slot's own has no live row) — or the row-only one's."""
     cfg, c, model, _params, get = tiny
     prompt = np.random.default_rng(2).integers(0, cfg.vocab_size, 37)
-    chunked, full_c, lp_c = _one(model, prompt, 5, prefill_buckets=(12,))
+    chunked, full_c, lp_c = _one(model, prompt, 5, prefill_buckets=(12,),
+                                 max_slots=3, carry=carry)
+    assert chunked._carried_rows(12) == (3 if carry else 0)
+    assert chunked._sm.carries_rounds
     whole, full_w, lp_w = _one(model, prompt, 5, prefill_buckets=(64,),
-                               max_seq_len=128)
+                               max_seq_len=128, carry=False)
     cc, cw = (e.stats()["counters"] for e in (chunked, whole))
     assert cc["prefill_chunks_total"] == 4 and cc["state_resumes_total"] == 3
     assert cw["prefill_chunks_total"] == 1 and \
@@ -305,34 +338,83 @@ def test_a_prompt_in_chunks_is_the_prompt_whole(tiny):
         _state_close(a, r)
 
 
+@pytest.mark.parametrize("carry", [True, False])
 def test_a_round_between_two_chunks_leaves_the_joining_slots_state_alone(
-        tiny):
-    """One sequence decodes while a long prompt joins: rounds go out between
-    the prompt's chunks and both come out as the reference says — a round
-    that advanced the joining slot's row or tail, or a chunk that started
-    from zero, would show."""
+        tiny, carry):
+    """One sequence decodes while a long prompt joins a slot whose last
+    tenant left a state behind: the running sequence's rounds RIDE the
+    prompt's chunks (``carry``: the one bucket is the largest) or go out
+    between them (the carry switched off at the call: what a state model
+    that does not qualify gets). Both come out as the reference says — a
+    round that advanced the joining slot's row or tail, a chunk that started
+    from zero, or a round sent twice would show — and the joining slot's row
+    of every arena is, at its install, bit for bit what it was at its join;
+    every decode step is counted once, carried or not."""
     cfg, c, model, _params, get = tiny
-    eng = _engine(model, max_slots=2, max_seq_len=160, prefill_buckets=(8,),
-                  max_queue=16)
+    eng = _engine(model, carry=carry, max_slots=2, max_seq_len=160,
+                  prefill_buckets=(8,), max_queue=16)
+    assert eng._carried_rows(8) == (2 if carry else 0)
+    rows, join, install = {}, eng._join, eng._install_state
+
+    def row_of(slot_no):
+        return [{k: np.asarray(a[slot_no]) for k, a in layer.items()}
+                for layer in eng._pool.state]
+
+    def joined(adm):
+        join(adm)
+        rows[adm.req] = [row_of(adm.slot_no)]
+
+    def installed(slot_no, row):
+        rows[eng._slots[slot_no].req].append(row_of(slot_no))
+        install(slot_no, row)
+
+    eng._join, eng._install_state = joined, installed
     eng.start()
     rng = np.random.default_rng(5)
+    # both slots' rows hold a tenant's state before anybody joins them
+    for f in [eng.submit(rng.integers(0, cfg.vocab_size, 9),
+                         max_new_tokens=3) for _ in range(2)]:
+        f.result(timeout=300)
     first = eng.submit(rng.integers(0, cfg.vocab_size, 6), max_new_tokens=60,
                        return_logprobs=True)
-    while eng.stats()["counters"].get("decode_steps", 0) < 2:
+    while eng.stats()["counters"].get("decode_steps", 0) < 6:
         pass
-    before = eng.stats()["counters"]["decode_steps"]
+    before = eng.stats()["counters"]
     long = rng.integers(0, cfg.vocab_size, 61)     # 8 chunks, the last of 5
     second = eng.submit(long, max_new_tokens=4, return_logprobs=True)
     out2, lp2 = second.result(timeout=300)
     out1, lp1 = first.result(timeout=300)
     counters = eng.stats()["counters"]
     eng.close()
-    assert counters["state_resumes_total"] == 7
-    assert counters["decode_steps"] - before >= 7
+    chunks = _spans(eng, "pt.serve.prefill_chunk")
+    rounds = _spans(eng, "pt.serve.decode_round")
+    assert counters["state_resumes_total"] == 7 + 2     # (9 tokens: 2 calls)
+    assert counters["decode_steps"] - before["decode_steps"] >= 7
     for full, lps, n in ((out1, lp1, 6), (out2, lp2, 61)):
         want, _ch, _st = ref.next_token_logprobs(get, c, np.asarray(full),
                                                  128)
         np.testing.assert_allclose(lps, want[n - 1:], rtol=0, atol=PARITY)
+    # the long prompt's slot: its last tenant's state, untouched by the seven
+    # or eight rounds that went by, until the install wrote the prompt's own
+    at_join, at_install = next(v for req, v in rows.items()
+                               if len(req.prompt) == 61)
+    assert len(at_join) == 3                  # the three Mamba-2 layers
+    for was, then in zip(at_join, at_install):
+        for k in ("ssm", "conv"):
+            assert np.abs(was[k]).max() > 0
+            np.testing.assert_array_equal(then[k], was[k])
+    # a call carries at most one round, and a round is one step: counted once
+    carried = [a["carried"] for a in chunks]
+    assert carried[-8:] == ([1] * 8 if carry else [0] * 8)  # the long one's
+    assert counters.get("rounds_carried_total", 0) == \
+        sum(n > 0 for n in carried)
+    # (a round between two chunks is read outside any round's span: the
+    # long prompt's seven gaps had one each, a carrying engine's none)
+    between = counters["decode_steps"] - len(rounds) - \
+        counters.get("rounds_carried_total", 0)
+    assert between == 0 if carry else between >= 7
+    assert counters["tokens_total"] == counters["slot_rounds"] == \
+        2 + 2 + 59 + 3
 
 
 # -- refusals, in words ---------------------------------------------------------
