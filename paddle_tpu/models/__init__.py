@@ -38,3 +38,6 @@ from .xing4 import (  # noqa: F401
 from .nemotron_h import (  # noqa: F401
     NemotronHConfig, NemotronHForCausalLM, NemotronHBlock, NemotronHServed,
 )
+from .zaya1 import (  # noqa: F401
+    Zaya1Config, Zaya1ForCausalLM, Zaya1Block, Zaya1Served,
+)

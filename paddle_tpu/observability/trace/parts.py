@@ -22,7 +22,11 @@ inside ``attention``; ``pt.mhc``: the residual path of a model whose stream is
 several rows — a sublayer's mixing maps and the mix itself, inside
 ``attn_proj`` and ``mlp``; ``pt.ssm_scan``: a Mamba-2 layer's recurrence —
 the chunked scan of a prefill, the one-step kernel of a round — inside
-``mixer``): it marks work INSIDE parts without being one. The ten parts stay a partition of the
+``mixer``; ``pt.cca_mix``: what a compressed convolutional attention does to
+its latent queries and keys between the projections and the cache — the two
+causal convolutions, the q-k mean, the L2 norm and temperature, the partial
+RoPE — inside ``attn_proj``): it marks work INSIDE parts without being one.
+The ten parts stay a partition of the
 step — a reader of ``PARTS`` skips a ``pt.`` name outside its vocabulary and
 finds the part around it, so the indexer's projections are still ``attn_proj``
 and its scores ``attention`` — and one reader of its own
@@ -58,7 +62,7 @@ __all__ = ["PARTS", "SUBPARTS", "STEP_PARTS", "PHASES", "PREFIX", "part",
 
 PARTS = ("embed", "norm", "attn_proj", "cache_write", "attention", "mlp",
          "router", "experts", "mixer", "head")
-SUBPARTS = ("indexer", "retention", "mhc", "ssm_scan")
+SUBPARTS = ("indexer", "retention", "mhc", "ssm_scan", "cca_mix")
 STEP_PARTS = ("stack", "optimizer")
 PHASES = ("forward", "recompute", "backward")
 PREFIX = "pt."

@@ -43,7 +43,7 @@ from ..observability.trace.parts import part, subpart
 from ..observability.trace.request_trace import span
 from .base import (BadRequest, DeadlineExceeded, EngineBase, EngineClosed,
                    _oom_guard, _tracer)
-from .paged_kv import (HostPagePool, PagedKVPool, PoolExhausted,
+from .paged_kv import (LAYER_KEEPS, HostPagePool, PagedKVPool, PoolExhausted,
                        latent_width, token_blocks, window_page_bound)
 from .served_model import (Carried, GPTServed, ServedModel, flatten_params,
                            nest_params)
@@ -683,8 +683,9 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     and an all-zero table: it costs its grid step and no bytes. For a model
     that keeps recurrent state ``state`` is a pair under a carry as well, in
     and out — the prompt's own row from its previous chunk (``None``: the
-    from-zero program) and the slot arenas, both donated: a ``"state"``
-    layer's block is handed its own of both as a ``served_model.Carried``
+    from-zero program) and the slot arenas, both donated: the block of a
+    layer that keeps state (``"state"``, or ``"full+state"``: then beside
+    its ``attend``) is handed its own of both as a ``served_model.Carried``
     and hands back the chunk's final row and its arenas advanced one step in
     place (``served_model.recur``: the conv and the scan split at ``W`` as
     ``call`` splits an attention, nothing else in the block does); a row of
@@ -792,11 +793,21 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     # layers of several kinds (``"layers"``: the K/V form, or a latent cache
     # that declares its layers' kinds): the paging kinds have a pool and a
     # table each ("full", then "window" where there is one), a "state" layer
-    # keeps a row of the state arenas and a "none" layer nothing — and
-    # ``k_arenas`` / ``v_arenas`` hold the paging layers' arenas alone,
-    # ``state`` the "state" layers' (every layer's where no kind is declared)
+    # keeps a row of the state arenas, a "full+state" layer both and a "none"
+    # layer nothing — and ``k_arenas`` / ``v_arenas`` hold the paging layers'
+    # arenas alone, ``state`` the layers' that keep a row (every layer's
+    # where no kind is declared)
     kinds = list((sm.cache_spec or {}).get("layers") or ())
     table_kinds = ("full", "window") if "window" in kinds else ("full",)
+    # by layer: the paging kind whose table and pool it uses (``None``: its
+    # kind pages nothing, or the cache names no kinds), whether it gets an
+    # ``attend``, whether it keeps a row — a "full+state" layer both
+    if kinds:
+        paging, keeping = zip(*map(LAYER_KEEPS.get, kinds))
+        paged = [kind is not None for kind in paging]
+    else:
+        paging, keeping, paged = ([v] * sm.num_layers for v in (
+            None, stateful, not unpaged))
     counter_names = sm.program_counters
     # a model whose block resumes is told which of its two state conventions
     # a program uses: the slot arenas of a round, or a row's own state
@@ -830,7 +841,7 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
             sparse = _Sparse(sm, attends, prefill, bool(R) or not prefill)
     elif by_layer:
         ranged = {kind: _attention(sm, attends, kind)
-                  for kind in sorted(set(kinds) & set(table_kinds))}
+                  for kind in sorted(set(paging) & set(table_kinds))}
     elif not unpaged:
         paged_attend = _attention(sm, attends, "paged")
 
@@ -933,12 +944,10 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
         arenas, values, states = iter(k_arenas), iter(v_arenas), \
             states_of(state)
         for li, p in enumerate(params["layers"]):
-            kind = kinds[li] if kinds else None
             # a layer that pages nothing (every layer of a cache of kind
             # "none"; a "state" or "none" layer): no arena, no table, and no
             # ``attend``
-            pages = not unpaged and kind not in ("state", "none")
-            keeps = stateful and kind in (None, "state")
+            kind, pages, keeps = paging[li], paged[li], keeping[li]
             kc = next(arenas) if pages else None
             vc = None if latent or not pages else next(values)
             # the layer's own table and places: its kind's, where there are two
@@ -1121,11 +1130,12 @@ class GenerationEngine(EngineBase):
         if self._by_layer and not self._windowed and \
                 self.config.warm_pool_bytes:
             # (the prefix cache and a draft model were refused above: such a
-            # cache has a "state" layer; docs/serving.md, "Memory by layer
-            # kind")
+            # cache has a layer that keeps state; docs/serving.md, "Memory by
+            # layer kind")
             raise ValueError(
                 f"{type(model).__name__} keeps pages in some of its layers "
-                "and a recurrent state in others: the warm tier spills and "
+                "and a recurrent state in others (or both in one): the warm "
+                "tier spills and "
                 "restores prefixes of pages, and a prefix's state is in none "
                 "— pass GenerationConfig(warm_pool_bytes=0)")
         if self._windowed:
@@ -1221,8 +1231,9 @@ class GenerationEngine(EngineBase):
                     f"{S * self._wbound + chunk + 1}")
         if self._by_layer:
             # the layers of each paging kind (a table and a pool each)
+            paging = [LAYER_KEEPS[kind][0] for kind in kinds]
             self._layers_of = {
-                kind: kinds.count(kind)
+                kind: paging.count(kind)
                 for kind in ("full", "window")[:1 + self._windowed]}
             # a page as the ranged kernel sees it: K/V heads, tokens, head
             # size, bytes an element (``_count_walk``; a latent page is rows)

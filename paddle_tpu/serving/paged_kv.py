@@ -34,6 +34,7 @@ import threading
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 __all__ = ["PoolExhausted", "PageAllocator", "PrefixCache", "PagedKVPool",
+           "LAYER_KEEPS",
            "HostPagePool", "token_blocks", "window_page_bound"]
 
 
@@ -438,6 +439,24 @@ def window_page_bound(window: int, tokens: int, page_len: int) -> int:
     return -(-(int(window) + int(tokens)) // int(page_len)) + 1
 
 
+# What a layer of each declared kind (``cache_spec["layers"]``) keeps: the
+# paging kind whose pool and table hold its pages (``None``: it pages nothing)
+# and whether it keeps a row of the ``state_spec`` arenas. ``"full+state"`` is
+# a layer of BOTH memories: a page for every ``page_len`` tokens AND a row by
+# slot (an attention whose keys are mixed over the sequence before they are
+# cached: the conv's tail is the row). ``layers_by_kind`` counts from this
+# table alone: a kind's name is only a name.
+LAYER_KEEPS = {"full": ("full", False), "window": ("window", False),
+               "state": (None, True), "none": (None, False),
+               "full+state": ("full", True)}
+
+
+def _paging(kinds) -> List[str]:
+    """The paging kind of each layer of ``kinds`` that pages, in order: one
+    K/V (or latent) arena each."""
+    return [pages for pages, _row in map(LAYER_KEEPS.get, kinds) if pages]
+
+
 def latent_width(dim: int) -> int:
     """Columns of a latent arena: ``dim`` rounded up to whole 128-lane
     tiles (the TPU lays a row out so anyway; a page is DMA'd whole)."""
@@ -486,11 +505,11 @@ class PagedKVPool:
         # (``cache_spec["layers"]``): a "full" layer's arenas hold the pool's
         # pages, a "window" layer's ``window_pages`` pages of an allocator of
         # their own; a "state" layer keeps no page but a row of the state
-        # arenas below, a "none" layer nothing at all
+        # arenas below, a "none" layer nothing at all, a "full+state" layer a
+        # full layer's pages AND a row (``LAYER_KEEPS``)
         kinds = self._kinds(cache_spec, num_layers, window_pages,
-                            prefix_cache or warm_pool is not None,
-                            state_spec) or [None] * num_layers
-        kinds = [kind for kind in kinds if kind not in ("state", "none")]
+                            prefix_cache or warm_pool is not None, state_spec)
+        kinds = [None] * num_layers if kinds is None else _paging(kinds)
         pages_of = [window_pages if kind == "window" else num_pages
                     for kind in kinds]
         if cache_spec is None:
@@ -542,13 +561,13 @@ class PagedKVPool:
         # conv tail [slots, d_conv - 1, channels]. Donated into every
         # program like the K/V arenas and updated in place; a row is
         # overwritten whole when its slot is admitted. None: K/V only. Where
-        # the cache declares its layers' kinds, the "state" layers alone have
-        # one (in their order), else every layer.
+        # the cache declares its layers' kinds, the layers that keep a row
+        # alone have one (in their order), else every layer.
         self.state = None if state_spec is None else [
             {name: jnp.zeros((int(max_slots),) + tuple(shape), dt)
              for name, (shape, dt) in state_spec.items()}
             for _ in range(num_layers if self.layer_kinds is None
-                           else self.layer_kinds.count("state"))]
+                           else self.layers_by_kind().get("state", 0))]
 
     def _kinds(self, cache_spec, num_layers: int, window_pages: int,
                shares_pages: bool, state_spec) -> Optional[List[str]]:
@@ -559,17 +578,18 @@ class PagedKVPool:
         if kinds is None:
             return None
         kinds = list(kinds)
-        if len(kinds) != num_layers or \
-                set(kinds) - {"full", "window", "state", "none"}:
+        if len(kinds) != num_layers or set(kinds) - set(LAYER_KEEPS):
             raise ValueError(
                 f"cache_spec['layers'] must name {num_layers} layers "
-                f"'full' or 'window' (pages), 'state' or 'none', got {kinds}")
-        if ("state" in kinds) != (state_spec is not None):
+                "'full' or 'window' (pages), 'state' or 'none', or "
+                f"'full+state' (pages and a state row), got {kinds}")
+        keeps = [LAYER_KEEPS[kind] for kind in kinds]
+        if any(row for _pages, row in keeps) != (state_spec is not None):
             raise ValueError(
-                "cache_spec['layers'] names a 'state' layer exactly where the "
-                f"model declares a state_spec: got {kinds} and state_spec "
-                f"{state_spec}")
-        if not set(kinds) & {"full", "window"}:
+                "cache_spec['layers'] names a layer that keeps state "
+                "('state', 'full+state') exactly where the model declares a "
+                f"state_spec: got {kinds} and state_spec {state_spec}")
+        if not any(pages for pages, _row in keeps):
             raise ValueError(
                 f"cache_spec['layers'] {kinds} pages nothing: a model with "
                 "nothing paged declares a cache_spec of kind 'none'")
@@ -730,8 +750,7 @@ class PagedKVPool:
         where it declares two (``latent_full`` / ``latent_window``); the
         state arenas as ``"state"`` where some layers are declared to keep
         one."""
-        kinds = [kind for kind in self.layer_kinds or ["full"] * len(self.k)
-                 if kind in ("full", "window")]
+        kinds = _paging(self.layer_kinds or ["full"] * len(self.k))
         if self.cache_spec is not None and self.cache_spec.get("index"):
             names = [f"latent_{kind}" for kind in kinds] \
                 if self.layer_kinds else ["latent"] * len(self.k)
@@ -743,19 +762,24 @@ class PagedKVPool:
         for arenas in (self.k, self.v):
             for kind, a in zip(kinds, arenas):
                 out[kind] += int(a.nbytes)
-        if "state" in (self.layer_kinds or ()):
+        if self.layer_kinds and self.state:
             out["state"] = self.state_bytes()
         return out
 
     def layers_by_kind(self) -> Dict[str, int]:
         """How many layers keep what: ``{"full": 1, "state": 4, "none": 4}``
-        for a cache that declares its layers' kinds, else every layer under
-        the one kind the cache has."""
+        for a cache that declares its layers' kinds (a ``"full+state"`` layer
+        under both its memories: ``{"full": 20, "state": 20}``), else every
+        layer under the one kind the cache has."""
         if self.layer_kinds is None:
             one = "kv" if self.cache_spec is None else self.cache_spec["kind"]
             return {one: len(self.k) or len(self.state or ())}
-        return {kind: self.layer_kinds.count(kind)
-                for kind in dict.fromkeys(self.layer_kinds)}
+        out: Dict[str, int] = {}
+        for pages, row in map(LAYER_KEEPS.get, self.layer_kinds):
+            names = [pages] * bool(pages) + ["state"] * row
+            for name in names or ["none"]:
+                out[name] = out.get(name, 0) + 1
+        return out
 
     def live_pages_by_kind(self) -> Dict[str, int]:
         out = {"full": self.allocator.live_pages}

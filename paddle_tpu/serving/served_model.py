@@ -33,6 +33,17 @@ contains no model. A model that can be served implements
   positions that hold a real token: the recurrence must not advance on the
   others (a padded prefill bucket, an idle decode row);
 - ``head(params, x) -> logits``: final norm and output head;
+- THE STREAM BETWEEN BLOCKS is ``x`` and nothing else, but its trailing
+  width is the model's: what must flow from layer to layer beside the
+  residual stream rides as further COLUMNS of ``x`` — ``Xing4Served``'s four
+  residual rows (``4 x hidden``), ``Zaya1Served``'s router representation
+  (the last ``router_hidden_size`` columns: layer ``l``'s router reads layer
+  ``l - 1``'s; ``embed`` starts them at zero, every block reads and rewrites
+  them, ``head`` reads the first ``hidden`` alone). A wider ``x`` and not a
+  pytree, because the engine's own reads of ``x`` are position-wise
+  (``_last_real``, the carried step's ``x[:, W:]``) and never touch the last
+  axis: no line of the engine knows of it. It is per token and per call,
+  never cached;
 - ``state_spec``: ``None``, or ``{name: (per-slot shape, dtype)}`` — the
   slot-indexed arenas the engine keeps per layer beside the paged K/V;
 - ``cache_spec``: what ONE token leaves in a layer's paged cache. ``None``
@@ -87,9 +98,17 @@ contains no model. A model that can be served implements
   ``None`` for the other; a page id is one page in each PAGING layer's
   arena, so admission counts pages for those layers alone. ``"window"`` (and
   the key of that name) is then optional: with no window layer there is no
-  second allocator and the tables are ``[1, rows, B]``. At least one layer
-  pages (else the spec is ``{"kind": "none"}``), and ``"state"`` layers are
-  named exactly where a ``state_spec`` is declared;
+  second allocator and the tables are ``[1, rows, B]``. And a fifth kind,
+  ``"full+state"``: a layer of BOTH memories — a full layer's pages AND a
+  row of the ``state_spec`` arenas (``Zaya1ForCausalLM``: an attention whose
+  keys are mixed over the sequence by causal convolutions before they are
+  cached, so that beside its pages a slot keeps the conv's tail). The pool
+  builds K/V arenas and state arenas for it, admission counts its pages as a
+  full layer's, and ``block`` is handed BOTH its ``attend`` (``attend.kind``
+  ``"full"``) and its ``state`` (``paged_kv.LAYER_KEEPS`` says what each
+  kind keeps). At least one layer pages (else the spec is ``{"kind":
+  "none"}``), and a ``state_spec`` is declared exactly where some layer
+  keeps state (``"state"``, ``"full+state"``);
   ``{"kind": "none"}`` (a model whose every layer keeps a recurrent state
   and NOTHING else: Brumby's power retention): a token leaves nothing in
   pages. The pool builds no K/V arena, the window programs take no page
@@ -117,7 +136,8 @@ contains no model. A model that can be served implements
   S`` tokens to everything position-wise in ``block``, and only what keeps
   memory tells them apart, each written once: ``attend`` (the builder's
   ``land`` and ``call`` split the row) and, in a ``"state"`` layer, the
-  recurrence — ``block`` is handed ``state`` as a :class:`Carried` PAIR (the
+  recurrence (or, in a ``"full+state"`` layer, BOTH: ``attend`` and a pair) —
+  ``block`` is handed ``state`` as a :class:`Carried` PAIR (the
   prompt's own row from its previous chunk, or ``None``, and the slot
   arenas; ``step=False``), runs what recurs through :func:`recur`, which
   splits the window at the chunk's last token — the chunk from the row's
@@ -150,8 +170,8 @@ A model with recurrent state cannot use what assumes a cache is pages of
 K/V (the prefix trie, speculative verify, KV-page export/install) — one with
 nothing paged least of all, and the warm tier neither: there is no page to
 share, spill or ship, and no cache of state snapshots is built; one that
-keeps state in some layers and pages in others is refused the same four (a
-prefix's pages hold no state) — a latent
+keeps state in some layers and pages in others — or both in one layer — is
+refused the same four (a prefix's pages hold no state, no conv tail) — a latent
 cache cannot yet use what moves K/V pages (export/install and its wire
 format, the warm tier) — with an index row it shares index keys through the
 prefix trie like latent rows (one page table) but refuses a draft model too —
@@ -186,8 +206,8 @@ class ServedModel:
     # None: a token leaves K and V of [num_kv_heads, head_dim] in a layer;
     # else {"kind": "latent", ...}, {"kind": "kv_by_layer", ...} (with
     # "layers": what each layer keeps — "full" / "window" pages, "state",
-    # "none") or {"kind": "none"} (nothing paged: the state is the model's
-    # memory)
+    # "none", "full+state") or {"kind": "none"} (nothing paged: the state is
+    # the model's memory)
     cache_spec: Optional[Dict[str, Any]] = None
     # None: the window programs hand back tokens and logprobs alone
     program_counters: Optional[Tuple[str, ...]] = None
@@ -219,7 +239,7 @@ class ServedModel:
 
 
 class Carried(NamedTuple):
-    """A ``"state"`` layer's ``state`` in a program that carries a round
+    """A state-keeping layer's ``state`` in a program that carries a round
     (``ServedModel.carries_rounds``): the window is the chunk's ``W`` tokens,
     then one token for each slot of the arenas."""
     chunk: Any   # the prompt's own row from its previous chunk; None: zero

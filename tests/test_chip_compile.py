@@ -890,3 +890,68 @@ def test_nemotron_ungated_expert_layer_published_widths(one_chip, monkeypatch,
                     ((128, lanes, 2688), BF16), names=(), foreign="gmm")
     assert sum(c.startswith("gmm") for c in _CUSTOM_CALL.findall(text)) == 2
     assert not re.search(r"bf16\[128,\d+,\d+\]\S* copy\(", text)
+
+
+# ZAYA1-8B (``zaya1-8b-d20``): every layer keeps K/V pages of 2 heads x 128
+# AND a conv tail by slot ([256 slots, 2688] float32), 8 query heads (4 a K/V
+# head), all 16 experts of 2048 x 2048 held, top-1. Two of the published
+# layers, the engine's own programs: the 256-row round and the 2048-token
+# chunk that carries it, from the tail the chunk before it left
+@pytest.mark.parametrize("program", ["round", "chunk2048+round:resume"])
+def test_zaya1_programs_copy_no_arena_of_either_kind(one_chip, monkeypatch,
+                                                     program):
+    """The compiled text of the decode round and of the 2048-token carrying
+    program at the published widths: the ranged kernel and the three grouped
+    matmuls a layer by name, the K/V arenas and the tail arenas updated in
+    place — no copy of a whole arena of pages or of tails."""
+    import dataclasses
+
+    from paddle_tpu.jit import lowerable
+    from paddle_tpu.kernels import grouped_matmul as gm
+    from paddle_tpu.kernels import registry
+    from paddle_tpu.models import Zaya1Config
+    from paddle_tpu.serving import generation as gen
+
+    monkeypatch.setattr(gm, "_on_tpu", lambda: True)
+    monkeypatch.setattr(registry, "_backend", lambda: "tpu")
+    L, S, P, PL, B = 2, 256, 513, 128, 48
+    sm = dataclasses.replace(Zaya1Config(), num_hidden_layers=L,
+                             layer_types=("hybrid",) * L).served_model()
+    assert sm.carries_rounds and sm.cache_spec["layers"] == ["full+state"] * L
+
+    def sd(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    def i32(*s):
+        return sd(s, jnp.int32)
+
+    params = jax.tree_util.tree_map(lambda a: sd(a.shape, a.dtype),
+                                    sm.param_shapes())
+    page, tail = (P, 2, PL, 128), (S, sm.cfg.tail_dim)
+    arena = [sd(page, BF16) for _ in range(L)]
+    tails = [{"tail": sd(tail, jnp.float32)} for _ in range(L)]
+    if program == "round":
+        step = gen._build_window_step(sm, S, B, PL, 1, True, label="aot:z",
+                                      attends={})
+        ops = (i32(1, S, B), i32(S, 1), i32(S), i32(S), tails)
+    else:
+        step = gen._build_window_step(sm, 1, B, PL, 2048, True,
+                                      label="aot:z", prefill=True, carry=S,
+                                      attends={})
+        pair = zip((i32(1, 1, B), i32(1, 2048), i32(1), i32(1)),
+                   (i32(1, S, B), i32(S, 1), i32(S), i32(S)))
+        row = [{"tail": sd((1, sm.cfg.tail_dim), jnp.float32)}
+               for _ in range(L)]
+        ops = tuple(pair) + ((row, tails),)
+    text = lowerable(step).lower(params, arena, arena, *ops).compile() \
+        .as_text()
+    calls = [c for c in _CUSTOM_CALL.findall(text)]
+    twice = 1 if program == "round" else 2      # the chunk's and the round's
+    assert sum("pt_ranged_attention_full" in c for c in calls) == twice * L
+    assert sum(c.startswith("gmm") for c in calls) == 3 * L
+    assert "_unknown_" not in text
+    for dtype, shape in (("bf16", page), ("f32", tail)):
+        dims = ",".join(map(str, shape))
+        assert not re.search(rf"= {dtype}\[{dims}\]\S* copy\(", text), shape
+    # an expert layer's stacked matrices are read where they lie
+    assert not re.search(r"bf16\[16,2048,2048\]\S* copy\(", text)

@@ -17,7 +17,8 @@ from paddle_tpu.models import (BrumbyConfig, BrumbyForCausalLM,
                                LagunaForCausalLM, NemotronHConfig,
                                NemotronHForCausalLM, OpenPanguMoEConfig,
                                OpenPanguMoEForCausalLM, Xing4Config,
-                               Xing4ForCausalLM)
+                               Xing4ForCausalLM, Zaya1Config,
+                               Zaya1ForCausalLM)
 from paddle_tpu.observability.trace import parts
 
 MODELS = {
@@ -34,6 +35,7 @@ MODELS = {
     "dots3_note": (Dots3NoteForCausalLM, Dots3NoteConfig.tiny),
     "xing4": (Xing4ForCausalLM, Xing4Config.tiny),
     "nemotron_h": (NemotronHForCausalLM, NemotronHConfig.tiny),
+    "zaya1": (Zaya1ForCausalLM, Zaya1Config.tiny),
 }
 # decode, the largest prefill bucket (which carries the round where the
 # model's ``carries_rounds``), and a smaller bucket
@@ -154,7 +156,7 @@ def test_every_heavy_op_sits_under_a_part(lowered, model, program):
     want |= {"mixer"} if model in ("falcon_h1", "nemotron_h") else set()
     want |= {"router", "experts"} if model in (
         "openpangu", "laguna", "glm_dsa", "dots3_note", "xing4",
-        "nemotron_h") else set()
+        "nemotron_h", "zaya1") else set()
     # the shared expert of a stack whose layers are one mixer each
     want |= {"mlp"} if model == "nemotron_h" else set()
     assert want <= used <= set(parts.PARTS), used
@@ -241,8 +243,37 @@ def test_the_state_space_scan_is_a_scope_inside_the_mixer(lowered, program):
 
 
 @pytest.mark.parametrize("program", list(PROGRAMS))
+def test_the_conv_mixing_is_a_scope_inside_the_projections(lowered, program):
+    """The nested ``pt.cca_mix`` scope marks what a compressed convolutional
+    attention does between its projections and the cache — the two causal
+    convs behind the tail, the q-k mean, the norm and temperature, the
+    partial RoPE — and nothing else of the layer: every op under it is
+    ``attn_proj``'s (a norm inside is the mixing's own: no weight), the
+    latent and output projections are ``attn_proj``'s outside it, and the
+    router, the experts and the attention hold none of it."""
+    eng, progs = lowered("zaya1")
+    ops = ops_with_name_stacks(progs[program].as_text(debug_info=True))
+    inside = [(op, st) for op, st in ops if "pt.cca_mix" in st.split("/")]
+    assert len(inside) > 10
+    assert {parts.part_of(st) for _op, st in inside} == {"attn_proj"}
+    assert "cca_mix" not in parts.PARTS and "cca_mix" in parts.SUBPARTS
+    layers = len(eng._pool.layer_kinds)
+    # the second conv is two grouped matmuls a layer — a chunk's and a
+    # round's where the program carries one
+    convs = [op for op, _st in inside].count("dot_general")
+    assert convs in (2 * layers, 4 * layers)
+    outside = [op for op, st in ops if parts.part_of(st) == "attn_proj"
+               and "pt.cca_mix" not in st.split("/")]
+    # Wq | Wk, Wv1 | Wv2 and Wo of each layer
+    assert outside.count("dot_general") == 3 * layers
+    assert not any("pt.router" in st or "pt.experts" in st
+                   or "pt.attention" in st for _op, st in inside)
+
+
+@pytest.mark.parametrize("program", list(PROGRAMS))
 @pytest.mark.parametrize("model", ["openpangu", "laguna", "glm_dsa",
-                                   "dots3_note", "xing4", "nemotron_h"])
+                                   "dots3_note", "xing4", "nemotron_h",
+                                   "zaya1"])
 def test_expert_models_programs_hand_back_their_weight_streams(
         lowered, model, program):
     """Every window program of a model with expert layers hands back, beside
