@@ -203,7 +203,7 @@ class _Round:
     on the host and its outputs, unread, on the device."""
 
     __slots__ = ("rows", "k", "tokens", "lengths", "tables", "nxt", "lp",
-                 "counters")
+                 "counters", "pool_bound")
 
     def __init__(self, rows, k, tokens, lengths, tables):
         self.rows: List[Tuple[int, _GenRequest]] = rows
@@ -211,6 +211,9 @@ class _Round:
         self.tokens, self.lengths, self.tables = tokens, lengths, tables
         self.nxt = self.lp = None
         self.counters: List[Dict[str, Any]] = []
+        # it went out ahead with a slot free, because the pool held none of
+        # the prompts that waited (``_run_ahead``)
+        self.pool_bound = False
 
 
 def _unread(flying) -> Dict[int, Tuple[_GenRequest, int]]:
@@ -2212,9 +2215,12 @@ class GenerationEngine(EngineBase):
         return max(window_page_bound(self._win, widest, self._pl),
                    self._wbound)
 
-    def _next_request(self) -> Optional[_GenRequest]:
+    def _next_request(self) -> Tuple[Optional[_GenRequest], bool]:
         """Shed expired queued requests, then pick the earliest-deadline
-        queued request whose KV pages can be allocated right now."""
+        queued request whose KV pages can be allocated right now. Beside the
+        pick, whether the pool BINDS: prompts wait and it can hold none of
+        them — a statement about the whole queue, not its head, made under
+        the lock that an arrival takes."""
         now = time.monotonic()
         shed: List[_GenRequest] = []
         picked: Optional[_GenRequest] = None
@@ -2231,13 +2237,14 @@ class GenerationEngine(EngineBase):
                     self._queue.remove(r)
                     picked = r
                     break
+            bound = picked is None and bool(self._queue)
         for r in shed:  # outside the lock: future callbacks may re-submit
             self.metrics.inc("shed_total")
             if not r.future.done():
                 r.future.set_exception(DeadlineExceeded(
                     "deadline expired while queued"))
             _tracer().finish(r.trace, ok=False, error="DeadlineExceeded")
-        return picked
+        return picked, bound
 
     def _free_slot(self) -> Optional[int]:
         """The free slot that has been free the longest (one never used
@@ -2257,7 +2264,10 @@ class GenerationEngine(EngineBase):
         dispatch to the host's read of its result, and dispatches the program
         after it BEFORE that read wherever what comes next does not hang on
         the result (``_run_ahead``): the device then starts the next program
-        the moment this one ends instead of idling for a host round trip.
+        the moment this one ends instead of idling for a host round trip. A
+        round goes out so with no slot free, or with a slot free and prompts
+        waiting of which the pool holds none, unless a request ends behind
+        the unread program; with a slot free and nobody waiting nothing does.
         ``prog`` is the program that went out ahead of its turn, if one did;
         with none in flight the worker is at a boundary and decides with
         everything read, as it always did. Prompts are admitted back to back
@@ -2280,7 +2290,7 @@ class GenerationEngine(EngineBase):
                 # earliest deadline first, bounded by KV page headroom)
                 if self._pending_swap is None:
                     free = self._free_slot()
-                    req = None if free is None else self._next_request()
+                    req = None if free is None else self._next_request()[0]
                     if req is not None:
                         prog = _Admission(free, req)
             if isinstance(prog, _Admission):
@@ -2332,16 +2342,24 @@ class GenerationEngine(EngineBase):
           the round that would otherwise have to wait behind it
           (``_carried_round``), so a chunked admission holds nobody and
           owes nobody a round;
-        - with no slot free, and none to come free when ``flying`` is read, no
-          prompt can join whatever arrives, so a round comes next. Every
-          request's remaining budget is known here; one that ends on EOS
-          instead is the one wasted row: ``_emit_round`` drops its extra
-          token.
+        - with no slot free, OR with a slot free and prompts waiting of which
+          the pool holds none (``_next_request`` picked nothing from a queue
+          that is not empty, or the pick's join met ``PoolExhausted`` and is
+          back at the queue's front), and neither a slot nor a page to come
+          free when ``flying`` is read, no prompt can join whatever
+          arrives, so a round comes next. Every request's remaining budget
+          is known here: a round behind which one ends is NOT sent — its
+          pages come back at the read and a prompt that waits may then fit,
+          so the worker reads first. One that ends on EOS instead is the one
+          wasted row: ``_emit_round`` drops its extra token, and a prompt its
+          pages make room for joins one round later. ``pool_bound`` marks the
+          round that went out under the second condition
+          (``rounds_ahead_pool_bound_total``).
 
         Either takes the tokens ``flying`` has not handed over from its own
         output on the device (``_round_feed``).
 
-        With a slot free and nothing to admit NOTHING goes out: the next
+        With a slot free and NOBODY waiting nothing goes out: the next
         arrival would otherwise prefill behind a round dispatched on a guess
         and pay up to that round in first-token latency. A draft model's
         proposals cross the host, so speculative decoding keeps one program
@@ -2356,24 +2374,26 @@ class GenerationEngine(EngineBase):
                 return None
         free = self._free_slot()
         if free is not None:
-            req = self._next_request()
-            if req is None:
+            req, pool_bound = self._next_request()
+            if req is not None:
+                adm = _Admission(free, req)
+                try:
+                    self._join(adm)
+                    self._send_chunk(adm, flying,
+                                     self._carried_round(adm, flying))
+                    return adm
+                except PoolExhausted:
+                    self._requeue(req)
+                    pool_bound = True
+                except Exception as e:  # isolate: fail this prompt only
+                    self._fail_admission(adm, e)
+                    return None
+            if not pool_bound:
                 return None
-            adm = _Admission(free, req)
-            try:
-                self._join(adm)
-                self._send_chunk(adm, flying,
-                                 self._carried_round(adm, flying))
-            except PoolExhausted:
-                self._requeue(req)
-                return None
-            except Exception as e:  # isolate: fail this prompt only
-                self._fail_admission(adm, e)
-                return None
-            return adm
         rnd = self._build_round(flying)
         if not rnd.rows or len(rnd.rows) < len(self._active()):
             return None
+        rnd.pool_bound = free is not None
         try:
             self._send_round(rnd, flying)
         except Exception as e:
@@ -2831,6 +2851,8 @@ class GenerationEngine(EngineBase):
         self._count_tokens(len(rnd.rows) * (k + 1))
         if flying is not None:
             self.metrics.inc("programs_run_ahead_total")
+        if rnd.pool_bound:
+            self.metrics.inc("rounds_ahead_pool_bound_total")
 
     def _fail_rows(self, rows, e: Exception) -> None:
         """A fault in a round fails the requests it was to advance (those
@@ -2863,7 +2885,8 @@ class GenerationEngine(EngineBase):
         after = None
         try:
             with span("pt.serve.decode_round", n_active=n_active, W=k + 1,
-                      ahead=int(ahead)):
+                      ahead=int(ahead),
+                      pool_bound=int(ahead and rnd.pool_bound)):
                 t_dec = time.monotonic()
                 if not ahead:
                     rnd = self._build_round()

@@ -547,10 +547,11 @@ def test_pool_exhausted_is_what_the_lie_raises(served):
     futs = [eng.submit(p, max_new_tokens=4)
             for p in _prompts(cfg, (3 * C, 2 * C), 1)]
     del eng.start
-    eng._join(gen._Admission(0, eng._next_request()))
-    assert eng._next_request() is None      # 7 of 9 pages are taken
+    eng._join(gen._Admission(0, eng._next_request()[0]))
+    # 7 of 9 pages are taken: a prompt waits and the pool holds none
+    assert eng._next_request() == (None, True)
     _lies_once(eng)
     with pytest.raises(PoolExhausted):
-        eng._join(gen._Admission(1, eng._next_request()))
+        eng._join(gen._Admission(1, eng._next_request()[0]))
     assert eng._free_slot() == 1            # the slot stayed free
     del futs

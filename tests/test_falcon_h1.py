@@ -150,6 +150,48 @@ def test_a_prefill_run_ahead_leaves_its_state_for_the_round_behind_it(tiny):
     assert c["decode_steps"] == 5 and c["slot_rounds"] == 4 + 3 + 5
 
 
+def test_rounds_ahead_of_a_bound_pool_leave_an_idle_rows_state_alone(tiny):
+    """Four slots, a pool of six usable pages, four requests of three pages
+    each, queued together: two run, two wait, two slots are free — the
+    rounds go out ahead because the pool holds neither of those that wait,
+    with idle rows for the free slots and, later, for the slots of requests
+    that have ended. Every request is served in a slot of its own (the slot
+    longest free goes first), so each one's final state can be read once
+    the engine is closed: had a round sent ahead stepped an idle row, the
+    rows of the two that ended first would have moved on from the
+    reference's. Logprobs are the reference's too."""
+    cfg, model, _params, get = tiny
+    rng = np.random.default_rng(23)
+    lens, outs = (6, 13, 10, 5), (14, 9, 12, 17)   # 3 pages of 8 each
+    prompts = [rng.integers(0, cfg.vocab_size, n) for n in lens]
+    eng = _engine(model, max_slots=4, num_pages=7)
+    eng.start = lambda: eng
+    futs = [eng.submit(p, max_new_tokens=n, return_logprobs=True)
+            for p, n in zip(prompts, outs)]
+    del eng.start
+    with eng:
+        done = [f.result(timeout=300) for f in futs]
+    assert [s for s, _t0, _t1, _n in sorted(eng._slot_hist,
+                                            key=lambda h: h[1])] == \
+        [0, 1, 2, 3]
+    for slot, (p, (full, lps)) in enumerate(zip(lens, done)):
+        want, states = ref.next_token_logprobs(
+            get, _cfg_dict(cfg), full, 64, vocab_slices=3, with_state=True)
+        np.testing.assert_allclose(lps, want[p - 1:], atol=2e-5)
+        for got, st in zip(eng.slot_state(slot), states):
+            np.testing.assert_allclose(got["ssm"], st["ssm"], atol=2e-5)
+            np.testing.assert_allclose(got["conv"], st["conv"], atol=2e-5)
+    c = eng.stats()["counters"]
+    assert c["state_installs_total"] == 4
+    # rounds 1-8 behind the second call and each other, until the second
+    # request's budget ends; the third joins with that read, and rounds
+    # 9-13 go out ahead of the fourth, until the first one's ends. Then
+    # nobody waits and nothing goes out ahead
+    assert c["rounds_ahead_pool_bound_total"] == 8 + 5
+    assert c["programs_run_ahead_total"] == \
+        1 + c["rounds_ahead_pool_bound_total"]
+
+
 def _prefill(sm, params, prompt, W, B=8, PL=8):
     P = B + 1
     arena = [jnp.zeros((P, PL, sm.num_kv_heads, sm.head_dim), jnp.float32)

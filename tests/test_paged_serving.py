@@ -576,14 +576,14 @@ def test_prefill_window_tokens_counter_and_fill_rate(tiny_lm):
 
 # -- one window program in flight ---------------------------------------------
 
-def _queued_then_started(eng, jobs, on_first_token=None):
+def _queued_then_started(eng, jobs, on_first_token=None, **submit):
     """Every request is queued before the worker's first turn (``submit``
     would start it on the first): the schedule is the same in every run.
     ``on_first_token`` streams the first job's tokens."""
     eng.start = lambda: eng
     try:
         futs = [eng.submit(p.astype("int64"), max_new_tokens=m,
-                           on_token=None if i else on_first_token)
+                           on_token=None if i else on_first_token, **submit)
                 for i, (p, m) in enumerate(jobs)]
     finally:
         del eng.start
@@ -702,6 +702,174 @@ def test_a_fault_in_a_round_run_ahead_fails_that_rounds_requests(tiny_lm):
     assert c["responses_total"] == 1 and stats["active_slots"] == 0
     # rounds 0 and 1 were read (the second for nobody), then the survivor's
     assert c["decode_steps"] == 2 + 2 and c["tokens_total"] == 2 + 2
+
+
+# -- run-ahead when the pool's pages bind before its slots ----------------------
+
+def _pool_bound_engine(model, name, **over):
+    """Four slots over a pool of four usable pages: a request of two pages
+    (up to 16 tokens, prompt and budget) shares it with one other, so two
+    slots stay free for good while a queue stands."""
+    kw = dict(max_slots=4, max_seq_len=32, page_len=8, num_pages=5,
+              prefill_buckets=(8, 16), prefix_cache=False)
+    kw.update(over)
+    return serving.GenerationEngine(model, serving.GenerationConfig(**kw),
+                                    name=name)
+
+
+def _programs(eng):
+    """The window programs the worker ran, in order: ``(kind, args)``."""
+    from paddle_tpu.observability.trace import tracer
+
+    return [(r["name"].rsplit(".", 1)[1], r["args"])
+            for r in tracer().worker_spans(thread=f"pt-serving-{eng.name}")
+            if r["name"] in ("pt.serve.decode_round",
+                             "pt.serve.prefill_chunk")]
+
+
+def test_rounds_run_ahead_where_the_pool_holds_none_of_the_prompts_that_wait(
+        tiny_lm):
+    """Five prompts of two pages each before a pool of four: two run, three
+    wait, two slots are free all along. ``_next_request`` picks nobody from
+    a queue that is not empty, so the round is decided and goes out before
+    the one in front is read — until a budget ends behind the unread round:
+    that one is read first, and the prompt its pages make room for joins at
+    that boundary. Tokens and logprobs are those of the same engine serving
+    one prompt at a time (nothing can run ahead there) and ``generate``'s."""
+    model, pattern = tiny_lm
+    jobs = [(pattern[:3], 12), (pattern[:6], 9), (pattern[:5], 10),
+            (pattern[:4], 8), (pattern[:7], 6)]
+    eng = _pool_bound_engine(model, "poolbound")
+    futs = _queued_then_started(eng, jobs, return_logprobs=True)
+    with eng:
+        got = [f.result(timeout=300) for f in futs]
+        stats = eng.stats()
+    alone = _pool_bound_engine(model, "poolbound_alone")
+    with alone:
+        for (p, m), (full, lps) in zip(jobs, got):
+            want, want_lps = alone.submit(
+                p.astype("int64"), max_new_tokens=m,
+                return_logprobs=True).result(timeout=300)
+            assert full.tolist() == want.tolist() == \
+                _greedy(model, p, m).tolist()
+            np.testing.assert_allclose(lps, want_lps, atol=1e-5)
+        assert "programs_run_ahead_total" not in alone.stats()["counters"]
+    c = stats["counters"]
+    assert c["prefill_chunks_total"] == 5 and c["tokens_total"] == 45 - 5
+    # b's call behind a's; then every round but the five behind which a
+    # budget ended (the last four run beside an empty queue)
+    assert c["decode_steps"] == 22
+    assert c["rounds_ahead_pool_bound_total"] == 17
+    assert c["programs_run_ahead_total"] == 1 + 17
+    assert stats["run_ahead_rate"] >= 0.6
+    progs = _programs(eng)
+    assert sum(a.get("pool_bound", 0) for _k, a in progs) == 17
+    assert all(a["ahead"] for _k, a in progs if a.get("pool_bound"))
+    # c, d and e joined at a boundary, with everything read
+    assert [a["ahead"] for k, a in progs if k == "prefill_chunk"] == \
+        [0, 1, 0, 0, 0]
+    assert eng._pool.allocator.live_pages == 0
+
+
+def test_a_round_behind_which_a_budget_ends_waits_for_the_read(tiny_lm):
+    """``a`` has four tokens to give, ``b`` nine, ``c`` waits for pages.
+    Rounds 1–3 go out ahead (the pool holds no ``c``); ``a``'s budget ends
+    with round 3, so NOTHING goes out behind it: round 3 is read, ``a``'s
+    pages come back and ``c`` joins at that boundary — its prefill call is
+    the very next program, not a round later. From there a slot is free and
+    nobody waits: nothing goes out ahead again."""
+    model, pattern = tiny_lm
+    jobs = [(pattern[:3], 4), (pattern[:6], 9), (pattern[:5], 10)]
+    eng = _pool_bound_engine(model, "poolbound_budget")
+    futs = _queued_then_started(eng, jobs, return_logprobs=True)
+    with eng:
+        got = [f.result(timeout=300)[0] for f in futs]
+        stats = eng.stats()
+    for (p, m), full in zip(jobs, got):
+        assert full.tolist() == _greedy(model, p, m).tolist()
+    kinds = [(k, a["ahead"], a.get("pool_bound", 0))
+             for k, a in _programs(eng)]
+    assert kinds[:6] == [("prefill_chunk", 0, 0), ("prefill_chunk", 1, 0),
+                         ("decode_round", 1, 1), ("decode_round", 1, 1),
+                         ("decode_round", 1, 1), ("prefill_chunk", 0, 0)]
+    assert all(k == ("decode_round", 0, 0) for k in kinds[6:])
+    c = stats["counters"]
+    assert c["rounds_ahead_pool_bound_total"] == 3
+    assert c["programs_run_ahead_total"] == 1 + 3
+    assert c["decode_steps"] == 3 + 9 and c["tokens_total"] == 23 - 3
+
+
+def test_a_full_pool_and_an_empty_queue_send_nothing_ahead(tiny_lm):
+    """The first-token rule where the PAGES are gone: two requests hold the
+    whole pool, two slots are free and nobody waits. Not one round goes out
+    on a guess — an arrival the pool could hold after the unread round
+    would prefill behind it."""
+    model, pattern = tiny_lm
+    jobs = [(pattern[:3], 7), (pattern[:6], 9)]
+    eng = _pool_bound_engine(model, "poolbound_calm")
+    futs = _queued_then_started(eng, jobs, return_logprobs=True)
+    with eng:
+        for (p, m), f in zip(jobs, futs):
+            assert f.result(timeout=300)[0].tolist() == \
+                _greedy(model, p, m).tolist()
+        stats = eng.stats()
+    c = stats["counters"]
+    assert c["decode_steps"] == 8 and c["prefill_chunks_total"] == 2
+    assert c["programs_run_ahead_total"] == 1  # b's call, behind a's
+    assert "rounds_ahead_pool_bound_total" not in c
+    assert all(not a["ahead"] and not a["pool_bound"]
+               for k, a in _programs(eng) if k == "decode_round")
+
+
+def test_a_join_that_meets_pool_exhausted_is_requeued_and_the_round_goes_out(
+        tiny_lm):
+    """The rarer way in: ``can_allocate`` says yes (here it lies once) and
+    the join behind it raises ``PoolExhausted``. The prompt goes back to the
+    FRONT of the queue, the slot stays free and the round goes out all the
+    same; the schedule and the tokens are those of the honest pool."""
+    model, pattern = tiny_lm
+    jobs = [(pattern[:3], 4), (pattern[:6], 9), (pattern[:5], 10),
+            (pattern[:4], 6)]
+    runs = {}
+    for lie in (False, True):
+        eng = _pool_bound_engine(model, f"poolbound_lie{int(lie)}")
+        requeued = []
+        if lie:
+            real, requeue, told = eng._pool.can_allocate, eng._requeue, []
+
+            def can_allocate(*a, **kw):
+                ok = real(*a, **kw)
+                if not ok and not told:
+                    told.append(1)
+                    return True
+                return ok
+
+            def at_the_front(req):
+                requeue(req)
+                requeued.append((eng._queue[0] is req, len(eng._queue),
+                                 eng._free_slot()))
+
+            eng._pool.can_allocate, eng._requeue = can_allocate, at_the_front
+            with pytest.raises(PoolExhausted):  # what the lie leads to
+                eng._pool.allocate(5)
+        futs = _queued_then_started(eng, jobs, return_logprobs=True)
+        with eng:
+            got = [f.result(timeout=300)[0].tolist() for f in futs]
+            runs[lie] = (got, eng.stats()["counters"], _programs(eng))
+        if lie:
+            # c was picked behind b's call, met the exhausted pool and went
+            # back in front of d; slot 2 stayed free
+            assert requeued == [(True, 2, 2)]
+    for (p, m), got in zip(jobs, runs[True][0]):
+        assert got == _greedy(model, p, m).tolist()
+    assert runs[True][0] == runs[False][0]
+    assert runs[True][2] == runs[False][2]
+    honest, lied = runs[False][1], runs[True][1]
+    assert lied["admits_requeued"] == 1 and "admits_requeued" not in honest
+    assert lied["rounds_ahead_pool_bound_total"] == \
+        honest["rounds_ahead_pool_bound_total"] >= 3
+    assert lied["programs_run_ahead_total"] == \
+        honest["programs_run_ahead_total"]
 
 
 # -- speculative decoding -----------------------------------------------------
