@@ -43,8 +43,8 @@ from ..observability.trace.parts import part, subpart
 from ..observability.trace.request_trace import span
 from .base import (BadRequest, DeadlineExceeded, EngineBase, EngineClosed,
                    _oom_guard, _tracer)
-from .paged_kv import (LAYER_KEEPS, HostPagePool, PagedKVPool, PoolExhausted,
-                       latent_width, token_blocks, window_page_bound)
+from .paged_kv import (HostPagePool, PagedKVPool, PoolExhausted, SlotPages,
+                       token_blocks)
 from .served_model import (Carried, GPTServed, ServedModel, flatten_params,
                            nest_params)
 from .speculative import greedy_accept
@@ -81,11 +81,9 @@ class GenerationConfig:
 
     ``prefill_buckets``: the widths of the one-row prefill programs. A
     prompt longer than the largest is prefilled in chunks of it, so the
-    largest bucket is also the most tokens one program writes: in a cache of
-    two layer kinds it sets how many pages a slot's window layers hold while
-    its chunk runs (``paged_kv.window_page_bound``), and ``window_pages``
-    (None: every slot's decode bound + three chunks' worth + scratch) must
-    cover every slot decoding plus one such chunk."""
+    largest bucket is also the most tokens one program writes: what
+    ``window_pages`` (the window layers' pool, where the cache has such
+    layers) must cover is ``PagedKVPool``'s to say."""
 
     def __init__(self, max_slots: int = 4, max_seq_len: Optional[int] = None,
                  prefill_buckets: Tuple[int, ...] = (16, 32, 64, 128),
@@ -119,8 +117,7 @@ class GenerationConfig:
 class _GenRequest:
     __slots__ = ("prompt", "max_new_tokens", "future", "t_submit",
                  "generated", "trace", "t_decode0", "deadline",
-                 "blocks", "total_blocks", "on_token", "logprobs",
-                 "want_logprobs")
+                 "pages", "on_token", "logprobs", "want_logprobs")
 
     def __init__(self, prompt, max_new_tokens, future, t_submit,
                  deadline=None, on_token=None, want_logprobs=False):
@@ -135,10 +132,7 @@ class _GenRequest:
         self.logprobs: List[float] = []  # behavior logprob per token
         self.trace = None      # request-scoped trace id
         self.t_decode0 = None  # decode-phase start (prefill done)
-        # immutable paging facts, computed ONCE at submit (the admission
-        # scan runs under the engine lock and must stay cheap)
-        self.blocks: List[Tuple[int, ...]] = []  # full prompt token-blocks
-        self.total_blocks = 0                    # worst-case pages
+        self.pages = None      # what it asks of the pool (``PageDemand``)
 
     def edf_key(self) -> Tuple[float, float]:
         eff = self.deadline if self.deadline is not None \
@@ -147,23 +141,15 @@ class _GenRequest:
 
 
 class _Slot:
-    __slots__ = ("req", "length", "last_token", "t0", "table", "blocks",
-                 "shared", "wtable", "wlo", "whi", "freed")
+    __slots__ = ("req", "length", "last_token", "t0", "pages", "freed")
 
-    def __init__(self, n_blocks: int):
+    def __init__(self, pages: SlotPages):
         self.freed = 0   # which release of the engine's freed it (0: none)
-        # a cache of two layer kinds: the window layers' page table, by
-        # ABSOLUTE block like ``table``; blocks [wlo, whi) hold a page, the
-        # blocks behind the window have given theirs back (entry 0)
-        self.wtable = np.zeros(n_blocks, dtype=np.int32)
-        self.wlo = self.whi = 0
         self.req: Optional[_GenRequest] = None
         self.length = 0
         self.last_token = 0
         self.t0 = 0.0  # residency start (occupancy track)
-        self.table = np.zeros(n_blocks, dtype=np.int32)  # page ids (0=scratch)
-        self.blocks = 0   # allocated entries of `table`
-        self.shared = 0   # leading entries borrowed from the prefix cache
+        self.pages = pages   # the pool's: its tables, filled and emptied there
 
 
 class _Admission:
@@ -470,26 +456,26 @@ def _program_name(label: str, carries: bool = False) -> str:
     return f"pt_{tail}" + ("_carry" if carries else "")
 
 
-def _attention(sm, attends: Optional[Dict], name: str):
-    """The jitted attention callable ``name`` of a window program of ``sm``:
+def _attention(sm, layout, attends: Optional[Dict], name: str):
+    """The jitted attention callable ``name`` of a window program of ``sm``,
+    whose cache is ``layout`` (``paged_kv.CacheLayout``):
     ``"paged"`` (K/V arenas), ``"latent"`` (and ``"latent_window"``, the
-    window layers' of a latent cache of two layer kinds), the ``"index_select"`` and
-    ``"sparse"`` of a latent cache with an index row (``_Sparse``), or the
-    ``"full"`` / ``"window"`` of a cache of two layer kinds. ONE jitted
-    callable for every layer of a program: the kernel is traced and lowered once a program and called L
-    times, not traced L times (the 36 kernel traces of a GPT-2-large program
-    were most of warmup's time, PERF.md section 6, PR 28); XLA inlines the
-    calls. And ONE for every program that is handed the same ``attends``
-    dict (an engine hands all of its programs one): ``jax.jit`` caches a
-    trace by the callable and its operands' shapes, so a kernel traced at a
-    shape by one program is not traced again at that shape by the next —
-    the program that carries a round finds the round's shape traced by the
-    decode program (tracing a Pallas kernel's body is most of a window
-    program's build: 0.7–1.4 s a kernel and shape on the chip's host,
-    PERF.md section 2). ``None``: the program keeps its own. Every call of
-    the callable is the ``attention`` part of the step
-    (``observability.trace.parts``): the kernel, and in the program that
-    carries a round the slices and the join around its two calls."""
+    window layers' of a latent cache of two layer kinds), the
+    ``"index_select"`` and ``"sparse"`` of a latent cache with an index row
+    (``_Sparse``), or the ``"full"`` / ``"window"`` of a cache of two layer
+    kinds. ONE jitted callable for every layer of a program: the kernel is
+    traced and lowered once a program and called L times (the 36 kernel
+    traces of a GPT-2-large program were most of warmup's time, PERF.md
+    section 6, PR 28); XLA inlines the calls. And ONE for every program that
+    is handed the same ``attends`` dict (an engine hands all of its programs
+    one): ``jax.jit`` caches a trace by the callable and its operands'
+    shapes, so the program that carries a round finds the round's shape
+    traced by the decode program (a Pallas kernel's body costs 0.7–1.4 s to
+    trace a shape on the chip's host, PERF.md section 2). ``None``: the
+    program keeps its own. Every call of the callable is the ``attention``
+    part of the step (``observability.trace.parts``): the kernel, and in the
+    program that carries a round the slices and the join around its two
+    calls."""
     import jax
 
     if attends is not None and name in attends:
@@ -507,7 +493,7 @@ def _attention(sm, attends: Optional[Dict], name: str):
     elif name == "latent":
         from ..kernels.pallas.mla_paged_attention import mla_paged_attention
 
-        dv = sm.cache_spec["value_dim"]
+        dv = layout.rows["full"].value_dim
 
         @jax.jit
         def latent_attend(q, arena, tables, lengths):
@@ -520,9 +506,9 @@ def _attention(sm, attends: Optional[Dict], name: str):
         # softmax scale) and the walk from the window's first block
         from ..kernels.pallas.mla_paged_attention import mla_paged_attention
 
-        own = sm.cache_spec.get("window_row", sm.cache_spec)
-        dv, window = own["value_dim"], int(sm.cache_spec["window"])
-        own_scale = float(own.get("scale", scale))
+        own = layout.rows["window"]
+        dv, window = own.value_dim, layout.window
+        own_scale = float(scale if own.scale is None else own.scale)
 
         @jax.jit
         def latent_window_attend(q, arena, tables, lengths):
@@ -536,7 +522,7 @@ def _attention(sm, attends: Optional[Dict], name: str):
         from ..kernels.pallas.dsa_index import (NEG, dsa_index_scores,
                                                 exact_topk_bias)
 
-        topk = int(sm.cache_spec["index"]["topk"])
+        topk = layout.index.topk
 
         @jax.jit
         def index_select(qi, wi, index_arena, tables, lengths, live):
@@ -555,7 +541,7 @@ def _attention(sm, attends: Optional[Dict], name: str):
     elif name == "sparse":
         from ..kernels.pallas.mla_sparse_attention import mla_sparse_attention
 
-        dv = sm.cache_spec["value_dim"]
+        dv = layout.rows["full"].value_dim
 
         @jax.jit
         def sparse_attend(q, arena, tables, lengths, bias):
@@ -567,7 +553,7 @@ def _attention(sm, attends: Optional[Dict], name: str):
         from ..kernels.pallas.ranged_paged_attention import \
             ranged_paged_attention
 
-        window = None if name == "full" else int(sm.cache_spec["window"])
+        window = None if name == "full" else layout.window
         # window tokens a row -> query heads a K/V head, noted as a shape is
         # traced (the query's own shape says how many heads the layer has):
         # what ``_count_walk`` needs to ask the kernel's chooser for the tiles
@@ -588,8 +574,8 @@ def _attention(sm, attends: Optional[Dict], name: str):
 
 
 class _Sparse:
-    """What a latent cache that declares an index row (``cache_spec["index"]``:
-    a learned sparse attention) adds to a window program. A ``full`` layer's
+    """What a latent cache that declares an index row (``layout.index``: a
+    learned sparse attention) adds to a window program. A ``full`` layer's
     ``attend`` gets ``index = (qI, wI, kI)``: the key row lands in the layer's
     index arena (``v_arenas[arena_of[layer]]``: the SAME page table and
     allocator as the latent rows), ``select`` scores the window's tokens
@@ -599,16 +585,16 @@ class _Sparse:
     ``shared`` layers that follow (the layer loop is unrolled Python).
     ``counters`` names what the program hands back beside the model's own."""
 
-    def __init__(self, sm, attends: Optional[Dict], prefill: bool,
+    def __init__(self, sm, layout, attends: Optional[Dict], prefill: bool,
                  decode: bool):
-        kinds = list(sm.cache_spec["index"]["layers"])
+        kinds = layout.index.layers
         self.arena_of = {li: n for n, li in enumerate(
             i for i, kind in enumerate(kinds) if kind == "full")}
         # a layer the list names nothing for (``None``: a window layer of a
         # cache of two kinds) selects nothing and attends its own range
         self.selects = [kind is not None for kind in kinds]
-        self.select = _attention(sm, attends, "index_select")
-        self.attend = _attention(sm, attends, "sparse")
+        self.select = _attention(sm, layout, attends, "index_select")
+        self.attend = _attention(sm, layout, attends, "sparse")
         # a prompt's row, a round's rows, or (a carrying program) both
         self.counters = ("attn_keys_selected_prefill_total",) * prefill + \
             ("attn_keys_selected_decode_total",) * decode
@@ -696,38 +682,18 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     its state and its tail. All of that is Python at trace time (``if R:``,
     ``if R and stateful``): a program that carries nothing traces none of
     it, and one that carries and keeps no state lowers the text it lowered
-    before a state could ride. The cache has one of four shapes, by the
-    model's ``cache_spec``:
-
-    - ``None``: K and V arenas ``[pages, page_len, heads, dim]`` a layer,
-      ``attend(q, k, v)``, ``kernels.pallas.paged_attention`` (below);
-    - ``"latent"``: ONE arena a layer (``k_arenas``; ``v_arenas`` is empty)
-      and the blocks get ``attend(q_lat, q_rope, row)``: the window's rows
-      are written through the page table and
-      ``kernels.pallas.mla_paged_attention`` walks the pages each row's
-      length covers. With an index row (``cache_spec["index"]``: a learned
-      sparse attention) ``v_arenas`` holds the index keys of the layers that
-      own an indexer, and each layer attends the keys its query SELECTED
-      (``_Sparse``); built with ``selection`` the program also names them
-      (``counters["selection"]``: ``GenerationEngine.selected_keys``, a
-      check's — no program that serves a request is built so). Where the
-      cache also declares its layers' KINDS (``"layers"``, as below:
-      ``tables`` ``[2, rows, B]``) a window layer's rows land through its
-      kind's table in its own, wider arena and
-      ``mla_paged_attention(window=)`` walks from the first block that holds
-      a visible key; such a layer selects nothing;
-    - ``"kv_by_layer"``: K and V arenas ``[pages, heads, page_len, dim]`` a
-      layer, a "full" layer's of the pool's pages and a "window" layer's of
-      the window pool's; ``tables`` is ``[2, rows, B]`` (the full layers'
-      table, then the window layers', both by absolute block) and each layer
-      gets the ``attend(q, k, v)`` of its kind (``attend.kind``): the
-      window's keys and values are written through its kind's table and
-      ``kernels.pallas.ranged_paged_attention`` walks the pages from the
-      first that holds a visible key (0 in a full layer) to the row's last.
-      The query's own shape says how many heads the layer has;
-    - ``"none"``: NOTHING paged — ``k_arenas`` and ``v_arenas`` are empty,
-      ``tables`` is ``None`` and the blocks get ``attend=None``: every
-      layer's memory is its recurrent ``state``.
+    before a state could ride. What the arenas and ``tables`` are, by layer,
+    is the cache's layout (``paged_kv.CacheLayout``, where the format of
+    ``cache_spec`` is described; ``ServedModel.cache_layout``); what each
+    kind's ``attend`` takes and returns is the protocol's
+    (``serving.served_model``). A layer's rows land through its paging kind's
+    table in its arena and its kind's kernel attends them
+    (``_attention``); a layer that pages nothing gets ``attend=None``, and
+    with nothing paged at all ``tables`` is ``None``. With an index row each
+    layer attends the keys its query SELECTED (``_Sparse``); built with
+    ``selection`` the program also names them (``counters["selection"]``:
+    ``GenerationEngine.selected_keys``, a check's — no program that serves a
+    request is built so).
 
     The window's keys and values (or latent rows) land through
     ``write_rows``, one scatter index a token — but a ONE-ROW prefill whose
@@ -738,26 +704,19 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     program to rows.
 
     ``counters`` is the model's ``program_counters`` summed over the layers
-    (int32 scalars).
-    ``n_valid`` (``[rows]``: real tokens in each row's
+    (int32 scalars). ``n_valid`` (``[rows]``: real tokens in each row's
     window) gives the blocks ``valid = arange(W) < n_valid``. A ``prefill``
     program (one fresh sequence a row, ``n_valid`` required) computes the
     head at the last real position only (``[rows, 1]`` outputs): an
     admission reads nothing else, and a 256 x 261120 float32 logits tensor
-    is 267 MB. A model that declares recurrent state
-    (``served.state_spec``) gets ``state``: ``None`` lets every block
-    start from zero (a prefill, which returns the rows' FINAL state for
-    the engine to install); the per-layer slot arenas (the decode program:
-    donated like the K/V arenas) are advanced one step and returned
-    updated, in place. A model whose block RESUMES
-    (``ServedModel.resumes_state``) may be handed a ``state`` in a prefill
-    too — what the prompt's previous chunk returned, one row, donated — and
-    is told which it holds by ``step=`` (true in a round); its carrying
-    program takes and returns the pair of both (above). A model without
-    state gets and returns ``None``.
+    is 267 MB. ``state`` is the protocol's (``serving.served_model``):
+    ``None`` (from zero) or a row's own in a prefill, which returns the
+    rows' FINAL state for the engine to install; the slot arenas in a round,
+    donated like the K/V arenas and advanced one step in place; ``step=``
+    tells a block that resumes which it holds.
 
-    Attention is one of the three kernels above: on the TPU the Pallas
-    kernel attends straight against the page table (the dense
+    Attention is the cache kind's kernel (``_attention``): on the TPU the
+    Pallas kernel attends straight against the page table (the dense
     ``kc[tables]`` gathered context never materializes), elsewhere its jnp
     reference gathers and attends — ``kernels.registry.resolve`` decides
     as the program is traced. ``fused`` selects nothing: it is accepted,
@@ -769,11 +728,8 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
     trace says of this program) are set OUTSIDE ``step``'s body — the
     protocol's methods once a build (``_InParts``), the attention callables
     (``_attention``), the helpers above — so that the body reads as the
-    model step and nothing else. (PR 36 measured sixty dead lines in ``step``
-    as + 0.4–1.0 s a program's trace on the chip's host and made that a rule;
-    the cause was where the Python stack's 16 KiB chunks ended, which any
-    change of a frame's size moves: ``persistent_cache.in_one_stack_chunk``,
-    under which every program is built, PERF.md section 6, PR 37.)
+    model step and nothing else (and every program is built under
+    ``persistent_cache.in_one_stack_chunk``: PERF.md section 6, PRs 36-37).
     """
     import jax.numpy as jnp
 
@@ -788,29 +744,17 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
                 "takes each row's own range, and whose recurrent state, if "
                 "it keeps one, resumes, carries a decode round "
                 "(ServedModel.carries_rounds)")
-    stateful = sm.state_spec is not None
-    cache_kind = None if sm.cache_spec is None else sm.cache_spec["kind"]
-    latent = cache_kind == "latent"
-    by_layer = cache_kind == "kv_by_layer"
-    unpaged = cache_kind == "none"
-    # layers of several kinds (``"layers"``: the K/V form, or a latent cache
-    # that declares its layers' kinds): the paging kinds have a pool and a
-    # table each ("full", then "window" where there is one), a "state" layer
-    # keeps a row of the state arenas, a "full+state" layer both and a "none"
-    # layer nothing — and ``k_arenas`` / ``v_arenas`` hold the paging layers'
-    # arenas alone, ``state`` the layers' that keep a row (every layer's
-    # where no kind is declared)
-    kinds = list((sm.cache_spec or {}).get("layers") or ())
-    table_kinds = ("full", "window") if "window" in kinds else ("full",)
-    # by layer: the paging kind whose table and pool it uses (``None``: its
-    # kind pages nothing, or the cache names no kinds), whether it gets an
-    # ``attend``, whether it keeps a row — a "full+state" layer both
-    if kinds:
-        paging, keeping = zip(*map(LAYER_KEEPS.get, kinds))
-        paged = [kind is not None for kind in paging]
-    else:
-        paging, keeping, paged = ([v] * sm.num_layers for v in (
-            None, stateful, not unpaged))
+    layout = sm.cache_layout(PL)
+    stateful, latent, unpaged = layout.stateful, layout.latent, \
+        not layout.paged
+    # (keys and values a K/V head's tokens contiguous: the ranged kernel's)
+    by_layer = layout.ranged and not latent
+    # declared kinds: tables stacked, one a paging kind. ``k_arenas`` /
+    # ``v_arenas`` hold the paging layers' arenas alone, ``state`` the layers'
+    # that keep a row. By layer: the paging kind whose table and pool it uses
+    # (``None``: no pages, no ``attend``) and whether it keeps a row
+    kinds, table_kinds = layout.by_layer, layout.table_kinds
+    paging, keeping = zip(*layout.keeps)
     counter_names = sm.program_counters
     # a model whose block resumes is told which of its two state conventions
     # a program uses: the slot arenas of a round, or a row's own state
@@ -826,31 +770,28 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
             "paged_attention.paged_attention(..., impl='reference')")
 
     sparse = None
-    if selection and not (latent and sm.cache_spec.get("index")):
+    if selection and not layout.index:
         raise ValueError("selection=True: the model's cache_spec declares no "
                          "index row, so nothing is selected")
     if latent:
-        # (row width, lanes, kernel, query slab) by layer kind: a window
-        # layer's rows may have a width of their own (``"window_row"``)
-        lat = {}
-        for kind in sorted(set(kinds)) or [None]:
-            dl = (sm.cache_spec.get("window_row", sm.cache_spec)
-                  if kind == "window" else sm.cache_spec)["dim"]
-            DL = latent_width(dl)
-            lat[kind] = (dl, DL, _attention(
-                sm, attends, "latent_window" if kind == "window"
-                else "latent"), _latent_query(DL - dl))
-        if sm.cache_spec.get("index"):
-            sparse = _Sparse(sm, attends, prefill, bool(R) or not prefill)
+        # (row width, lanes, kernel, query slab) by paging kind: a window
+        # layer's rows may have a width of their own
+        lat = {kind: (row.dim, row.width, _attention(
+            sm, layout, attends, "latent_window" if kind == "window"
+            else "latent"), _latent_query(row.width - row.dim))
+            for kind, row in layout.rows.items()}
+        if layout.index:
+            sparse = _Sparse(sm, layout, attends, prefill,
+                             bool(R) or not prefill)
     elif by_layer:
-        ranged = {kind: _attention(sm, attends, kind)
-                  for kind in sorted(set(paging) & set(table_kinds))}
+        ranged = {kind: _attention(sm, layout, attends, kind)
+                  for kind in table_kinds if layout.layers_of[kind]}
     elif not unpaged:
-        paged_attend = _attention(sm, attends, "paged")
+        paged_attend = _attention(sm, layout, attends, "paged")
 
     # a one-row prefill of whole pages writes them whole (``write_pages``),
     # every other program its rows (``write_rows``)
-    n_pages = _whole_pages(S, W, PL, prefill, cache_kind is None) \
+    n_pages = _whole_pages(S, W, PL, prefill, not layout.ranged) \
         if aligned else 0
     rows_of = functools.partial(write_rows, lead=3) if by_layer else write_rows
     write = write_pages if n_pages else rows_of
@@ -950,7 +891,8 @@ def _build_window_step(served, max_slots: int, n_blocks: int, page_len: int,
             # a layer that pages nothing (every layer of a cache of kind
             # "none"; a "state" or "none" layer): no arena, no table, and no
             # ``attend``
-            kind, pages, keeps = paging[li], paged[li], keeping[li]
+            kind, keeps = paging[li], keeping[li]
+            pages = kind is not None
             kc = next(arenas) if pages else None
             vc = None if latent or not pages else next(values)
             # the layer's own table and places: its kind's, where there are two
@@ -1088,102 +1030,16 @@ class GenerationEngine(EngineBase):
             raise ValueError(
                 f"max_seq_len {self.max_len} exceeds the model's position "
                 f"table ({sm.max_positions})")
-        self._stateful = sm.state_spec is not None
-        if self._stateful:
-            # everything that assumes a cache is pages of K/V is wrong for
-            # a recurrent state: refused in words, never switched off
-            # silently (docs/serving.md, "Recurrent state")
-            if self.config.prefix_cache:
-                raise ValueError(
-                    f"{type(model).__name__} carries recurrent state per "
-                    "slot: a cached K/V prefix has no state to resume "
-                    "from, so the prefix cache cannot serve it — pass "
-                    "GenerationConfig(prefix_cache=False)")
-            if self.config.draft_model is not None:
-                raise ValueError(
-                    f"{type(model).__name__} carries recurrent state per "
-                    "slot: a rejected draft token would have advanced it "
-                    "and it cannot be rolled back, so speculative decoding "
-                    "is refused — pass draft_model=None")
-        cache_kind = None if sm.cache_spec is None else sm.cache_spec["kind"]
-        self._latent = cache_kind == "latent"
-        # two kinds of layer, one pool each: the K/V form, or a latent cache
-        # that declares its layers' kinds (``cache_spec["layers"]``)
-        kinds = list((sm.cache_spec or {}).get("layers") or ())
-        self._by_layer = bool(kinds)
-        # ... of which some keep a sliding window, whose pages come from a
-        # second allocator through a second table
-        self._windowed = "window" in kinds
-        # nothing paged: every layer's memory is its recurrent state. No K/V
-        # arena, no page table in the programs, admission by slots alone, and
-        # ``max_seq_len`` bounds positions only (no memory grows with it).
-        # What needs pages was refused above, as for every state model; the
-        # warm tier is refused here (docs/serving.md, "Nothing paged")
-        self._unpaged = cache_kind == "none"
-        if self._unpaged:
-            if not self._stateful:
-                raise ValueError(
-                    f"{type(model).__name__} declares a cache of kind "
-                    "'none' and no state_spec: it would remember nothing")
-            if self.config.warm_pool_bytes:
-                raise ValueError(
-                    f"{type(model).__name__} keeps no K/V pages at all: "
-                    "the warm tier has nothing to spill or restore — pass "
-                    "GenerationConfig(warm_pool_bytes=0)")
-        if self._by_layer and not self._windowed and \
-                self.config.warm_pool_bytes:
-            # (the prefix cache and a draft model were refused above: such a
-            # cache has a layer that keeps state; docs/serving.md, "Memory by
-            # layer kind")
-            raise ValueError(
-                f"{type(model).__name__} keeps pages in some of its layers "
-                "and a recurrent state in others (or both in one): the warm "
-                "tier spills and "
-                "restores prefixes of pages, and a prefix's state is in none "
-                "— pass GenerationConfig(warm_pool_bytes=0)")
-        if self._windowed:
-            # what assumes that a page, once written, stays: refused in
-            # words (docs/serving.md, "A cache of two layer kinds")
-            why = (f"{type(model).__name__} keeps a sliding window of "
-                   f"{sm.cache_spec['window']} keys in some of its layers, "
-                   "whose pages go back to the pool as the window passes "
-                   "them: ")
-            if self.config.prefix_cache:
-                raise ValueError(
-                    why + "a cached prefix's pages behind the window are "
-                    "gone, so the prefix cache cannot serve it — pass "
-                    "GenerationConfig(prefix_cache=False)")
-            if self.config.draft_model is not None:
-                raise ValueError(
-                    why + "a verify round that rejects draft tokens would "
-                    "have to take back pages already given away, so "
-                    "speculative decoding is refused — pass "
-                    "draft_model=None")
-            if self.config.warm_pool_bytes:
-                raise ValueError(
-                    why + "the warm tier spills and restores whole "
-                    "prefixes — pass GenerationConfig(warm_pool_bytes=0)")
-        # a latent cache with an index row (a learned sparse attention): the
-        # index keys live in arenas of their own on the SAME page table, so a
-        # page the prefix trie shares carries a token's two rows together and
-        # the trie serves both; what it cannot take yet is refused in words
-        # (docs/serving.md, "Latent cache with an index row")
-        self._index = sm.cache_spec.get("index") if self._latent else None
-        self._indexers = self._index["layers"].count("full") \
-            if self._index else 0
-        if self._index and self.config.draft_model is not None:
-            raise ValueError(
-                f"{type(model).__name__} attends the keys an indexer selects: "
-                "a verify window of draft tokens would select with them in "
-                "the cache and no test holds that path yet, so speculative "
-                "decoding is refused — pass draft_model=None")
-        if self._latent and self.config.warm_pool_bytes:
-            # what moves K/V pages cannot take a latent row yet: refused in
-            # words (docs/serving.md, "Latent cache")
-            raise ValueError(
-                f"{type(model).__name__} caches one latent row a token: the "
-                "warm tier spills and restores K/V pages — pass "
-                "GenerationConfig(warm_pool_bytes=0)")
+        pl = self._pl = self.config.page_len
+        # the one parse of the model's ``cache_spec`` — and what such a cache
+        # cannot use: refused in words, never switched off silently
+        layout = sm.cache_layout(pl)
+        asked = {"prefix_cache": self.config.prefix_cache,
+                 "draft_model": self.config.draft_model is not None,
+                 "warm_pool": self.config.warm_pool_bytes}
+        for feature, why in layout.refuses.items():
+            if asked.get(feature):
+                raise ValueError(why.format(model=type(model).__name__))
         for b in self.config.prefill_buckets:
             if b > self.max_len:
                 raise ValueError(
@@ -1191,19 +1047,18 @@ class GenerationEngine(EngineBase):
         self._params = sm.params(model)
         dtype = self._params["embed"].dtype
         S = self.config.max_slots
-        pl = self.config.page_len
-        self._pl = pl
         # every prefill call starts on a page boundary: at 0 or behind whole
         # cached blocks (``_join``), then in steps of the largest bucket
         # (``_prefill_chunks``) — where that bucket is whole pages. What a
         # one-row prefill program's page write rests on (``_whole_pages``);
         # with any other bucket list every program scatters rows
         self._aligned = self.config.prefill_buckets[-1] % pl == 0
-        self._n_blocks = B = 0 if self._unpaged else \
-            -(-self.max_len // pl)  # ceil
+        # nothing paged: no page table in the programs, admission by slots
+        # alone, and ``max_seq_len`` bounds positions only
+        self._n_blocks = B = -(-self.max_len // pl) if layout.paged else 0
         # (the allocator wants a usable page beside the scratch one; nobody
         # takes it)
-        num_pages = 2 if self._unpaged else self.config.num_pages
+        num_pages = self.config.num_pages if layout.paged else 2
         if num_pages is None:
             # every slot's worst case + two cached prefixes' worth + scratch
             num_pages = S * B + 2 * B + 1
@@ -1212,42 +1067,12 @@ class GenerationEngine(EngineBase):
             warm = HostPagePool(
                 capacity_bytes=self.config.warm_pool_bytes,
                 admit_threshold=self.config.warm_admit_threshold)
-        window_pages = 0
-        self._win = 0       # no window layer: no key is counted inside one
-        if self._windowed:
-            # a slot's window layers hold at most this many pages while it
-            # decodes, and this many while its largest chunk runs
-            self._win = int(sm.cache_spec["window"])
-            self._wbound = window_page_bound(self._win, 1, pl)
-            chunk = window_page_bound(
-                self._win, self.config.prefill_buckets[-1], pl)
-            window_pages = self.config.window_pages
-            if window_pages is None:
-                window_pages = S * self._wbound + 3 * chunk + 1
-            if window_pages < S * self._wbound + chunk + 1:
-                raise ValueError(
-                    f"window_pages {window_pages}: the window layers need "
-                    f"{self._wbound} pages for each of {S} slots that "
-                    f"decode, {chunk} for the one whose "
-                    f"{self.config.prefill_buckets[-1]}-token chunk is "
-                    "running, and the scratch page: "
-                    f"{S * self._wbound + chunk + 1}")
-        if self._by_layer:
-            # the layers of each paging kind (a table and a pool each)
-            paging = [LAYER_KEEPS[kind][0] for kind in kinds]
-            self._layers_of = {
-                kind: paging.count(kind)
-                for kind in ("full", "window")[:1 + self._windowed]}
-            # a page as the ranged kernel sees it: K/V heads, tokens, head
-            # size, bytes an element (``_count_walk``; a latent page is rows)
-            self._page = (sm.num_kv_heads, pl, sm.head_dim,
-                          np.dtype(dtype).itemsize)
-        self._pool = PagedKVPool(sm.num_layers, num_pages, pl,
-                                 sm.num_kv_heads, sm.head_dim, dtype,
-                                 prefix_cache=self.config.prefix_cache,
-                                 warm_pool=warm, state_spec=sm.state_spec,
-                                 max_slots=S, cache_spec=sm.cache_spec,
-                                 window_pages=window_pages)
+        self._pool = PagedKVPool(
+            layout, num_pages, dtype, prefix_cache=self.config.prefix_cache,
+            warm_pool=warm, max_slots=S, n_blocks=B,
+            window_pages=self.config.window_pages,
+            chunk=self.config.prefill_buckets[-1])
+        self._layout = layout
         # cross-thread ops the worker must execute (the allocator and
         # the arenas are worker-owned): (fn, Future) pairs — the KV
         # export/install seam the page shipper rides
@@ -1325,7 +1150,7 @@ class GenerationEngine(EngineBase):
                         cache, kv, (slot, 0, 0, 0)),
                     donate_argnums=(0,) if donate else (), label=ilabel))
 
-        self._slots = [_Slot(B) for _ in range(S)]
+        self._slots = [_Slot(self._pool.slot_pages()) for _ in range(S)]
         self._releases = itertools.count(1)   # stamps ``_Slot.freed``
         # in-place weight push (post-training): a pending swap applies at
         # the first ZERO-ACTIVE step boundary — admission pauses while it
@@ -1339,7 +1164,7 @@ class GenerationEngine(EngineBase):
 
             register_component(f"serving:{self.name}:kv_pages",
                                type(self)._kv_pool_bytes, owner=self)
-            if self._stateful:
+            if layout.stateful:
                 register_component(f"serving:{self.name}:state",
                                    type(self)._state_pool_bytes, owner=self)
         except Exception:
@@ -1373,13 +1198,13 @@ class GenerationEngine(EngineBase):
         self.metrics.gauge("slot_occupancy", self.slot_occupancy)
         self.metrics.gauge("kv_headroom", self.kv_headroom)
         self.metrics.gauge("kv_pool_bytes", self._kv_pool_bytes)
-        if self._index or self._by_layer:
+        if layout.index or layout.by_layer:
             self.metrics.gauge("kv_pool_bytes_by_kind",
                                self._pool.bytes_by_kind)
-        if self._by_layer:
+        if layout.by_layer:
             self.metrics.gauge("kv_pages_live_by_kind",
                                self._pool.live_pages_by_kind)
-        if self._stateful:
+        if layout.stateful:
             self.metrics.gauge("state_pool_bytes", self._state_pool_bytes)
         # prefix-cache truth (hits/misses/evictions) rides the snapshot
         # so pd_top / render_snapshot show the warm-tier tuning baseline
@@ -1421,9 +1246,8 @@ class GenerationEngine(EngineBase):
     def _chunk_pages(self, W: int) -> int:
         """Pages the ``W``-token prefill program writes whole (0: it
         scatters rows), as its builder decided from the same facts."""
-        return _whole_pages(1, W, self._pl, True,
-                            self._sm.cache_spec is None) \
-            if self._aligned and not self._unpaged else 0
+        return _whole_pages(1, W, self._pl, True, not self._layout.ranged) \
+            if self._aligned else 0
 
     def _carried_rows(self, W: int) -> int:
         """The decode rows the ``W``-token prefill program carries: a whole
@@ -1463,7 +1287,8 @@ class GenerationEngine(EngineBase):
                 tokens = np.zeros((rows, W), np.int32)
                 tokens = jnp.asarray(tokens) if prefill else \
                     jax.device_put(tokens, self._device)
-            return (jnp.zeros(self._tables_shape(rows), jnp.int32), tokens,
+            return (jnp.zeros(self._pool.tables_shape(rows), jnp.int32),
+                    tokens,
                     jnp.zeros(rows, jnp.int32),
                     np.full(rows, int(prefill), np.int32))
 
@@ -1535,43 +1360,17 @@ class GenerationEngine(EngineBase):
                 self._draft_prefill(0, np.zeros(b, dtype=np.int64))
         return self
 
-    def _tables_shape(self, rows: int) -> Tuple[int, ...]:
-        """A window program's page tables for ``rows`` rows: ``[rows, B]``,
-        or the full and the window layers' stacked, ``[2, rows, B]``."""
-        return ((len(self._layers_of),) if self._by_layer else ()) + \
-            (rows, self._n_blocks)
+    @property
+    def _wbound(self) -> int:
+        """Window pages a decoding slot holds at most (a runner reads it)."""
+        return self._pool.window_bound
 
-    def _slot_tables(self, s: _Slot) -> np.ndarray:
-        return np.stack([s.table, s.wtable][:len(self._layers_of)]) \
-            if self._by_layer else s.table
-
-    def _window_pages(self, s: _Slot, lo: int, hi: int) -> None:
-        """The next program's queries of slot ``s`` sit at positions ``[lo,
-        hi]``: its window layers give back every page whose keys all lie
-        behind ``lo - (window - 1)``, the first key ``lo`` can see, and take
-        pages for the blocks up to ``hi``'s. Pages change hands in dispatch
-        order, which is the device's order: a program still in flight reads
-        its own copy of the table and runs before whatever writes the page
-        next."""
-        wa, pl = self._pool.window_allocator, self._pl
-        first = min(max(lo - (self._win - 1), 0) // pl, s.whi)
-        if first > s.wlo:
-            for b in range(s.wlo, first):
-                wa.release(int(s.wtable[b]))
-                s.wtable[b] = 0
-            self.metrics.inc("window_pages_released_total", first - s.wlo)
-            s.wlo = first
-        need = hi // pl + 1
-        if need > s.whi:    # positions are contiguous: wlo <= first <= whi
-            s.wtable[s.whi:need] = wa.alloc(need - s.whi)
-            self.metrics.inc("window_pages_taken_total", need - s.whi)
-            s.whi = need
-
-    def _window_reserved(self) -> int:
-        """Window pages promised to the running slots beyond what they
-        hold: each may grow to its decode bound."""
-        return sum(max(self._wbound - (s.whi - s.wlo), 0)
-                   for s in self._slots if s.req is not None)
+    def _count_slide(self, released: int, taken: int) -> None:
+        """Window pages that changed hands (``PagedKVPool.slide``)."""
+        if released:
+            self.metrics.inc("window_pages_released_total", released)
+        if taken:
+            self.metrics.inc("window_pages_taken_total", taken)
 
     @staticmethod
     def _keys_in_window(lo: int, hi: int, window: int) -> int:
@@ -1587,15 +1386,15 @@ class GenerationEngine(EngineBase):
         its own, once a layer; a decode round's part of the window layers'
         is counted apart too (a round reads its keys once a row, a chunk's
         tokens share theirs: the two have different floors)."""
-        self.metrics.inc("attn_keys_full_total",
-                         full * self._layers_of["full"])
-        if not self._windowed:
+        layers = self._layout.layers_of
+        self.metrics.inc("attn_keys_full_total", full * layers["full"])
+        if not self._layout.window:
             return
         self.metrics.inc("attn_keys_window_total",
-                         windowed * self._layers_of["window"])
+                         windowed * layers["window"])
         self.metrics.inc(
             f"attn_keys_window_{'decode' if decode else 'prefill'}_total",
-            windowed * self._layers_of["window"])
+            windowed * layers["window"])
 
     def _count_walk(self, W: int, keys, decode: bool = False) -> None:
         """What the two kinds' kernel calls of a program dispatched walk:
@@ -1604,26 +1403,28 @@ class GenerationEngine(EngineBase):
         (``walk_cost``: every tile of a row walks its range again) and the
         pages that hold a key in range, once a layer of the kind; a decode
         round's part is counted apart too, as its keys are."""
-        if self._latent:
+        layout = self._layout
+        if layout.latent:
             # a latent cache's window kernel alone walks a range (a full
             # layer attends what it selected, over every visible page): the
             # latent rows its tiles DMA, and the rows inside their windows
             from ..kernels.pallas.mla_paged_attention import window_walk
 
-            heads = self._sm.cache_spec.get("window_row", {}).get(
-                "heads", self._sm.num_heads)
-            walked, inside = window_walk(W, heads, self._pl, self._win, keys)
+            heads = layout.rows["window"].heads or self._sm.num_heads
+            walked, inside = window_walk(W, heads, self._pl, layout.window,
+                                         keys)
             for what, n in (("walked", walked), ("in", inside)):
                 self.metrics.inc(f"attn_rows_{what}_window_total",
-                                 n * self._layers_of["window"])
+                                 n * layout.layers_of["window"])
             return
         from ..kernels.pallas.ranged_paged_attention import \
             choose_tiles, walk_cost
 
-        G, PL, d, itemsize = self._page
-        for kind, layers in self._layers_of.items():
+        (G, PL, d), itemsize = layout.page, \
+            self._params["embed"].dtype.itemsize
+        for kind, layers in layout.layers_of.items():
             shape = (W, self._attends[kind].walks[W], G, PL, d,
-                     None if kind == "full" else self._win)
+                     None if kind == "full" else layout.window)
             cost = walk_cost(len(keys), *shape, keys,
                              choose_tiles(*shape, itemsize), itemsize)
             for what in ("walked", "in_range"):
@@ -1659,10 +1460,11 @@ class GenerationEngine(EngineBase):
 
         pool, fn = self._pool, self._window(rows, W, prefill)
         # a state model's carrying call: the row's state AND the arenas
-        pair = bool(prefill and self._stateful and self._carried_rows(W))
+        pair = bool(prefill and self._layout.stateful
+                    and self._carried_rows(W))
         nxt, lp, pool.k, pool.v, state, *counted = fn(
             self._params, pool.k, pool.v,
-            None if self._unpaged else tables, tokens, lengths,
+            tables if self._layout.paged else None, tokens, lengths,
             jax.tree_util.tree_map(jnp.asarray, n_valid),
             (state, pool.state) if pair else state if prefill
             else pool.state)
@@ -1742,7 +1544,7 @@ class GenerationEngine(EngineBase):
         on an idle or closed engine this is the FINAL state of the last
         request the slot served — what a check compares with a reference
         (the worker owns the arenas: do not call it under load)."""
-        if not self._stateful:
+        if not self._layout.stateful:
             raise ValueError(f"{type(self.model).__name__} declares no "
                              "recurrent state")
         return [{name: arena[slot_no] for name, arena in layer.items()}
@@ -1760,20 +1562,22 @@ class GenerationEngine(EngineBase):
         build of the largest prefill bucket's program that also hands its
         selections back — the same ``_build_window_step``, page table, arenas,
         kernels and chunk offsets as a served prompt's, on the caller's thread
-        and through pages 1, 2, ... of the pool. So only a closed engine may
-        (as ``release_caches``), and what the pool held is overwritten."""
+        and through pages it takes from the pool as a joining prompt does. So
+        only a closed engine may (as ``release_caches``), and what the pool
+        held is overwritten."""
         import jax
         import jax.numpy as jnp
 
-        if not self._index:
+        if not self._layout.index:
             raise ValueError("selected_keys: the model's cache declares no "
                              "index row, so nothing is selected")
         if not self._closed or self._thread is not None:
             raise RuntimeError("selected_keys: close() the engine first")
         tokens = np.asarray(tokens, np.int32).reshape(-1)
         W, PL, pool = self.config.prefill_buckets[-1], self._pl, self._pool
-        n_pages = -(-len(tokens) // PL)
-        if n_pages > min(self._n_blocks, pool.num_pages - 1):
+        # (no block is a key: the prompt shares nothing with the prefix cache)
+        demand = pool.demand(tokens, 0)._replace(blocks=[])
+        if demand.total > min(self._n_blocks, pool.num_pages - 1):
             raise ValueError(f"selected_keys: {len(tokens)} tokens are past "
                              "max_seq_len or the pool")
         label = f"serving:{self.name}:selection{W}"
@@ -1783,29 +1587,21 @@ class GenerationEngine(EngineBase):
                 self._sm, 1, self._n_blocks, PL, W, self._donate, label=label,
                 prefill=True, attends=self._attends, aligned=self._aligned,
                 selection=True)
-        table = np.zeros(self._tables_shape(1), np.int32)
-        (table[0] if self._by_layer else table)[0, :n_pages] = \
-            1 + np.arange(n_pages)
-        out = []
+        pages, out = self._slots[0].pages, []   # (every slot is free)
+        pool.join(pages, demand)
         for lo in range(0, len(tokens), W):
             chunk = np.zeros((1, W), np.int32)
             n = min(W, len(tokens) - lo)
             chunk[0, :n] = tokens[lo:lo + n]
-            if self._by_layer:
-                # the window layers' pages of this call, by absolute block
-                # round the window pool (it holds a chunk's bound and more)
-                first = max(lo - (self._win - 1), 0) // PL
-                blocks = np.arange(first, (lo + n - 1) // PL + 1)
-                table[1, 0] = 0
-                table[1, 0, blocks] = 1 + blocks % \
-                    pool.window_allocator.usable_pages
+            pool.slide(pages, lo, lo + n - 1)
             _nxt, _lp, pool.k, pool.v, _state, counted = fn(
-                self._params, pool.k, pool.v, jnp.asarray(table),
-                jnp.asarray(chunk),
+                self._params, pool.k, pool.v,
+                jnp.asarray(pool.row_tables(pages)), jnp.asarray(chunk),
                 jnp.asarray([lo], jnp.int32), jnp.asarray([n], jnp.int32),
                 None)
             out.append([np.asarray(a)[0, :n]
                         for a in jax.device_get(counted["selection"])])
+        pool.release(pages)
         return [np.concatenate(layer) for layer in zip(*out)]
 
     def release_caches(self) -> None:
@@ -1868,7 +1664,7 @@ class GenerationEngine(EngineBase):
         if self._hist_prompt is not None:
             self._hist_prompt.observe(len(prompt))
         if self._prefill_bucket(len(prompt)) is None and \
-                ((self._stateful and not self._sm.resumes_state)
+                ((self._layout.stateful and not self._sm.resumes_state)
                  or self.spec_k):
             # a longer prompt is prefilled in chunks against its own cached
             # pages, or from the state its previous chunk left where the
@@ -1889,15 +1685,14 @@ class GenerationEngine(EngineBase):
                 f"prompt ({len(prompt)}) + max_new_tokens "
                 f"({max_new_tokens}) exceeds max_seq_len {self.max_len}"))
             return fut
-        needed = 0 if self._unpaged else \
-            -(-(len(prompt) + max_new_tokens) // self._pl)
-        if needed > self._pool.allocator.usable_pages:
+        pages = self._pool.demand(prompt, max_new_tokens)
+        if pages.total > self._pool.allocator.usable_pages:
             # paged admission bound: POOL capacity, not slot length — a
             # request that could never hold enough pages is rejected; one
             # that merely has to wait for pages stays queued
             self.metrics.inc("errors_total")
             fut.set_exception(BadRequest(
-                f"request needs {needed} KV pages; the pool holds "
+                f"request needs {pages.total} KV pages; the pool holds "
                 f"{self._pool.allocator.usable_pages}"))
             return fut
         t_submit = time.monotonic()
@@ -1906,9 +1701,7 @@ class GenerationEngine(EngineBase):
         req = _GenRequest(prompt.astype(np.int64), int(max_new_tokens), fut,
                           t_submit, deadline, on_token=on_token,
                           want_logprobs=return_logprobs)
-        req.blocks = [] if self._unpaged else \
-            token_blocks(req.prompt, self._pl)
-        req.total_blocks = needed
+        req.pages = pages
         # ``trace_parent`` is the fleet-minted context carried over the
         # submit frame: this engine's spans nest under it when the
         # supervisor's collector merges traces across processes
@@ -2099,27 +1892,10 @@ class GenerationEngine(EngineBase):
                     fut.set_result(res)
 
     def _refuse_kv_transfer(self, what: str) -> None:
-        if self._unpaged:
-            raise RuntimeError(
-                f"{what}: {type(self.model).__name__} keeps no K/V pages at "
-                "all — a sequence is its recurrent state, and no state "
-                "snapshot is shipped")
-        if self._windowed:
-            raise RuntimeError(
-                f"{what}: {type(self.model).__name__} keeps a sliding "
-                "window in some of its layers — their pages behind the "
-                "window have gone back to the pool, so a prompt's cache "
-                "cannot be read out or installed page by page")
-        if self._latent:
-            raise RuntimeError(
-                f"{what}: {type(self.model).__name__} caches one latent row "
-                "a token — the page shipper's wire format is K and V stacks "
-                "of [pages, page_len, heads, dim] and cannot carry it yet")
-        if self._stateful:
-            raise RuntimeError(
-                f"{what}: {type(self.model).__name__} carries recurrent "
-                "state per slot — its K/V pages alone do not resume a "
-                "sequence, and no state snapshot is shipped with them")
+        why = self._layout.refuses.get("kv_transfer")
+        if why is not None:
+            raise RuntimeError(f"{what}: " + why.format(
+                model=type(self.model).__name__))
 
     def export_kv_pages(self, prompt_ids):
         """Read the cached KV of ``prompt_ids``' full prompt blocks out of
@@ -2196,25 +1972,6 @@ class GenerationEngine(EngineBase):
     def _active(self) -> List[int]:
         return [i for i, s in enumerate(self._slots) if s.req is not None]
 
-    def _blocks_needed(self, req: _GenRequest) -> int:
-        """Pages a request must be able to allocate at join time (worst
-        case, minus what the prefix cache already holds). Block tuples are
-        precomputed at submit — only the trie walk runs here."""
-        trie = self._pool.trie
-        if trie is None:
-            return req.total_blocks
-        m = trie.match_len(req.blocks[: (len(req.prompt) - 1) // self._pl])
-        return req.total_blocks - m
-
-    def _window_needed(self, req: _GenRequest) -> int:
-        """Window pages a request must find free at its join: what its
-        widest prefill call holds, and never less than a decoding slot's."""
-        if not self._windowed:
-            return 0
-        widest = min(len(req.prompt), self.config.prefill_buckets[-1])
-        return max(window_page_bound(self._win, widest, self._pl),
-                   self._wbound)
-
     def _next_request(self) -> Tuple[Optional[_GenRequest], bool]:
         """Shed expired queued requests, then pick the earliest-deadline
         queued request whose KV pages can be allocated right now. Beside the
@@ -2230,10 +1987,8 @@ class GenerationEngine(EngineBase):
                     self._queue.remove(r)
                     shed.append(r)
             order = sorted(self._queue, key=_GenRequest.edf_key)
-            reserved = self._window_reserved() if self._windowed else 0
             for r in order:
-                if self._pool.can_allocate(self._blocks_needed(r),
-                                           self._window_needed(r), reserved):
+                if self._pool.can_allocate(r.pages):
                     self._queue.remove(r)
                     picked = r
                     break
@@ -2409,66 +2164,21 @@ class GenerationEngine(EngineBase):
         import jax.numpy as jnp
 
         req, s = adm.req, self._slots[adm.slot_no]
-        p, pl = len(req.prompt), self._pl
-        total_blocks = req.total_blocks
-        trie = self._pool.trie
-        all_blocks = req.blocks
+        p = len(req.prompt)
+        # all its pages, or ``PoolExhausted`` and the pool as it was
         with span("pt.serve.page_table"):
-            s.table[:] = 0
-            # prefix reuse: longest cached chain of full prompt blocks,
-            # capped so at least one suffix token remains to produce the
-            # first logits
-            shared_pages: List[int] = []
-            if trie is not None:
-                if self._pool.warm is not None:
-                    # warm tier: restore spilled pages for this chain
-                    # before matching, so a previously-evicted prefix costs
-                    # a host dequantize instead of a re-prefill
-                    self._pool.warm_restore(all_blocks[: (p - 1) // pl])
-                shared_pages = trie.match(all_blocks[: (p - 1) // pl], pl,
-                                          self._pool.allocator)
-            m = len(shared_pages)
-            try:
-                private = self._pool.allocate(total_blocks - m)
-            except PoolExhausted:
-                for pg in shared_pages:
-                    self._pool.allocator.release(pg)
-                raise
-            s.table[:m] = shared_pages
-            s.table[m:total_blocks] = private
-            s.blocks, s.shared = total_blocks, m
-            # COW hook: every block the decode path will write must be
-            # exclusively ours. By construction they already are (the trie
-            # shares FULL prompt blocks only), so this is a no-op guard —
-            # but a future partial-block sharing scheme lands here.
-            for bi in range(p // pl, total_blocks):
-                pg, copied = self._pool.ensure_writable(int(s.table[bi]))
-                if copied:
-                    s.table[bi] = pg
-        chunks = self._prefill_chunks(m * pl, p)
-        if self._windowed:
-            # the first call's window pages; the later calls take theirs as
-            # they go out (``_send_chunk``), out of what ``_next_request``
-            # found free
-            try:
-                self._window_pages(s, chunks[0][0], chunks[0][1] - 1)
-            except PoolExhausted:
-                self._release_pages(s)
-                raise
+            m, taken = self._pool.join(s.pages, req.pages)
+        self._count_slide(0, taken)
+        chunks = self._prefill_chunks(m * self._pl, p)
         # the slot is taken from here on; its first token comes with the
         # read of the last prefill call
         s.req, s.length, s.last_token = req, p, 0
-        # suffix prefill through the ONE-ROW window step — this request's
-        # tokens, table and start, nobody else's. A suffix that fits a
-        # bucket is one call; a longer one runs as successive chunks of
-        # the largest bucket at start, start + C, ... back to back, each
-        # attending to the pages the earlier ones wrote, and the head is
-        # read after the last
+        # the suffix goes through the ONE-ROW window step: one call if it
+        # fits a bucket, else chunks of the largest back to back, each
+        # attending to the pages the earlier ones wrote
         adm.m = m
         adm.chunks = chunks
-        # a copy: the programs that read it may still be in flight when the
-        # slot's own table is written again
-        adm.table = jnp.asarray(self._slot_tables(s)[..., None, :].copy())
+        adm.table = jnp.asarray(self._pool.row_tables(s.pages))
 
     def _carried_round(self, adm: _Admission, flying) -> Optional[_Round]:
         """The decode round the next window call of ``adm``'s prefill
@@ -2503,12 +2213,12 @@ class GenerationEngine(EngineBase):
                 f"a {Wc}-token prefill call that writes whole pages starts "
                 f"at {lo}, inside a page of {self._pl}: it would overwrite "
                 "cached keys")
-        if self._windowed and adm.outs:
+        if self._layout.window and adm.outs:
+            # a later call's window pages, as it goes out
             s = self._slots[adm.slot_no]
             with span("pt.serve.page_table"):
-                self._window_pages(s, lo, hi - 1)
-                adm.table = jnp.asarray(
-                    self._slot_tables(s)[..., None, :].copy())
+                self._count_slide(*self._pool.slide(s.pages, lo, hi - 1))
+                adm.table = jnp.asarray(self._pool.row_tables(s.pages))
         tokens = np.zeros((1, Wc), dtype=np.int32)
         tokens[0, :hi - lo] = req.prompt[lo:hi]
         tables, tokens = adm.table, jnp.asarray(tokens)
@@ -2546,21 +2256,23 @@ class GenerationEngine(EngineBase):
         # how the call's tokens reached the cache: whole pages, or one row a
         # token (the chunk's where its program scatters, the carried round's)
         self.metrics.inc("kv_pages_written_total", pages)
-        self.metrics.inc("kv_rows_written_total", 0 if self._unpaged else
-                         (0 if pages else Wc)
+        self.metrics.inc("kv_rows_written_total", 0 if not self._layout.paged
+                         else (0 if pages else Wc)
                          + (0 if rnd is None else self.config.max_slots))
         # cached positions the chunk's queries see, summed (token w of the
         # chunk sees lo + w + 1)
         n = hi - lo
         self._count_tokens(n + (0 if rnd is None else len(rnd.rows)))
         self.metrics.inc("attn_keys_prefill_total", n * lo + n * (n + 1) // 2)
-        if self._index:
-            # every "full" layer's indexer scored them all, once a layer
+        layout = self._layout
+        if layout.index:
+            # every layer that owns an indexer scored them all, once a layer
             self.metrics.inc("index_keys_scored_prefill_total",
-                             (n * lo + n * (n + 1) // 2) * self._indexers)
-        if self._by_layer:
+                             (n * lo + n * (n + 1) // 2)
+                             * layout.index.indexers)
+        if layout.by_layer:
             self._count_keys(n * lo + n * (n + 1) // 2,
-                             self._keys_in_window(lo, hi, self._win))
+                             self._keys_in_window(lo, hi, layout.window))
             self._count_walk(Wc, [lo])
         if len(adm.outs) < len(adm.chunks):
             adm.state = row if self._sm.resumes_state else None
@@ -2569,15 +2281,10 @@ class GenerationEngine(EngineBase):
             with span("pt.serve.state_install", slot=adm.slot_no):
                 self._install_state(adm.slot_no, row)
             self.metrics.inc("state_installs_total")
-        trie = self._pool.trie
-        if trie is not None:
-            # adopt this prompt's full blocks into the prefix cache so the
-            # next same-prefix request skips their prefill
-            fp = len(req.prompt) // self._pl
-            table = self._slots[adm.slot_no].table
+        if self._pool.trie is not None:
+            # its full blocks into the prefix cache
             with span("pt.serve.page_table"):
-                trie.insert(req.blocks[:fp], [int(x) for x in table[:fp]],
-                            self._pool.allocator)
+                self._pool.adopt(self._slots[adm.slot_no].pages, req.pages)
 
     def _round_between(self, adm: _Admission) -> Optional[_Round]:
         """A decode round of the running sequences, dispatched between two
@@ -2591,7 +2298,7 @@ class GenerationEngine(EngineBase):
         advance every running sequence's state twice on one token. ``None``
         too where the model is another kind or nothing runs; a fault fails
         the round's requests alone."""
-        if not (self._stateful and self._sm.resumes_state) or \
+        if not (self._layout.stateful and self._sm.resumes_state) or \
                 adm.carried[-1] is not None:
             return None
         rnd = self._build_round(None, joining=adm)
@@ -2611,7 +2318,7 @@ class GenerationEngine(EngineBase):
         _tracer().finish(req.trace, ok=False, error=type(e).__name__)
         self.metrics.inc("errors_total")
         if s.req is req or s.req is None:
-            self._release_pages(s)
+            self._pool.release(s.pages)
             s.req, s.length, s.last_token = None, 0, 0
 
     def _admit(self, adm: _Admission):
@@ -2767,9 +2474,11 @@ class GenerationEngine(EngineBase):
         with span("pt.serve.decode_build"):
             tokens = np.zeros((S, k + 1), dtype=np.int32)
             lengths = np.zeros(S, dtype=np.int32)
-            tables = np.zeros(self._tables_shape(S), dtype=np.int32)
+            pool = self._pool
+            slide, put_tables = pool.slide, pool.put_tables
+            tables = np.zeros(pool.tables_shape(S), dtype=np.int32)
             unread = _unread(flying)
-            rows = []
+            rows, released, taken = [], 0, 0
             for i, s in enumerate(self._slots):
                 req, length = s.req, s.length
                 if req is None or \
@@ -2782,11 +2491,14 @@ class GenerationEngine(EngineBase):
                         continue
                 else:
                     tokens[i, 0] = s.last_token
-                lengths[i] = min(length, self.max_len - 1)
-                if self._windowed:
-                    self._window_pages(s, int(lengths[i]), int(lengths[i]))
-                tables[..., i, :] = self._slot_tables(s)
+                lengths[i] = at = min(length, self.max_len - 1)
+                # the slot's window layers move on to this position
+                moved = slide(s.pages, at, at)
+                released += moved[0]
+                taken += moved[1]
+                put_tables(tables, i, s.pages)
                 rows.append((i, req))
+            self._count_slide(released, taken)
         return _Round(rows, k, tokens, lengths, tables)
 
     def _round_feed(self, rnd: _Round, flying=None):
@@ -2846,7 +2558,7 @@ class GenerationEngine(EngineBase):
                     S, k + 1, jnp.asarray(rnd.tables),
                     self._round_feed(rnd, flying), jnp.asarray(rnd.lengths),
                     n_valid=self._round_valid(rnd))
-        if not self._unpaged:
+        if self._layout.paged:
             self.metrics.inc("kv_rows_written_total", S * (k + 1))
         self._count_tokens(len(rnd.rows) * (k + 1))
         if flying is not None:
@@ -2928,14 +2640,15 @@ class GenerationEngine(EngineBase):
         # cached positions the round's queries see, summed over its rows
         self.metrics.inc("attn_keys_decode_total",
                          int(rnd.lengths.sum()) + n_active)
-        if self._index:
+        layout = self._layout
+        if layout.index:
             self.metrics.inc("index_keys_scored_decode_total",
                              (int(rnd.lengths.sum()) + n_active)
-                             * self._indexers)
-        if self._by_layer:
+                             * layout.index.indexers)
+        if layout.by_layer:
             seen = rnd.lengths[[i for i, _req in rnd.rows]] + 1
             self._count_keys(int(seen.sum()), int(
-                np.minimum(seen, self._win).sum()), decode=True)
+                np.minimum(seen, layout.window).sum()), decode=True)
             # every row of the call walks, a live sequence's or not
             self._count_walk(k + 1, rnd.lengths, decode=True)
         self.metrics.observe_occupancy(n_active / S)
@@ -3011,19 +2724,6 @@ class GenerationEngine(EngineBase):
         self._release_slot(slot_no, now, failed=False)
         return True
 
-    def _release_pages(self, s: _Slot) -> None:
-        """Drop this slot's page refs (shared AND private; pages the trie
-        adopted survive on its ref and stay reusable)."""
-        for bi in range(s.blocks):
-            self._pool.allocator.release(int(s.table[bi]))
-        s.table[:] = 0
-        s.blocks = s.shared = 0
-        if self._windowed:
-            for bi in range(s.wlo, s.whi):
-                self._pool.window_allocator.release(int(s.wtable[bi]))
-            s.wtable[:] = 0
-            s.wlo = s.whi = 0
-
     def _release_slot(self, slot_no: int, now: float, failed: bool,
                       error: Optional[str] = None):
         """Close the residency: decode span + completion on the request's
@@ -3044,8 +2744,8 @@ class GenerationEngine(EngineBase):
                          tokens=tokens)
             self._slot_hist.append((slot_no, t0, now, tokens))
             self._residencies += 1
-        self._release_pages(s)
-        if self._stateful and req is not None:
+        self._pool.release(s.pages)
+        if self._layout.stateful and req is not None:
             # the row's state is dead from here: no decode round advances
             # an idle row, and the next admission overwrites all of it
             self.metrics.inc("state_resets_total")

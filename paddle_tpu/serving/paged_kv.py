@@ -30,11 +30,13 @@ fixed-shape decode executable always has somewhere harmless to write.
 """
 from __future__ import annotations
 
-import threading
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+import dataclasses
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
+
+import numpy as np
 
 __all__ = ["PoolExhausted", "PageAllocator", "PrefixCache", "PagedKVPool",
-           "LAYER_KEEPS",
+           "LAYER_KEEPS", "CacheLayout", "SlotPages", "PageDemand",
            "HostPagePool", "token_blocks", "window_page_bound"]
 
 
@@ -366,8 +368,6 @@ class HostPagePool:
 
     def put(self, key, k_layers, v_layers) -> bool:
         """Spill one page (per-layer ``[page_len, heads, dim]`` arrays)."""
-        import numpy as np
-
         from .kv_transfer import quantize_page
 
         with self._lock:
@@ -412,8 +412,6 @@ class HostPagePool:
             self._entries.move_to_end(key)
             self.hits += 1
             k_q, k_s, v_q, v_s, _ = ent
-        import numpy as np
-
         dt = dtype or np.float32
         return ([dequantize_page(q, s, dt) for q, s in zip(k_q, k_s)],
                 [dequantize_page(q, s, dt) for q, s in zip(v_q, v_s)])
@@ -441,20 +439,12 @@ def window_page_bound(window: int, tokens: int, page_len: int) -> int:
 
 # What a layer of each declared kind (``cache_spec["layers"]``) keeps: the
 # paging kind whose pool and table hold its pages (``None``: it pages nothing)
-# and whether it keeps a row of the ``state_spec`` arenas. ``"full+state"`` is
-# a layer of BOTH memories: a page for every ``page_len`` tokens AND a row by
-# slot (an attention whose keys are mixed over the sequence before they are
-# cached: the conv's tail is the row). ``layers_by_kind`` counts from this
-# table alone: a kind's name is only a name.
+# and whether it keeps a row of the ``state_spec`` arenas. A kind's name is
+# only a name: whoever asks what a layer keeps reads this pair
+# (``CacheLayout.keeps``).
 LAYER_KEEPS = {"full": ("full", False), "window": ("window", False),
                "state": (None, True), "none": (None, False),
                "full+state": ("full", True)}
-
-
-def _paging(kinds) -> List[str]:
-    """The paging kind of each layer of ``kinds`` that pages, in order: one
-    K/V (or latent) arena each."""
-    return [pages for pages, _row in map(LAYER_KEEPS.get, kinds) if pages]
 
 
 def latent_width(dim: int) -> int:
@@ -463,146 +453,531 @@ def latent_width(dim: int) -> int:
     return -(-int(dim) // 128) * 128
 
 
-class PagedKVPool:
-    """The device half: per-layer K/V page arenas + the control plane.
+class LatentRow(NamedTuple):
+    """The one row a token leaves in a latent layer of one paging kind."""
+    dim: int                  # values a token leaves
+    width: int                # the arena's columns (``latent_width(dim)``)
+    value_dim: int            # leading columns that are the value
+    scale: Optional[float]    # softmax scale (None: the model's attn_scale)
+    heads: Optional[int]      # query heads (None: the model's num_heads)
 
-    ``allocate(n)`` serves from the free list, evicting LRU prefix-cache
-    entries when short — so a hot serving process naturally trades cold
-    cached prefixes for live requests. With a ``warm_pool``, evicted
-    prefix pages spill (int8) to host RAM and can be restored by
-    ``warm_restore`` instead of re-prefilling.
+
+class CacheIndex(NamedTuple):
+    """A learned sparse attention's index row (``cache_spec["index"]``)."""
+    dim: int
+    heads: int
+    topk: int
+    layers: Tuple[Optional[str], ...]  # "full": owns an indexer; "shared":
+    # attends the last selection; None: selects nothing (a window layer)
+
+    @property
+    def indexers(self) -> int:
+        return self.layers.count("full")
+
+
+# what a cache kind cannot use, in the words the engine refuses it with: by
+# feature, the first kind that applies (``CacheLayout.refuses``)
+_STATE = "{model} carries recurrent state per slot: "
+_WINDOW = ("{model} keeps a sliding window of {window} keys in some of its "
+           "layers, whose pages go back to the pool as the window passes "
+           "them: ")
+_REFUSALS = {
+    "prefix_cache": (
+        ("stateful", _STATE + "a cached K/V prefix has no state to resume "
+         "from, so the prefix cache cannot serve it — pass "
+         "GenerationConfig(prefix_cache=False)"),
+        ("window", _WINDOW + "a cached prefix's pages behind the window are "
+         "gone, so the prefix cache cannot serve it — pass "
+         "GenerationConfig(prefix_cache=False)")),
+    "draft_model": (
+        ("stateful", _STATE + "a rejected draft token would have advanced it "
+         "and it cannot be rolled back, so speculative decoding is refused — "
+         "pass draft_model=None"),
+        ("window", _WINDOW + "a verify round that rejects draft tokens would "
+         "have to take back pages already given away, so speculative decoding "
+         "is refused — pass draft_model=None"),
+        ("index", "{model} attends the keys an indexer selects: a verify "
+         "window of draft tokens would select with them in the cache and no "
+         "test holds that path yet, so speculative decoding is refused — pass "
+         "draft_model=None")),
+    "warm_pool": (
+        ("unpaged", "{model} keeps no K/V pages at all: the warm tier has "
+         "nothing to spill or restore — pass "
+         "GenerationConfig(warm_pool_bytes=0)"),
+        ("mixed", "{model} keeps pages in some of its layers and a recurrent "
+         "state in others (or both in one): the warm tier spills and restores "
+         "prefixes of pages, and a prefix's state is in none — pass "
+         "GenerationConfig(warm_pool_bytes=0)"),
+        ("window", _WINDOW + "the warm tier spills and restores whole "
+         "prefixes — pass GenerationConfig(warm_pool_bytes=0)"),
+        ("latent", "{model} caches one latent row a token: the warm tier "
+         "spills and restores K/V pages — pass "
+         "GenerationConfig(warm_pool_bytes=0)")),
+    "kv_transfer": (
+        ("unpaged", "{model} keeps no K/V pages at all — a sequence is its "
+         "recurrent state, and no state snapshot is shipped"),
+        ("window", "{model} keeps a sliding window in some of its layers — "
+         "their pages behind the window have gone back to the pool, so a "
+         "prompt's cache cannot be read out or installed page by page"),
+        ("latent", "{model} caches one latent row a token — the page "
+         "shipper's wire format is K and V stacks of [pages, page_len, heads, "
+         "dim] and cannot carry it yet"),
+        ("stateful", "{model} carries recurrent state per slot — its K/V "
+         "pages alone do not resume a sequence, and no state snapshot is "
+         "shipped with them")),
+}
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheLayout:
+    """What a served model's ``cache_spec`` (and ``state_spec``) come to: the
+    ONE reader of that format, for the pool's arenas, the window programs'
+    ``attend`` by layer and the engine's refusals and counters alike. Never
+    changed once parsed.
+
+    ``cache_spec`` says what ONE token leaves in a layer's paged cache:
+
+    - ``None`` (kind ``"kv"``: GPT-2, Falcon-H1): a key and a value of
+      ``[kv_heads, head_dim]``; arenas K and V ``[pages, page_len, kv_heads,
+      head_dim]`` a layer (``kernels.pallas.paged_attention``, which walks
+      every page of every row);
+    - ``{"kind": "latent", "dim": d, "value_dim": dv}``: ONE row of ``d``
+      values, the first ``dv`` the value; one arena a layer, ``[pages,
+      page_len, d rounded up to 128 lanes]`` (``k``; no V), and
+      ``mla_paged_attention`` walks the pages each row's length covers. With
+      an ``"index"`` group ``{"dim": di, "heads": hi, "topk": k, "layers":
+      [...]}`` (a learned sparse attention) a token leaves a SECOND row of
+      ``di`` values, its index key, in every layer the group's ``layers`` calls
+      ``"full"`` (it owns an indexer): arenas of their own (``v``: ``[pages,
+      page_len, di]``) on the SAME page table, so the prefix trie shares both
+      rows of a page; a ``"shared"`` layer attends what the last ``"full"``
+      one selected and ``None`` (a window layer) selects nothing;
+    - ``{"kind": "kv_by_layer", "layers": [...]}``: a key and a value again,
+      laid out ``[pages, kv_heads, page_len, head_dim]`` — one K/V head's
+      tokens contiguous — for ``ranged_paged_attention``, which takes each
+      row's own range of pages;
+    - ``{"kind": "none"}``: NOTHING paged — no arena, no table, admission by
+      slots alone; every layer's memory is its recurrent state, so it needs a
+      ``state_spec`` (Brumby).
+
+    ``"layers"`` (either ranged kind: ``"kv_by_layer"``, or ``"latent"``)
+    names what EACH layer keeps (``LAYER_KEEPS``): ``"full"`` a page for every
+    ``page_len`` tokens cached, from the pool's allocator; ``"window"`` only
+    the pages that still hold a key a later query can see (``"window": n``:
+    query ``i`` sees keys ``i - n < j <= i``), from an allocator and through a
+    table of their own — the tables of a cache that declares kinds are stacked
+    ``[kinds, rows, B]``, both by absolute block; ``"state"`` no page but a row
+    of the ``state_spec`` arenas; ``"none"`` nothing (a position-wise layer);
+    ``"full+state"`` both a full layer's pages and a row (an attention whose
+    keys are mixed over the sequence before they are cached: the conv's tail).
+    Arenas exist for the layers that page alone (in their order) and state
+    arenas (``state_spec``: ``{name: (per-slot shape, dtype)}``) for those
+    that keep a row alone; at least one layer pages, and a ``state_spec`` is
+    declared exactly where some layer keeps a row. With no ``"layers"`` every
+    layer is ``"full"`` (kind ``"none"``: nothing) and keeps a row iff the
+    model has a ``state_spec``. A latent cache's window layers may have a row
+    of their own, ``"window_row": {"dim", "value_dim", "scale", "heads"}``."""
+
+    kind: str                       # "kv", "latent", "kv_by_layer", "none"
+    paged: bool                     # some layer keeps pages
+    latent: bool                    # a page holds rows, not keys and values
+    ranged: bool                    # the kernel takes each row's own range
+    stateful: bool                  # some layer keeps a row (``state_spec``)
+    by_layer: bool                  # kinds are declared: tables are stacked
+    kinds: Optional[List[str]]      # the declared kinds, a layer each
+    keeps: List[Tuple[Optional[str], bool]]  # (paging kind, row) a layer
+    table_kinds: Tuple[str, ...]    # ("full",) or ("full", "window")
+    layers_of: Dict[str, int]       # table kind -> layers that page so
+    state_layers: int               # layers that keep a row
+    window: int                     # the window layers' n (0: none)
+    index: Optional[CacheIndex]
+    rows: Dict[str, LatentRow]      # a latent cache's row, by paging kind
+    page: Tuple[int, int, int]      # (kv heads, page_len, head_dim)
+    state_spec: Optional[Dict[str, Any]]
+    # why this kind cannot use "prefix_cache", "draft_model", "warm_pool",
+    # "kv_transfer" (a feature it can use has no entry); why no page of it
+    # can be shared or spilled (None: one can)
+    refuses: Dict[str, str]
+    private: Optional[str]
+
+    @staticmethod
+    def kind_of(cache_spec) -> str:
+        if cache_spec is None:
+            return "kv"
+        kind = cache_spec["kind"]
+        if kind not in ("latent", "kv_by_layer", "none"):
+            raise ValueError(
+                f"unknown cache kind {kind!r}: a served "
+                "model's cache_spec is None (K and V), 'latent', "
+                "'kv_by_layer' or 'none'")
+        return kind
+
+    @classmethod
+    def ranges(cls, cache_spec) -> bool:
+        """Whether the cache's kernel takes each row's own range of pages."""
+        return cls.kind_of(cache_spec) in ("latent", "kv_by_layer")
+
+    @classmethod
+    def parse(cls, cache_spec, state_spec, num_layers: int, page_len: int,
+              num_kv_heads: int, head_dim: int) -> "CacheLayout":
+        spec = cache_spec or {}
+        stateful = state_spec is not None
+        kinds = spec.get("layers")
+        if kinds is not None:
+            kinds = list(kinds)
+            if len(kinds) != num_layers or set(kinds) - set(LAYER_KEEPS):
+                raise ValueError(
+                    f"cache_spec['layers'] must name {num_layers} layers "
+                    "'full' or 'window' (pages), 'state' or 'none', or "
+                    f"'full+state' (pages and a state row), got {kinds}")
+            keeps = [LAYER_KEEPS[kind] for kind in kinds]
+            if any(row for _pages, row in keeps) != stateful:
+                raise ValueError(
+                    "cache_spec['layers'] names a layer that keeps state "
+                    "('state', 'full+state') exactly where the model "
+                    "declares a "
+                    f"state_spec: got {kinds} and state_spec {state_spec}")
+            if not any(pages for pages, _row in keeps):
+                raise ValueError(
+                    f"cache_spec['layers'] {kinds} pages nothing: a model "
+                    "with nothing paged declares a cache_spec of kind 'none'")
+        kind = cls.kind_of(cache_spec)
+        if kind == "none" and not stateful:
+            raise ValueError(
+                "cache_spec of kind 'none' with no state_spec: the "
+                "model would remember nothing")
+        if kinds is None:
+            keeps = [("full" if kind != "none" else None, stateful)] \
+                * num_layers
+        paging = [pages for pages, _row in keeps]
+        window = int(spec["window"]) if "window" in paging else 0
+        table_kinds = ("full", "window")[:1 + bool(window)]
+        latent = kind == "latent"
+        rows, index = {}, None
+        if latent:
+            for name in table_kinds:
+                own = spec.get("window_row", spec) if name == "window" \
+                    else spec
+                rows[name] = LatentRow(
+                    int(own["dim"]), latent_width(own["dim"]),
+                    int(own["value_dim"]), own.get("scale"),
+                    own.get("heads"))
+            if spec.get("index"):
+                group = spec["index"]
+                index = CacheIndex(int(group["dim"]), int(group["heads"]),
+                                   int(group["topk"]), tuple(group["layers"]))
+        private = None
+        if kinds is not None:
+            private = ("a cache of two layer kinds has no prefix cache and "
+                       "no warm tier: a shared page behind a window has been "
+                       "given back, and a layer's recurrent state is in no "
+                       "page")
+        elif kind == "none":
+            private = ("a model with nothing paged has no prefix cache and "
+                       "no warm tier: there is no page to share or spill")
+        applies = {"stateful": stateful, "window": bool(window),
+                   "index": index is not None, "unpaged": kind == "none",
+                   "mixed": kinds is not None and not window,
+                   "latent": latent}
+        refuses = {}
+        for feature, reasons in _REFUSALS.items():
+            why = next((why for what, why in reasons if applies[what]), None)
+            if why is not None:
+                refuses[feature] = why.replace("{window}", str(window))
+        return cls(
+            kind=kind, paged=kind != "none", latent=latent,
+            ranged=kind in ("latent", "kv_by_layer"), stateful=stateful,
+            by_layer=kinds is not None, kinds=kinds, keeps=keeps,
+            table_kinds=table_kinds,
+            layers_of={name: paging.count(name) for name in table_kinds},
+            state_layers=sum(row for _pages, row in keeps), window=window,
+            index=index, rows=rows,
+            page=(int(num_kv_heads), int(page_len), int(head_dim)),
+            state_spec=state_spec, refuses=refuses, private=private)
+
+    def arenas(self, num_pages: int, window_pages: int):
+        """The shapes of ``(k, v)``: an arena for each layer that pages, in
+        the layers' order, a window layer's of ``window_pages`` pages; ``v``
+        of a latent cache holds its index keys, an arena an indexer."""
+        G, PL, d = self.page
+        paging = [kind for kind, _row in self.keeps if kind]
+        pages = [window_pages if kind == "window" else num_pages
+                 for kind in paging]
+        if self.latent:
+            index = self.index
+            return ([(p, PL, self.rows[kind].width)
+                     for p, kind in zip(pages, paging)],
+                    [(num_pages, PL, index.dim)] * index.indexers
+                    if index else [])
+        shapes = [(p, G, PL, d) if self.ranged else (p, PL, G, d)
+                  for p in pages]
+        return shapes, shapes
+
+
+class SlotPages:
+    """The pages ONE slot holds, handed out by ``PagedKVPool.slot_pages`` and
+    filled, slid and emptied by the pool alone (``join``, ``slide``,
+    ``release``). ``tables``: a row for each paging kind; ``table`` (row 0):
+    page ids by absolute block (0: the scratch page), ``blocks`` of them
+    allocated (0: the slot is free), the leading ``shared`` borrowed from the
+    prefix cache. With window layers ``wtable`` (row 1) is theirs, by ABSOLUTE
+    block too: blocks ``[wlo, whi)`` hold a page, the blocks behind the
+    window have given theirs back."""
+
+    __slots__ = ("tables", "table", "blocks", "shared", "wtable", "wlo",
+                 "whi")
+
+    def __init__(self, n_blocks: int, kinds: int):
+        self.tables = np.zeros((kinds, n_blocks), dtype=np.int32)
+        self.table = self.tables[0]
+        self.wtable = self.tables[1] if kinds > 1 else None
+        self.blocks = self.shared = self.wlo = self.whi = 0
+
+
+class PageDemand(NamedTuple):
+    """What a request asks of the pool, worked out ONCE at submit
+    (``PagedKVPool.demand``): the admission scan runs under the engine lock."""
+    blocks: List[Tuple[int, ...]]   # the prompt's full token-blocks
+    total: int                      # worst-case pages of a full layer
+    window: int                     # window pages its widest call holds
+    prompt_len: int
+
+
+class PagedKVPool:
+    """The device half: per-layer page arenas + the control plane of the
+    cache ``layout`` describes — and every slot's pages (``SlotPages``): the
+    scheduler asks whether a request can join (``can_allocate``), joins it
+    (``join``), moves a slot to its next program's positions (``slide``) and
+    gives its pages back (``release``); how many allocators answer is the
+    pool's business.
+
+    ``allocate`` evicts LRU prefix-cache entries when the free list is short
+    (with a ``warm_pool`` they spill, int8, to host RAM: ``warm_restore``).
+    ``n_blocks``: the blocks of a sequence's table (``max_seq_len`` in
+    pages). ``chunk``: the most tokens one program writes (the engine's
+    largest prefill bucket) — it sets how many pages a slot's window layers
+    hold while its chunk runs (``window_page_bound``), and ``window_pages``
+    (None: every slot's decode bound + three chunks' worth + scratch) must
+    cover every slot decoding plus one such chunk.
     """
 
-    def __init__(self, num_layers: int, num_pages: int, page_len: int,
-                 num_heads: int, head_dim: int, dtype,
+    def __init__(self, layout: CacheLayout, num_pages: int, dtype,
                  prefix_cache: bool = True,
                  warm_pool: Optional[HostPagePool] = None,
-                 state_spec=None, max_slots: int = 0, cache_spec=None,
-                 window_pages: int = 0):
+                 max_slots: int = 0, n_blocks: int = 0,
+                 window_pages: Optional[int] = None, chunk: int = 1):
         import jax.numpy as jnp
 
-        self.page_len = int(page_len)
+        if layout.private and (prefix_cache or warm_pool is not None):
+            raise ValueError(layout.private)
+        self.layout = layout
+        self.layer_kinds = layout.kinds
+        self.page_len = pl = layout.page[1]
         self.num_pages = int(num_pages)
+        self.n_blocks, self.chunk = int(n_blocks), int(chunk)
         self.allocator = PageAllocator(num_pages)
         self.trie: Optional[PrefixCache] = PrefixCache() if prefix_cache \
             else None
         self.warm = warm_pool
-        # what a token leaves in a layer (the served model's ``cache_spec``):
-        # K and V of [heads, head_dim] — or ONE latent row, kept in ``k``
-        # alone at a whole number of 128-lane tiles (a page is DMA'd whole)
-        # — or K and V by the layer's KIND: a "full" layer keeps a page for
-        # every ``page_len`` tokens cached, from ``allocator``; a "window"
-        # layer only the pages that still hold a key some later query can
-        # see, from ``window_allocator`` (``window_pages`` of them, scratch
-        # included). Both kinds lay a page out [heads, page_len, head_dim]:
-        # one K/V head's tokens contiguous (``ranged_paged_attention``) — or
-        # ONE latent row by the layer's kind (a latent spec with ``"layers"``:
-        # the same two allocators, a window layer's rows of their own width)
-        self.cache_spec = cache_spec
+        # the window layers' pages: an allocator of their own, and the most
+        # a slot holds of them while it decodes (``window_bound``)
+        self.window = layout.window
         self.window_allocator: Optional[PageAllocator] = None
-        self.layer_kinds: Optional[List[str]] = None
-        # a cache that declares its layers' KINDS, K/V or latent
-        # (``cache_spec["layers"]``): a "full" layer's arenas hold the pool's
-        # pages, a "window" layer's ``window_pages`` pages of an allocator of
-        # their own; a "state" layer keeps no page but a row of the state
-        # arenas below, a "none" layer nothing at all, a "full+state" layer a
-        # full layer's pages AND a row (``LAYER_KEEPS``)
-        kinds = self._kinds(cache_spec, num_layers, window_pages,
-                            prefix_cache or warm_pool is not None, state_spec)
-        kinds = [None] * num_layers if kinds is None else _paging(kinds)
-        pages_of = [window_pages if kind == "window" else num_pages
-                    for kind in kinds]
-        if cache_spec is None:
-            shapes = [(num_pages, page_len, num_heads, head_dim)] * num_layers
-        elif cache_spec["kind"] == "latent":
-            # a window layer's row may have a width of its own
-            # (``cache_spec["window_row"]``)
-            wide = {"window": cache_spec.get("window_row", cache_spec)["dim"]}
-            shapes = [(n, page_len, latent_width(
-                wide.get(kind, cache_spec["dim"])))
-                for n, kind in zip(pages_of, kinds)]
-        elif cache_spec["kind"] == "kv_by_layer":
-            # (an arena for each layer that pages, in the layers' order)
-            shapes = [(n, num_heads, page_len, head_dim) for n in pages_of]
-        elif cache_spec["kind"] == "none":
-            # NOTHING paged: every layer's memory is its recurrent state
-            # (``state_spec``), whatever the context. No arena, no page a
-            # request could hold; the allocator keeps the scratch page alone
-            if state_spec is None:
+        self.window_bound = 0
+        if self.window:
+            S = int(max_slots)
+            self.window_bound = bound = window_page_bound(self.window, 1, pl)
+            widest = window_page_bound(self.window, chunk, pl)
+            if window_pages is None:
+                window_pages = S * bound + 3 * widest + 1
+            if window_pages < S * bound + widest + 1:
                 raise ValueError(
-                    "cache_spec of kind 'none' with no state_spec: the "
-                    "model would remember nothing")
-            if prefix_cache or warm_pool is not None:
-                raise ValueError(
-                    "a model with nothing paged has no prefix cache and no "
-                    "warm tier: there is no page to share or spill")
-            shapes = []
-        else:
-            raise ValueError(
-                f"unknown cache kind {cache_spec['kind']!r}: a served "
-                "model's cache_spec is None (K and V), 'latent', "
-                "'kv_by_layer' or 'none'")
-        self.k = [jnp.zeros(shape, dtype) for shape in shapes]
-        if cache_spec is not None and cache_spec["kind"] == "latent":
-            # a latent cache keeps no V; with an index row (a learned sparse
-            # attention: ``cache_spec["index"]``) ``v`` holds the index keys
-            # instead, one arena a layer that owns an indexer ("full"), on
-            # the same pages: page p of a sequence holds its tokens' latent
-            # rows in ``k`` and their index keys in ``v``
-            index = cache_spec.get("index")
-            self.v = [] if not index else [
-                jnp.zeros((num_pages, page_len, int(index["dim"])), dtype)
-                for kind in index["layers"] if kind == "full"]
-        else:
-            self.v = [jnp.zeros(shape, dtype) for shape in shapes]
-        # the second kind of cache: per layer, one slot-indexed arena per
-        # entry of a recurrent model's ``state_spec`` ({name: (per-slot
-        # shape, dtype)}) — e.g. the SSM state [slots, heads, P, N] and the
-        # conv tail [slots, d_conv - 1, channels]. Donated into every
-        # program like the K/V arenas and updated in place; a row is
-        # overwritten whole when its slot is admitted. None: K/V only. Where
-        # the cache declares its layers' kinds, the layers that keep a row
-        # alone have one (in their order), else every layer.
-        self.state = None if state_spec is None else [
-            {name: jnp.zeros((int(max_slots),) + tuple(shape), dt)
-             for name, (shape, dt) in state_spec.items()}
-            for _ in range(num_layers if self.layer_kinds is None
-                           else self.layers_by_kind().get("state", 0))]
-
-    def _kinds(self, cache_spec, num_layers: int, window_pages: int,
-               shares_pages: bool, state_spec) -> Optional[List[str]]:
-        """The layers' kinds where the cache declares them (``"layers"``: a
-        K/V cache ``kv_by_layer``, or a latent one), and the window layers'
-        allocator with them; ``None`` for a cache of one kind."""
-        kinds = (cache_spec or {}).get("layers")
-        if kinds is None:
-            return None
-        kinds = list(kinds)
-        if len(kinds) != num_layers or set(kinds) - set(LAYER_KEEPS):
-            raise ValueError(
-                f"cache_spec['layers'] must name {num_layers} layers "
-                "'full' or 'window' (pages), 'state' or 'none', or "
-                f"'full+state' (pages and a state row), got {kinds}")
-        keeps = [LAYER_KEEPS[kind] for kind in kinds]
-        if any(row for _pages, row in keeps) != (state_spec is not None):
-            raise ValueError(
-                "cache_spec['layers'] names a layer that keeps state "
-                "('state', 'full+state') exactly where the model declares a "
-                f"state_spec: got {kinds} and state_spec {state_spec}")
-        if not any(pages for pages, _row in keeps):
-            raise ValueError(
-                f"cache_spec['layers'] {kinds} pages nothing: a model with "
-                "nothing paged declares a cache_spec of kind 'none'")
-        if shares_pages:
-            raise ValueError(
-                "a cache of two layer kinds has no prefix cache and no "
-                "warm tier: a shared page behind a window has been "
-                "given back, and a layer's recurrent state is in no page")
-        self.layer_kinds = kinds
-        if "window" in kinds:
-            self.window = int(cache_spec["window"])
+                    f"window_pages {window_pages}: the window layers need "
+                    f"{bound} pages for each of {S} slots that decode, "
+                    f"{widest} for the one whose {chunk}-token chunk is "
+                    f"running, and the scratch page: {S * bound + widest + 1}")
             self.window_allocator = PageAllocator(window_pages)
-        return kinds
+        # tables stacked by kind, [kinds, rows, B], where kinds are declared
+        self._stacked = len(layout.table_kinds) * layout.by_layer
+        self._slots: List[SlotPages] = []
+        self._reserved: Optional[int] = None   # ``_window_reserved``, cached
+        k_shapes, v_shapes = layout.arenas(num_pages, window_pages)
+        self.k = [jnp.zeros(shape, dtype) for shape in k_shapes]
+        self.v = [jnp.zeros(shape, dtype) for shape in v_shapes]
+        # the second kind of cache: one slot-indexed arena per entry of the
+        # ``state_spec`` in every layer that keeps a row (the SSM state
+        # [slots, heads, P, N], the conv tail). Donated into every program
+        # like the K/V arenas and updated in place; a row is overwritten
+        # whole when its slot is admitted. None: pages only
+        self.state = None if not layout.stateful else [
+            {name: jnp.zeros((int(max_slots),) + tuple(shape), dt)
+             for name, (shape, dt) in layout.state_spec.items()}
+            for _ in range(layout.state_layers)]
+
+    # -- a slot's pages -------------------------------------------------------
+    def slot_pages(self) -> SlotPages:
+        """The (empty) pages of one more slot; the pool keeps it in view."""
+        pages = SlotPages(self.n_blocks, len(self.layout.table_kinds))
+        self._slots.append(pages)
+        return pages
+
+    def tables_shape(self, rows: int) -> Tuple[int, ...]:
+        """A window program's page tables for ``rows`` rows: ``[rows, B]``,
+        or a table for each paging kind stacked, ``[kinds, rows, B]``."""
+        return ((self._stacked,) if self._stacked else ()) + \
+            (rows, self.n_blocks)
+
+    def put_tables(self, out: np.ndarray, row: int, pages: SlotPages) -> None:
+        """A slot's tables into row ``row`` of ``out`` (``tables_shape``)."""
+        out[..., row, :] = pages.tables if self._stacked else pages.table
+
+    def row_tables(self, pages: SlotPages) -> np.ndarray:
+        """A slot's tables as a one-row program takes them — a COPY: the
+        programs that read it may still be in flight when the slot's own
+        tables are written again."""
+        own = pages.tables if self._stacked else pages.table
+        return own[..., None, :].copy()
+
+    def demand(self, prompt, max_new_tokens: int) -> PageDemand:
+        """What a request must find free at its join: its worst case in full
+        pages and, of the window pages, what its widest prefill call holds —
+        never less than a decoding slot's."""
+        p, pl = len(prompt), self.page_len
+        if not self.layout.paged:
+            return PageDemand([], 0, 0, p)
+        window = max(window_page_bound(self.window, min(p, self.chunk), pl),
+                     self.window_bound) if self.window else 0
+        return PageDemand(token_blocks(prompt, pl),
+                          -(-(p + max_new_tokens) // pl), window, p)
+
+    def _window_reserved(self) -> int:
+        """Window pages promised to the running slots beyond what they hold
+        (each may grow to its decode bound); once an admission scan."""
+        if self._reserved is None:
+            bound = self.window_bound
+            self._reserved = sum(max(bound - (s.whi - s.wlo), 0)
+                                 for s in self._slots if s.blocks)
+        return self._reserved
+
+    def can_allocate(self, demand: PageDemand) -> bool:
+        """Can this request join now? Its worst case less what the prefix
+        cache holds of it must be free (or evictable) and, with window layers,
+        its widest call's pages beside what the decoding slots are promised."""
+        wa = self.window_allocator
+        if wa is not None and \
+                demand.window > wa.free_pages - self._window_reserved():
+            return False
+        n = demand.total
+        if self.trie is not None:
+            n -= self.trie.match_len(
+                demand.blocks[: (demand.prompt_len - 1) // self.page_len])
+        free = self.allocator.free_pages
+        if n <= free:
+            return True
+        if self.trie is None:
+            return False
+        # leaf-only eviction frees parents as it goes, so every trie-only
+        # page is ultimately reachable: count all of them
+        evictable = sum(1 for node in self.trie._nodes.values()
+                        if self.allocator.ref(node.page) == 1)
+        return n <= free + evictable
+
+    def join(self, pages: SlotPages, demand: PageDemand) -> Tuple[int, int]:
+        """Give a joining prompt ALL its pages, or leave the pool as it was
+        and raise ``PoolExhausted``: borrow its cached prefix's pages,
+        allocate private ones for the rest, and take the window pages of its
+        first prefill call (the later calls': ``slide``). Returns ``(m,
+        taken)``: the leading blocks borrowed — the prefill starts at ``m *
+        page_len`` — and the window pages taken."""
+        p, pl, total = demand.prompt_len, self.page_len, demand.total
+        table = pages.table
+        table[:] = 0
+        # prefix reuse: longest cached chain of full prompt blocks, capped
+        # so at least one suffix token remains to produce the first logits
+        shared: List[int] = []
+        if self.trie is not None:
+            head = demand.blocks[: (p - 1) // pl]
+            if self.warm is not None:
+                # a previously-evicted prefix then costs a host dequantize
+                # instead of a re-prefill
+                self.warm_restore(head)
+            shared = self.trie.match(head, pl, self.allocator)
+        m = len(shared)
+        try:
+            private = self.allocate(total - m)
+        except PoolExhausted:
+            for pg in shared:
+                self.allocator.release(pg)
+            raise
+        table[:m] = shared
+        table[m:total] = private
+        pages.blocks, pages.shared = total, m
+        # COW hook: every block the decode path will write must be
+        # exclusively ours. By construction they already are (the trie
+        # shares FULL prompt blocks only), so this is a no-op guard — but a
+        # future partial-block sharing scheme lands here.
+        for bi in range(p // pl, total):
+            pg, copied = self.ensure_writable(int(table[bi]))
+            if copied:
+                table[bi] = pg
+        try:
+            _released, taken = self.slide(
+                pages, m * pl, min(p, m * pl + self.chunk) - 1)
+        except PoolExhausted:
+            self.release(pages)
+            raise
+        self._reserved = None
+        return m, taken
+
+    def slide(self, pages: SlotPages, lo: int, hi: int) -> Tuple[int, int]:
+        """The next program's queries of this slot sit at positions ``[lo,
+        hi]``: its window layers give back every page whose keys all lie
+        behind ``lo - (window - 1)``, the first key ``lo`` can see, and take
+        pages for the blocks up to ``hi``'s; ``(released, taken)``. Nothing
+        to do, ``(0, 0)``, with no window layer. Pages change hands in
+        dispatch order, which is the device's order: a program still in
+        flight reads its own copy of the table and runs before whatever
+        writes the page next."""
+        wa = self.window_allocator
+        if wa is None:
+            return 0, 0
+        pl, wtable = self.page_len, pages.wtable
+        first = min(max(lo - (self.window - 1), 0) // pl, pages.whi)
+        released = max(first - pages.wlo, 0)
+        if released:
+            for b in range(pages.wlo, first):
+                wa.release(int(wtable[b]))
+                wtable[b] = 0
+            pages.wlo = first
+            self._reserved = None
+        need = hi // pl + 1
+        taken = max(need - pages.whi, 0)
+        if taken:   # positions are contiguous: wlo <= first <= whi
+            wtable[pages.whi:need] = wa.alloc(taken)
+            pages.whi = need
+            self._reserved = None
+        return released, taken
+
+    def release(self, pages: SlotPages) -> None:
+        """Drop a slot's page refs (shared AND private; pages the trie
+        adopted survive on its ref and stay reusable)."""
+        table = pages.table
+        for bi in range(pages.blocks):
+            self.allocator.release(int(table[bi]))
+        table[:] = 0
+        pages.blocks = pages.shared = 0
+        if self.window_allocator is not None:
+            for bi in range(pages.wlo, pages.whi):
+                self.window_allocator.release(int(pages.wtable[bi]))
+            pages.wtable[:] = 0
+            pages.wlo = pages.whi = 0
+        self._reserved = None
+
+    def adopt(self, pages: SlotPages, demand: PageDemand) -> None:
+        """A finished prefill's full prompt blocks into the prefix cache."""
+        fp = demand.prompt_len // self.page_len
+        self.trie.insert(demand.blocks[:fp],
+                         [int(x) for x in pages.table[:fp]], self.allocator)
 
     # -- control plane --------------------------------------------------------
     def allocate(self, n: int) -> List[int]:
@@ -616,8 +991,6 @@ class PagedKVPool:
 
     def _spill(self, key, page: int) -> None:
         """Warm-tier spill hook: page contents -> host RAM (int8)."""
-        import numpy as np
-
         self.warm.note_access(key)
         k_layers = [np.asarray(a[page]) for a in self.k]
         v_layers = [np.asarray(a[page]) for a in self.v]
@@ -630,8 +1003,6 @@ class PagedKVPool:
         adopt it into the trie. Returns pages restored."""
         if self.trie is None or self.warm is None or not blocks:
             return 0
-        import numpy as np
-
         depth = self.trie.match_len(blocks)
         # note accesses for the whole tail so repeat traffic becomes
         # admittable even before anything is ever spilled
@@ -664,26 +1035,6 @@ class PagedKVPool:
             restored += 1
         return restored
 
-    def can_allocate(self, n: int, n_window: int = 0,
-                     window_reserved: int = 0) -> bool:
-        """Can a request join that needs ``n`` pages of the full layers
-        and, in a cache of two layer kinds, ``n_window`` pages of the window
-        layers while ``window_reserved`` more stay promised to the slots
-        that are decoding (each may yet grow to its bound)?"""
-        if n_window and n_window > \
-                self.window_allocator.free_pages - window_reserved:
-            return False
-        free = self.allocator.free_pages
-        if n <= free:
-            return True
-        if self.trie is None:
-            return False
-        # leaf-only eviction frees parents as it goes, so every trie-only
-        # page is ultimately reachable: count all of them
-        evictable = sum(1 for node in self.trie._nodes.values()
-                        if self.allocator.ref(node.page) == 1)
-        return n <= free + evictable
-
     def ensure_writable(self, page: int) -> Tuple[int, bool]:
         """COW hook: give the caller a page it may write. When the page is
         shared, a fresh page is allocated and the K/V CONTENT IS COPIED
@@ -702,8 +1053,6 @@ class PagedKVPool:
                 return arena.at[d].set(arena[s])
 
             fn = self._copy_fn = jax.jit(copy)
-        import numpy as np
-
         s, d = np.int32(src), np.int32(dst)
         self.k = [fn(a, s, d) for a in self.k]
         self.v = [fn(a, s, d) for a in self.v]
@@ -713,8 +1062,6 @@ class PagedKVPool:
         """Page CONTENTS as per-layer host arrays ``[n, page_len, h, d]``
         (the export path). Caller must hold refs on ``pages``."""
         import jax.numpy as jnp
-        import numpy as np
-
         idx = jnp.asarray(list(pages), dtype=jnp.int32)
         return ([np.asarray(a[idx]) for a in self.k],
                 [np.asarray(a[idx]) for a in self.v])
@@ -750,8 +1097,8 @@ class PagedKVPool:
         where it declares two (``latent_full`` / ``latent_window``); the
         state arenas as ``"state"`` where some layers are declared to keep
         one."""
-        kinds = _paging(self.layer_kinds or ["full"] * len(self.k))
-        if self.cache_spec is not None and self.cache_spec.get("index"):
+        kinds = [kind for kind, _row in self.layout.keeps if kind]
+        if self.layout.index:
             names = [f"latent_{kind}" for kind in kinds] \
                 if self.layer_kinds else ["latent"] * len(self.k)
             out = {name: 0 for name in names}
@@ -772,8 +1119,7 @@ class PagedKVPool:
         under both its memories: ``{"full": 20, "state": 20}``), else every
         layer under the one kind the cache has."""
         if self.layer_kinds is None:
-            one = "kv" if self.cache_spec is None else self.cache_spec["kind"]
-            return {one: len(self.k) or len(self.state or ())}
+            return {self.layout.kind: len(self.k) or len(self.state or ())}
         out: Dict[str, int] = {}
         for pages, row in map(LAYER_KEEPS.get, self.layer_kinds):
             names = [pages] * bool(pages) + ["state"] * row
@@ -794,8 +1140,7 @@ class PagedKVPool:
     def stats(self) -> Dict[str, Any]:
         a = self.allocator
         out = {"pages_total": a.num_pages, "page_len": self.page_len,
-               "cache": "kv" if self.cache_spec is None
-               else self.cache_spec["kind"],
+               "cache": self.layout.kind,
                "pages_free": a.free_pages, "pages_live": a.live_pages,
                "pages_peak": a.peak_live, "pool_bytes": self.bytes(),
                "state_bytes": self.state_bytes(),

@@ -46,74 +46,36 @@ contains no model. A model that can be served implements
   never cached;
 - ``state_spec``: ``None``, or ``{name: (per-slot shape, dtype)}`` — the
   slot-indexed arenas the engine keeps per layer beside the paged K/V;
-- ``cache_spec``: what ONE token leaves in a layer's paged cache. ``None``
-  (GPT-2, Falcon-H1): a key and a value of ``[num_kv_heads, head_dim]``,
-  two arenas a layer. ``{"kind": "latent", "dim": d, "value_dim": dv}`` (a
-  latent-attention model): ONE row of ``d`` values — the arena is
-  ``[pages, page_len, d rounded up to 128 lanes]``, and ``attend`` takes
-  its latent form: ``attend(q_lat, q_rope, row)`` with ``q_lat`` ``[rows,
-  W, heads, dv]``, ``q_rope`` ``[rows, W, heads, d - dv]`` and the window's
-  own cache rows ``row`` ``[rows, W, d]``; the engine writes ``row``
-  through the page table and returns each head's softmax-weighted sum of
-  the cached rows' first ``dv`` columns, ``[rows, W, heads, dv]`` (the
-  absorbed form: the model carries it through its value up-projection).
-  With an ``"index"`` group — ``{"dim": di, "heads": hi, "topk": k, "layers":
-  ["full", "shared", ...]}``: a learned sparse attention — a token leaves a
-  SECOND row of ``di`` values (its index key) in every ``"full"`` layer, in
-  arenas of their own on the same page table, and ``attend`` of a ``"full"``
-  layer takes ``index=(qI, wI, kI)`` (index queries ``[rows, W, hi, di]``,
-  their float32 weights ``[rows, W, hi]``, the window's own index keys
+- ``cache_spec``: what ONE token leaves in a layer's paged cache — a dict
+  whose format is described where it is parsed, ``paged_kv.CacheLayout``
+  (``cache_layout`` hands a model's out), and which nothing else reads. What
+  ``block`` is handed follows the kind. ``None`` (GPT-2, Falcon-H1):
+  ``attend(q, k, v)`` as above. ``"latent"``: ``attend(q_lat, q_rope, row)``
+  with ``q_lat`` ``[rows, W, heads, dv]``, ``q_rope`` ``[rows, W, heads, d -
+  dv]`` and the window's own cache rows ``row`` ``[rows, W, d]``; the engine
+  writes ``row`` through the page table and returns each head's
+  softmax-weighted sum of the cached rows' first ``dv`` columns, ``[rows, W,
+  heads, dv]`` (the absorbed form: the model carries it through its value
+  up-projection). With an ``"index"`` group the ``attend`` of a layer that
+  owns an indexer takes ``index=(qI, wI, kI)`` (index queries ``[rows, W, hi,
+  di]``, their float32 weights ``[rows, W, hi]``, the window's own index keys
   ``[rows, W, di]``): the engine writes ``kI``, scores every visible key
   (``sum_j wI_j relu(qI_j . kI)``), takes each token's EXACT top-``k`` and
-  attends those keys alone; ``attend`` of a ``"shared"`` layer takes no
-  ``index`` and attends the set the last ``"full"`` layer selected, which
-  the window program keeps. A latent cache may also be OF TWO LAYER KINDS —
-  ``"layers": ["full", "window", ...]`` and ``"window": n`` as in
-  ``kv_by_layer`` below (two tables a row, two allocators, a window layer's
-  pages given back behind the window; ``attend.kind`` says the layer's), with
-  ``"window_row": {"dim": dw, "value_dim": dvw, "scale": s, "heads": hw}``,
-  a window layer's own row, value width, softmax scale and query heads (its
-  arena is ``[window pages, page_len, dw rounded up to 128 lanes]`` and its
-  ``attend`` the same latent form at those widths, over the keys ``i - n < j
-  <= i``; the heads say what tiles its kernel walks: ``stats()``), and an
-  ``"index"`` group whose ``layers`` says ``"full"`` for a layer that owns an
-  indexer and ``None`` for a window layer, which selects nothing
-  (``Dots3NoteForCausalLM``).
-  ``{"kind": "kv_by_layer", "layers": ["full", "window", ...], "window":
-  n}`` (a model whose layers are of two kinds): a key and a value of
-  ``[num_kv_heads, head_dim]`` in every layer, but a "window" layer's query
-  at position ``i`` sees only the keys ``i - n < j <= i``, so its pages go
-  back to their own pool as the window passes them while a "full" layer
-  keeps a page for every ``page_len`` tokens cached. ``attend(q, k, v)`` is
-  the K/V form, built for the layer it serves: it carries the layer's kind
-  as ``attend.kind`` (what a block needs to pick its RoPE), and the query's
-  own shape says how many heads the layer has — ``num_heads`` is not read.
-  ``"layers"`` may name two more kinds — memory BY LAYER KIND, for a stack
-  whose layers are one mixer each (``NemotronHForCausalLM``): ``"state"``, a
-  layer that keeps a row of the ``state_spec`` arenas and no page, and
-  ``"none"``, a layer that keeps nothing (a position-wise layer: routed
-  experts). The pool builds K/V arenas for the paging layers alone and state
-  arenas for the ``"state"`` layers alone; ``block`` is handed the ``attend``
-  (a paging layer) or the ``state`` (a ``"state"`` layer) its kind has and
-  ``None`` for the other; a page id is one page in each PAGING layer's
-  arena, so admission counts pages for those layers alone. ``"window"`` (and
-  the key of that name) is then optional: with no window layer there is no
-  second allocator and the tables are ``[1, rows, B]``. And a fifth kind,
-  ``"full+state"``: a layer of BOTH memories — a full layer's pages AND a
-  row of the ``state_spec`` arenas (``Zaya1ForCausalLM``: an attention whose
-  keys are mixed over the sequence by causal convolutions before they are
-  cached, so that beside its pages a slot keeps the conv's tail). The pool
-  builds K/V arenas and state arenas for it, admission counts its pages as a
-  full layer's, and ``block`` is handed BOTH its ``attend`` (``attend.kind``
-  ``"full"``) and its ``state`` (``paged_kv.LAYER_KEEPS`` says what each
-  kind keeps). At least one layer pages (else the spec is ``{"kind":
-  "none"}``), and a ``state_spec`` is declared exactly where some layer
-  keeps state (``"state"``, ``"full+state"``);
-  ``{"kind": "none"}`` (a model whose every layer keeps a recurrent state
-  and NOTHING else: Brumby's power retention): a token leaves nothing in
-  pages. The pool builds no K/V arena, the window programs take no page
-  table, admission counts slots alone, ``max_seq_len`` bounds positions only
-  and ``block`` is handed ``attend=None``; it needs a ``state_spec``;
+  attends those keys alone; a ``"shared"`` layer's takes no ``index`` and
+  attends the set the last owner selected, which the window program keeps.
+  ``"kv_by_layer"``: ``attend(q, k, v)``, the K/V form; the query's own shape
+  says how many heads the layer has — ``num_heads`` is not read. Where the
+  spec declares its layers' kinds (``"layers"``) each layer gets what ITS
+  kind keeps: an ``attend`` built for it if it pages (``attend.kind`` is
+  ``"full"`` or ``"window"`` — what a block needs to pick its RoPE or its
+  sizes; a window layer's sees the keys ``i - n < j <= i``, a latent one's
+  at its own row's widths), else ``None``; its own ``state`` if it keeps a
+  row, else ``None``; both for ``"full+state"`` (``Zaya1ForCausalLM``: an
+  attention whose keys are mixed by causal convolutions before they are
+  cached keeps the conv's tail), neither for ``"none"`` (a position-wise
+  layer: ``NemotronHForCausalLM``'s experts). ``{"kind": "none"}`` (Brumby):
+  ``attend=None`` in every layer, no page table in the programs, and
+  ``max_seq_len`` bounds positions only;
 - ``program_counters``: ``None``, or the names of int32 scalars a block may
   hand back as a THIRD result (``(x, state, {name: scalar})``, ``None`` from
   a layer that has none). The window program sums them over its layers and
@@ -145,14 +107,11 @@ contains no model. A model that can be served implements
   and returns the pair ``(the chunk's final row, the arenas)``
   (``NemotronHServed``). A block that resumes already speaks both
   conventions (``step=``); the pair is the two in one program. Who stays
-  out, and why: Falcon-H1 (``cache_spec`` ``None``: ``pt_paged_attention``
-  walks every page of every slot, and its scan starts from zero, so it does
-  not resume) and GPT-2 (the same kernel; each of its window programs
-  copies the whole arenas between two layouts: once it moves onto the ranged
-  kernel's layout, ROADMAP S2, it inherits the carried step through this
-  property); Brumby (cache kind ``"none"``: it resumes, but no kernel of its
-  takes a round's rows beside a chunk's, its 2048-token program from a state
-  is 15.16 of the chip's 15.2 GB as it is, and its cell sits at its knee:
+  out: Falcon-H1 and GPT-2 (``cache_spec`` ``None``: ``pt_paged_attention``
+  walks every page of every slot; Falcon-H1's scan does not resume either,
+  and GPT-2 inherits the carried step through this property once it moves
+  onto the ranged kernel's layout, ROADMAP S2) and Brumby (nothing paged: it
+  resumes, but no kernel of its takes a round's rows beside a chunk's:
   ROADMAP S16). For a state model that resumes and does not carry the engine
   sends a round of its own BETWEEN two chunks of a prompt
   (``GenerationEngine._round_between``); behind a call that carried one it
@@ -166,19 +125,10 @@ and ``head`` itself. A device trace then says where a window program's time
 goes in the model's own words (``tools/program_parts.py``), and
 ``tests/test_step_parts.py`` holds every served model to it.
 
-A model with recurrent state cannot use what assumes a cache is pages of
-K/V (the prefix trie, speculative verify, KV-page export/install) — one with
-nothing paged least of all, and the warm tier neither: there is no page to
-share, spill or ship, and no cache of state snapshots is built; one that
-keeps state in some layers and pages in others — or both in one layer — is
-refused the same four (a prefix's pages hold no state, no conv tail) — a latent
-cache cannot yet use what moves K/V pages (export/install and its wire
-format, the warm tier) — with an index row it shares index keys through the
-prefix trie like latent rows (one page table) but refuses a draft model too —
-and a cache with window layers, K/V or latent, cannot use what
-assumes that a page, once written, stays (the prefix trie, speculative
-verify, export/install, the warm tier): the engine refuses those in words
-(``docs/serving.md``).
+What a cache kind cannot use of what assumes that a cache is pages of K/V
+which, once written, stay (the prefix trie, speculative verify, KV-page
+export/install, the warm tier) the engine refuses in words, from one table by
+feature: ``paged_kv.CacheLayout.refuses`` (``docs/serving.md``).
 """
 from __future__ import annotations
 
@@ -186,6 +136,7 @@ import math
 from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 from ..observability.trace.parts import part
+from .paged_kv import CacheLayout
 
 __all__ = ["ServedModel", "GPTServed", "Carried", "recur", "flatten_params",
            "nest_params"]
@@ -204,10 +155,7 @@ class ServedModel:
     # None: the only cache is the paged K/V
     state_spec: Optional[Dict[str, Tuple[Tuple[int, ...], Any]]] = None
     # None: a token leaves K and V of [num_kv_heads, head_dim] in a layer;
-    # else {"kind": "latent", ...}, {"kind": "kv_by_layer", ...} (with
-    # "layers": what each layer keeps — "full" / "window" pages, "state",
-    # "none", "full+state") or {"kind": "none"} (nothing paged: the state is
-    # the model's memory)
+    # else a dict in the format ``paged_kv.CacheLayout`` describes
     cache_spec: Optional[Dict[str, Any]] = None
     # None: the window programs hand back tokens and logprobs alone
     program_counters: Optional[Tuple[str, ...]] = None
@@ -221,9 +169,15 @@ class ServedModel:
     def carries_rounds(self) -> bool:
         """Whether a prefill call of the largest bucket carries the running
         sequences' decode step (module docstring)."""
-        ranged = self.cache_spec is not None and \
-            self.cache_spec["kind"] in ("latent", "kv_by_layer")
-        return ranged and (self.state_spec is None or self.resumes_state)
+        return CacheLayout.ranges(self.cache_spec) and (
+            self.state_spec is None or self.resumes_state)
+
+    def cache_layout(self, page_len: int) -> CacheLayout:
+        """What ``cache_spec`` and ``state_spec`` come to at pages of
+        ``page_len`` tokens (``paged_kv.CacheLayout``: the format's reader)."""
+        return CacheLayout.parse(self.cache_spec, self.state_spec,
+                                 self.num_layers, page_len,
+                                 self.num_kv_heads, self.head_dim)
 
     def params(self, model) -> Dict[str, Any]:
         raise NotImplementedError

@@ -499,15 +499,14 @@ def test_a_long_prompt_on_falcon_h1_is_still_refused_with_todays_words():
 
 
 def test_a_cache_of_kind_none_needs_a_state():
-    from paddle_tpu.serving.paged_kv import PagedKVPool
+    from paddle_tpu.serving.paged_kv import CacheLayout, PagedKVPool
 
     with pytest.raises(ValueError, match="would remember nothing"):
-        PagedKVPool(2, 2, 16, 2, 8, F32, prefix_cache=False,
-                    cache_spec={"kind": "none"})
+        CacheLayout.parse({"kind": "none"}, None, 2, 16, 2, 8)
     with pytest.raises(ValueError, match="no prefix cache and no warm tier"):
-        PagedKVPool(2, 2, 16, 2, 8, F32, prefix_cache=True, max_slots=2,
-                    state_spec={"S": ((2, 64, 8), F32)},
-                    cache_spec={"kind": "none"})
+        PagedKVPool(CacheLayout.parse(
+            {"kind": "none"}, {"S": ((2, 64, 8), F32)}, 2, 16, 2, 8),
+            2, F32, prefix_cache=True, max_slots=2)
 
 
 def test_the_two_reference_files_are_one():

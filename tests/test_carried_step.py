@@ -284,7 +284,7 @@ def _operands(eng, rows, W, prefill):
         return jnp.zeros(shape, jnp.int32)
 
     return (eng._params, eng._pool.k, eng._pool.v,
-            None if eng._unpaged else i32(*eng._tables_shape(rows)),
+            i32(*eng._pool.tables_shape(rows)) if eng._layout.paged else None,
             i32(rows, W), i32(rows), i32(rows),
             None if prefill else eng._pool.state)
 
@@ -296,7 +296,7 @@ def _carrying_operands(eng):
     S = eng.config.max_slots
     lone, rnd = _operands(eng, 1, C, True), _operands(eng, S, 1, False)
     return lone[:3] + tuple(zip(lone[3:7], rnd[3:7])) + (
-        (None, rnd[7]) if eng._stateful else None,)
+        (None, rnd[7]) if eng._layout.stateful else None,)
 
 
 @pytest.mark.parametrize("kind,draft", [

@@ -18,8 +18,8 @@ from paddle_tpu.models import LagunaConfig, LagunaForCausalLM
 from paddle_tpu.models import laguna
 from paddle_tpu.models.reference import laguna as ref
 from paddle_tpu.nn.layer import moe
-from paddle_tpu.serving.paged_kv import (PagedKVPool, PoolExhausted,
-                                         window_page_bound)
+from paddle_tpu.serving.paged_kv import (CacheLayout, PageDemand, PagedKVPool,
+                                         PoolExhausted, window_page_bound)
 
 
 def _build(cfg, seed=3):
@@ -291,24 +291,28 @@ def test_keys_in_window_arithmetic():
 # -- (d) the cache's accounting -------------------------------------------------
 
 def test_a_slots_window_pages_never_pass_the_bound(tiny):
-    """Drive ``_window_pages`` as a prompt's chunks and then its decode
+    """Drive the pool's ``slide`` as a prompt's chunks and then its decode
     rounds do: the slot never holds more than the bound of the program in
-    flight, and what is behind the window has gone back."""
+    flight, what is behind the window has gone back, and the slide says what
+    changed hands."""
     _cfg, model, _params, _get = tiny
     eng = _engine(model, max_slots=2)
-    s, wa = eng._slots[0], eng._pool.window_allocator
+    pool = eng._pool
+    s, wa = eng._slots[0].pages, pool.window_allocator
     held = lambda: s.whi - s.wlo  # noqa: E731
     for lo in range(0, 64, 16):                       # four 16-token chunks
-        eng._window_pages(s, lo, lo + 15)
+        before = held()
+        released, taken = pool.slide(s, lo, lo + 15)
+        assert held() == before - released + taken
         assert held() <= window_page_bound(8, 16, 4) == 7
         assert wa.live_pages == held()
         first_visible = max(lo - 7, 0) // 4
         assert s.wlo == first_visible and s.whi == (lo + 15) // 4 + 1
         assert (s.wtable[:s.wlo] == 0).all() and (s.wtable[s.wlo:s.whi] > 0).all()
     for pos in range(64, 100):                        # decode rounds
-        eng._window_pages(s, pos, pos)
-        assert held() <= window_page_bound(8, 1, 4) == 4
-    eng._release_pages(s)
+        pool.slide(s, pos, pos)
+        assert held() <= window_page_bound(8, 1, 4) == 4 == eng._wbound
+    pool.release(s)
     assert wa.live_pages == 0 and (s.wtable == 0).all()
     wa.check()
     eng.close()
@@ -319,11 +323,34 @@ def test_admission_counts_both_kinds_and_requeues_when_either_is_short(tiny):
     give its widest chunk beside what the running slots are promised; the
     engine serves both requests all the same, one after the other."""
     cfg, model, _params, _get = tiny
-    pool = PagedKVPool(5, 40, 4, 2, 16, jnp.float32, prefix_cache=False,
-                       cache_spec=model.served_model().cache_spec,
-                       window_pages=12)
-    assert pool.can_allocate(10, 7, 4) and not pool.can_allocate(10, 8, 4)
-    assert not pool.can_allocate(40, 1, 0)       # the full pool is short
+    pool = PagedKVPool(model.served_model().cache_layout(4), 40, jnp.float32,
+                       prefix_cache=False, max_slots=1, n_blocks=16,
+                       window_pages=12, chunk=16)
+    # a running slot holds one window page and is promised its bound, 4: of
+    # the 11 usable, 10 are free and 7 left for a joining prompt's widest call
+    running = pool.slot_pages()
+    assert pool.join(running, pool.demand(np.arange(1), 3)) == (0, 1)
+    long = pool.demand(np.arange(20), 20)
+    assert long == PageDemand([tuple(range(i, i + 4)) for i in range(0, 20, 4)],
+                              10, 7, 20)
+    assert pool.can_allocate(long)
+    (taken,) = pool.window_allocator.alloc(1)
+    assert not pool.can_allocate(long)
+    pool.window_allocator.release(taken)
+    # the full pool is short
+    assert not pool.can_allocate(PageDemand([], 40, 4, 1))
+    # a join that cannot have its window pages leaves the pool as it was
+    joining, held = pool.slot_pages(), pool.window_allocator.alloc(9)
+    free = pool.allocator.free_pages
+    with pytest.raises(PoolExhausted):
+        pool.join(joining, long)
+    assert pool.allocator.free_pages == free and joining.blocks == 0 \
+        and not joining.table.any()
+    for page in held:
+        pool.window_allocator.release(page)
+    pool.release(running)
+    assert pool.allocator.live_pages == 0 == \
+        pool.window_allocator.live_pages
     assert [a.shape for a in pool.k] == [
         (40, 2, 4, 16), (12, 2, 4, 16), (12, 2, 4, 16), (12, 2, 4, 16),
         (40, 2, 4, 16)]
@@ -381,12 +408,10 @@ def test_what_cannot_serve_a_windowed_cache_is_refused_in_words(tiny):
         eng.install_kv_pages(prompt, [], [])
     eng.close()
     with pytest.raises(ValueError, match="unknown cache kind 'ring'"):
-        PagedKVPool(1, 4, 4, 2, 16, jnp.float32, prefix_cache=False,
-                    cache_spec={"kind": "ring"})
+        CacheLayout.parse({"kind": "ring"}, None, 1, 4, 2, 16)
     with pytest.raises(ValueError, match="no prefix cache and no warm tier"):
-        PagedKVPool(5, 8, 4, 2, 16, jnp.float32, prefix_cache=True,
-                    cache_spec=model.served_model().cache_spec,
-                    window_pages=8)
+        PagedKVPool(model.served_model().cache_layout(4), 8, jnp.float32,
+                    prefix_cache=True, window_pages=8)
 
 
 def test_the_benchmarks_reference_is_the_repos():

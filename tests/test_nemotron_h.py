@@ -436,26 +436,25 @@ def test_what_assumes_pages_of_kv_is_refused_in_words(tiny):
 
 
 def test_a_cache_spec_must_say_what_its_layers_keep():
-    from paddle_tpu.serving.paged_kv import PagedKVPool
+    from paddle_tpu.serving.paged_kv import (CacheLayout, PageDemand,
+                                             PagedKVPool)
 
     spec = {"ssm": ((2, 4, 8), jnp.float32)}
-    kw = dict(prefix_cache=False, max_slots=2)
+
+    def layout(layers, state_spec):
+        return CacheLayout.parse({"kind": "kv_by_layer", "layers": layers},
+                                 state_spec, len(layers), 4, 2, 8)
+
     with pytest.raises(ValueError, match="'state' or 'none'"):
-        PagedKVPool(2, 8, 4, 2, 8, jnp.float32, state_spec=spec, **kw,
-                    cache_spec={"kind": "kv_by_layer",
-                                "layers": ["full", "ring"]})
+        layout(["full", "ring"], spec)
     with pytest.raises(ValueError, match="exactly where the model declares"):
-        PagedKVPool(2, 8, 4, 2, 8, jnp.float32, **kw,
-                    cache_spec={"kind": "kv_by_layer",
-                                "layers": ["full", "state"]})
+        layout(["full", "state"], None)
     with pytest.raises(ValueError, match="pages nothing"):
-        PagedKVPool(2, 8, 4, 2, 8, jnp.float32, state_spec=spec, **kw,
-                    cache_spec={"kind": "kv_by_layer",
-                                "layers": ["state", "none"]})
-    pool = PagedKVPool(3, 8, 4, 2, 8, jnp.float32, state_spec=spec, **kw,
-                       cache_spec={"kind": "kv_by_layer",
-                                   "layers": ["state", "full", "none"]})
+        layout(["state", "none"], spec)
+    pool = PagedKVPool(layout(["state", "full", "none"], spec), 8,
+                       jnp.float32, prefix_cache=False, max_slots=2)
     assert len(pool.k) == len(pool.v) == len(pool.state) == 1
     assert pool.state[0]["ssm"].shape == (2, 2, 4, 8)
     assert pool.layers_by_kind() == {"state": 1, "full": 1, "none": 1}
-    assert pool.can_allocate(7) and not pool.can_allocate(8)
+    assert pool.can_allocate(PageDemand([], 7, 0, 1)) and \
+        not pool.can_allocate(PageDemand([], 8, 0, 1))

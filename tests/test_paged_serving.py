@@ -22,7 +22,8 @@ import pytest
 import paddle_tpu as paddle
 from paddle_tpu import serving
 from paddle_tpu.serving.paged_kv import (
-    PageAllocator, PagedKVPool, PoolExhausted, PrefixCache, token_blocks,
+    CacheLayout, PageAllocator, PagedKVPool, PoolExhausted, PrefixCache,
+    token_blocks,
 )
 
 
@@ -144,8 +145,8 @@ def test_token_blocks_full_blocks_only():
 
 
 def test_pool_cow_copies_device_contents():
-    pool = PagedKVPool(num_layers=1, num_pages=4, page_len=2, num_heads=1,
-                       head_dim=2, dtype="float32")
+    pool = PagedKVPool(CacheLayout.parse(None, None, 1, 2, 1, 2), 4,
+                       "float32")
     (p,) = pool.allocate(1)
     pool.k[0] = pool.k[0].at[p].set(1.5)
     pool.allocator.retain(p)               # shared: a writer must COW
@@ -1209,18 +1210,16 @@ def test_window_page_bound(window, tokens, page_len, want):
 def test_allocator_tracks_its_peak_and_a_two_kind_pool_reports_both():
     import jax.numpy as jnp
 
-    from paddle_tpu.serving.paged_kv import PagedKVPool
-
     a = PageAllocator(8)
     held = a.alloc(5)
     for p in held[:3]:
         a.release(p)
     a.alloc(2)
     assert (a.peak_live, a.live_pages) == (5, 4)
-    pool = PagedKVPool(3, 10, 4, 2, 8, jnp.float32, prefix_cache=False,
-                       cache_spec={"kind": "kv_by_layer", "window": 8,
-                                   "layers": ["full", "window", "window"]},
-                       window_pages=6)
+    pool = PagedKVPool(CacheLayout.parse(
+        {"kind": "kv_by_layer", "window": 8,
+         "layers": ["full", "window", "window"]}, None, 3, 4, 2, 8),
+        10, jnp.float32, prefix_cache=False, window_pages=6)
     pool.allocate(3)
     pool.window_allocator.alloc(2)
     st = pool.stats()
@@ -1231,6 +1230,6 @@ def test_allocator_tracks_its_peak_and_a_two_kind_pool_reports_both():
     assert pool.bytes() == sum(pool.bytes_by_kind().values()) == \
         st["pool_bytes"]
     with pytest.raises(ValueError, match="must name 3 layers"):
-        PagedKVPool(3, 10, 4, 2, 8, jnp.float32, prefix_cache=False,
-                    cache_spec={"kind": "kv_by_layer", "window": 8,
-                                "layers": ["full", "ring", "window"]})
+        CacheLayout.parse({"kind": "kv_by_layer", "window": 8,
+                           "layers": ["full", "ring", "window"]},
+                          None, 3, 4, 2, 8)

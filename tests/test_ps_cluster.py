@@ -118,6 +118,11 @@ _PS_WORKER = textwrap.dedent("""
         w = trainer.pull_dense("w")
         uniq, inv = np.unique(ids.ravel(), return_inverse=True)
         rows = trainer.pull("emb", uniq)
+        # a synchronous step: every trainer has pulled before any pushes —
+        # without this rendezvous a trainer descheduled between the first
+        # barrier and its pull reads the other's update (the parity below
+        # then fails by 0.6 %: 3 of 24 runs with six of them at once)
+        store.barrier(f"step{step}pulled")
         e = rows[inv].reshape(len(y), F, D)
         s = e.sum(1)
         pred = s @ w

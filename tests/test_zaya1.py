@@ -425,18 +425,18 @@ def test_what_assumes_pages_of_kv_is_refused_in_words(tiny):
 
 
 def test_a_layer_of_both_memories_is_a_declared_kind():
-    from paddle_tpu.serving.paged_kv import LAYER_KEEPS, PagedKVPool
+    from paddle_tpu.serving.paged_kv import (LAYER_KEEPS, CacheLayout,
+                                             PagedKVPool)
 
     assert LAYER_KEEPS["full+state"] == ("full", True)
     spec = {"tail": ((10,), jnp.float32)}
-    kw = dict(prefix_cache=False, max_slots=2)
     with pytest.raises(ValueError, match="exactly where the model declares"):
-        PagedKVPool(2, 8, 4, 2, 8, jnp.float32, **kw,
-                    cache_spec={"kind": "kv_by_layer",
-                                "layers": ["full", "full+state"]})
-    pool = PagedKVPool(4, 8, 4, 2, 8, jnp.float32, state_spec=spec, **kw,
-                       cache_spec={"kind": "kv_by_layer", "layers": [
-                           "full+state", "none", "state", "full"]})
+        CacheLayout.parse({"kind": "kv_by_layer",
+                           "layers": ["full", "full+state"]}, None, 2, 4, 2, 8)
+    pool = PagedKVPool(CacheLayout.parse(
+        {"kind": "kv_by_layer",
+         "layers": ["full+state", "none", "state", "full"]}, spec, 4, 4, 2, 8),
+        8, jnp.float32, prefix_cache=False, max_slots=2)
     assert len(pool.k) == len(pool.v) == len(pool.state) == 2
     assert pool.state[0]["tail"].shape == (2, 10)
     assert pool.layers_by_kind() == {"full": 2, "state": 2, "none": 1}
