@@ -1396,13 +1396,17 @@ class GenerationEngine(EngineBase):
             f"attn_keys_window_{'decode' if decode else 'prefill'}_total",
             windowed * layers["window"])
 
-    def _count_walk(self, W: int, keys, decode: bool = False) -> None:
+    def _count_walk(self, W: int, keys, live=None,
+                    decode: bool = False) -> None:
         """What the two kinds' kernel calls of a program dispatched walk:
         ``keys`` cached tokens in front of each row's ``W`` window tokens (one
-        number a row). The pages the kernel's tiles DMA for them
-        (``walk_cost``: every tile of a row walks its range again) and the
-        pages that hold a key in range, once a layer of the kind; a decode
-        round's part is counted apart too, as its keys are."""
+        number a row), ``live`` which rows hold a sequence (``None``: all —
+        the kernel starts no walk for an idle row). The pages the kernel's
+        tiles DMA for them (``walk_cost``: every tile of a row walks its
+        range again) and the pages that hold a key in range, once a layer of
+        the kind; a decode round's part is counted apart too, as its keys
+        are. And how often the kernel's pipeline engaged: the idle rows it
+        skipped, and the grid steps whose first block was in flight."""
         layout = self._layout
         if layout.latent:
             # a latent cache's window kernel alone walks a range (a full
@@ -1422,11 +1426,15 @@ class GenerationEngine(EngineBase):
 
         (G, PL, d), itemsize = layout.page, \
             self._params["embed"].dtype.itemsize
+        idle = 0 if live is None else len(live) - int(np.count_nonzero(live))
         for kind, layers in layout.layers_of.items():
             shape = (W, self._attends[kind].walks[W], G, PL, d,
                      None if kind == "full" else layout.window)
             cost = walk_cost(len(keys), *shape, keys,
-                             choose_tiles(*shape, itemsize), itemsize)
+                             choose_tiles(*shape, itemsize), itemsize, live)
+            self.metrics.inc("attn_rows_idle_skipped_total", idle * layers)
+            self.metrics.inc("attn_steps_prefetched_total",
+                             cost["prefetched"] * layers)
             for what in ("walked", "in_range"):
                 n = cost["pages" if what == "walked" else "pages_in_range"]
                 self.metrics.inc(f"attn_pages_{what}_{kind}_total",
@@ -2649,8 +2657,9 @@ class GenerationEngine(EngineBase):
             seen = rnd.lengths[[i for i, _req in rnd.rows]] + 1
             self._count_keys(int(seen.sum()), int(
                 np.minimum(seen, layout.window).sum()), decode=True)
-            # every row of the call walks, a live sequence's or not
-            self._count_walk(k + 1, rnd.lengths, decode=True)
+            # the rows of the call that walk: the live sequences'
+            self._count_walk(k + 1, rnd.lengths, self._round_valid(rnd) > 0,
+                             decode=True)
         self.metrics.observe_occupancy(n_active / S)
         with span("pt.serve.emit"):
             emitted_total = self._emit_round(rnd, n, lpn)

@@ -233,8 +233,9 @@ def test_the_engine_counts_the_pages_its_attention_calls_walk(tiny):
     """``attn_pages_walked_<kind>_total`` / ``attn_pages_in_range_<kind>_total``
     and ``stats()["attn_walk_amplification"]``: ``walk_cost`` of the tiles
     ``choose_tiles`` gives each traced shape, over the calls dispatched — a prompt of three
-    chunks (behind 0, 16 and 32 tokens) and the rounds that follow, every
-    row of a round counted, idle ones too."""
+    chunks (behind 0, 16 and 32 tokens) and the rounds that follow, whose
+    idle rows walk nothing: they are counted as skipped, and every live
+    grid step but a call's first as one whose first block was in flight."""
     from paddle_tpu.kernels.pallas.ranged_paged_attention import \
         choose_tiles, walk_cost
 
@@ -246,30 +247,36 @@ def test_the_engine_counts_the_pages_its_attention_calls_walk(tiny):
     c = st["counters"]
     G, PL, d = cfg.num_key_value_heads, 4, cfg.head_dim
     layers = {"full": 2, "window": 3}
+    pipeline = {"skipped": 0, "prefetched": 0}
     for kind, window in (("full", None), ("window", 8)):
         walks = eng._attends[kind].walks
         assert {1, 8, 16} <= set(walks)     # a round, the buckets called
         want = {"pages": 0, "pages_in_range": 0}
         # chunks of 16, 16 and 5 tokens (the last in the bucket of 8), then
         # the three rounds that emit tokens 2..4: the prompt's slot at 37,
-        # 38, 39 and two idle rows at 0
-        calls = [(16, [0]), (16, [16]), (8, [32])] + \
-            [(1, [n, 0, 0]) for n in (37, 38, 39)]
+        # 38, 39 and two idle rows, which walk nothing
+        calls = [(16, [0], None), (16, [16], None), (8, [32], None)] + \
+            [(1, [n, 0, 0], [True, False, False]) for n in (37, 38, 39)]
         Hg = cfg.num_attention_heads_per_layer[0 if kind == "full" else 1] // G
-        for W, keys in calls:
+        for W, keys, live in calls:
             assert walks[W] == Hg
             cost = walk_cost(len(keys), W, Hg, G, PL, d, window, keys,
-                             choose_tiles(W, Hg, G, PL, d, window, 4), 4)
+                             choose_tiles(W, Hg, G, PL, d, window, 4), 4,
+                             live)
             for k in want:
                 want[k] += cost[k] * layers[kind]
+            for k in pipeline:
+                pipeline[k] += cost[k] * layers[kind]
         walked = c[f"attn_pages_walked_{kind}_total"]
         held = c[f"attn_pages_in_range_{kind}_total"]
         assert (walked, held) == (want["pages"], want["pages_in_range"])
-        # the rounds' part apart: three rounds of three rows
+        # the rounds' part apart: three rounds of one live row of three
         dec = {"pages": 0, "pages_in_range": 0}
-        for W, keys in calls[3:]:
+        for W, keys, live in calls[3:]:
             cost = walk_cost(3, 1, Hg, G, PL, d, window, keys,
-                             choose_tiles(1, Hg, G, PL, d, window, 4), 4)
+                             choose_tiles(1, Hg, G, PL, d, window, 4), 4,
+                             live)
+            assert (cost["skipped"], cost["prefetched"]) == (2, 0)
             for k in dec:
                 dec[k] += cost[k] * layers[kind]
         assert c[f"attn_pages_walked_{kind}_decode_total"] == dec["pages"]
@@ -280,6 +287,12 @@ def test_the_engine_counts_the_pages_its_attention_calls_walk(tiny):
                              / (held - dec["pages_in_range"]), 3),
             "decode": round(dec["pages"] / dec["pages_in_range"], 3)}
         assert walked >= held > 0
+    # two idle rows a round, three rounds, five layers; a round's one live
+    # step is its call's first and every chunk here is one tile: no step
+    # found its first block in flight (``test_ranged_attention_tiles.py``
+    # lists streams that do)
+    assert c["attn_rows_idle_skipped_total"] == pipeline["skipped"] == 30
+    assert c["attn_steps_prefetched_total"] == pipeline["prefetched"] == 0
 
 
 def test_keys_in_window_arithmetic():

@@ -750,5 +750,83 @@ def test_ranged_paged_attention_ignores_pages_given_back():
     got = kr.ranged_paged_attention(q, ka, va, jnp.asarray(gone), st,
                                     window=8, scale=0.09, impl="interpret")
     _close(got, want)
+    # a live row whose table STARTS with the scratch page is not idle
+    assert (np.abs(np.asarray(got)).max(axis=(1, 2, 3)) > 0).all()
     with pytest.raises(ValueError, match="query heads over"):
         kr.ranged_paged_attention(q[:, :, :7], ka, va, tables, st, scale=1.0)
+
+
+# rows of a round of six that hold no sequence (lengths 0, a table of zeros)
+_IDLE_ROWS = {"first": [0, 1], "last": [4, 5], "consecutive": [2, 3],
+              "alternate": [0, 2, 4], "all": [0, 1, 2, 3, 4, 5]}
+
+
+@pytest.mark.parametrize("window", [None, 24])
+@pytest.mark.parametrize("W", [1, 4])
+@pytest.mark.parametrize("idle", list(_IDLE_ROWS))
+def test_ranged_paged_attention_skips_idle_rows(monkeypatch, idle, W, window):
+    """A row whose first token's own key lies in the scratch page starts no
+    walk and returns zeros, wherever it sits among the live rows (whose
+    first block the step before starts: the pipeline crosses the idle rows
+    too). The live rows give the reference's result and, bit for bit, what
+    the kernel gives them with the idle rows taken out of the call."""
+    starts = [3, 37, 90, 12, 64, 121]
+    kr, q, ka, va, tables, st = _ranged_case(6, W, 8 if window else 6,
+                                             starts, B=32, seed=7)
+    # a round's tiles at the served shapes: blocks of 4 pages, of 1 in a
+    # window layer; W = k + 1 = 4 in two tiles a row
+    monkeypatch.setattr(kr, "choose_tiles", lambda *a, **k: (
+        1 if W == 1 else 2, 1 if window else 4))
+    gone = _IDLE_ROWS[idle]
+    live = [r for r in range(6) if r not in gone]
+    tables, st = np.asarray(tables).copy(), np.asarray(st).copy()
+    tables[gone], st[gone] = 0, 0
+    if window:   # the live rows' pages behind the window went back too
+        for r in live:
+            tables[r, :max(starts[r] - (window - 1), 0) // 8] = 0
+    tables, st = jnp.asarray(tables), jnp.asarray(st)
+    ka, va = ka.at[0].set(1e3), va.at[0].set(1e3)   # never read
+
+    def attend(rows, impl):
+        rows = jnp.asarray(rows, jnp.int32)
+        return np.asarray(kr.ranged_paged_attention(
+            q[rows], ka, va, tables[rows], st[rows], window=window,
+            scale=0.09, impl=impl))
+
+    got = attend(range(6), "interpret")
+    assert not got[gone].any()
+    _close(got, attend(range(6), "reference"))
+    if live:
+        assert np.abs(got[live]).max(axis=(1, 2, 3)).min() > 0
+        np.testing.assert_array_equal(got[live], attend(live, "interpret"))
+
+
+@pytest.mark.parametrize("tiles,W,Hg,window,starts", [
+    # what `choose_tiles` hands the three served configurations' chunks, in
+    # this file's pages of 8: one tile a chunk over blocks of 8 pages,
+    ((32, 8), 32, 4, None, [40, 0, 8]),
+    # many tiles a row (every tile's first block started by the tile before)
+    ((8, 8), 32, 16, None, [136, 0, 64]),
+    ((16, 8), 64, 6, None, [200, 0, 16]),
+    # a window layer's blocks of 2 pages, each tile its own ``lo``
+    ((8, 2), 32, 8, 24, [53, 0, 120]),
+    ((16, 2), 64, 8, 24, [77, 0, 8]),
+    # and a round's (1, 4) and (1, 1) under W = k + 1 = 3 tiles a row
+    ((1, 4), 3, 4, None, [61, 0, 95]),
+    ((1, 1), 3, 8, 24, [61, 0, 95]),
+])
+def test_ranged_paged_attention_pipeline_over_tiles(monkeypatch, tiles, W, Hg,
+                                                    window, starts):
+    """Rows of several tiles with an idle row between them: the walk's
+    pipeline runs from tile to tile, over the idle row and into the next
+    row, under every kind of tiling the chooser returns at the served
+    shapes."""
+    kr, q, ka, va, tables, st = _ranged_case(3, W, Hg, starts, B=40, seed=11)
+    monkeypatch.setattr(kr, "choose_tiles", lambda *a, **k: tiles)
+    tables = tables.at[1].set(0)
+    ka = ka.at[0].set(1e3)
+    got, want = (np.asarray(kr.ranged_paged_attention(
+        q, ka, va, tables, st, window=window, scale=0.09, impl=impl))
+        for impl in ("interpret", "reference"))
+    assert not got[1].any() and np.abs(got[[0, 2]]).min(axis=-1).max() > 0
+    _close(got, want)

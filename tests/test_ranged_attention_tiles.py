@@ -14,9 +14,9 @@ LAGUNA = dict(G=8, PL=128, d=128)
 PAGE = 2 * 8 * 128 * 128 * 2          # a page's K and V, bytes
 
 
-def _cost(S, W, Hg, window, keys, tiling, **shape):
+def _cost(S, W, Hg, window, keys, tiling, live=None, **shape):
     return kr.walk_cost(S, W, Hg, window=window, keys=keys, tiling=tiling,
-                        **{**LAGUNA, **shape})
+                        live=live, **{**LAGUNA, **shape})
 
 
 # -- walk_cost against a hand count --------------------------------------------
@@ -50,11 +50,51 @@ def test_a_round_walks_each_rows_range_once():
     # key 1000 lies in block 1 of 512: two blocks, the 8 pages that hold a key
     assert c["steps"] == 128 and c["iterations"] == 256
     assert c["pages"] == c["pages_in_range"] == 128 * 8
-    # one length a row; an idle row (length 0) still walks its first block
+    # one length a row; a sequence with nothing cached yet walks the block
+    # its own key lies in
     lengths = np.array([0, 511, 512, 5000])
     c = _cost(4, 1, 6, None, lengths, (1, 4))
     assert c["iterations"] == 1 + 1 + 2 + 10
     assert c["pages_in_range"] == 1 + 4 + 5 + 40
+    assert (c["skipped"], c["prefetched"]) == (0, 3)
+
+
+def test_an_idle_row_starts_no_walk():
+    """A hand-listed round: rows 0, 2 and 5 hold no sequence. They add no
+    iteration, page or byte; a live step finds its first block in flight
+    where the step before it was live (row 4 behind row 3), and starts its
+    own behind an idle one or at the call's start."""
+    lengths = np.array([0, 511, 0, 512, 5000, 0])
+    c = _cost(6, 1, 6, None, lengths, (1, 4), live=lengths > 0)
+    assert (c["steps"], c["skipped"], c["prefetched"]) == (6, 3, 1)
+    assert c["iterations"] == 1 + 2 + 10
+    assert c["pages"] == 4 * 13 and c["bytes"] == 4 * 13 * PAGE
+    assert c["pages_in_range"] == 4 + 5 + 40
+    # the same rows alone: the same walk, but the first live row is the
+    # call's first step now
+    alone = _cost(3, 1, 6, None, lengths[lengths > 0], (1, 4))
+    assert {k: c[k] for k in ("iterations", "pages", "bytes",
+                              "pages_in_range")} == \
+        {k: alone[k] for k in ("iterations", "pages", "bytes",
+                               "pages_in_range")}
+    assert (alone["steps"], alone["skipped"], alone["prefetched"]) == (3, 0, 2)
+    # a window layer's idle rows: the five pages of each live row, no more
+    c = _cost(4, 1, 8, 512, [1000, 0, 0, 1000], (1, 1),
+              live=[True, False, False, True])
+    assert c["pages"] == c["pages_in_range"] == 10
+    assert (c["skipped"], c["prefetched"]) == (2, 0)
+    # W = k + 1 = 4 tokens a row in tiles of one: an idle row is 4 steps, a
+    # live row's later tiles find their block in flight
+    c = _cost(3, 4, 6, None, [0, 600, 0], (1, 4), live=[False, True, False])
+    assert (c["steps"], c["skipped"], c["prefetched"]) == (12, 8, 3)
+    assert c["iterations"] == 4 * 2
+    # nothing live: nothing walked
+    c = _cost(5, 1, 6, None, 0, (1, 4), live=np.zeros(5, bool))
+    assert (c["skipped"], c["prefetched"], c["iterations"], c["pages"],
+            c["bytes"], c["pages_in_range"]) == (5, 0, 0, 0, 0, 0)
+    # a chunk never has an idle tile: every tile but the first is prefetched
+    c = _cost(1, 2048, 6, None, 8192, (128, 8))
+    assert (c["steps"], c["skipped"], c["prefetched"]) == (16, 0, 15)
 
 
 def test_a_window_layer_walks_six_pages_for_five():
@@ -102,11 +142,20 @@ CHOSEN = {
     "laguna-chunk512-window": ((512, 8, 8, 128, 128, 512), (128, 2)),
     "gpt2-large-round": ((1, 1, 20, 16, 64, None), (1, 32)),
     "gpt2-large-chunk256": ((256, 1, 20, 16, 64, None), (256, 64)),
-    "falcon-h1-round": ((1, 5, 4, 16, 128, None), (1, 32)),
+    "falcon-h1-round": ((1, 5, 4, 16, 128, None), (1, 64)),
     "falcon-h1-chunk256": ((256, 5, 4, 16, 128, None), (128, 64)),
+    # 2 K/V heads of 128 on pages of 128 (PR 62: an iteration's fixed part is
+    # paid once whatever the heads, so fewer heads want longer blocks): ZAYA1
+    # (4 query heads a K/V head, 256 rows) and Nemotron-H (16, 128 rows);
+    # their chunks as before
+    "zaya1-round": ((1, 4, 2, 128, 128, None), (1, 8)),
+    "nemotron-h-round": ((1, 16, 2, 128, 128, None), (1, 8)),
+    "zaya1-chunk256": ((256, 4, 2, 128, 128, None), (256, 8)),
+    "zaya1-chunk2048": ((2048, 4, 2, 128, 128, None), (256, 8)),
+    "nemotron-h-chunk2048": ((2048, 16, 2, 128, 128, None), (64, 8)),
     # the parity tests' pages of 8 tokens, windows of 8 and 24
-    "tiny-round": ((1, 6, 2, 8, 128, None), (1, 64)),
-    "tiny-chunk-window": ((16, 8, 2, 8, 128, 8), (8, 1)),
+    "tiny-round": ((1, 6, 2, 8, 128, None), (1, 128)),
+    "tiny-chunk-window": ((16, 8, 2, 8, 128, 8), (16, 1)),
     "tiny-chunk64-window": ((64, 8, 2, 8, 128, 24), (8, 1)),
 }
 

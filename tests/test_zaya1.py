@@ -404,6 +404,34 @@ def test_a_carried_round_is_a_round_of_its_own(tiny, carry):
         2 + 2 + 59 + 3
 
 
+def test_a_round_with_idle_slots_is_the_round_without_them(tiny):
+    """One sequence in an engine of four slots: three rows of every round
+    hold nothing. The attention kernel starts no walk for them (their own
+    key's page is the scratch page) and hands back zeros, which nothing
+    reads: the sequence's tokens and logprobs are those of an engine of ONE
+    slot, and the reference's; the pages walked are the live row's alone."""
+    cfg, c, model, _params, get = tiny
+    prompt = np.random.default_rng(4).integers(0, cfg.vocab_size, 21)
+    alone, full_a, lp_a = _one(model, prompt, 6, prefill_buckets=(64,),
+                               carry=False)
+    among, full_b, lp_b = _one(model, prompt, 6, prefill_buckets=(64,),
+                               max_slots=4, carry=False)
+    assert (full_a == full_b).all()
+    np.testing.assert_allclose(lp_b, lp_a, rtol=0, atol=2e-5)
+    want, _chosen, _st = ref.next_token_logprobs(get, c, full_b, 64)
+    np.testing.assert_allclose(lp_b, want[len(prompt) - 1:], rtol=0,
+                               atol=PARITY)
+    ca, cb = (e.stats()["counters"] for e in (alone, among))
+    rounds = cb["decode_steps"]
+    assert rounds == ca["decode_steps"] == 5
+    assert ca.get("attn_rows_idle_skipped_total", 0) == 0
+    assert cb["attn_rows_idle_skipped_total"] == \
+        3 * rounds * cfg.num_hidden_layers
+    for name in ("attn_pages_walked_full_decode_total",
+                 "attn_pages_in_range_full_decode_total"):
+        assert cb[name] == ca[name] > 0
+
+
 # -- refusals, in words ---------------------------------------------------------
 
 def test_what_assumes_pages_of_kv_is_refused_in_words(tiny):
